@@ -39,9 +39,9 @@ import os
 import sys
 
 # f32 single-chip data-parallel throughput of this framework measured with
-# the scan-differencing methodology below on one TPU v5e (the reference
-# repo publishes no figures — BASELINE.md; its perf story is
-# self-relative).
+# the scan-differencing methodology below on one TPU v5e in August 2026
+# (the reference repo publishes no figures; its perf story is
+# self-relative). Not re-measured on the current machine.
 BASELINE_SAMPLES_PER_SEC = 238.0
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -64,7 +64,7 @@ def run_flagship():
         num_layers=layers,
     )
     batch = model.executor.shard_batch(synthetic_batch(batch_size, seq, hidden))
-    per_step = measure_train_step(model, batch, reps=8, rep_sleep_s=2.0)
+    per_step = measure_train_step(model, batch, reps=8)
     thpt = batch_size / per_step
 
     print(

@@ -44,7 +44,11 @@ class FFConfig:
     # machine (reference: -ll:gpu/-ll:cpu + numNodes)
     num_nodes: int = 1
     workers_per_node: int = 0  # 0 = use all local devices
-    chip: str = "v4"
+    # CHIP_SPECS key the cost model prices (--chip). "" = the chip JAX
+    # reports (core.machine.detect_chip), filled in by compile() and by
+    # every MachineSpec built from it; naming one is the
+    # search-without-hardware override
+    chip: str = ""
 
     # search (reference: --budget/--alpha/--import/--export/…)
     search_budget: int = 0

@@ -142,6 +142,41 @@ CHIP_SPECS = {
     "cpu-sim": (0.2, 50.0, 16.0, 10.0, 2),
 }
 
+# `jax.devices()[0].device_kind` -> CHIP_SPECS key, the one table every
+# default chip goes through. A TPU kind that is not listed is an error:
+# pricing a chip the program is not on is how a 16 GB v5e got searched
+# with 32 GiB of v4 memory.
+DEVICE_KIND_TO_CHIP = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+}
+
+# off a TPU there is no chip to read: the search-without-hardware target
+# stays the one the CPU tests and strategy fixtures were written against
+# (`--chip` / MachineSpec(chip=...) choose another)
+_NO_TPU_CHIP = "v4"
+
+
+def detect_chip() -> str:
+    """The CHIP_SPECS key of the chip this process runs on."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return _NO_TPU_CHIP
+    chip = DEVICE_KIND_TO_CHIP.get(device.device_kind)
+    if chip is None:
+        raise ValueError(
+            f"TPU device_kind {device.device_kind!r} is not in "
+            f"DEVICE_KIND_TO_CHIP ({sorted(DEVICE_KIND_TO_CHIP)}); add it "
+            "with its CHIP_SPECS entry, or pass --chip to search for "
+            "another machine"
+        )
+    return chip
+
 
 @dataclasses.dataclass(frozen=True)
 class MachineSpec:
@@ -154,13 +189,17 @@ class MachineSpec:
 
     num_nodes: int = 1
     chips_per_node: int = 4
-    chip: str = "v4"
+    chip: str = ""  # "" = detect_chip(): the chip this process runs on
     # mesh topology of the full slice, e.g. (4, 4, 2) for v4-32.
     torus: Optional[Tuple[int, ...]] = None
     dcn_bandwidth_gbps: float = 25.0  # per-host DCN GB/s
     # override the chip's HBM capacity (search-without-hardware: probe
     # feasibility against a hypothetical memory budget)
     hbm_bytes_override: Optional[int] = None
+
+    def __post_init__(self):
+        if not self.chip:
+            object.__setattr__(self, "chip", detect_chip())
 
     @property
     def num_chips(self) -> int:

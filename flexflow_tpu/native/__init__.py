@@ -12,8 +12,10 @@ fallback so the framework works where no C++ toolchain exists
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -33,17 +35,54 @@ _PKG_LIB_PATH = os.path.join(
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _lib_failed = False
+# one line saying which implementation serves this process and why;
+# written by the first get_lib() (see implementation())
+_status = ""
+
+# written beside the library after a successful build: the hash of the
+# sources it was built from
+_STAMP_PATH = _LIB_PATH + ".srchash"
 
 
-def _sources_newer_than_lib() -> bool:
+def _source_hash() -> str:
+    """Hash of everything `make` reads for libffnative.so. The build is
+    keyed on this, not on mtimes: a copied checkout keeps neither the
+    mtimes nor any promise that native/build/ (git-ignored, so whatever
+    the last machine left) matches native/src."""
+    h = hashlib.sha256()
+    paths = [os.path.join(_NATIVE_DIR, "Makefile")]
+    for sub in ("src", "include"):
+        d = os.path.join(_NATIVE_DIR, sub)
+        paths += [os.path.join(d, f) for f in sorted(os.listdir(d))]
+    for path in paths:
+        h.update(os.path.relpath(path, _NATIVE_DIR).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()[:16]
+
+
+def _built_from(src_hash: str) -> bool:
     if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    src_dir = os.path.join(_NATIVE_DIR, "src")
-    for f in os.listdir(src_dir):
-        if os.path.getmtime(os.path.join(src_dir, f)) > lib_mtime:
-            return True
-    return False
+        return False
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() == src_hash
+    except OSError:
+        return False
+
+
+def _build(src_hash: str) -> None:
+    # -B: objects left in build/ by another machine are as untrusted as
+    # the library itself
+    subprocess.run(
+        ["make", "-s", "-B", "-j4"],
+        cwd=_NATIVE_DIR,
+        check=True,
+        capture_output=True,
+        timeout=300,
+    )
+    with open(_STAMP_PATH, "w") as f:
+        f.write(src_hash + "\n")
 
 
 def _declare(lib: ctypes.CDLL):
@@ -99,41 +138,48 @@ def _declare(lib: ctypes.CDLL):
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None when unavailable."""
-    global _lib, _lib_failed
+    """Load (building if needed) the native library; None when
+    unavailable, and every caller then takes its pure-Python path. Says
+    once on stderr which of the two serves this process."""
+    global _lib, _lib_failed, _status
     if _lib is not None or _lib_failed:
         return _lib
-    if os.environ.get("FFTPU_NO_NATIVE"):
-        _lib_failed = True
-        return None
     with _lib_lock:
         if _lib is not None or _lib_failed:
             return _lib
         try:
+            if os.environ.get("FFTPU_NO_NATIVE"):
+                raise RuntimeError("FFTPU_NO_NATIVE is set")
             if os.path.exists(_PKG_LIB_PATH):
                 lib = ctypes.CDLL(_PKG_LIB_PATH)
+                origin = "packaged with the wheel"
             else:
-                if _sources_newer_than_lib():
-                    import sys
-
-                    print(
-                        "[flexflow_tpu] building native core (libffnative.so)…",
-                        file=sys.stderr,
-                        flush=True,
-                    )
-                    subprocess.run(
-                        ["make", "-s", "-j4"],
-                        cwd=_NATIVE_DIR,
-                        check=True,
-                        capture_output=True,
-                        timeout=300,
-                    )
+                src_hash = _source_hash()
+                if _built_from(src_hash):
+                    origin = f"build of sources {src_hash} reused"
+                else:
+                    _build(src_hash)
+                    origin = f"built from sources {src_hash}"
                 lib = ctypes.CDLL(_LIB_PATH)
             _declare(lib)
             _lib = lib
-        except Exception:
+            _status = f"native (libffnative.so, {origin})"
+        except (OSError, RuntimeError, subprocess.SubprocessError) as e:
             _lib_failed = True
+            detail = getattr(e, "stderr", None) or e
+            if isinstance(detail, bytes):
+                detail = detail.decode(errors="replace")
+            _status = f"python (native core unavailable: {detail})"
+        print(f"[flexflow_tpu] search core: {_status}", file=sys.stderr)
     return _lib
+
+
+def implementation() -> str:
+    """Which implementation serves the graph algorithms, the simulator,
+    the Unity DP and the data loader in this process: "native (...)" or
+    "python (...)" with the reason."""
+    get_lib()
+    return _status
 
 
 def _as_i32(a) -> np.ndarray:

@@ -171,7 +171,38 @@ def lora_delta_out(attn, tbl, a_o, b_o):
     return jnp.einsum("bspr,bpre->bse", u, b_o[tbl], **mm)
 
 
-def _decode_pallas_hook(q, k_cache, v_cache, lengths, kernel="auto"):
+def _kernel_call(entry, head_shard, args, head_dims):
+    """Call a decode-kernel entry point (pallas/decode_kernel.py).
+
+    A Mosaic call has no GSPMD partitioning rule: inside plain jit with
+    sharded operands JAX refuses it ("Mosaic kernels cannot be
+    automatically partitioned"). `head_shard` = (mesh, axis) — from
+    ServingPlacement.kernel_head_shard() — runs the kernel per head
+    shard under shard_map instead: `head_dims[i]` is the heads dim of
+    `args[i]` (None = replicated: lengths, block tables, masks), the
+    [b, w, h, d] result is sharded on heads, and since attention never
+    mixes heads there is no collective. None calls the kernel directly
+    (one device)."""
+    if head_shard is None:
+        return entry(*args)
+    from jax.sharding import PartitionSpec as P
+
+    mesh, axis = head_shard
+
+    def spec(ndim, head_dim):
+        return P(*[axis if i == head_dim else None for i in range(ndim)])
+
+    return jax.shard_map(
+        entry,
+        mesh=mesh,
+        in_specs=tuple(spec(a.ndim, hd) for a, hd in zip(args, head_dims)),
+        out_specs=spec(4, 2),
+        check_vma=False,
+    )(*args)
+
+
+def _decode_pallas_hook(q, k_cache, v_cache, lengths, kernel="auto",
+                        head_shard=None):
     """Seam for the hand-tiled TPU decode kernel (single-query flash
     against the cache — pallas/decode_kernel.py, the serving analog of
     flash_kernel.py for training). `kernel` is the ServeConfig
@@ -184,10 +215,14 @@ def _decode_pallas_hook(q, k_cache, v_cache, lengths, kernel="auto"):
 
     if not dk.use_kernel(kernel, q.shape[1], k_cache.shape[1], q.shape[-1]):
         return None
-    return dk.flash_decode(q, k_cache, v_cache, lengths)
+    return _kernel_call(
+        dk.flash_decode, head_shard, (q, k_cache, v_cache, lengths),
+        (2, 2, 2, None),
+    )
 
 
-def decode_attention(q, k_cache, v_cache, lengths, kernel="auto"):
+def decode_attention(q, k_cache, v_cache, lengths, kernel="auto",
+                     head_shard=None):
     """Serving decode regime: one-query attention against a preallocated
     KV cache. q: [b, 1, h, d]; k_cache/v_cache: [b, max_len, h, d];
     lengths: [b] int32, the cache position the current token was written
@@ -198,7 +233,9 @@ def decode_attention(q, k_cache, v_cache, lengths, kernel="auto"):
     fp32 score accumulation like scaled_dot_product_attention; the mask
     uses the same -1e30 fill so decode softmax numerics line up with the
     causal prefill path."""
-    out = _decode_pallas_hook(q, k_cache, v_cache, lengths, kernel)
+    out = _decode_pallas_hook(
+        q, k_cache, v_cache, lengths, kernel, head_shard
+    )
     if out is not None:
         return out
     d = q.shape[-1]
@@ -266,7 +303,7 @@ def tree_allowed_mask(tree_parents, lengths, w, klen):
 
 
 def _verify_pallas_hook(q, k_cache, v_cache, lengths, kernel="auto",
-                        allowed=None):
+                        allowed=None, head_shard=None):
     """Seam for the hand-tiled TPU verify kernel (w-query flash against
     the cache — the speculative-decoding scoring pass; decode is its
     w == 1 case, so pallas/decode_kernel.py serves both with one body).
@@ -282,14 +319,19 @@ def _verify_pallas_hook(q, k_cache, v_cache, lengths, kernel="auto",
     if allowed is not None:
         if not dk.supports_tree(q.shape[1]):
             return None
-        return dk.flash_verify_tree(
-            q, k_cache, v_cache, lengths, allowed.astype(jnp.float32)
+        return _kernel_call(
+            dk.flash_verify_tree, head_shard,
+            (q, k_cache, v_cache, lengths, allowed),
+            (2, 2, 2, None, None),
         )
-    return dk.flash_verify(q, k_cache, v_cache, lengths)
+    return _kernel_call(
+        dk.flash_verify, head_shard, (q, k_cache, v_cache, lengths),
+        (2, 2, 2, None),
+    )
 
 
 def verify_attention(q, k_cache, v_cache, lengths, kernel="auto",
-                     tree_parents=None):
+                     tree_parents=None, head_shard=None):
     """Speculative-decoding verify regime: w query positions per sequence
     (the last emitted token plus the drafted continuation) attend
     against the cache in ONE call. q: [b, w, h, d]; k_cache/v_cache:
@@ -316,7 +358,8 @@ def verify_attention(q, k_cache, v_cache, lengths, kernel="auto",
             tree_parents, lengths, q.shape[1], k_cache.shape[1]
         )
     out = _verify_pallas_hook(
-        q, k_cache, v_cache, lengths, kernel, allowed=allowed_tree
+        q, k_cache, v_cache, lengths, kernel, allowed=allowed_tree,
+        head_shard=head_shard,
     )
     if out is not None:
         return out
@@ -352,7 +395,7 @@ def _dequant_pages(pool, tbl, scale, b, heads, d):
 
 def _paged_verify_pallas_hook(q, k_pool, v_pool, block_tables, lengths,
                               kernel="auto", k_scale=None, v_scale=None,
-                              allowed=None):
+                              allowed=None, head_shard=None):
     """Seam for the hand-tiled TPU paged-verify kernel (w-query flash
     walking the block table page by page — the fourth member of the
     pallas/decode_kernel.py family, completing the seam symmetry:
@@ -375,25 +418,34 @@ def _paged_verify_pallas_hook(q, k_pool, v_pool, block_tables, lengths,
     if allowed is not None:
         if not dk.supports_tree(q.shape[1]):
             return None
-        mask = allowed.astype(jnp.float32)
         if quant:
-            return dk.paged_flash_verify_tree_quant(
-                q, k_pool, v_pool, k_scale, v_scale, block_tables,
-                lengths, mask,
+            return _kernel_call(
+                dk.paged_flash_verify_tree_quant, head_shard,
+                (q, k_pool, v_pool, k_scale, v_scale, block_tables,
+                 lengths, allowed),
+                (2, 2, 2, 1, 1, None, None, None),
             )
-        return dk.paged_flash_verify_tree(
-            q, k_pool, v_pool, block_tables, lengths, mask
+        return _kernel_call(
+            dk.paged_flash_verify_tree, head_shard,
+            (q, k_pool, v_pool, block_tables, lengths, allowed),
+            (2, 2, 2, None, None, None),
         )
     if quant:
-        return dk.paged_flash_verify_quant(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths
+        return _kernel_call(
+            dk.paged_flash_verify_quant, head_shard,
+            (q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths),
+            (2, 2, 2, 1, 1, None, None),
         )
-    return dk.paged_flash_verify(q, k_pool, v_pool, block_tables, lengths)
+    return _kernel_call(
+        dk.paged_flash_verify, head_shard,
+        (q, k_pool, v_pool, block_tables, lengths),
+        (2, 2, 2, None, None),
+    )
 
 
 def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths,
                            kernel="auto", k_scale=None, v_scale=None,
-                           tree_parents=None):
+                           tree_parents=None, head_shard=None):
     """Verify attention against the block-paged cache. The dense path
     gathers each sequence's pages into a contiguous view (same
     dense-gather strategy as paged_decode_attention, same sentinel
@@ -414,6 +466,7 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths,
     out = _paged_verify_pallas_hook(
         q, k_pool, v_pool, block_tables, lengths, kernel,
         k_scale=k_scale, v_scale=v_scale, allowed=allowed_tree,
+        head_shard=head_shard,
     )
     if out is not None:
         return out
@@ -428,11 +481,16 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths,
     else:
         k = k_pool[tbl].reshape(b, -1, heads, d)
         v = v_pool[tbl].reshape(b, -1, heads, d)
-    return verify_attention(q, k, v, lengths, tree_parents=tree_parents)
+    # the gather is this path's choice: the contiguous kernel must not
+    # take the gathered view on its own "auto"
+    return verify_attention(
+        q, k, v, lengths, kernel="dense", tree_parents=tree_parents
+    )
 
 
 def _paged_decode_pallas_hook(q, k_pool, v_pool, block_tables, lengths,
-                              kernel="auto", k_scale=None, v_scale=None):
+                              kernel="auto", k_scale=None, v_scale=None,
+                              head_shard=None):
     """Seam for the hand-tiled TPU paged-decode kernel (single-query
     flash that walks the block table page by page instead of gathering
     the pages into a contiguous [b, max_len] view first — the
@@ -451,14 +509,21 @@ def _paged_decode_pallas_hook(q, k_pool, v_pool, block_tables, lengths,
     ):
         return None
     if quant:
-        return dk.paged_flash_decode_quant(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths
+        return _kernel_call(
+            dk.paged_flash_decode_quant, head_shard,
+            (q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths),
+            (2, 2, 2, 1, 1, None, None),
         )
-    return dk.paged_flash_decode(q, k_pool, v_pool, block_tables, lengths)
+    return _kernel_call(
+        dk.paged_flash_decode, head_shard,
+        (q, k_pool, v_pool, block_tables, lengths),
+        (2, 2, 2, None, None),
+    )
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           kernel="auto", k_scale=None, v_scale=None):
+                           kernel="auto", k_scale=None, v_scale=None,
+                           head_shard=None):
     """Serving decode against a block-paged KV cache. q: [b, 1, h, d];
     k_pool/v_pool: [num_pages, page_size, h, d]; block_tables:
     [b, max_pages_per_seq] int32 page ids (sentinel num_pages for
@@ -476,7 +541,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     gathered pages in place."""
     out = _paged_decode_pallas_hook(
         q, k_pool, v_pool, block_tables, lengths, kernel,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, head_shard=head_shard,
     )
     if out is not None:
         return out
@@ -493,7 +558,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     else:
         k = k_pool[tbl].reshape(b, -1, heads, d)
         v = v_pool[tbl].reshape(b, -1, heads, d)
-    return decode_attention(q, k, v, lengths)
+    # the gather is this path's choice: the contiguous kernel must not
+    # take the gathered view on its own "auto"
+    return decode_attention(q, k, v, lengths, kernel="dense")
 
 
 def _q_mesh_axes(ctx):
@@ -676,10 +743,6 @@ def _tiled_flash_sharded(q, k, v, ctx, causal, specs):
     the layout (e.g. Ulysses' seq→head all-to-all is exactly the
     reshard this wrapper's in_specs induce). Returns None when the
     per-device block doesn't tile."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
     from flexflow_tpu.ops.pallas.flash_kernel import (
         flash_attention_tpu,
         supports,
@@ -715,11 +778,14 @@ def _tiled_flash_sharded(q, k, v, ctx, causal, specs):
     from jax.sharding import PartitionSpec as P
 
     spec = P(*specs)
-    fn = shard_map(
+    # check_vma off: a pallas_call's out_shape carries no varying-axes
+    # annotation, and the checker refuses it
+    fn = jax.shard_map(
         lambda a, b, c: flash_attention_tpu(a, b, c, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)
 

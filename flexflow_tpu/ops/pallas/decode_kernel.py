@@ -9,14 +9,14 @@ for gathering every page into a contiguous cache view first. This
 module is the kernel family that fills the Pallas hook seams there,
 Flash-Decoding style (Dao et al., 2023):
 
-  * **Split-KV online softmax** — grid (batch, heads, kv_chunks) with
-    the KV-chunk dim innermost ("arbitrary", i.e. sequential): each
-    chunk folds an MXU `q @ k^T` score tile into running
-    max / sum-exp / weighted-V accumulators held in VMEM scratch, and
-    the output tile is written once on the last chunk. No score tensor
-    ever exists in HBM — the same trade flash_kernel.py makes for
-    training, restricted to the w-query forward (no backward: serving
-    never differentiates through the cache).
+  * **Split-KV online softmax** — the KV-chunk grid dim is innermost
+    ("arbitrary", i.e. sequential): each chunk folds an MXU `q @ k^T`
+    score tile into running max / sum-exp / weighted-V accumulators
+    held in VMEM scratch, and the output tile is written once on the
+    last chunk. No score tensor ever exists in HBM — the same trade
+    flash_kernel.py makes for training, restricted to the w-query
+    forward (no backward: serving never differentiates through the
+    cache).
   * **Length gating per chunk** — `lengths` rides in as a
     scalar-prefetch argument, so whole chunks past
     `lengths[i] + w - 1` are skipped (pl.when) and their DMAs
@@ -28,14 +28,32 @@ Flash-Decoding style (Dao et al., 2023):
     staircase degenerates to decode_attention's `key_pos <= lengths[i]`
     mask. Sharing the body is what keeps greedy speculative decoding
     token-identical to plain decode on the kernel path.
-  * **The paged variant walks the block table** — grid
-    (batch, heads, pages): the K/V BlockSpec index maps read the
-    scalar-prefetched block table to DMA each logical page straight
-    from the pool (PagedAttention, Kwon et al., SOSP'23), so the
-    per-step contiguous gather the dense paged path pays disappears.
-    Sentinel entries (num_pages) are clamped for the DMA and masked in
-    the score tile, so unallocated pages are numerically inert exactly
-    like the dense path's clamp-and-mask.
+  * **The paged variant walks the block table** — grid (batch, pages):
+    the K/V BlockSpec index maps read the scalar-prefetched block table
+    to DMA each logical page straight from the pool (PagedAttention,
+    Kwon et al., SOSP'23), so the per-step contiguous gather the dense
+    paged path pays disappears. Sentinel entries (num_pages) are
+    clamped for the DMA and masked in the score tile, so unallocated
+    pages are numerically inert exactly like the dense path's
+    clamp-and-mask.
+
+Block shapes and the TPU tiling rule. Mosaic takes a block only when its
+last two dims are multiples of the (sublane, lane) tile — (8, 128) for
+f32, (32, 128) for int8 — or span the whole array dim. The pool layout
+[num_pages, page_size, h, d] is shared with KVCacheSpec, the serving
+placement, swap and the journal, so the kernels do not change it; they
+change how they LOOK at it:
+
+  * a page is one block with ALL its heads: the pool is viewed as
+    [num_pages, page_size, h*d] (a free row-major reshape) and the
+    block is (1, page_size, h*d) — rows are the sublane dim, the whole
+    h*d row the lane dim. One program handles every head of a page
+    (a static loop over lane slices), so a page is one contiguous DMA
+    instead of h strided ones and the grid has h times fewer steps;
+  * the int8 scale pools [num_pages, h] are viewed as
+    [num_pages, 1, h], block (1, 1, h);
+  * the tree mask [b, w, kv] is regrouped to [b, chunks, w, chunk],
+    block (1, 1, w, chunk), for both layouts.
 
 Tile size: the contiguous kernel's KV chunk defaults to the
 v5e-calibrated 512 rows (calibration/v5e.json "decode_blocks", installed
@@ -44,10 +62,11 @@ largest sublane-aligned divisor of max_len; the paged kernel's chunk is
 one page (the block table gives no contiguity beyond a page).
 
 `supports()` gates geometry (callers fall back to the dense paths), and
-`interpret=None` auto-selects the Pallas interpreter off-TPU so the
-exact kernel code path runs under JAX_PLATFORMS=cpu — tier-1 tests
-(tests/test_decode_kernel.py) assert parity against the dense paths
-there.
+`interpret=None` selects the Pallas interpreter off-TPU
+(ops.pallas.resolve_interpret) so the exact kernel code path runs under
+JAX_PLATFORMS=cpu — tier-1 tests (tests/test_decode_kernel.py) assert
+parity against the dense paths there, and tests/test_pallas_lowering.py
+lowers every entry point for TPU from the CPU sandbox.
 
 Shapes at the API boundary match ops/attention.py: q [b, w, h, d],
 contiguous cache [b, max_len, h, d], paged pools
@@ -78,6 +97,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from flexflow_tpu.ops.pallas import compiler_params as _compiler_params
+from flexflow_tpu.ops.pallas import mxu_dot, resolve_interpret
 
 LANES = 128
 SUBLANES = 8
@@ -204,6 +224,12 @@ def _stair_mask(s, cfg, length, k_start):
     return jnp.where(kpos <= length + qoff, s, _MASK)
 
 
+def _init_scratch(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, _MASK)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
 def _online_softmax_step(s, v, m_scr, l_scr, acc_scr):
     """Fold one masked score tile (w, bk) and its V chunk (bk, d) into
     the running (m, l, acc) accumulators — the flash_kernel.py forward
@@ -214,53 +240,132 @@ def _online_softmax_step(s, v, m_scr, l_scr, acc_scr):
     p = jnp.exp(s - m_new)  # masked entries: exp(~-1e30) == 0
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc_scr[...] = acc_scr[...] * corr + mxu_dot(p.astype(v.dtype), v, (1, 0))
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
 
-def _finish(o_ref, l_scr, acc_scr):
+def _finish(l_scr, acc_scr, dtype):
     # position 0 is visible to every query row (lengths >= 0), so l > 0
     # for live rows; the max guards the padded scratch lanes
     l = jnp.maximum(l_scr[:, :1], 1e-30)
-    o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    return (acc_scr[...] / l).astype(dtype)
+
+
+def _chunked_mask(allowed, chunk: int):
+    """[b, w, kv] tree visibility -> [b, kv/chunk, w, chunk] f32, so a
+    (w, chunk) mask tile spans the last two dims whole (a legal TPU
+    block at any chunk width, where a (w, chunk) window of [b, w, kv]
+    needs chunk % 128 == 0)."""
+    b, w, kv = allowed.shape
+    return (
+        allowed.astype(jnp.float32)
+        .reshape(b, w, kv // chunk, chunk)
+        .transpose(0, 2, 1, 3)
+    )
 
 
 # -- contiguous cache ---------------------------------------------------------
 
 
-def _decode_kernel(
-    len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, cfg, nk
-):
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, *rest, cfg, nk, tree):
+    """Staircase (tree=False) or tree-masked (tree=True: one extra
+    (1, 1, w, bk) mask tile per chunk) split-KV body."""
+    mask_ref = rest[0] if tree else None
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     ib = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _MASK)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_scratch(m_scr, l_scr, acc_scr)
 
     length = len_ref[ib]
 
     # chunk visible iff it holds at least one key some query row sees
+    # (every tree row lives inside the w-row window at positions
+    # lengths..lengths + w - 1, so the gate serves both masks)
     @pl.when(ik * cfg.block_k <= length + (cfg.w - 1))
     def _body():
         q = q_ref[0, 0]  # (w, d)
         k = k_ref[0, 0]  # (bk, d)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (w, bk) f32
-        s = _stair_mask(s, cfg, length, ik * cfg.block_k)
+        s = mxu_dot(q, k, (1, 1)) * cfg.sm_scale  # (w, bk) f32
+        if tree:
+            s = jnp.where(mask_ref[0, 0] > 0.0, s, _MASK)
+        else:
+            s = _stair_mask(s, cfg, length, ik * cfg.block_k)
         _online_softmax_step(s, v_ref[0, 0], m_scr, l_scr, acc_scr)
 
     @pl.when(ik == nk - 1)
     def _done():
-        _finish(o_ref, l_scr, acc_scr)
+        o_ref[0, 0] = _finish(l_scr, acc_scr, o_ref.dtype)
+
+
+def _contiguous_call(
+    q, k_cache, v_cache, lengths, allowed, sm_scale, block_k, interpret
+):
+    b, w, h, d = q.shape
+    kv_len = k_cache.shape[1]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    bk = block_k or _pick_chunk(kv_len)
+    if bk is None or kv_len % bk:
+        raise ValueError(
+            f"flash decode: cache length {kv_len} not tileable "
+            f"(chunk {bk}); use supports() and fall back to dense"
+        )
+    cfg = _Cfg(w, sm_scale, bk, resolve_interpret(interpret))
+    nk = kv_len // bk
+    tree = allowed is not None
+    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
+    kt = k_cache.transpose(0, 2, 1, 3)
+    vt = v_cache.transpose(0, 2, 1, 3)
+
+    def q_map(ib, ih, ik, lens):
+        return (ib, ih, 0, 0)
+
+    def visible(ib, ik, lens):
+        # skipped (past-length) chunk: redirect the DMA to chunk 0,
+        # which the next (ib, ih) program always needs
+        return lax.select(ik * bk <= lens[ib] + (w - 1), ik, 0)
+
+    def kv_map(ib, ih, ik, lens):
+        return (ib, ih, visible(ib, ik, lens), 0)
+
+    def mask_map(ib, ih, ik, lens):
+        # the mask tile follows K's chunk redirect so a skipped chunk's
+        # DMA still lands on resident rows
+        return (ib, visible(ib, ik, lens), 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, 1, w, d), q_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+        pl.BlockSpec((1, 1, bk, d), kv_map),
+    ]
+    operands = [lengths.astype(jnp.int32), qt, kt, vt]
+    if tree:
+        in_specs.append(pl.BlockSpec((1, 1, w, bk), mask_map))
+        operands.append(_chunked_mask(allowed, bk))
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, cfg=cfg, nk=nk, tree=tree),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((w, LANES), jnp.float32),
+                pltpu.VMEM((w, LANES), jnp.float32),
+                pltpu.VMEM((w, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary")
+        ),
+        interpret=cfg.interpret,
+    )(*operands)
+    return out.transpose(0, 2, 1, 3)
 
 
 def flash_verify(
@@ -277,57 +382,9 @@ def flash_verify(
     split-KV kernel. q: [b, w, h, d]; k_cache/v_cache:
     [b, max_len, h, d]; lengths: [b] int32. Returns [b, w, h, d].
     interpret=None auto-selects the Pallas interpreter off-TPU."""
-    b, w, h, d = q.shape
-    kv_len = k_cache.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    bk = block_k or _pick_chunk(kv_len)
-    if bk is None or kv_len % bk:
-        raise ValueError(
-            f"flash decode: cache length {kv_len} not tileable "
-            f"(chunk {bk}); use supports() and fall back to dense"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(w, sm_scale, bk, interpret)
-    nk = kv_len // bk
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
-    kt = k_cache.transpose(0, 2, 1, 3)
-    vt = v_cache.transpose(0, 2, 1, 3)
-
-    def q_map(ib, ih, ik, lens):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, ik, lens):
-        # skipped (past-length) chunk: redirect the DMA to chunk 0,
-        # which the next (ib, ih) program always needs
-        ik = lax.select(ik * bk <= lens[ib] + (w - 1), ik, 0)
-        return (ib, ih, ik, 0)
-
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, cfg=cfg, nk=nk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h, nk),
-            in_specs=[
-                pl.BlockSpec((1, 1, w, d), q_map),
-                pl.BlockSpec((1, 1, bk, d), kv_map),
-                pl.BlockSpec((1, 1, bk, d), kv_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    return _contiguous_call(
+        q, k_cache, v_cache, lengths, None, sm_scale, block_k, interpret
+    )
 
 
 def flash_decode(q, k_cache, v_cache, lengths, **kw):
@@ -336,21 +393,53 @@ def flash_decode(q, k_cache, v_cache, lengths, **kw):
     return flash_verify(q, k_cache, v_cache, lengths, **kw)
 
 
+def flash_verify_tree(
+    q,
+    k_cache,
+    v_cache,
+    lengths,
+    allowed,
+    sm_scale: Optional[float] = None,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+):
+    """w-query flash attention against the contiguous cache under an
+    arbitrary tree-ancestor mask — ops/attention.verify_attention's
+    tree_parents semantics on the split-KV kernel. allowed:
+    [b, w, max_len], > 0 where query row j may see the key position
+    (tree_allowed_mask over the dispatch's parent table): the tree SHAPE
+    is data, so one compiled program serves every tree of width w.
+    Other shapes as flash_verify. Gate with supports() AND
+    supports_tree() before calling."""
+    return _contiguous_call(
+        q, k_cache, v_cache, lengths, allowed, sm_scale, block_k, interpret
+    )
+
+
 # -- block-paged cache --------------------------------------------------------
 
 
 def _paged_kernel(
-    len_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, cfg, num_pages, page_size, np_seq,
+    len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
+    cfg, num_pages, np_seq, heads, head_dim, quant, tree,
 ):
+    """One page, all heads. k_ref/v_ref: (1, page_size, h*d) — head ih
+    is lanes [ih*d, (ih+1)*d). quant adds the (1, 1, h) per-(page,
+    head) scale tiles and dequantizes each head's slice INSIDE the
+    chunk loop, so no dequantized cache view ever exists outside VMEM;
+    tree swaps the staircase for a (1, 1, w, page_size) mask tile.
+    Scratch is per head: m/l (h, w, LANES), acc (h, w, d)."""
+    rest = list(rest)
+    ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quant else (None, None)
+    mask_ref = rest.pop(0) if tree else None
+    o_ref, m_scr, l_scr, acc_scr = rest
+    page_size = cfg.block_k
     ib = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
 
     @pl.when(ip == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, _MASK)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_scratch(m_scr, l_scr, acc_scr)
 
     length = len_ref[ib]
 
@@ -363,18 +452,120 @@ def _paged_kernel(
         & (tbl_ref[ib, ip] < num_pages)
     )
     def _body():
-        q = q_ref[0, 0]  # (w, d)
-        k = k_ref[0, :, 0, :]  # (page_size, d)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (w, page_size)
-        s = _stair_mask(s, cfg, length, ip * page_size)
-        _online_softmax_step(s, v_ref[0, :, 0, :], m_scr, l_scr, acc_scr)
+        k_page = k_ref[0]  # (page_size, h*d)
+        v_page = v_ref[0]
+        for ih in range(heads):
+            lanes = slice(ih * head_dim, (ih + 1) * head_dim)
+            q = q_ref[0, ih]  # (w, d)
+            k = k_page[:, lanes]  # (page_size, d)
+            v = v_page[:, lanes]
+            if quant:
+                q = q.astype(jnp.float32)
+                k = k.astype(jnp.float32) * ks_ref[0, :, ih:ih + 1]
+                v = v.astype(jnp.float32) * vs_ref[0, :, ih:ih + 1]
+            s = mxu_dot(q, k, (1, 1)) * cfg.sm_scale  # (w, page_size)
+            if tree:
+                s = jnp.where(mask_ref[0, 0] > 0.0, s, _MASK)
+            else:
+                s = _stair_mask(s, cfg, length, ip * page_size)
+            _online_softmax_step(
+                s, v, m_scr.at[ih], l_scr.at[ih], acc_scr.at[ih]
+            )
 
     @pl.when(ip == np_seq - 1)
     def _done():
-        _finish(o_ref, l_scr, acc_scr)
+        for ih in range(heads):
+            o_ref[0, ih] = _finish(
+                l_scr.at[ih], acc_scr.at[ih], o_ref.dtype
+            )
+
+
+def _paged_call(
+    q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, allowed,
+    sm_scale, interpret,
+):
+    b, w, h, d = q.shape
+    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    np_seq = block_tables.shape[1]
+    quant = k_scale is not None
+    tree = allowed is not None
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
+    align = _INT8_SUBLANES if quant else SUBLANES
+    if page_size % align:
+        raise ValueError(
+            f"paged flash decode{' (int8)' if quant else ''}: page_size "
+            f"{page_size} is not sublane-aligned ({align}); use "
+            "supports() and fall back to dense"
+        )
+    cfg = _Cfg(w, sm_scale, page_size, resolve_interpret(interpret))
+    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
+
+    def q_map(ib, ip, lens, tbl):
+        return (ib, 0, 0, 0)
+
+    def page_of(ib, ip, lens, tbl):
+        # skipped pages prefetch the sequence's first page; sentinel
+        # entries clamp to a real page (their scores are masked)
+        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
+        return jnp.minimum(tbl[ib, ip], num_pages - 1)
+
+    def kv_map(ib, ip, lens, tbl):
+        return (page_of(ib, ip, lens, tbl), 0, 0)
+
+    def mask_map(ib, ip, lens, tbl):
+        # the mask is over LOGICAL positions: its tile is just the page
+        # index — no table lookup, every logical tile is resident
+        return (ib, ip, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, h, w, d), q_map),
+        pl.BlockSpec((1, page_size, h * d), kv_map),
+        pl.BlockSpec((1, page_size, h * d), kv_map),
+    ]
+    operands = [
+        lengths.astype(jnp.int32),
+        block_tables.astype(jnp.int32),
+        qt,
+        k_pool.reshape(num_pages, page_size, h * d),
+        v_pool.reshape(num_pages, page_size, h * d),
+    ]
+    if quant:
+        in_specs += [pl.BlockSpec((1, 1, h), kv_map)] * 2
+        operands += [
+            k_scale.astype(jnp.float32).reshape(num_pages, 1, h),
+            v_scale.astype(jnp.float32).reshape(num_pages, 1, h),
+        ]
+    if tree:
+        in_specs.append(pl.BlockSpec((1, 1, w, page_size), mask_map))
+        operands.append(_chunked_mask(allowed, page_size))
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_kernel,
+            cfg=cfg,
+            num_pages=num_pages,
+            np_seq=np_seq,
+            heads=h,
+            head_dim=d,
+            quant=quant,
+            tree=tree,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, np_seq),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, h, w, d), q_map),
+            scratch_shapes=[
+                pltpu.VMEM((h, w, LANES), jnp.float32),
+                pltpu.VMEM((h, w, LANES), jnp.float32),
+                pltpu.VMEM((h, w, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
+        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        interpret=cfg.interpret,
+    )(*operands)
+    return out.transpose(0, 2, 1, 3)
 
 
 def paged_flash_verify(
@@ -399,68 +590,10 @@ def paged_flash_verify(
     slots — the engine allocates every page inside a live slot's
     lengths + w before the step, so live rows agree exactly — and dead
     rows' outputs are discarded by the scheduler either way."""
-    b, w, h, d = q.shape
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
-    np_seq = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    if page_size % SUBLANES:
-        raise ValueError(
-            f"paged flash decode: page_size {page_size} is not "
-            f"sublane-aligned ({SUBLANES}); use supports() and fall "
-            "back to dense"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(w, sm_scale, page_size, interpret)
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
-
-    def q_map(ib, ih, ip, lens, tbl):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, ip, lens, tbl):
-        # skipped pages prefetch the sequence's first page; sentinel
-        # entries clamp to a real page (their scores are masked)
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        page = jnp.minimum(tbl[ib, ip], num_pages - 1)
-        return (page, 0, ih, 0)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_kernel,
-            cfg=cfg,
-            num_pages=num_pages,
-            page_size=page_size,
-            np_seq=np_seq,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, np_seq),
-            in_specs=[
-                pl.BlockSpec((1, 1, w, d), q_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        qt,
-        k_pool,
-        v_pool,
+    return _paged_call(
+        q, k_pool, v_pool, block_tables, lengths, None, None, None,
+        sm_scale, interpret,
     )
-    return out.transpose(0, 2, 1, 3)
 
 
 def paged_flash_decode(q, k_pool, v_pool, block_tables, lengths, **kw):
@@ -468,49 +601,6 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, lengths, **kw):
     paged_flash_verify (ops/attention.paged_decode_attention's
     semantics)."""
     return paged_flash_verify(q, k_pool, v_pool, block_tables, lengths, **kw)
-
-
-# -- int8-quantized block-paged cache -----------------------------------------
-
-
-def _paged_kernel_quant(
-    len_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-    m_scr, l_scr, acc_scr, *, cfg, num_pages, page_size, np_seq,
-):
-    """_paged_kernel with fused per-page dequant: the K/V tiles arrive
-    int8 and the (1, 1) scale tiles — one fp32 scalar per (page, head),
-    DMA'd through the same table-driven index map — multiply them back
-    to fp32 INSIDE the chunk loop, so no dequantized cache view ever
-    exists outside VMEM."""
-    ib = pl.program_id(0)
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _MASK)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[ib]
-
-    @pl.when(
-        (ip * page_size <= length + (cfg.w - 1))
-        & (tbl_ref[ib, ip] < num_pages)
-    )
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)  # (w, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, 0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (w, page_size)
-        s = _stair_mask(s, cfg, length, ip * page_size)
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, 0]
-        _online_softmax_step(s, v, m_scr, l_scr, acc_scr)
-
-    @pl.when(ip == np_seq - 1)
-    def _done():
-        _finish(o_ref, l_scr, acc_scr)
 
 
 def paged_flash_verify_quant(
@@ -528,76 +618,11 @@ def paged_flash_verify_quant(
     scale side pools [num_pages, h]: dequant fuses into the page walk
     (each page's scale rides the same scalar-prefetched table lookup as
     its K/V tile). Semantics match paged_verify_attention's dense
-    dequant path bit-for-bit on the visible positions."""
-    b, w, h, d = q.shape
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
-    np_seq = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    if page_size % _INT8_SUBLANES:
-        raise ValueError(
-            f"paged flash decode (int8): page_size {page_size} is not "
-            f"int8-sublane-aligned ({_INT8_SUBLANES}); use supports() "
-            "and fall back to dense"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(w, sm_scale, page_size, interpret)
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
-
-    def q_map(ib, ih, ip, lens, tbl):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, ip, lens, tbl):
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        page = jnp.minimum(tbl[ib, ip], num_pages - 1)
-        return (page, 0, ih, 0)
-
-    def scale_map(ib, ih, ip, lens, tbl):
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        page = jnp.minimum(tbl[ib, ip], num_pages - 1)
-        return (page, ih)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_kernel_quant,
-            cfg=cfg,
-            num_pages=num_pages,
-            page_size=page_size,
-            np_seq=np_seq,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, np_seq),
-            in_specs=[
-                pl.BlockSpec((1, 1, w, d), q_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, 1), scale_map),
-                pl.BlockSpec((1, 1), scale_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        qt,
-        k_pool,
-        v_pool,
-        k_scale.astype(jnp.float32),
-        v_scale.astype(jnp.float32),
+    dequant path on the visible positions."""
+    return _paged_call(
+        q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, None,
+        sm_scale, interpret,
     )
-    return out.transpose(0, 2, 1, 3)
 
 
 def paged_flash_decode_quant(
@@ -608,159 +633,6 @@ def paged_flash_decode_quant(
     return paged_flash_verify_quant(
         q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths, **kw
     )
-
-
-# -- token-tree verify (SpecInfer ancestor mask as a data operand) ------------
-#
-# The tree variants replace the iota-computed staircase with a
-# precomputed [b, w, kv] visibility mask (ops/attention.tree_allowed_mask)
-# DMA'd chunk by chunk alongside K — the tree SHAPE is data, so one
-# compiled program serves every tree of width w and a future fused
-# draft+verify device round can rewrite the tree without recompiling.
-# Everything else (online softmax, chunk-skip gate, sentinel clamping)
-# is the staircase kernel verbatim: the chunk gate
-# `ik * bk <= length + (w - 1)` still holds because every tree row lives
-# inside the w-row window at positions lengths..lengths + w - 1.
-
-
-def _tree_kernel(
-    len_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, cfg, nk,
-):
-    ib = pl.program_id(0)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _MASK)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[ib]
-
-    @pl.when(ik * cfg.block_k <= length + (cfg.w - 1))
-    def _body():
-        q = q_ref[0, 0]  # (w, d)
-        k = k_ref[0, 0]  # (bk, d)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (w, bk) f32
-        s = jnp.where(mask_ref[0] > 0.0, s, _MASK)
-        _online_softmax_step(s, v_ref[0, 0], m_scr, l_scr, acc_scr)
-
-    @pl.when(ik == nk - 1)
-    def _done():
-        _finish(o_ref, l_scr, acc_scr)
-
-
-def flash_verify_tree(
-    q,
-    k_cache,
-    v_cache,
-    lengths,
-    allowed,
-    sm_scale: Optional[float] = None,
-    block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
-):
-    """w-query flash attention against the contiguous cache under an
-    arbitrary tree-ancestor mask — ops/attention.verify_attention's
-    tree_parents semantics on the split-KV kernel. allowed:
-    [b, w, max_len] float32, 1.0 where query row j may see the key
-    position (tree_allowed_mask over the dispatch's parent table).
-    Other shapes as flash_verify. Gate with supports() AND
-    supports_tree() before calling."""
-    b, w, h, d = q.shape
-    kv_len = k_cache.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    bk = block_k or _pick_chunk(kv_len)
-    if bk is None or kv_len % bk:
-        raise ValueError(
-            f"flash decode: cache length {kv_len} not tileable "
-            f"(chunk {bk}); use supports() and fall back to dense"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(w, sm_scale, bk, interpret)
-    nk = kv_len // bk
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
-    kt = k_cache.transpose(0, 2, 1, 3)
-    vt = v_cache.transpose(0, 2, 1, 3)
-
-    def q_map(ib, ih, ik, lens):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, ik, lens):
-        ik = lax.select(ik * bk <= lens[ib] + (w - 1), ik, 0)
-        return (ib, ih, ik, 0)
-
-    def mask_map(ib, ih, ik, lens):
-        # the mask tile follows K's chunk redirect so a skipped chunk's
-        # DMA still lands on resident rows
-        ik = lax.select(ik * bk <= lens[ib] + (w - 1), ik, 0)
-        return (ib, 0, ik)
-
-    out = pl.pallas_call(
-        functools.partial(_tree_kernel, cfg=cfg, nk=nk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h, nk),
-            in_specs=[
-                pl.BlockSpec((1, 1, w, d), q_map),
-                pl.BlockSpec((1, 1, bk, d), kv_map),
-                pl.BlockSpec((1, 1, bk, d), kv_map),
-                pl.BlockSpec((1, w, bk), mask_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), qt, kt, vt, allowed.astype(jnp.float32))
-    return out.transpose(0, 2, 1, 3)
-
-
-def _paged_tree_kernel(
-    len_ref, tbl_ref, q_ref, k_ref, v_ref, mask_ref, o_ref,
-    m_scr, l_scr, acc_scr, *, cfg, num_pages, page_size, np_seq,
-):
-    ib = pl.program_id(0)
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _MASK)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[ib]
-
-    @pl.when(
-        (ip * page_size <= length + (cfg.w - 1))
-        & (tbl_ref[ib, ip] < num_pages)
-    )
-    def _body():
-        q = q_ref[0, 0]  # (w, d)
-        k = k_ref[0, :, 0, :]  # (page_size, d)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (w, page_size)
-        s = jnp.where(mask_ref[0] > 0.0, s, _MASK)
-        _online_softmax_step(s, v_ref[0, :, 0, :], m_scr, l_scr, acc_scr)
-
-    @pl.when(ip == np_seq - 1)
-    def _done():
-        _finish(o_ref, l_scr, acc_scr)
 
 
 def paged_flash_verify_tree(
@@ -776,111 +648,11 @@ def paged_flash_verify_tree(
     """Tree-masked w-query flash attention walking the block table —
     ops/attention.paged_verify_attention's tree_parents semantics with
     no contiguous gather. allowed: [b, w, max_pages_per_seq * page_size]
-    float32 over LOGICAL positions, so its index map is just the page
-    index — no table lookup, no redirect needed (every logical tile is
-    resident). Other shapes as paged_flash_verify."""
-    b, w, h, d = q.shape
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
-    np_seq = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    if page_size % SUBLANES:
-        raise ValueError(
-            f"paged flash decode: page_size {page_size} is not "
-            f"sublane-aligned ({SUBLANES}); use supports() and fall "
-            "back to dense"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(w, sm_scale, page_size, interpret)
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
-
-    def q_map(ib, ih, ip, lens, tbl):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, ip, lens, tbl):
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        page = jnp.minimum(tbl[ib, ip], num_pages - 1)
-        return (page, 0, ih, 0)
-
-    def mask_map(ib, ih, ip, lens, tbl):
-        return (ib, 0, ip)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_tree_kernel,
-            cfg=cfg,
-            num_pages=num_pages,
-            page_size=page_size,
-            np_seq=np_seq,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, np_seq),
-            in_specs=[
-                pl.BlockSpec((1, 1, w, d), q_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, w, page_size), mask_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        qt,
-        k_pool,
-        v_pool,
-        allowed.astype(jnp.float32),
+    over LOGICAL positions. Other shapes as paged_flash_verify."""
+    return _paged_call(
+        q, k_pool, v_pool, block_tables, lengths, None, None, allowed,
+        sm_scale, interpret,
     )
-    return out.transpose(0, 2, 1, 3)
-
-
-def _paged_tree_kernel_quant(
-    len_ref, tbl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref,
-    o_ref, m_scr, l_scr, acc_scr, *, cfg, num_pages, page_size, np_seq,
-):
-    """_paged_tree_kernel with the fused per-page dequant of
-    _paged_kernel_quant — the int8 member of the tree family."""
-    ib = pl.program_id(0)
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _MASK)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[ib]
-
-    @pl.when(
-        (ip * page_size <= length + (cfg.w - 1))
-        & (tbl_ref[ib, ip] < num_pages)
-    )
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)  # (w, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, 0]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (w, page_size)
-        s = jnp.where(mask_ref[0] > 0.0, s, _MASK)
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, 0]
-        _online_softmax_step(s, v, m_scr, l_scr, acc_scr)
-
-    @pl.when(ip == np_seq - 1)
-    def _done():
-        _finish(o_ref, l_scr, acc_scr)
 
 
 def paged_flash_verify_tree_quant(
@@ -899,77 +671,7 @@ def paged_flash_verify_tree_quant(
     head) scale side pools — dequant fuses into the page walk exactly
     as in paged_flash_verify_quant, the tree mask rides as in
     paged_flash_verify_tree."""
-    b, w, h, d = q.shape
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
-    np_seq = block_tables.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    if page_size % _INT8_SUBLANES:
-        raise ValueError(
-            f"paged flash decode (int8): page_size {page_size} is not "
-            f"int8-sublane-aligned ({_INT8_SUBLANES}); use supports() "
-            "and fall back to dense"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(w, sm_scale, page_size, interpret)
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
-
-    def q_map(ib, ih, ip, lens, tbl):
-        return (ib, ih, 0, 0)
-
-    def kv_map(ib, ih, ip, lens, tbl):
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        page = jnp.minimum(tbl[ib, ip], num_pages - 1)
-        return (page, 0, ih, 0)
-
-    def scale_map(ib, ih, ip, lens, tbl):
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        page = jnp.minimum(tbl[ib, ip], num_pages - 1)
-        return (page, ih)
-
-    def mask_map(ib, ih, ip, lens, tbl):
-        return (ib, 0, ip)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _paged_tree_kernel_quant,
-            cfg=cfg,
-            num_pages=num_pages,
-            page_size=page_size,
-            np_seq=np_seq,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, h, np_seq),
-            in_specs=[
-                pl.BlockSpec((1, 1, w, d), q_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, page_size, 1, d), kv_map),
-                pl.BlockSpec((1, 1), scale_map),
-                pl.BlockSpec((1, 1), scale_map),
-                pl.BlockSpec((1, w, page_size), mask_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, w, d), q_map),
-            scratch_shapes=[
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, LANES), jnp.float32),
-                pltpu.VMEM((w, d), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        qt,
-        k_pool,
-        v_pool,
-        k_scale.astype(jnp.float32),
-        v_scale.astype(jnp.float32),
-        allowed.astype(jnp.float32),
+    return _paged_call(
+        q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale,
+        allowed, sm_scale, interpret,
     )
-    return out.transpose(0, 2, 1, 3)

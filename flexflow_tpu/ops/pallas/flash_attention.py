@@ -86,6 +86,13 @@ def _blockwise_attention(q, k, v, causal: bool, block_k: int):
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 
+def _lib_supports(sq: int, sk: int, d: int) -> bool:
+    """Shapes the library kernel takes at its default 128-row blocks:
+    both sequences in whole blocks, and a head_dim that is one lane tile
+    or a whole number of them."""
+    return sq % 128 == 0 and sk % 128 == 0 and (d <= 128 or d % 128 == 0)
+
+
 def _lib_flash(q, k, v, causal: bool):
     """The public JAX Pallas TPU flash kernel ([b, h, s, d] layout) — a
     hand-written fwd+bwd that beats the autodiff'd blockwise scan at long
@@ -139,8 +146,6 @@ def flash_attention(
             q.shape[1], k.shape[1], q.shape[-1]
         ):
             return flash_attention_tpu(q, k, v, causal=causal)
-        try:
+        if _lib_supports(q.shape[1], k.shape[1], q.shape[-1]):
             return _lib_flash(q, k, v, causal)
-        except Exception:  # noqa: BLE001 — trace-time shape/support errors
-            pass
     return _blockwise_attention(q, k, v, causal, block_k)

@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from flexflow_tpu.ops.pallas import compiler_params as _compiler_params
+from flexflow_tpu.ops.pallas import mxu_dot, resolve_interpret
 
 LANES = 128
 _MASK = -1e30  # finite mask value: keeps exp()=0 without inf-inf NaNs
@@ -143,10 +144,7 @@ def _fwd_kernel(
         q = q_ref[0, 0]  # (bq, d)
         k = k_ref[0, 0]  # (bk, d)
         v = v_ref[0, 0]  # (bk, d)
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale  # (bq, bk) f32
+        s = mxu_dot(q, k, (1, 1)) * cfg.sm_scale  # (bq, bk) f32
         s = _mask_causal(s, cfg, iq, ik)
         m_prev = m_scr[:, :1]  # (bq, 1)
         l_prev = l_scr[:, :1]
@@ -154,9 +152,8 @@ def _fwd_kernel(
         p = jnp.exp(s - m_new)  # masked entries: exp(~-1e30) == 0
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        acc_scr[...] = acc_scr[...] * corr + mxu_dot(
+            p.astype(v.dtype), v, (1, 0)
         )
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -245,21 +242,12 @@ def _dq_kernel(
         do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]  # (bq, 1)
         delta = dl_ref[0, 0][:, :1]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale
+        s = mxu_dot(q, k, (1, 1)) * cfg.sm_scale
         s = _mask_causal(s, cfg, iq, ik)
         p = jnp.exp(s - lse)  # normalized probabilities
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = mxu_dot(do, v, (1, 1))
         ds = p * (dp - delta) * cfg.sm_scale
-        dq_scr[...] += lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dq_scr[...] += mxu_dot(ds.astype(k.dtype), k, (1, 0))
 
     @pl.when(ik == nk - 1)
     def _finish():
@@ -286,26 +274,14 @@ def _dkv_kernel(
         do = do_ref[0, 0]
         lse = lse_ref[0, 0][:, :1]
         delta = dl_ref[0, 0][:, :1]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * cfg.sm_scale
+        s = mxu_dot(q, k, (1, 1)) * cfg.sm_scale
         s = _mask_causal(s, cfg, iq, ik)
         p = jnp.exp(s - lse)  # (bq, bk)
         # dv += p^T @ do  — contract the q (sublane) dim of both
-        dv_scr[...] += lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_scr[...] += mxu_dot(p.astype(do.dtype), do, (0, 0))
+        dp = mxu_dot(do, v, (1, 1))
         ds = p * (dp - delta) * cfg.sm_scale
-        dk_scr[...] += lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_scr[...] += mxu_dot(ds.astype(q.dtype), q, (0, 0))
 
     @pl.when(iq == nq - 1)
     def _finish():
@@ -484,9 +460,7 @@ def flash_attention_tpu(
             f"flash_attention_tpu: seq ({sq}, {sk}) not tileable by "
             f"({bq}, {bk}); use supports() and fall back to blockwise"
         )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    cfg = _Cfg(causal, sm_scale, bq, bk, interpret)
+    cfg = _Cfg(causal, sm_scale, bq, bk, resolve_interpret(interpret))
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from flexflow_tpu.parallel._shardmap_compat import shard_map_unchecked
-
 
 def _local_ring_attention(q, k, v, axis_name: str, n_shards: int, causal: bool):
     """Per-device body. q, k, v: local [b, s_loc, h, d] blocks."""
@@ -213,10 +211,9 @@ def ring_attention(
         _local_ring_attention_pallas if use_pallas else _local_ring_attention
     )
     spec = P(batch_axis, seq_axis, head_axis, None)
-    # replication checking off (the scan carry mixes locally-created
-    # accumulators with ring-permuted blocks) via the version-compat
-    # shim: check_vma on jax >= 0.8, check_rep before
-    inner = shard_map_unchecked(
+    # replication checking off: the scan carry mixes locally-created
+    # accumulators with ring-permuted blocks
+    inner = jax.shard_map(
         functools.partial(
             body,
             axis_name=seq_axis,
@@ -226,5 +223,6 @@ def ring_attention(
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return inner(q, k, v)

@@ -133,13 +133,15 @@ def pipeline_apply(
         lambda _: PartitionSpec(axis_name), stacked_params
     )
     x_spec = PartitionSpec(data_axis) if data_axis else PartitionSpec()
-    from flexflow_tpu.parallel._shardmap_compat import shard_map_unchecked
-
-    mapped = shard_map_unchecked(
+    # replication checking off here and in submesh.py / ring_attention.py:
+    # the inner functions use psum/all_gather/ppermute collectives the
+    # checker cannot always see through
+    mapped = jax.shard_map(
         inner,
-        mesh,
+        mesh=mesh,
         in_specs=(p_spec, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )
     return mapped(stacked_params, x)
 

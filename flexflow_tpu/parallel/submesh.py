@@ -72,7 +72,6 @@ def _run_block_mapped(mesh, axis_name, body, stacked, x):
     """Shared shard_map harness for the block axis: `body(local_leaves,
     xin)` runs with this block's parameter slices and the broadcast
     input; outputs gather to a replicated [k, ...] stack."""
-    from flexflow_tpu.parallel._shardmap_compat import shard_map_unchecked
 
     def inner(params_slices, xin):
         out = body([p[0] for p in params_slices], xin)
@@ -83,11 +82,12 @@ def _run_block_mapped(mesh, axis_name, body, stacked, x):
     specs_p = [
         PartitionSpec(axis_name, *([None] * (s.ndim - 1))) for s in stacked
     ]
-    fn = shard_map_unchecked(
+    fn = jax.shard_map(
         inner,
-        mesh,
+        mesh=mesh,
         in_specs=(tuple(specs_p), PartitionSpec()),
         out_specs=PartitionSpec(),
+        check_vma=False,
     )
     return fn(tuple(stacked), x)
 

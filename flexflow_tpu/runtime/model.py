@@ -693,8 +693,13 @@ class FFModel:
         strategy: an explicit parallel.strategy.Strategy to use instead of
         the config-driven choice (the reference's --import-strategy path).
         """
+        from flexflow_tpu.core.machine import detect_chip
         from flexflow_tpu.parallel.strategy import choose_strategy
+        from flexflow_tpu.utils.compile_cache import place_compile_cache
 
+        place_compile_cache()
+        # before the search prices anything: an unlisted TPU raises here
+        self.config.chip = self.config.chip or detect_chip()
         if any(
             n.op_type == OperatorType.PIPELINE for n in self.graph.nodes.values()
         ):
@@ -946,7 +951,9 @@ class FFModel:
         )
         self._rng, init_key = jax.random.split(self._rng)
         self.params = self.executor.init_params(init_key)
-        self.opt_state = self.optimizer.init_state(self.params)
+        self.opt_state = self.executor.commit_opt_state(
+            self.optimizer.init_state(self.params)
+        )
 
         if self.config.computation_graph_file or self.config.task_graph_file:
             # cost the artifacts with the SAME machine description the

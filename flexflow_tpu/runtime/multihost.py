@@ -35,17 +35,8 @@ def initialize(
     so idempotency is checked against the distributed client itself."""
     import jax
 
-    state = getattr(jax.distributed, "global_state", None)
-    if state is None:
-        try:
-            from jax._src import distributed as _dist
-
-            state = _dist.global_state
-        except ImportError:
-            state = None  # private module moved: fall back to catching
-            # the public initialize()'s already-initialized error below
-    if state is not None and getattr(state, "client", None) is not None:
-        return  # already initialized
+    if jax.distributed.is_initialized():
+        return
     if (
         coordinator_address is None
         and num_processes is None
@@ -57,17 +48,11 @@ def initialize(
             # single-process run without a cluster environment: fine
             return
     else:
-        try:
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-        except RuntimeError as e:
-            # keep the documented idempotency when the client state was
-            # not inspectable (future-JAX fallback above)
-            if "already" not in str(e).lower():
-                raise
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
 
 
 def is_primary() -> bool:

@@ -4,7 +4,7 @@ topology simulation.
 Rebuild of the reference's machine-model hierarchy (reference:
 src/runtime/machine_model.cc (1287 LoC), simulator.h:203-367;
 network simulation src/runtime/network.cc (586 LoC), simulator.h:372-596)
-with the comm-device taxonomy swapped from NVLink/PCIe/NIC/membus to the
+with the comm-device classes swapped from NVLink/PCIe/NIC/membus to the
 TPU stack:
 
   * **ICI** — chip↔chip torus links inside a slice (one device per torus

@@ -239,7 +239,7 @@ def estimate_graph_cost(
     # model.cu:38-74): an elementwise op downstream of an MXU op costs a
     # full activation round-trip on its own, but XLA folds it into the
     # producer's epilogue in the real compiled step. Charging it again is
-    # why ResNet over-predicted 1.8-2.3x (BASELINE.md round-2 residuals).
+    # why ResNet over-predicted 1.8-2.3x (the round-2 residuals).
     # Under cm.measure, unary elementwise ops whose sole producer is an
     # MXU head (or an op already fused into one) are costed at zero;
     # binary elementwise (residual adds: the skip read is real traffic)

@@ -146,6 +146,29 @@ class ServingPlacement:
         spec[heads_dim] = "model"
         return NamedSharding(self.mesh, PartitionSpec(*spec))
 
+    def kernel_head_shard(self):
+        """Where the Pallas decode kernels run on this mesh. A Mosaic
+        call has no GSPMD partitioning rule — under plain jit with
+        sharded operands JAX refuses it ("Mosaic kernels cannot be
+        automatically partitioned") — so a kernel step needs shard_map:
+
+        * one device: None, the kernel is called directly;
+        * heads sharded over the model axis, pages whole (data == 1):
+          (mesh, "model") — the kernel runs per head shard on that
+          shard's slice of q, pools and scales; attention never mixes
+          heads, so no collective is needed;
+        * pages sharded over the data axis (data > 1): "dense". A
+          per-shard kernel would need each slot's pages on its own data
+          shard, which the block tables do not promise (num_hosts is
+          free to differ from data), and gathering the pool onto every
+          chip each step is the silent replication this method exists
+          to rule out. The XLA gather path partitions instead."""
+        if self.dp > 1:
+            return "dense"
+        if self.tp > 1:
+            return (self.mesh, "model")
+        return None
+
     def validate_geometry(self, max_seqs: int, num_pages: int) -> None:
         """Reject cache geometries the host partition cannot split
         evenly — the runtime mirror of fxlint's FX311/FX312 doc rules."""
@@ -168,10 +191,24 @@ class ServingPlacement:
                 )
 
     def describe(self) -> str:
+        shard = self.kernel_head_shard()
+        if shard == "dense":
+            attention = (
+                "decode attention: dense XLA paths (pool pages are "
+                f"sharded over data={self.dp}; the Pallas kernels run "
+                "only where a shard holds whole sequences)"
+            )
+        elif shard is None:
+            attention = "decode attention: as ServeConfig.decode_kernel"
+        else:
+            attention = (
+                "decode attention: as ServeConfig.decode_kernel, Pallas "
+                f"kernels per head shard under shard_map(model={self.tp})"
+            )
         return (
             f"serving placement mesh(data={self.dp}, model={self.tp}) "
             f"[{self.mesh_source}], {self.num_hosts} host partition(s), "
-            f"{self.num_heads} heads"
+            f"{self.num_heads} heads; {attention}"
         )
 
     def to_doc(

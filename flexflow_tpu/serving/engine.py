@@ -84,6 +84,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import time
 from collections import OrderedDict
 from typing import Dict, Optional, Sequence, Tuple
@@ -91,6 +92,18 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from flexflow_tpu.core.types import OperatorType
+
+_log = logging.getLogger(__name__)
+
+
+class KernelCompileError(RuntimeError):
+    """A kernel-path step failed the first time its program was
+    dispatched: the kernel did not lower, compile, or complete one run.
+    Not a fault to isolate or survive — neither the engine's dense
+    fallback nor the scheduler's per-step isolation may absorb it,
+    because then a kernel that cannot run on this device looks like a
+    healthy server. Carries the compiler's message; `__cause__` is the
+    original exception."""
 
 
 def snapshot(host_state: np.ndarray):
@@ -255,6 +268,9 @@ class GenerationEngine:
         self.injector = injector
         self.kernel_fallbacks = 0
         self.kernel_fallback_error: str = ""
+        # kernel-path programs that have completed a step: only these can
+        # have a run-time fault (see _dispatch)
+        self._kernel_programs_run: set = set()
         # telemetry (flexflow_tpu.telemetry.Telemetry): None when
         # disabled — engine instrument points (prefill span, kernel
         # fallback) each cost one predicate on the disabled path
@@ -270,6 +286,24 @@ class GenerationEngine:
         # trace-time constant: each engine owns its jitted steps, so two
         # engines with different modes coexist in one process.
         self.decode_kernel = decode_kernel
+        # on a serving mesh the placement decides where a kernel may run
+        # (ServingPlacement.kernel_head_shard): per head shard under
+        # shard_map, or not at all when pages are sharded over data
+        self._head_shard = None
+        placement = getattr(model, "serving_placement", None)
+        if placement is not None:
+            shard = placement.kernel_head_shard()
+            if shard == "dense":
+                if decode_kernel == "pallas":
+                    raise ValueError(
+                        "decode_kernel='pallas' cannot run on this mesh: "
+                        + placement.describe()
+                    )
+                if decode_kernel != "dense":
+                    _log.warning("%s", placement.describe())
+                self.decode_kernel = "dense"
+            else:
+                self._head_shard = shard
         # multi-tenant LoRA (serving.tenancy.adapters.AdapterPool):
         # None keeps every traced step byte-for-byte the base engine —
         # the adapter argument is simply never passed, so no select or
@@ -396,6 +430,13 @@ class GenerationEngine:
     def chunk_cache_max(self, n: int) -> None:
         self._chunk_cache.max_entries = int(n)
 
+    @property
+    def _attn_core(self) -> dict:
+        """How every cache-attention call below runs its core: the kernel
+        mode (read at trace time — a run-time fallback flips it to dense
+        and re-traces) and where a kernel must be shard_mapped."""
+        return {"kernel": self.decode_kernel, "head_shard": self._head_shard}
+
     def _verify_fn(self, w: int):
         """The jitted verify program for draft width `w` (LRU-managed
         by the shared _JitCache)."""
@@ -440,34 +481,60 @@ class GenerationEngine:
 
     # -- kernel-failure fallback ---------------------------------------------
 
-    def _dispatch(self, site: str, call):
+    def _dispatch(self, site: str, call, program=None):
         """Run one jitted decode/verify step. On the dense paths this is
         just `call()`; on a Pallas-kernel path the outputs are forced
-        first (surfacing async compile/runtime errors BEFORE the cache
-        commits them) and ANY failure — injected through the fault seam
-        or real — permanently falls the engine back to the dense paths
-        and retries the step once. Serving survives a broken kernel at
-        the cost of the dense path's speed; the fallback is recorded in
-        `kernel_fallbacks` / `kernel_fallback_error`."""
+        first (surfacing async runtime errors BEFORE the cache commits
+        them), and a fault — injected through the chaos seam, or raised
+        at run time by a program that has already run — permanently
+        falls the engine back to the dense paths and retries the step
+        once. Serving survives a broken kernel at the cost of the dense
+        path's speed; the fallback is recorded in `kernel_fallbacks` /
+        `kernel_fallback_error` and logged.
+
+        `program` names the compiled program behind `call` (the site
+        plus its shape key; defaults to the site). The FIRST dispatch of
+        a program is where its kernel lowers and compiles, and a kernel
+        that cannot compile is not a fault to survive: it raises
+        KernelCompileError with the compiler's message, because answering
+        it with dense is how a kernel that never ran on the chip looked
+        healthy."""
         import jax
+
+        from flexflow_tpu.serving.faults import KernelFault
 
         if self.decode_kernel == "dense":
             return call()
+        program = site if program is None else program
         try:
             if self.injector is not None:
                 self.injector.maybe_kernel_fault(site)
             out = call()
             jax.block_until_ready(out)
-            return out
         except Exception as e:
+            if program not in self._kernel_programs_run and not isinstance(
+                e, KernelFault
+            ):
+                raise KernelCompileError(
+                    f"{site} step with decode_kernel="
+                    f"{self.decode_kernel!r} failed on the first dispatch "
+                    f"of program {program!r}: {e}"
+                ) from e
             self._fall_back_to_dense(e)
             return call()
+        self._kernel_programs_run.add(program)
+        return out
 
     def _fall_back_to_dense(self, error) -> None:
         import jax
 
         self.kernel_fallbacks += 1
         self.kernel_fallback_error = repr(error)
+        _log.warning(
+            "Pallas %s kernel path failed; serving continues on the dense "
+            "attention paths for the life of this engine: %r",
+            self.decode_kernel, error,
+        )
         if self.telemetry is not None:
             self.telemetry.registry.counter(
                 "serve_kernel_fallbacks_total",
@@ -906,7 +973,7 @@ class GenerationEngine:
             new_k[g] = kc
             new_v[g] = vc
             attn = decode_attention(
-                q, kc, vc, lengths, kernel=self.decode_kernel
+                q, kc, vc, lengths, **self._attn_core
             )
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
@@ -984,14 +1051,14 @@ class GenerationEngine:
                     cv[g], cvs[g], v[:, 0], dest
                 )
                 attn = paged_decode_attention(
-                    q, kc, vc, tables, lengths, kernel=self.decode_kernel,
+                    q, kc, vc, tables, lengths, **self._attn_core,
                     k_scale=new_ks[g], v_scale=new_vs[g],
                 )
             else:
                 kc = row_update(ck[g], k)
                 vc = row_update(cv[g], v)
                 attn = paged_decode_attention(
-                    q, kc, vc, tables, lengths, kernel=self.decode_kernel
+                    q, kc, vc, tables, lengths, **self._attn_core
                 )
             new_k[g] = kc
             new_v[g] = vc
@@ -1372,6 +1439,7 @@ class GenerationEngine:
             # cleared cache re-traces with the dense attention core
             return self._multistep_cache.get(key)(*step_args)
 
+        program = ("multistep", key)
         if self.paged:
             (
                 new_k,
@@ -1383,11 +1451,11 @@ class GenerationEngine:
                 toks_ks,
                 logits_ks,
                 mask_ks,
-            ) = self._dispatch("multistep", call)
+            ) = self._dispatch("multistep", call, program)
             self.cache.commit(new_k, new_v, new_ks, new_vs)
         else:
             new_k, new_v, d_lens, d_toks, toks_ks, logits_ks, mask_ks = (
-                self._dispatch("multistep", call)
+                self._dispatch("multistep", call, program)
             )
             self.cache.commit(new_k, new_v)
         act = np.asarray(active_mask, dtype=bool)
@@ -1534,7 +1602,7 @@ class GenerationEngine:
             new_k[g] = kc
             new_v[g] = vc
             attn = verify_attention(
-                q, kc, vc, lengths, kernel=self.decode_kernel
+                q, kc, vc, lengths, **self._attn_core
             )
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
@@ -1608,7 +1676,7 @@ class GenerationEngine:
                     vc,
                     tables,
                     lengths,
-                    kernel=self.decode_kernel,
+                    **self._attn_core,
                     k_scale=new_ks[g],
                     v_scale=new_vs[g],
                 )
@@ -1618,7 +1686,7 @@ class GenerationEngine:
                 new_k[g] = kc
                 new_v[g] = vc
                 attn = paged_verify_attention(
-                    q, kc, vc, tables, lengths, kernel=self.decode_kernel
+                    q, kc, vc, tables, lengths, **self._attn_core
                 )
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
@@ -1681,7 +1749,7 @@ class GenerationEngine:
                 kc,
                 vc,
                 lengths,
-                kernel=self.decode_kernel,
+                **self._attn_core,
                 tree_parents=parents,
             )
             out = mha_project_out(
@@ -1753,7 +1821,7 @@ class GenerationEngine:
                     vc,
                     tables,
                     lengths,
-                    kernel=self.decode_kernel,
+                    **self._attn_core,
                     k_scale=new_ks[g],
                     v_scale=new_vs[g],
                     tree_parents=parents,
@@ -1769,7 +1837,7 @@ class GenerationEngine:
                     vc,
                     tables,
                     lengths,
-                    kernel=self.decode_kernel,
+                    **self._attn_core,
                     tree_parents=parents,
                 )
             out = mha_project_out(
@@ -1854,13 +1922,14 @@ class GenerationEngine:
             # cleared cache re-traces with the dense attention core
             return self._verify_fn(w)(*step_args)
 
+        program = ("verify", w)
         if self.paged:
             new_k, new_v, new_ks, new_vs, logits = self._dispatch(
-                "verify", call
+                "verify", call, program
             )
             self.cache.commit(new_k, new_v, new_ks, new_vs)
         else:
-            new_k, new_v, logits = self._dispatch("verify", call)
+            new_k, new_v, logits = self._dispatch("verify", call, program)
             self.cache.commit(new_k, new_v)
         self.cache.begin_inflight()
         return InflightStep(
@@ -1972,13 +2041,14 @@ class GenerationEngine:
             # cleared cache re-traces with the dense attention core
             return self._tree_fn(w)(*step_args)
 
+        program = ("tree", w)
         if self.paged:
             new_k, new_v, new_ks, new_vs, logits = self._dispatch(
-                "verify", call
+                "verify", call, program
             )
             self.cache.commit(new_k, new_v, new_ks, new_vs)
         else:
-            new_k, new_v, logits = self._dispatch("verify", call)
+            new_k, new_v, logits = self._dispatch("verify", call, program)
             self.cache.commit(new_k, new_v)
         self.cache.begin_inflight()
         return InflightStep(
@@ -2076,7 +2146,7 @@ class GenerationEngine:
             # update above already wrote the full cache for commit
             attn = verify_attention(
                 q, kc[slot_ids], vc[slot_ids], lengths,
-                kernel=self.decode_kernel,
+                **self._attn_core,
             )
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
@@ -2166,7 +2236,7 @@ class GenerationEngine:
                     vc,
                     tables_g,
                     lengths,
-                    kernel=self.decode_kernel,
+                    **self._attn_core,
                     k_scale=new_ks[g],
                     v_scale=new_vs[g],
                 )
@@ -2176,7 +2246,7 @@ class GenerationEngine:
                 new_k[g] = kc
                 new_v[g] = vc
                 attn = paged_verify_attention(
-                    q, kc, vc, tables_g, lengths, kernel=self.decode_kernel
+                    q, kc, vc, tables_g, lengths, **self._attn_core
                 )
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
@@ -2276,13 +2346,14 @@ class GenerationEngine:
             # cleared cache re-traces with the dense attention core
             return self._chunk_fn((slot_ids.size, w))(*step_args)
 
+        program = ("chunk", slot_ids.size, w)
         if self.paged:
             new_k, new_v, new_ks, new_vs, nxt, last = self._dispatch(
-                "chunk", call
+                "chunk", call, program
             )
             self.cache.commit(new_k, new_v, new_ks, new_vs)
         else:
-            new_k, new_v, nxt, last = self._dispatch("chunk", call)
+            new_k, new_v, nxt, last = self._dispatch("chunk", call, program)
             self.cache.commit(new_k, new_v)
         # prompt rows are committed by construction — advance the
         # cursors now so the NEXT chunk step dispatches against them

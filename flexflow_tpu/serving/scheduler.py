@@ -85,6 +85,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from flexflow_tpu.serving.engine import KernelCompileError
 from flexflow_tpu.serving.kv_cache import PagePoolExhausted
 from flexflow_tpu.telemetry import MetricsRegistry
 from flexflow_tpu.telemetry.slo import percentiles as _percentiles
@@ -1589,6 +1590,8 @@ class _SchedulerBase:
                 chain=chain,
                 chain_mask=chain_mask if chain is not None else None,
             )
+        except KernelCompileError:
+            raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"decode step failed: {e!r}")
             return None
@@ -1816,6 +1819,8 @@ class _SchedulerBase:
             step = self.engine.decode_multi_dispatch(
                 self.params, tokens, active, step_limits, eos_tokens=eos
             )
+        except KernelCompileError:
+            raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"multistep decode failed: {e!r}")
             return None
@@ -1916,6 +1921,8 @@ class _SchedulerBase:
             if self.injector is not None:
                 self.injector.maybe_draft_fault()
             proposals = self.proposer.propose(draftable, k)
+        except KernelCompileError:
+            raise  # the draft engine's kernel never ran: not a fault
         except Exception:
             self.stats.draft_faults += 1
             return {}
@@ -1983,6 +1990,8 @@ class _SchedulerBase:
             step = self.engine.verify_dispatch(
                 self.params, tokens, draft_lens
             )
+        except KernelCompileError:
+            raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"verify step failed: {e!r}")
             return None
@@ -2101,6 +2110,8 @@ class _SchedulerBase:
             trees = self.proposer.propose_trees(
                 draftable, self.spec_k, self.spec_branch
             )
+        except KernelCompileError:
+            raise  # the draft engine's kernel never ran: not a fault
         except Exception:
             self.stats.draft_faults += 1
             return {}
@@ -2177,6 +2188,8 @@ class _SchedulerBase:
             step = self.engine.verify_tree_dispatch(
                 self.params, tokens, draft_lens, parents
             )
+        except KernelCompileError:
+            raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"tree verify step failed: {e!r}")
             return None
@@ -2516,6 +2529,8 @@ class _SchedulerBase:
             step = self.engine.prefill_chunk_dispatch(
                 self.params, tokens, chunk_lens
             )
+        except KernelCompileError:
+            raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"chunk step failed: {e!r}")
             return None

@@ -5,7 +5,7 @@ Usage: ab_attn_tiled.py [bs]     (default 8 — the reference headline config)
 
 Both variants compile INSIDE their patch scope (jit compiles lazily; a
 variant compiled after `finally` restores the patch silently measures the
-other lowering — the round-3 trap, BASELINE.md).
+other lowering — the round-3 trap, docs/perf_notes.md).
 """
 
 from __future__ import annotations

@@ -9,7 +9,7 @@ trap):
              activation; adopted as core_ops._lower_batchnorm.
 
 The protocol-grade magnitude of the win is the ONE number recorded in
-BASELINE.md's round-5 section (the first run of this script read
+docs/perf_notes.md (the first run of this script read
 11.71 -> 3.79 ms under a biased estimator — a contention spike in the
 A window faked a 3.1x — and the corrected interleaved A/B measured
 5.41 -> 4.36 ms, ~19%; run this script for the current chip's number
@@ -125,7 +125,7 @@ def main():
     for name in names:
         runners[name], (n1, n2) = build(bs, name)
     # the chip ramps its clock over the first ~0.25 s of a burst
-    # (BASELINE.md): discard a warm-up burst before each measurement and
+    # (docs/perf_notes.md): discard a warm-up burst before each measurement and
     # ALTERNATE the variant order across reps so any residual ramp bias
     # cancels in the mins instead of crediting whichever ran second (the
     # first two runs of this script disagreed for exactly that reason)

@@ -3,7 +3,7 @@
 Round-2 recorded bs8 21.3 ms (52-54% MFU), bs16 54.7 ms (40%), bs32
 109.7 ms (40%). Round 3 adds batch-chunked dense attention; this script
 re-measures the full train step at all three batch sizes and prints the
-implied MFU against the repo's 107 TF/s raw-matmul anchor (BASELINE.md).
+implied MFU against the 107 TF/s raw matmul measured in August.
 """
 
 from __future__ import annotations
@@ -38,7 +38,7 @@ def main():
             num_heads=HEADS, num_layers=LAYERS,
         )
         batch = model.executor.shard_batch(synthetic_batch(bs, SEQ, HIDDEN))
-        per_step = measure_train_step(model, batch, reps=6, rep_sleep_s=2.0)
+        per_step = measure_train_step(model, batch, reps=6)
         tfps = step_flops(bs) / per_step / 1e12
         rows.append(
             {

@@ -52,7 +52,7 @@ def build(bs, remat, mono_mb):
             # patched module attributes, and this function's original
             # version compiled lazily AFTER the finally restored them —
             # silently measuring the unpatched lowering twice (the bug
-            # that hid the bs8 chunking win; BASELINE.md round 3)
+            # that hid the bs8 chunking win in round 3)
             run.lower(model.params, model.opt_state).compile()
             return run
 

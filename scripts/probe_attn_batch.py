@@ -1,6 +1,6 @@
 """Probe: attention core fwd+bwd across batch sizes and kernels (real chip).
 
-Round-2 finding (BASELINE.md batch sweep): the flagship transformer drops
+Round-2 finding (the August batch sweep): the flagship transformer drops
 from 52-54% MFU at bs8 to 40% at bs16/32, and the dense-attention backward
 was named as superlinear (0.58 -> 1.58 ms/layer core from bs8 -> bs16).
 This probe isolates the attention core (post-projection q,k,v -> attn out)

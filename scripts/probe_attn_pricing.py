@@ -77,7 +77,7 @@ def actual(model, data):
     from flexflow_tpu.utils.benchmark import measure_train_step
 
     batch = model.executor.shard_batch(data)
-    return measure_train_step(model, batch, estimates=3, rep_sleep_s=1.0)
+    return measure_train_step(model, batch, estimates=3)
 
 
 def main():

@@ -47,3 +47,28 @@ def test_all_failed_reports_error():
     bad = {"cfg": {"metric": "cfg", "error": "noise floor"}}
     out = bp.aggregate([bad, bad])["cfg"]
     assert out["error"] == "no valid samples"
+
+
+def test_parent_process_stays_off_jax():
+    """The protocol's parent spawns one chip user at a time. A chip
+    belongs to one process: a parent that had touched JAX would hold it
+    and every child would fail or hang. Importing the launcher must pull
+    in neither jax nor the package."""
+    import subprocess
+    import sys
+
+    code = (
+        "import importlib.util, sys\n"
+        "spec = importlib.util.spec_from_file_location('bp', sys.argv[1])\n"
+        "mod = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(mod)\n"
+        "bad = [m for m in sys.modules if m.split('.')[0] in "
+        "('jax', 'jaxlib', 'flexflow_tpu')]\n"
+        "assert not bad, bad\n"
+    )
+    subprocess.run(
+        [sys.executable, "-c", code,
+         os.path.join(_ROOT, "scripts", "bench_protocol.py")],
+        check=True,
+        timeout=60,
+    )

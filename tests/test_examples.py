@@ -104,5 +104,5 @@ def test_full_workflow_runs(capsys):
 
 def test_longctx_transformer_runs_small():
     """The long-context example at a CPU-suite-sized sequence (the real
-    seq-8192 run needs the chip; BASELINE.md records it)."""
+    seq-8192 run needs the chip)."""
     _run_main("longctx_transformer", ["--seq", "256", "-b", "2", "-i", "1", "-e", "1"])

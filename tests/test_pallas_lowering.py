@@ -1,0 +1,297 @@
+"""Every Pallas entry point lowers — and, where the TPU compiler is
+loadable, compiles — for TPU from the CPU sandbox.
+
+Interpret-mode parity (tests/test_decode_kernel.py and friends) runs the
+kernel bodies but never the TPU lowering's block-shape rules, so a block
+the chip refuses used to pass every test here and fail (or silently fall
+back to dense) only on the chip. Two layers, both without a chip:
+
+  * `.trace(...).lower(lowering_platforms=("tpu",))` applies the Pallas
+    TPU lowering's own checks (last-two-dims tiling, supported
+    primitives);
+  * an ahead-of-time compile against a v5e topology description
+    (libtpu's compiler, no device) runs Mosaic itself: vector layouts,
+    VMEM limits, matmul tiles. Skipped when libtpu cannot describe a
+    topology here.
+
+Geometries: "smoke" is what the CPU serving tests build (2 layers,
+hidden 32, 4 heads), "flagship" the 1024-wide, 16-head, 512-token
+decoder chip_smoke.py serves."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P, SingleDeviceSharding
+
+from flexflow_tpu.ops.pallas import decode_kernel as dk
+from flexflow_tpu.ops.pallas import flash_kernel as fk
+from flexflow_tpu.ops.pallas.ring_attention import ring_attention
+
+# name: (batch, heads, head_dim, max_len, page sizes, query widths)
+GEOMETRIES = {
+    "smoke": (4, 4, 8, 32, (8, 16, 32), (1, 4)),
+    "flagship": (8, 16, 64, 512, (16, 32, 128), (1, 5, 13)),
+}
+# (batch, seq, heads, head_dim) the training path hands the tiled kernel
+FLASH_SHAPES = {"smoke": (1, 256, 2, 8), "flagship": (8, 2048, 16, 64)}
+
+
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _decode_cases(geom):
+    """(id, fn, arg shapes) for the nine decode/verify entry points."""
+    b, h, d, max_len, page_sizes, widths = GEOMETRIES[geom]
+    lens = _sds((b,), jnp.int32)
+    cache = _sds((b, max_len, h, d))
+    run = dict(interpret=False)
+    for w in widths:
+        q = _sds((b, w, h, d))
+        allowed = _sds((b, w, max_len))
+        entry = dk.flash_decode if w == 1 else dk.flash_verify
+        yield (
+            f"{geom}-{entry.__name__}-w{w}",
+            functools.partial(entry, **run),
+            (q, cache, cache, lens),
+        )
+        yield (
+            f"{geom}-flash_verify_tree-w{w}",
+            functools.partial(dk.flash_verify_tree, **run),
+            (q, cache, cache, lens, allowed),
+        )
+        for ps in page_sizes:
+            pages = b * max_len // ps
+            tables = _sds((b, max_len // ps), jnp.int32)
+            pool = _sds((pages, ps, h, d))
+            entry = dk.paged_flash_decode if w == 1 else dk.paged_flash_verify
+            yield (
+                f"{geom}-{entry.__name__}-ps{ps}-w{w}",
+                functools.partial(entry, **run),
+                (q, pool, pool, tables, lens),
+            )
+            yield (
+                f"{geom}-paged_flash_verify_tree-ps{ps}-w{w}",
+                functools.partial(dk.paged_flash_verify_tree, **run),
+                (q, pool, pool, tables, lens, allowed),
+            )
+            if not dk.supports(w, 0, d, page_size=ps, kv_dtype="int8"):
+                continue
+            pool8 = _sds((pages, ps, h, d), jnp.int8)
+            scale = _sds((pages, h))
+            entry = (
+                dk.paged_flash_decode_quant
+                if w == 1
+                else dk.paged_flash_verify_quant
+            )
+            yield (
+                f"{geom}-{entry.__name__}-ps{ps}-w{w}",
+                functools.partial(entry, **run),
+                (q, pool8, pool8, scale, scale, tables, lens),
+            )
+            yield (
+                f"{geom}-paged_flash_verify_tree_quant-ps{ps}-w{w}",
+                functools.partial(dk.paged_flash_verify_tree_quant, **run),
+                (q, pool8, pool8, scale, scale, tables, lens, allowed),
+            )
+
+
+def _flash_cases(geom):
+    """Training flash kernel: forward, forward with LSE (the ring's
+    residual), and forward+backward through the custom VJP."""
+    shape = FLASH_SHAPES[geom]
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = _sds(shape, dtype)
+        name = f"{geom}-flash-{jnp.dtype(dtype).name}"
+
+        def fwd(q, k, v):
+            return fk.flash_attention_tpu(
+                q, k, v, causal=True, interpret=False
+            )
+
+        def fwd_lse(q, k, v):
+            return fk.flash_attention_tpu(
+                q, k, v, return_lse=True, interpret=False
+            )
+
+        def loss(q, k, v):
+            return fwd(q, k, v).astype(jnp.float32).sum()
+
+        yield (f"{name}-fwd", fwd, (x, x, x))
+        yield (f"{name}-fwd-lse", fwd_lse, (x, x, x))
+        yield (f"{name}-fwd-bwd", jax.grad(loss, argnums=(0, 1, 2)), (x, x, x))
+
+
+CASES = [
+    c
+    for geom in GEOMETRIES
+    for c in (*_decode_cases(geom), *_flash_cases(geom))
+]
+
+
+def _ids(cases):
+    return [c[0] for c in cases]
+
+
+@pytest.mark.parametrize("case", CASES, ids=_ids(CASES))
+def test_lowers_for_tpu(case):
+    _, fn, shapes = case
+    jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",))
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Make the repo's own backend probes (`jax.default_backend()`, which
+    JAX itself never calls through the module attribute) answer as they
+    do on the chip, so `interpret=None` and the "on TPU" gates resolve
+    the way they will there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _ring(mesh, causal):
+    def fn(q, k, v):
+        return ring_attention(
+            q, k, v, mesh, "seq", causal=causal, use_pallas=True
+        )
+
+    return fn
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_body_lowers_for_tpu(as_tpu, causal):
+    mesh = Mesh(jax.devices()[:4], ("seq",))
+    x = _sds((2, 4 * 256, 4, 64), jnp.bfloat16)
+    jax.jit(_ring(mesh, causal)).trace(x, x, x).lower(
+        lowering_platforms=("tpu",)
+    )
+
+
+# -- Mosaic itself, ahead of time ---------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _v5e_devices():
+    """Four compile-only v5e devices (a 2x2 host), or None when libtpu
+    cannot describe a topology on this machine."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception:  # no libtpu here: nothing to compile against
+        return None
+    return tuple(topo.devices)
+
+
+def _compile_for(devices, fn, shapes, spec=None):
+    if spec is None:
+        sharding = SingleDeviceSharding(devices[0])
+    else:
+        sharding = jax.sharding.NamedSharding(*spec)
+    placed = [
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
+        for s in shapes
+    ]
+    return (
+        jax.jit(fn)
+        .trace(*placed)
+        .lower(lowering_platforms=("tpu",))
+        .compile()
+    )
+
+
+FLAGSHIP = [c for c in CASES if c[0].startswith("flagship")]
+
+
+@pytest.mark.parametrize("case", FLAGSHIP, ids=_ids(FLAGSHIP))
+def test_mosaic_compiles_flagship(case):
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    _, fn, shapes = case
+    _compile_for(devices, fn, shapes)
+
+
+def test_mosaic_compiles_ring_body(as_tpu):
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    mesh = Mesh(devices, ("seq",))
+    x = _sds((2, 4 * 256, 4, 64), jnp.bfloat16)
+    _compile_for(
+        devices, _ring(mesh, True), (x, x, x),
+        spec=(mesh, P(None, "seq", None, None)),
+    )
+
+
+# -- the kernels on a serving mesh --------------------------------------------
+
+
+def _paged_decode_on_mesh(mesh, head_shard):
+    """(fn, arg shapes) for one flagship paged decode attention with the
+    pools sharded as ServingPlacement.kv_sharding() shards them."""
+    from flexflow_tpu.ops.attention import paged_decode_attention
+
+    b, h, d, max_len, ps = 8, 16, 64, 512, 16
+
+    def placed(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=jax.sharding.NamedSharding(mesh, P(*spec))
+        )
+
+    pool = placed(
+        (b * max_len // ps, ps, h, d), jnp.float32,
+        "data", None, "model", None,
+    )
+    shapes = (
+        placed((b, 1, h, d), jnp.float32, None, None, "model", None),
+        pool,
+        pool,
+        placed((b, max_len // ps), jnp.int32),
+        placed((b,), jnp.int32),
+    )
+
+    def fn(q, k, v, tables, lens):
+        return paged_decode_attention(
+            q, k, v, tables, lens, kernel="auto", head_shard=head_shard
+        )
+
+    return fn, shapes
+
+
+def test_kernel_on_head_sharded_mesh_needs_shard_map(as_tpu):
+    """Under plain jit JAX refuses to partition a Mosaic call — it never
+    replicates the pool behind the caller's back — and the head-shard
+    wrapper is what makes the same call lower."""
+    import numpy as np
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 4), ("data", "model"))
+    fn, shapes = _paged_decode_on_mesh(mesh, None)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",))
+    fn, shapes = _paged_decode_on_mesh(mesh, (mesh, "model"))
+    jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",))
+
+
+def test_mosaic_compiles_head_sharded_kernel(as_tpu):
+    """Four v5e chips, heads over the model axis: compiles, and the
+    program moves no pool between chips."""
+    import numpy as np
+
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    mesh = Mesh(np.array(devices).reshape(1, 4), ("data", "model"))
+    fn, shapes = _paged_decode_on_mesh(mesh, (mesh, "model"))
+    compiled = (
+        jax.jit(fn)
+        .trace(*shapes)
+        .lower(lowering_platforms=("tpu",))
+        .compile()
+    )
+    hlo = compiled.as_text()
+    for collective in ("all-gather", "all-reduce", "all-to-all"):
+        assert collective not in hlo, collective

@@ -347,6 +347,63 @@ def test_kernel_fault_falls_back_to_dense_and_keeps_serving(lm, layout):
     assert sched.stats.step_faults == 0  # fallback, not a step fault
 
 
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+def test_kernel_that_cannot_compile_raises_instead_of_falling_back(
+    lm, layout, monkeypatch
+):
+    """A kernel that fails on the FIRST dispatch of its program never
+    ran: that is the compiler refusing it, not a fault to survive.
+    Neither the engine's dense fallback nor the scheduler's per-step
+    isolation may absorb it — the run fails with the compiler's message
+    (on the chip, the fallback is how a kernel Mosaic refused served
+    every request from the dense path and exited 0)."""
+    from flexflow_tpu.ops.pallas import decode_kernel as dk
+    from flexflow_tpu.serving.engine import KernelCompileError
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(dk, "_paged_call", refuse)
+    monkeypatch.setattr(dk, "_contiguous_call", refuse)
+    sched, engine, _ = build_scheduler(
+        lm,
+        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
+                    decode_kernel="pallas"),
+    )
+    with pytest.raises(KernelCompileError, match="Mosaic failed to compile"):
+        sched.run(_requests())
+    assert engine.kernel_fallbacks == 0
+    assert engine.decode_kernel == "pallas"
+
+
+def test_runtime_kernel_failure_after_a_good_step_still_falls_back(lm):
+    """The other half of the contract: once a program has run, a failure
+    of it IS a run-time fault, answered by the permanent dense fallback
+    exactly like an injected one."""
+    base = _baseline(lm, layout="paged", decode_kernel="dense")
+    sched, engine, _ = build_scheduler(
+        lm,
+        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+                    decode_kernel="pallas"),
+    )
+    good = engine._decode_jit
+    calls = {"n": 0}
+
+    def flaky(*args):
+        calls["n"] += 1
+        if calls["n"] == 3:
+            raise RuntimeError("device halted")
+        return good(*args)
+
+    engine._decode_jit = flaky
+    done = sched.run(_requests())
+    assert engine.kernel_fallbacks == 1
+    assert engine.decode_kernel == "dense"
+    assert "device halted" in engine.kernel_fallback_error
+    for r in done:
+        assert r.ok and r.generated == base[r.rid]
+
+
 def test_draft_fault_degrades_iteration_to_plain_decode(lm):
     """A faulting draft proposer costs speed, never correctness: the
     iteration runs as plain decode and the streams match the fault-free

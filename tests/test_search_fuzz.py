@@ -83,7 +83,7 @@ def test_random_graph_survives_search_and_training(seed, engine):
 def test_auto_flash_fires_at_threshold_boundary():
     """Regression: a score tensor exactly AT the 2 GiB threshold must take
     the streaming path (it used to take dense with strict >, materializing
-    the 2 GiB it exists to avoid — BASELINE.md round 2)."""
+    the 2 GiB it exists to avoid)."""
     from flexflow_tpu.ops.attention import _FLASH_SCORE_BYTES, _auto_flash
 
     # batch 1, heads 8, seq 8192: 1*8*8192*8192*4 == 2 GiB exactly
