@@ -33,11 +33,15 @@ path round differently, and an argmax over 32000 near-flat random
 logits is not a fair witness. The user-facing serve phase itself runs
 at the default precision.
 
-Exit code 0 and a last stdout line `{"ok": true, "device": {...}, ...}`
-only when every check passed. Any failed check raises: no phase is
-wrapped in a try/except that lets the run go on. Timings, compile
-seconds and peak memory are printed as observations, not metrics; the
-summary ends with `"claim": null`.
+Exit code 0 only when every check passed, and then the last two stdout
+lines are JSON: the summary (phases, timings, compile seconds and peak
+memory as observations, not metrics, ending with `"claim": null`), and
+last of all the verdict, with exactly these keys and nothing else:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Any failed check raises: no phase is wrapped in a try/except that lets
+the run go on, and no verdict is printed.
 
     python chip_smoke.py                 # everything
     python chip_smoke.py --phase serve   # one phase (repeatable), for
@@ -115,6 +119,19 @@ class Compiles:
         }
         self.seconds, self.hits, self.misses = 0.0, 0, 0
         return out
+
+
+def verdict(device: dict) -> str:
+    """The LAST stdout line of a run that passed: these keys and no
+    others (the driver refuses anything more)."""
+    return json.dumps({
+        "ok": True,
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    })
 
 
 def peak_bytes() -> list:
@@ -962,6 +979,7 @@ def main(argv) -> int:
     summary["ok"] = True
     summary["claim"] = None
     print(json.dumps(summary), flush=True)
+    print(verdict(summary["device"]), flush=True)
     return 0
 
 

@@ -133,3 +133,39 @@ def test_native_build_is_keyed_on_the_sources():
     assert native.implementation().startswith("native (")
     assert native._built_from(native._source_hash())
     assert not native._built_from("0" * 16)
+
+
+# -- chip_smoke.py's contract -------------------------------------------------
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_chip_smoke_verdict_has_exactly_the_contract_keys():
+    import importlib.util
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_REPO, "chip_smoke.py")
+    )
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    line = smoke.verdict(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "extra": 0}
+    )
+    assert "\n" not in line
+    out = json.loads(line)
+    assert list(out) == ["ok", "device"] and out["ok"] is True
+    assert out["device"] == {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+    }
+
+
+def test_chip_smoke_refuses_a_cpu_and_prints_no_result():
+    run = subprocess.run(
+        [sys.executable, os.path.join(_REPO, "chip_smoke.py")],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=300,
+    )
+    assert run.returncode == 2
+    assert run.stdout == ""
+    assert "'cpu'" in run.stderr
