@@ -36,6 +36,7 @@ from flexflow_tpu.runtime.initializer import ConstantInitializer, ZeroInitialize
 from flexflow_tpu.runtime.executor import Executor, MeshConfig, propagate_shapes
 from flexflow_tpu.runtime.metrics import PerfMetrics
 from flexflow_tpu.runtime.optimizer import Optimizer, SGDOptimizer
+from flexflow_tpu.telemetry.trace import span
 
 
 class Tensor:
@@ -1032,6 +1033,9 @@ class FFModel:
 
             tele = build_telemetry(self.config)
         self._telemetry = tele
+        # fit's phases: profiler annotations always, Chrome events too
+        # when the bundle traces (telemetry/trace.py:span)
+        tracer = getattr(tele, "tracer", None)
         train_iters = 0  # global iteration counter across epochs
         for cb in callbacks:
             # the keras frontend pre-binds its own Model wrapper; direct
@@ -1056,7 +1060,8 @@ class FFModel:
             if callbacks:
                 step = self.executor.train_step()
             perf = PerfMetrics()
-            loader.reset()
+            with span("train.epoch_end.reset", tracer):
+                loader.reset()
             t0 = time.perf_counter()
             epoch_t0 = t0
             samples = 0
@@ -1067,12 +1072,15 @@ class FFModel:
             for it in range(loader.num_batches):
                 for cb in callbacks:
                     cb.on_batch_begin(it)
-                np_batch = loader.next_batch()
-                batch = self.executor.shard_batch(np_batch)
-                self._rng, key = jax.random.split(self._rng)
-                self.params, self.opt_state, loss, mets = step(
-                    self.params, self.opt_state, batch, key
-                )
+                with span("train.input.next_batch", tracer):
+                    np_batch = loader.next_batch()
+                with span("train.input.shard_batch", tracer):
+                    batch = self.executor.shard_batch(np_batch)
+                with span("train.input.dispatch", tracer):
+                    self._rng, key = jax.random.split(self._rng)
+                    self.params, self.opt_state, loss, mets = step(
+                        self.params, self.opt_state, batch, key
+                    )
                 if tele is not None:
                     # dispatch-to-dispatch host stamps; rows/spans are
                     # built at epoch end, off the hot loop
@@ -1111,13 +1119,15 @@ class FFModel:
                         f"iter {it + 1}/{loader.num_batches}: "
                         f"loss = {float(loss):.4f}"
                     )
-            jax.block_until_ready(self.params)
+            with span("train.epoch_end.drain", tracer):
+                jax.block_until_ready(self.params)
             elapsed = time.perf_counter() - t0
             losses = []
-            for loss, mets in step_results:
-                fl = float(loss)
-                perf.update(jax.tree_util.tree_map(float, mets), fl)
-                losses.append(fl)
+            with span("train.epoch_end.losses", tracer):
+                for loss, mets in step_results:
+                    fl = float(loss)
+                    perf.update(jax.tree_util.tree_map(float, mets), fl)
+                    losses.append(fl)
             self._perf_metrics = perf
             thpt = samples / elapsed if elapsed > 0 else 0.0
             if tele is not None:

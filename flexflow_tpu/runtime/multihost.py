@@ -20,6 +20,8 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 
+from flexflow_tpu.telemetry.trace import span
+
 
 def initialize(
     coordinator_address: Optional[str] = None,
@@ -129,11 +131,12 @@ def place_batch(
     shapes = executor.input_shapes()
     out = {}
     for name, arr in batch.items():
-        if name in shapes:
-            sharding = executor.sharding_for(shapes[name])
-            out[name] = place_array(arr, sharding, multi=multi)
-        else:
-            out[name] = place_array(arr)
+        with span(f"train.input.shard_batch.{name}"):
+            if name in shapes:
+                sharding = executor.sharding_for(shapes[name])
+                out[name] = place_array(arr, sharding, multi=multi)
+            else:
+                out[name] = place_array(arr)
     return out
 
 

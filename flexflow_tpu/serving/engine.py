@@ -92,6 +92,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 from flexflow_tpu.core.types import OperatorType
+from flexflow_tpu.telemetry.trace import span
 
 _log = logging.getLogger(__name__)
 
@@ -272,13 +273,23 @@ class GenerationEngine:
         # have a run-time fault (see _dispatch)
         self._kernel_programs_run: set = set()
         # telemetry (flexflow_tpu.telemetry.Telemetry): None when
-        # disabled — engine instrument points (prefill span, kernel
-        # fallback) each cost one predicate on the disabled path
+        # disabled. `_tracer` is what every `span` below is handed: the
+        # bundle's Chrome tracer, or None (the span is then only the
+        # profiler's annotation)
         self.telemetry = (
             telemetry
             if telemetry is not None and getattr(telemetry, "enabled", False)
             else None
         )
+        self._tracer = getattr(self.telemetry, "tracer", None)
+        # counted inside the span that does the work, mirrored into
+        # SchedulerStats at each iteration's end: blocking reads of a
+        # device value and the bytes they brought to the host; prompt
+        # tokens prefilled and the [max_seqs, bucket] tokens they ran as
+        self.device_syncs = 0
+        self.readback_bytes = 0
+        self.prefill_tokens_real = 0
+        self.prefill_tokens_padded = 0
         # how the decode/verify attention core runs (threaded into every
         # ops.attention call below): "auto" = Pallas decode kernel on TPU
         # when the geometry supports() it, "pallas" = force the kernel
@@ -510,7 +521,9 @@ class GenerationEngine:
             if self.injector is not None:
                 self.injector.maybe_kernel_fault(site)
             out = call()
-            jax.block_until_ready(out)
+            with span(f"scheduler.step.{site}.wait", self._tracer):
+                jax.block_until_ready(out)
+                self.device_syncs += 1
         except Exception as e:
             if program not in self._kernel_programs_run and not isinstance(
                 e, KernelFault
@@ -555,6 +568,15 @@ class GenerationEngine:
         self._chunk_cache.clear()
         self._multistep_cache.clear()
         self._tree_cache.clear()
+
+    def _readback(self, kind: str, *arrays):
+        """Bring a step's device outputs to the host: one blocking read
+        each, counted where it happens."""
+        with span(f"scheduler.step.{kind}.readback", self._tracer):
+            out = [np.asarray(a) for a in arrays]
+            self.device_syncs += len(out)
+            self.readback_bytes += sum(a.nbytes for a in out)
+        return out
 
     # -- shared forward ------------------------------------------------------
 
@@ -799,7 +821,6 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        t0 = time.perf_counter()
         spec = self.cache.spec
         n = len(prompts)
         if n == 0:
@@ -807,70 +828,53 @@ class GenerationEngine:
         if n > spec.max_seqs:
             raise ValueError(f"{n} prompts > max_seqs {spec.max_seqs}")
         bucket = spec.bucket(max(len(p) for p in prompts))
-        tokens = np.zeros((spec.max_seqs, bucket), dtype=np.int32)
-        slot_ids = np.full(spec.max_seqs, spec.max_seqs, dtype=np.int32)
-        plens = np.ones(spec.max_seqs, dtype=np.int32)
-        for i, (p, s) in enumerate(zip(prompts, slots)):
-            if not 0 < len(p) <= spec.max_len:
-                raise ValueError(
-                    f"prompt length {len(p)} outside (0, {spec.max_len}]"
+        with span(
+            "scheduler.step.prefill.pack", self._tracer,
+            {"prompts": n, "bucket": bucket},
+        ):
+            tokens = np.zeros((spec.max_seqs, bucket), dtype=np.int32)
+            slot_ids = np.full(spec.max_seqs, spec.max_seqs, dtype=np.int32)
+            plens = np.ones(spec.max_seqs, dtype=np.int32)
+            for i, (p, s) in enumerate(zip(prompts, slots)):
+                if not 0 < len(p) <= spec.max_len:
+                    raise ValueError(
+                        f"prompt length {len(p)} outside (0, {spec.max_len}]"
+                    )
+                tokens[i, : len(p)] = np.asarray(p, dtype=np.int32)
+                slot_ids[i] = s
+                plens[i] = len(p)
+            self.prefill_tokens_real += int(plens[:n].sum())
+            self.prefill_tokens_padded += spec.max_seqs * bucket
+            fn = self._prefill_cache.get(bucket)
+            if fn is None:
+                fn = jax.jit(
+                    self._prefill_impl_paged
+                    if self.paged
+                    else self._prefill_impl
                 )
-            tokens[i, : len(p)] = np.asarray(p, dtype=np.int32)
-            slot_ids[i] = s
-            plens[i] = len(p)
-        fn = self._prefill_cache.get(bucket)
-        if fn is None:
-            fn = jax.jit(
-                self._prefill_impl_paged if self.paged else self._prefill_impl
+                self._prefill_cache[bucket] = fn
+            route = [jnp.asarray(slot_ids)]
+            if self.paged:
+                width = -(-bucket // spec.page_size)
+                row_tables = np.full(
+                    (spec.max_seqs, width), spec.num_pages, dtype=np.int32
+                )
+                for i, s in enumerate(slots):
+                    row_tables[i] = self.cache.block_tables[s, :width]
+                route.append(jnp.asarray(row_tables))
+            inputs = (jnp.asarray(tokens), *route, jnp.asarray(plens))
+        with span("scheduler.step.prefill.dispatch", self._tracer):
+            scales = (
+                (self.cache.k_scale, self.cache.v_scale) if self.paged else ()
             )
-            self._prefill_cache[bucket] = fn
-        route = [jnp.asarray(slot_ids)]
-        if self.paged:
-            ps = spec.page_size
-            width = -(-bucket // ps)
-            row_tables = np.full(
-                (spec.max_seqs, width), spec.num_pages, dtype=np.int32
-            )
-            for i, s in enumerate(slots):
-                row_tables[i] = self.cache.block_tables[s, :width]
-            route.append(jnp.asarray(row_tables))
-            new_k, new_v, new_ks, new_vs, nxt, last = fn(
-                params,
-                jnp.asarray(tokens),
-                *route,
-                jnp.asarray(plens),
-                self.cache.k,
-                self.cache.v,
-                self.cache.k_scale,
-                self.cache.v_scale,
+            *new_cache, nxt, last = fn(
+                params, *inputs, self.cache.k, self.cache.v, *scales,
                 *self._adapter_row_args(slots),
             )
-            self.cache.commit(new_k, new_v, new_ks, new_vs)
-        else:
-            new_k, new_v, nxt, last = fn(
-                params,
-                jnp.asarray(tokens),
-                *route,
-                jnp.asarray(plens),
-                self.cache.k,
-                self.cache.v,
-                *self._adapter_row_args(slots),
-            )
-            self.cache.commit(new_k, new_v)
-        for p, s in zip(prompts, slots):
-            self.cache.lengths[s] = len(p)
-        out_nxt, out_last = np.asarray(nxt[:n]), np.asarray(last[:n])
-        if self.telemetry is not None:
-            # prefill is synchronous (the np.asarray reads above block
-            # on the device), so one host-lane span covers it whole
-            self.telemetry.tracer.complete(
-                "prefill",
-                "engine",
-                t0,
-                time.perf_counter(),
-                args={"prompts": n, "bucket": bucket},
-            )
-        return out_nxt, out_last
+            self.cache.commit(*new_cache)
+            for p, s in zip(prompts, slots):
+                self.cache.lengths[s] = len(p)
+        return tuple(self._readback("prefill", nxt[:n], last[:n]))
 
     def prefill_suffix(
         self,
@@ -890,7 +894,6 @@ class GenerationEngine:
         lands at each request's FULL prompt length — the same _pick key
         the monolithic path uses. Returns (next_tokens [n],
         last_logits [n, V]) in request order."""
-        t0 = time.perf_counter()
         spec = self.cache.spec
         if not prompts:
             raise ValueError("prefill_suffix needs at least one prompt")
@@ -904,20 +907,16 @@ class GenerationEngine:
                 )
             suffixes.append(list(p[c:]))
         w = max(len(sfx) for sfx in suffixes)
-        tokens = np.zeros((spec.max_seqs, w), dtype=np.int32)
-        chunk_lens = np.zeros(spec.max_seqs, dtype=np.int32)
-        for sfx, s in zip(suffixes, slots):
-            tokens[s, : len(sfx)] = np.asarray(sfx, dtype=np.int32)
-            chunk_lens[s] = len(sfx)
-        nxt, logits = self.prefill_chunk(params, tokens, chunk_lens)
-        if self.telemetry is not None:
-            self.telemetry.tracer.complete(
-                "prefill_suffix",
-                "engine",
-                t0,
-                time.perf_counter(),
-                args={"prompts": len(prompts), "width": w},
-            )
+        with span(
+            "scheduler.step.prefill_suffix", self._tracer,
+            {"prompts": len(prompts), "width": w},
+        ):
+            tokens = np.zeros((spec.max_seqs, w), dtype=np.int32)
+            chunk_lens = np.zeros(spec.max_seqs, dtype=np.int32)
+            for sfx, s in zip(suffixes, slots):
+                tokens[s, : len(sfx)] = np.asarray(sfx, dtype=np.int32)
+                chunk_lens[s] = len(sfx)
+            nxt, logits = self.prefill_chunk(params, tokens, chunk_lens)
         return (
             np.asarray([nxt[s] for s in slots]),
             np.stack([logits[s] for s in slots]),
@@ -1306,8 +1305,9 @@ class GenerationEngine:
         lives on the step record's snapshots — by the time this runs,
         live cache/scheduler state is one iteration ahead."""
         try:
-            nxt = np.asarray(step.device_next)
-            logits = np.asarray(step.device_logits)
+            nxt, logits = self._readback(
+                "decode", step.device_next, step.device_logits
+            )
         finally:
             self.cache.end_inflight()
         return nxt, logits
@@ -1491,9 +1491,10 @@ class GenerationEngine:
         this runs, live cache/scheduler state is a whole window
         ahead (fxlint FX109)."""
         try:
-            toks_ks = np.asarray(step.device_tokens)
-            logits_ks = np.asarray(step.device_logits)
-            mask_ks = np.asarray(step.device_mask)
+            toks_ks, logits_ks, mask_ks = self._readback(
+                "multistep", step.device_tokens, step.device_logits,
+                step.device_mask,
+            )
         finally:
             self.cache.end_inflight()
         k = int(step.k_steps)
@@ -1946,7 +1947,7 @@ class GenerationEngine:
         in-flight window. Acceptance/rollback decisions belong to the
         caller, made against the step record's SNAPSHOT lengths."""
         try:
-            return np.asarray(step.device_logits)
+            return self._readback(step.kind, step.device_logits)[0]
         finally:
             self.cache.end_inflight()
 
@@ -2381,8 +2382,9 @@ class GenerationEngine:
         final-chunk rows carry meaning either way; the caller's cursor
         snapshot on the step record says which."""
         try:
-            nxt_c = np.asarray(step.device_next)
-            logits_c = np.asarray(step.device_logits)
+            nxt_c, logits_c = self._readback(
+                "chunk", step.device_next, step.device_logits
+            )
         finally:
             self.cache.end_inflight()
         spec = self.cache.spec

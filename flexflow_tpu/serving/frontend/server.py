@@ -45,6 +45,7 @@ from flexflow_tpu.serving.scheduler import (
     Request,
     TERMINAL_STATUSES,
 )
+from flexflow_tpu.telemetry.trace import span
 
 __all__ = ["StreamEvent", "FrontDoor", "serve_tcp"]
 
@@ -392,30 +393,31 @@ class FrontDoor:
         stream — no scheduler hook needed, and a burst (speculative
         accepts, chunk-final + decode) publishes as individual
         events."""
-        for rid, queue in list(self._queues.items()):
-            if rid in self._done:
-                continue
-            req = self._requests[rid]
-            cursor = self._published[rid]
-            fresh = req.generated[cursor:]
-            for token in fresh:
-                queue.put_nowait(
-                    StreamEvent(rid=rid, kind="token", token=int(token))
-                )
-            self._published[rid] = cursor + len(fresh)
-            if req.status in TERMINAL_STATUSES:
-                # the queue stays registered (buffered events included)
-                # until the consumer detaches — a client may open its
-                # stream after a short request already finished
-                queue.put_nowait(
-                    StreamEvent(
-                        rid=rid,
-                        kind="done",
-                        status=req.status,
-                        error=req.error,
+        with span("door.pump.publish"):
+            for rid, queue in list(self._queues.items()):
+                if rid in self._done:
+                    continue
+                req = self._requests[rid]
+                cursor = self._published[rid]
+                fresh = req.generated[cursor:]
+                for token in fresh:
+                    queue.put_nowait(
+                        StreamEvent(rid=rid, kind="token", token=int(token))
                     )
-                )
-                self._done.add(rid)
+                self._published[rid] = cursor + len(fresh)
+                if req.status in TERMINAL_STATUSES:
+                    # the queue stays registered (buffered events included)
+                    # until the consumer detaches — a client may open its
+                    # stream after a short request already finished
+                    queue.put_nowait(
+                        StreamEvent(
+                            rid=rid,
+                            kind="done",
+                            status=req.status,
+                            error=req.error,
+                        )
+                    )
+                    self._done.add(rid)
 
     def _detach(self, rid: int) -> None:
         """A consumer left. If the request is still live this is a

@@ -89,6 +89,7 @@ from flexflow_tpu.serving.engine import KernelCompileError
 from flexflow_tpu.serving.kv_cache import PagePoolExhausted
 from flexflow_tpu.telemetry import MetricsRegistry
 from flexflow_tpu.telemetry.slo import percentiles as _percentiles
+from flexflow_tpu.telemetry.trace import span
 
 
 class RequestStatus:
@@ -262,7 +263,8 @@ _STAT_FIELDS: Dict[str, object] = dict(
     # device-resident multi-step decode (decode_multistep=True)
     multistep_windows=0,  # fused K-step scan windows dispatched
     multistep_steps=0,  # Σ decode steps executed inside fused windows
-    host_syncs=0,  # step reconciles (host round-trips), all kinds
+    host_syncs=0,  # step RECONCILES, all kinds: one per step, however
+    # many device values it reads (those are device_syncs, below)
     multistep_cache_entries=0,  # live jitted scan programs (LRU gauge)
 
     # request lifecycle (filled at terminal transitions)
@@ -300,6 +302,12 @@ _STAT_FIELDS: Dict[str, object] = dict(
     # kernel-failure dense fallbacks (mirrored from the engine's ledger
     # at each iteration end)
     kernel_fallbacks=0,
+    # counted by the engine at the boundary where the work happens
+    # (mirrored at each iteration end)
+    device_syncs=0,  # blocking reads of a device value (wait, readbacks)
+    readback_bytes=0,  # bytes those reads brought to the host
+    prefill_tokens_real=0,  # prompt tokens run by monolithic prefills
+    prefill_tokens_padded=0,  # the max_seqs x bucket tokens they ran as
     # prefix-sharing page cache (paged layout with --prefix-cache;
     # mirrored from the allocator's ledgers at each iteration end)
     prefix_hits=0,  # admissions that mapped at least one shared page
@@ -647,6 +655,9 @@ class _SchedulerBase:
             if telemetry is not None and getattr(telemetry, "enabled", False)
             else None
         )
+        # what every `span` of the host loop is handed: the bundle's
+        # Chrome tracer, or None (then only the profiler's annotation)
+        self._tracer = getattr(self._tele, "tracer", None)
         self.queue: deque = deque()
         self.running: Dict[int, Request] = {}  # slot -> request
         self.finished: List[Request] = []
@@ -1338,6 +1349,10 @@ class _SchedulerBase:
         the blocking rule: a selected head that cannot take a slot NOW
         stops admission for everyone (no bypass), exactly the single-
         class no-reorder guarantee, just applied to the DRR order."""
+        with span("scheduler.step.admit", self._tracer):
+            return self._admit_batch(limit)
+
+    def _admit_batch(self, limit: Optional[int]) -> List[Request]:
         optimistic = self.admission == "optimistic"
         prefix = bool(getattr(self.cache, "prefix_cache", False))
         admitted: List[Request] = []
@@ -1542,67 +1557,65 @@ class _SchedulerBase:
         token is that step's not-yet-materialized output read it on
         device instead of from the host. Returns the InflightStep, or
         None when there is nothing to step."""
-        # predicted-view budget gate: a slot whose still-in-flight step
-        # will emit its FINAL budgeted token has nothing useful to
-        # compute here — the commit-phase identity check would discard
-        # the result anyway. EOS is not predictable at dispatch time, so
-        # an EOS retire still costs one wasted (discarded) slot-step.
-        stepped: Dict[int, Request] = {}
-        for slot, req in self.running.items():
-            if self._prefill_pending(req) or slot in self._chunk_unlocked:
-                continue  # chunked prefill: no decode until the last
-                #            chunk's token has committed, and none in
-                #            the commit's own iteration (its tokens
-                #            were never in this budget's plan)
-            chained = (
-                chain is not None
-                and chain.kind == "decode"
-                and chain.active[slot]
-                and chain.participants.get(slot) is req
-            )
-            if len(req.generated) + int(chained) >= req.max_new_tokens:
-                continue
-            stepped[slot] = req
-        self._secure_pages({slot: 1 for slot in stepped})
-        stepped = {s: r for s, r in stepped.items() if self.running.get(s) is r}
-        if not stepped:
-            return None
-        spec = self.cache.spec
-        tokens = np.zeros(spec.max_seqs, dtype=np.int32)
-        active = np.zeros(spec.max_seqs, dtype=bool)
-        chain_mask = np.zeros(spec.max_seqs, dtype=bool)
-        for slot, req in stepped.items():
-            tokens[slot] = req.generated[-1]
-            active[slot] = True
-            if (
-                chain is not None
-                and chain.kind == "decode"
-                and chain.active[slot]
-                and chain.participants.get(slot) is req
-            ):
-                chain_mask[slot] = True
-        t0 = time.perf_counter()
+        with span("scheduler.step.decode.plan", self._tracer):
+            # predicted-view budget gate: a slot whose still-in-flight step
+            # will emit its FINAL budgeted token has nothing useful to
+            # compute here — the commit-phase identity check would discard
+            # the result anyway. EOS is not predictable at dispatch time, so
+            # an EOS retire still costs one wasted (discarded) slot-step.
+            stepped: Dict[int, Request] = {}
+            for slot, req in self.running.items():
+                if self._prefill_pending(req) or slot in self._chunk_unlocked:
+                    continue  # chunked prefill: no decode until the last
+                    #            chunk's token has committed, and none in
+                    #            the commit's own iteration (its tokens
+                    #            were never in this budget's plan)
+                chained = (
+                    chain is not None
+                    and chain.kind == "decode"
+                    and chain.active[slot]
+                    and chain.participants.get(slot) is req
+                )
+                if len(req.generated) + int(chained) >= req.max_new_tokens:
+                    continue
+                stepped[slot] = req
+            self._secure_pages({slot: 1 for slot in stepped})
+            stepped = {
+                s: r for s, r in stepped.items() if self.running.get(s) is r
+            }
+            if not stepped:
+                return None
+            spec = self.cache.spec
+            tokens = np.zeros(spec.max_seqs, dtype=np.int32)
+            active = np.zeros(spec.max_seqs, dtype=bool)
+            chain_mask = np.zeros(spec.max_seqs, dtype=bool)
+            for slot, req in stepped.items():
+                tokens[slot] = req.generated[-1]
+                active[slot] = True
+                if (
+                    chain is not None
+                    and chain.kind == "decode"
+                    and chain.active[slot]
+                    and chain.participants.get(slot) is req
+                ):
+                    chain_mask[slot] = True
         try:
-            step = self.engine.decode_dispatch(
-                self.params,
-                tokens,
-                active,
-                chain=chain,
-                chain_mask=chain_mask if chain is not None else None,
-            )
+            with span(
+                "scheduler.step.decode.dispatch", self._tracer,
+                {"iter": self._iter, "active": int(active.sum())},
+            ):
+                step = self.engine.decode_dispatch(
+                    self.params,
+                    tokens,
+                    active,
+                    chain=chain,
+                    chain_mask=chain_mask if chain is not None else None,
+                )
         except KernelCompileError:
             raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"decode step failed: {e!r}")
             return None
-        if self._tele is not None:
-            self._tele.tracer.complete(
-                "dispatch:decode",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={"iter": self._iter, "active": int(active.sum())},
-            )
         step.iteration = self._iter
         step.participants = stepped
         self._note_dispatch(step)
@@ -1637,24 +1650,27 @@ class _SchedulerBase:
         # every reconcile is exactly one host round-trip, whatever the
         # step's width — the denominator of host_syncs_per_token
         self.stats.host_syncs += 1
-        if step.kind == "decode":
-            self._commit_decode(step, nxt, logits)
-        elif step.kind == "chunk":
-            self._commit_chunk(step, nxt, logits)
-        elif step.kind == "multistep":
-            self._commit_multistep(step, toks_ks, logits_ks, mask_ks)
-        elif step.kind == "verify_tree":
-            self._commit_verify_tree(step, logits)
-        else:
-            self._commit_verify(step, logits)
+        # the engine's readback span came first; the commit is the other
+        # half of the reconcile. Everything read here comes off the step
+        # record, never live cache state (fxlint FX103)
+        with span(
+            f"scheduler.step.{step.kind}.commit", self._tracer,
+            {"iter": step.iteration, "step": step.seq},
+        ):
+            if step.kind == "decode":
+                self._commit_decode(step, nxt, logits)
+            elif step.kind == "chunk":
+                self._commit_chunk(step, nxt, logits)
+            elif step.kind == "multistep":
+                self._commit_multistep(step, toks_ks, logits_ks, mask_ks)
+            elif step.kind == "verify_tree":
+                self._commit_verify_tree(step, logits)
+            else:
+                self._commit_verify(step, logits)
         if self._tele is not None:
-            # trace the step's whole in-flight window (dispatch →
-            # outputs materialized) on a device lane, and the host-side
-            # reconcile (block + commit) on the host lane — everything
-            # read here comes off the step record, never live cache
-            # state (fxlint FX103)
-            tr = self._tele.tracer
-            tr.device_window(
+            # the step's whole in-flight window (dispatch → outputs
+            # materialized) on a device lane
+            self._tele.tracer.device_window(
                 f"multistep[{int(step.k_steps)}]"
                 if step.kind == "multistep"
                 else step.kind,
@@ -1662,13 +1678,6 @@ class _SchedulerBase:
                 step.dispatch_t,
                 t1,
                 args={"iter": step.iteration},
-            )
-            tr.complete(
-                f"reconcile:{step.kind}",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={"iter": step.iteration, "step": step.seq},
             )
 
     def _commit_decode(self, step, nxt, logits) -> None:
@@ -1814,31 +1823,20 @@ class _SchedulerBase:
             step_limits[slot] = limits[slot]
             if req.eos_token is not None:
                 eos[slot] = int(req.eos_token)
-        t0 = time.perf_counter()
+        args = {"iter": self._iter, "active": int(active.sum())}
         try:
-            step = self.engine.decode_multi_dispatch(
-                self.params, tokens, active, step_limits, eos_tokens=eos
-            )
+            with span("scheduler.step.multistep.dispatch", self._tracer, args):
+                step = self.engine.decode_multi_dispatch(
+                    self.params, tokens, active, step_limits, eos_tokens=eos
+                )
+                args["k"] = kmax = int(step.k_steps)
         except KernelCompileError:
             raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"multistep decode failed: {e!r}")
             return None
-        kmax = int(step.k_steps)
         if self._tele is not None:
-            tele = self._tele
-            tele.tracer.complete(
-                "dispatch:multistep",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={
-                    "iter": self._iter,
-                    "active": int(active.sum()),
-                    "k": kmax,
-                },
-            )
-            reg = tele.registry
+            reg = self._tele.registry
             reg.counter(
                 "serve_multistep_windows_total",
                 help="fused K-step decode windows dispatched",
@@ -1909,7 +1907,6 @@ class _SchedulerBase:
         injected) degrades THIS iteration to plain decode — empty
         proposals make every verify a w=1 decode — instead of killing
         the run."""
-        t0 = time.perf_counter()
         # chunked prefill: a slot mid-prefill has no committed history
         # to draft from — exclude it until its last chunk lands
         draftable = {
@@ -1917,23 +1914,18 @@ class _SchedulerBase:
             for s, r in self.running.items()
             if not self._prefill_pending(r) and s not in self._chunk_unlocked
         }
+        args = {"iter": self._iter}
         try:
-            if self.injector is not None:
-                self.injector.maybe_draft_fault()
-            proposals = self.proposer.propose(draftable, k)
+            with span("scheduler.step.draft.propose", self._tracer, args):
+                if self.injector is not None:
+                    self.injector.maybe_draft_fault()
+                proposals = self.proposer.propose(draftable, k)
+                args["slots"] = len(proposals)
         except KernelCompileError:
             raise  # the draft engine's kernel never ran: not a fault
         except Exception:
             self.stats.draft_faults += 1
             return {}
-        if self._tele is not None:
-            self._tele.tracer.complete(
-                "draft:propose",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={"iter": self._iter, "slots": len(proposals)},
-            )
         return proposals
 
     def _verify_dispatch_step(self, proposals):
@@ -1985,24 +1977,19 @@ class _SchedulerBase:
             for j, t in enumerate(drafts):
                 tokens[slot, 1 + j] = int(t)
             draft_lens[slot] = 1 + len(drafts)
-        t0 = time.perf_counter()
         try:
-            step = self.engine.verify_dispatch(
-                self.params, tokens, draft_lens
-            )
+            with span(
+                "scheduler.step.verify.dispatch", self._tracer,
+                {"iter": self._iter, "slots": len(plan)},
+            ):
+                step = self.engine.verify_dispatch(
+                    self.params, tokens, draft_lens
+                )
         except KernelCompileError:
             raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"verify step failed: {e!r}")
             return None
-        if self._tele is not None:
-            self._tele.tracer.complete(
-                "dispatch:verify",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={"iter": self._iter, "slots": len(plan)},
-            )
         step.iteration = self._iter
         step.plan = plan
         step.participants = {s: self.running[s] for s in plan}
@@ -2098,31 +2085,25 @@ class _SchedulerBase:
         injected) degrades THIS iteration to plain decode — empty
         trees make every verify row a w=1 decode — instead of killing
         the run."""
-        t0 = time.perf_counter()
         draftable = {
             s: r
             for s, r in self.running.items()
             if not self._prefill_pending(r) and s not in self._chunk_unlocked
         }
+        args = {"iter": self._iter}
         try:
-            if self.injector is not None:
-                self.injector.maybe_draft_fault()
-            trees = self.proposer.propose_trees(
-                draftable, self.spec_k, self.spec_branch
-            )
+            with span("scheduler.step.draft.propose_tree", self._tracer, args):
+                if self.injector is not None:
+                    self.injector.maybe_draft_fault()
+                trees = self.proposer.propose_trees(
+                    draftable, self.spec_k, self.spec_branch
+                )
+                args["slots"] = len(trees)
         except KernelCompileError:
             raise  # the draft engine's kernel never ran: not a fault
         except Exception:
             self.stats.draft_faults += 1
             return {}
-        if self._tele is not None:
-            self._tele.tracer.complete(
-                "draft:propose_tree",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={"iter": self._iter, "slots": len(trees)},
-            )
         return trees
 
     def _verify_tree_dispatch_step(self, trees):
@@ -2183,28 +2164,20 @@ class _SchedulerBase:
             parents[slot] = tree.row_parents(w)
             draft_lens[slot] = 1 + len(tree.tokens)
             nodes_total += len(tree.tokens)
-        t0 = time.perf_counter()
         try:
-            step = self.engine.verify_tree_dispatch(
-                self.params, tokens, draft_lens, parents
-            )
+            with span(
+                "scheduler.step.verify_tree.dispatch", self._tracer,
+                {"iter": self._iter, "slots": len(plan), "nodes": nodes_total},
+            ):
+                step = self.engine.verify_tree_dispatch(
+                    self.params, tokens, draft_lens, parents
+                )
         except KernelCompileError:
             raise  # never ran: not a fault to isolate
         except Exception as e:
             self._fail_all_running(f"tree verify step failed: {e!r}")
             return None
         if self._tele is not None:
-            self._tele.tracer.complete(
-                "dispatch:verify_tree",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={
-                    "iter": self._iter,
-                    "slots": len(plan),
-                    "nodes": nodes_total,
-                },
-            )
             self._tele.registry.counter(
                 "serve_spec_tree_nodes_total",
                 help="draft-tree nodes dispatched for verification",
@@ -2524,11 +2497,15 @@ class _SchedulerBase:
             tokens[slot, :c] = req.prefill_seq[start : start + c]
             chunk_lens[slot] = c
             chunks[slot] = (start, c, start + c >= len(req.prefill_seq))
-        t0 = time.perf_counter()
         try:
-            step = self.engine.prefill_chunk_dispatch(
-                self.params, tokens, chunk_lens
-            )
+            with span(
+                "scheduler.step.chunk.dispatch", self._tracer,
+                {"iter": self._iter, "slots": len(chunks),
+                 "tokens": int(chunk_lens.sum())},
+            ):
+                step = self.engine.prefill_chunk_dispatch(
+                    self.params, tokens, chunk_lens
+                )
         except KernelCompileError:
             raise  # never ran: not a fault to isolate
         except Exception as e:
@@ -2537,17 +2514,6 @@ class _SchedulerBase:
         for slot, (start, c, _final) in chunks.items():
             self.running[slot].prefill_dispatched = start + c
         if self._tele is not None:
-            self._tele.tracer.complete(
-                "prefill:chunk",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={
-                    "iter": self._iter,
-                    "slots": len(chunks),
-                    "tokens": int(chunk_lens.sum()),
-                },
-            )
             self._tele.registry.counter(
                 "serve_chunks_total",
                 help="prompt chunks dispatched (chunked prefill)",
@@ -2630,63 +2596,68 @@ class _SchedulerBase:
         self._decode_once()
 
     def _begin_iteration(self) -> None:
-        self._iter += 1
-        self.stats.iterations += 1
-        self._budget_used_iter = 0
-        self._chunk_unlocked.clear()
-        self._cached_proposals = None
         if self._tele is not None:
             self._iter_t0 = time.perf_counter()
-        if self.injector is not None:
-            self.injector.on_iteration(self._iter, self)
-            # chaos: process death at the step boundary, before any
-            # work — everything journaled so far survives, nothing new
-            # is at risk (serving/journal.py proves the restart)
-            crash = getattr(self.injector, "maybe_crash", None)
-            if crash is not None:
-                crash("begin")
-        self._reap_deadlines()
+        with span("scheduler.step.begin", self._tracer):
+            self._iter += 1
+            self.stats.iterations += 1
+            self._budget_used_iter = 0
+            self._chunk_unlocked.clear()
+            self._cached_proposals = None
+            if self.injector is not None:
+                self.injector.on_iteration(self._iter, self)
+                # chaos: process death at the step boundary, before any
+                # work — everything journaled so far survives, nothing
+                # new is at risk (serving/journal.py proves the restart)
+                crash = getattr(self.injector, "maybe_crash", None)
+                if crash is not None:
+                    crash("begin")
+            self._reap_deadlines()
+
+    #: engine ledgers mirrored into the stats at each iteration's end
+    _ENGINE_MIRRORS = (
+        "verify_cache_entries", "kernel_fallbacks", "multistep_cache_entries",
+        "device_syncs", "readback_bytes", "prefill_tokens_real",
+        "prefill_tokens_padded",
+    )
 
     def _end_iteration(self) -> None:
-        # per-iteration gauge: tokens this iteration's dispatches
-        # charged against the budget (chunk + decode/verify widths)
-        self.stats.budget_used = self._budget_used_iter
-        self.stats.verify_cache_entries = getattr(
-            self.engine, "verify_cache_entries", 0
-        )
-        self.stats.kernel_fallbacks = getattr(
-            self.engine, "kernel_fallbacks", 0
-        )
-        self.stats.multistep_cache_entries = getattr(
-            self.engine, "multistep_cache_entries", 0
-        )
-        self.stats.prefix_hits = getattr(self.cache, "prefix_hits", 0)
-        self.stats.prefix_pages_shared = int(
-            getattr(self.cache, "_shared", np.zeros(1)).sum()
-        )
-        self.stats.cow_copies = getattr(self.cache, "cow_copies", 0)
-        self.stats.swap_outs = getattr(self.cache, "swap_outs", 0)
-        self.stats.swap_ins = getattr(self.cache, "swap_ins", 0)
-        self.stats.swap_bytes = getattr(self.cache, "swap_bytes_total", 0)
-        self.stats.swapped_pages = getattr(self.cache, "swapped_pages", 0)
-        self.stats.prefix_evictions = getattr(
-            self.cache, "prefix_evictions", 0
-        )
-        if self.debug_invariants:
-            # pages the injector stole this iteration are accounted as
-            # extra frees — conservation must hold even mid-chaos
-            self.cache.check_invariants(
-                extra_free=(
-                    self.injector.stolen_pages
-                    if self.injector is not None
-                    else 0
-                )
+        with span("scheduler.step.end", self._tracer):
+            # per-iteration gauge: tokens this iteration's dispatches
+            # charged against the budget (chunk + decode/verify widths)
+            self.stats.budget_used = self._budget_used_iter
+            for name in self._ENGINE_MIRRORS:
+                setattr(self.stats, name, getattr(self.engine, name, 0))
+            cache = self.cache
+            self.stats.prefix_hits = getattr(cache, "prefix_hits", 0)
+            self.stats.prefix_pages_shared = int(
+                getattr(cache, "_shared", np.zeros(1)).sum()
             )
-            if self.adapters is not None:
-                self.adapters.check_invariants()
-            if self._admit_drr is not None:
-                self._admit_drr.check_invariants(max_cost=1.0)
+            self.stats.cow_copies = getattr(cache, "cow_copies", 0)
+            self.stats.swap_outs = getattr(cache, "swap_outs", 0)
+            self.stats.swap_ins = getattr(cache, "swap_ins", 0)
+            self.stats.swap_bytes = getattr(cache, "swap_bytes_total", 0)
+            self.stats.swapped_pages = getattr(cache, "swapped_pages", 0)
+            self.stats.prefix_evictions = getattr(
+                cache, "prefix_evictions", 0
+            )
+            if self.debug_invariants:
+                # pages the injector stole this iteration are accounted
+                # as extra frees — conservation must hold even mid-chaos
+                cache.check_invariants(
+                    extra_free=(
+                        self.injector.stolen_pages
+                        if self.injector is not None
+                        else 0
+                    )
+                )
+                if self.adapters is not None:
+                    self.adapters.check_invariants()
+                if self._admit_drr is not None:
+                    self._admit_drr.check_invariants(max_cost=1.0)
         if self._tele is not None:
+            # closes the Chrome `iteration` span, so it sits between the
+            # two parts of `scheduler.step.end` and not inside one
             self._sample_telemetry()
         if self.injector is not None:
             # chaos: process death AFTER this iteration's tokens were
@@ -2702,12 +2673,13 @@ class _SchedulerBase:
             # per-host-sync commit flush, INSIDE step(): the front
             # door's publish runs after step() returns, so the journal
             # always dominates the published cursor (FX111)
-            self.journal.commit_pending(self._iter)
-            if (
-                self.journal_snapshot_every
-                and self._iter % self.journal_snapshot_every == 0
-            ):
-                self._journal_snapshots()
+            with span("scheduler.step.end", self._tracer):
+                self.journal.commit_pending(self._iter)
+                if (
+                    self.journal_snapshot_every
+                    and self._iter % self.journal_snapshot_every == 0
+                ):
+                    self._journal_snapshots()
 
     def _journal_snapshots(self) -> None:
         """Journal-referenced KV snapshots (paged layout only): every
@@ -3142,19 +3114,14 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
         # draft one EXTRA token: the prediction cannot know the verify's
         # bonus/correction token, so a pre-proposal only survives when
         # its first token turns out to BE that token — the rest aligns
-        t0 = time.perf_counter()
-        proposals = self.proposer.propose_sequences(seqs, self.spec_k + 1)
-        if self._tele is not None:
-            # the draft/verify overlap the async spec loop exists for:
-            # this host span sits INSIDE the in-flight verify's device
-            # window in the exported trace
-            self._tele.tracer.complete(
-                "draft:pre_propose",
-                "host",
-                t0,
-                time.perf_counter(),
-                args={"iter": self._iter, "slots": len(seqs)},
-            )
+        # the draft/verify overlap the async spec loop exists for: this
+        # host span sits INSIDE the in-flight verify's device window in
+        # the exported trace
+        with span(
+            "scheduler.step.draft.pre_propose", self._tracer,
+            {"iter": self._iter, "slots": len(seqs)},
+        ):
+            proposals = self.proposer.propose_sequences(seqs, self.spec_k + 1)
         return {
             s: (basis[s], [int(t) for t in proposals.get(s) or ()])
             for s in seqs

@@ -54,6 +54,7 @@ from flexflow_tpu.telemetry.trace import (
     TID_DEVICE0,
     TID_HOST,
     Tracer,
+    span,
 )
 from flexflow_tpu.telemetry.validate import (
     ValidationError,
@@ -85,6 +86,7 @@ __all__ = [
     "register_durability_metrics",
     "validate_durability_metrics",
     "Tracer",
+    "span",
     "SLOMonitor",
     "RollingWindow",
     "percentiles",

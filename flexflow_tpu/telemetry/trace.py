@@ -23,16 +23,27 @@ Timestamps are `time.perf_counter()` seconds relative to the tracer's
 construction, exported as microseconds (the trace-event unit). All
 recording methods are allocation-light appends; the NullTracer twin in
 __init__.py makes every call a no-op when tracing is off.
+
+`span` (module level) is THE way a region of the host loop is marked,
+in the trainer and in the server: it always enters a
+`jax.profiler.TraceAnnotation`, so a profiler session (the benchmark's
+`--trace 1`, `utils/profiling.trace`) shows the program's phases on the
+device trace's own clock, and, handed a real `Tracer`, it also appends
+the Chrome complete event under the same name on the host lane. With
+neither a session nor a tracer it costs one TraceMe activity check.
 """
 
 from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
 from typing import Dict, List, Mapping, Optional
 
-__all__ = ["Tracer", "PID_ENGINE", "PID_REQUESTS", "TID_HOST", "TID_DEVICE0"]
+from jax.profiler import TraceAnnotation
+
+__all__ = [
+    "Tracer", "span", "PID_ENGINE", "PID_REQUESTS", "TID_HOST", "TID_DEVICE0",
+]
 
 #: process lanes: engine timeline vs per-request lifecycle
 PID_ENGINE = 1
@@ -166,7 +177,6 @@ class Tracer:
             ev["args"] = dict(args)
         self._push(ev)
 
-    @contextmanager
     def span(
         self,
         name: str,
@@ -176,12 +186,7 @@ class Tracer:
         args: Optional[Mapping[str, object]] = None,
     ):
         """Context-managed complete event around a host code block."""
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.complete(name, cat, t0, self.now(), pid=pid, tid=tid,
-                          args=args)
+        return span(name, self, args, cat=cat, pid=pid, tid=tid)
 
     def device_window(
         self, kind: str, step_index: int, start_s: float, end_s: float,
@@ -269,3 +274,47 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_json(), f)
             f.write("\n")
+
+
+class span:
+    """`with span(name, tracer, args):` marks one region of host code.
+
+    Always a `jax.profiler.TraceAnnotation(name)`: inside a profiler
+    session the region is an event of the Python thread's line, on the
+    clock of the device's ops; outside one it is a TraceMe activity
+    check. When `tracer` is a real `Tracer` (not None, not the
+    NullTracer) the region is also one Chrome complete event under the
+    same name. `args` is read at exit, so the block may fill it in. A
+    child's name is its parent's plus a dotted suffix
+    (`scheduler.step.decode.wait`): readers filter by prefix."""
+
+    __slots__ = ("_ann", "_tracer", "_event", "_t0")
+
+    def __init__(
+        self,
+        name: str,
+        tracer: Optional[Tracer] = None,
+        args: Optional[Mapping[str, object]] = None,
+        cat: str = "host",
+        pid: int = PID_ENGINE,
+        tid: int = TID_HOST,
+    ):
+        self._ann = TraceAnnotation(name)
+        self._tracer = tracer if isinstance(tracer, Tracer) else None
+        self._event = (name, cat, pid, tid, args)
+
+    def __enter__(self):
+        if self._tracer is not None:
+            self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        if self._tracer is not None:
+            name, cat, pid, tid, args = self._event
+            self._tracer.complete(
+                name, cat, self._t0, time.perf_counter(),
+                pid=pid, tid=tid, args=args,
+            )
+        return False

@@ -11,7 +11,7 @@ kernels/linear_kernels.cu:95-117; SURVEY §5.1). Two TPU-native tools:
     model documents), so treat them as relative weights.
   * `trace(dir)` — context manager around jax.profiler for a real XLA
     trace (the analog of `-lg:prof` external profiles, viewable in
-    TensorBoard / Perfetto).
+    TensorBoard / Perfetto), with the program's own host phases in it.
 """
 
 from __future__ import annotations
@@ -117,10 +117,20 @@ def trace(log_dir: str):
 
         with profiling.trace("/tmp/trace"):
             model.fit(...)
-    """
+
+    The session is the one the benchmark's traced runs use: the Python
+    tracer off (it would put an event on every call of the host loop)
+    and the host tracer at level 1, which keeps `TraceAnnotation`s. So
+    the program's own phases (`telemetry.trace.span`: `train.input.*`,
+    `train.epoch_end.*`, `scheduler.step.*`) sit on the Python thread's
+    line above the device's ops, on one clock
+    (docs/observability.md, "Reading a profile")."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:

@@ -461,7 +461,7 @@ def test_chunked_telemetry_counters_and_spans(lm):
     `serve_chunks_total`, zero-grant iterations into
     `serve_budget_deferrals_total`, the per-iteration ledger lands on
     the `serve_stats_budget_used` gauge, and each chunk step records a
-    `prefill:chunk` trace span."""
+    `scheduler.step.chunk.dispatch` trace span."""
     serve = ServeConfig(
         max_seqs=4, max_seq_len=32, token_budget=4, chunk_size=4,
         decode_kernel="dense", telemetry=True,
@@ -482,7 +482,7 @@ def test_chunked_telemetry_counters_and_spans(lm):
         sched.stats.chunk_steps
     )
     assert any(
-        e.get("name") == "prefill:chunk"
+        e.get("name") == "scheduler.step.chunk.dispatch"
         for e in sched.telemetry.tracer.events
     )
 

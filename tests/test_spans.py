@@ -1,0 +1,429 @@
+"""The program's host phases on the profiler's clock (ISSUE 24).
+
+`telemetry.trace.span` is the one way a region of the trainer's loop or
+the server's step is marked. Held here:
+
+* a tiny `fit()` and a tiny scheduler run under a `jax.profiler` session
+  yield exactly the named spans, properly nested, read back through
+  `jax.profiler.ProfileData` (the CPU backend's `python` line carries
+  `TraceAnnotation`s as the chip's `python3` line does);
+* with no session and no `Telemetry` the primitive records nothing and
+  no `Tracer` is built;
+* with a `Telemetry` trace attached the Chrome export carries the same
+  names and still passes `validate_trace`;
+* token streams and trained parameters are bit-identical with the spans
+  read (a session running, a tracer attached) and unread;
+* the counts taken at the same boundaries (`device_syncs`,
+  `readback_bytes`, `prefill_tokens_real/padded`) equal hand counts.
+"""
+
+import glob
+import os
+
+import numpy as np
+import pytest
+
+import jax
+
+from flexflow_tpu import (
+    ActiMode,
+    DataType,
+    FFConfig,
+    FFModel,
+    LossType,
+    SGDOptimizer,
+)
+from flexflow_tpu.models import build_decoder_lm
+from flexflow_tpu.serving import Request, ServeConfig, Telemetry, build_scheduler
+from flexflow_tpu.telemetry import NullTracer, Tracer, span, validate_trace
+from flexflow_tpu.telemetry import trace as trace_mod
+from flexflow_tpu.utils import profiling
+
+pytestmark = [pytest.mark.serving, pytest.mark.telemetry]
+
+VOCAB = 50
+SLOTS = 4
+
+TRAIN_SPANS = {
+    "train.epoch_end.reset",
+    "train.input.next_batch",
+    "train.input.shard_batch",
+    "train.input.shard_batch.x",
+    "train.input.shard_batch.label",
+    "train.input.dispatch",
+    "train.epoch_end.drain",
+    "train.epoch_end.losses",
+}
+STEP = "scheduler.step."
+#: the served path: ServeConfig() defaults but for the sizes
+SERVE_SPANS = {
+    STEP + s for s in (
+        "begin", "admit", "prefill.pack", "prefill.dispatch",
+        "prefill.readback", "decode.plan", "decode.dispatch", "decode.wait",
+        "decode.readback", "decode.commit", "end",
+    )
+}
+#: child -> the span it has to sit inside
+PARENTS = {
+    "train.input.shard_batch.x": "train.input.shard_batch",
+    "train.input.shard_batch.label": "train.input.shard_batch",
+    STEP + "prefill.pack": STEP + "admit",
+    STEP + "prefill.dispatch": STEP + "admit",
+    STEP + "prefill.readback": STEP + "admit",
+    STEP + "decode.wait": STEP + "decode.dispatch",
+    STEP + "chunk.wait": STEP + "chunk.dispatch",
+    STEP + "verify.wait": STEP + "verify.dispatch",
+    STEP + "multistep.wait": STEP + "multistep.dispatch",
+}
+
+
+# -- tiny programs -------------------------------------------------------------
+
+
+def _trainer():
+    cfg = FFConfig(batch_size=8)
+    model = FFModel(cfg)
+    x = model.create_tensor([8, 16], name="x")
+    t = model.dense(x, 16, activation=ActiMode.RELU, name="d0")
+    model.dense(t, 4, name="head")
+    model.compile(
+        optimizer=SGDOptimizer(lr=0.05),
+        loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+        metrics=[],
+        devices=jax.devices()[:1],
+    )
+    return model
+
+
+def _dataset():
+    rng = np.random.RandomState(0)
+    return (
+        rng.randn(24, 16).astype(np.float32),
+        rng.randint(0, 4, size=(24, 1)).astype(np.int32),
+    )
+
+
+def _fit(telemetry=None, epochs=2):
+    model = _trainer()
+    x, y = _dataset()
+    model.fit(x, y, epochs=epochs, verbose=False, telemetry=telemetry)
+    return model
+
+
+@pytest.fixture(scope="module")
+def lm():
+    cfg = FFConfig(batch_size=SLOTS, seed=0)
+    model = FFModel(cfg)
+    tok = model.create_tensor([SLOTS, 32], dtype=DataType.INT32, name="tokens")
+    build_decoder_lm(
+        model, tok, vocab_size=VOCAB, hidden=32, num_heads=4, num_layers=2,
+        ff_dim=64,
+    )
+    model.compile(
+        optimizer=SGDOptimizer(lr=0.01),
+        loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+        metrics=[],
+        devices=jax.devices()[:1],
+    )
+    return model
+
+
+#: three requests, scripted: two admitted together, the third queued
+#: behind them (max_seqs=2), so the run has two prefill batches
+SCRIPT = ([1, 2, 3], [4, 5, 6, 7, 8], [9, 8])
+
+
+def _requests(max_new=4):
+    return [
+        Request(rid=i, prompt=list(p), max_new_tokens=max_new)
+        for i, p in enumerate(SCRIPT)
+    ]
+
+
+def _serve(lm, telemetry=None, **kw):
+    serve = ServeConfig(max_seqs=2, max_seq_len=32, **kw)
+    sched, engine, cache = build_scheduler(lm, serve, telemetry=telemetry)
+    done = sched.run(_requests())
+    assert all(r.ok for r in done)
+    return sched, engine, {r.rid: list(r.generated) for r in done}
+
+
+# -- reading a profile ---------------------------------------------------------
+
+
+def _profiled(tmp_path, fn):
+    """Run `fn` inside a profiler session; return (fn's result, the
+    events of the Python thread's line as (name, start_ns, end_ns))."""
+    with profiling.trace(str(tmp_path)):
+        out = fn()
+    found = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+    )
+    assert len(found) == 1, found
+    data = jax.profiler.ProfileData.from_file(found[0])
+    events = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            if line.name in ("python", "python3"):
+                events += [
+                    (e.name, e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events
+                ]
+    return out, events
+
+
+def _ours(events, prefixes):
+    return [e for e in events if e[0].startswith(prefixes)]
+
+
+def _assert_nested(events):
+    """Any two spans of the thread are disjoint or one holds the other,
+    and every child sits inside a span of its parent's name."""
+    ordered = sorted(events, key=lambda e: (e[1], -e[2]))
+    stack = []
+    for name, start, end in ordered:
+        while stack and start >= stack[-1][2]:
+            stack.pop()
+        if stack:
+            assert end <= stack[-1][2], (name, stack[-1][0])
+        parent = PARENTS.get(name)
+        if parent is not None:
+            assert parent in [s[0] for s in stack], (name, [s[0] for s in stack])
+        stack.append((name, start, end))
+
+
+def test_fit_under_a_profiler_session_yields_exactly_the_named_spans(tmp_path):
+    _, events = _profiled(tmp_path, lambda: _fit(epochs=2))
+    ours = _ours(events, ("train.",))
+    assert {e[0] for e in ours} == TRAIN_SPANS
+    _assert_nested(ours)
+    count = {n: sum(1 for e in ours if e[0] == n) for n in TRAIN_SPANS}
+    # 2 epochs of 3 batches: one of each per step, one of each per epoch
+    assert count["train.input.next_batch"] == 6
+    assert count["train.input.shard_batch.label"] == 6
+    assert count["train.input.dispatch"] == 6
+    assert count["train.epoch_end.drain"] == 2
+    assert count["train.epoch_end.losses"] == 2
+    assert count["train.epoch_end.reset"] == 2
+    # within a step: gather, then place, then dispatch
+    firsts = [
+        min(e[1] for e in ours if e[0] == n) for n in (
+            "train.epoch_end.reset", "train.input.next_batch",
+            "train.input.shard_batch", "train.input.dispatch",
+            "train.epoch_end.drain", "train.epoch_end.losses",
+        )
+    ]
+    assert firsts == sorted(firsts)
+
+
+@pytest.mark.parametrize("serve_async", [False, True], ids=["sync", "async"])
+def test_scheduler_under_a_profiler_session_yields_exactly_the_named_spans(
+    lm, tmp_path, serve_async
+):
+    (sched, _, _), events = _profiled(
+        tmp_path, lambda: _serve(lm, serve_async=serve_async)
+    )
+    ours = _ours(events, ("scheduler.", "door."))
+    assert {e[0] for e in ours} == SERVE_SPANS
+    _assert_nested(ours)
+    count = {n: sum(1 for e in ours if e[0] == n) for n in SERVE_SPANS}
+    st = sched.stats
+    assert count[STEP + "begin"] == count[STEP + "end"] == st.iterations
+    assert count[STEP + "admit"] == st.iterations
+    assert count[STEP + "prefill.dispatch"] == st.prefill_batches == 2
+    assert count[STEP + "decode.dispatch"] == st.decode_steps
+    assert count[STEP + "decode.wait"] == st.decode_steps
+    assert count[STEP + "decode.readback"] == st.decode_steps
+    assert count[STEP + "decode.commit"] == st.host_syncs == st.decode_steps
+
+
+@pytest.mark.parametrize(
+    "kw, expected",
+    [
+        (dict(token_budget=8, chunk_size=8),
+         {"chunk.dispatch", "chunk.wait", "chunk.readback", "chunk.commit"}),
+        (dict(spec_draft="ngram", spec_k=2),
+         {"draft.propose", "verify.dispatch", "verify.wait",
+          "verify.readback", "verify.commit"}),
+        (dict(decode_multistep=True, max_fused_steps=4),
+         {"multistep.dispatch", "multistep.wait", "multistep.readback",
+          "multistep.commit"}),
+    ],
+    ids=["chunked", "spec", "multistep"],
+)
+def test_other_step_kinds_follow_the_pattern(lm, tmp_path, kw, expected):
+    """`scheduler.step.<kind>.{dispatch,wait,readback,commit}` by
+    `step.kind`, in the profile and in the Chrome export alike."""
+    tele = Telemetry(trace_enabled=True)
+    _, events = _profiled(tmp_path, lambda: _serve(lm, telemetry=tele, **kw))
+    ours = _ours(events, ("scheduler.",))
+    names = {e[0] for e in ours}
+    assert {STEP + s for s in expected} <= names
+    _assert_nested(ours)
+    chrome = {e["name"] for e in tele.tracer.events if e.get("ph") == "X"}
+    assert names <= chrome
+    assert validate_trace(tele.tracer.to_json(), errors="list") == []
+
+
+def test_front_door_publish_span(lm, tmp_path):
+    import asyncio
+
+    from flexflow_tpu.serving.frontend.server import FrontDoor
+
+    async def drive():
+        sched, _, _ = build_scheduler(lm, ServeConfig(max_seqs=2, max_seq_len=32))
+        door = FrontDoor(sched)
+        rid = await door.submit([1, 2, 3], max_new_tokens=3)
+        return [ev async for ev in door.stream(rid)]
+
+    out, events = _profiled(tmp_path, lambda: asyncio.run(drive()))
+    assert [ev.kind for ev in out] == ["token"] * 3 + ["done"]
+    assert any(e[0] == "door.pump.publish" for e in events)
+
+
+# -- nothing read, nothing recorded ---------------------------------------------
+
+
+def test_no_session_and_no_telemetry_records_nothing_and_builds_no_tracer(
+    lm, monkeypatch
+):
+    built = []
+    real_init = Tracer.__init__
+
+    def counting_init(self, *a, **k):
+        built.append(self)
+        real_init(self, *a, **k)
+
+    monkeypatch.setattr(Tracer, "__init__", counting_init)
+    sched, engine, _ = _serve(lm)
+    model = _fit(epochs=1)
+    assert built == []
+    assert sched._tracer is None and engine._tracer is None
+    assert sched.telemetry is None and model._telemetry is None
+    # the primitive itself: no tracer, or the NullTracer, keeps no event
+    null = NullTracer()
+    with span("a.b"), span("a.b.c", null, {"k": 1}), null.span("a.b.d"):
+        pass
+    assert null.events == ()
+
+
+def test_tracer_span_is_a_thin_call_of_the_primitive():
+    tracer = Tracer()
+    args = {"iter": 3}
+    with tracer.span("outer", args=args) as s:
+        assert isinstance(s, trace_mod.span)
+        with span("outer.inner", tracer, cat="engine"):
+            pass
+        args["filled_inside"] = True  # read at exit
+    xs = [e for e in tracer.events if e.get("ph") == "X"]
+    assert [e["name"] for e in xs] == ["outer.inner", "outer"]
+    assert xs[1]["args"] == {"iter": 3, "filled_inside": True}
+    assert xs[0]["cat"] == "engine" and xs[1]["cat"] == "host"
+    assert xs[1]["ts"] <= xs[0]["ts"]
+    assert xs[0]["ts"] + xs[0]["dur"] <= xs[1]["ts"] + xs[1]["dur"] + 1e-3
+    assert validate_trace(tracer.to_json(), errors="list") == []
+
+
+# -- the Chrome export carries the same names -----------------------------------
+
+
+def test_chrome_export_carries_the_profile_names_and_validates(lm, tmp_path):
+    tele = Telemetry(trace_enabled=True)
+    _, events = _profiled(tmp_path, lambda: _serve(lm, telemetry=tele))
+    profile = {e[0] for e in _ours(events, ("scheduler.",))}
+    assert profile == SERVE_SPANS
+    doc = tele.tracer.to_json()
+    chrome = {
+        e["name"] for e in doc["traceEvents"]
+        if e.get("ph") == "X" and e["name"].startswith("scheduler.")
+    }
+    assert chrome == profile
+    # what stays Chrome-only is still there, and the lanes still nest
+    names = {e["name"] for e in doc["traceEvents"]}
+    assert {"iteration", "inflight:decode", "QUEUED", "RUNNING"} <= names
+    assert validate_trace(doc, errors="list") == []
+
+
+def test_training_chrome_export_carries_fit_phases_and_validates():
+    tele = Telemetry(trace_enabled=True)
+    _fit(telemetry=tele, epochs=2)
+    doc = tele.tracer.to_json()
+    names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
+    # place_batch has no tracer to hand: its per-input spans are the
+    # profiler's only
+    assert names == (
+        TRAIN_SPANS
+        - {"train.input.shard_batch.x", "train.input.shard_batch.label"}
+    ) | {"iteration", "epoch"}
+    assert validate_trace(doc, errors="list") == []
+
+
+# -- observation does not perturb ------------------------------------------------
+
+
+def test_token_streams_bit_identical_with_spans_read_and_unread(lm, tmp_path):
+    _, _, plain = _serve(lm)
+    (_, _, profiled), _ = _profiled(tmp_path, lambda: _serve(lm))
+    _, _, traced = _serve(lm, telemetry=Telemetry(trace_enabled=True))
+    assert plain == profiled == traced
+    assert all(len(toks) == 4 for toks in plain.values())
+
+
+def test_trained_parameters_bit_identical_with_spans_read_and_unread(tmp_path):
+    def leaves(model):
+        return [np.asarray(w) for w in jax.tree_util.tree_leaves(model.params)]
+
+    plain = leaves(_fit())
+    profiled = leaves(_profiled(tmp_path, _fit)[0])
+    traced = leaves(_fit(telemetry=Telemetry(trace_enabled=True)))
+    assert len(plain) == len(profiled) == len(traced) > 0
+    for a, b, c in zip(plain, profiled, traced):
+        assert a.tobytes() == b.tobytes() == c.tobytes()
+
+
+# -- counts at the same boundaries -----------------------------------------------
+
+
+def test_sync_and_byte_counts_equal_hand_counts_on_a_scripted_run(lm):
+    sched, engine, streams = _serve(lm)
+    st = sched.stats
+    spec = engine.cache.spec
+    # two prefill batches: requests 0 and 1 together, then request 2
+    # once a slot is free
+    assert st.prefill_batches == 2
+    batches = ([SCRIPT[0], SCRIPT[1]], [SCRIPT[2]])
+    assert st.prefill_tokens_real == sum(len(p) for b in batches for p in b) == 10
+    assert st.prefill_tokens_padded == sum(
+        spec.max_seqs * spec.bucket(max(len(p) for p in b)) for b in batches
+    )
+    assert st.prefill_tokens_padded >= 2 * spec.max_seqs * 5
+    # per prefill: the tokens and the last logits of its n prompts; per
+    # decode step: the wait, then tokens [max_seqs] and logits [max_seqs, V]
+    assert st.host_syncs == st.decode_steps
+    assert st.device_syncs == 2 * st.prefill_batches + 3 * st.decode_steps
+    tok, logit = 4, 4 * VOCAB  # int32 token, float32 logits row
+    assert st.readback_bytes == (
+        (2 + 1) * (tok + logit)
+        + st.decode_steps * spec.max_seqs * (tok + logit)
+    )
+    # the stats mirror the engine's ledgers, and every token is there
+    assert st.device_syncs == engine.device_syncs
+    assert st.readback_bytes == engine.readback_bytes
+    assert sum(len(t) for t in streams.values()) == st.tokens_generated == 12
+    # every field is a serve_stats_* gauge for the operator
+    gauge = st._registry.get("serve_stats_device_syncs")
+    assert gauge is not None and gauge.value == st.device_syncs
+
+
+def test_dense_decode_path_has_no_wait_and_one_sync_fewer_per_step(lm, tmp_path):
+    """`decode_kernel="dense"` does not force its outputs in `_dispatch`:
+    no `decode.wait` span, and the readback is the only blocking read."""
+    (sched, engine, _), events = _profiled(
+        tmp_path, lambda: _serve(lm, decode_kernel="dense")
+    )
+    names = {e[0] for e in _ours(events, ("scheduler.",))}
+    assert names == SERVE_SPANS - {STEP + "decode.wait"}
+    st = sched.stats
+    assert st.device_syncs == 2 * st.prefill_batches + 2 * st.decode_steps

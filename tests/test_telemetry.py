@@ -353,7 +353,8 @@ def test_async_trace_shows_dispatch_overlapping_reconcile(async_run):
     # and the host dispatch span of the NEXT iteration sits inside an
     # earlier step's open window
     disp = [
-        e for e in doc["traceEvents"] if e.get("name") == "dispatch:decode"
+        e for e in doc["traceEvents"]
+        if e.get("name") == "scheduler.step.decode.dispatch"
     ]
     assert any(
         t0 <= e["ts"] < t1
