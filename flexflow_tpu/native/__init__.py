@@ -111,12 +111,14 @@ def _declare(lib: ctypes.CDLL):
     lib.ffn_loader_create.argtypes = [
         ctypes.POINTER(ctypes.c_void_p), i64p,
         ctypes.c_int32, ctypes.c_int64, ctypes.c_int64, i64p,
-        ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_int32, ctypes.POINTER(ctypes.c_void_p),
     ]
     lib.ffn_loader_num_batches.restype = ctypes.c_int64
     lib.ffn_loader_num_batches.argtypes = [ctypes.c_void_p]
-    lib.ffn_loader_next.restype = ctypes.c_int64
-    lib.ffn_loader_next.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p)]
+    lib.ffn_loader_borrow.restype = ctypes.c_int64
+    lib.ffn_loader_borrow.argtypes = [ctypes.c_void_p]
+    lib.ffn_loader_release.restype = None
+    lib.ffn_loader_release.argtypes = [ctypes.c_void_p, ctypes.c_int64]
     lib.ffn_loader_reset.restype = None
     lib.ffn_loader_reset.argtypes = [ctypes.c_void_p, i64p]
     lib.ffn_loader_destroy.restype = None
@@ -471,6 +473,18 @@ def _py_simulate(resource_of, duration, edges, num_resources):
 # -- data loader --------------------------------------------------------------
 
 
+def _alloc_slot(shape, dtype) -> np.ndarray:
+    """One reusable batch buffer, on a 64-byte boundary. Where the slot
+    sits decides nothing about correctness (a backend that keeps host
+    memory it was handed is found out by looking, see
+    `SingleDataLoader.lend`); a fixed boundary makes which way a backend
+    goes the same in every run."""
+    nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    raw = np.empty(nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    return raw[start : start + nbytes].view(dtype).reshape(shape)
+
+
 class NativeLoader:
     """Background-threaded shuffle/batch/prefetch loader (reference:
     SingleDataLoader, python/flexflow_dataloader.h:34). Falls back to
@@ -478,7 +492,12 @@ class NativeLoader:
 
     The epoch permutation is always drawn from numpy's seeded RNG here in
     Python and handed to the C++ side, so the batch stream for a given seed
-    is identical whether or not the native library loaded."""
+    is identical whether or not the native library loaded.
+
+    Batches are gathered into a ring of `prefetch_depth` slots that this
+    object owns and reuses. `next_batch()` copies a slot out and the
+    caller owns the copy; `borrow()` lends the slot itself, until
+    `release()`."""
 
     def __init__(
         self,
@@ -487,7 +506,8 @@ class NativeLoader:
         shuffle: bool = True,
         seed: int = 0,
         drop_last: bool = True,
-        prefetch_depth: int = 2,
+        prefetch_depth: int = 3,
+        use_lib: bool = True,
     ):
         self.arrays = [np.ascontiguousarray(a) for a in arrays]
         n = self.arrays[0].shape[0]
@@ -498,20 +518,27 @@ class NativeLoader:
         self.shuffle = shuffle
         self.seed = seed
         self.drop_last = drop_last
+        self.depth = max(1, int(prefetch_depth))
         self._handle = None
-        self._lib = get_lib()
+        self._lib = get_lib() if use_lib else None
         self._perm = self._make_perm(seed)
+        self._slots = None  # [depth][array]; the fallback makes them on first use
+        self._lent = set()  # batch indices of this epoch whose slot is out
         if self._lib is not None:
+            self._slots = self._make_slots()
             ptrs = (ctypes.c_void_p * len(self.arrays))(
-                *[a.ctypes.data_as(ctypes.c_void_p).value for a in self.arrays]
+                *[a.ctypes.data for a in self.arrays]
             )
             row_bytes = (ctypes.c_int64 * len(self.arrays))(
                 *[a.nbytes // n for a in self.arrays]
             )
+            slot_ptrs = (ctypes.c_void_p * (self.depth * len(self.arrays)))(
+                *[buf.ctypes.data for slot in self._slots for buf in slot]
+            )
             self._handle = self._lib.ffn_loader_create(
                 ptrs, row_bytes, len(self.arrays), n, batch_size,
                 self._perm.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-                1 if drop_last else 0, prefetch_depth,
+                1 if drop_last else 0, self.depth, slot_ptrs,
             )
         self._pos = 0
 
@@ -521,6 +548,15 @@ class NativeLoader:
             np.random.RandomState(seed).shuffle(idx)
         return np.ascontiguousarray(idx)
 
+    def _make_slots(self) -> List[List[np.ndarray]]:
+        return [
+            [
+                _alloc_slot((self.batch_size,) + a.shape[1:], a.dtype)
+                for a in self.arrays
+            ]
+            for _ in range(self.depth)
+        ]
+
     @property
     def num_batches(self) -> int:
         n = self.arrays[0].shape[0]
@@ -528,41 +564,74 @@ class NativeLoader:
             return int(self._lib.ffn_loader_num_batches(self._handle))
         return n // self.batch_size if self.drop_last else -(-n // self.batch_size)
 
-    def next_batch(self) -> Optional[List[np.ndarray]]:
-        """Returns per-array [batch_size, ...] copies, or None at epoch end."""
-        if self._handle is not None:
-            ptrs = (ctypes.c_void_p * len(self.arrays))()
-            idx = self._lib.ffn_loader_next(self._handle, ptrs)
-            if idx < 0:
-                return None
-            out = []
-            for a, p in zip(self.arrays, ptrs):
-                shape = (self.batch_size,) + a.shape[1:]
-                buf = np.ctypeslib.as_array(
-                    ctypes.cast(p, ctypes.POINTER(ctypes.c_uint8)),
-                    shape=(int(np.prod(shape)) * a.itemsize,),
-                )
-                out.append(buf.view(a.dtype).reshape(shape).copy())
-            return out
-        if self._pos >= self.num_batches:
-            return None
-        b = self._pos
-        self._pos += 1
-        rows = self._perm[b * self.batch_size : (b + 1) * self.batch_size]
+    def _gather(self) -> int:
+        """The fallback's gather, on the caller's thread: the next batch
+        into its slot. Returns its index, -1 at epoch end, -2 when that
+        slot is still lent (as ffn_loader_borrow does)."""
+        idx = self._pos
+        if idx >= self.num_batches:
+            return -1
+        if idx - self.depth in self._lent:
+            return -2
+        rows = self._perm[idx * self.batch_size : (idx + 1) * self.batch_size]
         if len(rows) < self.batch_size:  # pad short final batch
             rows = np.concatenate(
                 [rows, np.repeat(rows[:1], self.batch_size - len(rows))]
             )
-        return [a[rows] for a in self.arrays]
+        if self._slots is None:
+            self._slots = self._make_slots()
+        for a, buf in zip(self.arrays, self._slots[idx % self.depth]):
+            # mode="clip": numpy stages a checked take in a block of its
+            # own before it writes `out`; rows are always in range
+            np.take(a, rows, axis=0, out=buf, mode="clip")
+        self._pos += 1
+        return idx
+
+    def borrow(self) -> Optional[Tuple[int, List[np.ndarray]]]:
+        """The next batch, lent: (its index, per-array [batch_size, ...]
+        views of a slot this loader reuses), or None at epoch end. The
+        views hold the batch until `release(index)` or a reset; at most
+        `depth` batches can be out at once."""
+        if self._handle is not None:
+            idx = int(self._lib.ffn_loader_borrow(self._handle))
+        else:
+            idx = self._gather()
+        if idx == -1:
+            return None
+        if idx == -2:
+            raise RuntimeError(
+                f"all {self.depth} batch slots are lent; release one first"
+            )
+        self._lent.add(idx)
+        return idx, self._slots[idx % self.depth]
+
+    def release(self, index: int):
+        """Hand batch `index`'s slot back: whatever read the views is done."""
+        if index in self._lent:
+            self._lent.discard(index)
+            if self._handle is not None:
+                self._lib.ffn_loader_release(self._handle, index)
+
+    def next_batch(self) -> Optional[List[np.ndarray]]:
+        """Returns per-array [batch_size, ...] copies, or None at epoch end."""
+        got = self.borrow()
+        if got is None:
+            return None
+        index, views = got
+        out = [v.copy() for v in views]
+        self.release(index)
+        return out
 
     def reset(self, seed: Optional[int] = None):
         seed = self.seed if seed is None else seed
         self.reset_perm(self._make_perm(seed))
 
     def reset_perm(self, perm: np.ndarray):
-        """New epoch with an explicit sample order (len == num_samples)."""
+        """New epoch with an explicit sample order (len == num_samples).
+        Takes every lent slot back."""
         self._perm = np.ascontiguousarray(perm, dtype=np.int64)
         self._pos = 0
+        self._lent.clear()
         if self._handle is not None:
             self._lib.ffn_loader_reset(
                 self._handle,

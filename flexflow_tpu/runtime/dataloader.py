@@ -3,25 +3,52 @@ SingleDataLoader keeps the full dataset in zero-copy memory and
 index-launches per-shard batch copies; SURVEY §2.7).
 
 TPU-native version: the dataset lives in host RAM as numpy arrays; each
-`next_batch` slices a global batch and `jax.device_put`s it with the input's
+step takes a global batch and `jax.device_put`s it with the input's
 NamedSharding, so each chip receives exactly its shard (the same
 host→device movement pattern, without the Legion tasks). Batch assembly
 (shuffle + row gather) runs on the native threaded loader
 (native/src/dataloader.cc via flexflow_tpu.native.NativeLoader) when the
 C++ core is available, so the next batch is prefetched while the chip is
 still executing the current step — the role the reference's background
-CPU load tasks played."""
+CPU load tasks played. The gather writes into a ring of reused slots, and
+`fit()` transfers straight out of the slot (`borrow_batch` / `lend`):
+after the gather no batch is copied again on the host."""
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import time
 from typing import Dict, Optional
 
 import numpy as np
 
 
+def _aliases(placed, view: np.ndarray) -> bool:
+    """Whether the device array `placed` IS the host memory of `view`.
+    Only a backend whose device memory is the host's can do that (the CPU
+    backend does, for a buffer on a 64-byte boundary), so the platform is
+    asked first: reading a buffer's address on an accelerator would wait
+    for the transfer."""
+    shards = getattr(placed, "addressable_shards", None)
+    if not shards or shards[0].device.platform != "cpu":
+        return False
+    lo = view.ctypes.data
+    return any(
+        lo <= s.data.unsafe_buffer_pointer() < lo + view.nbytes for s in shards
+    )
+
+
 class SingleDataLoader:
     """Full-dataset-resident loader with sequential batches
-    (reference: flexflow_dataloader.h:34-107)."""
+    (reference: flexflow_dataloader.h:34-107).
+
+    Two ways to take a batch. `next_batch()` returns arrays the caller
+    owns. `borrow_batch()` lends views of a slot the loader reuses, which
+    is what `fit()` does: it places the views on the device, tells the
+    loader what it made (`lend`), and the loader refills that slot only
+    after those device arrays are ready, so no batch-sized block is
+    allocated or copied on the host per step."""
 
     def __init__(
         self,
@@ -43,29 +70,32 @@ class SingleDataLoader:
         self._rng = np.random.RandomState(seed)
         self._order = np.arange(self.num_samples)
         self._pos = 0
-        self._native = None
-        # Native prefetch path: only for full-batch epochs (drop_last) so
-        # both paths produce identical batch shapes, and only when at least
-        # one full batch exists. The permutation always comes from this
-        # object's numpy RNG, so batches are bit-identical with or without
-        # the native library.
-        if (
-            use_native
-            and drop_last
-            and self.num_samples >= batch_size
-        ):
+        self._keys = list(arrays.keys())
+        self._ring = None
+        # leases: (batch index, the device arrays made from its slot),
+        # oldest first, and the batch borrowed but not yet lent
+        self._out = collections.deque()
+        self._pending = None
+        self.batches_borrowed = 0
+        self.batches_copied = 0
+        self.lease_wait_s = 0.0
+        # The ring of reused slots (and, with the C++ core, the worker
+        # thread that gathers ahead into it): only for full-batch epochs
+        # (drop_last) so that every batch has the slot's shape, and only
+        # when at least one full batch exists. The permutation always
+        # comes from this object's numpy RNG, so batches are
+        # bit-identical with or without the native library.
+        if drop_last and self.num_samples >= batch_size:
             from flexflow_tpu import native as _native_mod
 
-            if _native_mod.available():
-                self._keys = list(arrays.keys())
-                self._native = _native_mod.NativeLoader(
-                    [arrays[k] for k in self._keys],
-                    batch_size,
-                    shuffle=False,  # order supplied via reset_perm below
-                    seed=seed,
-                    drop_last=drop_last,
-                )
-                self._native.reset_perm(self._order)
+            self._ring = _native_mod.NativeLoader(
+                [arrays[k] for k in self._keys],
+                batch_size,
+                shuffle=False,  # identity, as `_order`; reset() supplies the rest
+                seed=seed,
+                drop_last=drop_last,
+                use_lib=use_native,
+            )
 
     @property
     def num_batches(self) -> int:
@@ -77,15 +107,19 @@ class SingleDataLoader:
         self._pos = 0
         if self.shuffle:
             self._rng.shuffle(self._order)
-        if self._native is not None:
-            self._native.reset_perm(self._order)
+        if self._ring is not None:
+            # the ring rewinds under every lease: end them first
+            self._pending = None
+            while self._out:
+                self._return_oldest()
+            self._ring.reset_perm(self._order)
 
     def next_batch(self) -> Dict[str, np.ndarray]:
-        if self._native is not None:
-            bufs = self._native.next_batch()
+        if self._ring is not None:
+            bufs = self._ring.next_batch()
             if bufs is None:  # epoch rollover
                 self.reset()
-                bufs = self._native.next_batch()
+                bufs = self._ring.next_batch()
             return dict(zip(self._keys, bufs))
         remaining = self.num_samples - self._pos
         if remaining < self.batch_size and (self.drop_last or remaining == 0):
@@ -96,6 +130,74 @@ class SingleDataLoader:
         idx = self._order[self._pos : self._pos + take]
         self._pos += take
         return {k: v[idx] for k, v in self.arrays.items()}
+
+    # -- lending -------------------------------------------------------------
+
+    def _return_oldest(self, waiting=contextlib.nullcontext):
+        import jax
+
+        index, placed = self._out.popleft()
+        with waiting():
+            t0 = time.perf_counter()
+            jax.block_until_ready(placed)
+            self.lease_wait_s += time.perf_counter() - t0
+        self._ring.release(index)
+
+    def borrow_batch(self, waiting=contextlib.nullcontext) -> Dict[str, np.ndarray]:
+        """The next batch as views of a slot the loader reuses. Place them
+        on the device and pass the result to `lend` before asking for
+        another. One transfer stays in flight, the batch lent just before
+        this call; older ones had a whole step to finish, and their slots
+        go back to the ring here, after a wait (entered through
+        `waiting`, a context manager factory) for their device arrays.
+        Without a ring (`drop_last=False`) the arrays are the caller's."""
+        if self._ring is None:
+            self.batches_copied += 1
+            return self.next_batch()
+        if self._pending is not None:  # borrowed and never lent: unread
+            self._ring.release(self._pending[0])
+            self._pending = None
+        while len(self._out) > max(0, self._ring.depth - 2):
+            self._return_oldest(waiting)
+        got = self._ring.borrow()
+        if got is None:  # epoch rollover
+            self.reset()
+            got = self._ring.borrow()
+        self._pending = got
+        return dict(zip(self._keys, got[1]))
+
+    def lend(self, placed: Dict[str, object]) -> Dict[str, object]:
+        """`placed` is what the caller made on the device from the last
+        borrowed batch (name -> array). Its slot is refilled only after
+        every one of them is ready. Returns what to use in their place:
+        the same arrays, except that one the backend made by keeping the
+        slot's memory instead of copying it is replaced by a copy of its
+        own, since the slot will be overwritten while the array lives."""
+        if self._pending is None:
+            return placed
+        from flexflow_tpu.runtime.multihost import place_array
+
+        index, views = self._pending
+        self._pending = None
+        placed = dict(placed)
+        kept = False
+        for name, view in zip(self._keys, views):
+            arr = placed.get(name)
+            if arr is not None and _aliases(arr, view):
+                placed[name] = place_array(view.copy(), arr.sharding)
+                kept = True
+        self.batches_copied += kept
+        self.batches_borrowed += not kept
+        self._out.append((index, list(placed.values())))
+        return placed
+
+    def take_counts(self):
+        """(batches borrowed, batches copied, seconds waited for leases)
+        since the last call."""
+        got = (self.batches_borrowed, self.batches_copied, self.lease_wait_s)
+        self.batches_borrowed = self.batches_copied = 0
+        self.lease_wait_s = 0.0
+        return got
 
     def __iter__(self):
         self.reset()

@@ -1049,6 +1049,9 @@ class FFModel:
         loader = SingleDataLoader(arrays, batch_size, shuffle=shuffle)
         step = self.executor.train_step()
 
+        def lease_wait():
+            return span("train.input.next_batch.lease_wait", tracer)
+
         history = []
         warm = False
         early_stop = False
@@ -1072,10 +1075,12 @@ class FFModel:
             for it in range(loader.num_batches):
                 for cb in callbacks:
                     cb.on_batch_begin(it)
+                # the batch is views of the loader's slot, lent until the
+                # arrays placed from it are ready (dataloader.py)
                 with span("train.input.next_batch", tracer):
-                    np_batch = loader.next_batch()
+                    np_batch = loader.borrow_batch(lease_wait)
                 with span("train.input.shard_batch", tracer):
-                    batch = self.executor.shard_batch(np_batch)
+                    batch = loader.lend(self.executor.shard_batch(np_batch))
                 with span("train.input.dispatch", tracer):
                     self._rng, key = jax.random.split(self._rng)
                     self.params, self.opt_state, loss, mets = step(
@@ -1133,7 +1138,7 @@ class FFModel:
             if tele is not None:
                 train_iters = self._record_training_epoch(
                     tele, epoch, epoch_t0, stamps, sample_counts, losses,
-                    train_iters,
+                    train_iters, loader,
                 )
             history.append({"epoch": epoch, "throughput": thpt, **perf.__dict__})
             if verbose:
@@ -1161,7 +1166,7 @@ class FFModel:
 
     def _record_training_epoch(
         self, tele, epoch, epoch_t0, stamps, sample_counts, losses,
-        train_iters,
+        train_iters, loader,
     ) -> int:
         """Materialize one epoch's telemetry AFTER the epoch-end device
         sync: per-iteration train_* gauges + counters, one JSONL sample
@@ -1200,6 +1205,25 @@ class FFModel:
             help="cached step callables dropped (seq-length change, "
             "LR rebind)",
         )
+        # how the epoch's batches reached the device
+        c_borrowed = reg.counter(
+            "train_input_batches_borrowed",
+            help="batches transferred straight out of the loader's slot",
+        )
+        c_copied = reg.counter(
+            "train_input_batches_copied",
+            help="batches copied on the host before the transfer "
+            "(the backend would have kept the slot's memory)",
+        )
+        g_lease = reg.gauge(
+            "train_input_lease_wait_ms",
+            help="mean wait per batch for a lent slot's transfer, "
+            "last epoch",
+        )
+        borrowed, copied, lease_wait_s = loader.take_counts()
+        c_borrowed.inc(borrowed)
+        c_copied.inc(copied)
+        g_lease.set(1e3 * lease_wait_s / max(len(stamps), 1))
         tracer = tele.tracer
         g_epoch.set(epoch)
         prev = epoch_t0
@@ -1240,10 +1264,12 @@ class FFModel:
         loader = SingleDataLoader(arrays, batch_size)
         estep = self.executor.eval_step()
         perf = PerfMetrics()
-        for it, batch in enumerate(loader):
+        loader.reset()
+        for it in range(loader.num_batches):
             for cb in callbacks:
                 cb.on_batch_begin(it)
-            b = self.executor.shard_batch(batch)
+            # lent and placed as in fit()
+            b = loader.lend(self.executor.shard_batch(loader.borrow_batch()))
             loss, mets = estep(self.params, b)
             perf.update(jax.tree_util.tree_map(float, mets), float(loss))
             for cb in callbacks:

@@ -8,13 +8,14 @@
 // batch buffers, and background prefetch so the accelerator never waits on
 // Python-side batch assembly.
 //
-// Ownership: the caller keeps the source arrays alive for the loader's
-// lifetime. Batch buffers are owned by the loader and reused; a slot
-// returned by ffn_loader_next stays valid until the next
-// ffn_loader_next/reset call. The Python wrapper copies the slot into a
-// caller-owned array (its public API makes no lifetime promise); the
-// prefetch win is that the row gather ran on this thread while the
-// accelerator executed the previous step.
+// Ownership: the caller keeps the source arrays AND the batch buffers
+// (the ring of slots, `depth` x `num_arrays` pointers handed to
+// ffn_loader_create) alive for the loader's lifetime. A slot is lent, not
+// copied: ffn_loader_borrow hands the caller the slot that holds the next
+// batch, and this thread writes to it again only after
+// ffn_loader_release (or a reset). The caller may hold several slots at
+// once, for as long as a device transfer reads them; the worker gathers
+// ahead into whatever slots are free.
 
 #include <condition_variable>
 #include <cstdint>
@@ -26,10 +27,10 @@
 
 namespace {
 
-struct Batch {
-  std::vector<std::vector<uint8_t>> buffers;  // one per array
-  int64_t index = -1;   // batch index within the epoch
-  bool ready = false;
+struct Slot {
+  std::vector<uint8_t*> buffers;  // one per array, the caller's memory
+  int64_t index = -1;             // batch index within the epoch
+  enum { FREE, READY, LENT } state = FREE;
 };
 
 struct Loader {
@@ -45,15 +46,20 @@ struct Loader {
   std::vector<int64_t> perm;
   int64_t num_batches = 0;
 
-  std::vector<Batch> slots;
+  // Batch i lives in slot i % slots.size(): FREE until the worker has
+  // gathered it, READY until the caller borrows it, LENT until released.
+  std::vector<Slot> slots;
   int64_t produced = 0;  // next batch index the worker will fill
-  int64_t consumed = 0;  // next batch index the caller will take
-  bool handed_out = false;  // caller still owns the last returned slot
-  bool filling = false;     // worker is copying outside the lock
+  int64_t taken = 0;     // next batch index the caller will borrow
+  bool filling = false;  // worker is copying outside the lock
   bool stop = false;
   std::thread worker;
   std::mutex mu;
   std::condition_variable cv_produce, cv_consume;
+
+  Slot& slot_of(int64_t batch_idx) {
+    return slots[(size_t)(batch_idx % (int64_t)slots.size())];
+  }
 
   void set_perm(const int64_t* p) {
     perm.resize(num_samples);
@@ -63,13 +69,12 @@ struct Loader {
       std::iota(perm.begin(), perm.end(), 0);
   }
 
-  void fill(Batch* b, int64_t batch_idx) {
+  void fill(Slot* s, int64_t batch_idx) {
     int64_t begin = batch_idx * batch_size;
     int64_t rows = std::min(batch_size, num_samples - begin);
     for (size_t a = 0; a < arrays.size(); ++a) {
       int64_t rb = row_bytes[a];
-      b->buffers[a].resize((size_t)(batch_size * rb));
-      uint8_t* dst = b->buffers[a].data();
+      uint8_t* dst = s->buffers[a];
       for (int64_t r = 0; r < rows; ++r)
         std::memcpy(dst + r * rb, arrays[a] + perm[begin + r] * rb,
                     (size_t)rb);
@@ -77,8 +82,6 @@ struct Loader {
       for (int64_t r = rows; r < batch_size; ++r)
         std::memcpy(dst + r * rb, arrays[a] + perm[begin] * rb, (size_t)rb);
     }
-    b->index = batch_idx;
-    b->ready = true;
   }
 
   void run() {
@@ -86,19 +89,21 @@ struct Loader {
       std::unique_lock<std::mutex> lk(mu);
       cv_produce.wait(lk, [&] {
         return stop || (produced < num_batches &&
-                        produced - consumed < (int64_t)slots.size());
+                        slot_of(produced).state == Slot::FREE);
       });
       if (stop) return;
       int64_t idx = produced;
-      Batch* slot = &slots[idx % slots.size()];
+      Slot* slot = &slot_of(idx);
       filling = true;
       lk.unlock();
       fill(slot, idx);
       lk.lock();
       filling = false;
-      // A reset may have rewound `produced` while we copied; only publish
-      // if this fill still corresponds to the expected next batch.
-      if (produced == idx) produced++;
+      // A reset waits for `filling` to clear, so it runs after this
+      // publish and rewinds it with everything else.
+      slot->index = idx;
+      slot->state = Slot::READY;
+      produced++;
       cv_consume.notify_all();
     }
   }
@@ -110,11 +115,14 @@ extern "C" {
 
 // arrays[i] points at num_samples rows of row_bytes[i] bytes each.
 // perm (nullable -> identity) gives the epoch's sample order.
+// slot_ptrs[s * num_arrays + i] points at batch_size * row_bytes[i]
+// writable bytes: array i's buffer in slot s, for s < depth.
 void* ffn_loader_create(const void** arrays, const int64_t* row_bytes,
                         int32_t num_arrays, int64_t num_samples,
                         int64_t batch_size, const int64_t* perm,
-                        int32_t drop_last, int32_t prefetch_depth) {
-  if (num_arrays <= 0 || num_samples <= 0 || batch_size <= 0) return nullptr;
+                        int32_t drop_last, int32_t depth, void** slot_ptrs) {
+  if (num_arrays <= 0 || num_samples <= 0 || batch_size <= 0 || depth <= 0)
+    return nullptr;
   Loader* L = new Loader();
   for (int32_t i = 0; i < num_arrays; ++i) {
     L->arrays.push_back((const uint8_t*)arrays[i]);
@@ -126,9 +134,11 @@ void* ffn_loader_create(const void** arrays, const int64_t* row_bytes,
   L->num_batches = drop_last ? num_samples / batch_size
                              : (num_samples + batch_size - 1) / batch_size;
   L->set_perm(perm);
-  int32_t depth = prefetch_depth < 1 ? 1 : prefetch_depth;
   L->slots.resize((size_t)depth);
-  for (auto& s : L->slots) s.buffers.resize((size_t)num_arrays);
+  for (int32_t s = 0; s < depth; ++s)
+    for (int32_t i = 0; i < num_arrays; ++i)
+      L->slots[(size_t)s].buffers.push_back(
+          (uint8_t*)slot_ptrs[s * num_arrays + i]);
   L->worker = std::thread([L] { L->run(); });
   return L;
 }
@@ -137,29 +147,36 @@ int64_t ffn_loader_num_batches(void* loader) {
   return ((Loader*)loader)->num_batches;
 }
 
-// Blocks until the next batch is prefetched; writes per-array buffer
-// pointers into out_ptrs. Returns the batch index, or -1 at epoch end.
-// The returned buffers stay valid until the NEXT ffn_loader_next/reset
-// call — the slot is only recycled once the caller asks for more.
-int64_t ffn_loader_next(void* loader, void** out_ptrs) {
+// Blocks until the next batch is gathered and lends its slot to the
+// caller. Returns the batch index (its slot is index % depth), -1 at
+// epoch end, or -2 when the slot this batch needs is still lent: the
+// caller would be waiting for itself.
+int64_t ffn_loader_borrow(void* loader) {
   Loader* L = (Loader*)loader;
   std::unique_lock<std::mutex> lk(L->mu);
-  if (L->handed_out) {  // release the previously returned slot
-    L->handed_out = false;
-    L->consumed++;
-    L->cv_produce.notify_all();
-  }
-  if (L->consumed >= L->num_batches) return -1;
-  int64_t idx = L->consumed;
-  L->cv_consume.wait(lk, [&] { return L->produced > idx; });
-  Batch& b = L->slots[idx % L->slots.size()];
-  for (size_t a = 0; a < L->arrays.size(); ++a)
-    out_ptrs[a] = b.buffers[a].data();
-  L->handed_out = true;
-  return idx;
+  if (L->taken >= L->num_batches) return -1;
+  Slot& s = L->slot_of(L->taken);
+  if (s.state == Slot::LENT) return -2;
+  L->cv_consume.wait(lk, [&] { return s.state == Slot::READY; });
+  s.state = Slot::LENT;
+  return L->taken++;
 }
 
-// New epoch: install the caller's new sample order and restart prefetching.
+// The caller has finished reading batch `batch_idx`'s slot (every
+// transfer out of it has completed): the worker may gather into it again.
+void ffn_loader_release(void* loader, int64_t batch_idx) {
+  Loader* L = (Loader*)loader;
+  std::unique_lock<std::mutex> lk(L->mu);
+  Slot& s = L->slot_of(batch_idx);
+  if (s.state == Slot::LENT && s.index == batch_idx) {
+    s.state = Slot::FREE;
+    L->cv_produce.notify_all();
+  }
+}
+
+// New epoch: install the caller's new sample order and restart
+// prefetching from batch 0. Every lent slot is taken back: the caller
+// has to be done with all of them.
 void ffn_loader_reset(void* loader, const int64_t* perm) {
   Loader* L = (Loader*)loader;
   std::unique_lock<std::mutex> lk(L->mu);
@@ -168,9 +185,11 @@ void ffn_loader_reset(void* loader, const int64_t* perm) {
   L->cv_consume.wait(lk, [&] { return !L->filling; });
   L->set_perm(perm);
   L->produced = 0;
-  L->consumed = 0;
-  L->handed_out = false;
-  for (auto& s : L->slots) s.ready = false;
+  L->taken = 0;
+  for (auto& s : L->slots) {
+    s.state = Slot::FREE;
+    s.index = -1;
+  }
   L->cv_produce.notify_all();
 }
 

@@ -47,6 +47,7 @@ SLOTS = 4
 TRAIN_SPANS = {
     "train.epoch_end.reset",
     "train.input.next_batch",
+    "train.input.next_batch.lease_wait",
     "train.input.shard_batch",
     "train.input.shard_batch.x",
     "train.input.shard_batch.label",
@@ -65,6 +66,7 @@ SERVE_SPANS = {
 }
 #: child -> the span it has to sit inside
 PARENTS = {
+    "train.input.next_batch.lease_wait": "train.input.next_batch",
     "train.input.shard_batch.x": "train.input.shard_batch",
     "train.input.shard_batch.label": "train.input.shard_batch",
     STEP + "prefill.pack": STEP + "admit",
@@ -202,6 +204,9 @@ def test_fit_under_a_profiler_session_yields_exactly_the_named_spans(tmp_path):
     count = {n: sum(1 for e in ours if e[0] == n) for n in TRAIN_SPANS}
     # 2 epochs of 3 batches: one of each per step, one of each per epoch
     assert count["train.input.next_batch"] == 6
+    # the third batch of an epoch takes back the first one's slot; the
+    # other two leases end at the epoch's turn, under the reset
+    assert count["train.input.next_batch.lease_wait"] == 2
     assert count["train.input.shard_batch.label"] == 6
     assert count["train.input.dispatch"] == 6
     assert count["train.epoch_end.drain"] == 2
