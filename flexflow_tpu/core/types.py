@@ -149,6 +149,13 @@ class OperatorType(enum.Enum):
     PIPELINE = enum.auto()
     ALLTOALL = enum.auto()  # TPU-native addition: sequence/expert all-to-all
 
+    # Appended, so that every value above stays what it was. The blocks of
+    # the decoder LMs people deploy (no reference counterpart): RMSNorm,
+    # and a dropless top-k expert layer as ONE operator (router, sort by
+    # expert, grouped gated MLP, weighted sum — ops/moe.py)
+    RMSNORM = enum.auto()
+    SPARSE_MOE = enum.auto()
+
 
 PARALLEL_OP_TYPES = frozenset(
     {

@@ -15,6 +15,7 @@ from flexflow_tpu.models.nlp import (
     build_bert_proxy,
     build_decoder_lm,
     build_mt5_encoder,
+    build_olmoe,
     build_transformer_encoder,
 )
 from flexflow_tpu.models.recommender import build_candle_uno, build_dlrm, build_xdl
@@ -30,6 +31,7 @@ __all__ = [
     "build_bert_proxy",
     "build_decoder_lm",
     "build_mt5_encoder",
+    "build_olmoe",
     "build_dlrm",
     "build_xdl",
     "build_candle_uno",

@@ -1,5 +1,5 @@
 """NLP workloads: Transformer encoder (the flagship bench), BERT proxy,
-mT5-style encoder."""
+mT5-style encoder, and the served decoders (GPT-style, OLMoE)."""
 
 from __future__ import annotations
 
@@ -98,3 +98,39 @@ def build_decoder_lm(
         m = ff.dense(m, hidden, use_bias=False)
         t = ff.add(t, m)
     return ff.dense(ff.layer_norm(t), vocab_size, use_bias=False)
+
+
+def build_olmoe(
+    ff,
+    token_ids,
+    vocab_size: int = 50304,
+    hidden: int = 2048,
+    num_heads: int = 16,
+    num_layers: int = 16,
+    expert_hidden: int = 1024,
+    num_experts: int = 64,
+    experts_per_token: int = 8,
+    rope_theta: float = 10000.0,
+    eps: float = 1e-5,
+    renormalise: bool = False,
+):
+    """OLMoE (allenai/OLMoE-1B-7B): pre-RMSNorm blocks of causal attention
+    with QK-norm and rotary positions and as many key heads as query
+    heads, then a dropless top-k expert layer of SiLU-gated MLPs (no
+    shared expert, top-k weights not renormalised); a final RMSNorm and
+    an untied head. No biases. Served like build_decoder_lm: vocab
+    logits, one token-id input."""
+    t = ff.embedding(token_ids, vocab_size, hidden)
+    for _ in range(num_layers):
+        h = ff.rms_norm(t, eps=eps)
+        a = ff.multihead_attention(
+            h, h, h, hidden, num_heads, bias=False, causal=True,
+            rope_theta=rope_theta, qk_norm=True, qk_norm_eps=eps,
+        )
+        t = ff.add(t, a)
+        m = ff.sparse_moe(
+            ff.rms_norm(t, eps=eps), num_experts, experts_per_token,
+            expert_hidden, renormalise=renormalise,
+        )
+        t = ff.add(t, m)
+    return ff.dense(ff.rms_norm(t, eps=eps), vocab_size, use_bias=False)

@@ -70,6 +70,11 @@ def _infer_mha(input_shapes, params):
         bqkv = ParallelTensorShape((head, ParallelDim(head_dim)), dtype)
         bo = ParallelTensorShape((ParallelDim(embed_dim),), dtype)
         weights += [bqkv, bqkv, bqkv, bo]
+    if params.get("qk_norm", False):
+        # learned gains of the q and k RMSNorms, over the whole projection
+        # (all heads): stored in head space so that they shard with the heads
+        gain = ParallelTensorShape((head, ParallelDim(head_dim)), dtype)
+        weights += [gain, gain]
     return (out,), tuple(weights)
 
 
@@ -97,12 +102,68 @@ def scaled_dot_product_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def mha_project_qkv(ins, ws, ctx, use_bias=True):
+def rotary_cos_sin(positions, head_dim, theta):
+    """cos and sin tables [..., 1, head_dim] of the rotate-half rotary
+    embedding at integer `positions` [...]: frequency i of head_dim / 2
+    is theta ** (-2 i / head_dim), and both halves of a head carry the
+    same angles. float32 whatever the model's dtype."""
+    half = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    freqs = positions.astype(jnp.float32)[..., None] / (theta ** half)
+    angles = jnp.concatenate([freqs, freqs], axis=-1)[..., None, :]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def mha_qk_positions(q, k, ws, params, positions):
+    """QK-norm and rotary positions of an attention node whose `params`
+    ask for them, on projected q and k [b, s, h, d]: the ONE place where
+    the trainer's lowering and every serving step make a query or a key
+    depend on where it stands. `positions` [s] or [b, s] int32 is the
+    position of each row in ITS sequence (the cache row it is written
+    to, or for a draft tree its depth below the committed prefix); None
+    means 0..s-1, the trainer's. The q/k gains are the last two weights.
+    A node with neither parameter returns q and k as they came, and
+    adds nothing to the program."""
+    if params.get("qk_norm", False):
+        from flexflow_tpu.ops.core_ops import rms_normalize
+
+        eps = params.get("qk_norm_eps", 1e-5)
+        with jax.named_scope("attn.qk_norm"):
+            # statistics over the whole projection (heads x head_dim),
+            # before the split into heads means anything
+            q = rms_normalize(q, ws[-2], eps, axes=(-2, -1))
+            k = rms_normalize(k, ws[-1], eps, axes=(-2, -1))
+    theta = params.get("rope_theta")
+    if theta is not None:
+        if positions is None:
+            positions = jnp.arange(q.shape[1])
+        with jax.named_scope("attn.rope"):
+            cos, sin = rotary_cos_sin(positions, q.shape[-1], float(theta))
+
+            def rotate(x):
+                xf = x.astype(jnp.float32)
+                x1, x2 = jnp.split(xf, 2, axis=-1)
+                turned = jnp.concatenate([-x2, x1], axis=-1)
+                return (xf * cos + turned * sin).astype(x.dtype)
+
+            q, k = rotate(q), rotate(k)
+    return q, k
+
+
+def is_positional(params) -> bool:
+    """Whether an attention node's q and k depend on positions or on
+    weights beyond the projections (rotary, QK-norm)."""
+    return params.get("rope_theta") is not None or bool(params.get("qk_norm"))
+
+
+def mha_project_qkv(ins, ws, ctx, use_bias=True, params=None, positions=None):
     """Input projections of the MHA lowering: (xq, xk, xv) [b, s, e] ->
     (q, k, v) [b, s, h, d]. Split out of _lower_mha so the serving engine
     (flexflow_tpu.serving.engine) computes the exact same projections when
     it swaps the attention core for the KV-cache decode path — projection
-    numerics must match training bit-for-bit or cache-equivalence breaks."""
+    numerics must match training bit-for-bit or cache-equivalence breaks.
+    With the node's `params`, QK-norm and rotary positions follow the
+    projection (mha_qk_positions): the keys a serving step writes to its
+    cache are already normalised and rotated."""
     xq, xk, xv = ins
     wq, wk, wv = ws[0], ws[1], ws[2]
     xq, xk, xv, wq, wk, wv = mm_operands(ctx, xq, xk, xv, wq, wk, wv)
@@ -118,6 +179,8 @@ def mha_project_qkv(ins, ws, ctx, use_bias=True):
         q = q + bq.astype(cdt)
         k = k + bk.astype(cdt)
         v = v + bv.astype(cdt)
+    if params is not None and is_positional(params):
+        q, k = mha_qk_positions(q, k, ws, params, positions)
     return q, k, v
 
 
@@ -887,7 +950,9 @@ def _lower_mha(params):
 
     def fn(ins, ws, ctx):
         dt = ins[0].dtype
-        q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+        q, k, v = mha_project_qkv(
+            ins, ws, ctx, use_bias=use_bias, params=params
+        )
         seq = q.shape[1]
         dropping = dropout > 0.0 and ctx.train and ctx.rng is not None
         sp = None if seq_parallel == "none" else _seq_parallel_axes(ctx)

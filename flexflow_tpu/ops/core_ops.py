@@ -418,6 +418,39 @@ def _lower_layernorm(params):
 register_op(OperatorType.LAYERNORM, _infer_layernorm, _lower_layernorm)
 
 
+def _infer_rmsnorm(input_shapes, params):
+    (x,) = input_shapes
+    if x.dims[-1].degree > 1:
+        raise ValueError("rmsnorm: normalized dim may not be partitioned")
+    gain = ParallelTensorShape((ParallelDim(x.dims[-1].size),), x.dtype)
+    return (x,), (gain,)
+
+
+def rms_normalize(x, gain, eps, axes=(-1,)):
+    """x * rsqrt(mean(x^2) + eps) * gain, the mean over `axes`, with
+    float32 statistics whatever x's dtype. Shared by the RMSNORM op and
+    attention's QK-norm (ops/attention.py)."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
+    return (xf * jax.lax.rsqrt(ms + eps) * gain).astype(x.dtype)
+
+
+def _lower_rmsnorm(params):
+    eps = params.get("eps", 1e-5)
+
+    def fn(ins, ws, ctx):
+        return [rms_normalize(ins[0], ws[0], eps)]
+
+    return fn
+
+
+def _flops_rmsnorm(input_shapes, params):
+    return 4.0 * input_shapes[0].volume()
+
+
+register_op(OperatorType.RMSNORM, _infer_rmsnorm, _lower_rmsnorm, _flops_rmsnorm)
+
+
 # ---------------------------------------------------------------------------
 # Embedding (reference: src/ops/embedding.cc) — key DLRM op
 # ---------------------------------------------------------------------------

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from flexflow_tpu.core.parallel_tensor import ParallelDim, ParallelTensorShape
 from flexflow_tpu.core.types import DataType, OperatorType
-from flexflow_tpu.ops.registry import register_op
+from flexflow_tpu.ops.registry import mm_operands, mm_out_dtype, register_op
 
 
 # ---------------------------------------------------------------------------
@@ -169,8 +169,6 @@ def _infer_expert_ffn(input_shapes, params):
 
 
 def _lower_expert_ffn(params):
-    from flexflow_tpu.ops.registry import mm_operands
-
     def fn(ins, ws, ctx):
         (x,) = ins
         w1, b1, w2, b2 = ws
@@ -199,6 +197,117 @@ def _flops_expert_ffn(input_shapes, params):
 register_op(
     OperatorType.EXPERT_FFN, _infer_expert_ffn, _lower_expert_ffn,
     _flops_expert_ffn,
+)
+
+
+# ---------------------------------------------------------------------------
+# SparseMoE — a dropless top-k expert layer as ONE operator (TPU-native;
+# what the open MoE decoders deploy: OLMoE, Mixtral, Qwen-MoE). Unlike the
+# reference's family above there is no capacity and no one-hot: the
+# tokens x k (token, choice) rows are sorted by expert and a grouped matmul
+# runs each expert over its own ragged group, so nothing is ever dropped
+# and nothing is computed but the rows.
+# ---------------------------------------------------------------------------
+
+
+def _infer_sparse_moe(input_shapes, params):
+    (x,) = input_shapes  # [*lead, d]; a replica dim asks for expert parallelism
+    n, f = params["num_experts"], params["expert_hidden"]
+    rep = [dim for dim in x.dims if dim.is_replica_dim]
+    if len(rep) > 1:
+        raise ValueError("sparse_moe: at most one replica dim")
+    r_deg = rep[0].degree if rep else 1
+    r_idx = rep[0].parallel_idx if rep else -1
+    if n % r_deg != 0:
+        raise ValueError("sparse_moe: replica degree must divide num_experts")
+    d = x.dims[-1]
+    if d.degree > 1:
+        raise ValueError("sparse_moe: the feature dim may not be partitioned")
+    # the replica-dim protocol of attention's heads: a replicated input
+    # shards the stacked experts, each shard sums its own experts' rows,
+    # and the output's replica dim is folded by a downstream Reduction
+    expert = ParallelDim(n, r_deg, r_idx)
+    router = ParallelTensorShape((ParallelDim(d.size), ParallelDim(n)), x.dtype)
+    w_in = ParallelTensorShape(
+        (expert, ParallelDim(d.size), ParallelDim(f)), x.dtype
+    )
+    w_out = ParallelTensorShape(
+        (expert, ParallelDim(f), ParallelDim(d.size)), x.dtype
+    )
+    return (x,), (router, w_in, w_in, w_out)
+
+
+def sparse_moe_route(x2, router, k, renormalise):
+    """x2 [tokens, d] -> (weights [tokens, k] float32, experts [tokens, k]
+    int32). The router matmul and its softmax run in float32 at `highest`
+    whatever the model's precision: a top-k choice flips on rounding, and
+    a [d, experts] matmul costs nothing beside the experts."""
+    logits = jnp.dot(
+        x2.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def sparse_moe(x, ws, params, ctx=None):
+    """The layer on global logical arrays: x [*lead, d] -> (y [*lead, d],
+    counts [2] int32 = (rows computed, distinct experts with a row))."""
+    router, w_gate, w_up, w_down = ws
+    n, k = params["num_experts"], params["k"]
+    lead, d = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, d)
+    tokens = x2.shape[0]
+    with jax.named_scope("moe.route"):
+        weights, experts = sparse_moe_route(
+            x2, router, k, params.get("renormalise", False)
+        )
+    with jax.named_scope("moe.sort"):
+        flat = experts.reshape(-1)  # row r is token r // k, choice r % k
+        order = jnp.argsort(flat, stable=True)
+        group_sizes = jnp.zeros((n,), jnp.int32).at[flat].add(1)
+        rows = x2[order // k]  # [tokens * k, d], grouped by expert
+    with jax.named_scope("moe.experts"):
+        rows, w_gate, w_up = mm_operands(ctx, rows, w_gate, w_up)
+        mm = dict(preferred_element_type=jnp.float32)
+        gate = jax.lax.ragged_dot(rows, w_gate, group_sizes, **mm)
+        up = jax.lax.ragged_dot(rows, w_up, group_sizes, **mm)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        hidden, w_down = mm_operands(ctx, hidden, w_down)
+        out = jax.lax.ragged_dot(hidden, w_down, group_sizes, **mm)
+    with jax.named_scope("moe.combine"):
+        # unsort by the inverse permutation: row r again belongs to token
+        # r // k, and a token's k rows are summed under its gate weights
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
+        y = jnp.sum(
+            out[back].reshape(tokens, k, d) * weights[..., None], axis=1
+        ).astype(mm_out_dtype(ctx, x.dtype))
+    counts = jnp.stack(
+        [jnp.int32(tokens * k), jnp.sum(group_sizes > 0, dtype=jnp.int32)]
+    )
+    return y.reshape(lead + (d,)), counts
+
+
+def _lower_sparse_moe(params):
+    def fn(ins, ws, ctx):
+        return [sparse_moe(ins[0], ws, params, ctx)[0]]
+
+    return fn
+
+
+def _flops_sparse_moe(input_shapes, params):
+    (x,) = input_shapes
+    d = x.logical_sizes[-1]
+    tokens = x.volume() // d
+    n, k, f = params["num_experts"], params["k"], params["expert_hidden"]
+    return 2.0 * tokens * d * n + 3 * 2.0 * tokens * k * d * f
+
+
+register_op(
+    OperatorType.SPARSE_MOE, _infer_sparse_moe, _lower_sparse_moe,
+    _flops_sparse_moe,
 )
 
 

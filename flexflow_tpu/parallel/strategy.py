@@ -304,7 +304,7 @@ def mixed_site_strategy(
     full = dp * tp
     bracketable = {
         "linear_chain", "single_linear", "attention", "embedding",
-        "conv_channel",
+        "conv_channel", "sparse_moe",
     }
     if (
         tp == 1

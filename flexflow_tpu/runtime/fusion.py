@@ -44,6 +44,7 @@ _FUSIBLE = {
     OperatorType.DROPOUT,
     OperatorType.SOFTMAX,
     OperatorType.LAYERNORM,
+    OperatorType.RMSNORM,
     OperatorType.RESHAPE,
     OperatorType.TRANSPOSE,
     OperatorType.CAST,

@@ -32,7 +32,11 @@ from flexflow_tpu.core.types import (
 )
 from flexflow_tpu.ops.registry import _ensure_registered, infer_shapes
 from flexflow_tpu.runtime.dataloader import SingleDataLoader
-from flexflow_tpu.runtime.initializer import ConstantInitializer, ZeroInitializer
+from flexflow_tpu.runtime.initializer import (
+    ConstantInitializer,
+    UniformInitializer,
+    ZeroInitializer,
+)
 from flexflow_tpu.runtime.executor import Executor, MeshConfig, propagate_shapes
 from flexflow_tpu.runtime.metrics import PerfMetrics
 from flexflow_tpu.runtime.optimizer import Optimizer, SGDOptimizer
@@ -267,6 +271,14 @@ class FFModel:
         }
         return self._add(OperatorType.LAYERNORM, "layer_norm", [input], params, name)[0]
 
+    def rms_norm(
+        self, input: Tensor, eps: float = 1e-5, name: Optional[str] = None
+    ) -> Tensor:
+        """x * rsqrt(mean(x^2) + eps) * gain over the last dim, float32
+        statistics; the gain starts at one."""
+        params = {"eps": eps, "initializers": [ConstantInitializer(1.0)]}
+        return self._add(OperatorType.RMSNORM, "rms_norm", [input], params, name)[0]
+
     def embedding(
         self,
         input: Tensor,
@@ -299,8 +311,16 @@ class FFModel:
         bias: bool = True,
         causal: bool = False,
         seq_parallel: str = "auto",
+        rope_theta: Optional[float] = None,
+        qk_norm: bool = False,
+        qk_norm_eps: float = 1e-5,
         name: Optional[str] = None,
     ) -> Tensor:
+        """rope_theta: rotary positions (rotate-half form over each head)
+        on q and k; qk_norm: an RMSNorm with a learned gain over the whole
+        q and the whole k projection, before the rotation. Both follow the
+        projection and precede any cache write (ops/attention.py
+        mha_qk_positions); neither adds anything at its default."""
         params = {
             "embed_dim": embed_dim,
             "num_heads": num_heads,
@@ -314,6 +334,14 @@ class FFModel:
             "initializers": [None] * 4
             + ([ZeroInitializer()] * 4 if bias else []),
         }
+        if rope_theta is not None:
+            params["rope_theta"] = float(rope_theta)
+        if qk_norm:
+            params["qk_norm"] = True
+            params["qk_norm_eps"] = qk_norm_eps
+            params["initializers"] = params["initializers"] + [
+                ConstantInitializer(1.0)
+            ] * 2
         return self._add(
             OperatorType.MULTIHEAD_ATTENTION,
             "multihead_attention",
@@ -558,6 +586,39 @@ class FFModel:
             [stacked],
             {"hidden": hidden},
             name,
+        )[0]
+
+    def sparse_moe(
+        self,
+        input: Tensor,
+        num_experts: int,
+        k: int,
+        expert_hidden: int,
+        renormalise: bool = False,
+        name=None,
+    ) -> Tensor:
+        """A dropless top-k expert layer as one operator: router [d, E]
+        (float32 softmax over all E, top-k, the k weights renormalised to
+        sum to one only if asked), then for each token the weighted sum of
+        its k experts' gated MLPs down(silu(gate x) * up x), from stacked
+        gate / up [E, d, f] and down [E, f, d]. No capacity: every one of
+        the tokens x k rows is computed whatever the load (ops/moe.py
+        sparse_moe). The expert dim shards under a replicated input, as
+        attention's heads do."""
+        limit = (6.0 / (input.dims[-1] + expert_hidden)) ** 0.5
+        params = {
+            "num_experts": num_experts,
+            "k": k,
+            "expert_hidden": expert_hidden,
+            "renormalise": renormalise,
+            # Glorot of ONE expert's matrix: the default would count the
+            # stacked expert dim into the fan-in and start every expert
+            # at a sixty-fourth of its scale
+            "initializers": [None]
+            + [UniformInitializer(-limit, limit)] * 3,
+        }
+        return self._add(
+            OperatorType.SPARSE_MOE, "sparse_moe", [input], params, name
         )[0]
 
     def aggregate(

@@ -162,6 +162,17 @@ class AttentionSite(Site):
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseMoeSite(AttentionSite):
+    """One SparseMoE node: attention's bracket (Replicate the input, a
+    Reduction after), the replica dim sharding the stacked experts where
+    it shards attention's heads: each chip holds its experts' weights and
+    sums their rows' part of the result."""
+
+    def divisible_by(self, graph, tp):
+        return graph.nodes[self.guids[0]].params["num_experts"] % tp == 0
+
+
+@dataclasses.dataclass(frozen=True)
 class _ColumnParallelSite(Site):
     """Shared column-parallel bracket: Replicate the (single) input, let
     the replica-dim protocol shard the op's width param over the model
@@ -276,6 +287,9 @@ def find_tp_sites(graph: PCGGraph) -> List[Site]:
         node = graph.nodes[guid]
         if node.op_type == OperatorType.MULTIHEAD_ATTENTION:
             sites.append(AttentionSite("attention", (guid,)))
+            claimed.add(guid)
+        elif node.op_type == OperatorType.SPARSE_MOE:
+            sites.append(SparseMoeSite("sparse_moe", (guid,)))
             claimed.add(guid)
         elif node.op_type == OperatorType.EMBEDDING:
             sites.append(EmbeddingSite("embedding", (guid,)))

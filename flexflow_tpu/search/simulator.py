@@ -278,6 +278,7 @@ def estimate_graph_cost(
             OperatorType.EW_MIN,
             OperatorType.BATCHNORM,
             OperatorType.LAYERNORM,
+            OperatorType.RMSNORM,
             OperatorType.SOFTMAX,
         }
         _fusable = _free_types | _half_types

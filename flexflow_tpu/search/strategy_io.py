@@ -27,6 +27,7 @@ def _register_site_kinds():
         ExpertParallelSite,
         LinearChainSite,
         SingleLinearSite,
+        SparseMoeSite,
     )
 
     _SITE_KINDS.update(
@@ -37,6 +38,7 @@ def _register_site_kinds():
             "expert_parallel": ExpertParallelSite,
             "linear_chain": LinearChainSite,
             "single_linear": SingleLinearSite,
+            "sparse_moe": SparseMoeSite,
         }
     )
 

@@ -124,6 +124,17 @@ def snapshot(host_state: np.ndarray):
     return jnp.asarray(np.array(host_state))
 
 
+def _tree_depths(parents):
+    """[b, w] depth of each draft-tree row below the root row (row 0, depth
+    0): the number of its ancestors. A row accepted at depth m is
+    compacted to cache position lengths + m, so that is where it stands."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.attention import tree_ancestor_matrix
+
+    return jnp.sum(tree_ancestor_matrix(parents), axis=-1, dtype=jnp.int32) - 1
+
+
 class _JitCache:
     """Bounded keyed LRU over jitted step programs.
 
@@ -202,6 +213,9 @@ class InflightStep:
     # device futures (JAX arrays still computing behind the queue)
     device_next: object = None  # decode: sampled tokens [max_seqs]
     device_logits: object = None  # [max_seqs, V] or [max_seqs, w, V]
+    # decode of a model with expert layers: ([2] int32 (rows computed,
+    # experts touched),), else ()
+    device_moe: tuple = ()
     # device-resident multi-step decode (kind "multistep"): the fused
     # window's per-step device outputs — sampled tokens / logits /
     # executed-step masks are [K, max_seqs] stacks, device_lengths the
@@ -350,6 +364,35 @@ class GenerationEngine:
                     f"attention node '{node.name}' is cross-attention; "
                     "the KV-cache engine supports self-attention only"
                 )
+        # a model whose attention depends on positions (rotary) or carries
+        # weights beyond the projections (QK-norm): every step program
+        # below hands `mha_project_qkv` the node's params and the
+        # positions of the rows it writes. What cannot do that is refused
+        # here, not served position-free.
+        from flexflow_tpu.ops.attention import is_positional
+
+        self._positional = any(
+            is_positional(graph.nodes[g].params) for g in cache.spec.layer_guids
+        )
+        if self._positional and adapters is not None:
+            raise ValueError(
+                "adapters (multi-LoRA) are not supported for a model with "
+                "rotary positions or QK-norm: the LoRA deltas are added to "
+                "q and k after mha_project_qkv has normalised and rotated "
+                "them, which is not the adapted model; serve it without "
+                "ServeConfig.adapters"
+            )
+        # expert layers (ops/moe.py sparse_moe): the prefill and decode
+        # programs return their row and touched-expert counts beside the
+        # logits, read in the same readback; without one, nothing more
+        self._moe_guids = tuple(
+            g for g in self.executor.topo
+            if graph.nodes[g].op_type == OperatorType.SPARSE_MOE
+        )
+        self.moe_rows_prefill = 0
+        self.moe_rows_decode = 0
+        self.moe_experts_touched_prefill = 0
+        self.moe_experts_touched_decode = 0
         self._logits_ref = self.executor.logits_ref
         # per-iteration dynamic seq truncation is a training knob; a stale
         # value would truncate serving activations mid-stack
@@ -580,16 +623,57 @@ class GenerationEngine:
 
     # -- shared forward ------------------------------------------------------
 
-    def _forward_logits(self, params, tokens, hook):
+    def _positions(self, make):
+        """The positions a step program hands `mha_project_qkv`: `make()`
+        for a positional model, None otherwise (so that a model without
+        rotary or QK-norm traces exactly the program it always did)."""
+        return make() if self._positional else None
+
+    def _forward_logits(self, params, tokens, hook, moe_counts=None):
+        """`moe_counts`: a list that receives the [2] int32 (rows computed,
+        experts touched) of every expert layer, for the programs that
+        return them; the layer itself is the executor's lowering."""
+        hooks = {OperatorType.MULTIHEAD_ATTENTION: hook}
+        if moe_counts is not None and self._moe_guids:
+            from flexflow_tpu.ops.moe import sparse_moe
+
+            def count(node, ins, ws, ctx):
+                y, counts = sparse_moe(ins[0], ws, node.params, ctx)
+                moe_counts.append(counts)
+                return [y]
+
+            hooks[OperatorType.SPARSE_MOE] = count
         values = self.executor.forward_values(
             params,
             {self.input_name: tokens},
             rng=None,
             train=False,
-            op_hooks={OperatorType.MULTIHEAD_ATTENTION: hook},
+            op_hooks=hooks,
             constrain=False,
         )
         return values[(self._logits_ref.guid, self._logits_ref.out_idx)]
+
+    @staticmethod
+    def _moe_total(moe_counts):
+        """() for a model without expert layers, else a 1-tuple of the
+        [2] int32 sum over layers: what a step program appends to its
+        outputs."""
+        return (sum(moe_counts[1:], moe_counts[0]),) if moe_counts else ()
+
+    def _split_moe(self, out):
+        """A prefill or decode program's outputs without the expert
+        layers' counts, and the counts as a 0- or 1-tuple."""
+        n = len(out) - bool(self._moe_guids)
+        return out[:n], tuple(out[n:])
+
+    def _count_moe(self, kind: str, counts) -> None:
+        rows, touched = int(counts[0]), int(counts[1])
+        if kind == "prefill":
+            self.moe_rows_prefill += rows
+            self.moe_experts_touched_prefill += touched
+        else:
+            self.moe_rows_decode += rows
+            self.moe_experts_touched_decode += touched
 
     def _pick(self, logits, slots, positions):
         """logits [n, vocab] -> token ids [n]. Greedy at temperature 0,
@@ -679,7 +763,8 @@ class GenerationEngine:
         (>=1; pad rows use 1). `ad` is the optional batch-row-aligned
         adapter gather (tables, has, pools) — None leaves the traced HLO
         exactly the base engine's. Returns (ck', cv', next_tokens,
-        last_logits)."""
+        last_logits), and for a model with expert layers their [2] int32
+        (rows computed, experts touched) after them."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -695,9 +780,14 @@ class GenerationEngine:
         captured_k: Dict[int, object] = {}
         captured_v: Dict[int, object] = {}
 
+        positions = self._positions(lambda: jnp.arange(tokens.shape[1]))
+
         def hook(node, ins, ws, ctx):
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, node.guid)
             captured_k[node.guid] = k
             captured_v[node.guid] = v
@@ -707,7 +797,8 @@ class GenerationEngine:
             )
             return [apply_adapter_out(attn, out, ad, node.guid)]
 
-        logits = self._forward_logits(params, tokens, hook)
+        moe = []
+        logits = self._forward_logits(params, tokens, hook, moe)
         bucket = tokens.shape[1]
         new_k, new_v = {}, {}
         for g in self.cache.spec.layer_guids:
@@ -721,7 +812,10 @@ class GenerationEngine:
             logits, (prompt_lens - 1)[:, None, None], axis=1
         )[:, 0]
         # the sampled token will be written at cache position prompt_lens
-        return new_k, new_v, self._pick(last, slot_ids, prompt_lens), last
+        return (
+            new_k, new_v, self._pick(last, slot_ids, prompt_lens), last,
+            *self._moe_total(moe),
+        )
 
     def _prefill_impl_paged(
         self, params, tokens, slot_ids, row_tables, prompt_lens, ck, cv,
@@ -758,10 +852,15 @@ class GenerationEngine:
         new_k, new_v = {}, {}
         new_ks, new_vs = dict(cks), dict(cvs)
 
+        positions = self._positions(lambda: pos)
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             if quant:
                 # scatter inside the hook and attend over the int8
@@ -796,7 +895,8 @@ class GenerationEngine:
             )
             return [apply_adapter_out(attn, out, ad, g)]
 
-        logits = self._forward_logits(params, tokens, hook)
+        moe = []
+        logits = self._forward_logits(params, tokens, hook, moe)
         last = jnp.take_along_axis(
             logits, (prompt_lens - 1)[:, None, None], axis=1
         )[:, 0]
@@ -807,6 +907,7 @@ class GenerationEngine:
             new_vs,
             self._pick(last, slot_ids, prompt_lens),
             last,
+            *self._moe_total(moe),
         )
 
     def prefill(
@@ -867,14 +968,18 @@ class GenerationEngine:
             scales = (
                 (self.cache.k_scale, self.cache.v_scale) if self.paged else ()
             )
-            *new_cache, nxt, last = fn(
+            out = fn(
                 params, *inputs, self.cache.k, self.cache.v, *scales,
                 *self._adapter_row_args(slots),
             )
+            (*new_cache, nxt, last), moe = self._split_moe(out)
             self.cache.commit(*new_cache)
             for p, s in zip(prompts, slots):
                 self.cache.lengths[s] = len(p)
-        return tuple(self._readback("prefill", nxt[:n], last[:n]))
+        nxt, last, *counts = self._readback("prefill", nxt[:n], last[:n], *moe)
+        if counts:
+            self._count_moe("prefill", counts[0])
+        return nxt, last
 
     def prefill_suffix(
         self,
@@ -924,7 +1029,9 @@ class GenerationEngine:
 
     # -- decode --------------------------------------------------------------
 
-    def _decode_core(self, params, tokens, lengths, active, ck, cv, ad=None):
+    def _decode_core(
+        self, params, tokens, lengths, active, ck, cv, ad=None, moe=None
+    ):
         """One decode forward over the slot-contiguous cache: write the
         new K/V row per active slot at `lengths`, run masked one-query
         attention, return (ck', cv', logits [max_seqs, V]). The
@@ -932,7 +1039,9 @@ class GenerationEngine:
         function, so their HLO op sequence — and therefore their
         logits — match exactly (the token/logit-identity contract).
         `ad=None` (no adapter pool) leaves the traced HLO byte-for-byte
-        what it was before multi-LoRA existed."""
+        what it was before multi-LoRA existed. `moe`: the list that
+        receives the expert layers' counts (`_forward_logits`); the
+        single-step program returns them, the scan does not."""
         import jax
         import jax.numpy as jnp
 
@@ -957,10 +1066,15 @@ class GenerationEngine:
             )(cache, new.astype(cache.dtype), lengths)
             return jnp.where(active[:, None, None, None], upd, cache)
 
+        positions = self._positions(lambda: lengths[:, None])
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             # LoRA deltas land BEFORE the cache write: the K/V rows the
             # pool stores are the adapted values, so the attention
             # kernel (dense or Pallas) never needs to know adapters
@@ -979,7 +1093,7 @@ class GenerationEngine:
             )
             return [apply_adapter_out(attn, out, ad, g)]
 
-        logits = self._forward_logits(params, tokens, hook)[:, -1, :]
+        logits = self._forward_logits(params, tokens, hook, moe)[:, -1, :]
         return new_k, new_v, logits
 
     def _decode_impl(self, params, tokens, lengths, active, ck, cv, ad=None):
@@ -988,16 +1102,20 @@ class GenerationEngine:
         writes for free slots."""
         import jax.numpy as jnp
 
+        moe = []
         new_k, new_v, logits = self._decode_core(
-            params, tokens, lengths, active, ck, cv, ad
+            params, tokens, lengths, active, ck, cv, ad, moe
         )
         slots = jnp.arange(lengths.shape[0])
         # the sampled token will be written at cache position lengths + 1
-        return new_k, new_v, self._pick(logits, slots, lengths + 1), logits
+        return (
+            new_k, new_v, self._pick(logits, slots, lengths + 1), logits,
+            *self._moe_total(moe),
+        )
 
     def _decode_core_paged(
         self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
-        ad=None,
+        ad=None, moe=None,
     ):
         """Paged twin of _decode_core. tables [max_seqs,
         max_pages_per_seq] int32 block tables. The new K/V row scatters
@@ -1035,10 +1153,15 @@ class GenerationEngine:
                 pool.shape
             )
 
+        positions = self._positions(lambda: lengths[:, None])
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             # adapted K/V go INTO the pool (delta precedes the scatter),
             # so the Pallas kernel reads adapter-aware pages unchanged
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
@@ -1066,7 +1189,7 @@ class GenerationEngine:
             )
             return [apply_adapter_out(attn, out, ad, g)]
 
-        logits = self._forward_logits(params, tokens, hook)[:, -1, :]
+        logits = self._forward_logits(params, tokens, hook, moe)[:, -1, :]
         return new_k, new_v, new_ks, new_vs, logits
 
     def _decode_impl_paged(
@@ -1077,8 +1200,10 @@ class GenerationEngine:
         one _decode_core_paged forward plus the per-slot sample."""
         import jax.numpy as jnp
 
+        moe = []
         new_k, new_v, new_ks, new_vs, logits = self._decode_core_paged(
-            params, tokens, lengths, active, tables, ck, cv, cks, cvs, ad
+            params, tokens, lengths, active, tables, ck, cv, cks, cvs, ad,
+            moe,
         )
         slots = jnp.arange(lengths.shape[0])
         return (
@@ -1088,6 +1213,7 @@ class GenerationEngine:
             new_vs,
             self._pick(logits, slots, lengths + 1),
             logits,
+            *self._moe_total(moe),
         )
 
     # -- device-resident multi-step decode -----------------------------------
@@ -1272,16 +1398,10 @@ class GenerationEngine:
             *scale_args,
             *self._adapter_slot_args(),
         )
-        if self.paged:
-            new_k, new_v, new_ks, new_vs, nxt, logits = self._dispatch(
-                "decode", lambda: self._decode_jit(*step_args)
-            )
-            self.cache.commit(new_k, new_v, new_ks, new_vs)
-        else:
-            new_k, new_v, nxt, logits = self._dispatch(
-                "decode", lambda: self._decode_jit(*step_args)
-            )
-            self.cache.commit(new_k, new_v)
+        (*new_cache, nxt, logits), moe = self._split_moe(
+            self._dispatch("decode", lambda: self._decode_jit(*step_args))
+        )
+        self.cache.commit(*new_cache)
         self.cache.lengths[np.asarray(active_mask)] += 1
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
@@ -1294,6 +1414,7 @@ class GenerationEngine:
             host_tokens=host_tokens,
             device_next=nxt,
             device_logits=logits,
+            device_moe=moe,
         )
 
     def decode_reconcile(
@@ -1305,11 +1426,14 @@ class GenerationEngine:
         lives on the step record's snapshots — by the time this runs,
         live cache/scheduler state is one iteration ahead."""
         try:
-            nxt, logits = self._readback(
-                "decode", step.device_next, step.device_logits
+            nxt, logits, *counts = self._readback(
+                "decode", step.device_next, step.device_logits,
+                *step.device_moe,
             )
         finally:
             self.cache.end_inflight()
+        if counts:
+            self._count_moe("decode", counts[0])
         return nxt, logits
 
     def decode(
@@ -1593,10 +1717,17 @@ class GenerationEngine:
             )
             return flat.at[dest].set(rows).reshape(cache.shape)
 
+        positions = self._positions(
+            lambda: lengths[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        )
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             kc = row_update(ck[g], k)
             vc = row_update(cv[g], v)
@@ -1651,10 +1782,17 @@ class GenerationEngine:
             )
             return flat.at[dest].set(rows).reshape(pool.shape)
 
+        positions = self._positions(
+            lambda: lengths[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        )
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             if quant:
                 kc, new_ks[g], _ = self._quant_scatter(
@@ -1736,10 +1874,15 @@ class GenerationEngine:
             )
             return flat.at[dest].set(rows).reshape(cache.shape)
 
+        positions = self._positions(lambda: lengths[:, None] + _tree_depths(parents))
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             kc = row_update(ck[g], k)
             vc = row_update(cv[g], v)
@@ -1796,10 +1939,15 @@ class GenerationEngine:
             )
             return flat.at[dest].set(rows).reshape(pool.shape)
 
+        positions = self._positions(lambda: lengths[:, None] + _tree_depths(parents))
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             if quant:
                 kc, new_ks[g], _ = self._quant_scatter(
@@ -2134,10 +2282,17 @@ class GenerationEngine:
             )
             return flat.at[dest].set(rows).reshape(cache.shape)
 
+        positions = self._positions(
+            lambda: lengths[:, None] + jnp.arange(w)[None, :]
+        )
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             kc = row_update(ck[g], k)
             vc = row_update(cv[g], v)
@@ -2211,10 +2366,17 @@ class GenerationEngine:
             )
             return flat.at[dest].set(rows).reshape(pool.shape)
 
+        positions = self._positions(
+            lambda: lengths[:, None] + jnp.arange(w)[None, :]
+        )
+
         def hook(node, ins, ws, ctx):
             g = node.guid
             use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(ins, ws, ctx, use_bias=use_bias)
+            q, k, v = mha_project_qkv(
+                ins, ws, ctx, use_bias=use_bias, params=node.params,
+                positions=positions,
+            )
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             if quant:
                 kc, new_ks[g], _ = self._quant_scatter(

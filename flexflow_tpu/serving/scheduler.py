@@ -308,6 +308,12 @@ _STAT_FIELDS: Dict[str, object] = dict(
     readback_bytes=0,  # bytes those reads brought to the host
     prefill_tokens_real=0,  # prompt tokens run by monolithic prefills
     prefill_tokens_padded=0,  # the max_seqs x bucket tokens they ran as
+    # expert layers (ops/moe.py sparse_moe), summed over layers: the token x
+    # choice rows computed and the distinct experts with at least one row
+    moe_rows_prefill=0,
+    moe_rows_decode=0,
+    moe_experts_touched_prefill=0,
+    moe_experts_touched_decode=0,
     # prefix-sharing page cache (paged layout with --prefix-cache;
     # mirrored from the allocator's ledgers at each iteration end)
     prefix_hits=0,  # admissions that mapped at least one shared page
@@ -2618,7 +2624,8 @@ class _SchedulerBase:
     _ENGINE_MIRRORS = (
         "verify_cache_entries", "kernel_fallbacks", "multistep_cache_entries",
         "device_syncs", "readback_bytes", "prefill_tokens_real",
-        "prefill_tokens_padded",
+        "prefill_tokens_padded", "moe_rows_prefill", "moe_rows_decode",
+        "moe_experts_touched_prefill", "moe_experts_touched_decode",
     )
 
     def _end_iteration(self) -> None:
