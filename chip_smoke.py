@@ -867,7 +867,7 @@ def phase_four_chip(compiles: Compiles) -> dict:
             for g, pool in engine.cache.k.items():
                 check(
                     pool.sharding.is_equivalent_to(
-                        placement.kv_sharding(), pool.ndim
+                        placement.kv_sharding(pool.ndim), pool.ndim
                     ),
                     f"serve_mesh {mesh}: pool {g} sharding {pool.sharding}",
                 )

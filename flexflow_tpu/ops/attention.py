@@ -451,7 +451,9 @@ def _dequant_pages(pool, tbl, scale, b, heads, d):
     scale[tbl] is [b, np_seq, h], broadcast over page positions and
     head_dim. Unwritten pages carry scale 0 and dequantize to exact
     zeros at positions the length mask drops anyway."""
-    pages = pool[tbl].astype(jnp.float32)  # [b, np_seq, ps, h, d]
+    pages = pool[tbl].astype(jnp.float32).reshape(
+        *tbl.shape, -1, heads, d
+    )  # [b, np_seq, ps, h, d], whether the pool folds (h, d) or not
     s = scale[tbl][:, :, None, :, None]  # [b, np_seq, 1, h, 1]
     return (pages * s).reshape(b, -1, heads, d)
 
@@ -533,8 +535,8 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths,
     )
     if out is not None:
         return out
-    b = q.shape[0]
-    num_pages, page_size, heads, d = k_pool.shape
+    b, _, heads, d = q.shape
+    num_pages = k_pool.shape[0]
     tbl = jnp.minimum(block_tables, num_pages - 1)
     if k_scale is not None:
         k = _dequant_pages(k_pool, tbl, k_scale, b, heads, d)
@@ -608,8 +610,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     )
     if out is not None:
         return out
-    b = q.shape[0]
-    num_pages, page_size, heads, d = k_pool.shape
+    b, _, heads, d = q.shape
+    num_pages = k_pool.shape[0]
     # sentinel entries are clamped to a real page; whatever that page
     # holds sits at masked positions, so the clamp is numerically inert
     tbl = jnp.minimum(block_tables, num_pages - 1)

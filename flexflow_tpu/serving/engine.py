@@ -63,6 +63,15 @@ verify at `[max_seqs, w]` per draft width, so compile count is
 does not change the compile-count contract (tables are data, not
 shape).
 
+Every step program OWNS the KV pools it is handed: `_step_jit`, the one
+place a program's jit is built, donates them, so the scatter writes
+into the buffer it was given instead of copying the whole pool in front
+of every step. The host's side of that: after a dispatch the arrays
+that went in are deleted, and the only live pools are the ones
+`cache.commit` stored (`_run_step`); nothing keeps a pool array across
+a dispatch, and a step that fails after its program consumed the pools
+raises PoolsLostError rather than run or commit a deleted one.
+
 Every decode/verify is split into **dispatch** (enqueue the jitted
 step, commit the functional cache arrays, snapshot the mutable host
 state onto an `InflightStep`) and **reconcile** (block on the device
@@ -84,6 +93,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import inspect
 import logging
 import time
 from collections import OrderedDict
@@ -105,6 +115,22 @@ class KernelCompileError(RuntimeError):
     because then a kernel that cannot run on this device looks like a
     healthy server. Carries the compiler's message; `__cause__` is the
     original exception."""
+
+
+class PoolsLostError(RuntimeError):
+    """The KV pools' contents are lost: a step program consumed the pools
+    it was handed (every step program owns and rewrites them in place, see
+    `GenerationEngine._step_jit`) and then failed, so neither the arrays
+    that went in nor any output exist. Raised instead of calling a program
+    on, or committing, a deleted pool; every sequence in the cache has lost
+    its KV rows, and the scheduler's step-fault path reports it like any
+    other lost step. `__cause__` is the failure that cost the pools."""
+
+
+#: the arguments a step program rewrites and returns, by the name every
+#: `_*_impl` gives them: K and V pools and, in the paged programs, the
+#: int8 scale pools. These, and nothing else, are donated.
+_POOL_ARGNAMES = ("ck", "cv", "cks", "cvs")
 
 
 def snapshot(host_state: np.ndarray):
@@ -263,8 +289,6 @@ class GenerationEngine:
         telemetry=None,
         adapters=None,
     ):
-        import jax
-
         from flexflow_tpu.ops.pallas.decode_kernel import MODES
 
         if model.executor is None:
@@ -398,8 +422,14 @@ class GenerationEngine:
         # value would truncate serving activations mid-stack
         self.executor.set_seq_length(None)
         self.paged = bool(getattr(cache, "paged", False))
-        self._decode_jit = jax.jit(
-            self._decode_impl_paged if self.paged else self._decode_impl
+        # step programs that found the pools they were handed consumed by
+        # the call (the donation engaged: the rows were written in place),
+        # and those that found them still alive (the backend declined it
+        # and copied). One host check of one pool leaf after each dispatch
+        self.pool_steps_donated = 0
+        self.pool_steps_copied = 0
+        self._decode_jit = self._step_jit(
+            self._decode_impl, self._decode_impl_paged
         )
         # one jitted prefill per length bucket / one jitted verify per
         # draft width (jit caches by shape anyway; the explicit caches
@@ -412,29 +442,26 @@ class GenerationEngine:
         # executable) alive for the engine's whole life.
         self._prefill_cache: Dict[int, object] = {}
         self._verify_cache = _JitCache(
-            lambda w: jax.jit(
-                self._verify_impl_paged if self.paged else self._verify_impl
+            lambda w: self._step_jit(
+                self._verify_impl, self._verify_impl_paged
             )
         )
         # chunked-prefill programs, one per compact batch shape (B, w) —
         # the scheduler pads widths to multiples of chunk_size, so the
         # population is budget/chunk_size distinct widths at most
         self._chunk_cache = _JitCache(
-            lambda key: jax.jit(
-                self._chunk_impl_paged if self.paged else self._chunk_impl
+            lambda key: self._step_jit(
+                self._chunk_impl, self._chunk_impl_paged
             )
         )
         # multi-step decode scan programs, one per (B, K-bucket, layout)
         # key — K buckets are powers of two, so the population is
         # log2(max_fused_steps) at most
         self._multistep_cache = _JitCache(
-            lambda key: jax.jit(
-                functools.partial(
-                    self._decode_multi_impl_paged
-                    if self.paged
-                    else self._decode_multi_impl,
-                    key[1],
-                )
+            lambda key: self._step_jit(
+                self._decode_multi_impl,
+                self._decode_multi_impl_paged,
+                key[1],
             )
         )
         # tree-verify programs, one per row width w = 1 + tree nodes.
@@ -442,12 +469,30 @@ class GenerationEngine:
         # an extra parent-table operand; the scheduler pins a single
         # node budget, so the steady-state population is one entry
         self._tree_cache = _JitCache(
-            lambda w: jax.jit(
-                self._verify_tree_impl_paged
-                if self.paged
-                else self._verify_tree_impl
+            lambda w: self._step_jit(
+                self._verify_tree_impl, self._verify_tree_impl_paged
             )
         )
+
+    def _step_jit(self, slot_impl, paged_impl, *bound):
+        """The one place a step program's jit is built: the impl of this
+        engine's cache layout (`bound`: its leading trace-time constants),
+        jitted with the pools it rewrites and returns DONATED. XLA may
+        not write into a parameter it does not own, so an undonated pool
+        is copied whole into the output buffer in front of every
+        scatter; a donated one is updated in place. Parameters, adapter
+        pools (immutable, shared by steps in flight), tables, lengths
+        and tokens stay the caller's. By name, since the pools' positions
+        differ between the programs."""
+        import jax
+
+        impl = paged_impl if self.paged else slot_impl
+        donate = tuple(
+            n for n in _POOL_ARGNAMES if n in inspect.signature(impl).parameters
+        )
+        if bound:
+            impl = functools.partial(impl, *bound)
+        return jax.jit(impl, donate_argnames=donate)
 
     @property
     def verify_cache_entries(self) -> int:
@@ -535,7 +580,53 @@ class GenerationEngine:
 
     # -- kernel-failure fallback ---------------------------------------------
 
-    def _dispatch(self, site: str, call, program=None):
+    def _run_step(
+        self, site: str, step_fn, params, inputs, adapter_args=(),
+        program=None, kernel_path: bool = True,
+    ):
+        """Call one step program on the live pools, commit the pools it
+        returns, and hand back the rest of its outputs.
+
+        Every step program has this shape: `(params, *inputs, ck, cv
+        [, cks, cvs] [, ad])` in, `(ck', cv' [, cks', cvs'], ...)` out,
+        with the pools donated (`_step_jit`). So after the call the
+        arrays that went in are gone, and the only live pools are the
+        ones `commit` stores here: nothing else may keep a pool array
+        across a dispatch. `step_fn()` resolves the jitted program at
+        call time, so that a kernel fallback's rebuilt program is what a
+        retry runs. `kernel_path=False` for the prefill, whose program
+        never holds a decode kernel and is not forced at dispatch."""
+        cache = self.cache
+        # one leaf stands for all: they are donated, and deleted, together
+        witness = next(iter(cache.k.values()))
+        if witness.is_deleted():
+            raise PoolsLostError(
+                f"{site} step not dispatched: the KV pools were consumed "
+                "by an earlier step program that failed"
+            )
+        pools = (
+            (cache.k, cache.v, cache.k_scale, cache.v_scale)
+            if self.paged
+            else (cache.k, cache.v)
+        )
+        args = (params, *inputs, *pools, *adapter_args)
+
+        def call():
+            return step_fn()(*args)
+
+        out = (
+            self._dispatch(site, call, program, witness)
+            if kernel_path
+            else call()
+        )
+        if witness.is_deleted():
+            self.pool_steps_donated += 1
+        else:
+            self.pool_steps_copied += 1
+        cache.commit(*out[: len(pools)])
+        return out[len(pools):]
+
+    def _dispatch(self, site: str, call, program, witness):
         """Run one jitted decode/verify step. On the dense paths this is
         just `call()`; on a Pallas-kernel path the outputs are forced
         first (surfacing async runtime errors BEFORE the cache commits
@@ -545,6 +636,12 @@ class GenerationEngine:
         once. Serving survives a broken kernel at the cost of the dense
         path's speed; the fallback is recorded in `kernel_fallbacks` /
         `kernel_fallback_error` and logged.
+
+        The retry calls the same closure over the same pools, so it
+        stands only while they do: a fault raised before the program ran
+        (the chaos seam, a trace or compile error) leaves them intact.
+        One that surfaces after the call consumed them (`witness`, a pool
+        leaf that went in, is deleted) raises PoolsLostError instead.
 
         `program` names the compiled program behind `call` (the site
         plus its shape key; defaults to the site). The FIRST dispatch of
@@ -576,14 +673,17 @@ class GenerationEngine:
                     f"{self.decode_kernel!r} failed on the first dispatch "
                     f"of program {program!r}: {e}"
                 ) from e
+            if witness.is_deleted():
+                raise PoolsLostError(
+                    f"{site} step failed after its program consumed the "
+                    f"KV pools, so there is nothing to retry on: {e!r}"
+                ) from e
             self._fall_back_to_dense(e)
             return call()
         self._kernel_programs_run.add(program)
         return out
 
     def _fall_back_to_dense(self, error) -> None:
-        import jax
-
         self.kernel_fallbacks += 1
         self.kernel_fallback_error = repr(error)
         _log.warning(
@@ -604,8 +704,8 @@ class GenerationEngine:
         # the jitted steps baked the failed mode in at trace time;
         # rebuild them so the retry traces the dense attention cores
         # (prefill never touches the kernel, so its cache stands)
-        self._decode_jit = jax.jit(
-            self._decode_impl_paged if self.paged else self._decode_impl
+        self._decode_jit = self._step_jit(
+            self._decode_impl, self._decode_impl_paged
         )
         self._verify_cache.clear()
         self._chunk_cache.clear()
@@ -701,7 +801,16 @@ class GenerationEngine:
 
         return jax.vmap(one)(slots, positions, logits).astype(jnp.int32)
 
-    # -- int8 pool writes ----------------------------------------------------
+    # -- pool writes ---------------------------------------------------------
+
+    def _write_rows(self, pool, new, dest):
+        """Scatter K (V) rows `new` [..., heads, head_dim] into a paged
+        `pool` at flat row indices `dest` (out-of-bounds rows drop). A
+        pool row is one position's K (V) of every head."""
+        width = self.cache.spec.num_heads * self.cache.spec.head_dim
+        rows = new.astype(pool.dtype).reshape(-1, width)
+        return pool.reshape(-1, width).at[dest].set(rows).reshape(pool.shape)
+
 
     def _quant_scatter(self, pool, scale, rows, dest):
         """Quantize `rows` [N, heads, head_dim] into the int8 `pool` at
@@ -742,12 +851,14 @@ class GenerationEngine:
         q = jnp.clip(jnp.round(f32 / safe[:, :, None]), -127, 127).astype(
             pool.dtype
         )
-        flat = pool.reshape(-1, spec.num_heads, spec.head_dim)
+        flat = pool.reshape(-1, spec.num_heads * spec.head_dim)
         deq = q.astype(jnp.float32) * jnp.where(
             s > 0.0, s, 0.0
         )[:, :, None]
         return (
-            flat.at[dest].set(q, mode="drop").reshape(pool.shape),
+            flat.at[dest]
+            .set(q.reshape(q.shape[0], -1), mode="drop")
+            .reshape(pool.shape),
             new_scale,
             deq,
         )
@@ -879,16 +990,8 @@ class GenerationEngine:
                 k = k_deq.reshape(k.shape).astype(k.dtype)
                 v = v_deq.reshape(v.shape).astype(v.dtype)
             else:
-                kp = ck[g].reshape(-1, spec.num_heads, spec.head_dim)
-                vp = cv[g].reshape(-1, spec.num_heads, spec.head_dim)
-                kr = k.reshape(-1, spec.num_heads, spec.head_dim)
-                vr = v.reshape(-1, spec.num_heads, spec.head_dim)
-                new_k[g] = kp.at[dest].set(kr.astype(ck[g].dtype)).reshape(
-                    ck[g].shape
-                )
-                new_v[g] = vp.at[dest].set(vr.astype(cv[g].dtype)).reshape(
-                    cv[g].shape
-                )
+                new_k[g] = self._write_rows(ck[g], k, dest)
+                new_v[g] = self._write_rows(cv[g], v, dest)
             attn = scaled_dot_product_attention(q, k, v, causal=True)
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
@@ -919,7 +1022,6 @@ class GenerationEngine:
         """Run one admission batch; writes the cache in place (commit) and
         updates slot lengths. Returns (next_tokens [n], last_logits [n, V])
         for the n real rows."""
-        import jax
         import jax.numpy as jnp
 
         spec = self.cache.spec
@@ -948,10 +1050,8 @@ class GenerationEngine:
             self.prefill_tokens_padded += spec.max_seqs * bucket
             fn = self._prefill_cache.get(bucket)
             if fn is None:
-                fn = jax.jit(
-                    self._prefill_impl_paged
-                    if self.paged
-                    else self._prefill_impl
+                fn = self._step_jit(
+                    self._prefill_impl, self._prefill_impl_paged
                 )
                 self._prefill_cache[bucket] = fn
             route = [jnp.asarray(slot_ids)]
@@ -965,15 +1065,12 @@ class GenerationEngine:
                 route.append(jnp.asarray(row_tables))
             inputs = (jnp.asarray(tokens), *route, jnp.asarray(plens))
         with span("scheduler.step.prefill.dispatch", self._tracer):
-            scales = (
-                (self.cache.k_scale, self.cache.v_scale) if self.paged else ()
+            (nxt, last), moe = self._split_moe(
+                self._run_step(
+                    "prefill", lambda: fn, params, inputs,
+                    self._adapter_row_args(slots), kernel_path=False,
+                )
             )
-            out = fn(
-                params, *inputs, self.cache.k, self.cache.v, *scales,
-                *self._adapter_row_args(slots),
-            )
-            (*new_cache, nxt, last), moe = self._split_moe(out)
-            self.cache.commit(*new_cache)
             for p, s in zip(prompts, slots):
                 self.cache.lengths[s] = len(p)
         nxt, last, *counts = self._readback("prefill", nxt[:n], last[:n], *moe)
@@ -1147,12 +1244,6 @@ class GenerationEngine:
         ]
         dest = jnp.where(active, page * ps + lengths % ps, oob)
 
-        def row_update(pool, new):
-            flat = pool.reshape(-1, spec.num_heads, spec.head_dim)
-            return flat.at[dest].set(new[:, 0].astype(pool.dtype)).reshape(
-                pool.shape
-            )
-
         positions = self._positions(lambda: lengths[:, None])
 
         def hook(node, ins, ws, ctx):
@@ -1177,8 +1268,8 @@ class GenerationEngine:
                     k_scale=new_ks[g], v_scale=new_vs[g],
                 )
             else:
-                kc = row_update(ck[g], k)
-                vc = row_update(cv[g], v)
+                kc = self._write_rows(ck[g], k, dest)
+                vc = self._write_rows(cv[g], v, dest)
                 attn = paged_decode_attention(
                     q, kc, vc, tables, lengths, **self._attn_core
                 )
@@ -1384,24 +1475,20 @@ class GenerationEngine:
         # allocator table edits between iterations mutate behind the
         # async dispatch queue); the locals built above are fresh per
         # call and safe to hand over directly
-        scale_args = (
-            [self.cache.k_scale, self.cache.v_scale] if self.paged else []
+        (nxt, logits), moe = self._split_moe(
+            self._run_step(
+                "decode",
+                lambda: self._decode_jit,
+                params,
+                (
+                    dev_tokens[:, None],
+                    snapshot(self.cache.lengths),
+                    jnp.asarray(active_mask),
+                    *args,
+                ),
+                self._adapter_slot_args(),
+            )
         )
-        step_args = (
-            params,
-            dev_tokens[:, None],
-            snapshot(self.cache.lengths),
-            jnp.asarray(active_mask),
-            *args,
-            self.cache.k,
-            self.cache.v,
-            *scale_args,
-            *self._adapter_slot_args(),
-        )
-        (*new_cache, nxt, logits), moe = self._split_moe(
-            self._dispatch("decode", lambda: self._decode_jit(*step_args))
-        )
-        self.cache.commit(*new_cache)
         self.cache.lengths[np.asarray(active_mask)] += 1
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
@@ -1540,48 +1627,22 @@ class GenerationEngine:
         # snapshot() every mutable host array (lengths += limits below,
         # allocator table edits between iterations mutate behind the
         # async dispatch queue); see decode_dispatch()
-        scale_args = (
-            [self.cache.k_scale, self.cache.v_scale] if self.paged else []
-        )
-        step_args = (
-            params,
-            dev_tokens,
-            snapshot(self.cache.lengths),
-            jnp.asarray(np.asarray(active_mask, dtype=bool)),
-            jnp.asarray(limits),
-            jnp.asarray(eos),
-            *args,
-            self.cache.k,
-            self.cache.v,
-            *scale_args,
-            *self._adapter_slot_args(),
-        )
         key = (spec.max_seqs, k_bucket, "paged" if self.paged else "slot")
-
-        def call():
-            # resolved inside the dispatch so a kernel fallback's
-            # cleared cache re-traces with the dense attention core
-            return self._multistep_cache.get(key)(*step_args)
-
-        program = ("multistep", key)
-        if self.paged:
+        d_lens, d_toks, toks_ks, logits_ks, mask_ks = self._run_step(
+            "multistep",
+            lambda: self._multistep_cache.get(key),
+            params,
             (
-                new_k,
-                new_v,
-                new_ks,
-                new_vs,
-                d_lens,
-                d_toks,
-                toks_ks,
-                logits_ks,
-                mask_ks,
-            ) = self._dispatch("multistep", call, program)
-            self.cache.commit(new_k, new_v, new_ks, new_vs)
-        else:
-            new_k, new_v, d_lens, d_toks, toks_ks, logits_ks, mask_ks = (
-                self._dispatch("multistep", call, program)
-            )
-            self.cache.commit(new_k, new_v)
+                dev_tokens,
+                snapshot(self.cache.lengths),
+                jnp.asarray(np.asarray(active_mask, dtype=bool)),
+                jnp.asarray(limits),
+                jnp.asarray(eos),
+                *args,
+            ),
+            self._adapter_slot_args(),
+            program=("multistep", key),
+        )
         act = np.asarray(active_mask, dtype=bool)
         self.cache.lengths[act] += limits[act]
         # the in-flight window pins pages this window's snapshot tables
@@ -1775,13 +1836,6 @@ class GenerationEngine:
         new_ks = dict(cks)
         new_vs = dict(cvs)
 
-        def row_update(pool, new):
-            flat = pool.reshape(-1, spec.num_heads, spec.head_dim)
-            rows = new.astype(pool.dtype).reshape(
-                -1, spec.num_heads, spec.head_dim
-            )
-            return flat.at[dest].set(rows).reshape(pool.shape)
-
         positions = self._positions(
             lambda: lengths[:, None] + jnp.arange(tokens.shape[1])[None, :]
         )
@@ -1820,8 +1874,8 @@ class GenerationEngine:
                     v_scale=new_vs[g],
                 )
             else:
-                kc = row_update(ck[g], k)
-                vc = row_update(cv[g], v)
+                kc = self._write_rows(ck[g], k, dest)
+                vc = self._write_rows(cv[g], v, dest)
                 new_k[g] = kc
                 new_v[g] = vc
                 attn = paged_verify_attention(
@@ -1932,13 +1986,6 @@ class GenerationEngine:
         new_ks = dict(cks)
         new_vs = dict(cvs)
 
-        def row_update(pool, new):
-            flat = pool.reshape(-1, spec.num_heads, spec.head_dim)
-            rows = new.astype(pool.dtype).reshape(
-                -1, spec.num_heads, spec.head_dim
-            )
-            return flat.at[dest].set(rows).reshape(pool.shape)
-
         positions = self._positions(lambda: lengths[:, None] + _tree_depths(parents))
 
         def hook(node, ins, ws, ctx):
@@ -1976,8 +2023,8 @@ class GenerationEngine:
                     tree_parents=parents,
                 )
             else:
-                kc = row_update(ck[g], k)
-                vc = row_update(cv[g], v)
+                kc = self._write_rows(ck[g], k, dest)
+                vc = self._write_rows(cv[g], v, dest)
                 new_k[g] = kc
                 new_v[g] = vc
                 attn = paged_verify_attention(
@@ -2051,35 +2098,19 @@ class GenerationEngine:
         # snapshot() lengths/tables: the caller truncates the cache
         # right after the reconcile, and jnp.asarray's host read is
         # deferred behind the dispatch queue — see decode_dispatch()
-        scale_args = (
-            [self.cache.k_scale, self.cache.v_scale] if self.paged else []
-        )
-        step_args = (
+        (logits,) = self._run_step(
+            "verify",
+            lambda: self._verify_fn(w),
             params,
-            jnp.asarray(tokens),
-            snapshot(self.cache.lengths),
-            jnp.asarray(draft_lens),
-            *args,
-            self.cache.k,
-            self.cache.v,
-            *scale_args,
-            *self._adapter_slot_args(),
+            (
+                jnp.asarray(tokens),
+                snapshot(self.cache.lengths),
+                jnp.asarray(draft_lens),
+                *args,
+            ),
+            self._adapter_slot_args(),
+            program=("verify", w),
         )
-
-        def call():
-            # resolved inside the dispatch so a kernel fallback's
-            # cleared cache re-traces with the dense attention core
-            return self._verify_fn(w)(*step_args)
-
-        program = ("verify", w)
-        if self.paged:
-            new_k, new_v, new_ks, new_vs, logits = self._dispatch(
-                "verify", call, program
-            )
-            self.cache.commit(new_k, new_v, new_ks, new_vs)
-        else:
-            new_k, new_v, logits = self._dispatch("verify", call, program)
-            self.cache.commit(new_k, new_v)
         self.cache.begin_inflight()
         return InflightStep(
             kind="verify",
@@ -2169,36 +2200,20 @@ class GenerationEngine:
                     self.cache.ensure_position(int(slot), p)
             args = [snapshot(self.cache.block_tables)]
         lengths_snap = np.array(self.cache.lengths)
-        scale_args = (
-            [self.cache.k_scale, self.cache.v_scale] if self.paged else []
-        )
-        step_args = (
+        (logits,) = self._run_step(
+            "verify",
+            lambda: self._tree_fn(w),
             params,
-            jnp.asarray(tokens),
-            snapshot(self.cache.lengths),
-            jnp.asarray(draft_lens),
-            jnp.asarray(parents),
-            *args,
-            self.cache.k,
-            self.cache.v,
-            *scale_args,
-            *self._adapter_slot_args(),
+            (
+                jnp.asarray(tokens),
+                snapshot(self.cache.lengths),
+                jnp.asarray(draft_lens),
+                jnp.asarray(parents),
+                *args,
+            ),
+            self._adapter_slot_args(),
+            program=("tree", w),
         )
-
-        def call():
-            # resolved inside the dispatch so a kernel fallback's
-            # cleared cache re-traces with the dense attention core
-            return self._tree_fn(w)(*step_args)
-
-        program = ("tree", w)
-        if self.paged:
-            new_k, new_v, new_ks, new_vs, logits = self._dispatch(
-                "verify", call, program
-            )
-            self.cache.commit(new_k, new_v, new_ks, new_vs)
-        else:
-            new_k, new_v, logits = self._dispatch("verify", call, program)
-            self.cache.commit(new_k, new_v)
         self.cache.begin_inflight()
         return InflightStep(
             kind="verify_tree",
@@ -2359,13 +2374,6 @@ class GenerationEngine:
         new_ks = dict(cks)
         new_vs = dict(cvs)
 
-        def row_update(pool, new):
-            flat = pool.reshape(-1, spec.num_heads, spec.head_dim)
-            rows = new.astype(pool.dtype).reshape(
-                -1, spec.num_heads, spec.head_dim
-            )
-            return flat.at[dest].set(rows).reshape(pool.shape)
-
         positions = self._positions(
             lambda: lengths[:, None] + jnp.arange(w)[None, :]
         )
@@ -2404,8 +2412,8 @@ class GenerationEngine:
                     v_scale=new_vs[g],
                 )
             else:
-                kc = row_update(ck[g], k)
-                vc = row_update(cv[g], v)
+                kc = self._write_rows(ck[g], k, dest)
+                vc = self._write_rows(cv[g], v, dest)
                 new_k[g] = kc
                 new_v[g] = vc
                 attn = paged_verify_attention(
@@ -2488,36 +2496,20 @@ class GenerationEngine:
         # The batch compacts to the chunking slots (tokens/chunk_lens
         # rows); the jitted impl gathers its lengths/tables rows from
         # the full snapshots by slot_ids.
-        scale_args = (
-            [self.cache.k_scale, self.cache.v_scale] if self.paged else []
-        )
-        step_args = (
+        nxt, last = self._run_step(
+            "chunk",
+            lambda: self._chunk_fn((slot_ids.size, w)),
             params,
-            jnp.asarray(tokens[slot_ids]),
-            jnp.asarray(slot_ids.astype(np.int32)),
-            snapshot(self.cache.lengths),
-            jnp.asarray(chunk_lens[slot_ids]),
-            *args,
-            self.cache.k,
-            self.cache.v,
-            *scale_args,
-            *self._adapter_slot_args(),
+            (
+                jnp.asarray(tokens[slot_ids]),
+                jnp.asarray(slot_ids.astype(np.int32)),
+                snapshot(self.cache.lengths),
+                jnp.asarray(chunk_lens[slot_ids]),
+                *args,
+            ),
+            self._adapter_slot_args(),
+            program=("chunk", slot_ids.size, w),
         )
-
-        def call():
-            # resolved inside the dispatch so a kernel fallback's
-            # cleared cache re-traces with the dense attention core
-            return self._chunk_fn((slot_ids.size, w))(*step_args)
-
-        program = ("chunk", slot_ids.size, w)
-        if self.paged:
-            new_k, new_v, new_ks, new_vs, nxt, last = self._dispatch(
-                "chunk", call, program
-            )
-            self.cache.commit(new_k, new_v, new_ks, new_vs)
-        else:
-            new_k, new_v, nxt, last = self._dispatch("chunk", call, program)
-            self.cache.commit(new_k, new_v)
         # prompt rows are committed by construction — advance the
         # cursors now so the NEXT chunk step dispatches against them
         active = chunk_lens > 0

@@ -8,7 +8,7 @@ Two layouts share one spec/geometry derivation:
   `max_len` worth of HBM regardless of how many tokens it generates.
 
 * `PagedKVCache` — the PagedAttention layout (Kwon et al., SOSP'23 /
-  vLLM): K/V live in `[num_pages, page_size, heads, head_dim]` *pools*,
+  vLLM): K/V live in `[num_pages, page_size, heads * head_dim]` *pools*,
   a host-side free-page allocator hands pages to sequences on demand,
   and a per-slot *block table* (`[max_seqs, max_pages_per_seq]` int32,
   padded with the sentinel `num_pages`) maps logical cache positions to
@@ -207,8 +207,9 @@ def _derive_geometry(model):
     return guids, heads, head_dim, head_axis, executor
 
 
-def _heads_sharding(executor, head_axis):
-    """NamedSharding placing dim 2 (heads) on the strategy's head axis.
+def _heads_sharding(executor, head_axis, ndim=4):
+    """NamedSharding placing dim 2 (heads; in a paged pool's `ndim` 3
+    the folded heads * head_dim) on the strategy's head axis.
 
     Always place the cache on the mesh (replicated when heads are not
     sharded): uncommitted fresh zeros would give the first engine step a
@@ -217,7 +218,7 @@ def _heads_sharding(executor, head_axis):
     from jax.sharding import NamedSharding, PartitionSpec
 
     return NamedSharding(
-        executor.mesh, PartitionSpec(None, None, head_axis, None)
+        executor.mesh, PartitionSpec(*(None, None, head_axis, None)[:ndim])
     )
 
 
@@ -394,9 +395,10 @@ class KVCache:
     ) -> None:
         """Move the accepted tree rows into the contiguous tail of the
         committed prefix. Functional rebind (fresh dicts, gather before
-        scatter), not in-place mutation: already-queued steps read the
-        OLD arrays, and the new arrays chain behind the verify step's
-        committed outputs on the device queue — the commit() discipline."""
+        scatter) of the pools read at call time: they are the newest
+        step's committed outputs, which no queued program holds (a step
+        program consumes the pools it is handed), and the new arrays
+        chain behind them on the device queue — the commit() discipline."""
         import jax.numpy as jnp
 
         srcs = [int(p) for p in src_rows]
@@ -422,7 +424,9 @@ class KVCache:
         self.k, self.v = nk, nv
 
     def commit(self, new_k: Dict[int, object], new_v: Dict[int, object]):
-        """Swap in the arrays a jitted step returned."""
+        """Swap in the arrays a jitted step returned. The step program
+        was handed `self.k` / `self.v` donated (engine._step_jit): the
+        arrays it consumed are gone, these are the only live ones."""
         self.k = dict(new_k)
         self.v = dict(new_v)
 
@@ -516,8 +520,15 @@ class KVCache:
 class PagedKVCache:
     """Block-paged pools + host-side page allocator and block tables.
 
-    Device state: one `[num_pages, page_size, heads, head_dim]` K and V
-    pool per layer (functional, swapped via `commit` like KVCache).
+    Device state: one `[num_pages, page_size, heads * head_dim]` K and V
+    pool per layer (functional, swapped via `commit` like KVCache). The
+    heads and their dims are folded into one, heads-major, because a
+    device array's layout follows from its shape: a TPU keeps an array
+    whose last dim is under 128 lanes (a head_dim of 64) pages-minor,
+    not row-major, and every step program then converted each pool to
+    row-major and back (two whole-pool copies a step; PERF.md, PR 27).
+    This shape is row-major there, unpadded, and it is the view the
+    paged kernel reads; a row is one position's K (V) of every head.
     Host state: the free-page stack, per-slot block tables (sentinel =
     `num_pages`, an out-of-bounds page id — OOB scatters drop and OOB
     gathers are masked by lengths, so sentinel entries are inert on
@@ -577,7 +588,7 @@ class PagedKVCache:
         spec = self.spec
         self.dtype = dtype
         self.prefix_cache = bool(prefix_cache)
-        shape = (spec.num_pages, spec.page_size, spec.num_heads, spec.head_dim)
+        shape = (spec.num_pages, spec.page_size, spec.num_heads * spec.head_dim)
         self.k: Dict[int, object] = {}
         self.v: Dict[int, object] = {}
         # int8 side pools: fp32 scale per (page, head); scale == 0 marks
@@ -1258,10 +1269,13 @@ class PagedKVCache:
                 if self._free_pages_h[h]
                 else self._pop_free_page(h)  # LRU-evict a retained page
             )
-            # functional rebind (fresh dicts, whole-attribute swap), not
-            # in-place entry mutation: any already-queued step read the
-            # OLD array objects, which the .at[].set() copies leave
-            # untouched — same discipline as commit()
+            # functional rebind (fresh dicts, whole-attribute swap) of
+            # the pools read HERE, at call time: they are the newest
+            # step's committed outputs and no queued program holds them
+            # (a step program consumes the pools it is handed, so an
+            # array kept from before a dispatch would be deleted). The
+            # eager .at[].set() copies them, undonated, behind that step
+            # on the device queue — same discipline as commit()
             nk, nv = dict(self.k), dict(self.v)
             nks, nvs = dict(self.k_scale), dict(self.v_scale)
             for g in self.spec.layer_guids:
@@ -1746,7 +1760,11 @@ class PagedKVCache:
         new_v_scale: Optional[Dict[int, object]] = None,
     ):
         """Swap in the pools a jitted step returned (and, under int8,
-        the scale side pools the step's scatter-max may have claimed)."""
+        the scale side pools the step's scatter-max may have claimed).
+        The step program was handed the previous ones donated
+        (engine._step_jit): they are gone, these are the only live
+        pools, and nothing else may keep a pool array across a
+        dispatch."""
         self.k = dict(new_k)
         self.v = dict(new_v)
         if new_k_scale is not None:
@@ -2003,9 +2021,9 @@ class PagedKVCache:
         placement = getattr(model, "serving_placement", None)
         if placement is not None:
             placement.validate_geometry(max_seqs, num_pages)
-            shardings = placement.kv_sharding()
+            shardings = placement.kv_sharding(ndim=3)
         else:
-            shardings = _heads_sharding(executor, head_axis)
+            shardings = _heads_sharding(executor, head_axis, ndim=3)
         return PagedKVCache(
             spec,
             dtype,

@@ -308,6 +308,11 @@ _STAT_FIELDS: Dict[str, object] = dict(
     readback_bytes=0,  # bytes those reads brought to the host
     prefill_tokens_real=0,  # prompt tokens run by monolithic prefills
     prefill_tokens_padded=0,  # the max_seqs x bucket tokens they ran as
+    # step programs, all kinds, by what became of the KV pools they were
+    # handed: consumed by the call (donated: rows written in place), or
+    # still alive after it (the backend declined the donation and copied)
+    pool_steps_donated=0,
+    pool_steps_copied=0,
     # expert layers (ops/moe.py sparse_moe), summed over layers: the token x
     # choice rows computed and the distinct experts with at least one row
     moe_rows_prefill=0,
@@ -2624,7 +2629,8 @@ class _SchedulerBase:
     _ENGINE_MIRRORS = (
         "verify_cache_entries", "kernel_fallbacks", "multistep_cache_entries",
         "device_syncs", "readback_bytes", "prefill_tokens_real",
-        "prefill_tokens_padded", "moe_rows_prefill", "moe_rows_decode",
+        "prefill_tokens_padded", "pool_steps_donated", "pool_steps_copied",
+        "moe_rows_prefill", "moe_rows_decode",
         "moe_experts_touched_prefill", "moe_experts_touched_decode",
     )
 

@@ -220,7 +220,10 @@ def test_kv_pools_on_serving_mesh(mesh_lm):
             sh = pool.sharding
             assert isinstance(sh, NamedSharding)
             assert sh.mesh == pl.mesh
-            assert sh.spec == PartitionSpec("data", None, "model", None)
+            # paged pools are [pages, page_size, heads * head_dim]: the
+            # model axis cuts the folded dim into whole heads
+            assert pool.ndim == 3
+            assert sh.spec == PartitionSpec("data", None, "model")
 
 
 def test_quantized_scale_pools_on_serving_mesh(mesh_lm):

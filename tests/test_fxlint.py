@@ -519,10 +519,10 @@ def test_seeded_multistep_capture_is_caught(tmp_path):
     with open(src_path) as f:
         src = f.read()
     seeded = src.replace(
-        "            snapshot(self.cache.lengths),\n"
-        "            jnp.asarray(np.asarray(active_mask, dtype=bool)),\n",
-        "            self.cache.lengths,\n"
-        "            jnp.asarray(np.asarray(active_mask, dtype=bool)),\n",
+        "                snapshot(self.cache.lengths),\n"
+        "                jnp.asarray(np.asarray(active_mask, dtype=bool)),\n",
+        "                self.cache.lengths,\n"
+        "                jnp.asarray(np.asarray(active_mask, dtype=bool)),\n",
         1,
     )
     assert seeded != src, (
@@ -610,12 +610,12 @@ def test_seeded_tree_capture_is_caught(tmp_path):
     with open(src_path) as f:
         src = f.read()
     seeded = src.replace(
-        "            snapshot(self.cache.lengths),\n"
-        "            jnp.asarray(draft_lens),\n"
-        "            jnp.asarray(parents),\n",
-        "            self.cache.lengths,\n"
-        "            jnp.asarray(draft_lens),\n"
-        "            jnp.asarray(parents),\n",
+        "                snapshot(self.cache.lengths),\n"
+        "                jnp.asarray(draft_lens),\n"
+        "                jnp.asarray(parents),\n",
+        "                self.cache.lengths,\n"
+        "                jnp.asarray(draft_lens),\n"
+        "                jnp.asarray(parents),\n",
         1,
     )
     assert seeded != src, (

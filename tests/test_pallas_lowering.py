@@ -295,3 +295,139 @@ def test_mosaic_compiles_head_sharded_kernel(as_tpu):
     hlo = compiled.as_text()
     for collective in ("all-gather", "all-reduce", "all-to-all"):
         assert collective not in hlo, collective
+
+
+# -- the engine's step programs own the pools they rewrite --------------------
+
+from test_pool_donation import KINDS, PROMPT, _step, lm  # noqa: E402,F401
+
+
+@pytest.fixture
+def step_programs(as_tpu, monkeypatch):
+    """Every step program an engine builds, recorded as (jitted program,
+    argument shapes) at its first call and never run: lowering for the TPU
+    as the chip would (`as_tpu`: the decode kernel is the Mosaic one, which
+    the CPU cannot execute), the call answers with zeros of the program's
+    output shapes."""
+    from flexflow_tpu.serving.engine import GenerationEngine
+
+    programs = []
+    build = GenerationEngine._step_jit
+
+    def recording(self, *impls):
+        jitted = build(self, *impls)
+
+        def call(*args):
+            shapes = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args
+            )
+            programs.append((jitted, shapes))
+            return jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                jax.eval_shape(jitted, *shapes),
+            )
+
+        return call
+
+    monkeypatch.setattr(GenerationEngine, "_step_jit", recording)
+    return programs
+
+
+def _step_program(lm, programs, kind, layout, dtype="fp32"):
+    """The lowered-for-TPU program of step `kind`, its cache, and where
+    the pools sit among its arguments."""
+    from flexflow_tpu.serving import ServeConfig, build_scheduler
+
+    sched, eng, cache = build_scheduler(
+        lm,
+        ServeConfig(
+            max_seqs=2, max_seq_len=32, kv_layout=layout, kv_dtype=dtype,
+            decode_kernel="pallas", decode_multistep=(kind == "multistep"),
+        ),
+    )
+    slot = cache.alloc(len(PROMPT), len(PROMPT) + 8)
+    eng.prefill(sched.params, [PROMPT], [slot])
+    if kind != "prefill":
+        _step(kind, eng, cache, sched.params, slot, 7)
+    jitted, shapes = programs[-1]
+    return jitted.trace(*shapes).lower(lowering_platforms=("tpu",)), cache
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_step_program_donates_exactly_its_pools(
+    lm, step_programs, kind, layout
+):
+    """Each of the six programs, lowered for the TPU, marks every pool it
+    rewrites as donated (the lowered text carries one aliasing attribute a
+    pool) and nothing else: an edit that drops the donation at one site,
+    or extends it to the parameters, fails here without a chip."""
+    lowered, cache = _step_program(lm, step_programs, kind, layout)
+    pools = {
+        (s.shape, s.dtype)
+        for s in jax.tree.leaves((cache.k, cache.v))
+    }
+    n_pools = 2 * len(cache.spec.layer_guids)
+    args = jax.tree.leaves(lowered.args_info)
+    donated = [a for a in args if a.donated]
+    assert len(donated) == n_pools
+    assert {(a.shape, a.dtype) for a in donated} == pools
+    text = lowered.as_text()
+    assert (
+        text.count("tf.aliasing_output") + text.count("jax.buffer_donor")
+        == n_pools
+    )
+
+
+def test_int8_step_program_donates_its_scale_pools_too(lm, step_programs):
+    lowered, cache = _step_program(
+        lm, step_programs, "decode", "paged", "int8"
+    )
+    n_pools = 4 * len(cache.spec.layer_guids)
+    donated = [a for a in jax.tree.leaves(lowered.args_info) if a.donated]
+    assert len(donated) == n_pools
+    assert sorted({str(a.dtype) for a in donated}) == ["float32", "int8"]
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_compiled_step_program_aliases_its_pools(lm, step_programs, kind):
+    """Compiled for a v5e, the program's outputs alias at least the pools'
+    bytes, and it converts no pool to another layout (`copy`; a
+    `copy-start` prefetch of these tiny pools into fast memory is not
+    one): the scatter writes into the buffer it was handed. Parameters
+    and results take the layouts the device gives their shapes, as on
+    the chip."""
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    from flexflow_tpu.serving import ServeConfig, build_scheduler
+
+    sched, eng, cache = build_scheduler(
+        lm,
+        ServeConfig(max_seqs=2, max_seq_len=32, kv_layout="paged",
+                    decode_kernel="pallas"),
+    )
+    slot = cache.alloc(len(PROMPT), len(PROMPT) + 8)
+    eng.prefill(sched.params, [PROMPT], [slot])
+    if kind == "decode":
+        _step(kind, eng, cache, sched.params, slot, 7)
+    jitted, shapes = step_programs[-1]
+    one_chip = SingleDeviceSharding(devices[0])
+    placed = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        shapes,
+    )
+    compiled = (
+        jitted.trace(*placed).lower(lowering_platforms=("tpu",)).compile()
+    )
+    pool = next(iter(cache.k.values()))
+    pool_bytes = sum(
+        s.size * s.dtype.itemsize for s in jax.tree.leaves((cache.k, cache.v))
+    )
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    shape = "f32[%s]" % ",".join(map(str, pool.shape))
+    copies = [
+        line for line in compiled.as_text().splitlines()
+        if " copy(" in line and shape in line.split("=", 1)[-1][:80]
+    ]
+    assert not copies, copies[:2]

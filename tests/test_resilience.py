@@ -345,6 +345,12 @@ def test_kernel_fault_falls_back_to_dense_and_keeps_serving(lm, layout):
         assert r.ok
         assert r.generated == base[r.rid]
     assert sched.stats.step_faults == 0  # fallback, not a step fault
+    # the seam raises before the program is called: the pools were intact
+    # for the retry, and every program that ran donated them
+    assert engine.pool_steps_copied == 0
+    assert engine.pool_steps_donated == (
+        sched.stats.prefill_batches + sched.stats.decode_steps
+    )
 
 
 @pytest.mark.parametrize("layout", ["slot", "paged"])
@@ -402,6 +408,68 @@ def test_runtime_kernel_failure_after_a_good_step_still_falls_back(lm):
     assert "device halted" in engine.kernel_fallback_error
     for r in done:
         assert r.ok and r.generated == base[r.rid]
+    # the wrapper raised BEFORE the program ran: the pools it was handed
+    # were intact, so the retry stood on them and every step donated
+    assert engine.pool_steps_copied == 0
+    assert engine.pool_steps_donated == (
+        sched.stats.prefill_batches + sched.stats.decode_steps
+    )
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+def test_failure_after_the_program_consumed_its_pools_is_pools_lost(
+    lm, layout
+):
+    """A step program owns the pools it is handed (they are donated). A
+    failure that surfaces AFTER the call consumed them, as one at
+    `block_until_ready` does, leaves nothing to retry on: the engine must
+    not call a program on the deleted arrays, nor commit them, but raise
+    the named error, which the scheduler reports like any other lost
+    step — every request reaches a terminal status that says so."""
+    from flexflow_tpu.serving.engine import PoolsLostError
+
+    sched, engine, cache = build_scheduler(
+        lm,
+        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
+                    decode_kernel="pallas"),
+    )
+    good = engine._decode_jit
+    calls = {"n": 0, "after": 0}
+
+    def consumed_then_failed(*args):
+        calls["n"] += 1
+        if calls["n"] < 3:
+            return good(*args)
+        if calls["n"] == 3:
+            good(*args)  # runs, and takes the pools with it
+            raise RuntimeError("device halted")
+        calls["after"] += 1  # a retry, or any later call, on deleted pools
+        return good(*args)
+
+    commits = []
+    commit = cache.commit
+
+    def watched(*pools):
+        commits.append(
+            any(a.is_deleted() for a in jax.tree_util.tree_leaves(pools))
+        )
+        return commit(*pools)
+
+    engine._decode_jit = consumed_then_failed
+    cache.commit = watched
+    done = sched.run(_requests(n=6))
+    assert calls == {"n": 3, "after": 0}
+    assert commits and not any(commits)
+    assert engine.kernel_fallbacks == 0  # no fallback: nothing to retry on
+    assert sched.stats.step_faults >= 1
+    assert len(done) == 6
+    for r in done:
+        assert r.status == RequestStatus.FAILED
+        assert "PoolsLostError" in r.error
+    # the engine refuses every later dispatch the same way
+    slot = cache.alloc(3, 8)
+    with pytest.raises(PoolsLostError, match="consumed"):
+        engine.prefill(sched.params, [[1, 2, 3]], [slot])
 
 
 def test_draft_fault_degrades_iteration_to_plain_decode(lm):
