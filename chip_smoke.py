@@ -21,8 +21,9 @@ made from a seed, and checks what comes out:
             fallback, and greedy streams identical to a second engine
             with decode_kernel="dense"
   families  every kernel family through the serving path (speculative
-            chain and tree, int8 pages, the slot layout) on a 2-layer
-            cut of the same width, streams identical to dense
+            chain and tree, a draft model on its own paged cache, int8
+            pages) on a 2-layer cut of the same width, streams identical
+            to dense
   four_chip with four or more devices: training data-parallel and
             dp2 x tp2, serving on a (1, 4) and a (2, 2) mesh
 
@@ -60,7 +61,7 @@ PHASES = (
     "sync", "kernels", "train", "serve", "families", "four_chip",
 )
 
-# the flagship geometry (transformer.cc:79-85; bench_serve.py "flagship")
+# the flagship geometry (transformer.cc:79-85)
 LAYERS, HIDDEN, HEADS, SEQ, BATCH = 12, 1024, 16, 512, 8
 VOCAB, MAX_SEQS, MAX_LEN = 32000, 8, 512
 HEAD_DIM = HIDDEN // HEADS
@@ -515,11 +516,11 @@ def _serve_config(**kw):
     return ServeConfig(max_seqs=MAX_SEQS, max_seq_len=MAX_LEN, **kw)
 
 
-def _run_streams(model, serve, work):
+def _run_streams(model, serve, work, draft_model=None):
     """build_scheduler(...).run(...): (streams by request, stats, engine)."""
     from flexflow_tpu.serving import Request, build_scheduler
 
-    sched, engine, cache = build_scheduler(model, serve)
+    sched, engine, cache = build_scheduler(model, serve, draft_model=draft_model)
     done = sched.run(
         [
             Request(rid=i, prompt=list(p), max_new_tokens=n)
@@ -665,12 +666,10 @@ def phase_families(compiles: Compiles) -> dict:
         "paged-int8/verify": dict(
             kv_dtype="int8", kv_page_size=32, spec_draft="ngram"
         ),
-        "slot/decode": dict(kv_layout="slot"),
-        "slot/verify": dict(kv_layout="slot", spec_draft="ngram"),
-        "slot/tree": dict(
-            kv_layout="slot", spec_draft="ngram", spec_branch=3
-        ),
+        # the draft model decodes on a paged cache of its own
+        "paged/model-verify": dict(spec_draft="model"),
     }
+    draft = _decoder(1)
     out = {}
     with highest():
         # greedy speculation is token-identical to plain greedy decode, so
@@ -689,7 +688,10 @@ def phase_families(compiles: Compiles) -> dict:
         compiles.take()
         for name, kw in families.items():
             before = pallas.TRACE_MODES["compiled"]
-            got, stats, engine = _run_streams(model, _serve_config(**kw), work)
+            got, stats, engine = _run_streams(
+                model, _serve_config(**kw), work,
+                draft_model=draft if kw.get("spec_draft") == "model" else None,
+            )
             _check_kernel_engine(engine, "auto", name)
             check(
                 pallas.TRACE_MODES["compiled"] > before,
@@ -712,7 +714,7 @@ def phase_families(compiles: Compiles) -> dict:
             say(f"families: {name} ok {out[name]}")
             del engine
             gc.collect()
-    del model
+    del model, draft
     gc.collect()
     return out
 
@@ -867,7 +869,7 @@ def phase_four_chip(compiles: Compiles) -> dict:
             for g, pool in engine.cache.k.items():
                 check(
                     pool.sharding.is_equivalent_to(
-                        placement.kv_sharding(pool.ndim), pool.ndim
+                        placement.kv_sharding(), pool.ndim
                     ),
                     f"serve_mesh {mesh}: pool {g} sharding {pool.sharding}",
                 )

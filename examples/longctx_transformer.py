@@ -3,8 +3,7 @@
 The reference cannot run this workload at all: its attention is a
 monolithic cuDNN call per shard that materializes the [s, s] scores
 (attention.cu:35) — at seq 8192 the f32 score tensor alone is 4.3 GB per
-layer and the dense path measurably collapses (BENCH_LONGCTX.json: 0.6
-TF/s). Here `use_flash="auto"` switches to the fused streaming kernel
+layer and the dense path collapses. Here `use_flash="auto"` switches to the fused streaming kernel
 past the 2 GiB score threshold, so the same builder program trains at
 seq 8192+ unchanged; across chips the sequence dim shards with ring
 attention (sequence_parallel_strategy).

@@ -2,9 +2,8 @@
 
 Builds a small GPT-style decoder (models.build_decoder_lm), compiles it,
 and serves a mixed-length prompt stream through the continuous-batching
-scheduler, printing generations and the scheduler's occupancy — run with
-`--serve-scheduler static` to watch the occupancy (and tokens/s) drop on
-the same stream. Serving flags ride FFConfig: `--max-seqs 4
+scheduler, printing generations and the scheduler's occupancy. Serving
+flags ride FFConfig: `--max-seqs 4
 --max-seq-len 128 --eos-token 0`. Telemetry flags ride along too — try
 `--trace /tmp/serve_trace.json --metrics-out /tmp/serve_metrics.prom
 --slo-ttft-ms 200` and load the trace at https://ui.perfetto.dev
@@ -56,12 +55,10 @@ def main():
     model = build_lm(cfg)
     serve = ServeConfig.from_config(cfg)
     sched, _, cache = build_scheduler(model, serve)
-    if cache.paged:
-        print(
-            f"paged KV cache: {cache.spec.num_pages} pages of "
-            f"{cache.spec.page_size} tokens "
-            f"(try --kv-page-size / --kv-pages / --kv-layout slot)"
-        )
+    print(
+        f"paged KV cache: {cache.spec.num_pages} pages of "
+        f"{cache.spec.page_size} tokens (try --kv-page-size / --kv-pages)"
+    )
     requests = [
         Request(
             rid=i,
@@ -76,7 +73,7 @@ def main():
         print(f"req {r.rid}: prompt {r.prompt} -> {r.generated}")
     s = sched.stats
     print(
-        f"[{serve.scheduler}] {s.tokens_generated} tokens, "
+        f"{s.tokens_generated} tokens, "
         f"{s.decode_steps} decode steps, occupancy {s.occupancy:.2f}, "
         f"peak in-flight {s.peak_in_flight}, {s.tokens_per_s:.0f} tokens/s"
     )

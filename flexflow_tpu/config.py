@@ -114,20 +114,18 @@ class FFConfig:
 
     # serving (flexflow_tpu.serving; upstream grew the same flags in
     # FlexFlow Serve's RequestManager): KV-cache slots, cache length per
-    # slot, scheduler kind, EOS token (-1 = none). ServeConfig.from_config
+    # slot, EOS token (-1 = none). ServeConfig.from_config
     # lifts these into the engine.
     serve_max_seqs: int = 8
     serve_max_seq_len: int = 256
-    serve_scheduler: str = "continuous"
     serve_eos_token: int = -1
-    # paged KV cache geometry (PagedAttention): layout "paged" | "slot",
-    # page size in tokens (0 = auto) and pool pages (0 = derived from
-    # max_seqs * max_seq_len so default capacity matches the slot layout)
-    serve_kv_layout: str = "paged"
+    # paged KV cache geometry (PagedAttention): page size in tokens
+    # (0 = auto) and pool pages (0 = max_seqs * max_seq_len / page size:
+    # every slot can reach max_seq_len)
     serve_kv_page_size: int = 0
     serve_kv_pages: int = 0
     # --kv-dtype: K/V pool element type, "fp32" | "int8" (int8 stores
-    # fp32 scales per page per head in side pools; paged layout only)
+    # fp32 scales per page per head in side pools)
     serve_kv_dtype: str = "fp32"
     # --prefix-cache: hashed prefix-page cache with copy-on-write
     # forking — admissions map content-matching full pages instead of
@@ -352,10 +350,6 @@ class FFConfig:
                 cfg.serve_max_seqs = int(take())
             elif a == "--max-seq-len":
                 cfg.serve_max_seq_len = int(take())
-            elif a == "--serve-scheduler":
-                cfg.serve_scheduler = take()
-            elif a == "--kv-layout":
-                cfg.serve_kv_layout = take()
             elif a == "--kv-page-size":
                 cfg.serve_kv_page_size = int(take())
             elif a == "--kv-pages":

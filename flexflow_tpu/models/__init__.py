@@ -3,7 +3,7 @@
 
 Each builder takes an FFModel + config kwargs, adds layers, and returns the
 logits Tensor; compilation/training stays with the caller (the examples/
-scripts and bench.py)."""
+scripts and benchmarks/families/)."""
 
 from flexflow_tpu.models.vision import (
     build_alexnet,

@@ -514,9 +514,8 @@ def paged_verify_attention(q, k_pool, v_pool, block_tables, lengths,
     """Verify attention against the block-paged cache. The dense path
     gathers each sequence's pages into a contiguous view (same
     dense-gather strategy as paged_decode_attention, same sentinel
-    clamping) and runs the exact verify_attention math, so paged verify
-    is token-identical to the slot layout; the kernel path walks the
-    table with no gather. With int8 pools, k_scale/v_scale
+    clamping) and runs the exact verify_attention math; the kernel path
+    walks the table with no gather. With int8 pools, k_scale/v_scale
     [num_pages, heads] fp32 dequantize the gathered pages in place —
     the fused-dequant chunk loop of the ISSUE. tree_parents [b, w]
     int32 switches the staircase to the token-tree ancestor mask
@@ -597,9 +596,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
 
     The dense path gathers each sequence's pages into a contiguous
     [b, max_pages_per_seq * page_size, h, d] view and runs the exact
-    decode_attention math, so paged serving is token-identical to the
-    slot layout: sentinel/unwritten pages land at positions > lengths
-    and the same -1e30 mask drops them before softmax. (The gather is a
+    decode_attention math: sentinel/unwritten pages land at positions >
+    lengths and the same -1e30 mask drops them before softmax. (The gather is a
     per-step temp the size of ONE dense cache view; the capacity win is
     in the persistent pool allocation, not this working set.) With int8
     pools, k_scale/v_scale [num_pages, heads] fp32 dequantize the

@@ -95,10 +95,9 @@ def _lib_supports(sq: int, sk: int, d: int) -> bool:
 
 def _lib_flash(q, k, v, causal: bool):
     """The public JAX Pallas TPU flash kernel ([b, h, s, d] layout) — a
-    hand-written fwd+bwd that beats the autodiff'd blockwise scan at long
-    sequence (measured on v5e, BENCH_LONGCTX.json: fwd+bwd 60 vs 75 ms at
-    seq 8192, and it compiles at 16384 where the scan formulation does
-    not)."""
+    hand-written fwd+bwd, chosen over the autodiff'd blockwise scan at
+    long sequence (scripts/bench_longctx.py is the comparison; no reading
+    of it is in the ledger, ROADMAP Queue 3 item 8)."""
     import math as _math
 
     from jax.experimental.pallas.ops.tpu.flash_attention import (

@@ -1533,17 +1533,15 @@ class FFModel:
         # the placement doc for fxlint strategy-validate
         max_seqs = knob("max_seqs", "serve_max_seqs", 8)
         max_seq_len = knob("max_seq_len", "serve_max_seq_len", 256)
-        num_pages = None
-        if knob("kv_layout", "serve_kv_layout", "paged") == "paged":
-            from flexflow_tpu.serving.kv_cache import default_page_size
+        from flexflow_tpu.serving.kv_cache import default_page_size
 
-            page_size = knob(
-                "kv_page_size", "serve_kv_page_size", 0
-            ) or default_page_size(max_seq_len)
-            num_pages = knob("kv_pages", "serve_kv_pages", 0) or (
-                max_seqs * max_seq_len // page_size
-            )
-            placement.validate_geometry(max_seqs, num_pages)
+        page_size = knob(
+            "kv_page_size", "serve_kv_page_size", 0
+        ) or default_page_size(max_seq_len)
+        num_pages = knob("kv_pages", "serve_kv_pages", 0) or (
+            max_seqs * max_seq_len // page_size
+        )
+        placement.validate_geometry(max_seqs, num_pages)
 
         def _serving_sharding(node, i, wshape):
             if node.op_type == OperatorType.MULTIHEAD_ATTENTION:

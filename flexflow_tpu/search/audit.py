@@ -28,9 +28,8 @@ ran, and the two numbers never met. This module closes that loop:
   (calibrate.py --fit-family remains the precision tool; this is the
   in-situ coarse pass).
 
-Entry points: `audit_cost_model(model, ...)` after `compile()` (also
-surfaced as `FFModel.audit_cost_model`), and `bench.py --audit` which
-writes BENCH_COST_AUDIT.json in CI.
+Entry point: `audit_cost_model(model, ...)` after `compile()` (also
+surfaced as `FFModel.audit_cost_model`).
 """
 
 from __future__ import annotations
@@ -102,8 +101,7 @@ class CostAuditResult:
         return "\n".join(lines)
 
     def to_doc(self) -> dict:
-        """The JSON shape fed back through update_calibration_doc and
-        written by bench.py --audit."""
+        """The JSON shape fed back through update_calibration_doc."""
         return {
             "predicted_step_ms": self.predicted_step_s * 1e3,
             "measured_step_ms": self.measured_step_s * 1e3,

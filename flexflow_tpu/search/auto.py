@@ -732,8 +732,9 @@ _DECODE_TP_OPS = {
 
 #: modeled host round-trip per decode dispatch/reconcile (device-resident
 #: multi-step decode amortizes this over K fused steps): dispatch
-#: enqueue + output materialization + scheduler bookkeeping — the tax
-#: BENCH_ASYNC measured dominating small-batch decode on the host side
+#: enqueue + output materialization + scheduler bookkeeping. An
+#: assumption, not a chip reading: PERF.md section 5 has the measured
+#: host gaps round a decode step
 DECODE_HOST_SYNC_S = 50e-6
 
 
@@ -877,12 +878,13 @@ def estimate_max_in_flight(
     (mean_prompt_len + mean_gen_len cached tokens each) fit in a
     per-chip KV byte budget.
 
-    Prices the layout through KVCacheSpec.total_bytes (one-sequence
-    spec): the slot layout charges every sequence max_len rows; the
-    paged layout charges ceil((prompt + gen) / page_size) whole pages —
-    the per-request footprint difference that lets paging admit more
-    short requests at the same budget. TP over heads divides the
-    per-chip row size, so a TP mesh fits proportionally more.
+    Prices the cache through KVCacheSpec.total_bytes (one-sequence
+    spec): a sequence is charged ceil((prompt + gen) / page_size) whole
+    pages; page_size 0 charges it max_len rows, one page as long as the
+    sequence may grow — the per-request footprint difference that lets
+    small pages admit more short requests at the same budget. TP over
+    heads divides the per-chip row size, so a TP mesh fits
+    proportionally more.
 
     `admission` picks WHICH per-sequence charge divides the budget:
     "optimistic" (the default, and the only policy a steady-state
@@ -929,29 +931,20 @@ def estimate_max_in_flight(
     else:
         fresh_prompt = int(round(mean_prompt_len * (1.0 - prefix_hit_rate)))
         seq_len = min(max_len, fresh_prompt + int(mean_gen_len))
-    if page_size > 0:
-        one = KVCacheSpec(
-            layer_guids=guids,
-            max_seqs=1,
-            max_len=max_len,
-            num_heads=heads_chip,
-            head_dim=head_dim,
-            buckets=(max_len,),
-            page_size=page_size,
-            num_pages=-(-max(1, seq_len) // page_size),
-            itemsize=1 if kv_dtype == "int8" else itemsize,
-            kv_dtype=kv_dtype,
-        )
-    else:
-        one = KVCacheSpec(
-            layer_guids=guids,
-            max_seqs=1,
-            max_len=max_len,
-            num_heads=heads_chip,
-            head_dim=head_dim,
-            buckets=(max_len,),
-            itemsize=itemsize,
-        )
+    if page_size <= 0:
+        page_size = max_len
+    one = KVCacheSpec(
+        layer_guids=guids,
+        max_seqs=1,
+        max_len=max_len,
+        num_heads=heads_chip,
+        head_dim=head_dim,
+        buckets=(max_len,),
+        page_size=page_size,
+        num_pages=-(-max(1, seq_len) // page_size),
+        itemsize=1 if kv_dtype == "int8" else itemsize,
+        kv_dtype=kv_dtype,
+    )
     per_seq = one.total_bytes
     return int(cache_bytes // per_seq) if per_seq else 0
 
@@ -1805,8 +1798,8 @@ def search_serving_strategy(
 ) -> ServingSearchResult:
     """Model-level entry: cost the compiled builder graph's decode regime
     on the config's machine (chip/nodes like the training search). kv_len
-    defaults to the config's serving cache length; the KV layout and page
-    geometry come from the config's --kv-layout/--kv-page-size flags, the
+    defaults to the config's serving cache length; the page geometry
+    comes from the config's --kv-page-size flag, the
     attention core's cost shape from --decode-kernel (resolved against
     the graph's cache geometry exactly like the engine resolves it), and
     a supplied length profile fills the winner's max_in_flight capacity
@@ -1816,11 +1809,9 @@ def search_serving_strategy(
     from flexflow_tpu.serving.kv_cache import default_page_size
 
     cfg = model.config
-    page_size = 0
-    if getattr(cfg, "serve_kv_layout", "paged") == "paged":
-        page_size = cfg.serve_kv_page_size or default_page_size(
-            cfg.serve_max_seq_len
-        )
+    page_size = cfg.serve_kv_page_size or default_page_size(
+        cfg.serve_max_seq_len
+    )
     decode_kernel = resolve_decode_kernel(
         getattr(cfg, "serve_decode_kernel", "auto"),
         model.graph,

@@ -3,8 +3,7 @@
 The training side of this rebuild compiles a PCG into one jitted train
 step; this package is the inference mirror (upstream FlexFlow grew the
 same subsystem as FlexFlow Serve): a block-paged KV cache with a
-host-side page allocator and block tables (kv_cache; the PR-1
-slot-contiguous layout remains as the kv_layout="slot" baseline),
+host-side page allocator and block tables (kv_cache),
 prefill/decode step functions that re-execute the
 compiled graph with a cache-aware attention hook (engine), an Orca-style
 iteration-level scheduler with per-request fault isolation, deadlines/
@@ -54,7 +53,6 @@ from flexflow_tpu.serving.journal import (
     recover_journal,
 )
 from flexflow_tpu.serving.kv_cache import (
-    KVCache,
     KVCacheSpec,
     PagedKVCache,
     PagePoolExhausted,
@@ -68,7 +66,6 @@ from flexflow_tpu.serving.scheduler import (
     Request,
     RequestStatus,
     SchedulerStats,
-    StaticBatchingScheduler,
     latency_percentiles,
 )
 from flexflow_tpu.serving.spec import (
@@ -107,7 +104,6 @@ __all__ = [
     "GenerationEngine",
     "InflightStep",
     "snapshot",
-    "KVCache",
     "KVCacheSpec",
     "PagedKVCache",
     "default_buckets",
@@ -117,7 +113,6 @@ __all__ = [
     "TERMINAL_STATUSES",
     "AsyncContinuousBatchingScheduler",
     "ContinuousBatchingScheduler",
-    "StaticBatchingScheduler",
     "SchedulerStats",
     "latency_percentiles",
     "FaultError",

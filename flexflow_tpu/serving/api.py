@@ -3,7 +3,7 @@
 `FFModel.generate` (runtime/model.py) delegates here, mirroring how the
 reference grew FlexFlow Serve on top of the training FFModel. ServeConfig
 rides FFConfig flag parsing (`--max-seqs`, `--max-seq-len`,
-`--serve-scheduler`, `--eos-token`, `--spec-draft`, `--spec-k`), so
+`--eos-token`, `--spec-draft`, `--spec-k`), so
 serving scripts configure the engine with the same CLI the training
 examples use.
 """
@@ -14,18 +14,12 @@ import dataclasses
 from typing import List, Optional, Sequence, Tuple
 
 from flexflow_tpu.serving.engine import GenerationEngine
-from flexflow_tpu.serving.kv_cache import KVCache, PagedKVCache
+from flexflow_tpu.serving.kv_cache import PagedKVCache
 from flexflow_tpu.serving.scheduler import (
     AsyncContinuousBatchingScheduler,
     ContinuousBatchingScheduler,
     Request,
-    StaticBatchingScheduler,
 )
-
-_SCHEDULERS = {
-    "continuous": ContinuousBatchingScheduler,
-    "static": StaticBatchingScheduler,
-}
 
 _SPEC_DRAFTS = ("", "ngram", "model")
 
@@ -37,27 +31,23 @@ class ServeConfig:
 
     max_seqs: int = 8  # KV-cache slots = max in-flight requests
     max_seq_len: int = 256  # max tokens per sequence (prompt + generation)
-    scheduler: str = "continuous"  # "continuous" | "static"
     eos_token: Optional[int] = None
     temperature: float = 0.0  # 0 = greedy
     seed: int = 0
     prefill_buckets: Tuple[int, ...] = ()  # () = powers of two
-    # KV-cache layout (PagedAttention, SOSP'23): "paged" pools pages and
-    # routes them through block tables; "slot" is the PR-1 contiguous
-    # [max_seqs, max_len] layout, kept as the equivalence/bench baseline.
-    kv_layout: str = "paged"
+    # the KV cache is paged (PagedAttention, SOSP'23): pools of pages
+    # routed through block tables
     kv_page_size: int = 0  # 0 = auto (vLLM-style 16, halved to divide max_len)
     kv_pages: int = 0  # 0 = max_seqs * max_seq_len / page_size (same capacity)
     # K/V pool element type (--kv-dtype): "int8" quantizes both pools
     # (fp32 scale per page per head in side pools, dequant fused into
-    # the per-chunk attention loop) for ~4x cache bytes; paged layout
-    # only — the slot layout has no per-page scale granularity.
+    # the per-chunk attention loop) for ~4x cache bytes.
     kv_dtype: str = "fp32"
     # hashed prefix-page cache (--prefix-cache): admissions map full
     # pages whose chained content hash matches an already-resident
     # prefix (refcounted, copy-on-write on first divergent write)
-    # instead of recomputing them; paged layout only — sharing is
-    # page-aligned by construction.
+    # instead of recomputing them; sharing is page-aligned by
+    # construction.
     prefix_cache: bool = False
     # speculative decoding (SpecInfer, ASPLOS'24; serving/spec.py):
     # "" = off, "ngram" = weight-free prompt-lookup draft, "model" = a
@@ -77,9 +67,9 @@ class ServeConfig:
     # token_budget > 0 caps each iteration's token work — prompts
     # stream into the cache in chunk_size-aligned chunks interleaved
     # with in-flight decodes instead of one monolithic admission
-    # prefill (the head-of-line blocking fix). 0 = off. Requires the
-    # continuous scheduler; auto.optimize_token_budget picks a budget
-    # that meets slo_ttft_ms / slo_itl_ms from the cost model.
+    # prefill (the head-of-line blocking fix). 0 = off.
+    # auto.optimize_token_budget picks a budget that meets
+    # slo_ttft_ms / slo_itl_ms from the cost model.
     token_budget: int = 0
     chunk_size: int = 16
     # decode/verify attention core (ops/pallas/decode_kernel.py):
@@ -88,19 +78,19 @@ class ServeConfig:
     # (interpret mode off-TPU — the CI/parity path), "dense" = always
     # the jnp paths.
     decode_kernel: str = "auto"
-    # admission policy for the paged layout (serving/scheduler.py):
+    # admission policy (serving/scheduler.py):
     # "reserve" gates each admit on its worst-case page need on top of
     # every in-flight reservation (preemption-free); "optimistic"
     # admits on the pages needed NOW and answers later pool exhaustion
     # with preemption-by-recompute, bounded by max_preemptions per
-    # request before hard FAILED. The slot layout ignores both.
+    # request before hard FAILED.
     admission: str = "reserve"
     max_preemptions: int = 3
     # async double-buffered engine (--serve-async): overlap host
     # scheduling with device steps — dispatch step N+1 while N is in
     # flight, reconcile terminal events one step late
-    # (AsyncContinuousBatchingScheduler). Continuous scheduler only;
-    # the sync loop stays the token-identical reference.
+    # (AsyncContinuousBatchingScheduler). The sync loop stays the
+    # token-identical reference.
     serve_async: bool = False
     # debug: re-run cache.check_invariants() after every scheduler
     # iteration (--check-invariants). Off by default — the full
@@ -126,9 +116,7 @@ class ServeConfig:
     # pod serving (serving/distributed.py): serve_mesh = "dp,tp" applies
     # a (data, model) serving mesh via FFModel.compile_for_serving;
     # serve_hosts > 0 partitions slots and the page pool across that
-    # many host shards (0 = auto: jax.process_count(), else dp). The
-    # multihost KV partition is paged-layout only — the slot layout has
-    # no page pool to shard.
+    # many host shards (0 = auto: jax.process_count(), else dp).
     serve_mesh: str = ""
     serve_hosts: int = 0
     # graceful degradation under pressure (serving/kv_cache.py +
@@ -174,8 +162,8 @@ class ServeConfig:
     # journal_fsync (--journal-fsync): "commit" fsyncs every record,
     # "batch" once per host sync (default), "off" flushes but never
     # fsyncs. journal_snapshot_every (--journal-snapshot-every): > 0
-    # journals a KV snapshot of every running slot each N iterations
-    # (paged layout), letting recovery restore KV over import_swap
+    # journals a KV snapshot of every running slot each N iterations,
+    # letting recovery restore KV over import_swap
     # instead of recomputing when build_restore_decider prices the
     # copy cheaper. door_max_pending (--door-max-pending): bounds the
     # front door's admission backlog; past it, per-class weighted-share
@@ -193,18 +181,8 @@ class ServeConfig:
     breaker_cooldown: int = 8
 
     def __post_init__(self):
-        if self.scheduler not in _SCHEDULERS:
-            raise ValueError(
-                f"scheduler must be one of {sorted(_SCHEDULERS)}, "
-                f"got {self.scheduler!r}"
-            )
         if self.max_seqs < 1 or self.max_seq_len < 2:
             raise ValueError("max_seqs >= 1 and max_seq_len >= 2 required")
-        if self.serve_async and self.scheduler != "continuous":
-            raise ValueError(
-                "serve_async requires the continuous scheduler (the "
-                "static baseline is deliberately synchronous)"
-            )
         if self.temperature < 0.0:
             raise ValueError(
                 f"temperature must be >= 0, got {self.temperature}"
@@ -216,10 +194,6 @@ class ServeConfig:
             )
         if self.max_preemptions < 0:
             raise ValueError("max_preemptions must be >= 0")
-        if self.kv_layout not in ("paged", "slot"):
-            raise ValueError(
-                f"kv_layout must be 'paged' or 'slot', got {self.kv_layout!r}"
-            )
         if self.kv_page_size < 0 or self.kv_pages < 0:
             raise ValueError("kv_page_size and kv_pages must be >= 0")
         if self.kv_page_size and self.max_seq_len % self.kv_page_size:
@@ -230,16 +204,6 @@ class ServeConfig:
         if self.kv_dtype not in ("fp32", "int8"):
             raise ValueError(
                 f"kv_dtype must be 'fp32' or 'int8', got {self.kv_dtype!r}"
-            )
-        if self.kv_dtype == "int8" and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_dtype='int8' requires kv_layout='paged' (the scale "
-                "side pools are per page per head)"
-            )
-        if self.prefix_cache and self.kv_layout != "paged":
-            raise ValueError(
-                "prefix_cache requires kv_layout='paged' (sharing is "
-                "page-aligned: whole pages map through block tables)"
             )
         if self.spec_draft not in _SPEC_DRAFTS:
             raise ValueError(
@@ -261,11 +225,6 @@ class ServeConfig:
                 f"chunk_size={self.chunk_size}"
             )
         if self.token_budget:
-            if self.scheduler != "continuous":
-                raise ValueError(
-                    "token_budget (chunked prefill) requires the "
-                    "continuous scheduler"
-                )
             if self.token_budget < self.chunk_size:
                 raise ValueError(
                     f"token_budget {self.token_budget} < chunk_size "
@@ -302,21 +261,10 @@ class ServeConfig:
                 f"serve_hosts must be >= 0 (0 = auto), got "
                 f"{self.serve_hosts}"
             )
-        if self.serve_hosts > 1 and self.kv_layout != "paged":
-            raise ValueError(
-                "multihost serving requires kv_layout='paged' (the host "
-                "partition shards the page pool; the slot layout has no "
-                "pool to shard)"
-            )
         if self.serve_mesh:
             from flexflow_tpu.serving.distributed import parse_serve_mesh
 
             parse_serve_mesh(self.serve_mesh)  # raises on malformed text
-        if self.kv_swap and self.kv_layout != "paged":
-            raise ValueError(
-                "kv_swap requires kv_layout='paged' (swap stages whole "
-                "pages; the slot layout has none)"
-            )
         if self.kv_swap_bytes < 0:
             raise ValueError(
                 f"kv_swap_bytes must be >= 0 (0 = unbounded), got "
@@ -336,12 +284,6 @@ class ServeConfig:
             raise ValueError(
                 f"max_fused_steps must be >= 1, got "
                 f"{self.max_fused_steps}"
-            )
-        if self.decode_multistep and self.scheduler == "static":
-            raise ValueError(
-                "decode_multistep requires the continuous scheduler "
-                "(the static baseline is the reference the fused loop "
-                "is proved identical against)"
             )
         if self.adapters < 0:
             raise ValueError(
@@ -367,11 +309,6 @@ class ServeConfig:
                 f"journal_snapshot_every must be >= 0 (0 = off), got "
                 f"{self.journal_snapshot_every}"
             )
-        if self.journal_snapshot_every and self.kv_layout != "paged":
-            raise ValueError(
-                "journal_snapshot_every requires kv_layout='paged' "
-                "(snapshots ride snapshot_swap, which stages whole pages)"
-            )
         if self.door_max_pending < 0:
             raise ValueError(
                 f"door_max_pending must be >= 0 (0 = unbounded), got "
@@ -387,6 +324,12 @@ class ServeConfig:
                 f"breaker_cooldown must be >= 1, got "
                 f"{self.breaker_cooldown}"
             )
+
+    @property
+    def kv_layout(self) -> str:
+        # read-only, for benchmarks/families/decoder_lm.py:63 and
+        # olmoe.py:62, which refuse to run unless this reads "paged"
+        return "paged"
 
     @property
     def telemetry_requested(self) -> bool:
@@ -406,12 +349,10 @@ class ServeConfig:
         return ServeConfig(
             max_seqs=cfg.serve_max_seqs,
             max_seq_len=cfg.serve_max_seq_len,
-            scheduler=cfg.serve_scheduler,
             eos_token=(
                 cfg.serve_eos_token if cfg.serve_eos_token >= 0 else None
             ),
             seed=cfg.seed,
-            kv_layout=cfg.serve_kv_layout,
             kv_page_size=cfg.serve_kv_page_size,
             kv_pages=cfg.serve_kv_pages,
             kv_dtype=cfg.serve_kv_dtype,
@@ -454,8 +395,7 @@ class ServeConfig:
 def build_telemetry(serve: ServeConfig):
     """The Telemetry bundle a ServeConfig asks for, or None when every
     telemetry knob is off — the scheduler/engine then skip every
-    instrument point on a single predicate (the ≤2%-overhead contract
-    bench_serve.py --telemetry gates). Thin wrapper over the generic
+    instrument point on a single predicate. Thin wrapper over the generic
     telemetry.build_telemetry, which also accepts an FFConfig or plain
     kwargs (the training/search entry points use it directly)."""
     from flexflow_tpu.telemetry import build_telemetry as _build
@@ -521,7 +461,7 @@ def build_scheduler(
 ):
     """(scheduler, engine, cache) wired to a compiled model — the pieces
     generate() uses, exposed for callers that drive iterations themselves
-    (bench_serve.py, tests). With serve.spec_draft set, the scheduler
+    (benchmarks/families/, tests). With serve.spec_draft set, the scheduler
     runs the speculative draft/verify loop (serving/spec.py). `injector`
     threads a faults.FaultInjector through the engine and scheduler
     seams — the chaos harness's entry point. `telemetry` threads a
@@ -543,42 +483,23 @@ def build_scheduler(
         # placement up (idempotent — an explicit compile_for_serving()
         # call beforehand wins)
         model.compile_for_serving(serve_config=serve)
-    placement = getattr(model, "serving_placement", None)
-    if (
-        placement is not None
-        and placement.num_hosts > 1
-        and serve.kv_layout != "paged"
-    ):
-        raise ValueError(
-            "multihost serving requires kv_layout='paged' (the host "
-            "partition shards the page pool; the slot layout has no "
-            "pool to shard)"
-        )
-    if serve.kv_layout == "paged":
-        cache = PagedKVCache.from_model(
-            model,
-            max_seqs=serve.max_seqs,
-            max_len=serve.max_seq_len,
-            buckets=serve.prefill_buckets or None,
-            page_size=serve.kv_page_size,
-            num_pages=serve.kv_pages,
-            kv_dtype=serve.kv_dtype,
-            prefix_cache=serve.prefix_cache,
-            prefix_evict=serve.prefix_evict,
-            swap_bytes_budget=serve.kv_swap_bytes,
-            evict_pricer=(
-                build_evict_pricer(model)
-                if serve.prefix_evict == "cost"
-                else None
-            ),
-        )
-    else:
-        cache = KVCache.from_model(
-            model,
-            max_seqs=serve.max_seqs,
-            max_len=serve.max_seq_len,
-            buckets=serve.prefill_buckets or None,
-        )
+    cache = PagedKVCache.from_model(
+        model,
+        max_seqs=serve.max_seqs,
+        max_len=serve.max_seq_len,
+        buckets=serve.prefill_buckets or None,
+        page_size=serve.kv_page_size,
+        num_pages=serve.kv_pages,
+        kv_dtype=serve.kv_dtype,
+        prefix_cache=serve.prefix_cache,
+        prefix_evict=serve.prefix_evict,
+        swap_bytes_budget=serve.kv_swap_bytes,
+        evict_pricer=(
+            build_evict_pricer(model)
+            if serve.prefix_evict == "cost"
+            else None
+        ),
+    )
     if telemetry is None:
         telemetry = build_telemetry(serve)
     adapters = None
@@ -606,10 +527,8 @@ def build_scheduler(
         from flexflow_tpu.serving.tenancy.fairness import parse_classes
 
         classes = parse_classes(serve.classes)
-    cls = _SCHEDULERS[serve.scheduler]
+    cls = ContinuousBatchingScheduler
     if serve.serve_async:
-        # __post_init__ already pinned serve_async to the continuous
-        # scheduler; the async loop is its double-buffered subclass
         cls = AsyncContinuousBatchingScheduler
     if scheduler_cls is not None:
         cls = scheduler_cls
@@ -686,7 +605,7 @@ def build_victim_pricer(model):
             dp,
             tp,
             resume_len,
-            page_size=getattr(cache.spec, "page_size", 0),
+            page_size=cache.spec.page_size,
             decode_kernel="dense",
         )
         if cost is None:
@@ -737,7 +656,7 @@ def build_swap_decider(model):
             dp,
             tp,
             resume_len,
-            page_size=getattr(cache.spec, "page_size", 0),
+            page_size=cache.spec.page_size,
             decode_kernel="dense",
         )
         if cost is None:
@@ -789,7 +708,7 @@ def build_restore_decider(model):
             dp,
             tp,
             int(resume_len),
-            page_size=getattr(cache.spec, "page_size", 0),
+            page_size=cache.spec.page_size,
             decode_kernel="dense",
         )
         if cost is None:

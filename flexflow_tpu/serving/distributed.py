@@ -112,18 +112,14 @@ class ServingPlacement:
     num_heads: int
     mesh_source: str = "flag"
 
-    def kv_sharding(self, ndim: int = 4):
-        """NamedSharding for both KV pool layouts. Slot pools are
-        (max_seqs, max_len, heads, head_dim) — slots on the data axis,
-        heads on the model axis. Paged pools are (num_pages, page_size,
-        heads * head_dim), `ndim` 3 — pages follow the data axis
-        (host-owned blocks), and the model axis cuts the folded last dim
-        into whole heads (it is heads-major)."""
+    def kv_sharding(self):
+        """NamedSharding of a KV pool, (num_pages, page_size, heads *
+        head_dim): pages follow the data axis (host-owned blocks), and
+        the model axis cuts the folded last dim into whole heads (it is
+        heads-major)."""
         from jax.sharding import NamedSharding, PartitionSpec
 
-        return NamedSharding(
-            self.mesh, PartitionSpec(*("data", None, "model", None)[:ndim])
-        )
+        return NamedSharding(self.mesh, PartitionSpec("data", None, "model"))
 
     def scale_sharding(self):
         """Quantized-pool scale tables are (num_pages, num_heads)."""

@@ -8,21 +8,20 @@ attention core:
 
   * **prefill**: causal dense attention over the (padded) prompt, exactly
     the training forward — and captures each layer's K/V, scattered into
-    the cache rows of the admitted slots. The last valid position's
+    the cache pages of the admitted slots. The last valid position's
     logits yield the first generated token, so admission itself produces
     a token (Orca's iteration-level view: a prefill is just a fat
     iteration).
   * **decode**: one query position per slot. The new K/V row is written
-    at `lengths[slot]` via a per-row dynamic_update_slice, then
-    `ops.attention.decode_attention` runs masked one-query attention
+    at position `lengths[slot]`, then
+    `ops.attention.paged_decode_attention` runs masked one-query attention
     against the cache — the dense jnp path, or the Pallas flash-decode
     kernel (ops/pallas/decode_kernel.py) when the engine's
     `decode_kernel` mode selects it ("auto" on TPU, "pallas" forced,
     "dense" pinned).
 
-The engine serves BOTH cache layouts (kv_cache.KVCache slot-contiguous,
-kv_cache.PagedKVCache block-paged) with the same hooks: the paged steps
-route K/V rows through the slot's block table — prefill scatters each
+The cache is block-paged (kv_cache.PagedKVCache): every step
+routes K/V rows through the slot's block table — prefill scatters each
 captured row into `page * page_size + offset` of the flattened pool
 (sentinel table entries produce out-of-bounds destinations that JAX
 drops, so pad rows and unallocated positions never touch live pages),
@@ -37,8 +36,8 @@ guarantees that claim).
 A third step family serves speculative decoding (serving/spec.py):
 **verify** scores w = k+1 token positions per slot (the last emitted
 token plus k drafted tokens) through the KV cache in ONE prefill-shaped
-call — K/V rows for all w positions are written (slot-scattered or
-table-routed exactly like prefill), `ops.attention.verify_attention`
+call — K/V rows for all w positions are written (table-routed
+exactly like prefill), `ops.attention.paged_verify_attention`
 runs the staircase-masked w-query attention, and the caller accepts a
 prefix of the drafts and commits/rolls back via
 `cache.truncate(slot, new_len)` (verify itself never advances lengths).
@@ -59,9 +58,8 @@ discards the rest).
 All steps are jitted with static shapes: decode always runs at
 `[max_seqs, 1]`, prefill at `[max_seqs, bucket]` per length bucket,
 verify at `[max_seqs, w]` per draft width, so compile count is
-1 + #buckets + #draft-widths for an entire serving session — paging
-does not change the compile-count contract (tables are data, not
-shape).
+1 + #buckets + #draft-widths for an entire serving session (tables
+are data, not shape).
 
 Every step program OWNS the KV pools it is handed: `_step_jit`, the one
 place a program's jit is built, donates them, so the scatter writes
@@ -93,7 +91,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 import logging
 import time
 from collections import OrderedDict
@@ -128,8 +125,8 @@ class PoolsLostError(RuntimeError):
 
 
 #: the arguments a step program rewrites and returns, by the name every
-#: `_*_impl` gives them: K and V pools and, in the paged programs, the
-#: int8 scale pools. These, and nothing else, are donated.
+#: `_*_impl` gives them: K and V pools and the int8 scale pools.
+#: These, and nothing else, are donated.
 _POOL_ARGNAMES = ("ck", "cv", "cks", "cvs")
 
 
@@ -421,16 +418,13 @@ class GenerationEngine:
         # per-iteration dynamic seq truncation is a training knob; a stale
         # value would truncate serving activations mid-stack
         self.executor.set_seq_length(None)
-        self.paged = bool(getattr(cache, "paged", False))
         # step programs that found the pools they were handed consumed by
         # the call (the donation engaged: the rows were written in place),
         # and those that found them still alive (the backend declined it
         # and copied). One host check of one pool leaf after each dispatch
         self.pool_steps_donated = 0
         self.pool_steps_copied = 0
-        self._decode_jit = self._step_jit(
-            self._decode_impl, self._decode_impl_paged
-        )
+        self._decode_jit = self._step_jit(self._decode_impl_paged)
         # one jitted prefill per length bucket / one jitted verify per
         # draft width (jit caches by shape anyway; the explicit caches
         # make the compile-count contract inspectable). The verify,
@@ -442,41 +436,31 @@ class GenerationEngine:
         # executable) alive for the engine's whole life.
         self._prefill_cache: Dict[int, object] = {}
         self._verify_cache = _JitCache(
-            lambda w: self._step_jit(
-                self._verify_impl, self._verify_impl_paged
-            )
+            lambda w: self._step_jit(self._verify_impl_paged)
         )
         # chunked-prefill programs, one per compact batch shape (B, w) —
         # the scheduler pads widths to multiples of chunk_size, so the
         # population is budget/chunk_size distinct widths at most
         self._chunk_cache = _JitCache(
-            lambda key: self._step_jit(
-                self._chunk_impl, self._chunk_impl_paged
-            )
+            lambda key: self._step_jit(self._chunk_impl_paged)
         )
-        # multi-step decode scan programs, one per (B, K-bucket, layout)
-        # key — K buckets are powers of two, so the population is
+        # multi-step decode scan programs, one per (B, K-bucket) key —
+        # K buckets are powers of two, so the population is
         # log2(max_fused_steps) at most
         self._multistep_cache = _JitCache(
-            lambda key: self._step_jit(
-                self._decode_multi_impl,
-                self._decode_multi_impl_paged,
-                key[1],
-            )
+            lambda key: self._step_jit(self._decode_multi_impl_paged, key[1])
         )
         # tree-verify programs, one per row width w = 1 + tree nodes.
         # Kept apart from `_verify_cache` because the tree impl carries
         # an extra parent-table operand; the scheduler pins a single
         # node budget, so the steady-state population is one entry
         self._tree_cache = _JitCache(
-            lambda w: self._step_jit(
-                self._verify_tree_impl, self._verify_tree_impl_paged
-            )
+            lambda w: self._step_jit(self._verify_tree_impl_paged)
         )
 
-    def _step_jit(self, slot_impl, paged_impl, *bound):
-        """The one place a step program's jit is built: the impl of this
-        engine's cache layout (`bound`: its leading trace-time constants),
+    def _step_jit(self, impl, *bound):
+        """The one place a step program's jit is built: `impl` (`bound`:
+        its leading trace-time constants),
         jitted with the pools it rewrites and returns DONATED. XLA may
         not write into a parameter it does not own, so an undonated pool
         is copied whole into the output buffer in front of every
@@ -486,13 +470,9 @@ class GenerationEngine:
         differ between the programs."""
         import jax
 
-        impl = paged_impl if self.paged else slot_impl
-        donate = tuple(
-            n for n in _POOL_ARGNAMES if n in inspect.signature(impl).parameters
-        )
         if bound:
             impl = functools.partial(impl, *bound)
-        return jax.jit(impl, donate_argnames=donate)
+        return jax.jit(impl, donate_argnames=_POOL_ARGNAMES)
 
     @property
     def verify_cache_entries(self) -> int:
@@ -587,8 +567,8 @@ class GenerationEngine:
         """Call one step program on the live pools, commit the pools it
         returns, and hand back the rest of its outputs.
 
-        Every step program has this shape: `(params, *inputs, ck, cv
-        [, cks, cvs] [, ad])` in, `(ck', cv' [, cks', cvs'], ...)` out,
+        Every step program has this shape: `(params, *inputs, ck, cv,
+        cks, cvs [, ad])` in, `(ck', cv', cks', cvs', ...)` out,
         with the pools donated (`_step_jit`). So after the call the
         arrays that went in are gone, and the only live pools are the
         ones `commit` stores here: nothing else may keep a pool array
@@ -604,11 +584,7 @@ class GenerationEngine:
                 f"{site} step not dispatched: the KV pools were consumed "
                 "by an earlier step program that failed"
             )
-        pools = (
-            (cache.k, cache.v, cache.k_scale, cache.v_scale)
-            if self.paged
-            else (cache.k, cache.v)
-        )
+        pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
         args = (params, *inputs, *pools, *adapter_args)
 
         def call():
@@ -704,9 +680,7 @@ class GenerationEngine:
         # the jitted steps baked the failed mode in at trace time;
         # rebuild them so the retry traces the dense attention cores
         # (prefill never touches the kernel, so its cache stands)
-        self._decode_jit = self._step_jit(
-            self._decode_impl, self._decode_impl_paged
-        )
+        self._decode_jit = self._step_jit(self._decode_impl_paged)
         self._verify_cache.clear()
         self._chunk_cache.clear()
         self._multistep_cache.clear()
@@ -720,6 +694,16 @@ class GenerationEngine:
             self.device_syncs += len(out)
             self.readback_bytes += sum(a.nbytes for a in out)
         return out
+
+    def _claim_rows(self, widths) -> None:
+        """Claim every page a step's fresh rows touch BEFORE the jitted
+        step: slot s writes positions lengths[s] .. lengths[s] +
+        widths[s] - 1 (host-side allocator; the admission reserve
+        guarantees the claims, and a shared page forks here)."""
+        for slot in np.nonzero(widths)[0]:
+            start = int(self.cache.lengths[slot])
+            for p in range(start, start + int(widths[slot])):
+                self.cache.ensure_position(int(slot), p)
 
     # -- shared forward ------------------------------------------------------
 
@@ -865,82 +849,24 @@ class GenerationEngine:
 
     # -- prefill -------------------------------------------------------------
 
-    def _prefill_impl(
-        self, params, tokens, slot_ids, prompt_lens, ck, cv, ad=None
-    ):
-        """tokens [max_seqs, bucket] int32; slot_ids [max_seqs] (max_seqs
-        = out-of-bounds sentinel for padding rows — JAX drops OOB scatter
-        rows, so pad rows never touch live cache); prompt_lens [max_seqs]
-        (>=1; pad rows use 1). `ad` is the optional batch-row-aligned
-        adapter gather (tables, has, pools) — None leaves the traced HLO
-        exactly the base engine's. Returns (ck', cv', next_tokens,
-        last_logits), and for a model with expert layers their [2] int32
-        (rows computed, experts touched) after them."""
-        import jax.numpy as jnp
-
-        from flexflow_tpu.ops.attention import (
-            mha_project_qkv,
-            mha_project_out,
-            scaled_dot_product_attention,
-        )
-        from flexflow_tpu.serving.tenancy.adapters import (
-            apply_adapter_out,
-            apply_adapter_qkv,
-        )
-
-        captured_k: Dict[int, object] = {}
-        captured_v: Dict[int, object] = {}
-
-        positions = self._positions(lambda: jnp.arange(tokens.shape[1]))
-
-        def hook(node, ins, ws, ctx):
-            use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(
-                ins, ws, ctx, use_bias=use_bias, params=node.params,
-                positions=positions,
-            )
-            q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, node.guid)
-            captured_k[node.guid] = k
-            captured_v[node.guid] = v
-            attn = scaled_dot_product_attention(q, k, v, causal=True)
-            out = mha_project_out(
-                attn, ws, ctx, ins[0].dtype, use_bias=use_bias
-            )
-            return [apply_adapter_out(attn, out, ad, node.guid)]
-
-        moe = []
-        logits = self._forward_logits(params, tokens, hook, moe)
-        bucket = tokens.shape[1]
-        new_k, new_v = {}, {}
-        for g in self.cache.spec.layer_guids:
-            new_k[g] = ck[g].at[slot_ids, :bucket].set(
-                captured_k[g].astype(ck[g].dtype)
-            )
-            new_v[g] = cv[g].at[slot_ids, :bucket].set(
-                captured_v[g].astype(cv[g].dtype)
-            )
-        last = jnp.take_along_axis(
-            logits, (prompt_lens - 1)[:, None, None], axis=1
-        )[:, 0]
-        # the sampled token will be written at cache position prompt_lens
-        return (
-            new_k, new_v, self._pick(last, slot_ids, prompt_lens), last,
-            *self._moe_total(moe),
-        )
-
     def _prefill_impl_paged(
         self, params, tokens, slot_ids, row_tables, prompt_lens, ck, cv,
         cks, cvs, ad=None,
     ):
-        """Paged twin of _prefill_impl. row_tables [max_seqs,
-        ceil(bucket/page_size)] int32: the admitted slots' block-table
-        prefixes (pad rows and unallocated entries carry the sentinel
-        num_pages). slot_ids only seed the per-slot sampling keys here —
-        routing is entirely through the tables. Captured K/V rows scatter
+        """tokens [max_seqs, bucket] int32; slot_ids [max_seqs] (max_seqs
+        for padding rows) only seed the per-slot sampling keys — routing
+        is entirely through row_tables [max_seqs, ceil(bucket/page_size)]
+        int32: the admitted slots' block-table prefixes (pad rows and
+        unallocated entries carry the sentinel num_pages); prompt_lens
+        [max_seqs] (>=1; pad rows use 1). Captured K/V rows scatter
         into the flattened pools at `page * page_size + offset`; sentinel
         pages put the destination out of bounds, which JAX drops — so
-        bucket padding past a prompt's allocated pages writes nothing,
-        where the slot layout writes (masked) garbage rows."""
+        bucket padding past a prompt's allocated pages writes nothing.
+        `ad` is the optional batch-row-aligned adapter gather (tables,
+        has, pools) — None leaves the traced HLO exactly the base
+        engine's. Returns (ck', cv', cks', cvs', next_tokens,
+        last_logits), and for a model with expert layers their [2] int32
+        (rows computed, experts touched) after them."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -959,7 +885,7 @@ class GenerationEngine:
         pos = jnp.arange(bucket)
         # [max_seqs, bucket] flat pool destinations through the table
         dest = (row_tables[:, pos // ps] * ps + pos % ps).reshape(-1)
-        quant = getattr(self.cache, "quantized", False)
+        quant = self.cache.quantized
         new_k, new_v = {}, {}
         new_ks, new_vs = dict(cks), dict(cvs)
 
@@ -1050,20 +976,18 @@ class GenerationEngine:
             self.prefill_tokens_padded += spec.max_seqs * bucket
             fn = self._prefill_cache.get(bucket)
             if fn is None:
-                fn = self._step_jit(
-                    self._prefill_impl, self._prefill_impl_paged
-                )
+                fn = self._step_jit(self._prefill_impl_paged)
                 self._prefill_cache[bucket] = fn
-            route = [jnp.asarray(slot_ids)]
-            if self.paged:
-                width = -(-bucket // spec.page_size)
-                row_tables = np.full(
-                    (spec.max_seqs, width), spec.num_pages, dtype=np.int32
-                )
-                for i, s in enumerate(slots):
-                    row_tables[i] = self.cache.block_tables[s, :width]
-                route.append(jnp.asarray(row_tables))
-            inputs = (jnp.asarray(tokens), *route, jnp.asarray(plens))
+            width = -(-bucket // spec.page_size)
+            row_tables = np.full(
+                (spec.max_seqs, width), spec.num_pages, dtype=np.int32
+            )
+            for i, s in enumerate(slots):
+                row_tables[i] = self.cache.block_tables[s, :width]
+            inputs = (
+                jnp.asarray(tokens), jnp.asarray(slot_ids),
+                jnp.asarray(row_tables), jnp.asarray(plens),
+            )
         with span("scheduler.step.prefill.dispatch", self._tracer):
             (nxt, last), moe = self._split_moe(
                 self._run_step(
@@ -1126,100 +1050,25 @@ class GenerationEngine:
 
     # -- decode --------------------------------------------------------------
 
-    def _decode_core(
-        self, params, tokens, lengths, active, ck, cv, ad=None, moe=None
-    ):
-        """One decode forward over the slot-contiguous cache: write the
-        new K/V row per active slot at `lengths`, run masked one-query
-        attention, return (ck', cv', logits [max_seqs, V]). The
-        single-step jit and the multi-step scan body both trace THIS
-        function, so their HLO op sequence — and therefore their
-        logits — match exactly (the token/logit-identity contract).
-        `ad=None` (no adapter pool) leaves the traced HLO byte-for-byte
-        what it was before multi-LoRA existed. `moe`: the list that
-        receives the expert layers' counts (`_forward_logits`); the
-        single-step program returns them, the scan does not."""
-        import jax
-        import jax.numpy as jnp
-
-        from flexflow_tpu.ops.attention import (
-            decode_attention,
-            mha_project_qkv,
-            mha_project_out,
-        )
-        from flexflow_tpu.serving.tenancy.adapters import (
-            apply_adapter_out,
-            apply_adapter_qkv,
-        )
-
-        new_k = dict(ck)
-        new_v = dict(cv)
-
-        def row_update(cache, new):
-            upd = jax.vmap(
-                lambda c, nrow, pos: jax.lax.dynamic_update_slice(
-                    c, nrow, (pos, 0, 0)
-                )
-            )(cache, new.astype(cache.dtype), lengths)
-            return jnp.where(active[:, None, None, None], upd, cache)
-
-        positions = self._positions(lambda: lengths[:, None])
-
-        def hook(node, ins, ws, ctx):
-            g = node.guid
-            use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(
-                ins, ws, ctx, use_bias=use_bias, params=node.params,
-                positions=positions,
-            )
-            # LoRA deltas land BEFORE the cache write: the K/V rows the
-            # pool stores are the adapted values, so the attention
-            # kernel (dense or Pallas) never needs to know adapters
-            # exist — the out-projection delta below is the only
-            # post-kernel epilogue
-            q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
-            kc = row_update(ck[g], k)
-            vc = row_update(cv[g], v)
-            new_k[g] = kc
-            new_v[g] = vc
-            attn = decode_attention(
-                q, kc, vc, lengths, **self._attn_core
-            )
-            out = mha_project_out(
-                attn, ws, ctx, ins[0].dtype, use_bias=use_bias
-            )
-            return [apply_adapter_out(attn, out, ad, g)]
-
-        logits = self._forward_logits(params, tokens, hook, moe)[:, -1, :]
-        return new_k, new_v, logits
-
-    def _decode_impl(self, params, tokens, lengths, active, ck, cv, ad=None):
-        """tokens [max_seqs, 1]; lengths [max_seqs] = cache position the
-        incoming token is written at; active [max_seqs] bool masks cache
-        writes for free slots."""
-        import jax.numpy as jnp
-
-        moe = []
-        new_k, new_v, logits = self._decode_core(
-            params, tokens, lengths, active, ck, cv, ad, moe
-        )
-        slots = jnp.arange(lengths.shape[0])
-        # the sampled token will be written at cache position lengths + 1
-        return (
-            new_k, new_v, self._pick(logits, slots, lengths + 1), logits,
-            *self._moe_total(moe),
-        )
-
     def _decode_core_paged(
         self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
         ad=None, moe=None,
     ):
-        """Paged twin of _decode_core. tables [max_seqs,
-        max_pages_per_seq] int32 block tables. The new K/V row scatters
+        """One decode forward: tokens [max_seqs, 1]; lengths [max_seqs]
+        = cache position the incoming token is written at; active
+        [max_seqs] bool; tables [max_seqs, max_pages_per_seq] int32
+        block tables. The new K/V row scatters
         into `tables[slot, lengths // page_size] * page_size + lengths %
         page_size` of the flattened pool; inactive slots are routed to an
-        out-of-bounds destination (dropped), replacing the contiguous
-        path's where-mask. Returns (ck', cv', cks', cvs', logits)."""
+        out-of-bounds destination (dropped). The single-step jit and
+        the multi-step scan body both trace THIS function, so their HLO
+        op sequence — and therefore their logits — match exactly (the
+        token/logit-identity contract). `ad=None` (no adapter pool)
+        leaves the traced HLO byte-for-byte what it was before
+        multi-LoRA existed. `moe`: the list that receives the expert
+        layers' counts (`_forward_logits`); the single-step program
+        returns them, the scan does not. Returns (ck', cv', cks', cvs',
+        logits [max_seqs, V])."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -1235,7 +1084,7 @@ class GenerationEngine:
         spec = self.cache.spec
         ps = spec.page_size
         oob = spec.num_pages * ps
-        quant = getattr(self.cache, "quantized", False)
+        quant = self.cache.quantized
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks, new_vs = dict(cks), dict(cvs)
@@ -1254,7 +1103,9 @@ class GenerationEngine:
                 positions=positions,
             )
             # adapted K/V go INTO the pool (delta precedes the scatter),
-            # so the Pallas kernel reads adapter-aware pages unchanged
+            # so the attention core (dense or Pallas) reads adapter-aware
+            # pages unchanged: the out-projection delta below is the only
+            # post-kernel epilogue
             q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
             if quant:
                 kc, new_ks[g], _ = self._quant_scatter(
@@ -1287,8 +1138,9 @@ class GenerationEngine:
         self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
         ad=None,
     ):
-        """Paged twin of _decode_impl (the single-step jit target):
-        one _decode_core_paged forward plus the per-slot sample."""
+        """The single-step jit target: one _decode_core_paged forward
+        plus the per-slot sample (the sampled token will be written at
+        cache position lengths + 1)."""
         import jax.numpy as jnp
 
         moe = []
@@ -1309,58 +1161,6 @@ class GenerationEngine:
 
     # -- device-resident multi-step decode -----------------------------------
 
-    def _decode_multi_impl(
-        self, k_bucket, params, tokens, lengths, active, limits, eos, ck, cv,
-        ad=None,
-    ):
-        """K fused decode iterations as ONE jitted `lax.scan` — the
-        device-resident inner loop. tokens [max_seqs] int32 (the last
-        emitted token per slot); lengths [max_seqs] pre-window cache
-        lengths; active [max_seqs] bool; limits [max_seqs] int32
-        PER-SLOT fused-step caps (a budget- or boundary-capped slot
-        stops contributing at its own limit while deeper slots keep
-        fusing); eos [max_seqs] int32 EOS token id per slot (-1 =
-        none). Each scan step traces the SAME `_decode_core` the
-        single-step jit traces, then samples with the identical
-        position-derived `_pick` key — fold_in(fold_in(seed, slot),
-        position) depends only on the running length, never the step
-        counter, so the fused stream is identical-by-construction to
-        step-at-a-time. EOS detection, length bumps, and
-        retire-the-slot masking all live in the scan carry; `k_bucket`
-        is the trace-time scan length (the pow-2 bucket the dispatch
-        rounds K up to — steps past a slot's limit are masked out).
-
-        Returns (ck', cv', final_lengths, final_tokens,
-        tokens_ks [K, max_seqs], logits_ks [K, max_seqs, V],
-        mask_ks [K, max_seqs]) — the per-step stacks the window
-        reconcile slices to the true K."""
-        import jax
-        import jax.numpy as jnp
-
-        slots = jnp.arange(lengths.shape[0])
-
-        def body(carry, i):
-            ck_c, cv_c, lens, toks, alive = carry
-            act = alive & (i < limits)
-            nk, nv, logits = self._decode_core(
-                params, toks[:, None], lens, act, ck_c, cv_c, ad
-            )
-            nxt = self._pick(logits, slots, lens + 1)
-            hit = act & (eos >= 0) & (nxt == eos)
-            new_lens = jnp.where(act, lens + 1, lens)
-            new_toks = jnp.where(act, nxt, toks)
-            return (nk, nv, new_lens, new_toks, alive & ~hit), (
-                nxt,
-                logits,
-                act,
-            )
-
-        carry0 = (ck, cv, lengths, tokens, active)
-        (nk, nv, lens, toks, _), (toks_ks, logits_ks, mask_ks) = jax.lax.scan(
-            body, carry0, jnp.arange(k_bucket)
-        )
-        return nk, nv, lens, toks, toks_ks, logits_ks, mask_ks
-
     def _decode_multi_impl_paged(
         self,
         k_bucket,
@@ -1377,14 +1177,35 @@ class GenerationEngine:
         cvs,
         ad=None,
     ):
-        """Paged twin of _decode_multi_impl. The block tables ride in
+        """K fused decode iterations as ONE jitted `lax.scan` — the
+        device-resident inner loop. tokens [max_seqs] int32 (the last
+        emitted token per slot); lengths [max_seqs] pre-window cache
+        lengths; active [max_seqs] bool; limits [max_seqs] int32
+        PER-SLOT fused-step caps (a budget- or boundary-capped slot
+        stops contributing at its own limit while deeper slots keep
+        fusing); eos [max_seqs] int32 EOS token id per slot (-1 =
+        none). Each scan step traces the SAME `_decode_core_paged` the
+        single-step jit traces, then samples with the identical
+        position-derived `_pick` key — fold_in(fold_in(seed, slot),
+        position) depends only on the running length, never the step
+        counter, so the fused stream is identical-by-construction to
+        step-at-a-time. EOS detection, length bumps, and
+        retire-the-slot masking all live in the scan carry; `k_bucket`
+        is the trace-time scan length (the pow-2 bucket the dispatch
+        rounds K up to — steps past a slot's limit are masked out).
+        The block tables ride in
         as ONE trace-time snapshot: the dispatch pre-claims every page
         the window can touch (the scheduler's per-slot limits never
         cross more than one fresh page — the page-boundary K cap), so
         the scan body recomputes each step's scatter destination from
         the carried lengths against STATIC tables. int8 scale pools
         ride the carry through `_quant_scatter` exactly like the
-        single-step path."""
+        single-step path.
+
+        Returns (ck', cv', cks', cvs', final_lengths, final_tokens,
+        tokens_ks [K, max_seqs], logits_ks [K, max_seqs, V],
+        mask_ks [K, max_seqs]) — the per-step stacks the window
+        reconcile slices to the true K."""
         import jax
         import jax.numpy as jnp
 
@@ -1440,16 +1261,8 @@ class GenerationEngine:
         resolves entirely on device."""
         import jax.numpy as jnp
 
-        args = []
-        if self.paged:
-            # claim the next page for any sequence about to cross a page
-            # boundary BEFORE the jitted step (host-side allocator; the
-            # admission reserve guarantees the claim succeeds)
-            for slot in np.nonzero(np.asarray(active_mask))[0]:
-                self.cache.ensure_position(
-                    int(slot), int(self.cache.lengths[slot])
-                )
-            args = [snapshot(self.cache.block_tables)]
+        # the next page, for any sequence about to cross a page boundary
+        self._claim_rows(np.asarray(active_mask, dtype=np.int32))
         host_tokens = np.asarray(tokens, dtype=np.int32)
         mask = (
             np.asarray(chain_mask, dtype=bool)
@@ -1484,7 +1297,7 @@ class GenerationEngine:
                     dev_tokens[:, None],
                     snapshot(self.cache.lengths),
                     jnp.asarray(active_mask),
-                    *args,
+                    snapshot(self.cache.block_tables),
                 ),
                 self._adapter_slot_args(),
             )
@@ -1590,19 +1403,11 @@ class GenerationEngine:
         # bucketing keeps the compile population log-bounded; the
         # per-slot limits mask the bucket's surplus steps out
         k_bucket = 1 << (k - 1).bit_length()
-        args = []
-        if self.paged:
-            # pre-claim every page the window can touch BEFORE the
-            # jitted scan: the block tables ride in as one trace-time
-            # snapshot, so all K steps' destinations must already map
-            # (the admission reserve guarantees these claims; the
-            # scheduler's page-boundary K cap keeps them to at most one
-            # fresh page per slot)
-            for slot in np.nonzero(limits)[0]:
-                start = int(lengths_snap[slot])
-                for p in range(start, start + int(limits[slot])):
-                    self.cache.ensure_position(int(slot), p)
-            args = [snapshot(self.cache.block_tables)]
+        # the block tables ride in as one trace-time snapshot, so all K
+        # steps' destinations must already map (the scheduler's
+        # page-boundary K cap keeps the claims to at most one fresh page
+        # per slot)
+        self._claim_rows(limits)
         host_tokens = np.asarray(tokens, dtype=np.int32)
         eos = (
             np.asarray(eos_tokens, dtype=np.int32)
@@ -1627,7 +1432,7 @@ class GenerationEngine:
         # snapshot() every mutable host array (lengths += limits below,
         # allocator table edits between iterations mutate behind the
         # async dispatch queue); see decode_dispatch()
-        key = (spec.max_seqs, k_bucket, "paged" if self.paged else "slot")
+        key = (spec.max_seqs, k_bucket)
         d_lens, d_toks, toks_ks, logits_ks, mask_ks = self._run_step(
             "multistep",
             lambda: self._multistep_cache.get(key),
@@ -1638,7 +1443,7 @@ class GenerationEngine:
                 jnp.asarray(np.asarray(active_mask, dtype=bool)),
                 jnp.asarray(limits),
                 jnp.asarray(eos),
-                *args,
+                snapshot(self.cache.block_tables),
             ),
             self._adapter_slot_args(),
             program=("multistep", key),
@@ -1706,112 +1511,43 @@ class GenerationEngine:
 
     # -- verify (speculative decoding) ---------------------------------------
 
-    def _verify_scatter_dest(
-        self, w, lengths, draft_lens, tables, jnp, slot_ids=None
-    ):
-        """Flattened-cache destinations [batch * w] for the verify
+    def _verify_scatter_dest(self, w, lengths, draft_lens, tables, jnp):
+        """Flattened-pool destinations [batch * w] for the verify
         write: row j of batch row b lands at cache position
         lengths[b] + j when j < draft_lens[b] and the position is
         inside max_len; every other row routes out of bounds (JAX
         drops OOB scatter rows), so pad rows, inactive slots, and
-        overflow never touch live cache. The batch is slot-indexed
-        (batch row == slot) unless `slot_ids` maps a COMPACT batch's
-        rows to their slots (the chunked-prefill path); the paged
-        branch needs no ids because `tables` rows arrive already
-        batch-aligned."""
+        overflow never touch live cache. `tables` rows arrive
+        batch-aligned (slot-indexed, or gathered to a COMPACT batch's
+        rows by the chunked-prefill path)."""
         spec = self.cache.spec
         pos = lengths[:, None] + jnp.arange(w)[None, :]  # [batch, w]
         valid = (jnp.arange(w)[None, :] < draft_lens[:, None]) & (
             pos < spec.max_len
         )
-        if self.paged:
-            ps = spec.page_size
-            page_idx = jnp.clip(pos // ps, 0, spec.max_pages_per_seq - 1)
-            entry = jnp.take_along_axis(tables, page_idx, axis=1)
-            # sentinel entries (num_pages) already land past the pool
-            flat = entry * ps + pos % ps
-            oob = spec.num_pages * ps
-        else:
-            rows = (
-                jnp.arange(spec.max_seqs) if slot_ids is None else slot_ids
-            )
-            flat = rows[:, None] * spec.max_len + pos
-            oob = spec.max_seqs * spec.max_len
-        return jnp.where(valid, flat, oob).reshape(-1)
-
-    def _verify_impl(
-        self, params, tokens, lengths, draft_lens, ck, cv, ad=None
-    ):
-        """tokens [max_seqs, w] int32 — column 0 is each slot's last
-        emitted (not yet cached) token, columns 1..draft_lens-1 the
-        drafted continuation; lengths [max_seqs] = cache length BEFORE
-        the step; draft_lens [max_seqs] = real rows per slot (0 for
-        inactive slots). Writes all w K/V rows (masked via OOB scatter),
-        runs staircase-masked verify attention, and returns
-        (ck', cv', logits [max_seqs, w, V]) — logits[s, j] is the
-        model's distribution for the token FOLLOWING tokens[s, j].
-        Lengths are NOT advanced; acceptance commits via
-        cache.truncate."""
-        import jax.numpy as jnp
-
-        from flexflow_tpu.ops.attention import (
-            mha_project_qkv,
-            mha_project_out,
-            verify_attention,
-        )
-        from flexflow_tpu.serving.tenancy.adapters import (
-            apply_adapter_out,
-            apply_adapter_qkv,
-        )
-
-        spec = self.cache.spec
-        dest = self._verify_scatter_dest(
-            tokens.shape[1], lengths, draft_lens, None, jnp
-        )
-        new_k = dict(ck)
-        new_v = dict(cv)
-
-        def row_update(cache, new):
-            flat = cache.reshape(-1, spec.num_heads, spec.head_dim)
-            rows = new.astype(cache.dtype).reshape(
-                -1, spec.num_heads, spec.head_dim
-            )
-            return flat.at[dest].set(rows).reshape(cache.shape)
-
-        positions = self._positions(
-            lambda: lengths[:, None] + jnp.arange(tokens.shape[1])[None, :]
-        )
-
-        def hook(node, ins, ws, ctx):
-            g = node.guid
-            use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(
-                ins, ws, ctx, use_bias=use_bias, params=node.params,
-                positions=positions,
-            )
-            q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
-            kc = row_update(ck[g], k)
-            vc = row_update(cv[g], v)
-            new_k[g] = kc
-            new_v[g] = vc
-            attn = verify_attention(
-                q, kc, vc, lengths, **self._attn_core
-            )
-            out = mha_project_out(
-                attn, ws, ctx, ins[0].dtype, use_bias=use_bias
-            )
-            return [apply_adapter_out(attn, out, ad, g)]
-
-        logits = self._forward_logits(params, tokens, hook)
-        return new_k, new_v, logits
+        ps = spec.page_size
+        page_idx = jnp.clip(pos // ps, 0, spec.max_pages_per_seq - 1)
+        entry = jnp.take_along_axis(tables, page_idx, axis=1)
+        # sentinel entries (num_pages) already land past the pool
+        flat = entry * ps + pos % ps
+        return jnp.where(valid, flat, spec.num_pages * ps).reshape(-1)
 
     def _verify_impl_paged(
         self, params, tokens, lengths, draft_lens, tables, ck, cv, cks, cvs,
         ad=None,
     ):
-        """Paged twin of _verify_impl: rows route through the block
-        tables into the flattened pools, attention gathers pages via
-        ops.attention.paged_verify_attention. Under int8 pools the w
+        """tokens [max_seqs, w] int32 — column 0 is each slot's last
+        emitted (not yet cached) token, columns 1..draft_lens-1 the
+        drafted continuation; lengths [max_seqs] = cache length BEFORE
+        the step; draft_lens [max_seqs] = real rows per slot (0 for
+        inactive slots). Writes all w K/V rows through the block tables
+        into the flattened pools (masked via OOB scatter), runs
+        staircase-masked verify attention
+        (ops.attention.paged_verify_attention), and returns (ck', cv',
+        cks', cvs', logits [max_seqs, w, V]) — logits[s, j] is the
+        model's distribution for the token FOLLOWING tokens[s, j].
+        Lengths are NOT advanced; acceptance commits via
+        cache.truncate. Under int8 pools the w
         fresh rows quantize through `_quant_scatter` and the per-page
         scales ride along to the attention gather."""
         import jax.numpy as jnp
@@ -1827,7 +1563,7 @@ class GenerationEngine:
         )
 
         spec = self.cache.spec
-        quant = getattr(self.cache, "quantized", False)
+        quant = self.cache.quantized
         dest = self._verify_scatter_dest(
             tokens.shape[1], lengths, draft_lens, tables, jnp
         )
@@ -1889,81 +1625,20 @@ class GenerationEngine:
         logits = self._forward_logits(params, tokens, hook)
         return new_k, new_v, new_ks, new_vs, logits
 
-    def _verify_tree_impl(
-        self, params, tokens, lengths, draft_lens, parents, ck, cv, ad=None
+    def _verify_tree_impl_paged(
+        self, params, tokens, lengths, draft_lens, parents, tables, ck, cv,
+        cks, cvs, ad=None,
     ):
-        """Tree twin of _verify_impl: tokens [max_seqs, w] where column
-        0 is the slot's last emitted token (the tree ROOT's input) and
-        columns 1..w-1 are draft-tree nodes in topological order;
-        parents [max_seqs, w] int32 gives each row's parent ROW index
-        (-1 for row 0). The per-token ancestor mask replaces the
+        """Tree twin of _verify_impl_paged: tokens [max_seqs, w] where
+        column 0 is the slot's last emitted token (the tree ROOT's
+        input) and columns 1..w-1 are draft-tree nodes in topological
+        order; parents [max_seqs, w] int32 gives each row's parent ROW
+        index (-1 for row 0). The per-token ancestor mask replaces the
         staircase: row j attends the committed prefix plus its own
         root-to-j chain only, so every branch scores exactly as if it
         were the lone continuation. K/V rows still land at positions
         lengths + j — branch tokens occupy scattered rows that
         cache.truncate(slot, new_len, src_rows) later compacts."""
-        import jax.numpy as jnp
-
-        from flexflow_tpu.ops.attention import (
-            mha_project_qkv,
-            mha_project_out,
-            verify_attention,
-        )
-        from flexflow_tpu.serving.tenancy.adapters import (
-            apply_adapter_out,
-            apply_adapter_qkv,
-        )
-
-        spec = self.cache.spec
-        dest = self._verify_scatter_dest(
-            tokens.shape[1], lengths, draft_lens, None, jnp
-        )
-        new_k = dict(ck)
-        new_v = dict(cv)
-
-        def row_update(cache, new):
-            flat = cache.reshape(-1, spec.num_heads, spec.head_dim)
-            rows = new.astype(cache.dtype).reshape(
-                -1, spec.num_heads, spec.head_dim
-            )
-            return flat.at[dest].set(rows).reshape(cache.shape)
-
-        positions = self._positions(lambda: lengths[:, None] + _tree_depths(parents))
-
-        def hook(node, ins, ws, ctx):
-            g = node.guid
-            use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(
-                ins, ws, ctx, use_bias=use_bias, params=node.params,
-                positions=positions,
-            )
-            q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
-            kc = row_update(ck[g], k)
-            vc = row_update(cv[g], v)
-            new_k[g] = kc
-            new_v[g] = vc
-            attn = verify_attention(
-                q,
-                kc,
-                vc,
-                lengths,
-                **self._attn_core,
-                tree_parents=parents,
-            )
-            out = mha_project_out(
-                attn, ws, ctx, ins[0].dtype, use_bias=use_bias
-            )
-            return [apply_adapter_out(attn, out, ad, g)]
-
-        logits = self._forward_logits(params, tokens, hook)
-        return new_k, new_v, logits
-
-    def _verify_tree_impl_paged(
-        self, params, tokens, lengths, draft_lens, parents, tables, ck, cv,
-        cks, cvs, ad=None,
-    ):
-        """Paged twin of _verify_tree_impl — _verify_impl_paged with the
-        parent table threaded into paged_verify_attention."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -1977,7 +1652,7 @@ class GenerationEngine:
         )
 
         spec = self.cache.spec
-        quant = getattr(self.cache, "quantized", False)
+        quant = self.cache.quantized
         dest = self._verify_scatter_dest(
             tokens.shape[1], lengths, draft_lens, tables, jnp
         )
@@ -2085,15 +1760,7 @@ class GenerationEngine:
                     f"slot {int(slot)}: draft_lens {int(draft_lens[slot])} "
                     f"overruns width {w} or max_len {spec.max_len}"
                 )
-        args = []
-        if self.paged:
-            # claim every page the w fresh rows touch BEFORE the jitted
-            # step (host-side allocator, like decode's boundary claim)
-            for slot in np.nonzero(draft_lens)[0]:
-                start = int(self.cache.lengths[slot])
-                for p in range(start, start + int(draft_lens[slot])):
-                    self.cache.ensure_position(int(slot), p)
-            args = [snapshot(self.cache.block_tables)]
+        self._claim_rows(draft_lens)
         lengths_snap = np.array(self.cache.lengths)
         # snapshot() lengths/tables: the caller truncates the cache
         # right after the reconcile, and jnp.asarray's host read is
@@ -2106,7 +1773,7 @@ class GenerationEngine:
                 jnp.asarray(tokens),
                 snapshot(self.cache.lengths),
                 jnp.asarray(draft_lens),
-                *args,
+                snapshot(self.cache.block_tables),
             ),
             self._adapter_slot_args(),
             program=("verify", w),
@@ -2192,13 +1859,7 @@ class GenerationEngine:
                     f"slot {int(slot)}: draft_lens {int(draft_lens[slot])} "
                     f"overruns width {w} or max_len {spec.max_len}"
                 )
-        args = []
-        if self.paged:
-            for slot in np.nonzero(draft_lens)[0]:
-                start = int(self.cache.lengths[slot])
-                for p in range(start, start + int(draft_lens[slot])):
-                    self.cache.ensure_position(int(slot), p)
-            args = [snapshot(self.cache.block_tables)]
+        self._claim_rows(draft_lens)
         lengths_snap = np.array(self.cache.lengths)
         (logits,) = self._run_step(
             "verify",
@@ -2209,7 +1870,7 @@ class GenerationEngine:
                 snapshot(self.cache.lengths),
                 jnp.asarray(draft_lens),
                 jnp.asarray(parents),
-                *args,
+                snapshot(self.cache.block_tables),
             ),
             self._adapter_slot_args(),
             program=("tree", w),
@@ -2241,19 +1902,19 @@ class GenerationEngine:
 
     # -- chunked prefill -----------------------------------------------------
 
-    def _chunk_impl(
-        self, params, tokens, slot_ids, all_lengths, chunk_lens, ck, cv,
-        ad=None,
+    def _chunk_impl_paged(
+        self, params, tokens, slot_ids, all_lengths, chunk_lens, tables,
+        ck, cv, cks, cvs, ad=None,
     ):
         """tokens [B, w] int32 — the next chunk_lens[b] PROMPT tokens
         of each ACTIVE prefilling slot slot_ids[b] (0-padded);
-        all_lengths [max_seqs] = every slot's cache cursor (the impl
-        gathers its own rows). The batch is COMPACTED to chunking
+        all_lengths [max_seqs] = every slot's cache cursor and tables
+        [max_seqs, pages] the full block tables (the impl gathers its
+        own rows, so dest and attention both see batch-aligned tables).
+        The batch is COMPACTED to chunking
         slots: a lone long prompt streaming through the budget costs
         B=1 rows of transformer compute per chunk step instead of
-        max_seqs — the full-slot verify-style batch taxed every chunk
-        step max_seqs/B x and erased the head-of-line win in wall
-        clock. The verify core is otherwise verbatim — staircase mask
+        max_seqs. The verify core is otherwise verbatim — staircase mask
         with query_offset = cursor gives exact causal prefill
         semantics, and the same fp32 accumulation / -1e30 fill keeps
         chunked prefill logit-identical to the monolithic path (each
@@ -2261,92 +1922,10 @@ class GenerationEngine:
         move a logit) — plus the monolithic prefill's tail: the last
         valid position's logits are sampled at position cursor + chunk
         (== prompt length on the final chunk, so the first generated
-        token matches _prefill_impl's exactly). Returns (ck', cv',
-        next_tokens [B], last_logits [B, V]) in compact order;
-        prefill_chunk_reconcile scatters them back to slot-indexed
-        arrays."""
-        import jax.numpy as jnp
-
-        from flexflow_tpu.ops.attention import (
-            mha_project_qkv,
-            mha_project_out,
-            verify_attention,
-        )
-
-        from flexflow_tpu.serving.tenancy.adapters import (
-            adapter_rows,
-            apply_adapter_out,
-            apply_adapter_qkv,
-        )
-
-        spec = self.cache.spec
-        w = tokens.shape[1]
-        lengths = all_lengths[slot_ids]  # [B] cursor per active slot
-        # compact the slot-indexed adapter gather to the B batch rows
-        ad = adapter_rows(ad, slot_ids)
-        dest = self._verify_scatter_dest(
-            w, lengths, chunk_lens, None, jnp, slot_ids=slot_ids
-        )
-        new_k = dict(ck)
-        new_v = dict(cv)
-
-        def row_update(cache, new):
-            flat = cache.reshape(-1, spec.num_heads, spec.head_dim)
-            rows = new.astype(cache.dtype).reshape(
-                -1, spec.num_heads, spec.head_dim
-            )
-            return flat.at[dest].set(rows).reshape(cache.shape)
-
-        positions = self._positions(
-            lambda: lengths[:, None] + jnp.arange(w)[None, :]
-        )
-
-        def hook(node, ins, ws, ctx):
-            g = node.guid
-            use_bias = node.params.get("bias", True)
-            q, k, v = mha_project_qkv(
-                ins, ws, ctx, use_bias=use_bias, params=node.params,
-                positions=positions,
-            )
-            q, k, v = apply_adapter_qkv(ins[0], q, k, v, ad, g)
-            kc = row_update(ck[g], k)
-            vc = row_update(cv[g], v)
-            new_k[g] = kc
-            new_v[g] = vc
-            # attention sees only the active slots' cache rows — the
-            # update above already wrote the full cache for commit
-            attn = verify_attention(
-                q, kc[slot_ids], vc[slot_ids], lengths,
-                **self._attn_core,
-            )
-            out = mha_project_out(
-                attn, ws, ctx, ins[0].dtype, use_bias=use_bias
-            )
-            return [apply_adapter_out(attn, out, ad, g)]
-
-        logits = self._forward_logits(params, tokens, hook)
-        last = jnp.take_along_axis(
-            logits, jnp.clip(chunk_lens - 1, 0, w - 1)[:, None, None], axis=1
-        )[:, 0]
-        # the sampling key matches _prefill_impl's _pick(last, slot_ids,
-        # prompt_lens): on the final chunk cursor + chunk == prompt_len
-        return (
-            new_k,
-            new_v,
-            self._pick(last, slot_ids, lengths + chunk_lens),
-            last,
-        )
-
-    def _chunk_impl_paged(
-        self, params, tokens, slot_ids, all_lengths, chunk_lens, tables,
-        ck, cv, cks, cvs, ad=None,
-    ):
-        """Paged twin of _chunk_impl: rows route through the block
-        tables into the flattened pools, attention gathers pages via
-        ops.attention.paged_verify_attention. Same compact batch —
-        tables arrive full [max_seqs, pages] and the active rows are
-        gathered here, so dest and attention both see batch-aligned
-        tables."""
+        token matches _prefill_impl_paged's exactly). Returns (ck', cv',
+        cks', cvs', next_tokens [B], last_logits [B, V]) in compact
+        order; prefill_chunk_reconcile scatters them back to
+        slot-indexed arrays."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -2368,7 +1947,7 @@ class GenerationEngine:
         dest = self._verify_scatter_dest(
             w, lengths, chunk_lens, tables_g, jnp
         )
-        quant = getattr(self.cache, "quantized", False)
+        quant = self.cache.quantized
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks = dict(cks)
@@ -2480,15 +2059,7 @@ class GenerationEngine:
         slot_ids = np.nonzero(chunk_lens)[0]
         if slot_ids.size == 0:
             raise ValueError("chunk step needs at least one active slot")
-        args = []
-        if self.paged:
-            # claim every page the chunk rows touch BEFORE the jitted
-            # step (host-side allocator, like verify's claim loop)
-            for slot in slot_ids:
-                start = int(self.cache.lengths[slot])
-                for p in range(start, start + int(chunk_lens[slot])):
-                    self.cache.ensure_position(int(slot), p)
-            args = [snapshot(self.cache.block_tables)]
+        self._claim_rows(chunk_lens)
         lengths_snap = np.array(self.cache.lengths)
         # snapshot() lengths/tables: the cursor bump below mutates
         # lengths right after dispatch, and jnp.asarray's host read is
@@ -2505,7 +2076,7 @@ class GenerationEngine:
                 jnp.asarray(slot_ids.astype(np.int32)),
                 snapshot(self.cache.lengths),
                 jnp.asarray(chunk_lens[slot_ids]),
-                *args,
+                snapshot(self.cache.block_tables),
             ),
             self._adapter_slot_args(),
             program=("chunk", slot_ids.size, w),

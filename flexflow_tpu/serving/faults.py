@@ -268,9 +268,7 @@ class FaultInjector:
                 if self._rng("cancel", rid).random() < plan.cancel_rate:
                     if scheduler.cancel(rid):
                         self.injected["cancel"] += 1
-        cache = scheduler.cache
-        if getattr(cache, "paged", False):
-            self._page_faults(cache)
+        self._page_faults(scheduler.cache)
         self._host_faults(scheduler)
 
     def _host_faults(self, scheduler) -> None:
@@ -294,8 +292,8 @@ class FaultInjector:
         if host is None:
             return
         host = int(host)
-        num_hosts = getattr(cache, "num_hosts", 1)
-        if not getattr(cache, "paged", False) or num_hosts <= 1:
+        num_hosts = cache.num_hosts
+        if num_hosts <= 1:
             return
         down = {h for h, _ in self._downed}
         if host in down or host >= num_hosts:

@@ -93,11 +93,6 @@ class DisaggregatedPipeline:
     ):
         from flexflow_tpu.serving.api import build_scheduler
 
-        if serve.kv_layout != "paged":
-            raise ValueError(
-                "disaggregated handoff needs kv_layout='paged' (KV "
-                "moves between tiers page-by-page over the swap path)"
-            )
         if not serve.token_budget:
             raise ValueError(
                 "disaggregated handoff needs a token_budget (the "

@@ -194,12 +194,7 @@ class ReplicaRouter:
         routable = [r for r in alive if r.breaker_state != "open"]
         alive = routable or alive
         affinity = {
-            r.idx: (
-                len(r.cache.match_prefix(request.prompt))
-                if hasattr(r.cache, "match_prefix")
-                else 0
-            )
-            for r in alive
+            r.idx: len(r.cache.match_prefix(request.prompt)) for r in alive
         }
         best = max(affinity.values())
         pool = (

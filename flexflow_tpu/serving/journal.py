@@ -564,7 +564,6 @@ def readmit(scheduler, state: RecoveryState, decider=None):
         if (
             snap is not None
             and cache is not None
-            and hasattr(cache, "import_swap")
             and int(snap.get("gen_len", -1)) == len(rr.committed)
         ):
             resume_len = len(rr.prompt) + len(rr.committed)

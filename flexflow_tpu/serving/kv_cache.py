@@ -1,42 +1,35 @@
-"""KV caches for the serving engine: slot-contiguous and block-paged.
+"""The KV cache of the serving engine: block-paged pools.
 
-Two layouts share one spec/geometry derivation:
+`PagedKVCache` is the PagedAttention layout (Kwon et al., SOSP'23 /
+vLLM): K/V live in `[num_pages, page_size, heads * head_dim]` *pools*,
+a host-side free-page allocator hands pages to sequences on demand,
+and a per-slot *block table* (`[max_seqs, max_pages_per_seq]` int32,
+padded with the sentinel `num_pages`) maps logical cache positions to
+pool pages. A short request holds only the pages its tokens fill, so
+the same byte budget admits more concurrent short requests — the
+serving-capacity lever continuous batching turns into throughput.
 
-* `KVCache` — the PR-1 "static" layout: one pair of
-  `[max_seqs, max_len, heads, head_dim]` arrays per attention layer. A
-  *slot* is one row of the leading dim; every admitted request reserves
-  `max_len` worth of HBM regardless of how many tokens it generates.
+Admission supports two policies. The default *reserve* policy is
+preemption-free: a request is admitted only when the free pool covers
+its worst case (`ceil((prompt + max_new_tokens) / page_size)` pages)
+on top of every in-flight request's outstanding worst case, so a
+mid-flight decode can ALWAYS claim its next page — no preemption/swap
+path needed. The opt-in *optimistic* policy (vLLM's posture) admits on
+the pages a request needs NOW and reserves nothing for its growth;
+when the pool later runs dry mid-decode, `ensure_position` raises
+`PagePoolExhausted` and the scheduler preempts a victim — frees its
+pages and requeues it for prefill-from-recompute
+(serving/scheduler.py). Optimistic slots never contribute to the
+reserve ledger, so the two policies compose: reserve-admitted slots
+keep their guarantee even while optimistic slots gamble.
 
-* `PagedKVCache` — the PagedAttention layout (Kwon et al., SOSP'23 /
-  vLLM): K/V live in `[num_pages, page_size, heads * head_dim]` *pools*,
-  a host-side free-page allocator hands pages to sequences on demand,
-  and a per-slot *block table* (`[max_seqs, max_pages_per_seq]` int32,
-  padded with the sentinel `num_pages`) maps logical cache positions to
-  pool pages. A short request holds only the pages its tokens fill, so
-  the same byte budget admits more concurrent short requests — the
-  serving-capacity lever continuous batching turns into throughput.
-
-  Admission supports two policies. The default *reserve* policy is
-  preemption-free: a request is admitted only when the free pool covers
-  its worst case (`ceil((prompt + max_new_tokens) / page_size)` pages)
-  on top of every in-flight request's outstanding worst case, so a
-  mid-flight decode can ALWAYS claim its next page — no preemption/swap
-  path needed. The opt-in *optimistic* policy (vLLM's posture) admits on
-  the pages a request needs NOW and reserves nothing for its growth;
-  when the pool later runs dry mid-decode, `ensure_position` raises
-  `PagePoolExhausted` and the scheduler preempts a victim — frees its
-  pages and requeues it for prefill-from-recompute
-  (serving/scheduler.py). Optimistic slots never contribute to the
-  reserve ledger, so the two policies compose: reserve-admitted slots
-  keep their guarantee even while optimistic slots gamble.
-
-Prompt lengths are *bucketed* in both layouts: prefill pads each
+Prompt lengths are *bucketed*: prefill pads each
 admission batch's prompts up to the next bucket (powers of two by
 default), so the number of compiled prefill programs is bounded by the
 bucket count, not by the number of distinct prompt lengths the traffic
 happens to contain.
 
-Sharding: both layouts derive their specs from the compiled model's
+Sharding: the cache derives its spec from the compiled model's
 ParallelTensor annotations — if the strategy shards attention heads (the
 head-parallel replica-dim rewrite, ops/attention.py), the cache's heads
 dim rides the same mesh axis, so TP-over-heads serving (the decode
@@ -88,10 +81,8 @@ def default_page_size(max_len: int, target: int = 16) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
-    """Static geometry of the cache, derived from the compiled model.
-
-    page_size == 0 means the slot-contiguous layout; page_size > 0 means
-    the paged layout with `num_pages` pool pages. `itemsize` is the
+    """Static geometry of the cache, derived from the compiled model:
+    `num_pages` pool pages of `page_size` positions. `itemsize` is the
     cache dtype's element width in bytes (set from the actual dtype at
     cache construction, so bytes_per_layer/total_bytes price bf16
     caches at 2 bytes, not a hardcoded 4)."""
@@ -102,10 +93,10 @@ class KVCacheSpec:
     num_heads: int
     head_dim: int
     buckets: Tuple[int, ...]
-    page_size: int = 0
-    num_pages: int = 0
+    page_size: int
+    num_pages: int
     itemsize: int = 4
-    kv_dtype: str = "fp32"  # "fp32" | "int8" (int8 is paged-only)
+    kv_dtype: str = "fp32"  # "fp32" | "int8"
 
     def bucket(self, length: int) -> int:
         """Smallest bucket >= length (prefill pad target)."""
@@ -117,21 +108,13 @@ class KVCacheSpec:
         )
 
     @property
-    def paged(self) -> bool:
-        return self.page_size > 0
-
-    @property
     def max_pages_per_seq(self) -> int:
-        if not self.paged:
-            raise ValueError("max_pages_per_seq is a paged-layout property")
         return self.max_len // self.page_size
 
     @property
     def total_rows(self) -> int:
-        """Cache positions the layout can hold (pool rows)."""
-        if self.paged:
-            return self.num_pages * self.page_size
-        return self.max_seqs * self.max_len
+        """Cache positions the pools can hold (pool rows)."""
+        return self.num_pages * self.page_size
 
     @property
     def bytes_per_layer(self) -> int:
@@ -175,7 +158,7 @@ def _derive_geometry(model):
     axis), the cache heads dim shards on that axis; otherwise the cache
     is replicated."""
     if model.executor is None:
-        raise RuntimeError("compile() the model before building a KVCache")
+        raise RuntimeError("compile() the model before building its KV cache")
     graph = model.graph
     executor = model.executor
     guids = [
@@ -207,9 +190,9 @@ def _derive_geometry(model):
     return guids, heads, head_dim, head_axis, executor
 
 
-def _heads_sharding(executor, head_axis, ndim=4):
-    """NamedSharding placing dim 2 (heads; in a paged pool's `ndim` 3
-    the folded heads * head_dim) on the strategy's head axis.
+def _heads_sharding(executor, head_axis):
+    """NamedSharding placing a pool's dim 2 (the folded heads *
+    head_dim) on the strategy's head axis.
 
     Always place the cache on the mesh (replicated when heads are not
     sharded): uncommitted fresh zeros would give the first engine step a
@@ -217,311 +200,15 @@ def _heads_sharding(executor, head_axis, ndim=4):
     outputs) and buy a pointless recompile."""
     from jax.sharding import NamedSharding, PartitionSpec
 
-    return NamedSharding(
-        executor.mesh, PartitionSpec(*(None, None, head_axis, None)[:ndim])
-    )
-
-
-class KVCache:
-    """Slot-contiguous device arrays + host-side slot bookkeeping.
-
-    The arrays are functional (each engine step returns fresh ones;
-    `commit` swaps them in); the slot free-list and per-slot lengths are
-    plain host state the scheduler mutates between steps.
-    """
-
-    paged = False
-
-    def __init__(self, spec: KVCacheSpec, dtype, shardings=None):
-        import jax
-        import jax.numpy as jnp
-
-        self.spec = dataclasses.replace(
-            spec, itemsize=jnp.dtype(dtype).itemsize
-        )
-        spec = self.spec
-        self.dtype = dtype
-        shape = (spec.max_seqs, spec.max_len, spec.num_heads, spec.head_dim)
-        self.k: Dict[int, object] = {}
-        self.v: Dict[int, object] = {}
-        for g in spec.layer_guids:
-            k = jnp.zeros(shape, dtype)
-            v = jnp.zeros(shape, dtype)
-            if shardings is not None:
-                k = jax.device_put(k, shardings)
-                v = jax.device_put(v, shardings)
-            self.k[g] = k
-            self.v[g] = v
-        # host bookkeeping: lengths[i] = tokens currently cached in slot i.
-        # _free is a min-heap so alloc pops the lowest free id (dense,
-        # deterministic slot reuse) and free is O(log n) — no full re-sort
-        # per release.
-        self.lengths = np.zeros(spec.max_seqs, dtype=np.int32)
-        self._free: List[int] = list(range(spec.max_seqs))
-        self._active: set = set()
-        self._inflight_depth = 0
-        # host-partition parity with PagedKVCache: the slot layout is
-        # single-host only (serving.api rejects --serve-hosts > 1 on it)
-        self.num_hosts = 1
-
-    def host_of_slot(self, slot: int) -> int:
-        return 0
-
-    def free_pages_by_host(self) -> List[int]:
-        return [0]
-
-    # -- in-flight window (async dispatch) -----------------------------------
-
-    def begin_inflight(self) -> None:
-        """Open an in-flight window: a dispatched-but-not-reconciled step
-        references this cache's state. The slot layout needs no pinning
-        — a stale write from an in-flight step lands at a position the
-        next occupant overwrites before its lengths mask ever exposes it
-        — so the window is pure depth bookkeeping here; the paged twin
-        pins freed pages for the window's duration."""
-        self._inflight_depth += 1
-
-    def end_inflight(self) -> None:
-        if self._inflight_depth <= 0:
-            raise RuntimeError("end_inflight without a matching begin_inflight")
-        self._inflight_depth -= 1
-
-    @property
-    def pinned_pages(self) -> int:
-        """Signature parity with PagedKVCache (the slot layout pins
-        nothing)."""
-        return 0
-
-    # -- slot management (host side) ----------------------------------------
-
-    @property
-    def num_active(self) -> int:
-        return len(self._active)
-
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    def active_slots(self) -> List[int]:
-        return sorted(self._active)
-
-    def can_admit(
-        self,
-        prompt_len: int = 1,
-        total_len: int = 0,
-        optimistic: bool = False,
-    ) -> bool:
-        """A slot layout admits whenever a slot is free (every slot holds
-        max_len positions, so length arguments — and the admission policy
-        — cannot change the verdict; they exist for signature parity with
-        PagedKVCache)."""
-        return bool(self._free)
-
-    def alloc(
-        self,
-        prompt_len: Optional[int] = None,
-        total_len: Optional[int] = None,
-        optimistic: bool = False,
-    ) -> Optional[int]:
-        """Take a free slot (None when full). Lowest-free-id pop so slot
-        ids stay dense and deterministic under a fixed request stream.
-        The length/policy arguments are accepted (and ignored) so the
-        scheduler drives both layouts through one call — a slot pins
-        max_len rows either way, so the slot layout has no page pressure
-        and nothing to admit optimistically against."""
-        if not self._free:
-            return None
-        slot = heapq.heappop(self._free)
-        self._active.add(slot)
-        self.lengths[slot] = 0
-        return slot
-
-    def claim(self, slot: int) -> None:
-        """Allocate a SPECIFIC free slot. Speculative decoding keeps the
-        draft model's cache slot-aligned with the target's
-        (serving/spec.py ModelDraftProposer), so the draft mirrors the
-        target's admission instead of running its own allocator."""
-        if slot in self._active:
-            raise ValueError(f"slot {slot} is already active")
-        if slot not in self._free:
-            raise ValueError(f"slot {slot} is not a valid free slot")
-        self._free.remove(slot)
-        heapq.heapify(self._free)
-        self._active.add(slot)
-        self.lengths[slot] = 0
-
-    def free(self, slot: int) -> None:
-        if slot not in self._active:
-            raise ValueError(f"slot {slot} is not active")
-        self._active.remove(slot)
-        self.lengths[slot] = 0
-        heapq.heappush(self._free, slot)
-
-    def truncate(
-        self, slot: int, new_len: int, src_rows: Optional[Sequence[int]] = None
-    ) -> None:
-        """Roll the slot's visible length to `new_len` (speculative-decode
-        rollback: verify writes k+1 rows, acceptance keeps a prefix).
-        Rows past new_len stay in HBM as stale data — the lengths mask in
-        decode/verify attention hides them and later writes overwrite
-        them, so no device work is needed. new_len may also EXCEED the
-        current length: verify commits its accepted rows through this
-        same call.
-
-        src_rows (tree-verify commit): absolute cache positions, in
-        path order, holding the ACCEPTED root-to-leaf rows of a token
-        tree — scattered across the verify window because dead branches
-        sit between them. They are compacted into the contiguous tail
-        positions [new_len - len(src_rows), new_len) before the length
-        moves, so the committed cache is indistinguishable from a
-        linear decode of the accepted path (K/V rows carry no positional
-        encoding — attention context is the mask's job — so the row
-        copy is value-exact). Positions must be non-decreasing and each
-        source must sit at-or-after its destination (topological node
-        order guarantees both); src_rows == destinations is a no-op, so
-        chain trees never touch the device."""
-        if slot not in self._active:
-            raise ValueError(f"slot {slot} is not active")
-        if not 0 <= new_len <= self.spec.max_len:
-            raise ValueError(
-                f"new_len {new_len} outside [0, {self.spec.max_len}]"
-            )
-        if src_rows is not None and len(src_rows):
-            self._compact_rows(slot, new_len, src_rows)
-        self.lengths[slot] = new_len
-
-    def _compact_rows(
-        self, slot: int, new_len: int, src_rows: Sequence[int]
-    ) -> None:
-        """Move the accepted tree rows into the contiguous tail of the
-        committed prefix. Functional rebind (fresh dicts, gather before
-        scatter) of the pools read at call time: they are the newest
-        step's committed outputs, which no queued program holds (a step
-        program consumes the pools it is handed), and the new arrays
-        chain behind them on the device queue — the commit() discipline."""
-        import jax.numpy as jnp
-
-        srcs = [int(p) for p in src_rows]
-        dests = list(range(new_len - len(srcs), new_len))
-        if dests[0] < 0:
-            raise ValueError(
-                f"{len(srcs)} compacted rows do not fit under new_len "
-                f"{new_len}"
-            )
-        for s, d in zip(srcs, dests):
-            if not d <= s < self.spec.max_len:
-                raise ValueError(
-                    f"source row {s} outside [{d}, {self.spec.max_len})"
-                )
-        if srcs == dests:
-            return
-        si = jnp.asarray(np.asarray(srcs, dtype=np.int32))
-        di = jnp.asarray(np.asarray(dests, dtype=np.int32))
-        nk, nv = dict(self.k), dict(self.v)
-        for g in self.spec.layer_guids:
-            nk[g] = nk[g].at[slot, di].set(nk[g][slot, si])
-            nv[g] = nv[g].at[slot, di].set(nv[g][slot, si])
-        self.k, self.v = nk, nv
-
-    def commit(self, new_k: Dict[int, object], new_v: Dict[int, object]):
-        """Swap in the arrays a jitted step returned. The step program
-        was handed `self.k` / `self.v` donated (engine._step_jit): the
-        arrays it consumed are gone, these are the only live ones."""
-        self.k = dict(new_k)
-        self.v = dict(new_v)
-
-    def telemetry_gauges(self) -> Dict[str, float]:
-        """Point-in-time allocator gauges the per-iteration telemetry
-        sampler exports (`kv_*` series). Reads the same ledgers
-        `check_invariants` re-derives its truth from, so the KV-gauge
-        tests can hold the two to exact agreement. The slot layout has
-        no pages: occupancy is row-based (a slot pins max_len rows, so
-        `kv_occupancy` is the fraction of reserved rows actually
-        holding tokens) and the page gauges sit at zero for series
-        parity with the paged layout."""
-        spec = self.spec
-        used = int(self.lengths.sum())
-        return {
-            "kv_slots_active": len(self._active),
-            "kv_slots_free": len(self._free),
-            "kv_rows_used": used,
-            "kv_occupancy": used / spec.total_rows if spec.total_rows else 0.0,
-            "kv_pages_live": 0,
-            "kv_pages_pinned": 0,
-            "kv_free_heap_depth": 0,
-            "kv_pages_reserved": 0,
-            "kv_inflight_depth": self._inflight_depth,
-            "kv_prefix_pages_shared": 0,
-            "kv_swapped_pages": 0,
-            "kv_pages_pub_only": 0,
-        }
-
-    def telemetry_counters(self) -> Dict[str, int]:
-        """Series parity with PagedKVCache (the slot layout never
-        shares, swaps, or evicts pages)."""
-        return {
-            "kv_prefix_hits_total": 0,
-            "kv_cow_copies_total": 0,
-            "kv_swap_out_total": 0,
-            "kv_swap_in_total": 0,
-            "kv_swap_bytes_total": 0,
-            "kv_prefix_evictions_total": 0,
-        }
-
-    def check_invariants(self, extra_free: int = 0) -> None:
-        """Assert the slot bookkeeping is consistent — the chaos-harness
-        probe (tests/test_resilience.py, bench_serve.py --chaos) calls
-        this after every iteration. `extra_free` exists for signature
-        parity with PagedKVCache (a fault injector holding pages has no
-        slot-layout analog)."""
-        spec = self.spec
-        assert self._active.isdisjoint(self._free)
-        assert len(self._active) + len(self._free) == spec.max_seqs
-        for s in self._free:
-            assert self.lengths[s] == 0
-        for s in self._active:
-            assert 0 <= self.lengths[s] <= spec.max_len
-
-    # -- construction from a compiled model ---------------------------------
-
-    @staticmethod
-    def from_model(
-        model,
-        max_seqs: int,
-        max_len: int,
-        dtype=None,
-        buckets: Optional[Sequence[int]] = None,
-    ) -> "KVCache":
-        """Derive geometry + shardings from a compiled FFModel. When the
-        model carries a `serving_placement` (compile_for_serving), the
-        cache rides the SERVING mesh — slots on the data axis, heads on
-        the model axis — instead of the training strategy's sharding."""
-        import jax.numpy as jnp
-
-        guids, heads, head_dim, head_axis, executor = _derive_geometry(model)
-        spec = KVCacheSpec(
-            layer_guids=tuple(guids),
-            max_seqs=max_seqs,
-            max_len=max_len,
-            num_heads=heads,
-            head_dim=head_dim,
-            buckets=tuple(buckets) if buckets else default_buckets(max_len),
-        )
-        if dtype is None:
-            dtype = jnp.float32
-        placement = getattr(model, "serving_placement", None)
-        if placement is not None:
-            shardings = placement.kv_sharding()
-        else:
-            shardings = _heads_sharding(executor, head_axis)
-        return KVCache(spec, dtype, shardings=shardings)
+    return NamedSharding(executor.mesh, PartitionSpec(None, None, head_axis))
 
 
 class PagedKVCache:
     """Block-paged pools + host-side page allocator and block tables.
 
     Device state: one `[num_pages, page_size, heads * head_dim]` K and V
-    pool per layer (functional, swapped via `commit` like KVCache). The
+    pool per layer (functional: each step program returns the pools it
+    was handed, rewritten, and `commit` stores them). The
     heads and their dims are folded into one, heads-major, because a
     device array's layout follows from its shape: a TPU keeps an array
     whose last dim is under 128 lanes (a head_dim of 64) pages-minor,
@@ -553,8 +240,6 @@ class PagedKVCache:
     prefix-shared pages stay bit-identical across requests.
     """
 
-    paged = True
-
     def __init__(
         self,
         spec: KVCacheSpec,
@@ -569,8 +254,6 @@ class PagedKVCache:
         import jax
         import jax.numpy as jnp
 
-        if not spec.paged:
-            raise ValueError("PagedKVCache needs a spec with page_size > 0")
         if prefix_evict not in ("none", "lru", "cost"):
             raise ValueError(
                 f"prefix_evict must be 'none', 'lru', or 'cost', "
@@ -923,6 +606,7 @@ class PagedKVCache:
         prompt_len: Optional[int] = None,
         total_len: Optional[int] = None,
         optimistic: bool = False,
+        slot: Optional[int] = None,
     ) -> Optional[int]:
         """Admit a sequence: take a slot, allocate the pages its prompt
         fills now, and — under the default reserve policy — reserve
@@ -930,7 +614,11 @@ class PagedKVCache:
         policy refuses. `optimistic=True` reserves nothing beyond the
         prompt's pages (the slot may later hit PagePoolExhausted and be
         preempted). Omitted lengths reserve-and-fill a full max_len
-        (slot-equivalent behavior for ad-hoc engine callers)."""
+        (ad-hoc engine callers). `slot` names the slot to take, on its
+        own host partition, instead of the lowest free one: the draft
+        model's cache follows the target's slots this way
+        (serving/spec.py ModelDraftProposer); a slot that is active or
+        out of range raises."""
         spec = self.spec
         if prompt_len is None:
             prompt_len = spec.max_len
@@ -941,10 +629,20 @@ class PagedKVCache:
             )
         need_now = self._pages_for(prompt_len)
         max_p = self._pages_for(total)
-        h = self._pick_host(need_now if optimistic else max_p)
-        if h is None:
-            return None
-        slot = heapq.heappop(self._free_slots_h[h])
+        need = need_now if optimistic else max_p
+        if slot is None:
+            h = self._pick_host(need)
+            if h is None:
+                return None
+            slot = heapq.heappop(self._free_slots_h[h])
+        else:
+            if not 0 <= slot < spec.max_seqs or slot in self._active:
+                raise ValueError(f"slot {slot} is not a free slot")
+            h = self.host_of_slot(slot)
+            if h in self._hosts_down or self._host_avail(h) < need:
+                return None
+            self._free_slots_h[h].remove(slot)
+            heapq.heapify(self._free_slots_h[h])
         self._active.add(slot)
         for i in range(need_now):
             self._install_page(slot, i, self._pop_free_page(h))
@@ -1368,7 +1066,14 @@ class PagedKVCache:
         src_rows (tree-verify commit): the accepted root-to-leaf rows'
         absolute positions, compacted into [new_len - len(src_rows),
         new_len) through the block table BEFORE the dead branches' pages
-        are released — see KVCache.truncate for the contract. On int8
+        are released. They are compacted into the contiguous tail
+        before the length moves, so the committed cache is
+        indistinguishable from a linear decode of the accepted path (K/V
+        rows are value-exact under the copy: attention context is the
+        mask's job). Positions must be non-decreasing and each source
+        must sit at-or-after its destination (topological node order
+        guarantees both); src_rows == destinations is a no-op, so chain
+        trees never touch the device. On int8
         pools the moved rows dequantize with their source page's scale
         and requantize under the destination page's; a destination page
         whose FIRST row is among the moves re-derives its scale from
@@ -1834,8 +1539,8 @@ class PagedKVCache:
 
     def check_invariants(self, extra_free: int = 0) -> None:
         """Assert the page allocator's full accounting is consistent —
-        the chaos-harness probe (tests/test_resilience.py,
-        bench_serve.py --chaos) calls this after every iteration.
+        the chaos-harness probe (tests/test_resilience.py) calls this
+        after every iteration.
         `extra_free` is pages a fault injector is deliberately holding
         outside the pool (faults.FaultInjector page-steal), which the
         conservation check must count."""
@@ -1985,9 +1690,11 @@ class PagedKVCache:
     ) -> "PagedKVCache":
         """Derive geometry + shardings from a compiled FFModel. Defaults
         (page_size 0 / num_pages 0) pick the vLLM-style block size and a
-        pool with EXACTLY the slot layout's capacity
-        (max_seqs * max_len rows), so existing callers see identical
-        byte footprint and admission behavior. kv_dtype "int8" selects
+        pool of max_seqs * max_len rows, in which every slot can reach
+        max_len whatever the others hold. When the model carries a
+        `serving_placement` (compile_for_serving), the cache rides the
+        SERVING mesh — pages on the data axis, heads on the model axis —
+        instead of the training strategy's sharding. kv_dtype "int8" selects
         the quantized pool variant (the dtype argument is ignored);
         prefix_cache=True turns the hashed prefix-page index on."""
         import jax.numpy as jnp
@@ -2021,9 +1728,9 @@ class PagedKVCache:
         placement = getattr(model, "serving_placement", None)
         if placement is not None:
             placement.validate_geometry(max_seqs, num_pages)
-            shardings = placement.kv_sharding(ndim=3)
+            shardings = placement.kv_sharding()
         else:
-            shardings = _heads_sharding(executor, head_axis, ndim=3)
+            shardings = _heads_sharding(executor, head_axis)
         return PagedKVCache(
             spec,
             dtype,

@@ -34,7 +34,7 @@ stream is identical to a fault-free run, because greedy decode is a pure
 function of a slot's own context, never of which neighbors share the
 iteration.
 
-**Admission policies** (paged layout): the default `reserve` policy
+**Admission policies**: the default `reserve` policy
 admits only when the free pool covers a request's worst case on top of
 every in-flight reservation — preemption-free by construction. The
 opt-in `optimistic` policy admits on the pages a request needs NOW;
@@ -46,11 +46,6 @@ prefill-from-recompute over prompt + tokens generated so far — up to
 swap) is the right recovery here for the same reason vLLM defaults to
 it: a preempted sequence's KV is recomputable from its token history in
 one prefill-shaped step, so no swap-space subsystem is needed.
-
-`StaticBatchingScheduler` is the deliberately-worse baseline the bench
-and the comparison test measure against: admit a batch, decode until the
-WHOLE batch finishes, only then admit the next batch (the reference
-FFModel::generate shape, and every pre-Orca serving stack).
 
 **Async double-buffered loop** (`AsyncContinuousBatchingScheduler`,
 `--serve-async`): every decode/verify is split into a dispatch phase
@@ -319,7 +314,7 @@ _STAT_FIELDS: Dict[str, object] = dict(
     moe_rows_decode=0,
     moe_experts_touched_prefill=0,
     moe_experts_touched_decode=0,
-    # prefix-sharing page cache (paged layout with --prefix-cache;
+    # prefix-sharing page cache (--prefix-cache;
     # mirrored from the allocator's ledgers at each iteration end)
     prefix_hits=0,  # admissions that mapped at least one shared page
     prefix_pages_shared=0,  # live shared table entries (gauge-like)
@@ -630,15 +625,13 @@ class _SchedulerBase:
                 "journal_snapshot_every must be >= 0, got "
                 f"{journal_snapshot_every}"
             )
-        # KV swap-to-host: when on (paged layout only), a preemption
+        # KV swap-to-host: when on, a preemption
         # victim's committed pages ride the host link instead of being
         # recomputed — unless `swap_decider(cache, request)` (built from
         # CostModel.swap_cost vs estimate_recompute_step; None means
         # always-swap) says the recompute is cheaper, or the allocator
         # refuses (budget / in-flight step), or the injector fails it.
         self.kv_swap = bool(kv_swap)
-        if self.kv_swap and not getattr(engine.cache, "paged", False):
-            raise ValueError("kv_swap requires the paged KV layout")
         self.swap_decider = swap_decider
         # device-resident multi-step decode: when on, runs of decode
         # iterations with no host-visible event pending fuse into ONE
@@ -902,7 +895,7 @@ class _SchedulerBase:
                     labels={"status": status, "tenant": req.tenant},
                 ).inc()
             if (
-                getattr(self.cache, "num_hosts", 1) > 1
+                self.cache.num_hosts > 1
                 and slot_host is not None
             ):
                 reg.counter(
@@ -1042,12 +1035,12 @@ class _SchedulerBase:
 
     def _swap_eligible(self, req: Request) -> bool:
         """Whether this victim's KV should ride the host link instead of
-        being recomputed: swap must be ON and the layout paged, the
+        being recomputed: swap must be ON, the
         slot's committed history worth saving (generated tokens exist
         and no prefill is mid-stream — a half-prefilled slot recomputes
         its chunks anyway), the injector must not fail the swap-out,
         and the cost decider must prefer the copy over the recompute."""
-        if not self.kv_swap or not getattr(self.cache, "paged", False):
+        if not self.kv_swap:
             return False
         if req.slot is None or not req.generated:
             return False
@@ -1074,8 +1067,6 @@ class _SchedulerBase:
         slot); under optimistic admission a dry pool preempts the
         youngest victim and retries, so the engine's own ensure_position
         calls always find the pages already present."""
-        if not getattr(self.cache, "paged", False):
-            return
         for slot in sorted(widths):
             req = self.running.get(slot)
             if req is None:
@@ -1178,10 +1169,8 @@ class _SchedulerBase:
         every iteration: the dead partition's ledgers stay consistent,
         just unused, so recovery is mark_host_up and nothing else."""
         cache = self.cache
-        if not getattr(cache, "paged", False) or cache.num_hosts <= 1:
-            raise ValueError(
-                "host_down needs a multi-host paged partition"
-            )
+        if cache.num_hosts <= 1:
+            raise ValueError("host_down needs a multi-host partition")
         t0 = time.perf_counter()
         # in-flight steps may still reference the dying host's slots —
         # drain the pipeline first, same discipline as _secure_pages
@@ -1345,8 +1334,7 @@ class _SchedulerBase:
         """FIFO admission into free slots (never reorders the queue —
         starvation-free: the head either admits or blocks everyone
         behind it) + ONE prefill batch for the admitted set. Admission
-        asks the cache, so the gate is layout-specific: the slot layout
-        admits while a slot is free; the paged layout also requires
+        asks the cache: a free slot and
         enough free PAGES — the request's worst case under the reserve
         policy, only its immediate need under the optimistic one. A
         preempted request re-admits with its recompute sequence
@@ -1365,7 +1353,7 @@ class _SchedulerBase:
 
     def _admit_batch(self, limit: Optional[int]) -> List[Request]:
         optimistic = self.admission == "optimistic"
-        prefix = bool(getattr(self.cache, "prefix_cache", False))
+        prefix = bool(self.cache.prefix_cache)
         admitted: List[Request] = []
         seqs: List[List[int]] = []
         cursors: List[int] = []
@@ -1790,7 +1778,7 @@ class _SchedulerBase:
     def _decode_multi_dispatch_step(self, k: int):
         """Dispatch phase of one fused K-step decode window: per slot,
         cap the window depth at the request's remaining token budget,
-        the cache horizon, and (paged layout) the distance to the next
+        the cache horizon, and the distance to the next
         page boundary — so the window claims AT MOST one fresh page per
         slot, which `_secure_pages` handles exactly like a plain decode
         step's claim. Every cache read on this side goes through
@@ -1801,7 +1789,7 @@ class _SchedulerBase:
         step."""
         stepped: Dict[int, Request] = {}
         limits: Dict[int, int] = {}
-        ps = int(getattr(self.cache.spec, "page_size", 0) or 0)
+        ps = self.cache.spec.page_size
         max_len = self.cache.spec.max_len
         for slot, req in self.running.items():
             if self._prefill_pending(req) or slot in self._chunk_unlocked:
@@ -1812,10 +1800,9 @@ class _SchedulerBase:
                 req.max_new_tokens - len(req.generated),
                 max_len - cur_len,
             )
-            if ps:
-                # page-boundary truncation: the window ends where the
-                # slot's next fresh page would begin
-                cap = min(cap, ps - (cur_len % ps))
+            # page-boundary truncation: the window ends where the
+            # slot's next fresh page would begin
+            cap = min(cap, ps - (cur_len % ps))
             if cap >= 1:
                 stepped[slot] = req
                 limits[slot] = cap
@@ -2568,7 +2555,7 @@ class _SchedulerBase:
                 )
                 continue
             req.prefill_pos = start + size
-            if getattr(self.cache, "prefix_cache", False):
+            if self.cache.prefix_cache:
                 # progressive publication: every COMMITTED full page of
                 # the streaming prompt becomes matchable immediately —
                 # and only committed ones (a faulted chunk never
@@ -2642,18 +2629,14 @@ class _SchedulerBase:
             for name in self._ENGINE_MIRRORS:
                 setattr(self.stats, name, getattr(self.engine, name, 0))
             cache = self.cache
-            self.stats.prefix_hits = getattr(cache, "prefix_hits", 0)
-            self.stats.prefix_pages_shared = int(
-                getattr(cache, "_shared", np.zeros(1)).sum()
-            )
-            self.stats.cow_copies = getattr(cache, "cow_copies", 0)
-            self.stats.swap_outs = getattr(cache, "swap_outs", 0)
-            self.stats.swap_ins = getattr(cache, "swap_ins", 0)
-            self.stats.swap_bytes = getattr(cache, "swap_bytes_total", 0)
-            self.stats.swapped_pages = getattr(cache, "swapped_pages", 0)
-            self.stats.prefix_evictions = getattr(
-                cache, "prefix_evictions", 0
-            )
+            self.stats.prefix_hits = cache.prefix_hits
+            self.stats.prefix_pages_shared = int(cache._shared.sum())
+            self.stats.cow_copies = cache.cow_copies
+            self.stats.swap_outs = cache.swap_outs
+            self.stats.swap_ins = cache.swap_ins
+            self.stats.swap_bytes = cache.swap_bytes_total
+            self.stats.swapped_pages = cache.swapped_pages
+            self.stats.prefix_evictions = cache.prefix_evictions
             if self.debug_invariants:
                 # pages the injector stole this iteration are accounted
                 # as extra frees — conservation must hold even mid-chaos
@@ -2695,7 +2678,7 @@ class _SchedulerBase:
                     self._journal_snapshots()
 
     def _journal_snapshots(self) -> None:
-        """Journal-referenced KV snapshots (paged layout only): every
+        """Journal-referenced KV snapshots: every
         `journal_snapshot_every` iterations, each running slot's
         committed pages ride `snapshot_swap` into a snapshot record, so
         a restart can restore KV over `import_swap` instead of
@@ -2703,14 +2686,13 @@ class _SchedulerBase:
         `gen_len` stamps the committed-run length the snapshot is
         consistent with; recovery honors the snapshot only while that
         still matches the journal's committed cursor."""
-        snap = getattr(self.cache, "snapshot_swap", None)
-        if snap is None or self.journal.degraded:
+        if self.journal.degraded:
             return
         for slot in sorted(self.running):
             req = self.running[slot]
             if self._prefill_pending(req):
                 continue  # mid-prefill KV is not a resumable cursor
-            rec = snap(slot)
+            rec = self.cache.snapshot_swap(slot)
             if rec is not None:
                 rec["gen_len"] = len(req.generated)
                 self.journal.snapshot(req.rid, rec)
@@ -2742,7 +2724,7 @@ class _SchedulerBase:
             handles[name].value = value
         handles["serve_queue_depth"].value = len(self.queue)
         handles["serve_running_requests"].value = len(self.running)
-        if getattr(self.cache, "num_hosts", 1) > 1:
+        if self.cache.num_hosts > 1:
             # per-host pool/scheduler slices under a `host` label (the
             # process index on a real pod; simulated-host partitions on
             # one process). The unlabelled series above stay the
@@ -2795,10 +2777,8 @@ class _SchedulerBase:
         if self.proposer is not None:
             for name, value in self.proposer.telemetry_counters().items():
                 tele.registry.counter(name).set_monotonic(value)
-        cache_counters = getattr(self.cache, "telemetry_counters", None)
-        if cache_counters is not None:
-            for name, value in cache_counters().items():
-                tele.registry.counter(name).set_monotonic(value)
+        for name, value in self.cache.telemetry_counters().items():
+            tele.registry.counter(name).set_monotonic(value)
         self.stats.publish_derived()
         tele.sample(self._iter)
         now = time.perf_counter()
@@ -2809,7 +2789,7 @@ class _SchedulerBase:
             now,
             args={"iter": self._iter},
         )
-        if getattr(self.cache, "num_hosts", 1) > 1:
+        if self.cache.num_hosts > 1:
             # one lane per host partition: the iteration span again, but
             # annotated with that host's running/free-page view so the
             # Perfetto timeline shows per-host load side by side
@@ -2896,10 +2876,9 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
     One-step-stale semantics: terminal events land at RECONCILE, so a
     request that hits EOS/budget in step N is still (wastefully but
     harmlessly) stepped in N+1 — the identity check in the commit phase
-    discards its speculative token, the slot layout's stale cache write
-    is overwritten before any lengths mask exposes it, and the paged
-    layout pins every page an in-flight step references (kv_cache
-    limbo) so the row cannot land in a page a new sequence owns.
+    discards its speculative token, and the cache pins every page an
+    in-flight step references (kv_cache limbo) so the row cannot land
+    in a page a new sequence owns.
     `cancel()` of a RUNNING request and running-deadline reaping defer
     to the next reconcile for the same reason; queued requests cancel/
     reap immediately. When a page claim finds the pool dry because of
@@ -3163,30 +3142,6 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
             else:
                 self.stats.pre_proposal_misses += 1
         return proposals
-
-
-class StaticBatchingScheduler(_SchedulerBase):
-    """Request-level batching baseline: a batch runs until every member
-    finishes; freed slots stay idle until the batch drains. Chunked
-    prefill is an iteration-level technique — the baseline rejects a
-    token_budget rather than silently admitting requests whose prompts
-    would then never stream in."""
-
-    def __init__(self, *args, **kwargs):
-        if kwargs.get("token_budget"):
-            raise ValueError(
-                "token_budget (chunked prefill) requires the continuous "
-                "scheduler"
-            )
-        super().__init__(*args, **kwargs)
-
-    def step(self) -> None:
-        self._begin_iteration()
-        if not self.running:
-            self._admit()
-        if self.running:
-            self._generate_once()
-        self._end_iteration()
 
 
 _LATENCY_METRICS = {

@@ -20,17 +20,18 @@ Two draft sources implement the `DraftProposer` protocol:
   run, surprisingly effective on repetitive continuations, and the CI
   preset (no second model to build).
 * `ModelDraftProposer` — SpecInfer's small-model draft: a second
-  compiled `build_decoder_lm` with its OWN KVCache + GenerationEngine,
-  kept slot-aligned with the target (`KVCache.claim`) and rolled back
+  compiled `build_decoder_lm` with its OWN PagedKVCache +
+  GenerationEngine, kept slot-aligned with the target
+  (`PagedKVCache.alloc(slot=...)`) and rolled back
   with the same `truncate` API the target uses. The draft always
   decodes greedily, so its proposal is a point mass and the same
   acceptance rule covers both proposers.
 
 Rollback is the cache-side half of the protocol: verify writes K/V rows
 for ALL k+1 positions; `cache.truncate(slot, new_len)` then commits the
-accepted prefix — the slot layout just moves the visible length (stale
-rows are masked), the paged layout also returns the pages past the
-accepted length to the free pool under the admission-reserve accounting.
+accepted prefix: the visible length moves (stale rows are masked) and
+the pages past the accepted length return to the free pool under the
+admission-reserve accounting.
 
 The scheduler side lives in serving/scheduler.py (`proposer=`/`spec_k=`
 on either scheduler class); `optimize_spec_k` (search/auto.py) picks k
@@ -554,8 +555,9 @@ class NGramDraftProposer(DraftProposer):
 
 class ModelDraftProposer(DraftProposer):
     """Small-model draft (SpecInfer's SSM): a second compiled decoder LM
-    with its own slot-layout KVCache and GenerationEngine, slot-aligned
-    with the target via `KVCache.claim`. Drafting is k greedy decode
+    with its own PagedKVCache (default geometry: every slot can reach
+    max_len) and GenerationEngine, taking the target's slot ids through
+    `PagedKVCache.alloc(slot=...)`. Drafting is k greedy decode
     steps of the draft engine; between verify iterations the draft cache
     is rolled back to the target's accepted length with the same
     `truncate` call, and the next propose() replays whatever accepted
@@ -577,10 +579,10 @@ class ModelDraftProposer(DraftProposer):
         decode_kernel: str = "auto",
     ):
         from flexflow_tpu.serving.engine import GenerationEngine
-        from flexflow_tpu.serving.kv_cache import KVCache
+        from flexflow_tpu.serving.kv_cache import PagedKVCache
 
         self.model = draft_model
-        self.cache = KVCache.from_model(
+        self.cache = PagedKVCache.from_model(
             draft_model, max_seqs=max_seqs, max_len=max_len, buckets=buckets
         )
         # the draft's k decode steps live in the same memory-bound regime
@@ -608,7 +610,9 @@ class ModelDraftProposer(DraftProposer):
     # -- lifecycle -----------------------------------------------------------
 
     def admit(self, requests) -> None:
-        """Mirror the target's admission: claim the SAME slot ids and
+        """Mirror the target's admission: take the SAME slot ids (with
+        the whole of max_len reserved: the draft pool holds that for
+        every slot, so the draft never runs dry before the target) and
         prefill the draft cache with each request's committed history —
         the prompt, plus any tokens already generated when a preempted
         request re-admits for recompute (serving/scheduler.py); feeding
@@ -616,12 +620,18 @@ class ModelDraftProposer(DraftProposer):
         otherwise replay token-by-token as catch-up feeds. The
         prefill's own next-token output is unused — drafts start from
         the target's last emitted token at the next propose()."""
-        for req in requests:
-            self.cache.claim(req.slot)
+        histories = [list(r.prompt) + list(r.generated) for r in requests]
+        for req, hist in zip(requests, histories):
+            taken = self.cache.alloc(
+                len(hist), self.cache.spec.max_len, slot=req.slot
+            )
+            if taken is None:
+                raise RuntimeError(
+                    f"draft cache refused slot {req.slot}: its pool is "
+                    "smaller than max_seqs * max_len"
+                )
         self.engine.prefill(
-            self.params,
-            [list(r.prompt) + list(r.generated) for r in requests],
-            [r.slot for r in requests],
+            self.params, histories, [r.slot for r in requests]
         )
 
     def retire(self, request) -> None:

@@ -25,9 +25,8 @@ The `Telemetry` facade bundles the three and owns the output paths;
 `serving.build_scheduler` threads one instance through the engine,
 scheduler, cache, and fault injector. Cost discipline: when no
 Telemetry is attached the serving hot path takes a single predicate
-branch per hook and allocates nothing — proved by the bench gate
-(bench_serve.py --telemetry: disabled-telemetry throughput within 2%
-of the uninstrumented baseline).
+branch per hook and allocates nothing
+(tests/test_telemetry.py::test_disabled_telemetry_is_fully_absent).
 """
 
 from __future__ import annotations
@@ -198,8 +197,7 @@ class Telemetry:
         # JSONL path is configured: without one, `sample()` skips the
         # row build AND the rolling-percentile refresh (np.percentile
         # over the windows) — exposition refreshes them at flush/render
-        # instead. This is what keeps the in-memory bundle inside the
-        # 2% overhead gate (bench_serve.py --telemetry).
+        # instead.
         self.wants_samples = self._jsonl is not None
 
     @property

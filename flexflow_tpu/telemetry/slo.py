@@ -14,8 +14,8 @@ against.
 `percentiles()` here is THE percentile implementation for the serving
 stack: `scheduler.latency_percentiles` (the post-hoc per-request view)
 routes through it, so the rolling-window p95 and the post-hoc p95 agree
-exactly whenever the window still holds every sample — the acceptance
-check bench_serve's telemetry gate runs.
+exactly whenever the window still holds every sample
+(tests/test_telemetry.py::test_rolling_p95_ttft_agrees_with_post_hoc).
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
-"""Shared train-step timing for the benchmark surfaces (bench.py,
-scripts/bench_configs.py, scripts/calibrate.py callers).
+"""Shared train-step timing (search/audit.py, scripts/bench_configs.py,
+scripts/calibrate.py callers).
 
 On the chip `block_until_ready` waits for the device (chip_smoke.py's
 sync phase re-checks it on every run), so a step of tens of

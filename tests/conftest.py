@@ -47,3 +47,30 @@ def build_capi_lib():
         text=True,
     )
     assert build.returncode == 0, build.stderr
+
+
+def page_geometry(layout: str, max_seq_len: int) -> dict:
+    """ServeConfig keywords of a test's cache geometry. "paged" is
+    ServeConfig()'s own (pages of 16, halved until they divide the
+    sequence); "one_page" is one page a slot with the default pool: a
+    block table one wide, no growth past the first page, the kernel's
+    page as long as the sequence. A boundary to test at these tests'
+    32 to 64 positions, not a setting to serve with."""
+    return {"paged": {}, "one_page": {"kv_page_size": max_seq_len}}[layout]
+
+
+def ref_generate(model, prompt, n):
+    """Recomputed full-prefill forward per emitted token, with no cache:
+    the oracle the serving path must reproduce."""
+    import numpy as np
+
+    toks = list(prompt)
+    out = []
+    for _ in range(n):
+        logits = np.asarray(
+            model.forward({"tokens": np.asarray([toks], dtype=np.int32)})
+        )
+        t = int(np.argmax(logits[0, len(toks) - 1]))
+        out.append(t)
+        toks.append(t)
+    return out

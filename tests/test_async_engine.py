@@ -3,7 +3,7 @@ serving/scheduler.AsyncContinuousBatchingScheduler + the
 dispatch/reconcile split in serving/engine.py).
 
 The load-bearing proofs: async greedy streams are TOKEN-IDENTICAL to
-the synchronous reference loop on both kv layouts, with speculation on
+the synchronous reference loop at both page geometries, with speculation on
 and off, under forced preemption, and through a seeded chaos schedule
 whose NaN fault and mid-flight cancel land inside the in-flight window;
 the paged allocator pins every page an in-flight step references (limbo)
@@ -39,6 +39,7 @@ from flexflow_tpu.serving import (
     TERMINAL_STATUSES,
     build_scheduler,
 )
+from tests.conftest import page_geometry
 
 pytestmark = pytest.mark.serving
 
@@ -78,10 +79,10 @@ def _requests(n=6, max_new=8, **kw):
     ]
 
 
-def _run(lm, serve_async, layout="slot", n=6, max_new=8, reqs=None,
+def _run(lm, serve_async, layout="paged", n=6, max_new=8, reqs=None,
          injector=None, **cfg_kw):
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout=layout,
+        max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
         serve_async=serve_async, debug_invariants=True, **cfg_kw,
     )
     sched, engine, cache = build_scheduler(lm, serve, injector=injector)
@@ -92,7 +93,7 @@ def _run(lm, serve_async, layout="slot", n=6, max_new=8, reqs=None,
 # -- token-identity parity ----------------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_async_matches_sync_greedy_streams(lm, layout):
     _, _, _, sync = _run(lm, False, layout)
     _, _, _, asy = _run(lm, True, layout)
@@ -102,7 +103,7 @@ def test_async_matches_sync_greedy_streams(lm, layout):
         assert sync[rid].generated == asy[rid].generated, rid
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_async_matches_sync_with_speculation(lm, layout):
     kw = dict(spec_draft="ngram", spec_k=3)
     _, _, _, sync = _run(lm, False, layout, max_new=12, **kw)
@@ -176,7 +177,7 @@ def _chunked_requests(max_new=6, **kw):
     ]
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 @pytest.mark.parametrize(
     "spec_kw", [{}, dict(spec_draft="ngram", spec_k=3)],
     ids=["plain", "spec"],
@@ -184,7 +185,7 @@ def _chunked_requests(max_new=6, **kw):
 def test_async_chunked_matches_sync_and_unchunked(lm, layout, spec_kw):
     """Chunked prefill commits only at reconcile under --serve-async:
     the async chunked run is token-identical to the sync chunked run
-    AND to the unchunked sync reference, on both layouts, with
+    AND to the unchunked sync reference, at both page geometries, with
     speculation on and off — while actually chunking (chunk_steps > 0)
     and keeping chunk steps in flight alongside decode/verify."""
     chunk_kw = dict(token_budget=8, chunk_size=4, decode_kernel="dense",
@@ -287,7 +288,7 @@ def test_async_chaos_window_loses_nothing(lm):
     window (keyed by dispatch iteration): the hit request fails/cancels,
     every other stream is token-identical to a fault-free async run, no
     request is lost, and the paged accounting holds every iteration."""
-    for layout in ("slot", "paged"):
+    for layout in ("one_page", "paged"):
         _, _, _, clean = _run(lm, True, layout, n=6, max_new=10)
         plan = FaultPlan(
             nan_iters={4: [1]},  # slot 1's step DISPATCHED at iter 4
@@ -316,7 +317,7 @@ def test_async_chaos_window_loses_nothing(lm):
 
 def test_async_forced_preemption_completes_all(lm):
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout="paged",
+        max_seqs=4, max_seq_len=32,
         kv_page_size=4, kv_pages=8,  # minimum legal pool: forces preemption
         admission="optimistic", max_preemptions=8,
         serve_async=True, debug_invariants=True,
@@ -327,7 +328,7 @@ def test_async_forced_preemption_completes_all(lm):
     assert sched.stats.preemptions > 0
     # parity against the sync loop under the same pressure
     serve_sync = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout="paged",
+        max_seqs=4, max_seq_len=32,
         kv_page_size=4, kv_pages=8, admission="optimistic",
         max_preemptions=8, debug_invariants=True,
     )
@@ -463,8 +464,6 @@ def test_serve_async_flag_and_builder_wiring(lm):
         max_seqs=4, max_seq_len=32))
     assert not isinstance(sched, AsyncContinuousBatchingScheduler)
     assert isinstance(sched, ContinuousBatchingScheduler)
-    with pytest.raises(ValueError, match="continuous"):
-        ServeConfig(scheduler="static", serve_async=True)
 
 
 def test_inflight_step_snapshot_is_immutable_view(lm):

@@ -1,7 +1,7 @@
 """Pallas flash-decode kernel family (ops/pallas/decode_kernel.py):
 interpret-mode parity of all four kernel entry points against the dense
 jnp paths in ops/attention.py, token-identical greedy streams through
-GenerationEngine with the kernel forced on (both kv layouts, plain and
+GenerationEngine with the kernel forced on (both page geometries, plain and
 speculative), sentinel block-table handling, supports() rejection →
 dense fallback, the decode-kernel config/flag wiring, and the
 kernel-aware decode/verify cost terms. All CPU-fast (tier 1): off-TPU
@@ -30,6 +30,7 @@ from flexflow_tpu.ops.attention import (
 )
 from flexflow_tpu.ops.pallas import decode_kernel as dk
 from flexflow_tpu.serving import ServeConfig, build_scheduler
+from tests.conftest import page_geometry
 
 pytestmark = pytest.mark.serving
 
@@ -210,21 +211,21 @@ def test_tuned_chunk_installation():
         dk._TUNED.update(before)
 
 
-# -- engine integration: kernel forced on, both layouts -----------------------
+# -- engine integration: kernel forced on, both geometries -----------------------
 
 
 def _generate(lm, layout, mode, spec=False, max_new=6):
     serve = ServeConfig(
         max_seqs=2,
         max_seq_len=32,
-        kv_layout=layout,
+        **page_geometry(layout, 32),
         decode_kernel=mode,
         **(dict(spec_draft="ngram", spec_k=3) if spec else {}),
     )
     return lm.generate(PROMPTS, max_new_tokens=max_new, serve_config=serve)
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_greedy_streams_token_identical(lm, layout):
     """With the kernel forced on (interpret mode on CPU), greedy decode
     through the scheduler is token-for-token identical to the dense
@@ -232,17 +233,17 @@ def test_greedy_streams_token_identical(lm, layout):
     assert _generate(lm, layout, "pallas") == _generate(lm, layout, "dense")
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_spec_streams_token_identical(lm, layout):
     """Speculative greedy decode (n-gram drafts, verify through the
     kernel's staircase path) stays token-identical to the dense spec
-    engine AND to plain dense decode on both layouts."""
+    engine AND to plain dense decode at both page geometries."""
     spec_kernel = _generate(lm, layout, "pallas", spec=True, max_new=8)
     assert spec_kernel == _generate(lm, layout, "dense", spec=True, max_new=8)
     assert spec_kernel == _generate(lm, layout, "dense", max_new=8)
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_verify_logits_match_dense(lm, layout):
     """GenerationEngine.verify logits (the w-query staircase scoring
     pass) agree numerically between the kernel and dense engines."""
@@ -253,7 +254,7 @@ def test_verify_logits_match_dense(lm, layout):
         _, engine, cache = build_scheduler(
             lm,
             ServeConfig(
-                max_seqs=2, max_seq_len=32, kv_layout=layout,
+                max_seqs=2, max_seq_len=32, **page_geometry(layout, 32),
                 decode_kernel=mode,
             ),
         )
@@ -287,11 +288,11 @@ def test_rejected_geometry_falls_back_to_dense(monkeypatch):
     for fn in ("flash_decode", "flash_verify", "paged_flash_decode",
                "paged_flash_verify"):
         monkeypatch.setattr(dk, fn, boom)
-    for layout in ("slot", "paged"):
+    for layout in ("one_page", "paged"):
         forced = model.generate(
             PROMPTS[:4], max_new_tokens=5,
             serve_config=ServeConfig(max_seqs=2, max_seq_len=32,
-                                     kv_layout=layout,
+                                     **page_geometry(layout, 32),
                                      decode_kernel="pallas"),
         )
         assert forced == dense
@@ -299,14 +300,13 @@ def test_rejected_geometry_falls_back_to_dense(monkeypatch):
 
 def test_page_size_rejection_falls_back(monkeypatch):
     """A sublane-misaligned page size is rejected for the paged kernel
-    while the slot kernel geometry stays eligible — the fallback is
-    per-path, not global."""
+    and served on the dense path."""
     assert not dk.supports(1, 32, 8, page_size=4)
     model = _lm()
     dense = model.generate(
         PROMPTS[:4], max_new_tokens=5,
         serve_config=ServeConfig(max_seqs=2, max_seq_len=32,
-                                 kv_layout="paged", kv_page_size=4,
+                                 kv_page_size=4,
                                  decode_kernel="dense"),
     )
     for fn in ("paged_flash_decode", "paged_flash_verify"):
@@ -315,7 +315,7 @@ def test_page_size_rejection_falls_back(monkeypatch):
     forced = model.generate(
         PROMPTS[:4], max_new_tokens=5,
         serve_config=ServeConfig(max_seqs=2, max_seq_len=32,
-                                 kv_layout="paged", kv_page_size=4,
+                                 kv_page_size=4,
                                  decode_kernel="pallas"),
     )
     assert forced == dense
@@ -341,9 +341,9 @@ def test_decode_kernel_flag_wiring():
 
 
 def test_engine_rejects_bad_mode(lm):
-    from flexflow_tpu.serving import GenerationEngine, KVCache
+    from flexflow_tpu.serving import GenerationEngine, PagedKVCache
 
-    cache = KVCache.from_model(lm, max_seqs=2, max_len=32)
+    cache = PagedKVCache.from_model(lm, max_seqs=2, max_len=32)
     with pytest.raises(ValueError):
         GenerationEngine(lm, cache, decode_kernel="fast")
 

@@ -158,8 +158,6 @@ def test_build_placement_rejects_tp_not_dividing_heads(lm):
 
 
 def test_serve_config_pod_validation():
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(serve_hosts=2, kv_layout="slot")
     with pytest.raises(ValueError, match="serve-mesh"):
         ServeConfig(serve_mesh="nope")
     with pytest.raises(ValueError, match="serve_hosts"):
@@ -309,6 +307,33 @@ def test_serve_mesh_flag_end_to_end(lm):
     assert (pl.dp, pl.tp, pl.num_hosts) == (4, 1, 4)
     assert pl.mesh_source == "flag"
     assert out == _gen(lm)
+
+
+def test_pod_capacity_scales_with_hosts():
+    """The count the pod CI gate held: at an equal PER-HOST page budget,
+    four host partitions run four times the concurrent requests of one
+    (the partition is contention-free; the gate asked for 3x). Every
+    request reserves two pages, so pages bind, not slots."""
+    page, pages_per_host = 16, 8
+    peak = {}
+    for hosts in (1, 4):
+        slots = pages = pages_per_host * hosts
+        serve = ServeConfig(
+            max_seqs=slots, max_seq_len=32, kv_page_size=page,
+            kv_pages=pages, serve_hosts=hosts if hosts > 1 else 0,
+            **({"serve_mesh": "4,1"} if hosts > 1 else {}),
+        )
+        sched, _, cache = build_scheduler(_lm(batch=slots), serve)
+        assert cache.num_hosts == hosts
+        done = sched.run([
+            Request(rid=i, max_new_tokens=2,
+                    prompt=[(i * 7 + j) % (VOCAB - 1) + 1 for j in range(page)])
+            for i in range(2 * slots)
+        ])
+        assert len(done) == 2 * slots and all(r.ok for r in done)
+        peak[hosts] = sched.stats.peak_in_flight
+    assert peak[1] == pages_per_host // 2
+    assert peak[4] == 4 * peak[1]
 
 
 # -- searched mesh: applied vs inherited -------------------------------------

@@ -3,7 +3,7 @@ serving/scheduler._fusable_steps + _decode_multi_dispatch_step +
 engine.decode_multi_dispatch/_reconcile — the fused lax.scan window).
 
 The load-bearing proofs: fused K-step windows are TOKEN-identical to
-the step-at-a-time reference on both kv layouts × {sync, async} ×
+the step-at-a-time reference at both page geometries × {sync, async} ×
 {fp32, int8} × {prefix cache on/off} × {chunked on/off} × {dense,
 pallas} attention cores, and LOGIT-identical at the engine level (the
 scan body IS the single-step core, so parity is exact, not
@@ -34,6 +34,7 @@ from flexflow_tpu.serving import (
     ServeConfig,
     build_scheduler,
 )
+from tests.conftest import page_geometry
 
 pytestmark = pytest.mark.serving
 
@@ -73,10 +74,10 @@ def _requests(n=6, max_new=8, **kw):
     ]
 
 
-def _run(lm, multistep, layout="slot", serve_async=False, n=4, max_new=10,
+def _run(lm, multistep, layout="paged", serve_async=False, n=4, max_new=10,
          reqs=None, **cfg_kw):
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout=layout,
+        max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
         serve_async=serve_async, debug_invariants=True,
         decode_multistep=multistep, **cfg_kw,
     )
@@ -100,9 +101,9 @@ def _assert_parity(plain, fused):
 @pytest.mark.parametrize(
     "serve_async,layout",
     [
-        (False, "slot"),
+        (False, "one_page"),
         pytest.param(False, "paged", marks=pytest.mark.slow),
-        pytest.param(True, "slot", marks=pytest.mark.slow),
+        pytest.param(True, "one_page", marks=pytest.mark.slow),
         (True, "paged"),
     ],
 )
@@ -116,6 +117,22 @@ def test_multistep_matches_plain_streams(lm, layout, serve_async):
     assert s.multistep_steps > s.multistep_windows
     assert s.host_syncs < psched.stats.host_syncs
     assert s.host_syncs_per_token < psched.stats.host_syncs_per_token
+
+
+def test_fused_window_cuts_host_syncs_fourfold(lm):
+    """The count the multi-step CI gate held: on a quiet stretch (four
+    requests, four slots, no admission or retirement for 24 steps) a
+    fused window of up to 8 steps takes at most a quarter of the host
+    syncs a committed token costs step-at-a-time. One page a slot, so
+    no page boundary cuts a window short."""
+    kw = dict(n=4, max_new=24, max_fused_steps=8)
+    psched, _, _, plain = _run(lm, False, "one_page", **kw)
+    fsched, _, _, fused = _run(lm, True, "one_page", **kw)
+    _assert_parity(plain, fused)
+    assert (
+        4 * fsched.stats.host_syncs_per_token
+        <= psched.stats.host_syncs_per_token
+    )
 
 
 @pytest.mark.slow  # runs in the serving-multistep CI job
@@ -180,7 +197,7 @@ def test_multistep_matches_plain_kernel(lm, kernel):
 @pytest.mark.parametrize(
     "layout,dtype",
     [
-        pytest.param("slot", "fp32", marks=pytest.mark.slow),
+        pytest.param("one_page", "fp32", marks=pytest.mark.slow),
         ("paged", "fp32"),
         pytest.param("paged", "int8", marks=pytest.mark.slow),
     ],
@@ -194,7 +211,8 @@ def test_multistep_engine_logit_identity(lm, layout, dtype):
 
     def build():
         serve = ServeConfig(
-            max_seqs=4, max_seq_len=32, kv_layout=layout, kv_dtype=dtype,
+            max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
+            kv_dtype=dtype,
             decode_multistep=True, debug_invariants=True,
         )
         sched, eng, cache = build_scheduler(lm, serve)
@@ -244,7 +262,7 @@ def test_multistep_engine_logit_identity(lm, layout, dtype):
 
 
 @pytest.mark.parametrize(
-    "layout", [pytest.param("slot", marks=pytest.mark.slow), "paged"]
+    "layout", [pytest.param("one_page", marks=pytest.mark.slow), "paged"]
 )
 def test_eos_inside_window_retires_at_position(lm, layout):
     """Pick a token the greedy continuation actually emits mid-stream
@@ -366,8 +384,8 @@ def test_speculative_mode_fuses_only_draft_free_iterations(lm):
     spec + multistep interleave fused windows with verify steps, and
     the stream still matches plain decode exactly."""
     kw = dict(spec_draft="ngram", spec_k=3)
-    _, _, _, plain = _run(lm, False, "slot", **kw)
-    fsched, _, _, fused = _run(lm, True, "slot", **kw)
+    _, _, _, plain = _run(lm, False, **kw)
+    fsched, _, _, fused = _run(lm, True, **kw)
     _assert_parity(plain, fused)
     assert fsched.stats.verify_steps > 0
     assert fsched.stats.multistep_windows > 0
@@ -391,8 +409,6 @@ def test_flag_wiring_and_validation(lm):
     assert sched.decode_multistep is True and sched.max_fused_steps == 4
     with pytest.raises(ValueError):
         ServeConfig(decode_multistep=True, max_fused_steps=0)
-    with pytest.raises(ValueError):
-        ServeConfig(decode_multistep=True, scheduler="static")
 
 
 # -- observability ------------------------------------------------------------
@@ -410,8 +426,8 @@ def test_multistep_cache_is_bounded_and_observable(lm):
     assert sched.stats.multistep_cache_entries == eng.multistep_cache_entries
     # the LRU bound holds even if the horizon churns K buckets
     eng._multistep_cache.max_entries = 1
-    eng._multistep_cache.get((4, 2, "slot"))
-    eng._multistep_cache.get((4, 4, "slot"))
+    eng._multistep_cache.get((4, 2))
+    eng._multistep_cache.get((4, 4))
     assert eng.multistep_cache_entries == 1
 
 

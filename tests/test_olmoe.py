@@ -25,6 +25,7 @@ from flexflow_tpu import (  # noqa: E402
 )
 from flexflow_tpu.models import build_olmoe  # noqa: E402
 from flexflow_tpu.serving import ServeConfig, build_scheduler  # noqa: E402
+from tests.conftest import page_geometry
 
 VOCAB, K, SEQ, TOL = 211, 2, 32, 1e-5
 SIZES = dict(
@@ -85,7 +86,7 @@ def _one_hot_slot(values, slot, n=4, dtype=np.int32):
 def _prefill_then_decode(model, layout, steps=8):
     """Last-position logits of a prefill and `steps` cached decode steps
     through the engine, and the token sequence they were computed over."""
-    _, engine, cache = _serve(model, kv_layout=layout)
+    _, engine, cache = _serve(model, **page_geometry(layout, SEQ))
     prompt = _prompt(11)
     slot = cache.alloc(len(prompt), len(prompt) + steps)
     nxt, last = engine.prefill(model.params, [prompt], [slot])
@@ -120,8 +121,8 @@ def case_prefill_decode(model, layout="paged"):
     assert 8 * layers * K <= engine.moe_experts_touched_decode <= 8 * layers * 8
 
 
-def case_slot_layout(model):
-    case_prefill_decode(model, layout="slot")
+def case_one_page(model):
+    case_prefill_decode(model, layout="one_page")
 
 
 def case_chunked(model):

@@ -1,6 +1,6 @@
 """Paged KV cache (flexflow_tpu/serving/kv_cache.py PagedKVCache +
 ops/attention.paged_decode_attention): token-for-token equivalence with
-the slot-contiguous layout across admit/finish/re-admit schedules (page
+the cache-free full forward across admit/finish/re-admit schedules (page
 reuse), allocator invariants (no double allocation, free-list
 conservation, preemption-free reserve), the capacity win on
 short-request workloads at a fixed byte budget, page-geometry config
@@ -32,6 +32,7 @@ from flexflow_tpu.serving import (
     build_scheduler,
     default_page_size,
 )
+from tests.conftest import page_geometry, ref_generate
 
 pytestmark = pytest.mark.serving
 
@@ -71,35 +72,38 @@ def _requests(spec):
     ]
 
 
-# -- paged vs slot equivalence ------------------------------------------------
+# -- paged cache vs the cache-free forward -------------------------------------
 
 
 def test_paged_equals_slot_token_stream(lm):
     """Greedy decode through the paged cache is token-for-token identical
-    to the slot-contiguous cache on a schedule that admits, finishes, and
+    to recomputing the whole forward for every token, at both page
+    geometries, on a schedule that admits, finishes, and
     re-admits requests (forced page reuse: 10 requests through 2 slots)."""
     prompts = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 3, 1, 2], [7], [11, 12],
                [3, 3, 3], [8, 1], [2], [5, 9, 13], [6, 6]]
-    outs = {}
-    for layout in ("slot", "paged"):
-        outs[layout] = lm.generate(
+    want = [ref_generate(lm, p, 6) for p in prompts]
+    for layout in ("one_page", "paged"):
+        got = lm.generate(
             prompts,
             max_new_tokens=6,
             serve_config=ServeConfig(
-                max_seqs=2, max_seq_len=32, kv_layout=layout
+                max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)
             ),
         )
-    assert outs["paged"] == outs["slot"]
+        assert got == want, layout
 
 
 def test_paged_decode_logits_match_slot(lm):
-    """Numeric (not just argmax) agreement: one prefill + one decode on
-    each layout yields the same logits."""
+    """Numeric (not just argmax) agreement: one prefill + one decode at
+    each page geometry yields the full forward's logits."""
     prompt = [3, 1, 4, 1, 5]
-    logits = {}
-    for layout in ("slot", "paged"):
+    for layout in ("one_page", "paged"):
         _, engine, cache = build_scheduler(
-            lm, ServeConfig(max_seqs=2, max_seq_len=32, kv_layout=layout)
+            lm,
+            ServeConfig(
+                max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)
+            ),
         )
         slot = cache.alloc(len(prompt), len(prompt) + 2)
         nxt, last = engine.prefill(lm.params, [prompt], [slot])
@@ -108,9 +112,12 @@ def test_paged_decode_logits_match_slot(lm):
         tokens[slot] = int(nxt[0])
         active[slot] = True
         _, dec = engine.decode(lm.params, tokens, active)
-        logits[layout] = (np.asarray(last[0]), np.asarray(dec[slot]))
-    np.testing.assert_allclose(logits["paged"][0], logits["slot"][0], atol=1e-5)
-    np.testing.assert_allclose(logits["paged"][1], logits["slot"][1], atol=1e-5)
+        seq = prompt + [int(nxt[0])]
+        full = np.asarray(
+            lm.forward({"tokens": np.asarray([seq], dtype=np.int32)})
+        )[0]
+        np.testing.assert_allclose(last[0], full[len(prompt) - 1], atol=1e-5)
+        np.testing.assert_allclose(dec[slot], full[len(prompt)], atol=1e-5)
 
 
 def test_paged_decode_attention_matches_dense():
@@ -136,7 +143,7 @@ def test_paged_decode_attention_matches_dense():
         n = -(-int(lengths[i] + 1) // ps)
         tables[i, :n] = perm[used: used + n]
         used += n
-    # contiguous view the slot layout would hold
+    # contiguous view of the same positions
     k_ctg = np.zeros((b, max_len, h, d), np.float32)
     v_ctg = np.zeros((b, max_len, h, d), np.float32)
     for i in range(b):
@@ -196,7 +203,7 @@ def test_allocator_invariants_through_schedule(lm):
     back to empty."""
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=3, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=3, max_seq_len=32,
                     kv_page_size=8),
     )
     for r in _requests([2, 9, 4, 1, 7, 3, 5, 8, 2, 6]):
@@ -221,7 +228,7 @@ def test_reserve_policy_is_preemption_free(lm):
     # the default capacity, so admission must throttle on pages
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=8, kv_pages=8),
     )
     reqs = _requests([20, 20, 20, 20, 20])  # each needs 3 pages worst-case
@@ -236,17 +243,17 @@ def test_reserve_policy_is_preemption_free(lm):
 
 def test_paged_capacity_beats_slot_on_short_requests(lm):
     """The acceptance criterion, deterministically: at the SAME byte
-    budget (max_seqs * max_len rows), the paged layout admits >= 1.5x
-    more concurrent short requests than the slot layout."""
+    budget (max_seqs * max_len rows), pages of 16 admit >= 1.5x
+    more concurrent short requests than one page of max_len a request."""
     max_seqs, max_len = 2, 32
     ps = default_page_size(max_len)
     budget_pages = max_seqs * max_len // ps  # 4 pages of 16
     peak = {}
     for name, serve in (
-        ("slot", ServeConfig(max_seqs=max_seqs, max_seq_len=max_len,
-                             kv_layout="slot")),
+        ("one_page", ServeConfig(max_seqs=8, max_seq_len=max_len,
+                                 kv_page_size=max_len, kv_pages=max_seqs)),
         ("paged", ServeConfig(max_seqs=8, max_seq_len=max_len,
-                              kv_layout="paged", kv_page_size=ps,
+                              kv_page_size=ps,
                               kv_pages=budget_pages)),
     ):
         sched, _, _ = build_scheduler(lm, serve)
@@ -260,8 +267,8 @@ def test_paged_capacity_beats_slot_on_short_requests(lm):
             ]
         )
         peak[name] = sched.stats.peak_in_flight
-    assert peak["slot"] == max_seqs
-    assert peak["paged"] >= 1.5 * peak["slot"]
+    assert peak["one_page"] == max_seqs
+    assert peak["paged"] >= 1.5 * peak["one_page"]
 
 
 def test_optimistic_alloc_reserves_nothing():
@@ -316,23 +323,34 @@ def test_optimistic_alloc_reserves_nothing():
 
 
 def test_kv_flags_parse():
-    cfg = FFConfig.parse_args(
-        ["--kv-page-size", "8", "--kv-pages", "64", "--kv-layout", "slot"]
-    )
+    cfg = FFConfig.parse_args(["--kv-page-size", "8", "--kv-pages", "64"])
     sc = ServeConfig.from_config(cfg)
     assert sc.kv_page_size == 8
     assert sc.kv_pages == 64
-    assert sc.kv_layout == "slot"
-    # defaults: paged layout, auto geometry
+    # defaults: auto geometry
     sc = ServeConfig.from_config(FFConfig.parse_args([]))
-    assert (sc.kv_layout, sc.kv_page_size, sc.kv_pages) == ("paged", 0, 0)
+    assert (sc.kv_page_size, sc.kv_pages) == (0, 0)
+
+
+def test_kv_layout_is_not_an_option():
+    """One cache layout: the constructor has no such field, the flag
+    parser no such flag (it takes an unknown flag for a script's own,
+    as it always did, and sets nothing), and the name reads "paged"."""
+    with pytest.raises(TypeError, match="kv_layout"):
+        ServeConfig(kv_layout="paged")
+    assert "kv_layout" not in {f.name for f in dataclasses.fields(ServeConfig)}
+    assert ServeConfig().kv_layout == "paged"
+    with pytest.raises(AttributeError):
+        ServeConfig().kv_layout = "slot"
+    cfg = FFConfig.parse_args(["--kv-layout", "slot", "--kv-page-size", "8"])
+    assert not hasattr(cfg, "serve_kv_layout")
+    assert cfg.serve_kv_page_size == 8
+    assert ServeConfig.from_config(cfg).kv_layout == "paged"
 
 
 def test_page_geometry_validation(lm):
     with pytest.raises(ValueError, match="divisible"):
         ServeConfig(max_seqs=2, max_seq_len=30, kv_page_size=16)
-    with pytest.raises(ValueError, match="kv_layout"):
-        ServeConfig(kv_layout="ragged")
     # a pool too small to hold one max_len sequence is rejected
     with pytest.raises(ValueError, match="num_pages"):
         PagedKVCache.from_model(
@@ -341,17 +359,22 @@ def test_page_geometry_validation(lm):
 
 
 def test_default_geometry_matches_slot_capacity(lm):
-    """kv_page_size=0/kv_pages=0 derive a pool with exactly the slot
-    layout's capacity and byte footprint."""
+    """kv_page_size=0/kv_pages=0 derive a pool in which every slot can
+    reach max_seq_len: max_seqs * max_seq_len rows, whatever the page."""
     _, _, paged = build_scheduler(
         lm, ServeConfig(max_seqs=4, max_seq_len=32)
     )
-    _, _, slot = build_scheduler(
-        lm, ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="slot")
+    _, _, one = build_scheduler(
+        lm, ServeConfig(max_seqs=4, max_seq_len=32, kv_page_size=32)
     )
-    assert paged.spec.total_rows == slot.spec.total_rows == 4 * 32
-    assert paged.spec.total_bytes == slot.spec.total_bytes
+    assert paged.spec.total_rows == one.spec.total_rows == 4 * 32
+    assert paged.spec.total_bytes == one.spec.total_bytes
+    assert paged.spec.total_bytes == (
+        2 * 4 * 4 * 32 * paged.spec.num_heads * paged.spec.head_dim
+        * len(paged.spec.layer_guids)
+    )
     assert paged.spec.page_size == default_page_size(32)
+    assert (one.spec.num_pages, one.spec.max_pages_per_seq) == (4, 1)
 
 
 # -- spec byte accounting (the bytes_per_layer bugfix) ------------------------
@@ -378,9 +401,9 @@ def test_spec_total_rows_both_layouts():
         layer_guids=(1, 2), max_seqs=4, max_len=64, num_heads=4, head_dim=8,
         buckets=(64,),
     )
-    slot = KVCacheSpec(**base)
+    one = KVCacheSpec(**base, page_size=64, num_pages=4)
     paged = KVCacheSpec(**base, page_size=16, num_pages=10, itemsize=2)
-    assert slot.total_rows == 4 * 64
+    assert one.total_rows == 4 * 64 and one.max_pages_per_seq == 1
     assert paged.total_rows == 160
     assert paged.max_pages_per_seq == 4
     assert paged.bytes_per_layer == 2 * 2 * 160 * 4 * 8
@@ -431,8 +454,8 @@ def test_max_in_flight_estimate_prefers_paging():
     kw = dict(mean_prompt_len=16, mean_gen_len=16, max_len=1024)
     slot = estimate_max_in_flight(m.graph, budget, **kw)
     paged = estimate_max_in_flight(m.graph, budget, page_size=16, **kw)
-    # short requests (32 of 1024 positions): slot charges max_len rows,
-    # paged charges 2 pages of 16 -> 32x more sequences fit
+    # short requests (32 of 1024 positions): page_size 0 charges max_len
+    # rows, pages of 16 charge 2 -> 32x more sequences fit
     assert paged == 32 * slot
     # TP over heads halves per-chip row bytes -> twice the sequences
     assert estimate_max_in_flight(
@@ -465,20 +488,55 @@ def test_optimize_serving_reports_capacity():
 def test_engine_page_boundary_growth(lm):
     """A single long generation crosses several page boundaries: pages are
     claimed lazily (held pages grow during decode) and the output matches
-    the slot layout."""
+    the one of a single page that never grows."""
     outs = {}
-    held_trace = []
-    for layout in ("slot", "paged"):
-        sc = ServeConfig(max_seqs=1, max_seq_len=32, kv_layout=layout,
-                         kv_page_size=0 if layout == "slot" else 4)
+    held = {}
+    for layout, page in (("one_page", 32), ("paged", 4)):
+        sc = ServeConfig(max_seqs=1, max_seq_len=32, kv_page_size=page)
         sched, _, cache = build_scheduler(lm, sc)
         sched.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=20))
+        held[layout] = []
         while sched.queue or sched.running:
             sched.step()
-            if layout == "paged" and cache.num_active:
-                held_trace.append(int(cache._held[0]))
+            if cache.num_active:
+                held[layout].append(int(cache._held[0]))
         outs[layout] = sched.finished[0].generated
-    assert outs["paged"] == outs["slot"]
+    assert outs["paged"] == outs["one_page"] == ref_generate(lm, [1, 2, 3], 20)
     # 3-token prompt in pages of 4 starts with 1 page and grows lazily
-    assert held_trace[0] == 1
-    assert max(held_trace) > 1
+    assert held["paged"][0] == 1
+    assert max(held["paged"]) > 1
+    assert set(held["one_page"]) == {1}
+
+
+def test_one_page_never_grows(lm):
+    """One page a slot (kv_page_size = max_seq_len, default pool): after
+    every scheduler step the pages in use are the active slots, the
+    block table is one wide, and no decode, verify or chunk step claims
+    a page (`ensure_position` installs nothing after admission)."""
+    serve = ServeConfig(
+        max_seqs=4, max_seq_len=32, kv_page_size=32, spec_draft="ngram",
+        spec_k=3, debug_invariants=True,
+    )
+    sched, _, cache = build_scheduler(lm, serve)
+    assert cache.block_tables.shape == (4, 1)
+    assert cache.spec.num_pages == 4
+    installs = []
+    install = cache._install_page
+
+    def counting(slot, pi, page):
+        installs.append((sched.stats.iterations, slot, pi))
+        install(slot, pi, page)
+
+    cache._install_page = counting
+    for r in _requests([9, 3, 12, 5, 7, 2, 10]):
+        sched.submit(r)
+    while sched.queue or sched.running:
+        sched.step()
+        assert cache.pages_in_use == cache.num_active
+        assert cache._reserved == 0
+        # one install a request, at its admission, of its one page
+        assert all(pi == 0 for _, _, pi in installs)
+    assert len(sched.finished) == 7 and all(r.ok for r in sched.finished)
+    assert len(installs) == 7
+    assert sched.stats.verify_steps > 0
+    assert cache.pages_in_use == 0

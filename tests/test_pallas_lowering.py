@@ -28,6 +28,7 @@ from jax.sharding import Mesh, PartitionSpec as P, SingleDeviceSharding
 from flexflow_tpu.ops.pallas import decode_kernel as dk
 from flexflow_tpu.ops.pallas import flash_kernel as fk
 from flexflow_tpu.ops.pallas.ring_attention import ring_attention
+from tests.conftest import page_geometry
 
 # name: (batch, heads, head_dim, max_len, page sizes, query widths)
 GEOMETRIES = {
@@ -341,7 +342,8 @@ def _step_program(lm, programs, kind, layout, dtype="fp32"):
     sched, eng, cache = build_scheduler(
         lm,
         ServeConfig(
-            max_seqs=2, max_seq_len=32, kv_layout=layout, kv_dtype=dtype,
+            max_seqs=2, max_seq_len=32, **page_geometry(layout, 32),
+            kv_dtype=dtype,
             decode_kernel="pallas", decode_multistep=(kind == "multistep"),
         ),
     )
@@ -353,7 +355,7 @@ def _step_program(lm, programs, kind, layout, dtype="fp32"):
     return jitted.trace(*shapes).lower(lowering_platforms=("tpu",)), cache
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 @pytest.mark.parametrize("kind", KINDS)
 def test_step_program_donates_exactly_its_pools(
     lm, step_programs, kind, layout
@@ -404,7 +406,7 @@ def test_compiled_step_program_aliases_its_pools(lm, step_programs, kind):
 
     sched, eng, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=2, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=2, max_seq_len=32,
                     decode_kernel="pallas"),
     )
     slot = cache.alloc(len(PROMPT), len(PROMPT) + 8)

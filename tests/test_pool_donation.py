@@ -1,6 +1,6 @@
 """The step programs own the KV pools they rewrite (serving/engine.py
-`_step_jit`): every engine program donates `ck`, `cv` and, in the paged
-programs, the scale pools, so XLA scatters into the pool it was handed
+`_step_jit`): every engine program donates `ck`, `cv` and the scale
+pools, so XLA scatters into the pool it was handed
 instead of copying it whole into a fresh output first.
 
 What that asks of the host, held here on the CPU backend (which honours
@@ -35,10 +35,11 @@ VOCAB = 50
 PROMPT = [3, 1, 4, 1, 5]
 SLOTS = 2
 KINDS = ("prefill", "decode", "multistep", "verify", "tree", "chunk")
+# (ServeConfig keywords of the cache geometry, kv_dtype)
 LAYOUTS = [
-    pytest.param(("slot", "fp32"), id="slot"),
-    pytest.param(("paged", "fp32"), id="paged"),
-    pytest.param(("paged", "int8"), id="paged-int8"),
+    pytest.param(({"kv_page_size": 32}, "fp32"), id="one_page"),
+    pytest.param(({}, "fp32"), id="paged"),
+    pytest.param(({}, "int8"), id="paged-int8"),
 ]
 
 
@@ -63,24 +64,20 @@ def lm():
 def _pool_leaves(cache):
     """Every pool array the cache holds right now, by name."""
     groups = {"k": cache.k, "v": cache.v}
-    if getattr(cache, "quantized", False):
+    if cache.quantized:
         groups.update(k_scale=cache.k_scale, v_scale=cache.v_scale)
     return {(n, g): a for n, d in groups.items() for g, a in d.items()}
 
 
 def _rows_written(cache, slot, positions):
-    """Boolean map over a K/V pool's first two dims ([slots, max_len] or
-    [pages, page_size]) of the cache rows `positions` of `slot`."""
+    """Boolean map over a K/V pool's first two dims ([pages, page_size])
+    of the cache rows `positions` of `slot`."""
     spec = cache.spec
-    if getattr(cache, "paged", False):
-        rows = np.zeros((spec.num_pages, spec.page_size), dtype=bool)
-        for p in positions:
-            page = int(cache.block_tables[slot, p // spec.page_size])
-            assert page < spec.num_pages, f"position {p} has no page"
-            rows[page, p % spec.page_size] = True
-    else:
-        rows = np.zeros((spec.max_seqs, spec.max_len), dtype=bool)
-        rows[slot, list(positions)] = True
+    rows = np.zeros((spec.num_pages, spec.page_size), dtype=bool)
+    for p in positions:
+        page = int(cache.block_tables[slot, p // spec.page_size])
+        assert page < spec.num_pages, f"position {p} has no page"
+        rows[page, p % spec.page_size] = True
     return rows
 
 
@@ -119,11 +116,11 @@ def _step(kind, eng, cache, params, slot, nxt):
 @pytest.mark.parametrize("layout", LAYOUTS)
 @pytest.mark.parametrize("kind", KINDS)
 def test_step_consumes_its_pools_and_commits_the_rows(lm, kind, layout):
-    kv_layout, kv_dtype = layout
+    geometry, kv_dtype = layout
     sched, eng, cache = build_scheduler(
         lm,
         ServeConfig(
-            max_seqs=SLOTS, max_seq_len=32, kv_layout=kv_layout,
+            max_seqs=SLOTS, max_seq_len=32, **geometry,
             kv_dtype=kv_dtype, decode_multistep=(kind == "multistep"),
         ),
     )
@@ -159,19 +156,15 @@ def test_step_consumes_its_pools_and_commits_the_rows(lm, kind, layout):
     # the bucket's masked pad rows inside them)
     wrote = _rows_written(cache, slot, positions)
     own = np.zeros_like(wrote)
-    if cache.paged:
-        table = cache.block_tables[slot]
-        own[table[table < cache.spec.num_pages]] = True
-    else:
-        own[slot] = True
+    table = cache.block_tables[slot]
+    own[table[table < cache.spec.num_pages]] = True
     assert not (wrote & ~own).any()
     for (name, g), arr in live.items():
         new, old = np.asarray(arr), before[(name, g)]
         assert new.shape == old.shape and new.dtype == old.dtype
         if name in ("k", "v"):
-            # [slots, max_len, heads, head_dim], or a paged pool's
             # [pages, page_size, heads * head_dim]
-            changed = np.any(new != old, axis=tuple(range(2, new.ndim)))
+            changed = np.any(new != old, axis=2)
             assert changed[wrote].all(), (name, g)
             assert not changed[~own].any(), (name, g)
         else:  # int8 scale pools [pages, heads]: claimed for written pages
@@ -184,8 +177,7 @@ def test_step_consumes_its_pools_and_commits_the_rows(lm, kind, layout):
 def test_counters_reach_scheduler_stats_and_gauges(lm, kernel):
     sched, eng, _ = build_scheduler(
         lm,
-        ServeConfig(max_seqs=SLOTS, max_seq_len=32, kv_layout="paged",
-                    decode_kernel=kernel),
+        ServeConfig(max_seqs=SLOTS, max_seq_len=32, decode_kernel=kernel),
         telemetry=Telemetry(),
     )
     done = sched.run(
@@ -206,14 +198,11 @@ def test_counters_reach_scheduler_stats_and_gauges(lm, kernel):
 @pytest.mark.parametrize(
     "serve_kw",
     [
-        pytest.param({"kv_layout": "slot"}, id="slot"),
-        pytest.param({"kv_layout": "paged", "serve_async": True},
-                     id="paged-async"),
-        pytest.param({"kv_layout": "paged", "spec_draft": "ngram",
-                      "spec_k": 3}, id="paged-spec"),
-        pytest.param({"kv_layout": "paged", "token_budget": 10,
-                      "chunk_size": 4, "decode_kernel": "dense"},
-                     id="paged-chunked"),
+        pytest.param({"kv_page_size": 32}, id="one_page"),
+        pytest.param({"serve_async": True}, id="paged-async"),
+        pytest.param({"spec_draft": "ngram", "spec_k": 3}, id="paged-spec"),
+        pytest.param({"token_budget": 10, "chunk_size": 4,
+                      "decode_kernel": "dense"}, id="paged-chunked"),
     ],
 )
 def test_parameters_and_adapter_pools_are_never_consumed(lm, serve_kw):

@@ -384,17 +384,46 @@ def test_int8_pool_dtype_and_scales(lm):
     assert f32.k_scale == {} and f32.v_scale == {}
 
 
+def test_prefix_sharing_doubles_concurrency_at_equal_bytes(lm):
+    """The count the prefix CI gate held: on a stream whose requests
+    share a prompt prefix of whole pages, at the SAME pool (equal HBM
+    bytes) and optimistic admission, prefix sharing runs at least twice
+    the concurrent requests of the plain paged cache: a sharer is
+    charged only its fresh pages."""
+    page, pages = 4, 16
+    pref = [(j * 11 + 3) % (VOCAB - 1) + 1 for j in range(4 * page)]
+
+    def requests(n):
+        # the first request keeps the prefix pages live while the rest churn
+        return [
+            Request(rid=i, prompt=pref + [(i * 13 + j) % (VOCAB - 1) + 1
+                                          for j in range(1 + i % 3)],
+                    max_new_tokens=12 if i == 0 else 3)
+            for i in range(n)
+        ]
+
+    peak = {}
+    for prefix in (False, True):
+        sched, _, _ = build_scheduler(lm, ServeConfig(
+            max_seqs=pages, max_seq_len=32, kv_page_size=page,
+            kv_pages=pages, prefix_cache=prefix, admission="optimistic",
+            decode_kernel="dense", debug_invariants=True,
+        ))
+        done = sched.run(requests(2 * pages))
+        assert len(done) == 2 * pages
+        assert all(r.status == "finished" for r in done)
+        peak[prefix] = sched.stats.peak_in_flight
+        assert (sched.stats.prefix_hits > 0) == prefix
+    assert peak[True] >= 2 * peak[False]
+
+
 # -- config + flags -----------------------------------------------------------
 
 
 def test_flag_validation():
     with pytest.raises(ValueError, match="kv_dtype"):
         ServeConfig(kv_dtype="fp16")
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(kv_layout="slot", kv_dtype="int8")
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(kv_layout="slot", prefix_cache=True)
-    ServeConfig(kv_dtype="int8", prefix_cache=True)  # paged default: fine
+    ServeConfig(kv_dtype="int8", prefix_cache=True)
 
 
 def test_cli_flags_map_to_serve_config():

@@ -180,7 +180,6 @@ def _run_matrix(lm, *, pressured, serve_async=False, mode="plain",
     serve = ServeConfig(
         max_seqs=4,
         max_seq_len=32,
-        kv_layout="paged",
         kv_page_size=4,
         kv_pages=24 if not pressured else 12,
         admission="optimistic" if pressured else "reserve",
@@ -284,7 +283,7 @@ def test_swap_fail_degrades_to_recompute_never_loses(lm):
     request."""
     ref = _run_matrix(lm, pressured=False)
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout="paged", kv_page_size=4,
+        max_seqs=4, max_seq_len=32, kv_page_size=4,
         kv_pages=12, admission="optimistic", max_preemptions=32,
         kv_swap=True, decode_kernel="dense", debug_invariants=True,
     )
@@ -391,7 +390,7 @@ def test_eviction_never_takes_live_shared_pages():
 def test_prefix_evict_requires_prefix_cache():
     with pytest.raises(ValueError, match="prefix_evict"):
         ServeConfig(
-            max_seqs=2, max_seq_len=32, kv_layout="paged",
+            max_seqs=2, max_seq_len=32,
             prefix_evict="lru",
         )
 
@@ -409,7 +408,7 @@ def test_host_down_drains_and_completes_on_survivor(lm):
     stream completes on the survivor — token-identical to a calm run."""
     ref = _run_matrix(lm, pressured=False)
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout="paged", kv_page_size=4,
+        max_seqs=4, max_seq_len=32, kv_page_size=4,
         kv_pages=24, serve_hosts=2, admission="optimistic",
         max_preemptions=32, kv_swap=True, decode_kernel="dense",
         telemetry=True, debug_invariants=True,
@@ -446,7 +445,7 @@ def test_host_down_drain_is_replayable(lm):
     (the injector's counter-mode RNG keys by (seed, iteration, site))."""
     def run_once():
         serve = ServeConfig(
-            max_seqs=4, max_seq_len=32, kv_layout="paged", kv_page_size=4,
+            max_seqs=4, max_seq_len=32, kv_page_size=4,
             kv_pages=24, serve_hosts=2, admission="optimistic",
             max_preemptions=32, decode_kernel="dense",
         )
@@ -472,7 +471,7 @@ def test_hard_fail_after_max_preemptions_carries_cause(lm):
     triggering cause in Request.error — post-mortems read the error,
     not the scheduler source."""
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout="paged", kv_page_size=4,
+        max_seqs=4, max_seq_len=32, kv_page_size=4,
         kv_pages=12, admission="optimistic", max_preemptions=0,
         decode_kernel="dense",
     )

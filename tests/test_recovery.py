@@ -174,8 +174,6 @@ def test_fsync_mode_validation(tmp_path):
         ServeConfig(journal_fsync="always")
     with pytest.raises(ValueError, match="journal_snapshot_every"):
         ServeConfig(journal_snapshot_every=-1)
-    with pytest.raises(ValueError, match="paged"):
-        ServeConfig(journal_snapshot_every=2, kv_layout="slot")
 
 
 @pytest.mark.parametrize("mode", FSYNC_MODES)
@@ -205,7 +203,7 @@ def test_fsync_modes_all_durable_after_graceful_run(lm, tmp_path, mode):
 @pytest.mark.parametrize(
     "layout,dtype,prefix",
     [
-        ("slot", "fp32", False),
+        ("one_page", "fp32", False),
         ("paged", "fp32", False),
         ("paged", "fp32", True),
         ("paged", "int8", False),
@@ -216,11 +214,9 @@ def test_crash_restart_token_identical(lm, tmp_path, layout, dtype, prefix):
     """The headline contract: crash at the WORST phase (tokens emitted,
     commit flush not yet run), restart, and every stream resumes
     token-identically — no duplicated tokens, no gaps, nothing lost."""
-    over = dict(kv_layout=layout, kv_dtype=dtype, prefix_cache=prefix)
-    if layout == "paged":
-        over["kv_page_size"] = 8
-    base = _baseline(lm, layout=layout, max_new=8,
-                     **{k: v for k, v in over.items() if k != "kv_layout"})
+    over = dict(kv_dtype=dtype, prefix_cache=prefix,
+                kv_page_size=8 if layout == "paged" else 32)
+    base = _baseline(lm, max_new=8, **over)
     path = tmp_path / "serve.wal"
     sched = _crash_run(
         lm, path, FaultPlan(crash_iters={3: "commit"}), max_new=8, **over)
@@ -244,8 +240,8 @@ def test_crash_restart_token_identical(lm, tmp_path, layout, dtype, prefix):
 def test_crash_at_iteration_begin(lm, tmp_path):
     """The benign phase: death at the step boundary, before any new
     work — everything journaled survives, nothing was at risk."""
-    over = dict(kv_layout="paged", kv_page_size=8)
-    base = _baseline(lm, layout="paged", max_new=8, kv_page_size=8)
+    over = dict(kv_page_size=8)
+    base = _baseline(lm, max_new=8, kv_page_size=8)
     path = tmp_path / "begin.wal"
     _crash_run(lm, path, FaultPlan(crash_iters={2: "begin"}),
                max_new=8, **over)
@@ -260,8 +256,8 @@ def test_crash_at_iteration_begin(lm, tmp_path):
 def test_crash_after_torn_append_still_recovers(lm, tmp_path):
     """Crash + torn tail together: the torn record is dropped, every
     intact record folds, and the resume is still exact."""
-    over = dict(kv_layout="paged", kv_page_size=8)
-    base = _baseline(lm, layout="paged", max_new=8, kv_page_size=8)
+    over = dict(kv_page_size=8)
+    base = _baseline(lm, max_new=8, kv_page_size=8)
     path = tmp_path / "both.wal"
     _crash_run(lm, path, FaultPlan(crash_iters={4: "commit"}),
                max_new=8, **over)
@@ -278,9 +274,9 @@ def test_crash_mid_fused_window_recovers_token_identical(lm, tmp_path):
     unjournaled at the commit-phase crash; the restart recomputes it
     from the last durable cursor. Commit records land at the window
     grain — one record per request per host sync, K tokens long."""
-    over = dict(kv_layout="paged", kv_page_size=8,
+    over = dict(kv_page_size=8,
                 decode_multistep=True, max_fused_steps=4)
-    base = _baseline(lm, layout="paged", max_new=12, kv_page_size=8,
+    base = _baseline(lm, max_new=12, kv_page_size=8,
                      decode_multistep=True, max_fused_steps=4)
     path = tmp_path / "fused.wal"
     sched = _crash_run(
@@ -300,9 +296,9 @@ def test_crash_mid_tree_verify_recovers_token_identical(lm, tmp_path):
     """Same contract through the token-tree path: a verify round's
     accepted run journals as one commit record, and a crash between
     emit and commit flush recomputes it exactly."""
-    over = dict(kv_layout="paged", kv_page_size=8,
+    over = dict(kv_page_size=8,
                 spec_draft="ngram", spec_k=3, spec_branch=2)
-    base = _baseline(lm, layout="paged", max_new=12, kv_page_size=8,
+    base = _baseline(lm, max_new=12, kv_page_size=8,
                      spec_draft="ngram", spec_k=3, spec_branch=2)
     path = tmp_path / "tree.wal"
     sched = _crash_run(
@@ -318,8 +314,8 @@ def test_double_crash_recovers_exactly(lm, tmp_path):
     """Re-admitted requests journal fresh submit records CARRYING their
     committed run, so a second crash folds to the full cursor instead
     of resetting it — the recovery is idempotent under repetition."""
-    over = dict(kv_layout="paged", kv_page_size=8)
-    base = _baseline(lm, layout="paged", max_new=8, kv_page_size=8)
+    over = dict(kv_page_size=8)
+    base = _baseline(lm, max_new=8, kv_page_size=8)
     path = tmp_path / "twice.wal"
     _crash_run(lm, path, FaultPlan(crash_iters={3: "commit"}),
                max_new=8, **over)
@@ -349,14 +345,14 @@ def test_journal_write_failure_degrades_not_kills(lm, tmp_path):
     path = tmp_path / "fail.wal"
     inj = FaultInjector(FaultPlan(journal_fail_iters=(2,)))
     sched, _, _ = build_scheduler(
-        lm, _cfg(path, kv_layout="paged", kv_page_size=8), injector=inj)
+        lm, _cfg(path, kv_page_size=8), injector=inj)
     for r in _requests(max_new=6):
         sched.submit(r)
     done = sched.run()
     assert inj.injected["journal_fail"] == 1
     assert sched.journal.degraded
     assert "injected" in sched.journal.degraded_reason
-    base = _baseline(lm, layout="paged", max_new=6, kv_page_size=8)
+    base = _baseline(lm, max_new=6, kv_page_size=8)
     assert {r.rid: r.generated for r in done} == base
     assert all(r.status == RequestStatus.FINISHED for r in done)
     # what made it to disk before the failure still parses cleanly
@@ -373,9 +369,9 @@ def test_snapshot_restore_vs_recompute(lm, tmp_path, decider_mode):
     restores one over the swap-in path when the decider approves
     (None = always), and falls back to recompute when it refuses —
     token-identical either way."""
-    over = dict(kv_layout="paged", kv_page_size=8,
+    over = dict(kv_page_size=8,
                 journal_snapshot_every=2)
-    base = _baseline(lm, layout="paged", max_new=8, kv_page_size=8)
+    base = _baseline(lm, max_new=8, kv_page_size=8)
     path = tmp_path / f"snap-{decider_mode}.wal"
     _crash_run(lm, path, FaultPlan(crash_iters={5: "commit"}),
                max_new=8, **over)
@@ -414,8 +410,8 @@ def test_front_door_adopts_recovery_state(lm, tmp_path):
     """A fresh FrontDoor built with the RecoveryState replays every
     committed token and resumes the live set — the client-visible
     stream across the crash is exactly the fault-free one."""
-    over = dict(kv_layout="paged", kv_page_size=8)
-    base = _baseline(lm, layout="paged", max_new=8, kv_page_size=8)
+    over = dict(kv_page_size=8)
+    base = _baseline(lm, max_new=8, kv_page_size=8)
     path = tmp_path / "door.wal"
     _crash_run(lm, path, FaultPlan(crash_iters={3: "commit"}),
                max_new=8, **over)
@@ -456,7 +452,7 @@ def test_front_door_request_key_dedup_and_replay(lm):
 
     async def main():
         sched, _, _ = build_scheduler(
-            lm, _cfg(kv_layout="paged", kv_page_size=8))
+            lm, _cfg(kv_page_size=8))
         door = FrontDoor(sched)
         rid = await door.submit([1, 2, 3], max_new_tokens=6,
                                 request_key="alpha")
@@ -489,7 +485,7 @@ def test_front_door_request_key_dedup_and_replay(lm):
 def test_request_key_dedup_survives_restart(lm, tmp_path):
     """A retried submit whose key the JOURNAL remembers as finished
     replays the recorded verdict without touching the fresh engine."""
-    over = dict(kv_layout="paged", kv_page_size=8)
+    over = dict(kv_page_size=8)
     path = tmp_path / "dedup.wal"
     sched, _, _ = build_scheduler(lm, _cfg(path, **over))
     reqs = [
@@ -530,7 +526,7 @@ def test_front_door_sheds_by_class_share(lm, tmp_path):
     admitting — overload degrades in priority order, and the shed
     request never reaches the engine or the journal."""
     path = tmp_path / "shed.wal"
-    serve = _cfg(path, kv_layout="paged", kv_page_size=8,
+    serve = _cfg(path, kv_page_size=8,
                  classes="gold:4,bronze:1",
                  metrics_out=str(tmp_path / "m.prom"))
 
@@ -586,7 +582,7 @@ def test_circuit_breaker_state_machine(lm, tmp_path):
     probes (placements excluded), open -> half_open after the cooldown,
     a failed half-open trial reopens immediately, a healthy one
     closes."""
-    serve = _cfg(kv_layout="paged", kv_page_size=8,
+    serve = _cfg(kv_page_size=8,
                  breaker_threshold=2, breaker_cooldown=3,
                  metrics_out=str(tmp_path / "m.prom"))
     flaky = {"healthy": False}
@@ -624,7 +620,7 @@ def test_circuit_breaker_state_machine(lm, tmp_path):
 def test_breaker_never_manufactures_outage(lm):
     """With every alive replica open, the alive set routes anyway —
     availability over protection."""
-    serve = _cfg(kv_layout="paged", kv_page_size=8, breaker_threshold=1)
+    serve = _cfg(kv_page_size=8, breaker_threshold=1)
     router = ReplicaRouter([lm], serve, health_probe=lambda rep: False)
     router.step()
     assert router.replicas[0].breaker_state == "open"
@@ -639,7 +635,7 @@ def test_cancel_during_evacuation_window(lm):
     """The satellite regression: a cancel racing `kill_replica` while
     its request sits between schedulers must LAND (finalized CANCELLED
     at the router), not silently fall into the ownership gap."""
-    serve = _cfg(kv_layout="paged", kv_page_size=8)
+    serve = _cfg(kv_page_size=8)
     router = ReplicaRouter([lm, lm], serve)
     for r in _requests(n=4, max_new=8):
         router.submit(r)
@@ -669,7 +665,7 @@ def test_cancel_during_evacuation_window(lm):
     done = {r.rid: r for r in router.run()}
     assert set(done) == {0, 1, 2, 3}  # zero lost requests
     assert done[victim].status == RequestStatus.CANCELLED
-    base = _baseline(lm, layout="paged", max_new=8, kv_page_size=8)
+    base = _baseline(lm, max_new=8, kv_page_size=8)
     for rid, req in done.items():
         if rid != victim:
             assert req.status == RequestStatus.FINISHED

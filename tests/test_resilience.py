@@ -37,6 +37,7 @@ from flexflow_tpu.serving import (
     build_scheduler,
     latency_percentiles,
 )
+from tests.conftest import page_geometry
 
 pytestmark = pytest.mark.serving
 
@@ -76,13 +77,13 @@ def _requests(n=4, max_new=6, **kw):
     ]
 
 
-def _baseline(lm, layout="slot", max_new=6, n=4, **cfg_kw):
+def _baseline(lm, layout="paged", max_new=6, n=4, **cfg_kw):
     """Fault-free greedy streams, keyed by rid."""
     out = lm.generate(
         [list(_PROMPTS[i % len(_PROMPTS)]) for i in range(n)],
         max_new_tokens=max_new,
         serve_config=ServeConfig(max_seqs=4, max_seq_len=32,
-                                 kv_layout=layout, **cfg_kw),
+                                 **page_geometry(layout, 32), **cfg_kw),
     )
     return {i: out[i] for i in range(n)}
 
@@ -90,7 +91,7 @@ def _baseline(lm, layout="slot", max_new=6, n=4, **cfg_kw):
 def _drain(sched, cache=None, injector=None):
     while sched.queue or sched.running:
         sched.step()
-        if cache is not None and getattr(cache, "paged", False):
+        if cache is not None:
             _check_allocator_invariants(cache, injector=injector)
     return sched.finished
 
@@ -253,15 +254,16 @@ def test_running_deadline_retires_mid_flight(lm):
 # -- fault isolation: NaN logits ----------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_nan_fault_retires_only_its_slot(lm, layout):
     """Injected NaN logits on one slot: that request FAILs with the
     captured error; every other request's greedy stream is
-    token-identical to a fault-free run — on both kv layouts."""
+    token-identical to a fault-free run — at both page geometries."""
     base = _baseline(lm, layout=layout)
     inj = FaultInjector(FaultPlan(nan_iters={3: [1]}))
     sched, _, cache = build_scheduler(
-        lm, ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout),
+        lm,
+        ServeConfig(max_seqs=4, max_seq_len=32, **page_geometry(layout, 32)),
         injector=inj,
     )
     done = sched.run(_requests())
@@ -298,7 +300,7 @@ def test_nan_fault_at_prefill_fails_before_first_token(lm):
     )
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_nan_fault_in_verify_mode(lm, layout):
     """The finite guard covers the speculative verify path too: a NaN
     slot FAILs, unaffected slots' spec streams still equal the plain
@@ -307,7 +309,7 @@ def test_nan_fault_in_verify_mode(lm, layout):
     inj = FaultInjector(FaultPlan(nan_iters={2: [2]}))
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
+        ServeConfig(max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
                     spec_draft="ngram", spec_k=3),
         injector=inj,
     )
@@ -323,7 +325,7 @@ def test_nan_fault_in_verify_mode(lm, layout):
 # -- fault isolation: kernel failure ------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_kernel_fault_falls_back_to_dense_and_keeps_serving(lm, layout):
     """An injected Pallas-kernel dispatch failure permanently falls the
     engine back to the dense paths — no request is lost, and every
@@ -332,7 +334,7 @@ def test_kernel_fault_falls_back_to_dense_and_keeps_serving(lm, layout):
     inj = FaultInjector(FaultPlan(kernel_iters=(2,)))
     sched, engine, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
+        ServeConfig(max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
                     decode_kernel="pallas"),
         injector=inj,
     )
@@ -353,7 +355,7 @@ def test_kernel_fault_falls_back_to_dense_and_keeps_serving(lm, layout):
     )
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_kernel_that_cannot_compile_raises_instead_of_falling_back(
     lm, layout, monkeypatch
 ):
@@ -370,10 +372,9 @@ def test_kernel_that_cannot_compile_raises_instead_of_falling_back(
         raise NotImplementedError("Mosaic failed to compile TPU kernel")
 
     monkeypatch.setattr(dk, "_paged_call", refuse)
-    monkeypatch.setattr(dk, "_contiguous_call", refuse)
     sched, engine, _ = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
+        ServeConfig(max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
                     decode_kernel="pallas"),
     )
     with pytest.raises(KernelCompileError, match="Mosaic failed to compile"):
@@ -386,10 +387,10 @@ def test_runtime_kernel_failure_after_a_good_step_still_falls_back(lm):
     """The other half of the contract: once a program has run, a failure
     of it IS a run-time fault, answered by the permanent dense fallback
     exactly like an injected one."""
-    base = _baseline(lm, layout="paged", decode_kernel="dense")
+    base = _baseline(lm, decode_kernel="dense")
     sched, engine, _ = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     decode_kernel="pallas"),
     )
     good = engine._decode_jit
@@ -416,7 +417,7 @@ def test_runtime_kernel_failure_after_a_good_step_still_falls_back(lm):
     )
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_failure_after_the_program_consumed_its_pools_is_pools_lost(
     lm, layout
 ):
@@ -430,7 +431,7 @@ def test_failure_after_the_program_consumed_its_pools_is_pools_lost(
 
     sched, engine, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
+        ServeConfig(max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
                     decode_kernel="pallas"),
     )
     good = engine._decode_jit
@@ -512,7 +513,7 @@ def test_optimistic_admission_beats_reserve_concurrency(lm):
     for admission in ("reserve", "optimistic"):
         sched, _, cache = build_scheduler(
             lm,
-            ServeConfig(max_seqs=8, max_seq_len=32, kv_layout="paged",
+            ServeConfig(max_seqs=8, max_seq_len=32,
                         kv_page_size=4, kv_pages=16, admission=admission,
                         max_preemptions=8),
         )
@@ -537,7 +538,7 @@ def test_preemption_recompute_completes_all_requests(lm):
     every iteration, and the preempt events on the victims' logs."""
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=8, kv_pages=8, admission="optimistic",
                     max_preemptions=6),
     )
@@ -562,7 +563,7 @@ def test_preemption_picks_youngest_victim(lm):
     first, is never the one preempted."""
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=8, kv_pages=8, admission="optimistic",
                     max_preemptions=6),
     )
@@ -579,7 +580,7 @@ def test_preemption_bound_hard_fails(lm):
     lost."""
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=8, kv_pages=8, admission="optimistic",
                     max_preemptions=0),
     )
@@ -609,7 +610,7 @@ def test_page_steal_under_reserve_fails_only_the_claiming_slot(lm):
     )
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=4),
         injector=inj,
     )
@@ -631,10 +632,11 @@ def test_page_steal_under_reserve_fails_only_the_claiming_slot(lm):
 # -- the combined seeded chaos proof ------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_chaos_schedule_isolates_faults_both_layouts(lm, layout):
     """The acceptance criterion: a seeded schedule combining a NaN slot,
-    a kernel failure, and (paged) pool exhaustion. Every submitted rid
+    a kernel failure, and stolen pages (with small pages and optimistic
+    admission: pool exhaustion). Every submitted rid
     reaches a terminal status, and every request the faults did not
     touch streams token-identical to the fault-free run."""
     base = _baseline(lm, layout=layout, max_new=8, n=4,
@@ -649,8 +651,8 @@ def test_chaos_schedule_isolates_faults_both_layouts(lm, layout):
     inj = FaultInjector(plan, seed=0)
     sched, engine, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout=layout,
-                    kv_page_size=8 if layout == "paged" else 0,
+        ServeConfig(max_seqs=4, max_seq_len=32,
+                    kv_page_size=8 if layout == "paged" else 32,
                     admission="optimistic" if layout == "paged" else
                     "reserve",
                     decode_kernel="pallas"),
@@ -660,8 +662,7 @@ def test_chaos_schedule_isolates_faults_both_layouts(lm, layout):
         sched.submit(r)
     while sched.queue or sched.running:
         sched.step()
-        if layout == "paged":
-            _check_allocator_invariants(cache, injector=inj)
+        _check_allocator_invariants(cache, injector=inj)
     done = sched.finished
     # nothing lost: every rid terminal, accounting adds up
     assert {r.rid for r in done} == {0, 1, 2, 3}
@@ -676,14 +677,13 @@ def test_chaos_schedule_isolates_faults_both_layouts(lm, layout):
     assert untouched
     for r in untouched:
         assert r.generated == base[r.rid]
-    if layout == "paged":
-        inj.release_stolen_pages(cache)
-        _check_allocator_invariants(cache)
-        assert cache.pages_in_use == 0
+    inj.release_stolen_pages(cache)
+    _check_allocator_invariants(cache)
+    assert cache.pages_in_use == 0
 
 
 def test_chaos_rates_never_lose_requests(lm):
-    """Rate-driven chaos (the bench_serve --chaos shape): whatever the
+    """Rate-driven chaos: whatever the
     dice do, every request terminates and the allocator stays
     consistent."""
     plan = FaultPlan(nan_rate=0.02, cancel_rate=0.02,
@@ -691,7 +691,7 @@ def test_chaos_rates_never_lose_requests(lm):
     inj = FaultInjector(plan, seed=7)
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=8, kv_pages=10, admission="optimistic",
                     max_preemptions=6),
         injector=inj,
@@ -789,12 +789,11 @@ def _chaos_run(lm, plan, seed=0, n=4, max_new=10, reqs=None, **cfg_kw):
         sched.submit(r, strict=False)
     while sched.queue or sched.running:
         sched.step()
-        if getattr(cache, "paged", False):
-            _check_allocator_invariants(cache, injector=inj)
+        _check_allocator_invariants(cache, injector=inj)
     return inj, sched, engine, cache, {r.rid: r for r in sched.finished}
 
 
-_MULTISTEP_CFG = dict(kv_layout="paged", kv_page_size=8,
+_MULTISTEP_CFG = dict(kv_page_size=8,
                       decode_multistep=True, max_fused_steps=4)
 
 
@@ -805,7 +804,7 @@ def test_chaos_site_inside_multistep_window(lm, site):
     regime covers: exactly the planned victim is touched, every other
     stream is token-identical to the fault-free run, and windows
     actually fused around the fault."""
-    base = _baseline(lm, layout="paged", max_new=10,
+    base = _baseline(lm, max_new=10,
                      decode_kernel="dense")
     plan = {
         "spike": FaultPlan(spike_rate=1.0, spike_s=0.0005),
@@ -853,7 +852,7 @@ def test_chaos_site_inside_multistep_window(lm, site):
     _check_allocator_invariants(cache)
 
 
-_TREE_CFG = dict(kv_layout="paged", kv_page_size=8, spec_draft="ngram",
+_TREE_CFG = dict(kv_page_size=8, spec_draft="ngram",
                  spec_k=3, spec_branch=2)
 
 
@@ -866,7 +865,7 @@ def test_chaos_site_inside_tree_verify_round(lm, site):
     kernel — and every unaffected stream still equals the fault-free
     greedy run (tree speculation is exact, so the baseline is the
     plain stream)."""
-    base = _baseline(lm, layout="paged", max_new=10,
+    base = _baseline(lm, max_new=10,
                      decode_kernel="dense")
     plan = {
         "spike": FaultPlan(spike_rate=1.0, spike_s=0.0005),
@@ -918,7 +917,7 @@ def test_swap_fail_inside_tree_verify_round(lm):
     inj = FaultInjector(plan, seed=0)
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=4, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=4, max_seq_len=32,
                     kv_page_size=8, kv_pages=8, admission="optimistic",
                     max_preemptions=8, kv_swap=True,
                     spec_draft="ngram", spec_k=3, spec_branch=2),

@@ -52,7 +52,6 @@ def _serve(**over):
     base = dict(
         max_seqs=4,
         max_seq_len=32,
-        kv_layout="paged",
         kv_page_size=4,
         kv_pages=48,
         token_budget=8,
@@ -521,7 +520,6 @@ def test_prefix_evict_cost_requires_prefix_cache():
         ServeConfig(
             max_seqs=2,
             max_seq_len=32,
-            kv_layout="paged",
             prefix_evict="cost",
         )
 

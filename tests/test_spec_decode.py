@@ -25,7 +25,6 @@ from flexflow_tpu import (
 from flexflow_tpu.models import build_decoder_lm
 from flexflow_tpu.serving import (
     ContinuousBatchingScheduler,
-    KVCache,
     NGramDraftProposer,
     ModelDraftProposer,
     PagedKVCache,
@@ -35,6 +34,7 @@ from flexflow_tpu.serving import (
     build_scheduler,
     latency_percentiles,
 )
+from tests.conftest import page_geometry
 
 pytestmark = pytest.mark.serving
 
@@ -75,22 +75,24 @@ PROMPTS = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 3, 1, 2], [7], [11, 12]]
 # -- greedy equivalence (the core contract) -----------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 @pytest.mark.parametrize("draft", ["ngram", "model"])
 def test_greedy_spec_equals_plain(lm, draft_lm, layout, draft):
     """Greedy speculative decode (either proposer) produces EXACTLY the
-    plain greedy stream on both kv layouts — the draft changes when
+    plain greedy stream at both page geometries — the draft changes when
     tokens arrive, never which."""
     plain = lm.generate(
         PROMPTS,
         max_new_tokens=8,
-        serve_config=ServeConfig(max_seqs=2, max_seq_len=32, kv_layout=layout),
+        serve_config=ServeConfig(
+            max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)
+        ),
     )
     spec = lm.generate(
         PROMPTS,
         max_new_tokens=8,
         serve_config=ServeConfig(
-            max_seqs=2, max_seq_len=32, kv_layout=layout,
+            max_seqs=2, max_seq_len=32, **page_geometry(layout, 32),
             spec_draft=draft, spec_k=4,
         ),
         draft_model=draft_lm if draft == "model" else None,
@@ -98,7 +100,7 @@ def test_greedy_spec_equals_plain(lm, draft_lm, layout, draft):
     assert spec == plain
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_verify_logits_match_sequential_decode(lm, layout):
     """The verify step's w-position logits agree NUMERICALLY with w
     sequential decode steps feeding the same tokens — the staircase mask
@@ -107,7 +109,8 @@ def test_verify_logits_match_sequential_decode(lm, layout):
     prompt = [3, 1, 4, 1, 5]
     # engine A: sequential decodes
     _, eng_a, cache_a = build_scheduler(
-        lm, ServeConfig(max_seqs=2, max_seq_len=32, kv_layout=layout)
+        lm,
+        ServeConfig(max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)),
     )
     slot = cache_a.alloc(len(prompt), len(prompt) + 6)
     nxt, _ = eng_a.prefill(lm.params, [prompt], [slot])
@@ -123,7 +126,8 @@ def test_verify_logits_match_sequential_decode(lm, layout):
         toks.append(int(step_next[slot]))
     # engine B: ONE verify over the same token sequence
     _, eng_b, cache_b = build_scheduler(
-        lm, ServeConfig(max_seqs=2, max_seq_len=32, kv_layout=layout)
+        lm,
+        ServeConfig(max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)),
     )
     slot_b = cache_b.alloc(len(prompt), len(prompt) + 6)
     eng_b.prefill(lm.params, [prompt], [slot_b])
@@ -149,10 +153,10 @@ def test_verify_rollback_then_continue_matches_plain(lm):
     ref = lm.generate(
         [prompt], max_new_tokens=6,
         serve_config=ServeConfig(max_seqs=1, max_seq_len=32,
-                                 kv_layout="paged", kv_page_size=4),
+                                 kv_page_size=4),
     )[0]
     _, engine, cache = build_scheduler(
-        lm, ServeConfig(max_seqs=1, max_seq_len=32, kv_layout="paged",
+        lm, ServeConfig(max_seqs=1, max_seq_len=32,
                         kv_page_size=4)
     )
     slot = cache.alloc(len(prompt), len(prompt) + 6)
@@ -199,7 +203,7 @@ def test_allocator_invariants_through_spec_schedule(lm):
     them), and the pool drains to empty."""
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=3, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=3, max_seq_len=32,
                     kv_page_size=4, spec_draft="ngram", spec_k=4),
     )
     for i, n in enumerate([2, 9, 4, 1, 7, 3, 5, 8, 2, 6]):
@@ -216,20 +220,6 @@ def test_allocator_invariants_through_spec_schedule(lm):
     assert cache.num_free_pages == cache.spec.num_pages
     assert cache._reserved == 0
     assert np.all(cache.block_tables == cache.spec.num_pages)
-
-
-def test_truncate_slot_layout(lm):
-    cache = KVCache.from_model(lm, max_seqs=2, max_len=32)
-    slot = cache.alloc()
-    cache.lengths[slot] = 10
-    cache.truncate(slot, 6)
-    assert cache.lengths[slot] == 6
-    cache.truncate(slot, 9)  # verify commits forward through truncate too
-    assert cache.lengths[slot] == 9
-    with pytest.raises(ValueError, match="outside"):
-        cache.truncate(slot, 33)
-    with pytest.raises(ValueError, match="not active"):
-        cache.truncate(1 - slot if slot in (0, 1) else 0, 2)
 
 
 def test_truncate_paged_returns_pages_under_reserve():
@@ -271,11 +261,11 @@ def test_truncate_paged_returns_pages_under_reserve():
 # -- EOS mid-verify (satellite) ----------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_eos_mid_verify_retires_at_eos(lm, layout):
     """When the accepted run contains EOS, the request retires AT the
-    EOS position and emits nothing past it — on both kv layouts."""
-    base_sc = ServeConfig(max_seqs=1, max_seq_len=32, kv_layout=layout)
+    EOS position and emits nothing past it — at both page geometries."""
+    base_sc = ServeConfig(max_seqs=1, max_seq_len=32, **page_geometry(layout, 32))
     base = lm.generate([[1, 2, 3]], max_new_tokens=10,
                        serve_config=base_sc)[0]
     # an EOS the verify will accept mid-run: a token whose first
@@ -285,7 +275,7 @@ def test_eos_mid_verify_retires_at_eos(lm, layout):
     cut = base.index(eos)
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=1, max_seq_len=32, kv_layout=layout,
+        ServeConfig(max_seqs=1, max_seq_len=32, **page_geometry(layout, 32),
                     spec_draft="ngram", spec_k=4),
     )
     done = sched.run([
@@ -312,18 +302,17 @@ def test_slot_release_order_deterministic(lm):
     order stays lowest-id-first no matter the release order."""
     import heapq
 
-    for cls, kw in ((KVCache, {}), (PagedKVCache, {})):
-        cache = cls.from_model(lm, max_seqs=4, max_len=32, **kw)
-        slots = [cache.alloc(1, 2) for _ in range(4)]
-        assert slots == [0, 1, 2, 3]
-        for s in (2, 0, 3, 1):  # scrambled release
-            cache.free(s)
-        free_list = cache._free if cls is KVCache else cache._free_slots
-        # the free structure is a valid min-heap at all times
-        assert free_list[0] == min(free_list)
-        assert sorted(free_list) == [0, 1, 2, 3]
-        heapq.heappush(free_list, heapq.heappop(free_list))  # heap op works
-        assert [cache.alloc(1, 2) for _ in range(4)] == [0, 1, 2, 3]
+    cache = PagedKVCache.from_model(lm, max_seqs=4, max_len=32)
+    slots = [cache.alloc(1, 2) for _ in range(4)]
+    assert slots == [0, 1, 2, 3]
+    for s in (2, 0, 3, 1):  # scrambled release
+        cache.free(s)
+    free_list = cache._free_slots
+    # the free structure is a valid min-heap at all times
+    assert free_list[0] == min(free_list)
+    assert sorted(free_list) == [0, 1, 2, 3]
+    heapq.heappush(free_list, heapq.heappop(free_list))  # heap op works
+    assert [cache.alloc(1, 2) for _ in range(4)] == [0, 1, 2, 3]
 
 
 def test_paged_page_release_is_heap_ordered(lm):
@@ -344,13 +333,66 @@ def test_paged_page_release_is_heap_ordered(lm):
 
 
 def test_kv_claim_specific_slot(lm):
-    cache = KVCache.from_model(lm, max_seqs=3, max_len=32)
-    cache.claim(1)
-    assert cache.alloc() == 0  # lowest remaining
-    with pytest.raises(ValueError, match="already active"):
-        cache.claim(1)
+    """`alloc(slot=)` takes the named slot under the same page
+    accounting as any admission; the lowest-free order of unnamed
+    admissions goes on round it."""
+    cache = PagedKVCache.from_model(lm, max_seqs=3, max_len=32, page_size=8)
+    assert cache.alloc(9, 20, slot=1) == 1
+    assert cache._held[1] == 2 and cache._reserved == 1
+    assert cache.alloc(1, 1) == 0  # lowest remaining
+    with pytest.raises(ValueError, match="not a free slot"):
+        cache.alloc(1, 1, slot=1)
+    with pytest.raises(ValueError, match="not a free slot"):
+        cache.alloc(1, 1, slot=3)
     cache.free(1)
-    assert sorted(cache._free) == [1, 2]
+    cache.check_invariants()
+    assert sorted(cache._free_slots) == [1, 2]
+    # a named slot is refused, not granted, when its pages are not there
+    tight = PagedKVCache.from_model(
+        lm, max_seqs=2, max_len=32, page_size=8, num_pages=4
+    )
+    assert tight.alloc(32, 32) == 0
+    assert tight.alloc(8, 8, slot=1) is None
+    assert tight.num_active == 1
+    tight.check_invariants()
+
+
+def test_draft_cache_follows_the_targets_slots(lm, draft_lm):
+    """The draft model's paged cache through a request's life: admitted
+    into the target's slot (not the lowest free one) with the whole of
+    max_len reserved, rolled back and grown again page by page, freed
+    with every page returned."""
+    from flexflow_tpu.serving.spec import ModelDraftProposer
+
+    prop = ModelDraftProposer(draft_lm, max_seqs=3, max_len=32)
+    cache = prop.cache
+    ps = cache.spec.page_size
+    assert isinstance(cache, PagedKVCache)
+    assert cache.spec.num_pages * ps == 3 * 32  # every slot can reach max_len
+    prompt = list(range(1, ps + 4))  # two pages
+    req = Request(rid=0, prompt=prompt, max_new_tokens=8)
+    req.slot = 2
+    prop.admit([req])
+    assert cache.active_slots() == [2]
+    assert int(cache.lengths[2]) == len(prompt)
+    assert cache.pages_in_use == 2
+    assert cache._reserved == 32 // ps - 2
+    # drafting grows the slot through the allocator like any decode
+    req.generated = [5]
+    drafts = prop.propose({2: req}, k=3)
+    assert len(drafts[2]) == 3
+    assert int(cache.lengths[2]) == len(prompt) + 3
+    # the target accepted nothing past the prompt: roll back into page one
+    prop.rollback(2, ps - 1)
+    assert int(cache.lengths[2]) == ps - 1
+    assert cache.pages_in_use == 1
+    cache.check_invariants()
+    with pytest.raises(ValueError, match="not a free slot"):
+        prop.admit([req])
+    prop.retire(req)
+    assert cache.num_active == 0 and cache.pages_in_use == 0
+    assert cache._reserved == 0
+    cache.check_invariants()
 
 
 # -- satellite: per-slot PRNG keys --------------------------------------------
@@ -502,7 +544,7 @@ def test_ngram_proposer_lookup():
 def test_model_draft_same_weights_accepts_everything(lm):
     """A draft with the TARGET's own weights agrees on every greedy
     token — acceptance must be 1.0. This exercises the full
-    slot-aligned draft-cache lifecycle (claim/prefill/catch-up/rollback)
+    slot-aligned draft-cache lifecycle (alloc/prefill/catch-up/rollback)
     with a draft that makes disagreement impossible."""
     serve = ServeConfig(max_seqs=2, max_seq_len=32, spec_draft="model",
                         spec_k=3)

@@ -34,6 +34,7 @@ from flexflow_tpu.serving import (
     accept_tree,
     build_scheduler,
 )
+from tests.conftest import page_geometry
 
 pytestmark = pytest.mark.serving
 
@@ -76,23 +77,21 @@ PROMPTS = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 3, 1, 2], [7], [11, 12]]
 # the cross-product legs ride the serving-spec-tree CI job (no "not
 # slow" filter there); tier-1 keeps one leg per mechanism
 _MATRIX = [
-    pytest.param({"kv_layout": "slot"}, id="slot-dense-sync"),
-    pytest.param({"kv_layout": "paged"}, id="paged-dense-sync"),
-    pytest.param({"kv_layout": "paged", "kv_dtype": "int8"},
+    pytest.param({"kv_page_size": 32}, id="one_page-dense-sync"),
+    pytest.param({}, id="paged-dense-sync"),
+    pytest.param({"kv_dtype": "int8"},
                  id="paged-int8", marks=pytest.mark.slow),
-    pytest.param({"kv_layout": "paged", "serve_async": True},
-                 id="paged-async"),
-    pytest.param({"kv_layout": "slot", "serve_async": True},
-                 id="slot-async", marks=pytest.mark.slow),
-    pytest.param({"kv_layout": "paged", "prefix_cache": True},
+    pytest.param({"serve_async": True}, id="paged-async"),
+    pytest.param({"kv_page_size": 32, "serve_async": True},
+                 id="one_page-async", marks=pytest.mark.slow),
+    pytest.param({"prefix_cache": True},
                  id="paged-prefix", marks=pytest.mark.slow),
-    pytest.param({"kv_layout": "paged", "token_budget": 10,
-                  "chunk_size": 4, "decode_kernel": "dense"},
+    pytest.param({"token_budget": 10, "chunk_size": 4,
+                  "decode_kernel": "dense"},
                  id="paged-chunked", marks=pytest.mark.slow),
-    pytest.param({"kv_layout": "paged", "decode_kernel": "pallas"},
-                 id="paged-pallas"),
-    pytest.param({"kv_layout": "slot", "decode_kernel": "pallas"},
-                 id="slot-pallas", marks=pytest.mark.slow),
+    pytest.param({"decode_kernel": "pallas"}, id="paged-pallas"),
+    pytest.param({"kv_page_size": 32, "decode_kernel": "pallas"},
+                 id="one_page-pallas", marks=pytest.mark.slow),
 ]
 
 
@@ -117,7 +116,7 @@ def test_greedy_tree_spec_equals_plain(lm, serve_kw):
     assert tree == plain
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 @pytest.mark.parametrize(
     "branch", [2, pytest.param(3, marks=pytest.mark.slow)])
 def test_model_draft_tree_equals_plain(lm, draft_lm, layout, branch):
@@ -127,13 +126,13 @@ def test_model_draft_tree_equals_plain(lm, draft_lm, layout, branch):
         PROMPTS,
         max_new_tokens=8,
         serve_config=ServeConfig(max_seqs=2, max_seq_len=32,
-                                 kv_layout=layout),
+                                 **page_geometry(layout, 32)),
     )
     tree = lm.generate(
         PROMPTS,
         max_new_tokens=8,
         serve_config=ServeConfig(
-            max_seqs=2, max_seq_len=32, kv_layout=layout,
+            max_seqs=2, max_seq_len=32, **page_geometry(layout, 32),
             spec_draft="model", spec_k=3, spec_branch=branch,
         ),
         draft_model=draft_lm,
@@ -144,16 +143,17 @@ def test_model_draft_tree_equals_plain(lm, draft_lm, layout, branch):
 # -- branch-1 / chain identity to the linear verify path ----------------------
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 @pytest.mark.parametrize("kernel", ["dense", "pallas"])
 def test_chain_tree_verify_bit_matches_linear(lm, layout, kernel):
     """A depth-k, branch-1 tree (chain parents) produces BIT-IDENTICAL
     logits to the linear verify of the same drafts — the ancestor mask
-    degenerates to the staircase, on both layouts and kernels."""
+    degenerates to the staircase, at both page geometries and kernels."""
     prompt = [3, 1, 4, 1, 5]
     _, eng, cache = build_scheduler(
-        lm, ServeConfig(max_seqs=2, max_seq_len=32, kv_layout=layout,
-                        decode_kernel=kernel)
+        lm,
+        ServeConfig(max_seqs=2, max_seq_len=32, **page_geometry(layout, 32),
+                    decode_kernel=kernel),
     )
     slot = cache.alloc(len(prompt), len(prompt) + 8)
     nxt, _ = eng.prefill(lm.params, [prompt], [slot])
@@ -179,7 +179,7 @@ def test_chain_tree_verify_bit_matches_linear(lm, layout, kernel):
     assert len(path) == acc and em_tree == em_lin
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_tree_verify_logits_match_per_chain_linear(lm, layout):
     """Each root-to-node path in a BRANCHING tree scores its token
     against the same distribution a linear verify of that chain alone
@@ -188,7 +188,8 @@ def test_tree_verify_logits_match_per_chain_linear(lm, layout):
     ancestors and the committed prefix, never to a sibling branch."""
     prompt = [3, 1, 4, 1, 5]
     _, eng, cache = build_scheduler(
-        lm, ServeConfig(max_seqs=2, max_seq_len=32, kv_layout=layout)
+        lm,
+        ServeConfig(max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)),
     )
     slot = cache.alloc(len(prompt), len(prompt) + 8)
     nxt, _ = eng.prefill(lm.params, [prompt], [slot])
@@ -234,10 +235,10 @@ def test_tree_commit_compacts_accepted_branch_and_continues(lm):
     ref = lm.generate(
         [prompt], max_new_tokens=6,
         serve_config=ServeConfig(max_seqs=1, max_seq_len=32,
-                                 kv_layout="paged", kv_page_size=4),
+                                 kv_page_size=4),
     )[0]
     _, eng, cache = build_scheduler(
-        lm, ServeConfig(max_seqs=1, max_seq_len=32, kv_layout="paged",
+        lm, ServeConfig(max_seqs=1, max_seq_len=32,
                         kv_page_size=4)
     )
     slot = cache.alloc(len(prompt), len(prompt) + 8)
@@ -469,7 +470,7 @@ def test_allocator_invariants_through_tree_schedule(lm):
     the pool drains to empty."""
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=3, max_seq_len=32, kv_layout="paged",
+        ServeConfig(max_seqs=3, max_seq_len=32,
                     kv_page_size=4, spec_draft="ngram", spec_k=3,
                     spec_branch=3),
     )
@@ -517,18 +518,20 @@ def test_tree_telemetry_series(lm):
     assert sched.stats.tree_nodes_proposed == nodes.value
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_eos_mid_tree_verify_retires_at_eos(lm, layout):
     """EOS inside an accepted branch retires the request AT the EOS
     position — nothing past it is emitted, the slot recycles clean."""
-    base_sc = ServeConfig(max_seqs=1, max_seq_len=32, kv_layout=layout)
+    base_sc = ServeConfig(
+        max_seqs=1, max_seq_len=32, **page_geometry(layout, 32)
+    )
     base = lm.generate([[1, 2, 3]], max_new_tokens=10,
                        serve_config=base_sc)[0]
     eos = next(t for i, t in enumerate(base) if i >= 2)
     cut = base.index(eos)
     sched, _, cache = build_scheduler(
         lm,
-        ServeConfig(max_seqs=1, max_seq_len=32, kv_layout=layout,
+        ServeConfig(max_seqs=1, max_seq_len=32, **page_geometry(layout, 32),
                     spec_draft="ngram", spec_k=3, spec_branch=2),
     )
     done = sched.run([
@@ -541,8 +544,36 @@ def test_eos_mid_tree_verify_retires_at_eos(lm, layout):
     r1 = next(r for r in done if r.rid == 1)
     assert len(r1.generated) == 2
     assert cache.num_active == 0
-    if layout == "paged":
-        assert cache.pages_in_use == 0
+    assert cache.pages_in_use == 0
+
+
+def test_tree_accepts_more_per_verify_than_equal_budget_chain(lm):
+    """The count the spec-tree CI gate held: at the SAME verify budget
+    (1 + 12 scored rows a slot and step) a depth-4 x branch-3 n-gram
+    tree accepts at least 1.2 times the draft tokens per verify step of
+    a k = 12 chain (a rejected first candidate no longer kills the whole
+    draft), and both emit plain greedy decode's streams."""
+    def run(**spec):
+        sched, _, _ = build_scheduler(
+            lm, ServeConfig(max_seqs=4, max_seq_len=64, **spec)
+        )
+        done = sched.run([
+            Request(rid=i, max_new_tokens=48,
+                    prompt=[(i * 5 + j) % VOCAB for j in range(1 + i % 4)])
+            for i in range(8)
+        ])
+        assert all(r.ok for r in done)
+        return {r.rid: r.generated for r in done}, sched.stats
+
+    plain, _ = run()
+    chain, cs = run(spec_draft="ngram", spec_k=12)
+    tree, ts = run(spec_draft="ngram", spec_k=4, spec_branch=3)
+    assert chain == plain and tree == plain
+    assert cs.verify_steps > 0 and ts.tree_verify_steps > 0
+    per_verify = lambda st: st.draft_tokens_accepted / st.verify_steps
+    assert per_verify(ts) >= 1.2 * per_verify(cs), (
+        per_verify(ts), per_verify(cs)
+    )
 
 
 @pytest.mark.slow  # runs in the serving-spec-tree CI job

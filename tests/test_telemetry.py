@@ -13,7 +13,7 @@ Load-bearing proofs:
   implementation, two views);
 * KV-pool gauges match truth re-derived from the block tables across
   preemption, in-flight pinning, and truncate-rollback schedules on
-  both layouts — the same ledgers `check_invariants` audits;
+  both page geometries — the same ledgers `check_invariants` audits;
 * every fault the injector fires surfaces in the exported metrics
   keyed by site — a fault observability can't see is a bug;
 * exported artifacts validate against the checked-in schemas
@@ -61,6 +61,7 @@ from flexflow_tpu.telemetry import (
     validate_trace,
     validate_trace_file,
 )
+from tests.conftest import page_geometry
 
 pytestmark = [pytest.mark.serving, pytest.mark.telemetry]
 
@@ -100,9 +101,9 @@ def _requests(n=6, max_new=8, **kw):
     ]
 
 
-def _serve(layout="slot", serve_async=False, **kw):
+def _serve(layout="paged", serve_async=False, **kw):
     return ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout=layout,
+        max_seqs=4, max_seq_len=32, **page_geometry(layout, 32),
         serve_async=serve_async, **kw,
     )
 
@@ -270,10 +271,10 @@ def test_scheduler_stats_facade_over_registry():
 
 @pytest.fixture(scope="module")
 def reference_streams(lm):
-    """Telemetry-off greedy streams per layout (the sync loop; the
+    """Telemetry-off greedy streams per page geometry (the sync loop; the
     async loop is proved token-identical to it elsewhere)."""
     out = {}
-    for layout in ("slot", "paged"):
+    for layout in ("one_page", "paged"):
         sched, _, _ = build_scheduler(lm, _serve(layout))
         done = sched.run(_requests())
         out[layout] = {r.rid: list(r.generated) for r in done}
@@ -281,7 +282,7 @@ def reference_streams(lm):
     return out
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("layout", ["one_page", "paged"])
 @pytest.mark.parametrize("serve_async", [False, True])
 def test_streams_identical_with_telemetry(lm, reference_streams, layout,
                                           serve_async):
@@ -306,7 +307,7 @@ def test_streams_identical_with_telemetry(lm, reference_streams, layout,
 
 @pytest.fixture(scope="module")
 def async_run(lm, tmp_path_factory):
-    """One fully-exported async run (slot layout): trace + metrics +
+    """One fully-exported async run: trace + metrics +
     JSONL on disk, scheduler retained — shared by the artifact tests."""
     tmp = tmp_path_factory.mktemp("tele")
     paths = {
@@ -314,7 +315,7 @@ def async_run(lm, tmp_path_factory):
         "metrics_jsonl": str(tmp / "metrics.jsonl"),
         "trace": str(tmp / "trace.json"),
     }
-    serve = _serve("slot", serve_async=True, slo_ttft_ms=2000.0,
+    serve = _serve(serve_async=True, slo_ttft_ms=2000.0,
                    slo_itl_ms=500.0, **paths)
     sched, engine, cache = build_scheduler(lm, serve)
     done = sched.run(_requests(n=8, max_new=8))
@@ -426,7 +427,7 @@ def test_latency_percentiles_shared_math(lm):
 
 
 def test_request_events_ring_buffer_bounded(lm):
-    serve = _serve("slot", telemetry=True)
+    serve = _serve(telemetry=True)
     sched, _, _ = build_scheduler(lm, serve)
     reqs = [Request(rid=0, prompt=[1, 2, 3], max_new_tokens=12,
                     events_max=3)]
@@ -484,7 +485,7 @@ def _check_paged_gauges(cache, extra_free=0):
 def test_kv_gauges_match_truth_under_preemption(lm):
     # minimum legal pool + optimistic admission forces preemption
     serve = ServeConfig(
-        max_seqs=4, max_seq_len=32, kv_layout="paged", kv_page_size=4,
+        max_seqs=4, max_seq_len=32, kv_page_size=4,
         kv_pages=8, admission="optimistic", max_preemptions=6,
         telemetry=True,
     )
@@ -519,8 +520,9 @@ def test_kv_gauges_match_truth_async_pinning_and_rollback(lm):
     assert sched.stats.draft_tokens_proposed > 0  # rollback path exercised
 
 
-def test_kv_gauges_slot_layout(lm):
-    serve = _serve("slot", telemetry=True)
+def test_kv_gauges_one_page(lm):
+    """One page a slot: the page gauges are the slot gauges."""
+    serve = _serve("one_page", telemetry=True)
     sched, _, cache = build_scheduler(lm, serve)
     for r in _requests(n=6, max_new=6):
         sched.submit(r)
@@ -528,9 +530,12 @@ def test_kv_gauges_slot_layout(lm):
         sched.step()
         g = cache.telemetry_gauges()
         assert g["kv_slots_active"] == len(cache._active)
-        assert g["kv_slots_free"] == len(cache._free)
+        assert g["kv_slots_free"] == cache.num_free
+        assert g["kv_pages_live"] == g["kv_slots_active"]
+        assert g["kv_free_heap_depth"] == g["kv_slots_free"]
         assert g["kv_rows_used"] == int(cache.lengths.sum())
-        assert 0.0 <= g["kv_occupancy"] <= 1.0
+        assert g["kv_occupancy"] == len(cache._active) / cache.spec.max_seqs
+        assert g["kv_pages_reserved"] == 0
         cache.check_invariants()
 
 
@@ -569,7 +574,7 @@ def test_every_injected_fault_surfaces_in_metrics(lm):
 
 def test_kernel_fallback_surfaces_in_metrics_and_trace(lm):
     injector = FaultInjector(FaultPlan(kernel_iters=(1,)), seed=0)
-    serve = _serve("slot", telemetry=True, decode_kernel="pallas")
+    serve = _serve(telemetry=True, decode_kernel="pallas")
     sched, engine, _ = build_scheduler(lm, serve, injector=injector)
     done = sched.run(_requests(n=4, max_new=4))
     assert all(r.ok for r in done)
@@ -587,7 +592,7 @@ def test_injector_wiring_through_build(lm):
     # injector passed through build_scheduler reaches scheduler + engine
     injector = FaultInjector(FaultPlan(), seed=1)
     sched, engine, _ = build_scheduler(
-        lm, _serve("slot", telemetry=True), injector=injector
+        lm, _serve(telemetry=True), injector=injector
     )
     assert sched.injector is injector and engine.injector is injector
 
@@ -626,7 +631,7 @@ def test_flag_wiring_to_serveconfig_and_bundle(tmp_path):
 
 
 def test_disabled_telemetry_is_fully_absent(lm):
-    sched, engine, _ = build_scheduler(lm, _serve("slot"))
+    sched, engine, _ = build_scheduler(lm, _serve())
     assert sched.telemetry is None and sched._tele is None
     assert engine.telemetry is None
     done = sched.run(_requests(n=2, max_new=4))

@@ -299,23 +299,18 @@ def test_victim_tiebreak_is_deterministic_by_admission_order(lm):
 
 
 _MATRIX = [
-    pytest.param({"kv_layout": "slot"}, id="slot-dense-sync"),
-    pytest.param({"kv_layout": "paged"}, id="paged-dense-sync"),
-    pytest.param({"kv_layout": "paged", "kv_dtype": "int8"},
-                 id="paged-int8"),
-    pytest.param({"kv_layout": "paged", "serve_async": True},
-                 id="paged-async"),
-    pytest.param({"kv_layout": "paged", "spec_draft": "ngram",
-                  "spec_k": 3}, id="paged-spec"),
-    pytest.param({"kv_layout": "paged", "token_budget": 10,
-                  "chunk_size": 4, "decode_kernel": "dense"},
-                 id="paged-chunked"),
-    pytest.param({"kv_layout": "paged", "decode_multistep": True,
-                  "max_fused_steps": 4}, id="paged-multistep"),
-    pytest.param({"kv_layout": "paged", "decode_kernel": "pallas"},
-                 id="paged-pallas"),
-    pytest.param({"kv_layout": "slot", "decode_kernel": "pallas"},
-                 id="slot-pallas"),
+    pytest.param({"kv_page_size": 32}, id="one_page-dense-sync"),
+    pytest.param({}, id="paged-dense-sync"),
+    pytest.param({"kv_dtype": "int8"}, id="paged-int8"),
+    pytest.param({"serve_async": True}, id="paged-async"),
+    pytest.param({"spec_draft": "ngram", "spec_k": 3}, id="paged-spec"),
+    pytest.param({"token_budget": 10, "chunk_size": 4,
+                  "decode_kernel": "dense"}, id="paged-chunked"),
+    pytest.param({"decode_multistep": True, "max_fused_steps": 4},
+                 id="paged-multistep"),
+    pytest.param({"decode_kernel": "pallas"}, id="paged-pallas"),
+    pytest.param({"kv_page_size": 32, "decode_kernel": "pallas"},
+                 id="one_page-pallas"),
 ]
 
 
@@ -353,7 +348,7 @@ def test_mixed_adapter_batch_matches_isolated_runs(lm):
     alone — the per-slot gather never leaks one slot's delta into
     another's projection. The no-adapter stream also matches a
     pool-free engine (identity inside a mixed batch)."""
-    kw = dict(kv_layout="paged", adapters=2, adapter_rank=4)
+    kw = dict(adapters=2, adapter_rank=4)
     mk = lambda aid, rid: Request(  # noqa: E731
         rid=rid, prompt=[7, 3, 5], max_new_tokens=6, adapter_id=aid
     )
@@ -363,7 +358,7 @@ def test_mixed_adapter_batch_matches_isolated_runs(lm):
         alone.update(_run(lm, dict(kw), [mk(aid, aid if aid >= 0 else 2)]))
     assert mixed == alone
     # adapters actually bite: A and B disagree with the base stream
-    base = _run(lm, dict(kv_layout="paged"), [mk(-1, 9)])
+    base = _run(lm, dict(), [mk(-1, 9)])
     assert mixed[2] == base[9]
     assert mixed[0] != mixed[2] and mixed[1] != mixed[2]
     assert mixed[0] != mixed[1]
