@@ -234,6 +234,15 @@ def lora_delta_out(attn, tbl, a_o, b_o):
     return jnp.einsum("bspr,bpre->bse", u, b_o[tbl], **mm)
 
 
+def _kernel_heads(q, head_shard) -> int:
+    """Heads one kernel call sees of q [b, w, h, d]: a shard's under
+    `head_shard` = (mesh, axis), as `_kernel_call` splits them."""
+    if head_shard is None:
+        return q.shape[2]
+    mesh, axis = head_shard
+    return q.shape[2] // mesh.shape[axis]
+
+
 def _kernel_call(entry, head_shard, args, head_dims):
     """Call a decode-kernel entry point (pallas/decode_kernel.py).
 
@@ -478,6 +487,7 @@ def _paged_verify_pallas_hook(q, k_pool, v_pool, block_tables, lengths,
     if not dk.use_kernel(
         kernel, q.shape[1], 0, q.shape[-1], page_size=k_pool.shape[1],
         kv_dtype="int8" if quant else "fp32",
+        heads=_kernel_heads(q, head_shard),
     ):
         return None
     if allowed is not None:
@@ -570,6 +580,7 @@ def _paged_decode_pallas_hook(q, k_pool, v_pool, block_tables, lengths,
     if not dk.use_kernel(
         kernel, q.shape[1], 0, q.shape[-1], page_size=k_pool.shape[1],
         kv_dtype="int8" if quant else "fp32",
+        heads=_kernel_heads(q, head_shard),
     ):
         return None
     if quant:

@@ -28,39 +28,53 @@ Flash-Decoding style (Dao et al., 2023):
     staircase degenerates to decode_attention's `key_pos <= lengths[i]`
     mask. Sharing the body is what keeps greedy speculative decoding
     token-identical to plain decode on the kernel path.
-  * **The paged variant walks the block table** — grid (batch, pages):
-    the K/V BlockSpec index maps read the scalar-prefetched block table
-    to DMA each logical page straight from the pool (PagedAttention,
-    Kwon et al., SOSP'23), so the per-step contiguous gather the dense
-    paged path pays disappears. Sentinel entries (num_pages) are
-    clamped for the DMA and masked in the score tile, so unallocated
-    pages are numerically inert exactly like the dense path's
-    clamp-and-mask.
+  * **The paged variant walks the block table, a block of pages at a
+    time** — grid (batch,), the pools left in HBM: for each LIVE block
+    of `paged_block(...).pages` logical pages of a slot the kernel
+    itself copies the pages the scalar-prefetched block table names,
+    straight from the pool (PagedAttention, Kwon et al., SOSP'23), into
+    one of two (rows, h*d) VMEM buffers, one block ahead of the one it
+    computes, across slots too. So the per-step contiguous gather the
+    dense paged path pays disappears, a slot costs its live blocks and
+    a dead slot nearly nothing. Sentinel entries (num_pages) are clamped
+    for the copy and masked in the score tile, so unallocated pages are
+    numerically inert exactly like the dense path's clamp-and-mask.
 
-Block shapes and the TPU tiling rule. Mosaic takes a block only when its
-last two dims are multiples of the (sublane, lane) tile — (8, 128) for
-f32, (32, 128) for int8 — or span the whole array dim. The paged
-kernels read a pool as [num_pages, page_size, h*d]:
+Block shapes and the TPU tiling rule. Mosaic takes a block, or a copy,
+only when its last two dims are multiples of the (sublane, lane) tile —
+(8, 128) for f32, (32, 128) for int8 — or span the whole array dim. The
+paged kernels read a pool as [num_pages, page_size, h*d]:
 
-  * a page is one block with ALL its heads. The serving cache keeps its
-    pools in exactly this shape (PagedKVCache), so the reshape below is
-    the identity there; handed a [num_pages, page_size, h, d] pool it is
-    a reshape, which on a TPU's tiled layouts is a relayout of the whole
-    pool and not a view (1.1-1.4 s of a 12 s window before PR 27). The
-    block is (1, page_size, h*d) — rows are the sublane dim, the whole
-    h*d row the lane dim. One program handles every head of a page
-    (a static loop over lane slices), so a page is one contiguous DMA
-    instead of h strided ones and the grid has h times fewer steps;
-  * the int8 scale pools [num_pages, h] are viewed as
-    [num_pages, 1, h], block (1, 1, h);
-  * the tree mask [b, w, kv] is regrouped to [b, chunks, w, chunk],
-    block (1, 1, w, chunk), for both layouts.
+  * a page is one contiguous copy with ALL its heads. The serving cache
+    keeps its pools in exactly this shape (PagedKVCache), so the reshape
+    below is the identity there; handed a [num_pages, page_size, h, d]
+    pool it is a reshape, which on a TPU's tiled layouts is a relayout
+    of the whole pool and not a view (1.1-1.4 s of a 12 s window before
+    PR 27). Compiled, the row of h*d must be whole 128-lane tiles
+    (`use_kernel`'s `heads`); the interpreter takes any;
+  * heads go through the MXU a group at a time with their queries laid
+    block-diagonally (`_paged_kernel`): for decode all heads' scores are
+    ONE (h, h*d) x (rows, h*d)^T matmul and the softmax runs on (h,
+    rows), not h matmuls of M = 1 a page;
+  * the int8 scale pools [num_pages, h] are gathered by the wrapper into
+    a slot's [blocks, h, rows] tiles, the layout of a block's scores;
+  * the tree mask [b, w, kv] is regrouped to [b, chunks, w, chunk] for
+    both layouts (the contiguous kernel takes a (1, 1, w, chunk) block a
+    grid step, the paged one a slot's chunks at once).
 
 Tile size: the contiguous kernel's KV chunk defaults to the
 v5e-calibrated 512 rows (calibration/v5e.json "decode_blocks", installed
 at compile like the training kernel's flash_blocks) shrunk to the
-largest sublane-aligned divisor of max_len; the paged kernel's chunk is
-one page (the block table gives no contiguity beyond a page).
+largest sublane-aligned divisor of max_len. The paged kernel's block is
+`paged_block()`: from page_size, h*d, the pool's itemsize, w and the
+table's width alone, as many pages as fit `_VMEM_BUDGET` up to
+`_MAX_BLOCK_ROWS` rows (8 pages of 16 for both serving cells, one page
+where page_size == max_seq_len). Nothing sets it. Why copies by hand and
+not one BlockSpec a page (PR 29, measured on a v5e): a grid of (slots,
+blocks) with the pool handed to `pallas_call` once a page of the block
+evaluates 2 x slots x table-width index maps a call whatever the block
+size, 90 us a call at the cells' geometry, where this pays for live
+pages only (11 us a call with every slot dead).
 
 `supports()` gates geometry (callers fall back to the dense paths), and
 `interpret=None` selects the Pallas interpreter off-TPU
@@ -163,7 +177,7 @@ def supports(
 
     w: query positions per sequence (1 = decode, k+1 = verify);
     kv_len: max_len of the contiguous cache; page_size > 0 checks the
-    paged variant instead (its chunk is one page, so the page must be
+    paged variant instead (its block is whole pages, so the page must be
     sublane-aligned; kv_len is ignored — the walk is table-driven).
     kv_dtype "int8" selects the quantized paged variant's gate: pages
     must pack whole (32, 128) int8 tiles, and only the paged layout
@@ -181,21 +195,30 @@ def supports(
 
 def use_kernel(
     mode: str, w: int, kv_len: int, head_dim: int, page_size: int = 0,
-    kv_dtype: str = "fp32",
+    kv_dtype: str = "fp32", heads: int = 0,
 ) -> bool:
     """Resolve a ServeConfig.decode_kernel mode for one geometry:
     "dense" never takes the kernel, "pallas" takes it whenever
     supports() passes (interpret mode runs it off-TPU — the CI/test
     path), "auto" additionally requires a real TPU backend (on CPU the
     dense one-query path is the measured-fast choice; interpreting the
-    kernel there is a correctness tool, not a serving config)."""
+    kernel there is a correctness tool, not a serving config).
+
+    `heads` (the heads the kernel will see: a shard's, under a head
+    shard) matters to the paged kernel COMPILED: it copies whole cache
+    rows of heads * head_dim out of the pool itself, and Mosaic takes
+    such a copy only in whole 128-lane tiles. A toy model's narrower row
+    is served dense on a TPU; the interpreter has no such rule."""
     if mode not in MODES:
         raise ValueError(f"decode_kernel must be one of {MODES}, got {mode!r}")
     if mode == "dense" or not supports(
         w, kv_len, head_dim, page_size, kv_dtype=kv_dtype
     ):
         return False
-    return mode == "pallas" or jax.default_backend() == "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and page_size > 0 and (heads * head_dim) % LANES:
+        return False
+    return mode == "pallas" or on_tpu
 
 
 def supports_tree(w: int) -> bool:
@@ -231,16 +254,25 @@ def _init_scratch(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _online_softmax_step(s, v, m_scr, l_scr, acc_scr):
+def _online_softmax_step(
+    s, v, m_scr, l_scr, acc_scr, visible=None, p_scale=None
+):
     """Fold one masked score tile (w, bk) and its V chunk (bk, d) into
     the running (m, l, acc) accumulators — the flash_kernel.py forward
-    update, minus the LSE output serving never needs."""
+    update, minus the LSE output serving never needs. `visible` zeroes
+    the masked probabilities outright, for a tile that may hold a row
+    with NO visible key (there `s - m_new` is 0, not -1e30); `p_scale`
+    multiplies the probabilities on their way into `p @ v` only."""
     m_prev = m_scr[:, :1]  # (w, 1)
     l_prev = l_scr[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)  # masked entries: exp(~-1e30) == 0
+    if visible is not None:
+        p = jnp.where(visible, p, 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+    if p_scale is not None:
+        p = p * p_scale
     acc_scr[...] = acc_scr[...] * corr + mxu_dot(p.astype(v.dtype), v, (1, 0))
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -419,66 +451,273 @@ def flash_verify_tree(
 
 # -- block-paged cache --------------------------------------------------------
 
+# what one grid step may hold in VMEM, scratch and temporaries included:
+# three quarters of the 16 MiB Mosaic scopes to a kernel on a v5e by
+# default, so the choice below never needs a raised limit
+_VMEM_BUDGET = 12 << 20
+# blocks of 128 and of 256 rows measured the same on a v5e (PR 29); the
+# smaller needs half the VMEM and wastes less of a short sequence's tail
+_MAX_BLOCK_ROWS = 128
+# query rows a matmul takes (w x the heads folded into it): one MXU tile
+_MAX_Q_ROWS = 128
+
+
+class PagedBlock(NamedTuple):
+    """What one turn of the paged kernel's block loop handles: `pages`
+    logical pages of one slot (`rows` cache rows), `heads` heads a
+    matmul (their queries laid block-diagonally), and the VMEM the
+    kernel takes with it."""
+
+    pages: int
+    rows: int
+    heads: int
+    vmem_bytes: int
+
+
+def _head_group(w: int, heads: int) -> int:
+    """Heads folded into one matmul: the most that keep w x heads query
+    rows inside one MXU tile. Past one query a group's rows are laid out
+    query-major in pieces of `heads` rows, so the group is whole sublane
+    tiles or a single head (the plain per-head loop)."""
+    for g in range(heads, 1, -1):
+        if heads % g == 0 and g * w <= _MAX_Q_ROWS and (
+            w == 1 or g % SUBLANES == 0
+        ):
+            return g
+    return 1
+
+
+def paged_block(
+    w: int, heads: int, head_dim: int, page_size: int, np_seq: int,
+    itemsize: int,
+) -> PagedBlock:
+    """The paged kernel's block, from static shapes alone: as many pages
+    at a time as fit `_VMEM_BUDGET`, up to `_MAX_BLOCK_ROWS` rows and
+    the table's `np_seq` pages, and at least one (`page_size ==
+    max_seq_len` gives exactly one)."""
+    hd = heads * head_dim
+    group = _head_group(w, heads)
+    m = w * group
+    fixed = 4 * (
+        2 * w * heads * group * head_dim  # block-diagonal q, accumulator
+        + 2 * w * heads * LANES  # running max and sum
+        + 2 * w * hd  # q and output tiles
+    )
+
+    def vmem(rows):
+        table_rows = -(-np_seq * page_size // rows) * rows
+        return (
+            fixed
+            + rows * (
+                2 * 2 * hd * itemsize  # two buffers each of K and V
+                + 2 * hd * 4  # the block's K and V as float32 values
+                + 4 * 4 * m  # score, probability and mask tiles
+            )
+            # a slot's tree-mask and scale tiles, double-buffered
+            + table_rows * 2 * 4 * (w + 2 * heads)
+        )
+
+    pages = max(1, min(np_seq, _MAX_BLOCK_ROWS // page_size))
+    while pages > 1 and vmem(pages * page_size) > _VMEM_BUDGET:
+        pages //= 2
+    rows = pages * page_size
+    return PagedBlock(pages, rows, group, vmem(rows))
+
+
+def _repeat_rows(x, n: int):
+    """(w, c) -> (w * n, c), each row n times over (query-major)."""
+    if n == 1:
+        return x
+    return jnp.concatenate(
+        [
+            jnp.broadcast_to(x[j:j + 1], (n, x.shape[1]))
+            for j in range(x.shape[0])
+        ],
+        axis=0,
+    )
+
+
+def _tile_rows(x, n: int):
+    """(g, c) -> (n * g, c), the whole tile n times over."""
+    if x.shape[0] == 1:
+        return jnp.broadcast_to(x, (n, x.shape[1]))
+    return x if n == 1 else jnp.concatenate([x] * n, axis=0)
+
+
+def _own_head(group: int, head_dim: int):
+    """(group, group * head_dim) bool: row r's own head_dim lanes."""
+    shape = (group, group * head_dim)
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    lo = lax.broadcasted_iota(jnp.int32, shape, 0) * head_dim
+    return (lane >= lo) & (lane < lo + head_dim)
+
 
 def _paged_kernel(
-    len_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
-    cfg, num_pages, np_seq, heads, head_dim, quant, tree,
+    len_ref, tbl_ref, sched_ref, q_ref, k_hbm, v_hbm, *rest,
+    cfg, blk, num_pages, heads, head_dim, quant, tree,
 ):
-    """One page, all heads. k_ref/v_ref: (1, page_size, h*d) — head ih
-    is lanes [ih*d, (ih+1)*d). quant adds the (1, 1, h) per-(page,
-    head) scale tiles and dequantizes each head's slice INSIDE the
-    chunk loop, so no dequantized cache view ever exists outside VMEM;
-    tree swaps the staircase for a (1, 1, w, page_size) mask tile.
-    Scratch is per head: m/l (h, w, LANES), acc (h, w, d)."""
+    """One slot, all heads, its live blocks of `blk.pages` logical pages
+    in a loop.
+
+    k_hbm/v_hbm are the whole pools, left where they are: the kernel
+    copies a block's pages itself, page by page as the table names them,
+    into one of two (rows, h*d) VMEM buffers, so that only LIVE blocks
+    cost anything (a grid of (slots, blocks) pays its index maps for
+    every page of the table, live or not). Blocks alternate buffers
+    across the whole call: while block t is computed, block t + 1 is in
+    flight, be it the slot's next or the next live slot's first
+    (sched_ref: a slot's live blocks, the live blocks before it, the
+    next live slot; all from the wrapper).
+
+    Heads go through the MXU `blk.heads` at a time: the group's w
+    queries sit block-diagonally in a (w * group, group * d) tile (row
+    j * group + g holds query j's head g in that head's own lanes, zeros
+    elsewhere; built once a slot into q_scr), so ONE `q @ k^T` gives
+    every head's (w, rows) scores and ONE `p @ v` every head's weighted
+    values, whose own-head lanes are picked out when the slot is done.
+    With group == 1 that is the plain per-head (w, d) matmul. The
+    online-softmax update runs once a block. quant dequantizes through
+    the score and probability tiles (a page's scale is per head, so it
+    factors out of both matmuls): no dequantized cache view exists
+    anywhere; tree swaps the staircase for a (w, rows) mask tile."""
     rest = list(rest)
     ks_ref, vs_ref = (rest.pop(0), rest.pop(0)) if quant else (None, None)
     mask_ref = rest.pop(0) if tree else None
-    o_ref, m_scr, l_scr, acc_scr = rest
+    o_ref, k_buf, v_buf, sem, q_scr, m_scr, l_scr, acc_scr = rest
+    pages, rows, group = blk.pages, blk.rows, blk.heads
+    w = cfg.w
     page_size = cfg.block_k
+    gd = group * head_dim
+    m = w * group
     ib = pl.program_id(0)
-    ip = pl.program_id(1)
+    n_blocks = sched_ref[0, ib]
+    before = sched_ref[1, ib]
+    next_live = sched_ref[2, ib]
 
-    @pl.when(ip == 0)
-    def _init():
+    def lanes(g):
+        return slice(g * gd, (g + 1) * gd)
+
+    def copies(slot, block, buf):
+        """The block's page copies, K then V, into buffer `buf`.
+        Sentinel entries clamp to a real page (their keys are masked)."""
+        out = []
+        for j in range(pages):
+            page = jnp.minimum(tbl_ref[slot, block * pages + j], num_pages - 1)
+            dst = pl.ds(j * page_size, page_size)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[buf, dst], sem.at[0, buf]
+            ))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[buf, dst], sem.at[1, buf]
+            ))
+        return out
+
+    def start(slot, block, buf):
+        for c in copies(slot, block, buf):
+            c.start()
+
+    @pl.when(n_blocks == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live():
         _init_scratch(m_scr, l_scr, acc_scr)
+        q = q_ref[0].astype(jnp.float32)  # (w, h*d)
+        for g in range(heads // group):
+            qg = _repeat_rows(q[:, lanes(g)], group)
+            if group > 1:
+                own = _tile_rows(_own_head(group, head_dim), w)
+                qg = jnp.where(own, qg, 0.0)
+            q_scr[g] = qg
 
-    length = len_ref[ib]
+        # nobody fetched the call's first live block ahead
+        @pl.when(before == 0)
+        def _first():
+            start(ib, 0, 0)
 
-    # a page contributes iff it is inside the staircase AND allocated
-    # (sentinel entries sit past the length gate whenever the engine's
-    # allocator invariants hold — the table check is defensive, for
-    # standalone callers handing the kernel ragged tables)
-    @pl.when(
-        (ip * page_size <= length + (cfg.w - 1))
-        & (tbl_ref[ib, ip] < num_pages)
-    )
-    def _body():
-        k_page = k_ref[0]  # (page_size, h*d)
-        v_page = v_ref[0]
-        for ih in range(heads):
-            lanes = slice(ih * head_dim, (ih + 1) * head_dim)
-            q = q_ref[0, ih]  # (w, d)
-            k = k_page[:, lanes]  # (page_size, d)
-            v = v_page[:, lanes]
+        length = len_ref[ib]
+        col = lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        if not tree:
+            # query-major rows: row r is query r // group
+            row = lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+            qoff = sum(
+                (row >= j * group).astype(jnp.int32) for j in range(1, w)
+            )
+
+        def _block(i, carry):
+            buf = (before + i) % 2
+
+            @pl.when(i + 1 < n_blocks)
+            def _next_block():
+                start(ib, i + 1, 1 - buf)
+
+            @pl.when((i + 1 == n_blocks) & (next_live < pl.num_programs(0)))
+            def _next_slot():
+                start(next_live, 0, 1 - buf)
+
+            for c in copies(ib, i, buf):
+                c.wait()
+            k = k_buf[buf]  # (rows, h*d)
+            v = v_buf[buf]
             if quant:
-                q = q.astype(jnp.float32)
-                k = k.astype(jnp.float32) * ks_ref[0, :, ih:ih + 1]
-                v = v.astype(jnp.float32) * vs_ref[0, :, ih:ih + 1]
-            s = mxu_dot(q, k, (1, 1)) * cfg.sm_scale  # (w, page_size)
+                k = k.astype(jnp.float32)
+                v = v.astype(jnp.float32)
             if tree:
-                s = jnp.where(mask_ref[0, 0] > 0.0, s, _MASK)
+                visible = _repeat_rows(mask_ref[0, i], group) > 0.0
             else:
-                s = _stair_mask(s, cfg, length, ip * page_size)
-            _online_softmax_step(
-                s, v, m_scr.at[ih], l_scr.at[ih], acc_scr.at[ih]
+                visible = i * rows + col <= length + qoff
+            # an unallocated page inside a live block (a standalone
+            # caller's ragged table; the engine's sit past the length)
+            allocated = functools.reduce(
+                jnp.logical_or,
+                [
+                    (col >= j * page_size) & (col < (j + 1) * page_size)
+                    & (tbl_ref[ib, i * pages + j] < num_pages)
+                    for j in range(pages)
+                ],
             )
+            visible = visible & allocated
+            for g in range(heads // group):
+                s = mxu_dot(q_scr[g], k[:, lanes(g)], (1, 1)) * cfg.sm_scale
+                p_scale = None
+                if quant:
+                    rows_g = pl.ds(g * group, group)
+                    s = s * _tile_rows(ks_ref[0, i, rows_g], w)
+                    p_scale = _tile_rows(vs_ref[0, i, rows_g], w)
+                _online_softmax_step(
+                    jnp.where(visible, s, _MASK), v[:, lanes(g)],
+                    m_scr.at[g], l_scr.at[g], acc_scr.at[g],
+                    visible=visible, p_scale=p_scale,
+                )
+            return carry
 
-    @pl.when(ip == np_seq - 1)
-    def _done():
-        for ih in range(heads):
-            o_ref[0, ih] = _finish(
-                l_scr.at[ih], acc_scr.at[ih], o_ref.dtype
-            )
+        lax.fori_loop(0, n_blocks, _block, None)
+
+        for g in range(heads // group):
+            out = _finish(l_scr.at[g], acc_scr.at[g], o_ref.dtype)
+            if group == 1:
+                o_ref[0, :, lanes(g)] = out
+                continue
+            own = _own_head(group, head_dim)
+            for j in range(w):
+                piece = out[j * group:(j + 1) * group]
+                o_ref[0, j:j + 1, lanes(g)] = jnp.sum(
+                    jnp.where(own, piece, 0.0), axis=0, keepdims=True
+                )
+
+
+def _paged_schedule(live_blocks):
+    """[b] live blocks a slot -> [3, b] int32: the same, the live blocks
+    before the slot (which buffer its first goes to), and the next slot
+    that has any (b if none): what the kernel needs to fetch one block
+    ahead across slots."""
+    b = live_blocks.shape[0]
+    slot = jnp.arange(b, dtype=jnp.int32)
+    later = lax.cummin(jnp.where(live_blocks > 0, slot, b), reverse=True)
+    next_live = jnp.concatenate([later[1:], jnp.full((1,), b, jnp.int32)])
+    before = jnp.cumsum(live_blocks) - live_blocks
+    return jnp.stack([live_blocks, before, next_live]).astype(jnp.int32)
 
 
 def _paged_call(
@@ -486,12 +725,8 @@ def _paged_call(
     sm_scale, interpret,
 ):
     b, w, h, d = q.shape
-    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
-    np_seq = block_tables.shape[1]
+    page_size = k_pool.shape[1]
     quant = k_scale is not None
-    tree = allowed is not None
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
     align = _INT8_SUBLANES if quant else SUBLANES
     if page_size % align:
         raise ValueError(
@@ -499,74 +734,126 @@ def _paged_call(
             f"{page_size} is not sublane-aligned ({align}); use "
             "supports() and fall back to dense"
         )
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(d)
     cfg = _Cfg(w, sm_scale, page_size, resolve_interpret(interpret))
-    qt = q.transpose(0, 2, 1, 3)  # [b, h, w, d]
+    blk = paged_block(
+        w, h, d, page_size, block_tables.shape[1], k_pool.dtype.itemsize
+    )
+    return _paged_block_call(
+        q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, allowed,
+        cfg=cfg, blk=blk,
+    )
 
-    def q_map(ib, ip, lens, tbl):
+
+# jitted so that a step program traces and lowers the kernel ONCE and
+# calls it a layer: inline, each of 24 layers traced the body again
+# (18 s of every process's set-up on the chip's host, fetched programs
+# or not: the trace is what the cache key is made from)
+@functools.partial(jax.jit, static_argnames=("cfg", "blk"))
+def _paged_block_call(
+    q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale, allowed,
+    cfg, blk,
+):
+    b, w, h, d = q.shape
+    num_pages, page_size = k_pool.shape[0], k_pool.shape[1]
+    np_seq = block_tables.shape[1]
+    quant = k_scale is not None
+    tree = allowed is not None
+    pages, rows = blk.pages, blk.rows
+    nblk = -(-np_seq // pages)
+    lens = lengths.astype(jnp.int32)
+    # the table in whole blocks (the tail past np_seq is unallocated)
+    tbl = jnp.pad(
+        block_tables.astype(jnp.int32),
+        ((0, 0), (0, nblk * pages - np_seq)),
+        constant_values=num_pages,
+    )
+    # a page counts iff it is inside the staircase AND allocated; a slot's
+    # live blocks run up to the last block that holds such a page
+    first_row = jnp.arange(nblk * pages, dtype=jnp.int32) * page_size
+    live = (first_row[None, :] <= lens[:, None] + (w - 1)) & (tbl < num_pages)
+    block_no = jnp.arange(1, nblk + 1, dtype=jnp.int32)
+    live_blocks = jnp.max(
+        jnp.where(live.reshape(b, nblk, pages).any(axis=2), block_no, 0),
+        axis=1,
+    )
+
+    def slot_map(ib, lens, tbl, sched):
+        return (ib, 0, 0)
+
+    def slot_tiles_map(ib, lens, tbl, sched):
         return (ib, 0, 0, 0)
 
-    def page_of(ib, ip, lens, tbl):
-        # skipped pages prefetch the sequence's first page; sentinel
-        # entries clamp to a real page (their scores are masked)
-        ip = lax.select(ip * page_size <= lens[ib] + (w - 1), ip, 0)
-        return jnp.minimum(tbl[ib, ip], num_pages - 1)
-
-    def kv_map(ib, ip, lens, tbl):
-        return (page_of(ib, ip, lens, tbl), 0, 0)
-
-    def mask_map(ib, ip, lens, tbl):
-        # the mask is over LOGICAL positions: its tile is just the page
-        # index — no table lookup, every logical tile is resident
-        return (ib, ip, 0, 0)
-
     in_specs = [
-        pl.BlockSpec((1, h, w, d), q_map),
-        pl.BlockSpec((1, page_size, h * d), kv_map),
-        pl.BlockSpec((1, page_size, h * d), kv_map),
+        pl.BlockSpec((1, w, h * d), slot_map),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     operands = [
-        lengths.astype(jnp.int32),
-        block_tables.astype(jnp.int32),
-        qt,
+        lens, tbl, _paged_schedule(live_blocks), q.reshape(b, w, h * d),
         k_pool.reshape(num_pages, page_size, h * d),
         v_pool.reshape(num_pages, page_size, h * d),
     ]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, h), kv_map)] * 2
-        operands += [
-            k_scale.astype(jnp.float32).reshape(num_pages, 1, h),
-            v_scale.astype(jnp.float32).reshape(num_pages, 1, h),
-        ]
+        # a slot's scales as [blocks, h, rows] tiles, the layout of a
+        # block's scores (sentinel entries take a real page's, masked)
+        def scale_tiles(scale):
+            per_page = scale.astype(jnp.float32)[
+                jnp.minimum(tbl, num_pages - 1)
+            ]  # [b, nblk * pages, h]
+            return jnp.repeat(
+                per_page.reshape(b, nblk, pages, h).transpose(0, 1, 3, 2),
+                page_size, axis=3,
+            )
+
+        in_specs += [pl.BlockSpec((1, nblk, h, rows), slot_tiles_map)] * 2
+        operands += [scale_tiles(k_scale), scale_tiles(v_scale)]
     if tree:
-        in_specs.append(pl.BlockSpec((1, 1, w, page_size), mask_map))
-        operands.append(_chunked_mask(allowed, page_size))
+        in_specs.append(pl.BlockSpec((1, nblk, w, rows), slot_tiles_map))
+        operands.append(
+            _chunked_mask(
+                jnp.pad(
+                    allowed,
+                    ((0, 0), (0, 0), (0, nblk * rows - allowed.shape[2])),
+                ),
+                rows,
+            )
+        )
+    groups = h // blk.heads
+    m = w * blk.heads
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel,
             cfg=cfg,
+            blk=blk,
             num_pages=num_pages,
-            np_seq=np_seq,
             heads=h,
             head_dim=d,
             quant=quant,
             tree=tree,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, np_seq),
+            num_scalar_prefetch=3,
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, h, w, d), q_map),
+            out_specs=pl.BlockSpec((1, w, h * d), slot_map),
             scratch_shapes=[
-                pltpu.VMEM((h, w, LANES), jnp.float32),
-                pltpu.VMEM((h, w, LANES), jnp.float32),
-                pltpu.VMEM((h, w, d), jnp.float32),
+                pltpu.VMEM((2, rows, h * d), k_pool.dtype),
+                pltpu.VMEM((2, rows, h * d), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((groups, m, blk.heads * d), jnp.float32),
+                pltpu.VMEM((groups, m, LANES), jnp.float32),
+                pltpu.VMEM((groups, m, LANES), jnp.float32),
+                pltpu.VMEM((groups, m, blk.heads * d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w, d), q.dtype),
-        compiler_params=_compiler_params(("parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, w, h * d), q.dtype),
+        # slots in order: a slot's first block is fetched by the one before
+        compiler_params=_compiler_params(("arbitrary",)),
         interpret=cfg.interpret,
     )(*operands)
-    return out.transpose(0, 2, 1, 3)
+    return out.reshape(b, w, h, d)
 
 
 def paged_flash_verify(

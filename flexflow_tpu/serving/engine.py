@@ -350,6 +350,7 @@ class GenerationEngine:
                 self.decode_kernel = "dense"
             else:
                 self._head_shard = shard
+        self._publish_kernel_block()
         # multi-tenant LoRA (serving.tenancy.adapters.AdapterPool):
         # None keeps every traced step byte-for-byte the base engine —
         # the adapter argument is simply never passed, so no select or
@@ -658,6 +659,41 @@ class GenerationEngine:
             return call()
         self._kernel_programs_run.add(program)
         return out
+
+    def _publish_kernel_block(self) -> None:
+        """What one turn of the paged decode kernel's block loop handles
+        at this cache's geometry (ops/pallas/decode_kernel.paged_block: pages a
+        block, rows a block, VMEM bytes; the decode step's, w = 1), as
+        `serve_decode_kernel_block_*` gauges, once: a trace of the kernel
+        is read against them. Nothing where the kernel is not taken."""
+        from flexflow_tpu.ops.pallas import decode_kernel as dk
+
+        spec = self.cache.spec
+        heads = spec.num_heads
+        if self._head_shard is not None:
+            mesh, axis = self._head_shard
+            heads //= mesh.shape[axis]
+        self.kernel_block = None
+        if dk.use_kernel(
+            self.decode_kernel, 1, 0, spec.head_dim,
+            page_size=spec.page_size, kv_dtype=spec.kv_dtype, heads=heads,
+        ):
+            self.kernel_block = dk.paged_block(
+                1, heads, spec.head_dim, spec.page_size,
+                spec.max_pages_per_seq, spec.itemsize,
+            )
+        if self.telemetry is None or self.kernel_block is None:
+            return
+        for field, what in (
+            ("pages", "logical pages"),
+            ("rows", "cache rows"),
+            ("vmem_bytes", "VMEM bytes"),
+        ):
+            self.telemetry.registry.gauge(
+                f"serve_decode_kernel_block_{field}",
+                help=f"{what} the paged decode kernel handles at a time "
+                "(from the cache geometry)",
+            ).set(getattr(self.kernel_block, field))
 
     def _fall_back_to_dense(self, error) -> None:
         self.kernel_fallbacks += 1

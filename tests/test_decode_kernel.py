@@ -172,6 +172,219 @@ def test_paged_kernel_ignores_sentinel_pages():
     np.testing.assert_allclose(np.asarray(again), np.asarray(base), atol=2e-6)
 
 
+# -- the paged kernel's block: several pages a grid step ----------------------
+
+
+def _ref_paged(q, kp, vp, tbl, lens, allowed=None, k_scale=None, v_scale=None):
+    """What the paged kernels promise, in numpy float64: every slot's
+    allocated pages gathered, the staircase (or `allowed`) mask, keys of
+    unallocated pages invisible, a row that sees nothing zero."""
+    q, tbl, lens = (np.asarray(a) for a in (q, tbl, lens))
+    b, w, h, d = q.shape
+    num_pages, ps = kp.shape[0], kp.shape[1]
+    kp = np.asarray(kp, np.float64).reshape(num_pages, ps, h, d)
+    vp = np.asarray(vp, np.float64).reshape(num_pages, ps, h, d)
+    if k_scale is not None:
+        kp = kp * np.asarray(k_scale, np.float64)[:, None, :, None]
+        vp = vp * np.asarray(v_scale, np.float64)[:, None, :, None]
+    kv = tbl.shape[1] * ps
+    out = np.zeros(q.shape)
+    for i in range(b):
+        pages = np.minimum(tbl[i], num_pages - 1)
+        k = kp[pages].reshape(kv, h, d)
+        v = vp[pages].reshape(kv, h, d)
+        pos = np.arange(kv)
+        see = (
+            np.asarray(allowed[i]) > 0
+            if allowed is not None
+            else pos[None, :] <= lens[i] + np.arange(w)[:, None]
+        ) & np.repeat(tbl[i] < num_pages, ps)[None, :]
+        s = np.einsum("whd,khd->hwk", q[i], k) / np.sqrt(d)
+        s = np.where(see[None], s, -np.inf)
+        top = np.max(s, axis=-1, keepdims=True)
+        p = np.where(see[None], np.exp(s - np.where(np.isfinite(top), top, 0)), 0)
+        den = p.sum(-1, keepdims=True)
+        p = np.divide(p, den, out=np.zeros_like(p), where=den > 0)
+        out[i] = np.einsum("hwk,khd->whd", p, v)
+    return out
+
+
+@pytest.mark.parametrize(
+    "cell, want",
+    [
+        # (w, heads, head_dim, page_size, np_seq, itemsize) -> (pages, rows, heads a matmul)
+        pytest.param((1, 16, 64, 16, 64, 4), (8, 128, 16), id="gpt2_medium"),
+        pytest.param((1, 16, 128, 16, 64, 4), (8, 128, 16), id="olmoe"),
+        pytest.param((4, 16, 64, 16, 64, 4), (8, 128, 16), id="verify-w4"),
+        pytest.param((13, 16, 64, 16, 64, 4), (8, 128, 8), id="verify-w13"),
+        pytest.param((64, 16, 64, 16, 64, 4), (8, 128, 1), id="verify-w64"),
+        pytest.param((1, 16, 64, 32, 32, 1), (4, 128, 16), id="int8-ps32"),
+        pytest.param((1, 16, 64, 1024, 1, 4), (1, 1024, 16), id="one_page"),
+        pytest.param((1, 4, 8, 16, 2, 4), (2, 32, 4), id="short-table"),
+    ],
+)
+def test_paged_block_follows_from_shapes(cell, want):
+    """The block is a pure function of static shapes: pages a grid step,
+    rows a block, heads a matmul, and VMEM under the stated budget."""
+    blk = dk.paged_block(*cell)
+    assert (blk.pages, blk.rows, blk.heads) == want
+    assert blk.rows == blk.pages * cell[3]
+    assert blk.vmem_bytes <= dk._VMEM_BUDGET or blk.pages == 1
+    assert cell[1] % blk.heads == 0
+
+
+def _block_case(rng, w, lengths, h=2, d=64, ps=16, np_seq=20, dead=()):
+    """Slots whose pages are allocated up to lengths + w; `dead` slots
+    hold length 0 and an all-sentinel table."""
+    num_pages = len(lengths) * np_seq
+    q, kp, vp, tbl, lens = _paged_case(
+        rng, len(lengths), w, h, d, ps, num_pages, np_seq, lengths
+    )
+    tbl = np.asarray(tbl).copy()
+    tbl[list(dead)] = num_pages
+    return q, kp, vp, jnp.asarray(tbl), lens
+
+
+@pytest.mark.parametrize("block_rows", [64, 128])
+@pytest.mark.parametrize("w", [1, 4])
+def test_paged_blocks_match_dense_across_block_boundaries(
+    monkeypatch, w, block_rows
+):
+    """Lengths on, one before and one after a block boundary, a table
+    that is no whole number of blocks (20 pages in blocks of 4 or 16),
+    and a dead slot between live ones, which returns zeros."""
+    monkeypatch.setattr(dk, "_MAX_BLOCK_ROWS", block_rows)
+    rng = np.random.RandomState(5)
+    edge = block_rows if block_rows < 320 - 64 else 256
+    lengths = [edge - 1, 0, edge, 0, edge + 1, 320 - w]
+    q, kp, vp, tbl, lens = _block_case(rng, w, lengths, dead=(1, 3))
+    assert dk.paged_block(w, 2, 64, 16, 20, 4).rows == block_rows
+    kern = np.asarray(dk.paged_flash_verify(q, kp, vp, tbl, lens))
+    dense = np.asarray(paged_verify_attention(q, kp, vp, tbl, lens))
+    live = [0, 2, 4, 5]
+    np.testing.assert_allclose(kern[live], dense[live], atol=2e-6)
+    np.testing.assert_array_equal(kern[[1, 3]], 0.0)
+    np.testing.assert_allclose(
+        kern, _ref_paged(q, kp, vp, tbl, lens), atol=2e-6
+    )
+
+
+@pytest.mark.parametrize("w", [1, 4])
+def test_sentinel_page_inside_a_live_block_is_masked(w):
+    """A standalone caller's ragged table: an unallocated entry INSIDE
+    the visible prefix. Its keys are invisible (whatever the clamped
+    fetch brought), the block's other pages count."""
+    rng = np.random.RandomState(6)
+    q, kp, vp, tbl, lens = _block_case(rng, w, [100, 40])
+    tbl = np.asarray(tbl).copy()
+    num_pages = kp.shape[0]
+    tbl[0, 2] = num_pages
+    tbl[1, 0] = num_pages  # even the first page
+    tbl = jnp.asarray(tbl)
+    kern = dk.paged_flash_verify(q, kp, vp, tbl, lens)
+    np.testing.assert_allclose(
+        np.asarray(kern), _ref_paged(q, kp, vp, tbl, lens), atol=2e-6
+    )
+
+
+@pytest.mark.parametrize("block_rows", [64, 128])
+@pytest.mark.parametrize("w", [1, 4])
+def test_paged_tree_mask_follows_the_block(monkeypatch, w, block_rows):
+    """An arbitrary visibility mask over LOGICAL positions, regrouped to
+    the block's (w, rows) tiles, a table that is no whole number of
+    blocks."""
+    monkeypatch.setattr(dk, "_MAX_BLOCK_ROWS", block_rows)
+    rng = np.random.RandomState(7)
+    lengths = [70, 0, 250]
+    q, kp, vp, tbl, lens = _block_case(rng, w, lengths, dead=(1,))
+    kv = tbl.shape[1] * kp.shape[1]
+    pos = np.arange(kv)[None, None, :]
+    window = np.asarray(lengths)[:, None, None] + np.arange(w)[None, :, None]
+    allowed = (pos <= window) & (rng.rand(3, w, kv) < 0.7)
+    allowed[:, :, 0] = True
+    allowed = jnp.asarray(allowed.astype(np.float32))
+    kern = dk.paged_flash_verify_tree(q, kp, vp, tbl, lens, allowed)
+    np.testing.assert_allclose(
+        np.asarray(kern), _ref_paged(q, kp, vp, tbl, lens, allowed),
+        atol=2e-6,
+    )
+
+
+@pytest.mark.parametrize("tree", [False, True], ids=["stair", "tree"])
+@pytest.mark.parametrize("w", [1, 4])
+def test_paged_int8_blocks_match_dense_dequant(w, tree):
+    """int8 pools with 32-row pages and per-(page, head) scales: the
+    block dequantizes through its score and probability tiles and lands
+    where the dense path's dequantized gather does."""
+    rng = np.random.RandomState(8)
+    b, h, d, ps, np_seq = 3, 2, 64, 32, 10  # blocks of 4 pages, 3 blocks
+    num_pages = b * np_seq
+    lengths = [255, 0, 300]
+    q, _, _, tbl, lens = _paged_case(
+        rng, b, w, h, d, ps, num_pages, np_seq, lengths
+    )
+    kp = jnp.asarray(rng.randint(-127, 128, (num_pages, ps, h, d)).astype(np.int8))
+    vp = jnp.asarray(rng.randint(-127, 128, (num_pages, ps, h, d)).astype(np.int8))
+    ks = jnp.asarray((rng.rand(num_pages, h) * 0.02 + 0.001).astype(np.float32))
+    vs = jnp.asarray((rng.rand(num_pages, h) * 0.02 + 0.001).astype(np.float32))
+    assert dk.paged_block(w, h, d, ps, np_seq, 1).pages == 4
+    if tree:
+        kv = np_seq * ps
+        window = np.asarray(lengths)[:, None, None] + np.arange(w)[None, :, None]
+        allowed = (np.arange(kv)[None, None, :] <= window) & (
+            rng.rand(b, w, kv) < 0.7
+        )
+        allowed[:, :, 0] = True
+        allowed = jnp.asarray(allowed.astype(np.float32))
+        kern = dk.paged_flash_verify_tree_quant(
+            q, kp, vp, ks, vs, tbl, lens, allowed
+        )
+    else:
+        allowed = None
+        kern = dk.paged_flash_verify_quant(q, kp, vp, ks, vs, tbl, lens)
+        dense = paged_verify_attention(
+            q, kp, vp, tbl, lens, k_scale=ks, v_scale=vs, kernel="dense"
+        )
+        np.testing.assert_allclose(
+            np.asarray(kern), np.asarray(dense), atol=1e-5
+        )
+    np.testing.assert_allclose(
+        np.asarray(kern),
+        _ref_paged(q, kp, vp, tbl, lens, allowed, ks, vs),
+        atol=1e-5,
+    )
+
+
+@pytest.mark.parametrize("d", [64, 128], ids=["hd1024", "hd2048"])
+@pytest.mark.parametrize("w", [1, 4])
+def test_paged_blocks_at_the_cells_widths(d, w):
+    """16 heads of 64 and of 128 at page size 16: the two serving
+    cells' rows of 1024 and 2048 floats, all heads in one matmul."""
+    rng = np.random.RandomState(9)
+    lengths = [130, 0, 270 - w]
+    q, kp, vp, tbl, lens = _block_case(
+        rng, w, lengths, h=16, d=d, np_seq=17, dead=(1,)
+    )
+    assert dk.paged_block(w, 16, d, 16, 17, 4).heads == 16
+    kern = np.asarray(dk.paged_flash_verify(q, kp, vp, tbl, lens))
+    dense = np.asarray(paged_verify_attention(q, kp, vp, tbl, lens))
+    np.testing.assert_allclose(kern[[0, 2]], dense[[0, 2]], atol=2e-6)
+    np.testing.assert_array_equal(kern[1], 0.0)
+
+
+def test_paged_one_page_geometry_is_one_block():
+    """page_size == max_seq_len: one page a slot, one block, one grid
+    step a slot."""
+    rng = np.random.RandomState(10)
+    q, kp, vp, tbl, lens = _paged_case(rng, 3, 4, 2, 64, 64, 3, 1, [0, 17, 60])
+    assert dk.paged_block(4, 2, 64, 64, 1, 4).pages == 1
+    np.testing.assert_allclose(
+        np.asarray(dk.paged_flash_verify(q, kp, vp, tbl, lens)),
+        np.asarray(paged_verify_attention(q, kp, vp, tbl, lens)),
+        atol=2e-6,
+    )
+
+
 # -- supports() gate + mode resolution ----------------------------------------
 
 
@@ -198,6 +411,26 @@ def test_use_kernel_mode_resolution():
     assert not dk.use_kernel("pallas", 1, 64, 60)
     with pytest.raises(ValueError):
         dk.use_kernel("fast", 1, 64, 64)
+
+
+def test_compiled_paged_kernel_wants_whole_lane_rows(monkeypatch):
+    """Compiled, the paged kernel copies whole cache rows of heads x
+    head_dim out of the pool and Mosaic takes them in whole 128-lane
+    tiles only: a narrower row (a toy model's) is served dense on a TPU
+    and by the kernel under the interpreter."""
+    narrow = dict(page_size=16, heads=4)  # 4 heads of 8: 32 lanes
+    assert dk.use_kernel("pallas", 1, 0, 8, **narrow) == (
+        jax.default_backend() != "tpu"
+    )
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not dk.use_kernel("pallas", 1, 0, 8, **narrow)
+    assert not dk.use_kernel("auto", 4, 0, 8, **narrow)
+    assert dk.use_kernel("auto", 1, 0, 32, **narrow)  # one lane tile
+    assert dk.use_kernel("auto", 1, 0, 64, page_size=16, heads=16)
+    # a head shard's heads count, not the model's
+    assert not dk.use_kernel("auto", 1, 0, 64, page_size=16, heads=1)
+    # the contiguous kernel takes its rows through BlockSpecs
+    assert dk.use_kernel("auto", 1, 64, 8, heads=4)
 
 
 def test_tuned_chunk_installation():

@@ -16,7 +16,10 @@ back to dense) only on the chip. Two layers, both without a chip:
 
 Geometries: "smoke" is what the CPU serving tests build (2 layers,
 hidden 32, 4 heads), "flagship" the 1024-wide, 16-head, 512-token
-decoder chip_smoke.py serves."""
+decoder chip_smoke.py serves, "gpt2_medium" and "olmoe" the two serving
+cells of BENCHMARK.json as they run (16 slots, 1024 positions in pages
+of 16, pools of 8,192 and 16,384 tokens; paged entry points only: no
+cell serves the contiguous cache)."""
 
 import functools
 
@@ -34,7 +37,11 @@ from tests.conftest import page_geometry
 GEOMETRIES = {
     "smoke": (4, 4, 8, 32, (8, 16, 32), (1, 4)),
     "flagship": (8, 16, 64, 512, (16, 32, 128), (1, 5, 13)),
+    "gpt2_medium": (16, 16, 64, 1024, (16, 32), (1, 4)),
+    "olmoe": (16, 16, 128, 1024, (16, 32), (1, 4)),
 }
+# the serving cells' pools, in tokens (elsewhere: every slot's max_len)
+POOL_TOKENS = {"gpt2_medium": 8192, "olmoe": 16384}
 # (batch, seq, heads, head_dim) the training path hands the tiled kernel
 FLASH_SHAPES = {"smoke": (1, 256, 2, 8), "flagship": (8, 2048, 16, 64)}
 
@@ -53,18 +60,19 @@ def _decode_cases(geom):
         q = _sds((b, w, h, d))
         allowed = _sds((b, w, max_len))
         entry = dk.flash_decode if w == 1 else dk.flash_verify
-        yield (
-            f"{geom}-{entry.__name__}-w{w}",
-            functools.partial(entry, **run),
-            (q, cache, cache, lens),
-        )
-        yield (
-            f"{geom}-flash_verify_tree-w{w}",
-            functools.partial(dk.flash_verify_tree, **run),
-            (q, cache, cache, lens, allowed),
-        )
+        if geom not in POOL_TOKENS:
+            yield (
+                f"{geom}-{entry.__name__}-w{w}",
+                functools.partial(entry, **run),
+                (q, cache, cache, lens),
+            )
+            yield (
+                f"{geom}-flash_verify_tree-w{w}",
+                functools.partial(dk.flash_verify_tree, **run),
+                (q, cache, cache, lens, allowed),
+            )
         for ps in page_sizes:
-            pages = b * max_len // ps
+            pages = POOL_TOKENS.get(geom, b * max_len) // ps
             tables = _sds((b, max_len // ps), jnp.int32)
             pool = _sds((pages, ps, h, d))
             entry = dk.paged_flash_decode if w == 1 else dk.paged_flash_verify
@@ -128,7 +136,10 @@ def _flash_cases(geom):
 CASES = [
     c
     for geom in GEOMETRIES
-    for c in (*_decode_cases(geom), *_flash_cases(geom))
+    for c in (
+        *_decode_cases(geom),
+        *(_flash_cases(geom) if geom in FLASH_SHAPES else ()),
+    )
 ]
 
 
@@ -204,7 +215,7 @@ def _compile_for(devices, fn, shapes, spec=None):
     )
 
 
-FLAGSHIP = [c for c in CASES if c[0].startswith("flagship")]
+FLAGSHIP = [c for c in CASES if not c[0].startswith("smoke")]
 
 
 @pytest.mark.parametrize("case", FLAGSHIP, ids=_ids(FLAGSHIP))
@@ -214,6 +225,27 @@ def test_mosaic_compiles_flagship(case):
         pytest.skip("libtpu cannot describe a v5e topology here")
     _, fn, shapes = case
     _compile_for(devices, fn, shapes)
+
+
+PAGED_BLOCKS = [
+    pytest.param(w, h, d, ps, max_len // ps, itemsize,
+                 id=f"{geom}-ps{ps}-w{w}-{itemsize}B")
+    for geom, (_, h, d, max_len, page_sizes, widths) in GEOMETRIES.items()
+    for ps in page_sizes
+    for w in (*widths, dk._MAX_TREE_W, dk._MAX_W)
+    for itemsize in ((4, 1) if ps % dk._INT8_SUBLANES == 0 else (4,))
+]
+
+
+@pytest.mark.parametrize("w, h, d, ps, np_seq, itemsize", PAGED_BLOCKS)
+def test_paged_block_fits_the_stated_vmem_budget(w, h, d, ps, np_seq, itemsize):
+    """The pages a grid step takes follow from shapes; what they need in
+    VMEM, by the kernel's own count, is under the budget the code states
+    (the compiles above hold Mosaic's count under the chip's limit)."""
+    blk = dk.paged_block(w, h, d, ps, np_seq, itemsize)
+    assert 1 <= blk.pages <= np_seq and blk.rows == blk.pages * ps
+    assert blk.vmem_bytes <= dk._VMEM_BUDGET
+    assert h % blk.heads == 0 and w * blk.heads <= max(w, dk._MAX_Q_ROWS)
 
 
 def test_mosaic_compiles_ring_body(as_tpu):
@@ -300,7 +332,9 @@ def test_mosaic_compiles_head_sharded_kernel(as_tpu):
 
 # -- the engine's step programs own the pools they rewrite --------------------
 
-from test_pool_donation import KINDS, PROMPT, _step, lm  # noqa: E402,F401
+from test_pool_donation import (  # noqa: E402,F401
+    KINDS, PROMPT, _step, build_lm, lm,
+)
 
 
 @pytest.fixture
@@ -391,8 +425,19 @@ def test_int8_step_program_donates_its_scale_pools_too(lm, step_programs):
     assert sorted({str(a.dtype) for a in donated}) == ["float32", "int8"]
 
 
+@pytest.fixture(scope="module")
+def lm_lane_rows():
+    """`lm` with cache rows of one whole lane tile (4 heads of 32): the
+    narrowest row the COMPILED paged kernel copies out of a pool
+    (decode_kernel.use_kernel), so the decode program below holds the
+    Mosaic kernel as it does on the chip."""
+    return build_lm(hidden=128)
+
+
 @pytest.mark.parametrize("kind", ["prefill", "decode"])
-def test_compiled_step_program_aliases_its_pools(lm, step_programs, kind):
+def test_compiled_step_program_aliases_its_pools(
+    lm_lane_rows, step_programs, kind
+):
     """Compiled for a v5e, the program's outputs alias at least the pools'
     bytes, and it converts no pool to another layout (`copy`; a
     `copy-start` prefetch of these tiny pools into fast memory is not
@@ -403,6 +448,8 @@ def test_compiled_step_program_aliases_its_pools(lm, step_programs, kind):
     if devices is None:
         pytest.skip("libtpu cannot describe a v5e topology here")
     from flexflow_tpu.serving import ServeConfig, build_scheduler
+
+    lm = lm_lane_rows
 
     sched, eng, cache = build_scheduler(
         lm,
@@ -422,6 +469,7 @@ def test_compiled_step_program_aliases_its_pools(lm, step_programs, kind):
     compiled = (
         jitted.trace(*placed).lower(lowering_platforms=("tpu",)).compile()
     )
+    assert ("tpu_custom_call" in compiled.as_text()) == (kind == "decode")
     pool = next(iter(cache.k.values()))
     pool_bytes = sum(
         s.size * s.dtype.itemsize for s in jax.tree.leaves((cache.k, cache.v))

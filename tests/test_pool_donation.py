@@ -43,14 +43,13 @@ LAYOUTS = [
 ]
 
 
-@pytest.fixture(scope="module")
-def lm():
+def build_lm(hidden=32):
     cfg = FFConfig(batch_size=4, seed=0)
     model = FFModel(cfg)
     tok = model.create_tensor([4, 32], dtype=DataType.INT32, name="tokens")
     build_decoder_lm(
-        model, tok, vocab_size=VOCAB, hidden=32, num_heads=4, num_layers=2,
-        ff_dim=64,
+        model, tok, vocab_size=VOCAB, hidden=hidden, num_heads=4,
+        num_layers=2, ff_dim=64,
     )
     model.compile(
         optimizer=SGDOptimizer(lr=0.01),
@@ -59,6 +58,11 @@ def lm():
         devices=jax.devices()[:1],
     )
     return model
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return build_lm()
 
 
 def _pool_leaves(cache):

@@ -572,6 +572,34 @@ def test_every_injected_fault_surfaces_in_metrics(lm):
     assert sched.injector is injector
 
 
+@pytest.mark.parametrize("mode", ["pallas", "dense"])
+def test_kernel_block_geometry_is_published_once(lm, mode):
+    """The paged kernel's block (a pure function of the cache geometry)
+    is a gauge beside the fallback counter where the kernel is taken,
+    and absent where it is not."""
+    from flexflow_tpu.ops.pallas import decode_kernel as dk
+
+    sched, engine, cache = build_scheduler(
+        lm, _serve(telemetry=True, decode_kernel=mode)
+    )
+    reg = sched.telemetry.registry
+    names = [f"serve_decode_kernel_block_{f}" for f in
+             ("pages", "rows", "vmem_bytes")]
+    if mode == "dense":
+        assert engine.kernel_block is None
+        assert all(reg.get(n) is None for n in names)
+        return
+    spec = cache.spec
+    want = dk.paged_block(
+        1, spec.num_heads, spec.head_dim, spec.page_size,
+        spec.max_pages_per_seq, spec.itemsize,
+    )
+    assert engine.kernel_block == want
+    assert [reg.get(n).value for n in names] == [
+        want.pages, want.rows, want.vmem_bytes
+    ]
+
+
 def test_kernel_fallback_surfaces_in_metrics_and_trace(lm):
     injector = FaultInjector(FaultPlan(kernel_iters=(1,)), seed=0)
     serve = _serve(telemetry=True, decode_kernel="pallas")
