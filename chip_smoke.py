@@ -256,7 +256,34 @@ def phase_kernels() -> dict:
         p = np.array([-1] + [(j - 1) // 2 for j in range(1, w)], np.int32)
         return jnp.asarray(np.tile(p, (b, 1)))
 
+    def latent_case(slots=16, heads=32, row=640, v_width=512, ps=16, max_pos=2048):
+        """The latent pool at the geometry `serve_kanana2_assist` serves:
+        every head reads the same rows of 640 and the values are a row's
+        first 512 lanes, in blocks of 128 rows. Slots of one row, of less
+        than a block, of many blocks and of every position, with idle
+        slots between live ones (-1: no page allocated)."""
+        lens = np.array(
+            [0, 700, -1, 127, 128, 1663, -1, -1, 15, 2047, 300, 16, -1, 1024,
+             129, 5], np.int32,
+        )
+        np_seq, pages = max_pos // ps, slots * max_pos // ps
+        tbl = rng.permutation(pages).reshape(slots, np_seq).astype(np.int32)
+        held = -(-(lens + 1) // ps)  # pages that hold rows 0 .. length
+        tbl[np.arange(np_seq)[None, :] >= held[:, None]] = pages
+        return (
+            arr(slots, 1, heads, row), arr(pages, ps, row), jnp.asarray(tbl),
+            jnp.asarray(np.maximum(lens, 0)), v_width, 192 ** -0.5,
+        )
+
     with highest():
+        ql, *latent = latent_case()
+        got = dk.paged_flash_decode_latent(ql, *latent)
+        want = A.paged_latent_decode_attention(ql, *latent, kernel="dense")
+        live = np.asarray(latent[1])[:, 0] < latent[0].shape[0]
+        close(
+            "paged_flash_decode_latent/served_geometry",
+            got[live], want[live], 1e-4,
+        )
         for w in (1, 5, 13):
             q = arr(b, w, h, d)
             k, v = arr(b, max_len, h, d), arr(b, max_len, h, d)

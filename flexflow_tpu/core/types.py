@@ -155,6 +155,11 @@ class OperatorType(enum.Enum):
     # expert, grouped gated MLP, weighted sum — ops/moe.py)
     RMSNORM = enum.auto()
     SPARSE_MOE = enum.auto()
+    # latent attention (MLA: keys and values decompressed from one cached
+    # latent row a token, ops/attention.py) and the SiLU-gated MLP as one
+    # operator (ops/core_ops.py)
+    LATENT_ATTENTION = enum.auto()
+    GATED_MLP = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

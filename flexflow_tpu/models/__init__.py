@@ -14,6 +14,7 @@ from flexflow_tpu.models.vision import (
 from flexflow_tpu.models.nlp import (
     build_bert_proxy,
     build_decoder_lm,
+    build_deepseek_v3,
     build_mt5_encoder,
     build_olmoe,
     build_transformer_encoder,
@@ -31,6 +32,7 @@ __all__ = [
     "build_bert_proxy",
     "build_decoder_lm",
     "build_mt5_encoder",
+    "build_deepseek_v3",
     "build_olmoe",
     "build_dlrm",
     "build_xdl",

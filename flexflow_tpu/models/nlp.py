@@ -134,3 +134,59 @@ def build_olmoe(
         )
         t = ff.add(t, m)
     return ff.dense(ff.rms_norm(t, eps=eps), vocab_size, use_bias=False)
+
+
+def build_deepseek_v3(
+    ff,
+    token_ids,
+    vocab_size: int = 129280,
+    hidden: int = 7168,
+    num_heads: int = 128,
+    num_layers: int = 61,
+    kv_lora_rank: int = 512,
+    qk_nope_head_dim: int = 128,
+    qk_rope_head_dim: int = 64,
+    v_head_dim: int = 128,
+    dense_hidden: int = 18432,
+    dense_layers: int = 3,
+    expert_hidden: int = 2048,
+    num_experts: int = 256,
+    experts_per_token: int = 8,
+    shared_experts: int = 1,
+    routed_scale: float = 2.5,
+    rope_theta: float = 10000.0,
+    eps: float = 1e-6,
+    renormalise: bool = True,
+    experts_held=None,
+):
+    """The `deepseek_v3` architecture (DeepSeek-V3 and the models that
+    publish under its model_type, Kanana-2-30B-A3B among them) without
+    query compression (q_lora_rank null): pre-RMSNorm blocks of latent
+    attention, then a SiLU-gated MLP in the first `dense_layers` layers and
+    after them a sigmoid-routed top-k expert layer (a per-expert bias moves
+    the choice only; weights renormalised and scaled) plus the shared
+    experts, which are ONE gated MLP of shared_experts x expert_hidden; a
+    final RMSNorm and an untied head. No biases. `experts_held` (first,
+    count): this chip's share of each expert layer (FFModel.sparse_moe);
+    a sliced vocabulary is simply a smaller `vocab_size`. Served like
+    build_decoder_lm: vocab logits, one token-id input."""
+    t = ff.embedding(token_ids, vocab_size, hidden)
+    for layer in range(num_layers):
+        a = ff.latent_attention(
+            ff.rms_norm(t, eps=eps), hidden, num_heads, kv_lora_rank,
+            qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+            rope_theta=rope_theta, eps=eps,
+        )
+        t = ff.add(t, a)
+        m = ff.rms_norm(t, eps=eps)
+        if layer < dense_layers:
+            t = ff.add(t, ff.gated_mlp(m, dense_hidden))
+            continue
+        routed = ff.sparse_moe(
+            m, num_experts, experts_per_token, expert_hidden,
+            renormalise=renormalise, scoring="sigmoid", choice_bias=True,
+            scale=routed_scale, experts_held=experts_held,
+        )
+        shared = ff.gated_mlp(m, shared_experts * expert_hidden)
+        t = ff.add(t, ff.add(routed, shared))
+    return ff.dense(ff.rms_norm(t, eps=eps), vocab_size, use_bias=False)

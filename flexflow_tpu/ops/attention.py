@@ -1100,3 +1100,210 @@ def _flops_mha(input_shapes, params):
 
 
 register_op(OperatorType.MULTIHEAD_ATTENTION, _infer_mha, _lower_mha, _flops_mha)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA: DeepSeek-V2/V3 and the models built on them). A
+# token's keys and values of every head are decompressed from ONE latent
+# row [c | kr]: c the normalised down-projection (kv_lora_rank wide), kr
+# one rotary key shared by all heads. That row is all a cache holds. Two
+# computations of one function: DECOMPRESSED (k_h = [Wuk_h c | kr], v_h =
+# Wuv_h c, then plain causal attention: the lowering below, and a serving
+# prefill), and ABSORBED (Wuk folded into the query and Wuv applied after
+# the softmax, so that attention runs over the latent rows themselves: a
+# serving decode step, over the paged latent pool). No reference
+# counterpart. Weights: Wq [e, h, nope + rope], Wkva [e, rank + rope], the
+# gain [rank] of c's RMSNorm, Wkvb [rank, h, nope + v] = [Wuk | Wuv],
+# Wo [h, v, e]; no biases, no query compression (q_lora_rank null).
+# ---------------------------------------------------------------------------
+
+
+def _mla_dims(params):
+    return (
+        params["kv_lora_rank"], params["qk_nope_head_dim"],
+        params["qk_rope_head_dim"], params["v_head_dim"],
+    )
+
+
+def mla_cache_row(params) -> int:
+    """Width of the cache row a latent-attention node keeps of a token:
+    [c | kr] (rank + rope), padded with zeros to whole 128-lane tiles,
+    because the paged kernel copies whole rows out of the pool and Mosaic
+    takes such a copy only in whole tiles (512 + 64 -> 640)."""
+    r, _, dr, _ = _mla_dims(params)
+    return -(-(r + dr) // 128) * 128
+
+
+def _infer_latent_attention(input_shapes, params):
+    (x,) = input_shapes
+    e, h = params["embed_dim"], params["num_heads"]
+    r, dn, dr, dv = _mla_dims(params)
+    if any(d.is_replica_dim for d in x.dims) or any(
+        d.degree > 1 for d in x.dims[1:]
+    ):
+        raise ValueError(
+            "latent_attention: only the batch dim may be partitioned (the "
+            "latent row has one head: there is nothing to shard by heads)"
+        )
+    if x.dims[-1].size != e or dr % 2:
+        raise ValueError(
+            f"latent_attention: input width {x.dims[-1].size} != {e}, or an "
+            f"odd rotary width {dr}"
+        )
+    dt = x.dtype
+
+    def shape(*sizes):
+        return ParallelTensorShape(tuple(ParallelDim(s) for s in sizes), dt)
+
+    weights = (
+        shape(e, h, dn + dr), shape(e, r + dr), shape(r),
+        shape(r, h, dn + dv), shape(h, dv, e),
+    )
+    return (x,), weights
+
+
+def rope_interleaved(x, positions, theta):
+    """Rotary positions in the interleaved form, over the last dim of x
+    [b, s, h, d]: elements (2i, 2i + 1) rotate together by positions *
+    theta ** (-2 i / d). (The checkpoints' own code de-interleaves and
+    then rotates halves: the same dot products q . k.) positions [s] or
+    [b, s]; float32 inside."""
+    d = x.shape[-1]
+    cos, sin = rotary_cos_sin(positions, d, float(theta))
+    cos, sin = cos[..., : d // 2], sin[..., : d // 2]
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return turned.reshape(x.shape).astype(x.dtype)
+
+
+def mla_project(x, ws, params, ctx, positions=None):
+    """x [b, s, e] -> (q_nope [b, s, h, nope], q_rope [b, s, h, rope],
+    latent [b, s, rank + rope]): the query of every head, its rotary part
+    rotated, and the token's latent row [c | kr], c normalised and kr
+    rotated, as a cache keeps it. `positions` as in mha_qk_positions."""
+    r, dn, _, _ = _mla_dims(params)
+    with jax.named_scope("mla.project"):
+        xm, wq, wkva = mm_operands(ctx, x, ws[0], ws[1])
+        cdt = xm.dtype
+        mm = dict(preferred_element_type=jnp.float32)
+        q = jnp.einsum("bse,ehd->bshd", xm, wq, **mm).astype(cdt)
+        kva = jnp.einsum("bse,er->bsr", xm, wkva, **mm).astype(cdt)
+        from flexflow_tpu.ops.core_ops import rms_normalize
+
+        c = rms_normalize(kva[..., :r], ws[2], params.get("eps", 1e-6))
+        if positions is None:
+            positions = jnp.arange(x.shape[1])
+        theta = params["rope_theta"]
+        q_rope = rope_interleaved(q[..., dn:], positions, theta)
+        kr = rope_interleaved(kva[..., None, r:], positions, theta)[..., 0, :]
+        return q[..., :dn], q_rope, jnp.concatenate([c, kr], axis=-1)
+
+
+def mla_decompressed(q_nope, q_rope, latent, ws, params, ctx):
+    """Causal attention of every position over those before it, keys and
+    values decompressed from `latent` [b, s, rank + rope] -> [b, s, h, v].
+    The scale is 1 / sqrt(nope + rope)."""
+    r, dn, _, _ = _mla_dims(params)
+    with jax.named_scope("mla.project"):
+        c, wkvb = mm_operands(ctx, latent[..., :r], ws[3])
+        kv = jnp.einsum(
+            "bsr,rhd->bshd", c, wkvb, preferred_element_type=jnp.float32
+        ).astype(q_nope.dtype)
+        kr = jnp.broadcast_to(
+            latent[:, :, None, r:].astype(q_nope.dtype),
+            kv.shape[:3] + (latent.shape[-1] - r,),
+        )
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :dn], kr], axis=-1)
+    with jax.named_scope("mla.attend"):
+        return scaled_dot_product_attention(q, k, kv[..., dn:], causal=True)
+
+
+def mla_absorb_query(q_nope, q_rope, ws, params, ctx, row):
+    """The query against latent rows: [Wuk_h^T q_nope_h | q_rope_h | 0]
+    [b, s, h, row], `row` the (padded) width of a cache row."""
+    r, dn, dr, _ = _mla_dims(params)
+    with jax.named_scope("mla.absorb"):
+        qn, wuk = mm_operands(ctx, q_nope, ws[3][..., :dn])
+        q_lat = jnp.einsum(
+            "bshd,rhd->bshr", qn, wuk, preferred_element_type=jnp.float32
+        ).astype(q_nope.dtype)
+        pad = jnp.zeros(q_rope.shape[:-1] + (row - r - dr,), q_nope.dtype)
+        return jnp.concatenate([q_lat, q_rope, pad], axis=-1)
+
+
+def mla_absorb_values(attended, ws, params, ctx):
+    """attended [b, s, h, rank] (the softmax-weighted sum of latent c
+    rows) -> each head's values Wuv_h (.) [b, s, h, v]."""
+    _, dn, _, _ = _mla_dims(params)
+    with jax.named_scope("mla.absorb"):
+        a, wuv = mm_operands(ctx, attended, ws[3][..., dn:])
+        return jnp.einsum(
+            "bshr,rhd->bshd", a, wuv, preferred_element_type=jnp.float32
+        ).astype(attended.dtype)
+
+
+def mla_project_out(attn, ws, ctx, out_dtype):
+    """attn [b, s, h, v] -> [b, s, e]."""
+    with jax.named_scope("mla.out"):
+        a, wo = mm_operands(ctx, attn, ws[4])
+        return jnp.einsum(
+            "bshd,hde->bse", a, wo, preferred_element_type=jnp.float32
+        ).astype(mm_out_dtype(ctx, out_dtype))
+
+
+def paged_latent_decode_attention(
+    q, pool, block_tables, lengths, value_width, sm_scale, kernel="auto"
+):
+    """One query position a slot over a paged LATENT pool: q [b, 1, h, row]
+    (mla_absorb_query), pool [num_pages, page_size, row], every head
+    reading the same rows; the values are a row's first `value_width`
+    lanes. Returns [b, 1, h, value_width]. block_tables and lengths as in
+    paged_decode_attention; the Pallas kernel where the geometry takes it
+    (ops/pallas/decode_kernel.paged_flash_decode_latent), else the dense
+    gather, which is also what the CPU serves."""
+    from flexflow_tpu.ops.pallas import decode_kernel as dk
+
+    with jax.named_scope("mla.attend"):
+        if dk.supports_latent(kernel, q.shape[-1], pool.shape[1]):
+            return dk.paged_flash_decode_latent(
+                q, pool, block_tables, lengths, value_width, sm_scale
+            )
+        b = q.shape[0]
+        tbl = jnp.minimum(block_tables, pool.shape[0] - 1)
+        rows = pool[tbl].reshape(b, -1, pool.shape[-1])  # [b, max_len, row]
+        scores = jnp.einsum(
+            "bhr,bkr->bhk", q[:, 0], rows, preferred_element_type=jnp.float32
+        ) * sm_scale
+        seen = jnp.arange(rows.shape[1])[None, None, :] <= lengths[:, None, None]
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+        return jnp.einsum(
+            "bhk,bkr->bhr", probs.astype(q.dtype), rows[..., :value_width]
+        )[:, None]
+
+
+def _lower_latent_attention(params):
+    def fn(ins, ws, ctx):
+        (x,) = ins
+        q_nope, q_rope, latent = mla_project(x, ws, params, ctx)
+        attn = mla_decompressed(q_nope, q_rope, latent, ws, params, ctx)
+        return [mla_project_out(attn, ws, ctx, x.dtype)]
+
+    return fn
+
+
+def _flops_latent_attention(input_shapes, params):
+    (x,) = input_shapes
+    b, s, e = x.logical_sizes[-3:]
+    h = params["num_heads"]
+    r, dn, dr, dv = _mla_dims(params)
+    proj = e * (h * (dn + dr) + r + dr) + r * h * (dn + dv) + h * dv * e
+    attn = s * h * (dn + dr + dv)
+    return 2.0 * b * s * (proj + attn)
+
+
+register_op(
+    OperatorType.LATENT_ATTENTION, _infer_latent_attention,
+    _lower_latent_attention, _flops_latent_attention,
+)

@@ -452,6 +452,51 @@ register_op(OperatorType.RMSNORM, _infer_rmsnorm, _lower_rmsnorm, _flops_rmsnorm
 
 
 # ---------------------------------------------------------------------------
+# GatedMLP: down(silu(gate x) * (up x)), the feed-forward block of the
+# decoder LMs people deploy (no reference counterpart), as ONE operator
+# ---------------------------------------------------------------------------
+
+
+def _infer_gated_mlp(input_shapes, params):
+    (x,) = input_shapes
+    d = x.dims[-1]
+    if d.degree > 1:
+        raise ValueError("gated_mlp: the feature dim may not be partitioned")
+    f = params["width"]
+    w_in = ParallelTensorShape((ParallelDim(d.size), ParallelDim(f)), x.dtype)
+    w_out = ParallelTensorShape((ParallelDim(f), ParallelDim(d.size)), x.dtype)
+    return (x,), (w_in, w_in, w_out)
+
+
+def gated_mlp(x, ws, ctx=None):
+    """x [*lead, d] -> [*lead, d] from gate / up [d, f] and down [f, d]."""
+    w_gate, w_up, w_down = ws
+    xm, w_gate, w_up = mm_operands(ctx, x, w_gate, w_up)
+    mm = dict(preferred_element_type=jnp.float32)
+    hidden = (
+        jax.nn.silu(jnp.matmul(xm, w_gate, **mm)) * jnp.matmul(xm, w_up, **mm)
+    ).astype(x.dtype)
+    hidden, w_down = mm_operands(ctx, hidden, w_down)
+    return jnp.matmul(hidden, w_down, **mm).astype(mm_out_dtype(ctx, x.dtype))
+
+
+def _lower_gated_mlp(params):
+    return lambda ins, ws, ctx: [gated_mlp(ins[0], ws, ctx)]
+
+
+def _flops_gated_mlp(input_shapes, params):
+    (x,) = input_shapes
+    d = x.logical_sizes[-1]
+    return 3 * 2.0 * (x.volume() // d) * d * params["width"]
+
+
+register_op(
+    OperatorType.GATED_MLP, _infer_gated_mlp, _lower_gated_mlp,
+    _flops_gated_mlp,
+)
+
+
+# ---------------------------------------------------------------------------
 # Embedding (reference: src/ops/embedding.cc) — key DLRM op
 # ---------------------------------------------------------------------------
 

@@ -223,10 +223,19 @@ def _infer_sparse_moe(input_shapes, params):
     d = x.dims[-1]
     if d.degree > 1:
         raise ValueError("sparse_moe: the feature dim may not be partitioned")
+    held = params.get("experts_held")
+    if held is not None:
+        first, count = held
+        if r_deg > 1 or not (0 <= first and count >= 1 and first + count <= n):
+            raise ValueError(
+                f"sparse_moe: experts_held {held} must lie inside the {n} "
+                "experts, on a layer that is not sharded over a mesh axis too"
+            )
     # the replica-dim protocol of attention's heads: a replicated input
     # shards the stacked experts, each shard sums its own experts' rows,
-    # and the output's replica dim is folded by a downstream Reduction
-    expert = ParallelDim(n, r_deg, r_idx)
+    # and the output's replica dim is folded by a downstream Reduction.
+    # A layer that holds a share stacks only the experts it holds
+    expert = ParallelDim(n if held is None else held[1], r_deg, r_idx)
     router = ParallelTensorShape((ParallelDim(d.size), ParallelDim(n)), x.dtype)
     w_in = ParallelTensorShape(
         (expert, ParallelDim(d.size), ParallelDim(f)), x.dtype
@@ -234,38 +243,82 @@ def _infer_sparse_moe(input_shapes, params):
     w_out = ParallelTensorShape(
         (expert, ParallelDim(f), ParallelDim(d.size)), x.dtype
     )
-    return (x,), (router, w_in, w_in, w_out)
+    weights = [router, w_in, w_in, w_out]
+    if params.get("choice_bias", False):
+        weights.append(ParallelTensorShape((ParallelDim(n),), x.dtype))
+    return (x,), tuple(weights)
 
 
-def sparse_moe_route(x2, router, k, renormalise):
+def sparse_moe_route(
+    x2, router, k, renormalise, scoring="softmax", bias=None, scale=1.0
+):
     """x2 [tokens, d] -> (weights [tokens, k] float32, experts [tokens, k]
-    int32). The router matmul and its softmax run in float32 at `highest`
-    whatever the model's precision: a top-k choice flips on rounding, and
-    a [d, experts] matmul costs nothing beside the experts."""
+    int32). The router matmul and its softmax (or sigmoid) run in float32
+    at `highest` whatever the model's precision: a top-k choice flips on
+    rounding, and a [d, experts] matmul costs nothing beside the experts.
+    `bias` [experts] is added to the scores for the CHOICE only: the
+    weights are the chosen experts' own scores, divided by their sum if
+    asked and multiplied by `scale`."""
     logits = jnp.dot(
         x2.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"sparse_moe: scoring {scoring!r} is not softmax|sigmoid")
+    if bias is None:
+        weights, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if renormalise:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32)
 
 
-def sparse_moe(x, ws, params, ctx=None):
+def sparse_moe(x, ws, params, ctx=None, live=None, chosen=None):
     """The layer on global logical arrays: x [*lead, d] -> (y [*lead, d],
-    counts [2] int32 = (rows computed, distinct experts with a row))."""
-    router, w_gate, w_up, w_down = ws
+    counts int32). counts is (rows computed, distinct experts with a row),
+    and for a layer that holds a share of the experts (`experts_held` =
+    (first, count): the stacked weights are those `count` experts) a third:
+    the rows routed to experts it does not hold. Such rows are sorted
+    behind every group, so the grouped matmuls leave them out, and they
+    add nothing to y: what the absent experts would have given is another
+    chip's to compute, and nothing here stands in for it. `live` [*lead]
+    bool, for such a layer: the tokens that are someone's (a serving
+    step's other rows are padding: idle slots, positions past a prompt),
+    and only their rows count as absent. `chosen`: a list that receives
+    the router's choice, experts [*lead, k] int32 in the router's own
+    numbering."""
+    router, w_gate, w_up, w_down = ws[:4]
     n, k = params["num_experts"], params["k"]
+    held = params.get("experts_held")
     lead, d = x.shape[:-1], x.shape[-1]
     x2 = x.reshape(-1, d)
     tokens = x2.shape[0]
     with jax.named_scope("moe.route"):
         weights, experts = sparse_moe_route(
-            x2, router, k, params.get("renormalise", False)
+            x2, router, k, params.get("renormalise", False),
+            params.get("scoring", "softmax"),
+            ws[4] if params.get("choice_bias", False) else None,
+            params.get("scale", 1.0),
         )
+        if chosen is not None:
+            chosen.append(experts.reshape(lead + (k,)))
     with jax.named_scope("moe.sort"):
         flat = experts.reshape(-1)  # row r is token r // k, choice r % k
+        if held is not None:
+            # the held experts' own numbering; every other expert is one
+            # group past the last, which `group_sizes` does not have (an
+            # out-of-bounds add is dropped)
+            n = held[1]
+            here = (flat >= held[0]) & (flat < held[0] + n)
+            flat = jnp.where(here, flat - held[0], n)
         order = jnp.argsort(flat, stable=True)
         group_sizes = jnp.zeros((n,), jnp.int32).at[flat].add(1)
         rows = x2[order // k]  # [tokens * k, d], grouped by expert
@@ -281,12 +334,23 @@ def sparse_moe(x, ws, params, ctx=None):
         # unsort by the inverse permutation: row r again belongs to token
         # r // k, and a token's k rows are summed under its gate weights
         back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0]))
-        y = jnp.sum(
-            out[back].reshape(tokens, k, d) * weights[..., None], axis=1
-        ).astype(mm_out_dtype(ctx, x.dtype))
-    counts = jnp.stack(
-        [jnp.int32(tokens * k), jnp.sum(group_sizes > 0, dtype=jnp.int32)]
-    )
+        picked = out[back].reshape(tokens, k, d)
+        if held is not None:
+            # a row no group covers holds whatever the kernel left there
+            picked = jnp.where(here.reshape(tokens, k, 1), picked, 0.0)
+        y = jnp.sum(picked * weights[..., None], axis=1).astype(
+            mm_out_dtype(ctx, x.dtype)
+        )
+    touched = jnp.sum(group_sizes > 0, dtype=jnp.int32)
+    if held is None:
+        counts = jnp.stack([jnp.int32(tokens * k), touched])
+    else:
+        absent = ~here.reshape(tokens, k)
+        if live is not None:
+            absent = absent & live.reshape(tokens, 1)
+        counts = jnp.stack(
+            [jnp.sum(group_sizes), touched, jnp.sum(absent, dtype=jnp.int32)]
+        )
     return y.reshape(lead + (d,)), counts
 
 
@@ -302,7 +366,9 @@ def _flops_sparse_moe(input_shapes, params):
     d = x.logical_sizes[-1]
     tokens = x.volume() // d
     n, k, f = params["num_experts"], params["k"], params["expert_hidden"]
-    return 2.0 * tokens * d * n + 3 * 2.0 * tokens * k * d * f
+    held = params.get("experts_held")
+    share = 1.0 if held is None else held[1] / n  # of the rows, on average
+    return 2.0 * tokens * d * n + 3 * 2.0 * tokens * k * share * d * f
 
 
 register_op(

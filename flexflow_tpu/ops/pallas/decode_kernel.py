@@ -552,6 +552,63 @@ def _own_head(group: int, head_dim: int):
     return (lane >= lo) & (lane < lo + head_dim)
 
 
+def _page_copies(tbl_ref, pools, pages, page_size, num_pages):
+    """`copies(slot, block, buf)` of a paged kernel: the async copies of
+    a block's pages, page by page as the table names them and pool by
+    pool within a page, into buffer `buf`. `pools`: for each pool (the
+    pool in HBM, its VMEM buffer [2, rows, row], buf -> its semaphore).
+    Sentinel entries clamp to a real page (their keys are masked)."""
+
+    def copies(slot, block, buf):
+        out = []
+        for j in range(pages):
+            page = jnp.minimum(tbl_ref[slot, block * pages + j], num_pages - 1)
+            dst = pl.ds(j * page_size, page_size)
+            for hbm, vmem, sem_of in pools:
+                out.append(pltpu.make_async_copy(
+                    hbm.at[page], vmem.at[buf, dst], sem_of(buf)
+                ))
+        return out
+
+    return copies
+
+
+def _fetch_ahead(copies, ib, i, n_blocks, before, next_live):
+    """Inside slot `ib`'s block loop: start the copies of the block after
+    its block `i` into the other buffer (the slot's next block, or the
+    next live slot's first), wait for block `i`'s, and return the buffer
+    they filled. Blocks alternate buffers across the whole call."""
+    buf = (before + i) % 2
+
+    @pl.when(i + 1 < n_blocks)
+    def _next_block():
+        for c in copies(ib, i + 1, 1 - buf):
+            c.start()
+
+    @pl.when((i + 1 == n_blocks) & (next_live < pl.num_programs(0)))
+    def _next_slot():
+        for c in copies(next_live, 0, 1 - buf):
+            c.start()
+
+    for c in copies(ib, i, buf):
+        c.wait()
+    return buf
+
+
+def _allocated(tbl_ref, ib, i, col, pages, page_size, num_pages):
+    """(1, rows) bool: the columns of slot `ib`'s block `i` whose page is
+    allocated (a standalone caller's ragged table can leave a hole inside
+    a live block; the engine's unallocated pages sit past the length)."""
+    return functools.reduce(
+        jnp.logical_or,
+        [
+            (col >= j * page_size) & (col < (j + 1) * page_size)
+            & (tbl_ref[ib, i * pages + j] < num_pages)
+            for j in range(pages)
+        ],
+    )
+
+
 def _paged_kernel(
     len_ref, tbl_ref, sched_ref, q_ref, k_hbm, v_hbm, *rest,
     cfg, blk, num_pages, heads, head_dim, quant, tree,
@@ -597,24 +654,15 @@ def _paged_kernel(
     def lanes(g):
         return slice(g * gd, (g + 1) * gd)
 
-    def copies(slot, block, buf):
-        """The block's page copies, K then V, into buffer `buf`.
-        Sentinel entries clamp to a real page (their keys are masked)."""
-        out = []
-        for j in range(pages):
-            page = jnp.minimum(tbl_ref[slot, block * pages + j], num_pages - 1)
-            dst = pl.ds(j * page_size, page_size)
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[page], k_buf.at[buf, dst], sem.at[0, buf]
-            ))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[page], v_buf.at[buf, dst], sem.at[1, buf]
-            ))
-        return out
-
-    def start(slot, block, buf):
-        for c in copies(slot, block, buf):
-            c.start()
+    # a page's copies: K then V
+    copies = _page_copies(
+        tbl_ref,
+        (
+            (k_hbm, k_buf, lambda buf: sem.at[0, buf]),
+            (v_hbm, v_buf, lambda buf: sem.at[1, buf]),
+        ),
+        pages, page_size, num_pages,
+    )
 
     @pl.when(n_blocks == 0)
     def _dead():
@@ -634,7 +682,8 @@ def _paged_kernel(
         # nobody fetched the call's first live block ahead
         @pl.when(before == 0)
         def _first():
-            start(ib, 0, 0)
+            for c in copies(ib, 0, 0):
+                c.start()
 
         length = len_ref[ib]
         col = lax.broadcasted_iota(jnp.int32, (1, rows), 1)
@@ -646,18 +695,7 @@ def _paged_kernel(
             )
 
         def _block(i, carry):
-            buf = (before + i) % 2
-
-            @pl.when(i + 1 < n_blocks)
-            def _next_block():
-                start(ib, i + 1, 1 - buf)
-
-            @pl.when((i + 1 == n_blocks) & (next_live < pl.num_programs(0)))
-            def _next_slot():
-                start(next_live, 0, 1 - buf)
-
-            for c in copies(ib, i, buf):
-                c.wait()
+            buf = _fetch_ahead(copies, ib, i, n_blocks, before, next_live)
             k = k_buf[buf]  # (rows, h*d)
             v = v_buf[buf]
             if quant:
@@ -667,17 +705,9 @@ def _paged_kernel(
                 visible = _repeat_rows(mask_ref[0, i], group) > 0.0
             else:
                 visible = i * rows + col <= length + qoff
-            # an unallocated page inside a live block (a standalone
-            # caller's ragged table; the engine's sit past the length)
-            allocated = functools.reduce(
-                jnp.logical_or,
-                [
-                    (col >= j * page_size) & (col < (j + 1) * page_size)
-                    & (tbl_ref[ib, i * pages + j] < num_pages)
-                    for j in range(pages)
-                ],
+            visible = visible & _allocated(
+                tbl_ref, ib, i, col, pages, page_size, num_pages
             )
-            visible = visible & allocated
             for g in range(heads // group):
                 s = mxu_dot(q_scr[g], k[:, lanes(g)], (1, 1)) * cfg.sm_scale
                 p_scale = None
@@ -718,6 +748,31 @@ def _paged_schedule(live_blocks):
     next_live = jnp.concatenate([later[1:], jnp.full((1,), b, jnp.int32)])
     before = jnp.cumsum(live_blocks) - live_blocks
     return jnp.stack([live_blocks, before, next_live]).astype(jnp.int32)
+
+
+def _live_tables(block_tables, lengths, num_pages, page_size, pages, w=1):
+    """What a paged kernel prefetches as scalars: (lengths int32 [b], the
+    table in whole blocks of `pages` pages (the tail past the table is
+    unallocated: `num_pages`), `_paged_schedule` of each slot's live
+    blocks). A page counts iff it is inside the staircase of the `w`
+    queries AND allocated; a slot's live blocks run up to the last block
+    that holds such a page."""
+    b, np_seq = block_tables.shape
+    nblk = -(-np_seq // pages)
+    lens = lengths.astype(jnp.int32)
+    tbl = jnp.pad(
+        block_tables.astype(jnp.int32),
+        ((0, 0), (0, nblk * pages - np_seq)),
+        constant_values=num_pages,
+    )
+    first_row = jnp.arange(nblk * pages, dtype=jnp.int32) * page_size
+    live = (first_row[None, :] <= lens[:, None] + (w - 1)) & (tbl < num_pages)
+    block_no = jnp.arange(1, nblk + 1, dtype=jnp.int32)
+    live_blocks = jnp.max(
+        jnp.where(live.reshape(b, nblk, pages).any(axis=2), block_no, 0),
+        axis=1,
+    )
+    return lens, tbl, _paged_schedule(live_blocks)
 
 
 def _paged_call(
@@ -762,21 +817,8 @@ def _paged_block_call(
     tree = allowed is not None
     pages, rows = blk.pages, blk.rows
     nblk = -(-np_seq // pages)
-    lens = lengths.astype(jnp.int32)
-    # the table in whole blocks (the tail past np_seq is unallocated)
-    tbl = jnp.pad(
-        block_tables.astype(jnp.int32),
-        ((0, 0), (0, nblk * pages - np_seq)),
-        constant_values=num_pages,
-    )
-    # a page counts iff it is inside the staircase AND allocated; a slot's
-    # live blocks run up to the last block that holds such a page
-    first_row = jnp.arange(nblk * pages, dtype=jnp.int32) * page_size
-    live = (first_row[None, :] <= lens[:, None] + (w - 1)) & (tbl < num_pages)
-    block_no = jnp.arange(1, nblk + 1, dtype=jnp.int32)
-    live_blocks = jnp.max(
-        jnp.where(live.reshape(b, nblk, pages).any(axis=2), block_no, 0),
-        axis=1,
+    lens, tbl, sched = _live_tables(
+        block_tables, lengths, num_pages, page_size, pages, w
     )
 
     def slot_map(ib, lens, tbl, sched):
@@ -791,7 +833,7 @@ def _paged_block_call(
         pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     operands = [
-        lens, tbl, _paged_schedule(live_blocks), q.reshape(b, w, h * d),
+        lens, tbl, sched, q.reshape(b, w, h * d),
         k_pool.reshape(num_pages, page_size, h * d),
         v_pool.reshape(num_pages, page_size, h * d),
     ]
@@ -962,4 +1004,168 @@ def paged_flash_verify_tree_quant(
     return _paged_call(
         q, k_pool, v_pool, block_tables, lengths, k_scale, v_scale,
         allowed, sm_scale, interpret,
+    )
+
+
+# -- block-paged LATENT cache -------------------------------------------------
+#
+# Latent attention (MLA, ops/attention.py) caches ONE row a token and
+# layer, [c | kr | 0], and in the absorbed form every query head reads the
+# same rows: keys are the whole row, values its first `v_width` lanes. So
+# the paged walk above degenerates to its simplest case, taken here from
+# static shapes (one pool, a query of [b, 1, h, row]): a block's scores
+# are ONE (h, row) x (rows, row)^T matmul with no block-diagonal tiling,
+# its weighted values ONE (h, rows) x (rows, v_width) matmul on the same
+# VMEM block, and half the copies (there is no V pool). Pages are fetched
+# a block ahead across slots by `_paged_kernel`'s own walk (`_page_copies`,
+# `_fetch_ahead`, `_allocated`, `_live_tables`). Decode only (one query a
+# slot), fp32 or bf16 rows.
+
+
+def supports_latent(mode: str, row: int, page_size: int) -> bool:
+    """`use_kernel` for the latent pool: "dense" never, "pallas" whenever
+    the page is sublane-aligned (the interpreter off a TPU), "auto" on a
+    TPU alone, where the row must also be whole 128-lane tiles (the
+    kernel copies whole rows out of the pool)."""
+    if mode not in MODES:
+        raise ValueError(f"decode_kernel must be one of {MODES}, got {mode!r}")
+    if mode == "dense" or page_size % SUBLANES:
+        return False
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and row % LANES:
+        return False
+    return mode == "pallas" or on_tpu
+
+
+def latent_block(
+    heads: int, row: int, v_width: int, page_size: int, np_seq: int,
+    itemsize: int,
+) -> PagedBlock:
+    """The latent kernel's block, as `paged_block` chooses the other's:
+    as many pages as fit `_VMEM_BUDGET`, up to `_MAX_BLOCK_ROWS` rows and
+    the table's `np_seq` pages. `heads` is all of them: they share a row."""
+
+    def vmem(rows):
+        return (
+            4 * heads * (2 * row + v_width + 2 * LANES)  # q, output, m, l
+            + rows * (2 * row * itemsize + row * 4 + 4 * 4 * heads)
+        )
+
+    pages = max(1, min(np_seq, _MAX_BLOCK_ROWS // page_size))
+    while pages > 1 and vmem(pages * page_size) > _VMEM_BUDGET:
+        pages //= 2
+    rows = pages * page_size
+    return PagedBlock(pages, rows, heads, vmem(rows))
+
+
+def _latent_kernel(
+    len_ref, tbl_ref, sched_ref, q_ref, pool_hbm, o_ref, k_buf, sem, m_scr,
+    l_scr, acc_scr, *, cfg, blk, num_pages, v_width,
+):
+    """One slot, all heads at once, its live blocks in a loop: the walk
+    of `_paged_kernel` (`_page_copies`, `_fetch_ahead`) over one pool."""
+    pages, rows = blk.pages, blk.rows
+    page_size = cfg.block_k
+    ib = pl.program_id(0)
+    n_blocks = sched_ref[0, ib]
+    before = sched_ref[1, ib]
+    next_live = sched_ref[2, ib]
+    copies = _page_copies(
+        tbl_ref, ((pool_hbm, k_buf, lambda buf: sem.at[buf]),),
+        pages, page_size, num_pages,
+    )
+
+    @pl.when(n_blocks == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live():
+        _init_scratch(m_scr, l_scr, acc_scr)
+
+        @pl.when(before == 0)
+        def _first():
+            for c in copies(ib, 0, 0):
+                c.start()
+
+        length = len_ref[ib]
+        col = lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+
+        def _block(i, carry):
+            buf = _fetch_ahead(copies, ib, i, n_blocks, before, next_live)
+            k = k_buf[buf]  # (rows, row): every head's keys AND values
+            visible = (i * rows + col <= length) & _allocated(
+                tbl_ref, ib, i, col, pages, page_size, num_pages
+            )
+            s = mxu_dot(q_ref[0], k, (1, 1)) * cfg.sm_scale  # (h, rows)
+            _online_softmax_step(
+                jnp.where(visible, s, _MASK), k[:, :v_width],
+                m_scr, l_scr, acc_scr, visible=visible,
+            )
+            return carry
+
+        lax.fori_loop(0, n_blocks, _block, None)
+        o_ref[0] = _finish(l_scr, acc_scr, o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "blk", "v_width"))
+def _latent_block_call(q, pool, block_tables, lengths, cfg, blk, v_width):
+    b, _, h, row = q.shape
+    num_pages, page_size = pool.shape[0], pool.shape[1]
+    operands = _live_tables(
+        block_tables, lengths, num_pages, page_size, blk.pages
+    )
+
+    def slot_map(ib, lens, tbl, sched):
+        return (ib, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(
+            _latent_kernel, cfg=cfg, blk=blk, num_pages=num_pages,
+            v_width=v_width,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, row), slot_map),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+            ],
+            out_specs=pl.BlockSpec((1, h, v_width), slot_map),
+            scratch_shapes=[
+                pltpu.VMEM((2, blk.rows, row), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((h, LANES), jnp.float32),
+                pltpu.VMEM((h, LANES), jnp.float32),
+                pltpu.VMEM((h, v_width), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        # slots in order: a slot's first block is fetched by the one before
+        compiler_params=_compiler_params(("arbitrary",)),
+        interpret=cfg.interpret,
+    )(*operands, q[:, 0], pool)[:, None]
+
+
+def paged_flash_decode_latent(
+    q, pool, block_tables, lengths, v_width: int, sm_scale: float,
+    interpret: Optional[bool] = None,
+):
+    """Single-query flash decode over a paged LATENT pool —
+    ops/attention.paged_latent_decode_attention's semantics with no
+    gather. q: [b, 1, h, row]; pool: [num_pages, page_size, row];
+    block_tables, lengths as paged_flash_verify. Returns
+    [b, 1, h, v_width]: the softmax-weighted sum of each visible row's
+    first `v_width` lanes, per head. `sm_scale` has no default: it is
+    the model's 1 / sqrt(nope + rope), not a function of `row`."""
+    if q.shape[1] != 1:
+        raise ValueError("the latent kernel takes one query a slot (decode)")
+    page_size = pool.shape[1]
+    cfg = _Cfg(1, float(sm_scale), page_size, resolve_interpret(interpret))
+    blk = latent_block(
+        q.shape[2], q.shape[3], v_width, page_size, block_tables.shape[1],
+        pool.dtype.itemsize,
+    )
+    return _latent_block_call(
+        q, pool, block_tables, lengths, cfg=cfg, blk=blk, v_width=v_width
     )

@@ -12,7 +12,7 @@ propagates parallel shapes, and lowers to a jitted XLA train step through
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -350,6 +350,52 @@ class FFModel:
             name,
         )[0]
 
+    def latent_attention(
+        self,
+        input: Tensor,
+        hidden: int,
+        num_heads: int,
+        kv_lora_rank: int,
+        qk_nope_head_dim: int,
+        qk_rope_head_dim: int,
+        v_head_dim: int,
+        rope_theta: float = 10000.0,
+        eps: float = 1e-6,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """Causal latent self-attention (MLA): every head's keys and
+        values are decompressed from one latent row a token, [c | kr] of
+        kv_lora_rank + qk_rope_head_dim, which is all a serving cache
+        keeps (ops/attention.py, "Latent attention"). Rotary positions in
+        the interleaved form over the rope part of q and over kr; an
+        RMSNorm with a learned gain over c. No biases."""
+        params = {
+            "embed_dim": hidden,
+            "num_heads": num_heads,
+            "kv_lora_rank": kv_lora_rank,
+            "qk_nope_head_dim": qk_nope_head_dim,
+            "qk_rope_head_dim": qk_rope_head_dim,
+            "v_head_dim": v_head_dim,
+            "rope_theta": float(rope_theta),
+            "eps": eps,
+            "causal": True,
+            "initializers": [None, None, ConstantInitializer(1.0), None, None],
+        }
+        return self._add(
+            OperatorType.LATENT_ATTENTION, "latent_attention", [input],
+            params, name,
+        )[0]
+
+    def gated_mlp(
+        self, input: Tensor, width: int, name: Optional[str] = None
+    ) -> Tensor:
+        """down(silu(gate x) * (up x)) with gate / up [d, width] and down
+        [width, d], no biases, as one operator."""
+        params = {"width": width, "initializers": [None] * 3}
+        return self._add(
+            OperatorType.GATED_MLP, "gated_mlp", [input], params, name
+        )[0]
+
     def dropout(self, input: Tensor, rate: float = 0.5, seed: int = 0, name=None):
         return self._add(
             OperatorType.DROPOUT, "dropout", [input], {"rate": rate, "seed": seed}, name
@@ -595,6 +641,10 @@ class FFModel:
         k: int,
         expert_hidden: int,
         renormalise: bool = False,
+        scoring: str = "softmax",
+        choice_bias: bool = False,
+        scale: float = 1.0,
+        experts_held: Optional[Tuple[int, int]] = None,
         name=None,
     ) -> Tensor:
         """A dropless top-k expert layer as one operator: router [d, E]
@@ -604,7 +654,16 @@ class FFModel:
         gate / up [E, d, f] and down [E, f, d]. No capacity: every one of
         the tokens x k rows is computed whatever the load (ops/moe.py
         sparse_moe). The expert dim shards under a replicated input, as
-        attention's heads do."""
+        attention's heads do.
+
+        scoring "sigmoid": each expert's score is the sigmoid of its own
+        logit. choice_bias: a fifth weight [E] added to the scores for the
+        top-k CHOICE only, never to the weights. scale multiplies the
+        (renormalised) weights. experts_held (first, count): this layer
+        holds experts first .. first + count - 1 of the E (the stacked
+        weights are [count, ...]); it routes over all E, computes the rows
+        sent to its own experts and leaves the others out: one chip's
+        share of a layer whose experts are divided over chips."""
         limit = (6.0 / (input.dims[-1] + expert_hidden)) ** 0.5
         params = {
             "num_experts": num_experts,
@@ -617,6 +676,18 @@ class FFModel:
             "initializers": [None]
             + [UniformInitializer(-limit, limit)] * 3,
         }
+        if scoring != "softmax":
+            params["scoring"] = scoring
+        if choice_bias:
+            # a trained buffer: zero until a checkpoint (or a test) sets it
+            params["choice_bias"] = True
+            params["initializers"] = params["initializers"] + [
+                ConstantInitializer(0.0)
+            ]
+        if scale != 1.0:
+            params["scale"] = float(scale)
+        if experts_held is not None:
+            params["experts_held"] = (int(experts_held[0]), int(experts_held[1]))
         return self._add(
             OperatorType.SPARSE_MOE, "sparse_moe", [input], params, name
         )[0]

@@ -826,21 +826,23 @@ class ServingSearchResult:
 
 
 def _serving_cache_geometry(graph: PCGGraph):
-    """(mha_guids, heads, head_dim) of the graph's attention layers —
-    the cache geometry the capacity estimate needs."""
+    """(attention guids, pools, heads, head_dim) of the graph's
+    attention layers — the cache geometry the capacity estimate needs,
+    each layer's row sized by the cache's own `cache_row`."""
+    from flexflow_tpu.serving.kv_cache import CACHED_ATTENTION, cache_row
+
     guids, geom = [], set()
     for g, node in graph.nodes.items():
-        if node.op_type != OperatorType.MULTIHEAD_ATTENTION:
+        if node.op_type not in CACHED_ATTENTION:
             continue
         guids.append(g)
-        heads = int(node.params["num_heads"])
-        geom.add((heads, int(node.params["embed_dim"]) // heads))
+        geom.add(cache_row(node))
     if len(geom) != 1:
         raise ValueError(
-            f"attention layers disagree on (heads, head_dim): {geom or '∅'}"
+            "attention layers disagree on (pools, heads, head_dim): "
+            f"{geom or '∅'}"
         )
-    heads, head_dim = geom.pop()
-    return tuple(guids), heads, head_dim
+    return (tuple(guids),) + geom.pop()
 
 
 def resolve_decode_kernel(
@@ -854,7 +856,7 @@ def resolve_decode_kernel(
     will actually run."""
     from flexflow_tpu.ops.pallas import decode_kernel as dk
 
-    _, _, head_dim = _serving_cache_geometry(graph)
+    _, _, _, head_dim = _serving_cache_geometry(graph)
     if dk.use_kernel(mode, w, kv_len, head_dim, page_size):
         return "pallas"
     return "dense"
@@ -923,7 +925,7 @@ def estimate_max_in_flight(
         )
     if prefix_hit_rate and page_size <= 0:
         raise ValueError("prefix_hit_rate > 0 requires a paged layout")
-    guids, heads, head_dim = _serving_cache_geometry(graph)
+    guids, pools, heads, head_dim = _serving_cache_geometry(graph)
     heads_chip = max(1, heads // max(1, tp))
     if admission == "reserve":
         budget = max_new_tokens if max_new_tokens is not None else mean_gen_len
@@ -944,6 +946,7 @@ def estimate_max_in_flight(
         num_pages=-(-max(1, seq_len) // page_size),
         itemsize=1 if kv_dtype == "int8" else itemsize,
         kv_dtype=kv_dtype,
+        kv_pools=pools,
     )
     per_seq = one.total_bytes
     return int(cache_bytes // per_seq) if per_seq else 0

@@ -522,6 +522,13 @@ def build_scheduler(
         telemetry=telemetry,
         adapters=adapters,
     )
+    proposer = build_proposer(serve, draft_model)
+    # what this model's engine refuses (latent attention: engine.require)
+    # is refused here, before a request is admitted
+    engine.require(
+        *(("verify", "verify_tree") if proposer is not None else ()),
+        *(("chunk",) if serve.token_budget else ()),
+    )
     classes = None
     if serve.classes:
         from flexflow_tpu.serving.tenancy.fairness import parse_classes
@@ -534,7 +541,7 @@ def build_scheduler(
         cls = scheduler_cls
     sched = cls(
         engine,
-        proposer=build_proposer(serve, draft_model),
+        proposer=proposer,
         spec_k=serve.spec_k,
         spec_branch=serve.spec_branch,
         admission=serve.admission,

@@ -252,7 +252,7 @@ def build_placement(
     --serve-mesh flag can ask for anything)."""
     from flexflow_tpu.search.auto import _serving_cache_geometry
 
-    _, heads, _ = _serving_cache_geometry(model.graph)
+    _, _, heads, _ = _serving_cache_geometry(model.graph)
     if tp > 1 and heads % tp:
         raise ValueError(
             f"serving mesh model={tp} does not divide the graph's "

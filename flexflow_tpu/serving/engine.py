@@ -33,6 +33,16 @@ steps, and `decode()` claims each sequence's next page BEFORE the step
 when it is about to cross a page boundary (the admission reserve
 guarantees that claim).
 
+A model with latent attention (`OperatorType.LATENT_ATTENTION`,
+ops/attention.py "Latent attention") is served through a second hook of
+the prefill and decode programs: its cache is ONE pool a layer of latent
+rows `[c | kr]` (kv_cache.cache_row), written through the same table
+routing; prefill attends decompressed (the operator's plain lowering),
+decode attends absorbed over the pool
+(`ops.attention.paged_latent_decode_attention`: the latent Pallas kernel
+or the dense gather). The other step families below are refused for such
+a model at construction (`require`).
+
 A third step family serves speculative decoding (serving/spec.py):
 **verify** scores w = k+1 token positions per slot (the last emitted
 token plus k drafted tokens) through the KV cache in ONE prefill-shaped
@@ -236,8 +246,8 @@ class InflightStep:
     # device futures (JAX arrays still computing behind the queue)
     device_next: object = None  # decode: sampled tokens [max_seqs]
     device_logits: object = None  # [max_seqs, V] or [max_seqs, w, V]
-    # decode of a model with expert layers: ([2] int32 (rows computed,
-    # experts touched),), else ()
+    # decode of a model with expert layers or latent attention: (the int32
+    # vector of its counts (`GenerationEngine._count_fields`),), else ()
     device_moe: tuple = ()
     # device-resident multi-step decode (kind "multistep"): the fused
     # window's per-step device outputs — sampled tokens / logits /
@@ -404,17 +414,94 @@ class GenerationEngine:
                 "them, which is not the adapted model; serve it without "
                 "ServeConfig.adapters"
             )
+        # latent attention (ops/attention.py, "Latent attention"): ONE pool
+        # a layer of [c | kr] rows, written by the prefill and decode
+        # programs through the same `dest` scatter as K and V rows, and
+        # attended decompressed (prefill) or absorbed over the pool
+        # (decode, multi-step decode). What has not been taken through
+        # those helpers and tested is refused here, in words, not served
+        # by the operator's plain lowering with no cache behind it.
+        self._latent = tuple(
+            g for g in cache.spec.layer_guids
+            if graph.nodes[g].op_type == OperatorType.LATENT_ATTENTION
+        )
+        self._refused: Dict[str, str] = {}
+        if self._latent:
+            if len(self._latent) != len(cache.spec.layer_guids):
+                raise ValueError(
+                    "a model that mixes latent attention with multi-head "
+                    "attention is not supported: the cache keeps one kind of "
+                    "row for all layers"
+                )
+            for asked, what in (
+                (cache.quantized, "kv_dtype='int8' (a page's scale is per "
+                 "head, and a latent row has none to scale by)"),
+                (cache.prefix_cache, "prefix_cache (a shared prefix is "
+                 "resumed by a chunk step)"),
+                (placement is not None, "a serving mesh (the latent row has "
+                 "one head and cannot be sharded by heads)"),
+            ):
+                if asked:
+                    raise ValueError(
+                        f"{what} is not supported for a model with latent "
+                        "attention: only prefill, decode and multi-step "
+                        "decode read and write the latent pool"
+                    )
+            why = (
+                " steps are not supported for a model with latent attention: "
+                "only prefill, decode and multi-step decode read and write "
+                "the latent pool"
+            )
+            self._refused = {
+                "verify": "verify (speculative decoding)" + why,
+                "verify_tree": "tree verify (speculative decoding)" + why,
+                "chunk": "chunked-prefill and prefix-suffix" + why,
+                "draft": "draft-model" + why,
+            }
         # expert layers (ops/moe.py sparse_moe): the prefill and decode
         # programs return their row and touched-expert counts beside the
-        # logits, read in the same readback; without one, nothing more
+        # logits, read in the same readback; without one, nothing more.
+        # A layer that holds a share of its experts counts the live rows
+        # it left to the others too (one int32 vector either way,
+        # `_count_fields` naming its entries), and its programs leave the
+        # routers' choice on the device (`moe_choice`)
         self._moe_guids = tuple(
             g for g in self.executor.topo
             if graph.nodes[g].op_type == OperatorType.SPARSE_MOE
         )
+        shares = {
+            graph.nodes[g].params.get("experts_held") is not None
+            for g in self._moe_guids
+        }
+        if len(shares) > 1:
+            raise ValueError(
+                "expert layers that hold a share beside layers that hold "
+                "every expert are not supported: their counts differ"
+            )
+        self._count_fields = ("moe_rows", "moe_experts_touched") if shares else ()
+        self._moe_share = True in shares
+        if self._moe_share:
+            self._count_fields += ("moe_rows_absent",)
+        # a shared expert (a gated MLP beside an expert layer, on the same
+        # input) runs under the `moe.shared` scope in the step programs
+        routed_inputs = {graph.nodes[g].inputs[0] for g in self._moe_guids}
+        self._shared_guids = frozenset(
+            g for g in self.executor.topo
+            if graph.nodes[g].op_type == OperatorType.GATED_MLP
+            and graph.nodes[g].inputs[0] in routed_inputs
+        )
+        # of a model whose layers hold a share: the experts every token
+        # picked in the last prefill / single-step decode program, int32
+        # [expert layers, max_seqs, positions, k], padding rows included,
+        # left on the device (nothing reads it back but who asks)
+        self.moe_choice: Dict[str, object] = {}
         self.moe_rows_prefill = 0
         self.moe_rows_decode = 0
         self.moe_experts_touched_prefill = 0
         self.moe_experts_touched_decode = 0
+        self.moe_rows_absent_prefill = 0
+        self.moe_rows_absent_decode = 0
+        self.mla_rows_read_decode = 0
         self._logits_ref = self.executor.logits_ref
         # per-iteration dynamic seq truncation is a training knob; a stale
         # value would truncate serving activations mid-stack
@@ -520,17 +607,29 @@ class GenerationEngine:
     def _verify_fn(self, w: int):
         """The jitted verify program for draft width `w` (LRU-managed
         by the shared _JitCache)."""
+        self.require("verify")
         return self._verify_cache.get(w)
 
     def _tree_fn(self, w: int):
         """The jitted tree-verify program for row width `w` (root + tree
         nodes) — same keyed-LRU discipline as `_verify_fn`."""
+        self.require("verify_tree")
         return self._tree_cache.get(w)
 
     def _chunk_fn(self, key):
         """The jitted chunked-prefill program for compact batch shape
         `key` = (B, w) — same keyed-LRU discipline as `_verify_fn`."""
+        self.require("chunk")
         return self._chunk_cache.get(key)
+
+    def require(self, *kinds: str) -> None:
+        """Raise, in words, if this engine refuses one of the step `kinds`
+        ("verify", "verify_tree", "chunk", "draft": what a model with
+        latent attention is not served through). `build_scheduler` asks
+        before a request is admitted; the program getters above ask again."""
+        for kind in kinds:
+            if kind in self._refused:
+                raise ValueError(self._refused[kind])
 
     # -- adapter gather args (multi-LoRA) ------------------------------------
 
@@ -674,7 +773,18 @@ class GenerationEngine:
             mesh, axis = self._head_shard
             heads //= mesh.shape[axis]
         self.kernel_block = None
-        if dk.use_kernel(
+        if spec.kv_pools == 1:
+            # a latent pool: one row for all the model's query heads
+            node = self.model.graph.nodes[spec.layer_guids[0]]
+            if dk.supports_latent(
+                self.decode_kernel, spec.row_width, spec.page_size
+            ):
+                self.kernel_block = dk.latent_block(
+                    node.params["num_heads"], spec.row_width,
+                    node.params["kv_lora_rank"], spec.page_size,
+                    spec.max_pages_per_seq, spec.itemsize,
+                )
+        elif dk.use_kernel(
             self.decode_kernel, 1, 0, spec.head_dim,
             page_size=spec.page_size, kv_dtype=spec.kv_dtype, heads=heads,
         ):
@@ -749,20 +859,42 @@ class GenerationEngine:
         rotary or QK-norm traces exactly the program it always did)."""
         return make() if self._positional else None
 
-    def _forward_logits(self, params, tokens, hook, moe_counts=None):
-        """`moe_counts`: a list that receives the [2] int32 (rows computed,
-        experts touched) of every expert layer, for the programs that
-        return them; the layer itself is the executor's lowering."""
+    def _forward_logits(
+        self, params, tokens, hook, moe_counts=None, latent_hook=None,
+        share=None,
+    ):
+        """`moe_counts`: a list that receives the int32 counts of every
+        expert layer (`ops.moe.sparse_moe`), for the programs that
+        return them; the layer itself is the executor's lowering.
+        `latent_hook`: what stands in for a latent-attention node, from
+        the programs that serve one. `share` (`_share`): what the layers
+        of a model that holds a share of its experts are handed besides."""
+        import jax
+
         hooks = {OperatorType.MULTIHEAD_ATTENTION: hook}
+        if latent_hook is not None:
+            hooks[OperatorType.LATENT_ATTENTION] = latent_hook
         if moe_counts is not None and self._moe_guids:
             from flexflow_tpu.ops.moe import sparse_moe
 
             def count(node, ins, ws, ctx):
-                y, counts = sparse_moe(ins[0], ws, node.params, ctx)
+                y, counts = sparse_moe(
+                    ins[0], ws, node.params, ctx, **(share or {})
+                )
                 moe_counts.append(counts)
                 return [y]
 
             hooks[OperatorType.SPARSE_MOE] = count
+        if self._shared_guids:
+            lowered = self.executor._lowered
+
+            def gated(node, ins, ws, ctx):
+                if node.guid not in self._shared_guids:
+                    return lowered[node.guid](ins, ws, ctx)
+                with jax.named_scope("moe.shared"):
+                    return lowered[node.guid](ins, ws, ctx)
+
+            hooks[OperatorType.GATED_MLP] = gated
         values = self.executor.forward_values(
             params,
             {self.input_name: tokens},
@@ -773,27 +905,50 @@ class GenerationEngine:
         )
         return values[(self._logits_ref.guid, self._logits_ref.out_idx)]
 
+    def _share(self, live):
+        """What `_forward_logits` hands the expert layers of a model that
+        holds a share of its experts (None otherwise): which tokens are
+        someone's, `live` bool [max_seqs, positions], and the list that
+        receives each layer's choice."""
+        return {"live": live, "chosen": []} if self._moe_share else None
+
     @staticmethod
-    def _moe_total(moe_counts):
-        """() for a model without expert layers, else a 1-tuple of the
-        [2] int32 sum over layers: what a step program appends to its
-        outputs."""
-        return (sum(moe_counts[1:], moe_counts[0]),) if moe_counts else ()
+    def _step_counts(moe_counts, share=None):
+        """What a step program appends to its outputs: () for a model
+        without expert layers, else the int32 sum over layers of their
+        counts (`_count_fields` names the entries), and after it, from a
+        model that holds a share, every layer's choice stacked."""
+        import jax.numpy as jnp
 
-    def _split_moe(self, out):
-        """A prefill or decode program's outputs without the expert
-        layers' counts, and the counts as a 0- or 1-tuple."""
-        n = len(out) - bool(self._moe_guids)
-        return out[:n], tuple(out[n:])
+        out = (sum(moe_counts[1:], moe_counts[0]),) if moe_counts else ()
+        if share is not None:
+            out += (jnp.stack(share["chosen"]),)
+        return out
 
-    def _count_moe(self, kind: str, counts) -> None:
-        rows, touched = int(counts[0]), int(counts[1])
-        if kind == "prefill":
-            self.moe_rows_prefill += rows
-            self.moe_experts_touched_prefill += touched
-        else:
-            self.moe_rows_decode += rows
-            self.moe_experts_touched_decode += touched
+    def _split_counts(self, kind: str, out):
+        """A prefill or decode program's outputs without what
+        `_step_counts` appended, and the counts as a 0- or 1-tuple; the
+        choice stays on the device as `moe_choice[kind]`."""
+        if self._moe_share:
+            *out, self.moe_choice[kind] = out
+        n = len(out) - bool(self._count_fields)
+        return tuple(out[:n]), tuple(out[n:])
+
+    def _count(self, kind: str, counts) -> None:
+        for name, got in zip(self._count_fields, counts):
+            attr = f"{name}_{kind}"
+            setattr(self, attr, getattr(self, attr) + int(got))
+
+    def _write_latent(self, pool, latent, dest):
+        """Scatter latent rows [..., rank + rope] into the layer's ONE
+        pool, padded with zeros to the pool's row (`cache_row`)."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("mla.project"):
+            pad = self.cache.spec.row_width - latent.shape[-1]
+            rows = jnp.pad(latent, [(0, 0)] * (latent.ndim - 1) + [(0, pad)])
+            return self._write_rows(pool, rows, dest)
 
     def _pick(self, logits, slots, positions):
         """logits [n, vocab] -> token ids [n]. Greedy at temperature 0,
@@ -908,6 +1063,9 @@ class GenerationEngine:
         from flexflow_tpu.ops.attention import (
             mha_project_qkv,
             mha_project_out,
+            mla_decompressed,
+            mla_project,
+            mla_project_out,
             scaled_dot_product_attention,
         )
         from flexflow_tpu.serving.tenancy.adapters import (
@@ -960,8 +1118,24 @@ class GenerationEngine:
             )
             return [apply_adapter_out(attn, out, ad, g)]
 
+        def latent_hook(node, ins, ws, ctx):
+            # the operator's plain lowering (decompressed), and the rows
+            q_nope, q_rope, latent = mla_project(
+                ins[0], ws, node.params, ctx, positions
+            )
+            new_k[node.guid] = self._write_latent(ck[node.guid], latent, dest)
+            attn = mla_decompressed(q_nope, q_rope, latent, ws, node.params, ctx)
+            return [mla_project_out(attn, ws, ctx, ins[0].dtype)]
+
         moe = []
-        logits = self._forward_logits(params, tokens, hook, moe)
+        share = self._share(
+            (jnp.arange(tokens.shape[1])[None, :] < prompt_lens[:, None])
+            & (slot_ids < tokens.shape[0])[:, None]
+        )
+        logits = self._forward_logits(
+            params, tokens, hook, moe, latent_hook if self._latent else None,
+            share,
+        )
         last = jnp.take_along_axis(
             logits, (prompt_lens - 1)[:, None, None], axis=1
         )[:, 0]
@@ -972,7 +1146,7 @@ class GenerationEngine:
             new_vs,
             self._pick(last, slot_ids, prompt_lens),
             last,
-            *self._moe_total(moe),
+            *self._step_counts(moe, share),
         )
 
     def prefill(
@@ -1025,17 +1199,18 @@ class GenerationEngine:
                 jnp.asarray(row_tables), jnp.asarray(plens),
             )
         with span("scheduler.step.prefill.dispatch", self._tracer):
-            (nxt, last), moe = self._split_moe(
+            (nxt, last), moe = self._split_counts(
+                "prefill",
                 self._run_step(
                     "prefill", lambda: fn, params, inputs,
                     self._adapter_row_args(slots), kernel_path=False,
-                )
+                ),
             )
             for p, s in zip(prompts, slots):
                 self.cache.lengths[s] = len(p)
         nxt, last, *counts = self._readback("prefill", nxt[:n], last[:n], *moe)
         if counts:
-            self._count_moe("prefill", counts[0])
+            self._count("prefill", counts[0])
         return nxt, last
 
     def prefill_suffix(
@@ -1088,7 +1263,7 @@ class GenerationEngine:
 
     def _decode_core_paged(
         self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
-        ad=None, moe=None,
+        ad=None, moe=None, share=None,
     ):
         """One decode forward: tokens [max_seqs, 1]; lengths [max_seqs]
         = cache position the incoming token is written at; active
@@ -1102,15 +1277,21 @@ class GenerationEngine:
         token/logit-identity contract). `ad=None` (no adapter pool)
         leaves the traced HLO byte-for-byte what it was before
         multi-LoRA existed. `moe`: the list that receives the expert
-        layers' counts (`_forward_logits`); the single-step program
-        returns them, the scan does not. Returns (ck', cv', cks', cvs',
+        layers' counts and `share` what a model that holds a share
+        of its experts hands them (`_forward_logits`); the single-step
+        program returns both, the scan neither. Returns (ck', cv', cks', cvs',
         logits [max_seqs, V])."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
             mha_project_qkv,
             mha_project_out,
+            mla_absorb_query,
+            mla_absorb_values,
+            mla_project,
+            mla_project_out,
             paged_decode_attention,
+            paged_latent_decode_attention,
         )
         from flexflow_tpu.serving.tenancy.adapters import (
             apply_adapter_out,
@@ -1167,7 +1348,25 @@ class GenerationEngine:
             )
             return [apply_adapter_out(attn, out, ad, g)]
 
-        logits = self._forward_logits(params, tokens, hook, moe)[:, -1, :]
+        def latent_hook(node, ins, ws, ctx):
+            # absorbed: the one new row is written, and every head
+            # attends over the latent rows themselves, through the pool
+            g, p = node.guid, node.params
+            q_nope, q_rope, latent = mla_project(ins[0], ws, p, ctx, positions)
+            new_k[g] = self._write_latent(ck[g], latent, dest)
+            attended = paged_latent_decode_attention(
+                mla_absorb_query(q_nope, q_rope, ws, p, ctx, spec.row_width),
+                new_k[g], tables, lengths, p["kv_lora_rank"],
+                (p["qk_nope_head_dim"] + p["qk_rope_head_dim"]) ** -0.5,
+                kernel=self.decode_kernel,
+            )
+            attn = mla_absorb_values(attended, ws, p, ctx)
+            return [mla_project_out(attn, ws, ctx, ins[0].dtype)]
+
+        logits = self._forward_logits(
+            params, tokens, hook, moe, latent_hook if self._latent else None,
+            share,
+        )[:, -1, :]
         return new_k, new_v, new_ks, new_vs, logits
 
     def _decode_impl_paged(
@@ -1180,9 +1379,10 @@ class GenerationEngine:
         import jax.numpy as jnp
 
         moe = []
+        share = self._share(active[:, None])
         new_k, new_v, new_ks, new_vs, logits = self._decode_core_paged(
             params, tokens, lengths, active, tables, ck, cv, cks, cvs, ad,
-            moe,
+            moe, share,
         )
         slots = jnp.arange(lengths.shape[0])
         return (
@@ -1192,7 +1392,7 @@ class GenerationEngine:
             new_vs,
             self._pick(logits, slots, lengths + 1),
             logits,
-            *self._moe_total(moe),
+            *self._step_counts(moe, share),
         )
 
     # -- device-resident multi-step decode -----------------------------------
@@ -1324,7 +1524,8 @@ class GenerationEngine:
         # allocator table edits between iterations mutate behind the
         # async dispatch queue); the locals built above are fresh per
         # call and safe to hand over directly
-        (nxt, logits), moe = self._split_moe(
+        (nxt, logits), moe = self._split_counts(
+            "decode",
             self._run_step(
                 "decode",
                 lambda: self._decode_jit,
@@ -1336,9 +1537,14 @@ class GenerationEngine:
                     snapshot(self.cache.block_tables),
                 ),
                 self._adapter_slot_args(),
-            )
+            ),
         )
         self.cache.lengths[np.asarray(active_mask)] += 1
+        if self._latent:
+            # the live latent rows this step attends, the new one included
+            self.mla_rows_read_decode += len(self._latent) * int(
+                self.cache.lengths[np.asarray(active_mask)].sum()
+            )
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
         self.cache.begin_inflight()
@@ -1369,7 +1575,7 @@ class GenerationEngine:
         finally:
             self.cache.end_inflight()
         if counts:
-            self._count_moe("decode", counts[0])
+            self._count("decode", counts[0])
         return nxt, logits
 
     def decode(

@@ -82,12 +82,14 @@ def default_page_size(max_len: int, target: int = 16) -> int:
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
     """Static geometry of the cache, derived from the compiled model:
-    `num_pages` pool pages of `page_size` positions. `itemsize` is the
-    cache dtype's element width in bytes (set from the actual dtype at
-    cache construction, so bytes_per_layer/total_bytes price bf16
-    caches at 2 bytes, not a hardcoded 4)."""
+    `num_pages` pool pages of `page_size` positions, and per layer
+    `kv_pools` pools whose row, one position's, is `num_heads * head_dim`
+    wide (`cache_row`: K and V of every head, or one latent row).
+    `itemsize` is the cache dtype's element width in bytes (set from the
+    actual dtype at cache construction, so bytes_per_layer/total_bytes
+    price bf16 caches at 2 bytes, not a hardcoded 4)."""
 
-    layer_guids: Tuple[int, ...]  # MHA node guids, topo order
+    layer_guids: Tuple[int, ...]  # attention node guids, topo order
     max_seqs: int
     max_len: int
     num_heads: int
@@ -97,6 +99,7 @@ class KVCacheSpec:
     num_pages: int
     itemsize: int = 4
     kv_dtype: str = "fp32"  # "fp32" | "int8"
+    kv_pools: int = 2  # K and V; 1 where both are read from the same rows
 
     def bucket(self, length: int) -> int:
         """Smallest bucket >= length (prefill pad target)."""
@@ -117,15 +120,26 @@ class KVCacheSpec:
         return self.num_pages * self.page_size
 
     @property
-    def bytes_per_layer(self) -> int:
-        base = (
-            2 * self.itemsize * self.total_rows * self.num_heads * self.head_dim
+    def row_width(self) -> int:
+        """Elements of one pool row: one position of one layer."""
+        return self.num_heads * self.head_dim
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """What one more cached position costs, over all layers."""
+        return (
+            self.kv_pools * self.itemsize * self.row_width
+            * len(self.layer_guids)
         )
+
+    @property
+    def bytes_per_layer(self) -> int:
+        base = self.kv_pools * self.itemsize * self.total_rows * self.row_width
         if self.kv_dtype == "int8":
             # fp32 dequant scales ride in a side pool, one per page per
-            # head for K and V each — they are part of the cache's HBM
+            # head for each pool — they are part of the cache's HBM
             # bill even though the token pools shrink 4x
-            base += 2 * 4 * self.num_pages * self.num_heads
+            base += self.kv_pools * 4 * self.num_pages * self.num_heads
         return base
 
     @property
@@ -149,14 +163,35 @@ def _validate_page_geometry(max_seqs, max_len, page_size, num_pages):
         )
 
 
+#: the operator types that keep rows in the cache
+CACHED_ATTENTION = (
+    OperatorType.MULTIHEAD_ATTENTION, OperatorType.LATENT_ATTENTION,
+)
+
+
+def cache_row(node) -> Tuple[int, int, int]:
+    """(pools, heads, head_dim) of what an attention node keeps of each
+    position: K and V rows of heads x head_dim, or for latent attention
+    ONE row of one head (keys and values are both read from it), as wide
+    as `ops.attention.mla_cache_row` pads it. The one place a pool row
+    is sized: the cache, the engine's writes and `optimize_serving`'s
+    capacity estimate all go through KVCacheSpec built from this."""
+    if node.op_type == OperatorType.LATENT_ATTENTION:
+        from flexflow_tpu.ops.attention import mla_cache_row
+
+        return 1, 1, mla_cache_row(node.params)
+    heads = int(node.params["num_heads"])
+    return 2, heads, int(node.params["embed_dim"]) // heads
+
+
 def _derive_geometry(model):
     """(layer_guids, heads, head_dim, head_axis, executor) from a
-    compiled FFModel. Every MULTIHEAD_ATTENTION node must agree on
-    (heads, head_dim) — one cache block size per model, like the
+    compiled FFModel. Every attention node must agree on `cache_row` —
+    one cache block size per model, like the
     reference serve stack. The sharding comes from the Wq weight's head
     dim: if the chosen strategy partitioned heads (parallel_idx -> mesh
     axis), the cache heads dim shards on that axis; otherwise the cache
-    is replicated."""
+    is replicated (a latent row has one head: always)."""
     if model.executor is None:
         raise RuntimeError("compile() the model before building its KV cache")
     graph = model.graph
@@ -164,7 +199,7 @@ def _derive_geometry(model):
     guids = [
         g
         for g in executor.topo
-        if graph.nodes[g].op_type == OperatorType.MULTIHEAD_ATTENTION
+        if graph.nodes[g].op_type in CACHED_ATTENTION
     ]
     if not guids:
         raise ValueError("model has no attention layers to cache")
@@ -172,9 +207,7 @@ def _derive_geometry(model):
     head_axis = None
     for g in guids:
         node = graph.nodes[g]
-        heads = int(node.params["num_heads"])
-        head_dim = int(node.params["embed_dim"]) // heads
-        geom.add((heads, head_dim))
+        geom.add(cache_row(node))
         wq = node.weight_shapes[0] if node.weight_shapes else None
         if wq is not None and len(wq.dims) == 3:
             hd = wq.dims[1]
@@ -184,9 +217,9 @@ def _derive_geometry(model):
                 head_axis = executor.mesh_config.axis_names[hd.parallel_idx]
     if len(geom) != 1:
         raise ValueError(
-            f"attention layers disagree on (heads, head_dim): {geom}"
+            f"attention layers disagree on (pools, heads, head_dim): {geom}"
         )
-    heads, head_dim = geom.pop()
+    _, heads, head_dim = geom.pop()
     return guids, heads, head_dim, head_axis, executor
 
 
@@ -207,8 +240,9 @@ class PagedKVCache:
     """Block-paged pools + host-side page allocator and block tables.
 
     Device state: one `[num_pages, page_size, heads * head_dim]` K and V
-    pool per layer (functional: each step program returns the pools it
-    was handed, rewritten, and `commit` stores them). The
+    pool per layer, or for latent attention ONE pool of latent rows
+    (`spec.kv_pools`, `cache_row`) (functional: each step program returns
+    the pools it was handed, rewritten, and `commit` stores them). The
     heads and their dims are folded into one, heads-major, because a
     device array's layout follows from its shape: a TPU keeps an array
     whose last dim is under 128 lanes (a head_dim of 64) pages-minor,
@@ -292,22 +326,22 @@ class PagedKVCache:
             )
         from flexflow_tpu.runtime import multihost
 
+        def fresh(shape, dtype, sharding):
+            pool = jnp.zeros(shape, dtype)
+            return pool if sharding is None else multihost.place_array(pool, sharding)
+
+        # `kv_pools == 1` (latent rows: keys and values are the same
+        # rows): everything lives in `k`, and `v` / `v_scale` stay empty,
+        # which every loop over them below takes as nothing to do
         for g in spec.layer_guids:
-            k = jnp.zeros(shape, dtype)
-            v = jnp.zeros(shape, dtype)
-            if shardings is not None:
-                k = multihost.place_array(k, shardings)
-                v = multihost.place_array(v, shardings)
-            self.k[g] = k
-            self.v[g] = v
+            self.k[g] = fresh(shape, dtype, shardings)
+            if spec.kv_pools == 2:
+                self.v[g] = fresh(shape, dtype, shardings)
             if self.quantized:
-                ks = jnp.zeros((spec.num_pages, spec.num_heads), jnp.float32)
-                vs = jnp.zeros((spec.num_pages, spec.num_heads), jnp.float32)
-                if scale_shardings is not None:
-                    ks = multihost.place_array(ks, scale_shardings)
-                    vs = multihost.place_array(vs, scale_shardings)
-                self.k_scale[g] = ks
-                self.v_scale[g] = vs
+                scales = (spec.num_pages, spec.num_heads)
+                self.k_scale[g] = fresh(scales, jnp.float32, scale_shardings)
+                if spec.kv_pools == 2:
+                    self.v_scale[g] = fresh(scales, jnp.float32, scale_shardings)
         self.lengths = np.zeros(spec.max_seqs, dtype=np.int32)
         self.block_tables = np.full(
             (spec.max_seqs, spec.max_pages_per_seq),
@@ -974,16 +1008,12 @@ class PagedKVCache:
             # array kept from before a dispatch would be deleted). The
             # eager .at[].set() copies them, undonated, behind that step
             # on the device queue — same discipline as commit()
-            nk, nv = dict(self.k), dict(self.v)
-            nks, nvs = dict(self.k_scale), dict(self.v_scale)
-            for g in self.spec.layer_guids:
-                nk[g] = nk[g].at[new].set(nk[g][page])
-                nv[g] = nv[g].at[new].set(nv[g][page])
-                if self.quantized:
-                    nks[g] = nks[g].at[new].set(nks[g][page])
-                    nvs[g] = nvs[g].at[new].set(nvs[g][page])
-            self.k, self.v = nk, nv
-            self.k_scale, self.v_scale = nks, nvs
+            def copied(pools):
+                return {g: p.at[new].set(p[page]) for g, p in pools.items()}
+
+            self.k, self.v = copied(self.k), copied(self.v)
+            self.k_scale = copied(self.k_scale)
+            self.v_scale = copied(self.v_scale)
             self.block_tables[slot, pi] = new
             self._refcounts[new] = 1
             self._refcounts[page] -= 1
@@ -1153,12 +1183,12 @@ class PagedKVCache:
         di = jnp.asarray(df)
         nk, nv = dict(self.k), dict(self.v)
         if not self.quantized:
-            for g in spec.layer_guids:
-                kf = nk[g].reshape(-1, spec.num_heads, spec.head_dim)
-                vf = nv[g].reshape(-1, spec.num_heads, spec.head_dim)
-                nk[g] = kf.at[di].set(kf[si]).reshape(nk[g].shape)
-                nv[g] = vf.at[di].set(vf[si]).reshape(nv[g].shape)
-            self.k, self.v = nk, nv
+            def moved(pool):
+                flat_rows = pool.reshape(-1, spec.row_width)
+                return flat_rows.at[di].set(flat_rows[si]).reshape(pool.shape)
+
+            self.k = {g: moved(p) for g, p in nk.items()}
+            self.v = {g: moved(p) for g, p in nv.items()}
             return
         # int8 pools: dequant with the source page's scale, requantize
         # under the destination page's. A destination page whose first
@@ -1189,8 +1219,9 @@ class PagedKVCache:
             ).astype(pool.dtype)
             return f.at[di].set(q).reshape(pool.shape), new_scale
 
-        for g in spec.layer_guids:
+        for g in nk:
             nk[g], nks[g] = requant(nk[g], nks[g])
+        for g in nv:
             nv[g], nvs[g] = requant(nv[g], nvs[g])
         self.k, self.v = nk, nv
         self.k_scale, self.v_scale = nks, nvs
@@ -1221,17 +1252,23 @@ class PagedKVCache:
         slivers — the bytes_moved the cost model prices against one
         recompute prefill."""
         spec = self.spec
-        per_page = (
-            2 * spec.itemsize * spec.page_size * spec.num_heads * spec.head_dim
-        )
+        per_page = spec.kv_pools * spec.itemsize * spec.page_size * spec.row_width
         if self.quantized:
-            per_page += 2 * 4 * spec.num_heads
+            per_page += spec.kv_pools * 4 * spec.num_heads
         return int(self._held[slot]) * per_page * len(spec.layer_guids)
 
     @property
     def swapped_pages(self) -> int:
         """Pages' worth of KV currently staged in host swap buffers."""
         return sum(int(rec["pages"]) for rec in self._swapped.values())
+
+    def _stage_pages(self, idx: np.ndarray):
+        """Host copies of pages `idx` of every pool: (k, v, k_scale,
+        v_scale) dicts by layer, as a swap record holds them."""
+        return tuple(
+            {g: np.asarray(pool[idx]) for g, pool in pools.items()}
+            for pools in (self.k, self.v, self.k_scale, self.v_scale)
+        )
 
     def swap_out(self, slot: int) -> Optional[int]:
         """Stage `slot`'s committed pages (K/V pools AND int8 scale
@@ -1256,18 +1293,7 @@ class PagedKVCache:
         sentinel = self.spec.num_pages
         pages = [int(p) for p in self.block_tables[slot] if p != sentinel]
         idx = np.asarray(pages, dtype=np.int32)
-        hk: Dict[int, np.ndarray] = {}
-        hv: Dict[int, np.ndarray] = {}
-        hks: Dict[int, np.ndarray] = {}
-        hvs: Dict[int, np.ndarray] = {}
-        for g in self.spec.layer_guids:
-            kp, vp = self.k[g], self.v[g]
-            hk[g] = np.asarray(kp[idx])
-            hv[g] = np.asarray(vp[idx])
-            if self.quantized:
-                ksp, vsp = self.k_scale[g], self.v_scale[g]
-                hks[g] = np.asarray(ksp[idx])
-                hvs[g] = np.asarray(vsp[idx])
+        hk, hv, hks, hvs = self._stage_pages(idx)
         handle = self._swap_seq
         self._swap_seq += 1
         self._swapped[handle] = {
@@ -1304,18 +1330,7 @@ class PagedKVCache:
         sentinel = self.spec.num_pages
         pages = [int(p) for p in self.block_tables[slot] if p != sentinel]
         idx = np.asarray(pages, dtype=np.int32)
-        hk: Dict[int, np.ndarray] = {}
-        hv: Dict[int, np.ndarray] = {}
-        hks: Dict[int, np.ndarray] = {}
-        hvs: Dict[int, np.ndarray] = {}
-        for g in self.spec.layer_guids:
-            kp, vp = self.k[g], self.v[g]
-            hk[g] = np.asarray(kp[idx])
-            hv[g] = np.asarray(vp[idx])
-            if self.quantized:
-                ksp, vsp = self.k_scale[g], self.v_scale[g]
-                hks[g] = np.asarray(ksp[idx])
-                hvs[g] = np.asarray(vsp[idx])
+        hk, hv, hks, hvs = self._stage_pages(idx)
         return {
             "k": hk,
             "v": hv,
@@ -1374,18 +1389,16 @@ class PagedKVCache:
             import jax.numpy as jnp
 
             idx = np.asarray(pages, dtype=np.int32)
-            hk, hv = rec["k"], rec["v"]
-            hks, hvs = rec["k_scale"], rec["v_scale"]
-            nk, nv = dict(self.k), dict(self.v)
-            nks, nvs = dict(self.k_scale), dict(self.v_scale)
-            for g in spec.layer_guids:
-                nk[g] = nk[g].at[idx].set(jnp.asarray(hk[g]))
-                nv[g] = nv[g].at[idx].set(jnp.asarray(hv[g]))
-                if self.quantized:
-                    nks[g] = nks[g].at[idx].set(jnp.asarray(hks[g]))
-                    nvs[g] = nvs[g].at[idx].set(jnp.asarray(hvs[g]))
-            self.k, self.v = nk, nv
-            self.k_scale, self.v_scale = nks, nvs
+            def restored(pools, staged):
+                return {
+                    g: p.at[idx].set(jnp.asarray(staged[g]))
+                    for g, p in pools.items()
+                }
+
+            self.k = restored(self.k, rec["k"])
+            self.v = restored(self.v, rec["v"])
+            self.k_scale = restored(self.k_scale, rec["k_scale"])
+            self.v_scale = restored(self.v_scale, rec["v_scale"])
         self.swap_ins += 1
         self.swap_bytes_total += int(rec["bytes"])
         return slot
@@ -1722,6 +1735,7 @@ class PagedKVCache:
             page_size=page_size,
             num_pages=num_pages,
             kv_dtype=kv_dtype,
+            kv_pools=cache_row(model.graph.nodes[guids[0]])[0],
         )
         if dtype is None:
             dtype = jnp.float32

@@ -314,6 +314,13 @@ _STAT_FIELDS: Dict[str, object] = dict(
     moe_rows_decode=0,
     moe_experts_touched_prefill=0,
     moe_experts_touched_decode=0,
+    # of a layer that holds a share of its experts: the rows routed to
+    # experts it does not hold, which it leaves out
+    moe_rows_absent_prefill=0,
+    moe_rows_absent_decode=0,
+    # latent attention: the live latent rows the decode steps attended,
+    # summed over slots and layers
+    mla_rows_read_decode=0,
     # prefix-sharing page cache (--prefix-cache;
     # mirrored from the allocator's ledgers at each iteration end)
     prefix_hits=0,  # admissions that mapped at least one shared page
@@ -2619,6 +2626,8 @@ class _SchedulerBase:
         "prefill_tokens_padded", "pool_steps_donated", "pool_steps_copied",
         "moe_rows_prefill", "moe_rows_decode",
         "moe_experts_touched_prefill", "moe_experts_touched_decode",
+        "moe_rows_absent_prefill", "moe_rows_absent_decode",
+        "mla_rows_read_decode",
     )
 
     def _end_iteration(self) -> None:

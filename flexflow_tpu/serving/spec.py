@@ -591,6 +591,7 @@ class ModelDraftProposer(DraftProposer):
             draft_model, self.cache, temperature=0.0,
             decode_kernel=decode_kernel,
         )
+        self.engine.require("draft")
         self.params = draft_model.params
         # telemetry ledgers: draft-engine decode steps, split into
         # catch-up feeds (replaying tokens the target committed) vs
