@@ -89,6 +89,15 @@ loop holds the `InflightStep` across an iteration and chains the next
 step's input tokens from its `device_next` so the inter-step data
 dependency resolves entirely on device.
 
+A decode step crosses the host boundary once each way. In: the tokens,
+lengths, active mask and block tables as one packed int32 argument
+(`_pack_state`). Out: one int32 vector (`InflightStep.device_readback`:
+the picked tokens, whether each slot's logits row is finite, the expert
+layers' counts), whose copy to the host starts at dispatch and which
+the reconcile waits for, once. The logits stay on the device; `decode()`
+reads them because its caller asks for them. A dispatch blocks only on
+a program's first run (`_dispatch`).
+
 Greedy argmax is the default (temperature 0); temperature sampling
 derives a PRNG key per (serve seed, slot, cache position), so a
 request's sampled stream depends only on its slot and its own tokens —
@@ -245,10 +254,16 @@ class InflightStep:
     chunk_seqs: Optional[Dict[int, list]] = None
     # device futures (JAX arrays still computing behind the queue)
     device_next: object = None  # decode: sampled tokens [max_seqs]
-    device_logits: object = None  # [max_seqs, V] or [max_seqs, w, V]
-    # decode of a model with expert layers or latent attention: (the int32
-    # vector of its counts (`GenerationEngine._count_fields`),), else ()
-    device_moe: tuple = ()
+    # [max_seqs, V] or [max_seqs, w, V]. Of a decode step they stay on the
+    # device unless someone asks (`GenerationEngine.decode`): the
+    # scheduler's reconcile reads `device_readback` and nothing else
+    device_logits: object = None
+    # decode: everything the reconcile brings to the host, as ONE int32
+    # vector whose copy was started at dispatch: the sampled tokens
+    # [max_seqs], whether each slot's logits row is finite [max_seqs],
+    # then the counts of a model with expert layers
+    # (`GenerationEngine._count_fields`)
+    device_readback: object = None
     # device-resident multi-step decode (kind "multistep"): the fused
     # window's per-step device outputs — sampled tokens / logits /
     # executed-step masks are [K, max_seqs] stacks, device_lengths the
@@ -704,28 +719,31 @@ class GenerationEngine:
 
     def _dispatch(self, site: str, call, program, witness):
         """Run one jitted decode/verify step. On the dense paths this is
-        just `call()`; on a Pallas-kernel path the outputs are forced
-        first (surfacing async runtime errors BEFORE the cache commits
-        them), and a fault — injected through the chaos seam, or raised
-        at run time by a program that has already run — permanently
-        falls the engine back to the dense paths and retries the step
-        once. Serving survives a broken kernel at the cost of the dense
-        path's speed; the fallback is recorded in `kernel_fallbacks` /
-        `kernel_fallback_error` and logged.
+        just `call()`. On a Pallas-kernel path the FIRST dispatch of a
+        program forces its outputs: that is where its kernel lowers,
+        compiles and runs for the first time, and a kernel that cannot is
+        not a fault to survive: it raises KernelCompileError with the
+        compiler's message, because answering it with dense is how a
+        kernel that never ran on the chip looked healthy. `program` names
+        the compiled program behind `call` (the site plus its shape key;
+        defaults to the site); every program's first dispatch belongs in
+        a warm-up.
+
+        After that nothing blocks here: the host enqueues and goes on,
+        and a fault the device raises surfaces where the step's outputs
+        are read (`_readback`), as PoolsLostError. A fault raised by the
+        call itself (injected through the chaos seam, or thrown by a
+        program that has already run) permanently falls the engine back
+        to the dense paths and retries the step once. Serving survives a
+        broken kernel at the cost of the dense path's speed; the fallback
+        is recorded in `kernel_fallbacks` / `kernel_fallback_error` and
+        logged.
 
         The retry calls the same closure over the same pools, so it
         stands only while they do: a fault raised before the program ran
         (the chaos seam, a trace or compile error) leaves them intact.
         One that surfaces after the call consumed them (`witness`, a pool
-        leaf that went in, is deleted) raises PoolsLostError instead.
-
-        `program` names the compiled program behind `call` (the site
-        plus its shape key; defaults to the site). The FIRST dispatch of
-        a program is where its kernel lowers and compiles, and a kernel
-        that cannot compile is not a fault to survive: it raises
-        KernelCompileError with the compiler's message, because answering
-        it with dense is how a kernel that never ran on the chip looked
-        healthy."""
+        leaf that went in, is deleted) raises PoolsLostError instead."""
         import jax
 
         from flexflow_tpu.serving.faults import KernelFault
@@ -733,17 +751,17 @@ class GenerationEngine:
         if self.decode_kernel == "dense":
             return call()
         program = site if program is None else program
+        first = program not in self._kernel_programs_run
         try:
             if self.injector is not None:
                 self.injector.maybe_kernel_fault(site)
             out = call()
-            with span(f"scheduler.step.{site}.wait", self._tracer):
-                jax.block_until_ready(out)
-                self.device_syncs += 1
+            if first:
+                with span(f"scheduler.step.{site}.wait", self._tracer):
+                    jax.block_until_ready(out)
+                    self.device_syncs += 1
         except Exception as e:
-            if program not in self._kernel_programs_run and not isinstance(
-                e, KernelFault
-            ):
+            if first and not isinstance(e, KernelFault):
                 raise KernelCompileError(
                     f"{site} step with decode_kernel="
                     f"{self.decode_kernel!r} failed on the first dispatch "
@@ -834,9 +852,18 @@ class GenerationEngine:
 
     def _readback(self, kind: str, *arrays):
         """Bring a step's device outputs to the host: one blocking read
-        each, counted where it happens."""
+        each, counted where it happens. This is where a fault the device
+        raised while running the step's program surfaces (`_dispatch`
+        blocks on a program's first run only), and by then the program
+        has consumed the pools it was handed: PoolsLostError."""
         with span(f"scheduler.step.{kind}.readback", self._tracer):
-            out = [np.asarray(a) for a in arrays]
+            try:
+                out = [np.asarray(a) for a in arrays]
+            except Exception as e:
+                raise PoolsLostError(
+                    f"{kind} step failed after its program consumed the "
+                    f"KV pools: {e!r}"
+                ) from e
             self.device_syncs += len(out)
             self.readback_bytes += sum(a.nbytes for a in out)
         return out
@@ -914,10 +941,12 @@ class GenerationEngine:
 
     @staticmethod
     def _step_counts(moe_counts, share=None):
-        """What a step program appends to its outputs: () for a model
-        without expert layers, else the int32 sum over layers of their
-        counts (`_count_fields` names the entries), and after it, from a
-        model that holds a share, every layer's choice stacked."""
+        """What a step program returns beside its tokens and logits (the
+        prefill appends it to its outputs, the decode step packs the
+        counts into its readback): () for a model without expert layers,
+        else the int32 sum over layers of their counts (`_count_fields`
+        names the entries), and after it, from a model that holds a
+        share, every layer's choice stacked."""
         import jax.numpy as jnp
 
         out = (sum(moe_counts[1:], moe_counts[0]),) if moe_counts else ()
@@ -925,14 +954,13 @@ class GenerationEngine:
             out += (jnp.stack(share["chosen"]),)
         return out
 
-    def _split_counts(self, kind: str, out):
-        """A prefill or decode program's outputs without what
-        `_step_counts` appended, and the counts as a 0- or 1-tuple; the
-        choice stays on the device as `moe_choice[kind]`."""
+    def _keep_choice(self, kind: str, out):
+        """A prefill or decode program's outputs without the routers'
+        choice that `_step_counts` appended last for a model that holds a
+        share: it stays on the device as `moe_choice[kind]`."""
         if self._moe_share:
             *out, self.moe_choice[kind] = out
-        n = len(out) - bool(self._count_fields)
-        return tuple(out[:n]), tuple(out[n:])
+        return out
 
     def _count(self, kind: str, counts) -> None:
         for name, got in zip(self._count_fields, counts):
@@ -1199,7 +1227,7 @@ class GenerationEngine:
                 jnp.asarray(row_tables), jnp.asarray(plens),
             )
         with span("scheduler.step.prefill.dispatch", self._tracer):
-            (nxt, last), moe = self._split_counts(
+            nxt, last, *moe = self._keep_choice(
                 "prefill",
                 self._run_step(
                     "prefill", lambda: fn, params, inputs,
@@ -1369,31 +1397,49 @@ class GenerationEngine:
         )[:, -1, :]
         return new_k, new_v, new_ks, new_vs, logits
 
+    #: columns of a decode step's packed host state in front of the slot's
+    #: block table: the host's view of the last token, whether the token
+    #: comes from the chained step instead, the cache length, and activity
+    _STATE_COLUMNS = 4
+
     def _decode_impl_paged(
-        self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
-        ad=None,
+        self, params, chained, state, ck, cv, cks, cvs, ad=None,
     ):
         """The single-step jit target: one _decode_core_paged forward
         plus the per-slot sample (the sampled token will be written at
-        cache position lengths + 1)."""
+        cache position lengths + 1).
+
+        Everything the host knows about the step arrives as ONE int32
+        array, `state` [max_seqs, _STATE_COLUMNS + max_pages_per_seq]
+        (`_pack_state`: token, chain flag, length, active, block table),
+        and everything it decides on leaves as one: after the pools, the
+        sampled tokens [max_seqs], the logits [max_seqs, V] (read by who
+        asks), and `readback`, int32 [2 * max_seqs + counts]: the tokens
+        again, whether each slot's logits row is finite, and the expert
+        layers' counts. `chained`: None, or the in-flight previous step's
+        sampled tokens, taken where the chain flag is set, so that
+        consecutive steps' data dependency stays on the device."""
         import jax.numpy as jnp
 
+        tokens, from_chain, lengths = state[:, 0], state[:, 1], state[:, 2]
+        active = state[:, 3] != 0
+        tables = state[:, self._STATE_COLUMNS:]
+        if chained is not None:
+            tokens = jnp.where(from_chain != 0, chained, tokens)
         moe = []
         share = self._share(active[:, None])
         new_k, new_v, new_ks, new_vs, logits = self._decode_core_paged(
-            params, tokens, lengths, active, tables, ck, cv, cks, cvs, ad,
-            moe, share,
+            params, tokens[:, None], lengths, active, tables, ck, cv, cks,
+            cvs, ad, moe, share,
         )
         slots = jnp.arange(lengths.shape[0])
-        return (
-            new_k,
-            new_v,
-            new_ks,
-            new_vs,
-            self._pick(logits, slots, lengths + 1),
-            logits,
-            *self._step_counts(moe, share),
+        nxt = self._pick(logits, slots, lengths + 1)
+        extra = self._step_counts(moe, share)
+        n = bool(self._count_fields)  # the counts, then the choice
+        readback = jnp.concatenate(
+            [nxt, jnp.isfinite(logits).all(-1).astype(jnp.int32), *extra[:n]]
         )
+        return new_k, new_v, new_ks, new_vs, nxt, logits, readback, *extra[n:]
 
     # -- device-resident multi-step decode -----------------------------------
 
@@ -1472,6 +1518,26 @@ class GenerationEngine:
         ) = jax.lax.scan(body, carry0, jnp.arange(k_bucket))
         return nk, nv, nks, nvs, lens, toks, toks_ks, logits_ks, mask_ks
 
+    def _pack_state(self, tokens, from_chain, active) -> np.ndarray:
+        """A decode step's host state as the ONE int32 array
+        `_decode_impl_paged` takes apart: per slot the last token, the
+        chain flag, the cache length, activity, then the block table. A
+        fresh array each call; the lengths and tables in it are the
+        host's mutable state, so it goes to the device through
+        `snapshot()` like them."""
+        cache = self.cache
+        c = self._STATE_COLUMNS
+        state = np.empty(
+            (cache.spec.max_seqs, c + cache.block_tables.shape[1]),
+            dtype=np.int32,
+        )
+        state[:, 0] = tokens
+        state[:, 1] = from_chain
+        state[:, 2] = cache.lengths
+        state[:, 3] = active
+        state[:, c:] = cache.block_tables
+        return state
+
     def decode_dispatch(
         self,
         params,
@@ -1480,70 +1546,57 @@ class GenerationEngine:
         chain: Optional[InflightStep] = None,
         chain_mask: Optional[np.ndarray] = None,
     ) -> InflightStep:
-        """Enqueue one decode iteration WITHOUT blocking on its outputs.
+        """Enqueue one decode iteration WITHOUT blocking on its outputs
+        (but for a program's first dispatch on a kernel path, which
+        `_dispatch` forces: a warm-up's).
 
         tokens [max_seqs] (last emitted token per slot; free slots can
-        carry anything), active_mask [max_seqs] bool. The functional
-        cache arrays commit immediately (they are device futures — the
-        next dispatch chains on them on-device) and active lengths bump,
-        so the host's view is reserved-one-step-ahead; the sampled
-        tokens/logits stay device futures on the returned InflightStep
-        until `decode_reconcile`.
+        carry anything), active_mask [max_seqs] bool. They cross to the
+        device with the lengths and the block tables as one packed
+        argument (`_pack_state`). The functional cache arrays commit
+        immediately (they are device futures — the next dispatch chains
+        on them on-device) and active lengths bump, so the host's view
+        is reserved-one-step-ahead. What the reconcile will read, the
+        step's `device_readback`, starts its copy to the host here, right
+        behind the program; the sampled tokens and the logits stay device
+        futures on the returned InflightStep.
 
         `chain` + `chain_mask` pipeline two decode steps with no host
         round-trip: where chain_mask is set, the input token comes from
         the in-flight `chain` step's device_next instead of the host
         `tokens` row — the data dependency between step N and N+1
-        resolves entirely on device."""
-        import jax.numpy as jnp
-
+        resolves entirely on device (the program's second argument; None
+        without a chain, which is a program of its own)."""
+        active = np.asarray(active_mask, dtype=bool)
         # the next page, for any sequence about to cross a page boundary
-        self._claim_rows(np.asarray(active_mask, dtype=np.int32))
+        self._claim_rows(active.astype(np.int32))
         host_tokens = np.asarray(tokens, dtype=np.int32)
         mask = (
             np.asarray(chain_mask, dtype=bool)
             if chain is not None and chain_mask is not None
-            else None
+            else np.zeros_like(active)
         )
-        if mask is None or not mask.any():
-            dev_tokens = jnp.asarray(host_tokens)
-        elif mask.all() or np.array_equal(
-            mask, np.asarray(active_mask, dtype=bool)
-        ):
-            # steady state: every stepped slot chains on the in-flight
-            # step — its device_next IS the token vector (inactive rows
-            # carry garbage the active mask already hides)
-            dev_tokens = chain.device_next
-        else:
-            # device_next is already int32 (_pick's contract)
-            dev_tokens = jnp.where(
-                jnp.asarray(mask), chain.device_next, jnp.asarray(host_tokens)
-            )
+        chained = chain.device_next if mask.any() else None
         lengths_snap = np.array(self.cache.lengths)
-        # snapshot() every mutable host array (lengths += 1 below,
-        # allocator table edits between iterations mutate behind the
-        # async dispatch queue); the locals built above are fresh per
-        # call and safe to hand over directly
-        (nxt, logits), moe = self._split_counts(
+        # snapshot(): lengths += 1 below, and allocator table edits
+        # between iterations, mutate behind the async dispatch queue
+        nxt, logits, readback = self._keep_choice(
             "decode",
             self._run_step(
                 "decode",
                 lambda: self._decode_jit,
                 params,
-                (
-                    dev_tokens[:, None],
-                    snapshot(self.cache.lengths),
-                    jnp.asarray(active_mask),
-                    snapshot(self.cache.block_tables),
-                ),
+                (chained, snapshot(self._pack_state(host_tokens, mask, active))),
                 self._adapter_slot_args(),
+                program=("decode", "host" if chained is None else "chained"),
             ),
         )
-        self.cache.lengths[np.asarray(active_mask)] += 1
+        readback.copy_to_host_async()
+        self.cache.lengths[active] += 1
         if self._latent:
             # the live latent rows this step attends, the new one included
             self.mla_rows_read_decode += len(self._latent) * int(
-                self.cache.lengths[np.asarray(active_mask)].sum()
+                self.cache.lengths[active].sum()
             )
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
@@ -1551,32 +1604,33 @@ class GenerationEngine:
         return InflightStep(
             kind="decode",
             dispatch_t=time.perf_counter(),
-            active=np.array(active_mask, dtype=bool),
+            active=active.copy(),
             lengths=lengths_snap,
             host_tokens=host_tokens,
             device_next=nxt,
             device_logits=logits,
-            device_moe=moe,
+            device_readback=readback,
         )
 
     def decode_reconcile(
         self, step: InflightStep
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Block on a dispatched decode step's device outputs and close
-        its in-flight window. Returns (next_tokens [max_seqs], logits
-        [max_seqs, V]) as host arrays. Everything else the caller needs
-        lives on the step record's snapshots — by the time this runs,
-        live cache/scheduler state is one iteration ahead."""
+        """Wait, once, for the one small array a dispatched decode step
+        started copying to the host (`step.device_readback`), and close
+        the step's in-flight window. Returns (next_tokens [max_seqs]
+        int32, finite [max_seqs] bool: whether the slot's logits row is
+        finite) as host arrays; the logits themselves stay on the device
+        (`step.device_logits`) for who asks. Everything else the caller
+        needs lives on the step record's snapshots — by the time this
+        runs, live cache/scheduler state is one iteration ahead."""
         try:
-            nxt, logits, *counts = self._readback(
-                "decode", step.device_next, step.device_logits,
-                *step.device_moe,
-            )
+            (got,) = self._readback("decode", step.device_readback)
         finally:
             self.cache.end_inflight()
-        if counts:
-            self._count("decode", counts[0])
-        return nxt, logits
+        n = step.active.shape[0]
+        if self._count_fields:
+            self._count("decode", got[2 * n:])
+        return got[:n], got[n: 2 * n] != 0
 
     def decode(
         self,
@@ -1587,10 +1641,12 @@ class GenerationEngine:
         """One decode iteration over every slot — the synchronous wrapper
         (dispatch + immediate reconcile); the async loop calls the two
         halves an iteration apart. Writes the cache, bumps active
-        lengths, returns (next_tokens [max_seqs], logits [max_seqs, V])."""
-        return self.decode_reconcile(
-            self.decode_dispatch(params, tokens, active_mask)
-        )
+        lengths, returns (next_tokens [max_seqs], logits [max_seqs, V]):
+        this caller asks for the logits, so it reads them."""
+        step = self.decode_dispatch(params, tokens, active_mask)
+        nxt, _ = self.decode_reconcile(step)
+        (logits,) = self._readback("decode", step.device_logits)
+        return nxt, logits
 
     def decode_multi_dispatch(
         self,

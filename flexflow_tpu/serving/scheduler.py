@@ -1639,7 +1639,7 @@ class _SchedulerBase:
         self.stats.overlapped_host_s += max(0.0, t0 - step.dispatch_t)
         try:
             if step.kind == "decode":
-                nxt, logits = self.engine.decode_reconcile(step)
+                nxt, finite = self.engine.decode_reconcile(step)
             elif step.kind == "chunk":
                 nxt, logits = self.engine.prefill_chunk_reconcile(step)
             elif step.kind == "multistep":
@@ -1664,7 +1664,7 @@ class _SchedulerBase:
             {"iter": step.iteration, "step": step.seq},
         ):
             if step.kind == "decode":
-                self._commit_decode(step, nxt, logits)
+                self._commit_decode(step, nxt, finite)
             elif step.kind == "chunk":
                 self._commit_chunk(step, nxt, logits)
             elif step.kind == "multistep":
@@ -1686,25 +1686,29 @@ class _SchedulerBase:
                 args={"iter": step.iteration},
             )
 
-    def _commit_decode(self, step, nxt, logits) -> None:
+    def _commit_decode(self, step, nxt, finite) -> None:
         """Commit a reconciled decode step: NaN isolation, token emit,
-        EOS/budget retirement. Reads ONLY the step's snapshot — live
-        scheduler/cache state is an iteration ahead under the async
-        loop (fxlint FX103 holds this path to the snapshot). A
-        participant that retired, was preempted, or whose slot was
-        re-admitted while the step was in flight fails the identity
-        check and its speculative token is discarded."""
+        EOS/budget retirement. `finite` [max_seqs] bool is the step's own
+        verdict on each slot's logits row (the rows stay on the device).
+        Reads ONLY the step's snapshot — live scheduler/cache state is an
+        iteration ahead under the async loop (fxlint FX103 holds this
+        path to the snapshot). A participant that retired, was preempted,
+        or whose slot was re-admitted while the step was in flight fails
+        the identity check and its speculative token is discarded."""
         active_slots = [s for s, a in enumerate(step.active) if a]
         if self.injector is not None:
-            logits = np.array(logits)  # writable copy for the injector
+            # the injector plants NaN in rows of host logits: here a row
+            # is the one value that stands for the slot's verdict
+            rows = np.where(finite, 0.0, np.nan)
             self.injector.corrupt_logits(
-                logits, active_slots, iteration=step.iteration
+                rows, active_slots, iteration=step.iteration
             )
+            finite = np.isfinite(rows)
         for slot in active_slots:
             req = step.participants.get(slot)
             if req is None or self.running.get(slot) is not req:
                 continue
-            if not np.isfinite(logits[slot]).all():
+            if not finite[slot]:
                 self._fail(
                     req,
                     f"non-finite logits at iteration {step.iteration}",

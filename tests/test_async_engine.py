@@ -482,6 +482,7 @@ def test_inflight_step_snapshot_is_immutable_view(lm):
     pre = int(step.lengths[slot])
     cache.lengths[slot] = 31  # hostile post-dispatch mutation
     assert int(step.lengths[slot]) == pre
-    nxt, logits = engine.decode_reconcile(step)
-    assert np.isfinite(logits[slot]).all()
-    assert nxt.shape == (4,)
+    nxt, finite = engine.decode_reconcile(step)
+    assert finite.shape == (4,) and finite.dtype == bool and finite[slot]
+    assert np.isfinite(np.asarray(step.device_logits)[slot]).all()
+    assert nxt.shape == (4,) and nxt.dtype == np.int32

@@ -184,9 +184,9 @@ def case_scopes(model):
 
     n = spec.max_seqs
     text = engine._decode_jit.trace(
-        model.params, s((n, 1)), s((n,)), s((n,), jnp.bool_),
-        s((n, spec.max_pages_per_seq)), cache.k, cache.v, cache.k_scale,
-        cache.v_scale,
+        model.params, None,
+        s((n, engine._STATE_COLUMNS + spec.max_pages_per_seq)),
+        cache.k, cache.v, cache.k_scale, cache.v_scale,
     ).lower().as_text(debug_info=True)
     for scope in ("mla.project", "mla.absorb", "mla.attend", "mla.out",
                   "moe.route", "moe.experts", "moe.shared"):
