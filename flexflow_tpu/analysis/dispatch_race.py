@@ -730,7 +730,7 @@ def _adapter_violations(tree: ast.Module) -> List[Tuple[str, int, str]]:
     (or AugAssign) into an ``adapter_tables`` / ``slot_adapter`` /
     ``_adapter_refcounts`` attribute, or a ``heapq.heappush``/
     ``heappop`` whose argument reaches the ``_free_adapter_pages``
-    heap. Reads never match — ``slot_tables``/``row_tables`` gather
+    heap. Reads never match — ``slot_tables`` gathers
     from the ledgers freely, and ``check_invariants`` audits them.
     Module-level code reports under the pseudo-name '<module>'."""
     found: List[Tuple[str, int, str]] = []

@@ -79,11 +79,14 @@ def _infer_mha(input_shapes, params):
 
 
 def scaled_dot_product_attention(
-    q, k, v, causal=False, bias=None, dropout_rate=0.0, dropout_rng=None
+    q, k, v, causal=False, bias=None, dropout_rate=0.0, dropout_rng=None,
+    allowed=None,
 ):
     """q,k,v: [b, s, h, d] — plain XLA attention; fp32 softmax accumulation.
     dropout is applied to the attention probabilities (reference: cudnn MHA
-    attnDropout)."""
+    attnDropout). `allowed` bool [b or 1, q, k]: the keys each query may
+    see, where that is not the lower triangle `causal` stands for (rows
+    that hold several sequences end to end); the same -1e30 fill."""
     d = q.shape[-1]
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
@@ -94,6 +97,8 @@ def scaled_dot_product_attention(
         qlen, klen = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((qlen, klen), dtype=bool))
         logits = jnp.where(mask, logits, -1e30)
+    if allowed is not None:
+        logits = jnp.where(allowed[:, None, :, :], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     if dropout_rate > 0.0 and dropout_rng is not None:
         keep = 1.0 - dropout_rate
@@ -196,7 +201,7 @@ def mha_project_out(attn, ws, ctx, out_dtype, use_bias=True):
     return y
 
 
-def lora_delta_qkv(x, tbl, a_q, b_q, a_k, b_k, a_v, b_v):
+def lora_delta_qkv(x, tbl, a_q, b_q, a_k, b_k, a_v, b_v, owner=None):
     """Batched paged LoRA deltas for the Q/K/V projections (S-LoRA /
     Punica posture): per batch row, gather that row's adapter pages out
     of the pooled A/B factors and compute `(x @ A) @ B` summed over the
@@ -208,26 +213,42 @@ def lora_delta_qkv(x, tbl, a_q, b_q, a_k, b_k, a_v, b_v):
     0.0). a_*: [NP+1, e, pr]; b_*: [NP+1, pr, h, d]. Returns three
     [b, s, h, d] float32 deltas. Every contraction is per-batch-row
     independent — a mixed-adapter batch computes bit-identically to
-    each row running alone, which the identity gates rely on."""
+    each row running alone, which the identity gates rely on.
+
+    `owner` [s, n] one-hot float32, for ONE row x [1, s, e] that holds
+    several requests' tokens end to end (the packed prefill): tbl is then
+    [n, P], a page table for each request, and token t takes the delta of
+    the request that owns it. The rank activations of every request are
+    computed for every token (rank-r work) and all but the owner's zeroed,
+    so the sum over requests adds exact zeros to the owner's delta."""
     mm = dict(preferred_element_type=jnp.float32)
     x32 = x.astype(jnp.float32)
 
     def delta(a_pool, b_pool):
         # u: [b, s, P, pr] rank activations per page, then contract the
         # (page, rank-slice) pair back out through B
+        if owner is not None:
+            u = jnp.einsum("se,nper->snpr", x32[0], a_pool[tbl], **mm)
+            u = u * owner[:, :, None, None]
+            return jnp.einsum("snpr,nprhd->shd", u, b_pool[tbl], **mm)[None]
         u = jnp.einsum("bse,bper->bspr", x32, a_pool[tbl], **mm)
         return jnp.einsum("bspr,bprhd->bshd", u, b_pool[tbl], **mm)
 
     return delta(a_q, b_q), delta(a_k, b_k), delta(a_v, b_v)
 
 
-def lora_delta_out(attn, tbl, a_o, b_o):
+def lora_delta_out(attn, tbl, a_o, b_o, owner=None):
     """Paged LoRA delta for the output projection — the post-kernel
     epilogue: the attention core (dense or Pallas) runs unmodified and
     the delta applies to its [b, s, h, d] output. a_o: [NP+1, h, d, pr];
     b_o: [NP+1, pr, e]. Returns a [b, s, e] float32 delta with the same
-    per-row independence as lora_delta_qkv."""
+    per-row independence as lora_delta_qkv, and its `owner`."""
     mm = dict(preferred_element_type=jnp.float32)
+    if owner is not None:
+        u = jnp.einsum(
+            "shd,nphdr->snpr", attn[0].astype(jnp.float32), a_o[tbl], **mm
+        ) * owner[:, :, None, None]
+        return jnp.einsum("snpr,npre->se", u, b_o[tbl], **mm)[None]
     u = jnp.einsum(
         "bshd,bphdr->bspr", attn.astype(jnp.float32), a_o[tbl], **mm
     )
@@ -1200,10 +1221,12 @@ def mla_project(x, ws, params, ctx, positions=None):
         return q[..., :dn], q_rope, jnp.concatenate([c, kr], axis=-1)
 
 
-def mla_decompressed(q_nope, q_rope, latent, ws, params, ctx):
-    """Causal attention of every position over those before it, keys and
-    values decompressed from `latent` [b, s, rank + rope] -> [b, s, h, v].
-    The scale is 1 / sqrt(nope + rope)."""
+def mla_decompressed(q_nope, q_rope, latent, ws, params, ctx, allowed=None):
+    """Causal attention of every position over those before it (or over
+    the keys `allowed` [b or 1, s, s] gives it, as in
+    scaled_dot_product_attention), keys and values decompressed from
+    `latent` [b, s, rank + rope] -> [b, s, h, v]. The scale is
+    1 / sqrt(nope + rope)."""
     r, dn, _, _ = _mla_dims(params)
     with jax.named_scope("mla.project"):
         c, wkvb = mm_operands(ctx, latent[..., :r], ws[3])
@@ -1217,7 +1240,9 @@ def mla_decompressed(q_nope, q_rope, latent, ws, params, ctx):
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([kv[..., :dn], kr], axis=-1)
     with jax.named_scope("mla.attend"):
-        return scaled_dot_product_attention(q, k, kv[..., dn:], causal=True)
+        return scaled_dot_product_attention(
+            q, k, kv[..., dn:], causal=allowed is None, allowed=allowed
+        )
 
 
 def mla_absorb_query(q_nope, q_rope, ws, params, ctx, row):

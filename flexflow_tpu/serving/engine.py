@@ -6,12 +6,13 @@ computes the exact training projections (ops/attention.mha_project_qkv /
 mha_project_out — shared code, not a reimplementation) and swaps only the
 attention core:
 
-  * **prefill**: causal dense attention over the (padded) prompt, exactly
-    the training forward — and captures each layer's K/V, scattered into
-    the cache pages of the admitted slots. The last valid position's
-    logits yield the first generated token, so admission itself produces
-    a token (Orca's iteration-level view: a prefill is just a fat
-    iteration).
+  * **prefill**: the admitted prompts laid end to end in ONE row of
+    `bucket(total)` tokens, dense attention causal inside each prompt
+    (the training forward's, under a mask of segments) — and captures
+    each layer's K/V, scattered token by token into the cache pages of
+    the admitted slots. Each prompt's last position's logits yield its
+    first generated token, so admission itself produces a token (Orca's
+    iteration-level view: a prefill is just a fat iteration).
   * **decode**: one query position per slot. The new K/V row is written
     at position `lengths[slot]`, then
     `ops.attention.paged_decode_attention` runs masked one-query attention
@@ -23,8 +24,8 @@ attention core:
 The cache is block-paged (kv_cache.PagedKVCache): every step
 routes K/V rows through the slot's block table — prefill scatters each
 captured row into `page * page_size + offset` of the flattened pool
-(sentinel table entries produce out-of-bounds destinations that JAX
-drops, so pad rows and unallocated positions never touch live pages),
+(the padding behind the last prompt is given an out-of-bounds
+destination that JAX drops, so it never touches a live page),
 decode writes the one new row the same way and attends via
 `ops.attention.paged_decode_attention`. Block tables ride into the
 jitted steps as an ordinary `[max_seqs, max_pages_per_seq]` int32
@@ -66,7 +67,8 @@ only the FINAL chunk's sampled token means anything (the scheduler
 discards the rest).
 
 All steps are jitted with static shapes: decode always runs at
-`[max_seqs, 1]`, prefill at `[max_seqs, bucket]` per length bucket,
+`[max_seqs, 1]`, prefill at `[1, bucket]` per bucket of the admitted
+prompts' total length (an admission over the largest bucket runs several),
 verify at `[max_seqs, w]` per draft width, so compile count is
 1 + #buckets + #draft-widths for an entire serving session (tables
 are data, not shape).
@@ -220,6 +222,30 @@ class _JitCache:
         return key in self._entries
 
 
+class _PackedChoice:
+    """The routers' choice of one admission's prefill programs, as they
+    left it: int32 [expert layers, 1, T, k] a program, the prompts end to
+    end, on the device. `np.asarray` of it reads them back and lays them
+    out [expert layers, row of the call, position, k] (-1 past a prompt's
+    end), across the programs of a split admission."""
+
+    def __init__(self, programs):
+        self._programs = programs  # [(device choice, the prompts' lengths)]
+
+    def __array__(self, dtype=None, copy=None):
+        lens = [n for _, ns in self._programs for n in ns]
+        packed = [np.asarray(choice) for choice, _ in self._programs]
+        layers, _, _, k = packed[0].shape
+        out = np.full((layers, len(lens), max(lens), k), -1, np.int32)
+        row = 0
+        for choice, (_, ns) in zip(packed, self._programs):
+            at = 0
+            for n in ns:
+                out[:, row, :n] = choice[:, 0, at : at + n]
+                row, at = row + 1, at + n
+        return out if dtype is None else out.astype(dtype)
+
+
 @dataclasses.dataclass
 class InflightStep:
     """One dispatched-but-not-reconciled engine step.
@@ -345,11 +371,13 @@ class GenerationEngine:
         # counted inside the span that does the work, mirrored into
         # SchedulerStats at each iteration's end: blocking reads of a
         # device value and the bytes they brought to the host; prompt
-        # tokens prefilled and the [max_seqs, bucket] tokens they ran as
+        # tokens prefilled, the bucket(total) tokens of the rows they were
+        # packed into, and the prefill programs dispatched
         self.device_syncs = 0
         self.readback_bytes = 0
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
+        self.prefill_programs = 0
         # how the decode/verify attention core runs (threaded into every
         # ops.attention call below): "auto" = Pallas decode kernel on TPU
         # when the geometry supports() it, "pallas" = force the kernel
@@ -506,9 +534,11 @@ class GenerationEngine:
             and graph.nodes[g].inputs[0] in routed_inputs
         )
         # of a model whose layers hold a share: the experts every token
-        # picked in the last prefill / single-step decode program, int32
-        # [expert layers, max_seqs, positions, k], padding rows included,
-        # left on the device (nothing reads it back but who asks)
+        # picked in the last single-step decode program (int32 [expert
+        # layers, max_seqs, 1, k], idle slots included) and in the last
+        # admission's prefill programs ([expert layers, row of the call,
+        # position, k] to `np.asarray`: `_PackedChoice`), left on the
+        # device (nothing reads it back but who asks)
         self.moe_choice: Dict[str, object] = {}
         self.moe_rows_prefill = 0
         self.moe_rows_decode = 0
@@ -659,16 +689,6 @@ class GenerationEngine:
         if self.adapters is None:
             return ()
         tbl, has = self.adapters.slot_tables()
-        return (
-            (snapshot(tbl), snapshot(has), self.adapters.device_pools),
-        )
-
-    def _adapter_row_args(self, slots):
-        """Prefill twin of `_adapter_slot_args`: batch row i serves slot
-        `slots[i]`, pad rows gather the zero sentinel."""
-        if self.adapters is None:
-            return ()
-        tbl, has = self.adapters.row_tables(slots, self.cache.spec.max_seqs)
         return (
             (snapshot(tbl), snapshot(has), self.adapters.device_pools),
         )
@@ -935,7 +955,7 @@ class GenerationEngine:
     def _share(self, live):
         """What `_forward_logits` hands the expert layers of a model that
         holds a share of its experts (None otherwise): which tokens are
-        someone's, `live` bool [max_seqs, positions], and the list that
+        someone's, `live` bool [rows, positions], and the list that
         receives each layer's choice."""
         return {"live": live, "chosen": []} if self._moe_share else None
 
@@ -954,12 +974,12 @@ class GenerationEngine:
             out += (jnp.stack(share["chosen"]),)
         return out
 
-    def _keep_choice(self, kind: str, out):
-        """A prefill or decode program's outputs without the routers'
-        choice that `_step_counts` appended last for a model that holds a
-        share: it stays on the device as `moe_choice[kind]`."""
+    def _keep_choice(self, out):
+        """A decode program's outputs without the routers' choice that
+        `_step_counts` appended last for a model that holds a share: it
+        stays on the device as `moe_choice["decode"]`."""
         if self._moe_share:
-            *out, self.moe_choice[kind] = out
+            *out, self.moe_choice["decode"] = out
         return out
 
     def _count(self, kind: str, counts) -> None:
@@ -1019,7 +1039,8 @@ class GenerationEngine:
         """Quantize `rows` [N, heads, head_dim] into the int8 `pool` at
         flat row indices `dest` [N] (out-of-bounds rows drop, exactly
         like the fp32 scatter). A page's fp32 scale is claimed exactly
-        once, from the abs-max of its FIRST row (position page_size·p):
+        once, from the abs-max of its FIRST row (position page_size·p;
+        `kv_cache.int8_page_scale`):
         sequential streaming guarantees a fresh page's first write
         contains that row, and the first row's content is a pure
         function of the token history — so the scale (and therefore the
@@ -1033,13 +1054,15 @@ class GenerationEngine:
         read exactly what a later pool reader will see."""
         import jax.numpy as jnp
 
+        from flexflow_tpu.serving.kv_cache import int8_page_scale
+
         spec = self.cache.spec
         page = dest // spec.page_size  # OOB dest -> OOB page, dropped
         f32 = rows.astype(jnp.float32)
         amax = jnp.max(jnp.abs(f32), axis=-1)  # [N, heads]
         first = (dest % spec.page_size == 0)[:, None]  # page-initial rows
         cand = jnp.zeros_like(scale).at[page].max(
-            jnp.where(first, amax / 127.0, 0.0), mode="drop"
+            jnp.where(first, int8_page_scale(amax), 0.0), mode="drop"
         )
         # a batch that writes a page's first row (RE)DERIVES its scale —
         # never trust a stored value then: freed pages keep stale scales
@@ -1068,24 +1091,37 @@ class GenerationEngine:
 
     # -- prefill -------------------------------------------------------------
 
+    #: rows of a prefill's packed per-token layout, then of its per-request one
+    _TOKEN_ROWS = 4  # token id, segment, position, pool destination
+    _REQUEST_ROWS = 3  # slot, prompt length, index of the prompt's last token
+
     def _prefill_impl_paged(
-        self, params, tokens, slot_ids, row_tables, prompt_lens, ck, cv,
-        cks, cvs, ad=None,
+        self, params, layout, requests, ck, cv, cks, cvs, ad=None,
     ):
-        """tokens [max_seqs, bucket] int32; slot_ids [max_seqs] (max_seqs
-        for padding rows) only seed the per-slot sampling keys — routing
-        is entirely through row_tables [max_seqs, ceil(bucket/page_size)]
-        int32: the admitted slots' block-table prefixes (pad rows and
-        unallocated entries carry the sentinel num_pages); prompt_lens
-        [max_seqs] (>=1; pad rows use 1). Captured K/V rows scatter
-        into the flattened pools at `page * page_size + offset`; sentinel
-        pages put the destination out of bounds, which JAX drops — so
-        bucket padding past a prompt's allocated pages writes nothing.
-        `ad` is the optional batch-row-aligned adapter gather (tables,
-        has, pools) — None leaves the traced HLO exactly the base
-        engine's. Returns (ck', cv', cks', cvs', next_tokens,
-        last_logits), and for a model with expert layers their [2] int32
-        (rows computed, experts touched) after them."""
+        """One prefill program over the admitted prompts laid END TO END
+        in one row of T tokens (T a prefill bucket, of the prompts' TOTAL).
+
+        `layout` int32 [_TOKEN_ROWS, T], per token: its id; its `segment`,
+        the request of this call it belongs to (max_seqs for the padding
+        behind the last prompt); its `position` in its own prompt; and
+        `dest`, the flat pool row its K/V go to, `page * page_size +
+        offset` through the slot's block table (out of bounds for padding,
+        which JAX drops: nothing is written outside the prompts' pages).
+        `requests` int32 [_REQUEST_ROWS, max_seqs], per request of the
+        call: its slot (max_seqs for the rows past the last; it seeds the
+        sampling key and names the adapter), its prompt's length (1 there)
+        and where its last token stands in the row.
+
+        Attention is causal INSIDE a segment: a token sees the tokens of
+        its own prompt at or before its position and no other's, so a
+        prompt's rows, logits and token do not depend on what it was
+        packed with. An expert layer sorts T x k rows. `ad` is the
+        optional slot-indexed adapter gather (tables, has, pools): None
+        leaves the traced HLO exactly the base engine's. Returns (ck',
+        cv', cks', cvs', next_tokens [max_seqs], last_logits [max_seqs,
+        V]: each request's last position, in the call's order), and for a
+        model with expert layers their int32 counts after them (and the
+        routers' choice [expert layers, 1, T, k], `_step_counts`)."""
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -1097,21 +1133,25 @@ class GenerationEngine:
             scaled_dot_product_attention,
         )
         from flexflow_tpu.serving.tenancy.adapters import (
+            adapter_tokens,
             apply_adapter_out,
             apply_adapter_qkv,
         )
 
         spec = self.cache.spec
-        ps = spec.page_size
-        bucket = tokens.shape[1]
-        pos = jnp.arange(bucket)
-        # [max_seqs, bucket] flat pool destinations through the table
-        dest = (row_tables[:, pos // ps] * ps + pos % ps).reshape(-1)
+        tokens, segment, position, dest = layout
+        slot_ids, prompt_lens, last_at = requests
+        live = segment < spec.max_seqs
+        allowed = (
+            (segment[:, None] == segment[None, :])
+            & (position[None, :] <= position[:, None])
+        )[None]
+        ad = adapter_tokens(ad, jnp.where(live, slot_ids[segment], spec.max_seqs))
         quant = self.cache.quantized
         new_k, new_v = {}, {}
         new_ks, new_vs = dict(cks), dict(cvs)
 
-        positions = self._positions(lambda: pos)
+        positions = self._positions(lambda: position[None, :])
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -1140,7 +1180,7 @@ class GenerationEngine:
             else:
                 new_k[g] = self._write_rows(ck[g], k, dest)
                 new_v[g] = self._write_rows(cv[g], v, dest)
-            attn = scaled_dot_product_attention(q, k, v, causal=True)
+            attn = scaled_dot_product_attention(q, k, v, allowed=allowed)
             out = mha_project_out(
                 attn, ws, ctx, ins[0].dtype, use_bias=use_bias
             )
@@ -1152,21 +1192,18 @@ class GenerationEngine:
                 ins[0], ws, node.params, ctx, positions
             )
             new_k[node.guid] = self._write_latent(ck[node.guid], latent, dest)
-            attn = mla_decompressed(q_nope, q_rope, latent, ws, node.params, ctx)
+            attn = mla_decompressed(
+                q_nope, q_rope, latent, ws, node.params, ctx, allowed
+            )
             return [mla_project_out(attn, ws, ctx, ins[0].dtype)]
 
         moe = []
-        share = self._share(
-            (jnp.arange(tokens.shape[1])[None, :] < prompt_lens[:, None])
-            & (slot_ids < tokens.shape[0])[:, None]
-        )
+        share = self._share(live[None, :])
         logits = self._forward_logits(
-            params, tokens, hook, moe, latent_hook if self._latent else None,
-            share,
+            params, tokens[None, :], hook, moe,
+            latent_hook if self._latent else None, share,
         )
-        last = jnp.take_along_axis(
-            logits, (prompt_lens - 1)[:, None, None], axis=1
-        )[:, 0]
+        last = logits[0, last_at]
         return (
             new_k,
             new_v,
@@ -1183,63 +1220,103 @@ class GenerationEngine:
         prompts: Sequence[Sequence[int]],
         slots: Sequence[int],
     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Run one admission batch; writes the cache in place (commit) and
-        updates slot lengths. Returns (next_tokens [n], last_logits [n, V])
-        for the n real rows."""
-        import jax.numpy as jnp
-
+        """Run one admission; writes the cache in place (commit) and
+        updates slot lengths. The prompts are packed end to end into one
+        row of `bucket(total)` tokens, one program (`_prefill_impl_paged`);
+        an admission whose total exceeds the largest bucket is split, in
+        the order given, into consecutive groups that fit, one program a
+        group, all dispatched before anything is read back. Returns
+        (next_tokens [n], last_logits [n, V]) in request order."""
         spec = self.cache.spec
         n = len(prompts)
         if n == 0:
             raise ValueError("prefill needs at least one prompt")
         if n > spec.max_seqs:
             raise ValueError(f"{n} prompts > max_seqs {spec.max_seqs}")
-        bucket = spec.bucket(max(len(p) for p in prompts))
+        groups, lo, total = [], 0, 0
+        for i, p in enumerate(prompts):
+            if not 0 < len(p) <= spec.max_len:
+                raise ValueError(
+                    f"prompt length {len(p)} outside (0, {spec.max_len}]"
+                )
+            spec.bucket(len(p))  # raises for a prompt no bucket holds
+            if total + len(p) > spec.buckets[-1]:
+                groups.append((lo, i))
+                lo, total = i, 0
+            total += len(p)
+        groups.append((lo, n))
+        outs, choices = [], []
+        for lo, hi in groups:
+            nxt, last, *moe = self._prefill_group(
+                params, prompts[lo:hi], slots[lo:hi]
+            )
+            if self._moe_share:
+                *moe, choice = moe
+                choices.append((choice, [len(p) for p in prompts[lo:hi]]))
+            outs.append((nxt[: hi - lo], last[: hi - lo], *moe))
+        if self._moe_share:
+            self.moe_choice["prefill"] = _PackedChoice(choices)
+        host = self._readback("prefill", *(a for out in outs for a in out))
+        width = len(outs[0])  # tokens, logits, and the counts if any
+        host = [host[i : i + width] for i in range(0, len(host), width)]
+        for _, _, *counts in host:
+            if counts:
+                self._count("prefill", counts[0])
+        return (
+            np.concatenate([nxt for nxt, *_ in host]),
+            np.concatenate([last for _, last, *_ in host]),
+        )
+
+    def _prefill_group(self, params, prompts, slots):
+        """Pack `prompts` (whose total fits the largest bucket) into one
+        row and dispatch its program: `_prefill_impl_paged`'s outputs
+        behind the pools, still on the device."""
+        import jax.numpy as jnp
+
+        spec = self.cache.spec
+        ps = spec.page_size
+        lens = [len(p) for p in prompts]
+        total = sum(lens)
+        bucket = spec.bucket(total)
         with span(
             "scheduler.step.prefill.pack", self._tracer,
-            {"prompts": n, "bucket": bucket},
+            {"prompts": len(prompts), "bucket": bucket},
         ):
-            tokens = np.zeros((spec.max_seqs, bucket), dtype=np.int32)
-            slot_ids = np.full(spec.max_seqs, spec.max_seqs, dtype=np.int32)
-            plens = np.ones(spec.max_seqs, dtype=np.int32)
-            for i, (p, s) in enumerate(zip(prompts, slots)):
-                if not 0 < len(p) <= spec.max_len:
-                    raise ValueError(
-                        f"prompt length {len(p)} outside (0, {spec.max_len}]"
-                    )
-                tokens[i, : len(p)] = np.asarray(p, dtype=np.int32)
-                slot_ids[i] = s
-                plens[i] = len(p)
-            self.prefill_tokens_real += int(plens[:n].sum())
-            self.prefill_tokens_padded += spec.max_seqs * bucket
+            layout = np.zeros((self._TOKEN_ROWS, bucket), dtype=np.int32)
+            layout[1, total:] = spec.max_seqs
+            layout[3, total:] = spec.total_rows
+            requests = np.zeros(
+                (self._REQUEST_ROWS, spec.max_seqs), dtype=np.int32
+            )
+            requests[0, len(prompts):] = spec.max_seqs
+            requests[1, len(prompts):] = 1
+            at = 0
+            for i, (p, s, n) in enumerate(zip(prompts, slots, lens)):
+                pos = np.arange(n)
+                layout[0, at : at + n] = np.asarray(p, dtype=np.int32)
+                layout[1, at : at + n] = i
+                layout[2, at : at + n] = pos
+                layout[3, at : at + n] = (
+                    self.cache.block_tables[s, pos // ps] * ps + pos % ps
+                )
+                requests[:, i] = (s, n, at + n - 1)
+                at += n
+            self.prefill_tokens_real += total
+            self.prefill_tokens_padded += bucket
+            self.prefill_programs += 1
             fn = self._prefill_cache.get(bucket)
             if fn is None:
                 fn = self._step_jit(self._prefill_impl_paged)
                 self._prefill_cache[bucket] = fn
-            width = -(-bucket // spec.page_size)
-            row_tables = np.full(
-                (spec.max_seqs, width), spec.num_pages, dtype=np.int32
-            )
-            for i, s in enumerate(slots):
-                row_tables[i] = self.cache.block_tables[s, :width]
-            inputs = (
-                jnp.asarray(tokens), jnp.asarray(slot_ids),
-                jnp.asarray(row_tables), jnp.asarray(plens),
-            )
+            inputs = (jnp.asarray(layout), jnp.asarray(requests))
         with span("scheduler.step.prefill.dispatch", self._tracer):
-            nxt, last, *moe = self._keep_choice(
-                "prefill",
-                self._run_step(
-                    "prefill", lambda: fn, params, inputs,
-                    self._adapter_row_args(slots), kernel_path=False,
-                ),
+            out = self._run_step(
+                "prefill", lambda: fn, params, inputs,
+                self._adapter_slot_args(), kernel_path=False,
             )
-            for p, s in zip(prompts, slots):
-                self.cache.lengths[s] = len(p)
-        nxt, last, *counts = self._readback("prefill", nxt[:n], last[:n], *moe)
-        if counts:
-            self._count("prefill", counts[0])
-        return nxt, last
+            for s, n in zip(slots, lens):
+                self.cache.lengths[s] = n
+        return out
 
     def prefill_suffix(
         self,
@@ -1254,8 +1331,9 @@ class GenerationEngine:
         cache.lengths at the cursor), so this runs ONE chunked-prefill
         step over the unshared suffixes: the chunk core's staircase
         mask with query_offset = cursor reads the shared pages through
-        the block table and is logit-identical to the monolithic
-        prefill (PR 10's bit-identity argument), and the sampled token
+        the block table and agrees with the monolithic prefill to
+        float32 rounding (the same rows under the same mask, in another
+        program), and the sampled token
         lands at each request's FULL prompt length — the same _pick key
         the monolithic path uses. Returns (next_tokens [n],
         last_logits [n, V]) in request order."""
@@ -1581,7 +1659,6 @@ class GenerationEngine:
         # snapshot(): lengths += 1 below, and allocator table edits
         # between iterations, mutate behind the async dispatch queue
         nxt, logits, readback = self._keep_choice(
-            "decode",
             self._run_step(
                 "decode",
                 lambda: self._decode_jit,

@@ -59,6 +59,18 @@ class PagePoolExhausted(RuntimeError):
     """
 
 
+def int8_page_scale(first_row_amax):
+    """The dequant scale an int8 page claims from the abs-max of its FIRST
+    row (per head): twice that abs-max over 127. The later rows of the
+    page are quantized under it, and a row's abs-max exceeds the first
+    row's about every other time: at amax / 127 such a row clips (one
+    lost 53% of its largest element, and a decode step's logits moved by
+    19% of their range: tests/test_prefix_cache.py); with the factor of
+    two a row up to twice the first's reach is kept whole, for one of the
+    seven bits."""
+    return first_row_amax * (2.0 / 127.0)
+
+
 def default_buckets(max_len: int, smallest: int = 16) -> Tuple[int, ...]:
     """Powers of two from `smallest` up to (and including) max_len."""
     out = []
@@ -268,9 +280,10 @@ class PagedKVCache:
     int8 quantization (`spec.kv_dtype == "int8"`): the token pools hold
     int8 with one fp32 dequant scale per page per head in side pools
     (`k_scale`/`v_scale`, `[num_pages, num_heads]`). The FIRST write
-    into a page fixes its scale (engine-side scatter-max); later rows
-    reuse it (values beyond ±127·scale clip — the documented
-    tolerance), so a page's bytes depend only on its token content and
+    into a page fixes its scale (engine-side scatter-max, from the
+    page's first row: `int8_page_scale`); later rows reuse it (values
+    beyond ±127·scale clip — the documented tolerance), so a page's
+    bytes depend only on its token content and
     prefix-shared pages stay bit-identical across requests.
     """
 
@@ -1206,7 +1219,7 @@ class PagedKVCache:
             deq = f[si].astype(jnp.float32) * scale[spi][:, :, None]
             amax = jnp.max(jnp.abs(deq), axis=-1)  # [a, heads]
             cand = jnp.zeros_like(scale).at[dpi].max(
-                jnp.where(firstj, amax / 127.0, 0.0)
+                jnp.where(firstj, int8_page_scale(amax), 0.0)
             )
             claimed = jnp.zeros_like(scale).at[dpi].max(
                 jnp.where(firstj, 1.0, 0.0)

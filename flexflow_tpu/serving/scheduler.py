@@ -234,6 +234,9 @@ class Request:
 _STAT_FIELDS: Dict[str, object] = dict(
     iterations=0,
     decode_steps=0,
+    # prefill PROGRAMS dispatched by admissions: one a packed row, so more
+    # than one for an admission whose prompts overflow the largest bucket,
+    # plus the one chunk step of its prefix-shared prompts
     prefill_batches=0,
     tokens_generated=0,
     slot_steps=0,  # Σ over decode/verify iterations of max_seqs
@@ -302,7 +305,8 @@ _STAT_FIELDS: Dict[str, object] = dict(
     device_syncs=0,  # blocking reads of a device value (wait, readbacks)
     readback_bytes=0,  # bytes those reads brought to the host
     prefill_tokens_real=0,  # prompt tokens run by monolithic prefills
-    prefill_tokens_padded=0,  # the max_seqs x bucket tokens they ran as
+    prefill_tokens_padded=0,  # the bucket(total) tokens they were packed into
+    prefill_programs=0,  # packed prefill programs dispatched (engine.prefill)
     # step programs, all kinds, by what became of the KV pools they were
     # handed: consumed by the call (donated: rows written in place), or
     # still alive after it (the backend declined the donation and copied)
@@ -1443,6 +1447,7 @@ class _SchedulerBase:
                     req.prefill_pos = cur
                     req.prefill_dispatched = cur
                 return admitted
+            programs = self.engine.prefill_programs
             try:
                 plain = [i for i, c in enumerate(cursors) if c == 0]
                 shared = [i for i, c in enumerate(cursors) if c > 0]
@@ -1476,7 +1481,9 @@ class _SchedulerBase:
                 for req in admitted:
                     self._fail(req, f"prefill failed: {e!r}")
                 return admitted
-            self.stats.prefill_batches += 1
+            self.stats.prefill_batches += (
+                self.engine.prefill_programs - programs + bool(shared)
+            )
             if prefix:
                 # publish AFTER the prefill returned: a failed dispatch
                 # must never leave hash keys pointing at pages whose
@@ -2627,7 +2634,8 @@ class _SchedulerBase:
     _ENGINE_MIRRORS = (
         "verify_cache_entries", "kernel_fallbacks", "multistep_cache_entries",
         "device_syncs", "readback_bytes", "prefill_tokens_real",
-        "prefill_tokens_padded", "pool_steps_donated", "pool_steps_copied",
+        "prefill_tokens_padded", "prefill_programs", "pool_steps_donated",
+        "pool_steps_copied",
         "moe_rows_prefill", "moe_rows_decode",
         "moe_experts_touched_prefill", "moe_experts_touched_decode",
         "moe_rows_absent_prefill", "moe_rows_absent_decode",

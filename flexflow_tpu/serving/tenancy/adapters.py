@@ -39,6 +39,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.ops.attention import lora_delta_out, lora_delta_qkv
@@ -363,25 +364,6 @@ class AdapterPool:
             tbl[rows] = self.adapter_tables[self.slot_adapter[rows]]
         return tbl, has.copy()
 
-    def row_tables(
-        self, slots: Sequence[int], rows: int
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """(tbl [rows, P], has [rows]) aligned to a prefill batch whose
-        row i serves slot ``slots[i]`` (pad rows past len(slots) get the
-        sentinel/base row)."""
-        tbl = np.full(
-            (rows, self.spec.pages_per_adapter),
-            self.spec.num_pages,
-            dtype=np.int32,
-        )
-        has = np.zeros(rows, dtype=bool)
-        for i, s in enumerate(slots):
-            aid = int(self.slot_adapter[int(s)])
-            if aid >= 0:
-                tbl[i] = self.adapter_tables[aid]
-                has[i] = True
-        return tbl, has
-
     # -- invariants / telemetry ----------------------------------------------
 
     def check_invariants(self) -> None:
@@ -482,15 +464,18 @@ def apply_adapter_qkv(x, q, k, v, ad, guid):
     ``has`` False take the UNMODIFIED q/k/v elements through the select,
     so base-model rows stay bit-identical whether or not a pool rides
     the step. K/V deltas land BEFORE the cache writes — the paged/Pallas
-    attention cores then read adapted history with no kernel change."""
+    attention cores then read adapted history with no kernel change.
+    From ``adapter_tokens`` ``ad`` carries a fourth entry, the one-hot
+    ``owner`` of ``lora_delta_qkv``, and ``has`` is per token [1, s]."""
     if ad is None:
         return q, k, v
-    tbl, has, pools = ad
+    tbl, has, pools, *owner = ad
     p = pools[guid]
     dq, dk, dv = lora_delta_qkv(
-        x, tbl, p["a_q"], p["b_q"], p["a_k"], p["b_k"], p["a_v"], p["b_v"]
+        x, tbl, p["a_q"], p["b_q"], p["a_k"], p["b_k"], p["a_v"], p["b_v"],
+        *owner,
     )
-    sel = has[:, None, None, None]
+    sel = has.reshape(has.shape + (1,) * (q.ndim - has.ndim))
     q = jnp.where(sel, (q.astype(jnp.float32) + dq).astype(q.dtype), q)
     k = jnp.where(sel, (k.astype(jnp.float32) + dk).astype(k.dtype), k)
     v = jnp.where(sel, (v.astype(jnp.float32) + dv).astype(v.dtype), v)
@@ -503,12 +488,11 @@ def apply_adapter_out(attn, y, ad, guid):
     already ran, untouched."""
     if ad is None:
         return y
-    tbl, has, pools = ad
+    tbl, has, pools, *owner = ad
     p = pools[guid]
-    dy = lora_delta_out(attn, tbl, p["a_o"], p["b_o"])
-    return jnp.where(
-        has[:, None, None], (y.astype(jnp.float32) + dy).astype(y.dtype), y
-    )
+    dy = lora_delta_out(attn, tbl, p["a_o"], p["b_o"], *owner)
+    sel = has.reshape(has.shape + (1,) * (y.ndim - has.ndim))
+    return jnp.where(sel, (y.astype(jnp.float32) + dy).astype(y.dtype), y)
 
 
 def adapter_rows(ad, slot_ids):
@@ -518,6 +502,20 @@ def adapter_rows(ad, slot_ids):
         return None
     tbl, has, pools = ad
     return tbl[slot_ids], has[slot_ids], pools
+
+
+def adapter_tokens(ad, slots):
+    """A slot-indexed ``ad`` for ONE packed row whose token t serves slot
+    ``slots[t]`` (the packed prefill; a padding token names the slot past
+    the last, which owns nothing): ``has`` per token and the one-hot
+    ``owner`` that ``lora_delta_qkv`` / ``lora_delta_out`` select by."""
+    if ad is None:
+        return None
+    tbl, has, pools = ad
+    n = tbl.shape[0]
+    owner = jax.nn.one_hot(slots, n, dtype=jnp.float32)
+    has = has[jnp.minimum(slots, n - 1)] & (slots < n)
+    return tbl, has[None, :], pools, owner
 
 
 # -- test/bench weight helper ------------------------------------------------

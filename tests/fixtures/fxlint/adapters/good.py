@@ -48,7 +48,7 @@ class WellBehavedPool:
 
 class InnocentBystander:
     def gather_tables(self, pool, slots):
-        # loads never match — slot_tables/row_tables build gather
+        # loads never match — slot_tables builds its gather
         # tables by READING the ledgers into fresh locals
         tbl = {}
         for i, s in enumerate(slots):

@@ -114,9 +114,10 @@ def case_forward(model):
 def case_prefill_decode(model, layout="paged"):
     engine, got, seq, n = _prefill_then_decode(model, layout)
     assert _gap(got, _want(model, seq, range(n - 1, n + 8))) < TOL
-    # the counters: every (token, choice) row computed, pad rows too
+    # the counters: every (token, choice) row computed, the padding
+    # behind the prompt in its one packed row too
     layers = SIZES["num_layers"]
-    assert engine.moe_rows_prefill == 4 * engine.cache.spec.bucket(n) * K * layers
+    assert engine.moe_rows_prefill == engine.cache.spec.bucket(n) * K * layers
     assert engine.moe_rows_decode == 8 * 4 * K * layers
     assert 8 * layers * K <= engine.moe_experts_touched_decode <= 8 * layers * 8
 

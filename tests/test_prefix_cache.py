@@ -339,10 +339,13 @@ def test_spec_rollback_keeps_refcounts(lm):
 def test_int8_logits_within_tolerance(lm):
     """int8 K/V vs fp32: logits agree within the documented tolerance
     (max |Δlogit| under 15% of the fp32 logit range — per-page scales
-    bound the element error at scale/2 ≈ amax/254). Token streams are
-    NOT compared across dtypes: quantization is lossy and argmax near
-    ties legitimately flips; the bit-exact contract is shared-vs-
-    unshared WITHIN a dtype (the matrix test above)."""
+    bound the element error at scale/2 ≈ amax/127 for a row within twice
+    the reach of its page's first row, which claimed the scale:
+    `kv_cache.int8_page_scale`. Claimed at the first row's own abs-max,
+    every other later row clipped and this decode step read 19%). Token
+    streams are NOT compared across dtypes: quantization is lossy and
+    argmax near ties legitimately flips; the bit-exact contract is
+    shared-vs-unshared WITHIN a dtype (the matrix test above)."""
     prompt = [3, 1, 4, 1, 5, 9, 2, 6]
     out = {}
     for dt in ("fp32", "int8"):

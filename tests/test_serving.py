@@ -296,9 +296,14 @@ def _chunked_requests(max_new=6):
 @pytest.mark.parametrize("layout", ["one_page", "paged"])
 def test_chunk_steps_reproduce_monolithic_prefill(lm, layout):
     """Engine level: streaming a prompt in as staircase-masked chunk
-    steps leaves the SAME cache state and produces BIT-IDENTICAL final
-    logits and sampled token as one monolithic prefill — equality, not
-    allclose, at both page geometries."""
+    steps leaves the same cache state and produces the same sampled token
+    and (to 1e-5 of the largest logit) the same final logits as one
+    monolithic prefill, at both page geometries. Not bit equality: the two
+    are different programs over different shapes (the prompt's ten keys in
+    one packed row of 16 against three passes over the slot's pages, all
+    32 positions of them), and XLA sums a softmax row and a matmul's
+    contraction in an order that follows the shape: the same float32
+    terms in another order differ in the last bit (3.6e-7 here)."""
     prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3]
     serve = ServeConfig(
         max_seqs=2, max_seq_len=32, **page_geometry(layout, 32)
@@ -318,7 +323,9 @@ def test_chunk_steps_reproduce_monolithic_prefill(lm, layout):
         chunk_lens[slot_c] = len(chunk)
         nxt, logits = eng_c.prefill_chunk(lm.params, tokens, chunk_lens)
     assert int(cache_c.lengths[slot_c]) == len(prompt)
-    np.testing.assert_array_equal(logits[slot_c], last_m[0])
+    np.testing.assert_allclose(
+        logits[slot_c], last_m[0], rtol=0, atol=1e-5 * np.abs(last_m[0]).max()
+    )
     assert int(nxt[slot_c]) == int(nxt_m[0])
 
 

@@ -403,10 +403,11 @@ def test_sync_and_byte_counts_equal_hand_counts_on_a_scripted_run(lm):
     assert st.prefill_batches == 2
     batches = ([SCRIPT[0], SCRIPT[1]], [SCRIPT[2]])
     assert st.prefill_tokens_real == sum(len(p) for b in batches for p in b) == 10
+    # each batch is ONE packed row of bucket(total) tokens, one program
     assert st.prefill_tokens_padded == sum(
-        spec.max_seqs * spec.bucket(max(len(p) for p in b)) for b in batches
+        spec.bucket(sum(len(p) for p in b)) for b in batches
     )
-    assert st.prefill_tokens_padded >= 2 * spec.max_seqs * 5
+    assert st.prefill_programs == engine.prefill_programs == 2
     # per prefill: the tokens and the last logits of its n prompts; per
     # decode step: ONE read of one int32 vector, the token and the finite
     # flag of every slot (the logits [max_seqs, V] stay on the device);
