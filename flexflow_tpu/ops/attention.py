@@ -132,25 +132,23 @@ def mha_qk_positions(q, k, ws, params, positions):
         from flexflow_tpu.ops.core_ops import rms_normalize
 
         eps = params.get("qk_norm_eps", 1e-5)
-        with jax.named_scope("attn.qk_norm"):
-            # statistics over the whole projection (heads x head_dim),
-            # before the split into heads means anything
-            q = rms_normalize(q, ws[-2], eps, axes=(-2, -1))
-            k = rms_normalize(k, ws[-1], eps, axes=(-2, -1))
+        # statistics over the whole projection (heads x head_dim),
+        # before the split into heads means anything
+        q = rms_normalize(q, ws[-2], eps, axes=(-2, -1))
+        k = rms_normalize(k, ws[-1], eps, axes=(-2, -1))
     theta = params.get("rope_theta")
     if theta is not None:
         if positions is None:
             positions = jnp.arange(q.shape[1])
-        with jax.named_scope("attn.rope"):
-            cos, sin = rotary_cos_sin(positions, q.shape[-1], float(theta))
+        cos, sin = rotary_cos_sin(positions, q.shape[-1], float(theta))
 
-            def rotate(x):
-                xf = x.astype(jnp.float32)
-                x1, x2 = jnp.split(xf, 2, axis=-1)
-                turned = jnp.concatenate([-x2, x1], axis=-1)
-                return (xf * cos + turned * sin).astype(x.dtype)
+        def rotate(x):
+            xf = x.astype(jnp.float32)
+            x1, x2 = jnp.split(xf, 2, axis=-1)
+            turned = jnp.concatenate([-x2, x1], axis=-1)
+            return (xf * cos + turned * sin).astype(x.dtype)
 
-            q, k = rotate(q), rotate(k)
+        q, k = rotate(q), rotate(k)
     return q, k
 
 

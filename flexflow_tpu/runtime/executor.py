@@ -84,6 +84,14 @@ def propagate_shapes(graph: PCGGraph):
         node.weight_shapes = tuple(weights)
 
 
+def node_scope(node):
+    """The named scope every instruction lowered from `node` carries in
+    the compiled program (`kind:name`, `kind` the operator type in lower
+    case): what `utils.profiling.profile_step` and a profile viewer
+    group device time by. HLO metadata only."""
+    return jax.named_scope(f"{node.op_type.name.lower()}:{node.name}")
+
+
 class Executor:
     """Compiles an annotated PCG into jitted step functions."""
 
@@ -288,6 +296,30 @@ class Executor:
 
     # -- forward -------------------------------------------------------------
 
+    def lower_node(self, guid, ins, ws, ctx, hook=None, constrain=False):
+        """A node's outputs from its registered lowering, or from the
+        `hook` that stands in for it, each under its sharding constraint
+        if `constrain`; all under the node's scope. The one place any
+        step program lowers a node."""
+        node = self.graph.nodes[guid]
+        with node_scope(node):
+            if hook is not None:
+                outs = hook(node, ins, ws, ctx)
+            else:
+                outs = self._lowered[guid](ins, ws, ctx)
+            if constrain:
+                outs = [
+                    self._constrain(out, shape)
+                    for out, shape in zip(outs, node.output_shapes)
+                ]
+        return outs
+
+    def constrain_given(self, node, x):
+        """The sharding constraint on a value the step was handed for
+        `node` (a batch input, an injected activation), under its scope."""
+        with node_scope(node):
+            return self._constrain(x, node.output_shapes[0])
+
     def forward_values(
         self,
         params,
@@ -318,22 +350,18 @@ class Executor:
         explicitly instead."""
         values: Dict[Tuple[int, int], jnp.ndarray] = {}
 
-        def _maybe_constrain(x, shape):
-            return self._constrain(x, shape) if constrain else x
+        def _given(node, x):
+            return self.constrain_given(node, x) if constrain else x
 
         for guid in self.topo:
             node = self.graph.nodes[guid]
             if injected is not None and guid in injected:
-                values[(guid, 0)] = _maybe_constrain(
-                    injected[guid], node.output_shapes[0]
-                )
+                values[(guid, 0)] = _given(node, injected[guid])
                 continue
             if node.op_type in (OperatorType.INPUT, OperatorType.NOOP) and not node.inputs:
                 if node.name not in batch:
                     raise KeyError(f"batch missing input '{node.name}'")
-                x = batch[node.name]
-                x = _maybe_constrain(x, node.output_shapes[0])
-                values[(guid, 0)] = x
+                values[(guid, 0)] = _given(node, batch[node.name])
                 continue
             ins = [values[(r.guid, r.out_idx)] for r in node.inputs]
             ws = params.get(guid, [])
@@ -347,12 +375,8 @@ class Executor:
                 seq_length=self.seq_length,
             )
             hook = op_hooks.get(node.op_type) if op_hooks else None
-            if hook is not None:
-                outs = hook(node, ins, ws, ctx)
-            else:
-                outs = self._lowered[guid](ins, ws, ctx)
+            outs = self.lower_node(guid, ins, ws, ctx, hook, constrain)
             for i, out in enumerate(outs):
-                out = _maybe_constrain(out, node.output_shapes[i])
                 values[(guid, i)] = out
         return values
 
@@ -360,14 +384,17 @@ class Executor:
         values = self.forward_values(params, batch, rng, train, injected)
         logits = values[(self.logits_ref.guid, self.logits_ref.out_idx)]
         labels = batch["label"]
-        loss = compute_loss(
-            self.loss_type, logits, labels, from_logits=self.logits_from_logits
-        )
-        for fn in self.aux_loss_fns:
-            loss = loss + fn(values, batch)
-        mets = compute_metrics(
-            self.metric_types, logits, labels, from_logits=self.logits_from_logits
-        )
+        with jax.named_scope("loss"):
+            loss = compute_loss(
+                self.loss_type, logits, labels,
+                from_logits=self.logits_from_logits,
+            )
+            for fn in self.aux_loss_fns:
+                loss = loss + fn(values, batch)
+            mets = compute_metrics(
+                self.metric_types, logits, labels,
+                from_logits=self.logits_from_logits,
+            )
         if train and self.cache_guids:
             mets = dict(mets)
             for guid in self.cache_guids:
@@ -419,9 +446,10 @@ class Executor:
                 (loss, mets), grads = jax.value_and_grad(
                     loss_fn, has_aux=True
                 )(params)
-                new_params, new_state = self.optimizer.update(
-                    params, grads, opt_state
-                )
+                with jax.named_scope("update"):
+                    new_params, new_state = self.optimizer.update(
+                        params, grads, opt_state
+                    )
                 return new_params, new_state, loss, mets
 
             return step
@@ -453,8 +481,8 @@ class Executor:
                     bf16_matmul=self.mixed_precision,
                     seq_length=self.seq_length,
                 )
-                acts[g] = self._lowered[g](
-                    [batch[ids_name[g]]], [params[g][0]], ctx
+                acts[g] = self.lower_node(
+                    g, [batch[ids_name[g]]], [params[g][0]], ctx
                 )[0]
 
             dense = {k: v for k, v in params.items() if k not in sparse}
@@ -470,45 +498,46 @@ class Executor:
             (loss, mets), (gd, ga) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(dense, acts)
-            # split out the tables' optimizer-state entries so the dense
-            # update's pytrees line up, then row-update each table with
-            # its slot (Optimizer.sparse_row_update: lazy momentum/Adam)
-            dense_state, slots = self.optimizer.split_state(
-                opt_state, sparse
-            )
-            new_params, new_state = self.optimizer.update(
-                dense, gd, dense_state
-            )
-            for g in sparse:
-                node = self.graph.nodes[g]
-                table = params[g][0]
-                ids = batch[ids_name[g]]
-                gact = ga[g]
-                aggr = node.params.get("aggr", AggrMode.NONE)
-                if aggr == AggrMode.SUM:
-                    rows = jnp.broadcast_to(
-                        gact[..., None, :], ids.shape + gact.shape[-1:]
-                    )
-                elif aggr == AggrMode.AVG:
-                    rows = (
-                        jnp.broadcast_to(
+            with jax.named_scope("update"):
+                # split out the tables' optimizer-state entries so the dense
+                # update's pytrees line up, then row-update each table with
+                # its slot (Optimizer.sparse_row_update: lazy momentum/Adam)
+                dense_state, slots = self.optimizer.split_state(
+                    opt_state, sparse
+                )
+                new_params, new_state = self.optimizer.update(
+                    dense, gd, dense_state
+                )
+                for g in sparse:
+                    node = self.graph.nodes[g]
+                    table = params[g][0]
+                    ids = batch[ids_name[g]]
+                    gact = ga[g]
+                    aggr = node.params.get("aggr", AggrMode.NONE)
+                    if aggr == AggrMode.SUM:
+                        rows = jnp.broadcast_to(
                             gact[..., None, :], ids.shape + gact.shape[-1:]
                         )
-                        / ids.shape[-1]
+                    elif aggr == AggrMode.AVG:
+                        rows = (
+                            jnp.broadcast_to(
+                                gact[..., None, :], ids.shape + gact.shape[-1:]
+                            )
+                            / ids.shape[-1]
+                        )
+                    else:  # NONE: cotangent already one row per id
+                        rows = gact
+                    dim = rows.shape[-1]
+                    new_table, new_slot = self.optimizer.sparse_row_update(
+                        table,
+                        slots.get(g),
+                        ids.reshape(-1),
+                        rows.reshape(-1, dim).astype(table.dtype),
+                        new_state["step"],
                     )
-                else:  # NONE: cotangent already one row per id
-                    rows = gact
-                dim = rows.shape[-1]
-                new_table, new_slot = self.optimizer.sparse_row_update(
-                    table,
-                    slots.get(g),
-                    ids.reshape(-1),
-                    rows.reshape(-1, dim).astype(table.dtype),
-                    new_state["step"],
-                )
-                new_params[g] = [new_table]
-                slots[g] = new_slot
-            new_state = self.optimizer.merge_state(new_state, slots)
+                    new_params[g] = [new_table]
+                    slots[g] = new_slot
+                new_state = self.optimizer.merge_state(new_state, slots)
             return new_params, new_state, loss, mets
 
         return sparse_step

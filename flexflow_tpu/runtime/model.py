@@ -1703,11 +1703,24 @@ class FFModel:
         """See begin_trace."""
 
     def profile_operators(self, batch, iters: int = 5, verbose: bool = True):
-        """Per-op forward timing table (reference: --profiling per-kernel
-        cudaEvent prints, kernels/linear_kernels.cu:95-117)."""
+        """Each node's forward jitted ALONE and timed: a cost per node
+        before a program exists, for the calibration and the audit. It
+        sees no fusion across nodes, no backward, update, collective or
+        mesh lowering: `profile_step` is the table of the real step."""
         from flexflow_tpu.utils.profiling import profile_operators
 
         return profile_operators(self, batch, iters=iters, verbose=verbose)
+
+    def profile_step(self, batch, steps: int = 8, verbose: bool = True):
+        """The --profiling table (reference: per-kernel cudaEvent prints,
+        kernels/linear_kernels.cu:95-117) read from the compiled train
+        step as it runs: device time per PCG node, forward and backward,
+        with `loss`, `update` and the collectives
+        (`utils.profiling.profile_step`). Needs a device that writes
+        `XLA Ops` into its profile (a TPU)."""
+        from flexflow_tpu.utils.profiling import profile_step
+
+        return profile_step(self, batch, steps=steps, verbose=verbose)
 
     def audit_cost_model(self, batch=None, **kwargs):
         """Predicted-vs-measured cost-model audit (search/audit.py):

@@ -318,7 +318,7 @@ class PipelinedExecutor(Executor):
                     bf16_matmul=self.mixed_precision,
                     seq_length=self.seq_length,
                 )
-                outs = self._lowered[self.template[i]](ins, ws, ctx)
+                outs = self.lower_node(self.template[i], ins, ws, ctx)
                 for o_idx, out in enumerate(outs):
                     values[(i, o_idx)] = out
             return values[(len(template_nodes) - 1, 0)]
@@ -373,9 +373,9 @@ class PipelinedExecutor(Executor):
                 ):
                     if node.name not in batch:
                         raise KeyError(f"batch missing input '{node.name}'")
-                    x = batch[node.name]
-                    x = self._constrain(x, node.output_shapes[0])
-                    values[(guid, 0)] = x
+                    values[(guid, 0)] = self.constrain_given(
+                        node, batch[node.name]
+                    )
                     continue
                 ins = [values[(r.guid, r.out_idx)] for r in node.inputs]
                 ws = params.get(guid, [])
@@ -390,24 +390,26 @@ class PipelinedExecutor(Executor):
                     bf16_matmul=self.mixed_precision,
                     seq_length=self.seq_length,
                 )
-                outs = self._lowered[guid](ins, ws, ctx)
+                outs = self.lower_node(guid, ins, ws, ctx, constrain=True)
                 for i, out in enumerate(outs):
-                    out = self._constrain(out, node.output_shapes[i])
                     values[(guid, i)] = out
 
         walk(st.prologue)
         x = values[(self.entry_guid, 0)]
         data_axis = "data" if "data" in self.mesh_config.axis_names else None
-        y = pipeline_apply(
-            self.mesh,
-            self._block_fn(rng, train),
-            self._stacked_trunk_params(params),
-            x,
-            axis_name="pipe",
-            num_microbatches=self.pspec.num_microbatches,
-            data_axis=data_axis,
-            stage_leading_axis=True,
-        )
+        # the schedule's own instructions (microbatch stream, stage
+        # shifts) read `step.pipeline`; a block's node keeps its scope
+        with jax.named_scope("step.pipeline"):
+            y = pipeline_apply(
+                self.mesh,
+                self._block_fn(rng, train),
+                self._stacked_trunk_params(params),
+                x,
+                axis_name="pipe",
+                num_microbatches=self.pspec.num_microbatches,
+                data_axis=data_axis,
+                stage_leading_axis=True,
+            )
         # downstream consumers read the LAST block's output
         values[(self.exit_guid, 0)] = y
         walk(st.epilogue)

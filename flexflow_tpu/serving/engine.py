@@ -1122,6 +1122,7 @@ class GenerationEngine:
         V]: each request's last position, in the call's order), and for a
         model with expert layers their int32 counts after them (and the
         routers' choice [expert layers, 1, T, k], `_step_counts`)."""
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -1139,19 +1140,23 @@ class GenerationEngine:
         )
 
         spec = self.cache.spec
-        tokens, segment, position, dest = layout
-        slot_ids, prompt_lens, last_at = requests
-        live = segment < spec.max_seqs
-        allowed = (
-            (segment[:, None] == segment[None, :])
-            & (position[None, :] <= position[:, None])
-        )[None]
-        ad = adapter_tokens(ad, jnp.where(live, slot_ids[segment], spec.max_seqs))
+        with jax.named_scope("step.unpack"):
+            tokens, segment, position, dest = layout
+            slot_ids, prompt_lens, last_at = requests
+            live = segment < spec.max_seqs
+            allowed = (
+                (segment[:, None] == segment[None, :])
+                & (position[None, :] <= position[:, None])
+            )[None]
+            ad = adapter_tokens(
+                ad, jnp.where(live, slot_ids[segment], spec.max_seqs)
+            )
+            positions = self._positions(lambda: position[None, :])
+            tokens = tokens[None, :]
+            share = self._share(live[None, :])
         quant = self.cache.quantized
         new_k, new_v = {}, {}
         new_ks, new_vs = dict(cks), dict(cvs)
-
-        positions = self._positions(lambda: position[None, :])
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -1198,21 +1203,15 @@ class GenerationEngine:
             return [mla_project_out(attn, ws, ctx, ins[0].dtype)]
 
         moe = []
-        share = self._share(live[None, :])
         logits = self._forward_logits(
-            params, tokens[None, :], hook, moe,
+            params, tokens, hook, moe,
             latent_hook if self._latent else None, share,
         )
-        last = logits[0, last_at]
-        return (
-            new_k,
-            new_v,
-            new_ks,
-            new_vs,
-            self._pick(last, slot_ids, prompt_lens),
-            last,
-            *self._step_counts(moe, share),
-        )
+        with jax.named_scope("step.pick"):
+            last = logits[0, last_at]
+            nxt = self._pick(last, slot_ids, prompt_lens)
+            counts = self._step_counts(moe, share)
+        return new_k, new_v, new_ks, new_vs, nxt, last, *counts
 
     def prefill(
         self,
@@ -1387,6 +1386,7 @@ class GenerationEngine:
         of its experts hands them (`_forward_logits`); the single-step
         program returns both, the scan neither. Returns (ck', cv', cks', cvs',
         logits [max_seqs, V])."""
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -1411,12 +1411,12 @@ class GenerationEngine:
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks, new_vs = dict(cks), dict(cvs)
-        page = jnp.take_along_axis(tables, (lengths // ps)[:, None], axis=1)[
-            :, 0
-        ]
-        dest = jnp.where(active, page * ps + lengths % ps, oob)
-
-        positions = self._positions(lambda: lengths[:, None])
+        with jax.named_scope("step.unpack"):
+            page = jnp.take_along_axis(
+                tables, (lengths // ps)[:, None], axis=1
+            )[:, 0]
+            dest = jnp.where(active, page * ps + lengths % ps, oob)
+            positions = self._positions(lambda: lengths[:, None])
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -1472,8 +1472,10 @@ class GenerationEngine:
         logits = self._forward_logits(
             params, tokens, hook, moe, latent_hook if self._latent else None,
             share,
-        )[:, -1, :]
-        return new_k, new_v, new_ks, new_vs, logits
+        )
+        with jax.named_scope("step.pick"):
+            last = logits[:, -1, :]
+        return new_k, new_v, new_ks, new_vs, last
 
     #: columns of a decode step's packed host state in front of the slot's
     #: block table: the host's view of the last token, whether the token
@@ -1497,26 +1499,34 @@ class GenerationEngine:
         layers' counts. `chained`: None, or the in-flight previous step's
         sampled tokens, taken where the chain flag is set, so that
         consecutive steps' data dependency stays on the device."""
+        import jax
         import jax.numpy as jnp
 
-        tokens, from_chain, lengths = state[:, 0], state[:, 1], state[:, 2]
-        active = state[:, 3] != 0
-        tables = state[:, self._STATE_COLUMNS:]
-        if chained is not None:
-            tokens = jnp.where(from_chain != 0, chained, tokens)
+        with jax.named_scope("step.unpack"):
+            tokens, from_chain, lengths = state[:, 0], state[:, 1], state[:, 2]
+            active = state[:, 3] != 0
+            tables = state[:, self._STATE_COLUMNS:]
+            if chained is not None:
+                tokens = jnp.where(from_chain != 0, chained, tokens)
+            tokens = tokens[:, None]
+            share = self._share(active[:, None])
         moe = []
-        share = self._share(active[:, None])
         new_k, new_v, new_ks, new_vs, logits = self._decode_core_paged(
-            params, tokens[:, None], lengths, active, tables, ck, cv, cks,
-            cvs, ad, moe, share,
+            params, tokens, lengths, active, tables, ck, cv, cks, cvs, ad,
+            moe, share,
         )
-        slots = jnp.arange(lengths.shape[0])
-        nxt = self._pick(logits, slots, lengths + 1)
-        extra = self._step_counts(moe, share)
-        n = bool(self._count_fields)  # the counts, then the choice
-        readback = jnp.concatenate(
-            [nxt, jnp.isfinite(logits).all(-1).astype(jnp.int32), *extra[:n]]
-        )
+        with jax.named_scope("step.pick"):
+            slots = jnp.arange(lengths.shape[0])
+            nxt = self._pick(logits, slots, lengths + 1)
+            extra = self._step_counts(moe, share)
+            n = bool(self._count_fields)  # the counts, then the choice
+            readback = jnp.concatenate(
+                [
+                    nxt,
+                    jnp.isfinite(logits).all(-1).astype(jnp.int32),
+                    *extra[:n],
+                ]
+            )
         return new_k, new_v, new_ks, new_vs, nxt, logits, readback, *extra[n:]
 
     # -- device-resident multi-step decode -----------------------------------
@@ -1569,31 +1579,40 @@ class GenerationEngine:
         import jax
         import jax.numpy as jnp
 
-        slots = jnp.arange(lengths.shape[0])
+        with jax.named_scope("step.unpack"):
+            slots = jnp.arange(lengths.shape[0])
+            steps = jnp.arange(k_bucket)
 
         def body(carry, i):
             ck_c, cv_c, cks_c, cvs_c, lens, toks, alive = carry
-            act = alive & (i < limits)
+            with jax.named_scope("step.unpack"):
+                act = alive & (i < limits)
+                tokens = toks[:, None]
             nk, nv, nks, nvs, logits = self._decode_core_paged(
-                params, toks[:, None], lens, act, tables, ck_c, cv_c,
-                cks_c, cvs_c, ad,
+                params, tokens, lens, act, tables, ck_c, cv_c, cks_c, cvs_c,
+                ad,
             )
-            nxt = self._pick(logits, slots, lens + 1)
-            hit = act & (eos >= 0) & (nxt == eos)
-            new_lens = jnp.where(act, lens + 1, lens)
-            new_toks = jnp.where(act, nxt, toks)
-            return (nk, nv, nks, nvs, new_lens, new_toks, alive & ~hit), (
+            with jax.named_scope("step.pick"):
+                nxt = self._pick(logits, slots, lens + 1)
+                hit = act & (eos >= 0) & (nxt == eos)
+                new_lens = jnp.where(act, lens + 1, lens)
+                new_toks = jnp.where(act, nxt, toks)
+                still = alive & ~hit
+            return (nk, nv, nks, nvs, new_lens, new_toks, still), (
                 nxt,
                 logits,
                 act,
             )
 
         carry0 = (ck, cv, cks, cvs, lengths, tokens, active)
-        (nk, nv, nks, nvs, lens, toks, _), (
-            toks_ks,
-            logits_ks,
-            mask_ks,
-        ) = jax.lax.scan(body, carry0, jnp.arange(k_bucket))
+        # the loop's own instructions (counter, stacking of the outputs)
+        # read `step.scan`; a node's keep the node's scope inside it
+        with jax.named_scope("step.scan"):
+            (nk, nv, nks, nvs, lens, toks, _), (
+                toks_ks,
+                logits_ks,
+                mask_ks,
+            ) = jax.lax.scan(body, carry0, steps)
         return nk, nv, nks, nvs, lens, toks, toks_ks, logits_ks, mask_ks
 
     def _pack_state(self, tokens, from_chain, active) -> np.ndarray:
@@ -1925,6 +1944,7 @@ class GenerationEngine:
         cache.truncate. Under int8 pools the w
         fresh rows quantize through `_quant_scatter` and the per-page
         scales ride along to the attention gather."""
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -1939,17 +1959,18 @@ class GenerationEngine:
 
         spec = self.cache.spec
         quant = self.cache.quantized
-        dest = self._verify_scatter_dest(
-            tokens.shape[1], lengths, draft_lens, tables, jnp
-        )
+        with jax.named_scope("step.unpack"):
+            dest = self._verify_scatter_dest(
+                tokens.shape[1], lengths, draft_lens, tables, jnp
+            )
+            positions = self._positions(
+                lambda: lengths[:, None]
+                + jnp.arange(tokens.shape[1])[None, :]
+            )
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks = dict(cks)
         new_vs = dict(cvs)
-
-        positions = self._positions(
-            lambda: lengths[:, None] + jnp.arange(tokens.shape[1])[None, :]
-        )
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -2014,6 +2035,7 @@ class GenerationEngine:
         were the lone continuation. K/V rows still land at positions
         lengths + j — branch tokens occupy scattered rows that
         cache.truncate(slot, new_len, src_rows) later compacts."""
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -2028,15 +2050,17 @@ class GenerationEngine:
 
         spec = self.cache.spec
         quant = self.cache.quantized
-        dest = self._verify_scatter_dest(
-            tokens.shape[1], lengths, draft_lens, tables, jnp
-        )
+        with jax.named_scope("step.unpack"):
+            dest = self._verify_scatter_dest(
+                tokens.shape[1], lengths, draft_lens, tables, jnp
+            )
+            positions = self._positions(
+                lambda: lengths[:, None] + _tree_depths(parents)
+            )
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks = dict(cks)
         new_vs = dict(cvs)
-
-        positions = self._positions(lambda: lengths[:, None] + _tree_depths(parents))
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -2301,6 +2325,7 @@ class GenerationEngine:
         cks', cvs', next_tokens [B], last_logits [B, V]) in compact
         order; prefill_chunk_reconcile scatters them back to
         slot-indexed arrays."""
+        import jax
         import jax.numpy as jnp
 
         from flexflow_tpu.ops.attention import (
@@ -2316,21 +2341,21 @@ class GenerationEngine:
 
         spec = self.cache.spec
         w = tokens.shape[1]
-        lengths = all_lengths[slot_ids]  # [B] cursor per active slot
-        ad = adapter_rows(ad, slot_ids)
-        tables_g = tables[slot_ids]  # [B, pages] batch-aligned
-        dest = self._verify_scatter_dest(
-            w, lengths, chunk_lens, tables_g, jnp
-        )
+        with jax.named_scope("step.unpack"):
+            lengths = all_lengths[slot_ids]  # [B] cursor per active slot
+            ad = adapter_rows(ad, slot_ids)
+            tables_g = tables[slot_ids]  # [B, pages] batch-aligned
+            dest = self._verify_scatter_dest(
+                w, lengths, chunk_lens, tables_g, jnp
+            )
+            positions = self._positions(
+                lambda: lengths[:, None] + jnp.arange(w)[None, :]
+            )
         quant = self.cache.quantized
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks = dict(cks)
         new_vs = dict(cvs)
-
-        positions = self._positions(
-            lambda: lengths[:, None] + jnp.arange(w)[None, :]
-        )
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -2379,17 +2404,14 @@ class GenerationEngine:
             return [apply_adapter_out(attn, out, ad, g)]
 
         logits = self._forward_logits(params, tokens, hook)
-        last = jnp.take_along_axis(
-            logits, jnp.clip(chunk_lens - 1, 0, w - 1)[:, None, None], axis=1
-        )[:, 0]
-        return (
-            new_k,
-            new_v,
-            new_ks,
-            new_vs,
-            self._pick(last, slot_ids, lengths + chunk_lens),
-            last,
-        )
+        with jax.named_scope("step.pick"):
+            last = jnp.take_along_axis(
+                logits,
+                jnp.clip(chunk_lens - 1, 0, w - 1)[:, None, None],
+                axis=1,
+            )[:, 0]
+            nxt = self._pick(last, slot_ids, lengths + chunk_lens)
+        return new_k, new_v, new_ks, new_vs, nxt, last
 
     def prefill_chunk_dispatch(
         self,

@@ -106,3 +106,32 @@ def test_profile_operators_on_pipelined_executor():
         {"x": x[:16], "label": y[:16]}, verbose=False
     )
     assert rows and all(np.isfinite(t) for _, t in rows)
+
+
+def test_profile_step_on_the_cpu_backend_says_what_is_missing():
+    """`profile_step` runs the real compiled step under the profiler. A
+    CPU profile holds no `XLA Ops` line, so there is nothing to fold:
+    it says so (or, on a backend that writes one, returns rows whose
+    sum is the program's device time), and the model's own parameters
+    are the ones it had: the steps ran on copies."""
+    import pytest
+
+    from flexflow_tpu.utils import profiling
+
+    model = _model()
+    rng = np.random.RandomState(0)
+    batch = {
+        "x": rng.randn(16, 32).astype(np.float32),
+        "label": rng.randint(0, 4, (16,)).astype(np.int32),
+    }
+    before = [np.asarray(w) for ws in model.params.values() for w in ws]
+    try:
+        profile = model.profile_step(batch, steps=2, verbose=False)
+    except profiling.NoDeviceOps as e:
+        assert "no device ops in this trace" in str(e)
+    else:
+        assert profile.rows and 0.9 <= profile.accounted <= 1.0
+    after = [np.asarray(w) for ws in model.params.values() for w in ws]
+    assert all(np.array_equal(a, b) for a, b in zip(before, after))
+    with pytest.raises(RuntimeError, match="compile"):
+        profiling.profile_step(FFModel(FFConfig(batch_size=16)), batch)
