@@ -17,6 +17,7 @@ on the output that a downstream Reduction folds.
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -776,7 +777,17 @@ def _chunked_dense_attention(q, k, v, causal, chunk):
     66.7 ms — linear in batch at ~66-70% of bf16 peak
     (scripts/ab_attn_chunk2.py, scripts/probe_chain_lengths.py). Remat
     of the MONOLITHIC kernel does not help — the win needs the chunked
-    working set."""
+    working set.
+
+    The scan iterates the LEADING axis of the arrays it is given, so
+    that axis must be whole on every device: the global batch where
+    nothing shards it, each device's own sequences inside `_per_device`
+    where a mesh does (`mha_core_plan`). Four v5e chips, data parallel,
+    64 sequences a chip, against the one-shot kernel over the 64 (1 GiB
+    of scores a chip), which such a mesh took until PR 36: train step
+    243.7 -> 136.1 ms, its twelve attention nodes 215.7 -> 108.5 ms
+    where one chip's read 103.4, 525.6k -> 923.2k tokens/s
+    (`train_ff_b256_x4`; builder's chip runs, PRs 35 and 36)."""
     from jax import lax
 
     b = q.shape[0]
@@ -827,84 +838,234 @@ def _auto_flash(batch, heads, sq, sk, ctx=None) -> bool:
     return batch * heads * sq * sk * 4 >= _FLASH_SCORE_BYTES
 
 
-def _tiled_flash_sharded(q, k, v, ctx, causal, specs):
-    """Run the hand-tiled Pallas kernel (flash_kernel.py) per device by
-    wrapping it in shard_map — the GSPMD-compatible way to place an
-    opaque pallas call inside a sharded step (jit alone has no
-    partitioning rule for it). `specs` is the PartitionSpec for q/k/v
-    and the output; GSPMD reshards inputs to match, so callers choose
-    the layout (e.g. Ulysses' seq→head all-to-all is exactly the
-    reshard this wrapper's in_specs induce). Returns None when the
-    per-device block doesn't tile."""
-    from flexflow_tpu.ops.pallas.flash_kernel import (
-        flash_attention_tpu,
-        supports,
-    )
+def _single_device(ctx) -> bool:
+    return ctx is None or ctx.mesh is None or ctx.mesh.size == 1
 
-    if jax.default_backend() != "tpu":
-        return None
-    mesh = ctx.mesh
 
-    def deg(ax):
-        return mesh.shape[ax] if ax else 1
+def _batch_head_specs(ctx):
+    """PartitionSpec entries of q/k/v [b, s, h, d] that keep each device
+    on its own sequences and heads, the sequence whole."""
+    b_ax, _, h_ax = _q_mesh_axes(ctx) or (None, None, None)
+    return b_ax, None, h_ax, None
 
+
+def _tiled_takes(ctx, heads, sq, sk, head_dim, specs=None) -> bool:
+    """Whether the hand-tiled Pallas kernel (flash_kernel.py) takes this
+    shape here: on a TPU, a shape it `supports`, and on a mesh under
+    `specs` (default: q's own batch and head axes)."""
+    from flexflow_tpu.ops.pallas.flash_kernel import supports
+
+    if jax.default_backend() != "tpu" or not supports(sq, sk, head_dim):
+        return False
+    if specs is None:
+        if _single_device(ctx):
+            return True
+        specs = _batch_head_specs(ctx)
     bs_ax, sq_ax, h_ax, _ = specs
     if sq_ax is not None:
         # a sharded seq dim inside shard_map would compute BLOCK-DIAGONAL
         # attention (each device only its own keys) — that layout belongs
         # to ring_attention, not this wrapper
-        return None
+        return False
     if bs_ax is None and h_ax is None:
         # nothing to shard over: a fully-replicated shard_map would
         # all-gather whatever sharding the inputs DO carry (e.g. a seq
         # sharding this call was asked to densify) and recompute the
         # whole attention on every device — let XLA partition the
         # blockwise path instead
-        return None
-    h_loc = q.shape[2] // deg(h_ax)
-    if (
-        h_loc == 0
-        or q.shape[2] % max(1, deg(h_ax))
-        or not supports(q.shape[1], k.shape[1], q.shape[-1])
-    ):
-        return None
+        return False
+    return h_ax is None or heads % ctx.mesh.shape[h_ax] == 0
+
+
+def _per_device(core, ctx, specs, check_vma=None):
+    """`core(q, k, v)` run by every device on ITS block of q/k/v
+    [b, s, h, d] — shard_map, the GSPMD-compatible way to place inside a
+    sharded step what jit alone cannot partition: an opaque pallas call,
+    or a scan over an axis the mesh shards. `specs` is the PartitionSpec
+    for q/k/v and the output; GSPMD reshards inputs to match, so callers
+    choose the layout (e.g. Ulysses' seq→head all-to-all is exactly the
+    reshard these in_specs induce).
+
+    `check_vma` None keeps the varying-axes checker where it is needed:
+    on a mesh with an axis that `specs` leave out (q/k/v replicated over
+    it) it is what tells autodiff that their cotangents are replicated
+    too, and without it every backward pass psums them over that axis.
+    Where `specs` name every axis it has nothing to tell, and tracing
+    the x4 train step through it took 1.83 s on the chip's host where
+    1.18 s do (12 nodes; builder's chip run, PR 36)."""
     from jax.sharding import PartitionSpec as P
 
+    if check_vma is None:
+        check_vma = any(
+            size > 1 and axis not in specs
+            for axis, size in ctx.mesh.shape.items()
+        )
     spec = P(*specs)
-    # check_vma off: a pallas_call's out_shape carries no varying-axes
-    # annotation, and the checker refuses it
-    fn = jax.shard_map(
-        lambda a, b, c: flash_attention_tpu(a, b, c, causal=causal),
-        mesh=mesh,
+    return jax.shard_map(
+        core,
+        mesh=ctx.mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_vma=False,
+        check_vma=check_vma,
     )
-    return fn(q, k, v)
 
 
-def _try_tiled(q, k, v, ctx, causal):
+def _tiled_flash_sharded(q, k, v, ctx, causal, specs):
+    """Run the hand-tiled Pallas kernel (flash_kernel.py) per device.
+    Returns None when the per-device block doesn't tile
+    (`_tiled_takes`)."""
+    from flexflow_tpu.ops.pallas.flash_kernel import flash_attention_tpu
+
+    if not _tiled_takes(
+        ctx, q.shape[2], q.shape[1], k.shape[1], q.shape[-1], specs
+    ):
+        return None
+    # check_vma off: a pallas_call's out_shape carries no varying-axes
+    # annotation, and the checker refuses it
+    return _per_device(
+        lambda a, b, c: flash_attention_tpu(a, b, c, causal=causal),
+        ctx, specs, check_vma=False,
+    )(q, k, v)
+
+
+def _tiled(q, k, v, ctx, causal):
     """The one dispatch point for the hand-tiled kernel outside the
-    seq-parallel paths: direct call on a single device, shard_map over the
-    batch/head axes on a mesh. None when the shape/backend doesn't take it
-    (callers fall back to dense/blockwise)."""
-    single = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
-    if single:
-        if jax.default_backend() != "tpu":
-            return None
-        from flexflow_tpu.ops.pallas.flash_kernel import (
-            flash_attention_tpu,
-            supports,
-        )
+    seq-parallel paths, for a shape `_tiled_takes`: direct call on a
+    single device, per device over the batch/head axes on a mesh."""
+    if _single_device(ctx):
+        from flexflow_tpu.ops.pallas.flash_kernel import flash_attention_tpu
 
-        if not supports(q.shape[1], k.shape[1], q.shape[-1]):
-            return None
         return flash_attention_tpu(q, k, v, causal=causal)
-    axes = _q_mesh_axes(ctx)
-    b_ax, _, h_ax = axes if axes else (None, None, None)
-    return _tiled_flash_sharded(
-        q, k, v, ctx, causal, (b_ax, None, h_ax, None)
+    return _tiled_flash_sharded(q, k, v, ctx, causal, _batch_head_specs(ctx))
+
+
+def _drops(params, ctx) -> bool:
+    """Whether this lowering applies attention-prob dropout."""
+    return bool(
+        params.get("dropout", 0.0) > 0.0
+        and ctx.train
+        and ctx.rng is not None
     )
+
+
+class CorePlan(NamedTuple):
+    """What `mha_core_plan` chose for one attention node."""
+
+    core: str  # one_shot | chunked | tiled | flash | ring | ulysses
+    chunk: int  # sequences one pass of the core attends on a device
+    local_batch: int  # sequences of the batch a device holds
+    per_device: bool  # run under shard_map on each device's own block
+
+
+def mha_core_plan(params, ctx, shape=None) -> CorePlan:
+    """The attention core a `multihead_attention` node with `params`
+    lowers to under `ctx`: chosen ONCE, here, from what the lowering can
+    see (the node's parameters, the mesh and q's partition degrees in
+    `ctx`, the shapes, the measured caps) and nothing a user sets for
+    the purpose. `shape` is the GLOBAL (batch, sq, sk, heads, head_dim)
+    of the projected q and k; None reads it from `ctx.in_shapes`, so
+    that a test or a profile can ask with a ctx alone.
+
+    Sequence sharded and k/v sharded alike: `ring` or `ulysses`. Else by
+    the PER-DEVICE float32 score block [b, h, sq, sk]: `flash` (the
+    blockwise or library kernel) from _FLASH_SCORE_BYTES up, `tiled`
+    (the hand-tiled kernel) there and where one sequence's block already
+    overflows the chunk cap, if the kernel takes the shape; `chunked`
+    (the rematerialised scan over chunks of the local batch) past the
+    mono cap; `one_shot` below it. The scan wants its leading axis whole
+    on a device: the global batch when nothing shards it, and each
+    device's LOCAL batch (`per_device`) when the mesh shards the batch
+    and not the sequence. Attention-prob dropout keeps the one-shot
+    kernel (the rng path); a sequence sharded with no seq-parallel path
+    under a sharded batch keeps it too (per device the sequence would
+    have to be all-gathered: GSPMD partitions the one-shot einsums)."""
+    use_flash = params.get("use_flash", "auto")
+    seq_parallel = params.get("seq_parallel", "auto")
+    if shape is None:
+        q_dims, k_dims = (
+            [d.size for d in s.dims if not d.is_replica_dim]
+            for s in ctx.in_shapes[:2]
+        )
+        heads = params["num_heads"]
+        shape = (
+            q_dims[0], q_dims[1], k_dims[1], heads,
+            params["embed_dim"] // heads,
+        )
+    batch, sq, sk, heads, head_dim = shape
+    dropping = _drops(params, ctx)
+    b_deg, s_deg, h_deg = _q_degrees(ctx)
+    local_b = max(1, batch // b_deg)
+    h_loc = max(1, heads // h_deg)
+    sq_loc = max(1, sq // s_deg)
+
+    sp = None if seq_parallel == "none" else _seq_parallel_axes(ctx)
+    if sp is not None and dropping:
+        if seq_parallel in ("ring", "ulysses"):
+            # don't silently densify an explicitly requested SP path —
+            # dense attention materializes the [s, s] scores SP avoids
+            raise ValueError(
+                f"seq_parallel={seq_parallel!r} does not support "
+                "attention-prob dropout; use dropout=0.0 or "
+                "seq_parallel='auto' (which falls back to dense)"
+            )
+        sp = None
+    if sp is not None:
+        seq_ax, _, head_ax = sp
+        mode = "ring" if seq_parallel == "auto" else seq_parallel
+        # Ulysses reshards seq→heads, so it needs the head dim free of
+        # TP sharding and divisible by the seq-axis degree
+        if mode == "ulysses" and not (
+            head_ax is None and heads % ctx.mesh.shape[seq_ax] == 0
+        ):
+            raise ValueError(
+                "seq_parallel='ulysses' needs num_heads divisible by the "
+                f"seq-axis degree ({ctx.mesh.shape[seq_ax]}) and heads "
+                "free of tensor-parallel sharding; use 'ring'"
+            )
+        return CorePlan(mode, local_b, local_b, True)
+
+    def tiled_or(plan):
+        if use_flash is not False and _tiled_takes(
+            ctx, heads, sq, sk, head_dim
+        ):
+            return CorePlan("tiled", local_b, local_b, not _single_device(ctx))
+        return plan
+
+    if dropping:  # no prob-dropout path but the one-shot kernel's
+        return CorePlan("one_shot", local_b, local_b, False)
+    if use_flash is True or (
+        use_flash == "auto" and _auto_flash(batch, heads, sq, sk, ctx)
+    ):
+        return tiled_or(CorePlan("flash", local_b, local_b, False))
+    # batch-chunked dense, sized by the PER-DEVICE score block (seq/head
+    # sharding divides out like in _auto_flash). Where the mesh shards
+    # the batch the scan runs per device, on the local batch: it cannot
+    # iterate a GSPMD-sharded leading axis, and the one-shot kernel such
+    # a mesh used to get whatever its local block cost 1.9 times the
+    # step (_chunked_dense_attention has the numbers)
+    chunk, per_device = local_b, False
+    if b_deg == 1:
+        chunk = _dense_batch_chunk(batch, h_loc, sq_loc, sk)
+    elif s_deg == 1 and _batch_head_specs(ctx)[0] is not None:
+        chunk = _dense_batch_chunk(local_b, h_loc, sq, sk)
+        per_device = chunk < local_b
+    dense = CorePlan(
+        "chunked" if chunk < local_b else "one_shot", chunk, local_b,
+        per_device,
+    )
+    # when even ONE sample's score block overflows the chunk
+    # cap (seq ~2048-8192, small batch), the chunked scan
+    # degenerates to a stores-nothing single-sample remat —
+    # measured 10-60% SLOWER than one-shot dense in isolation.
+    # That band belongs to the hand-tiled kernel: 12.4 ms vs
+    # 21.8 dense / ~52 blockwise at seq 2048 bs8h16 on v5e
+    # (scripts/bench_flash_kernel.py). Below it, chunked dense
+    # keeps the full-step crown (19.0 vs 23.6 ms flagship
+    # A/B, scripts/ab_attn_tiled.py — the tiled kernel's
+    # per-call layout transposes eat its margin at seq 512).
+    if h_loc * sq_loc * sk * 4 > _DENSE_CHUNK_SCORE_BYTES:
+        return tiled_or(dense)
+    return dense
 
 
 def _lower_mha(params):
@@ -964,12 +1125,11 @@ def _lower_mha(params):
         ):
             from flexflow_tpu.ops.pallas.flash_attention import flash_attention
 
-            single = ctx.mesh is None or ctx.mesh.size == 1
             attn = flash_attention(
                 qh, kh, vh, causal=causal,
                 # None = auto (backend + device checks inside); a sharded
                 # mesh must force the partitionable blockwise path
-                use_lib=None if single else False,
+                use_lib=None if _single_device(ctx) else False,
             )
         else:
             attn = scaled_dot_product_attention(qh, kh, vh, causal=causal)
@@ -983,128 +1143,56 @@ def _lower_mha(params):
         q, k, v = mha_project_qkv(
             ins, ws, ctx, use_bias=use_bias, params=params
         )
-        seq = q.shape[1]
-        dropping = dropout > 0.0 and ctx.train and ctx.rng is not None
-        sp = None if seq_parallel == "none" else _seq_parallel_axes(ctx)
-        if sp is not None and dropping:
-            if seq_parallel in ("ring", "ulysses"):
-                # don't silently densify an explicitly requested SP path —
-                # dense attention materializes the [s, s] scores SP avoids
-                raise ValueError(
-                    f"seq_parallel={seq_parallel!r} does not support "
-                    "attention-prob dropout; use dropout=0.0 or "
-                    "seq_parallel='auto' (which falls back to dense)"
-                )
-            sp = None
-        if sp is not None:
-            seq_ax, batch_ax, head_ax = sp
-            mode = "ring" if seq_parallel == "auto" else seq_parallel
-            # Ulysses reshards seq→heads, so it needs the head dim free of
-            # TP sharding and divisible by the seq-axis degree
-            ulysses_ok = (
-                head_ax is None and q.shape[2] % ctx.mesh.shape[seq_ax] == 0
+        plan = mha_core_plan(
+            params, ctx,
+            (q.shape[0], q.shape[1], k.shape[1], q.shape[2], q.shape[3]),
+        )
+        if plan.core == "ulysses":
+            seq_ax, batch_ax, _ = _seq_parallel_axes(ctx)
+            attn = _ulysses(q, k, v, ctx, seq_ax, batch_ax)
+        elif plan.core == "ring":
+            from flexflow_tpu.ops.pallas.ring_attention import ring_attention
+
+            seq_ax, batch_ax, head_ax = _seq_parallel_axes(ctx)
+            attn = ring_attention(
+                q,
+                k,
+                v,
+                ctx.mesh,
+                seq_ax,
+                causal=causal,
+                batch_axis=batch_ax,
+                head_axis=head_ax,
             )
-            if mode == "ulysses" and not ulysses_ok:
-                raise ValueError(
-                    "seq_parallel='ulysses' needs num_heads divisible by the "
-                    f"seq-axis degree ({ctx.mesh.shape[seq_ax]}) and heads "
-                    "free of tensor-parallel sharding; use 'ring'"
-                )
-            if mode == "ulysses":
-                attn = _ulysses(q, k, v, ctx, seq_ax, batch_ax)
-            else:
-                from flexflow_tpu.ops.pallas.ring_attention import ring_attention
+        elif plan.core == "tiled":
+            attn = _tiled(q, k, v, ctx, causal)
+        elif plan.core == "flash":
+            from flexflow_tpu.ops.pallas.flash_attention import flash_attention
 
-                attn = ring_attention(
-                    q,
-                    k,
-                    v,
-                    ctx.mesh,
-                    seq_ax,
-                    causal=causal,
-                    batch_axis=batch_ax,
-                    head_axis=head_ax,
-                )
+            # the library kernel (single-device) or the jnp blockwise
+            # path, which XLA partitions over batch/heads
+            attn = flash_attention(
+                q, k, v, causal=causal,
+                use_lib=None if _single_device(ctx) else False,
+            )
+        elif plan.core == "chunked":
+
+            def core(qq, kk, vv):
+                return _chunked_dense_attention(qq, kk, vv, causal, plan.chunk)
+
+            if plan.per_device:
+                core = _per_device(core, ctx, _batch_head_specs(ctx))
+            attn = core(q, k, v)
         else:
-            flash = (
-                use_flash is True
-                or (
-                    use_flash == "auto"
-                    and _auto_flash(
-                        q.shape[0], q.shape[2], seq, k.shape[1], ctx
-                    )
-                )
-            ) and not dropping  # the blockwise kernel has no prob-dropout path
-            if flash:
-                from flexflow_tpu.ops.pallas.flash_attention import flash_attention
-
-                # the hand-tiled kernel wherever it takes the shape (direct
-                # single-device, shard_map over batch/head axes on a mesh);
-                # else the library kernel (single-device) or the jnp
-                # blockwise path, which XLA partitions over batch/heads
-                single = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
-                attn = _try_tiled(q, k, v, ctx, causal)
-                if attn is None:
-                    attn = flash_attention(
-                        q, k, v, causal=causal,
-                        use_lib=None if single else False,
-                    )
-            else:
-                # batch-chunked dense: only when the batch dim is unsharded
-                # (a scan cannot iterate a GSPMD-sharded leading axis) and
-                # no prob-dropout (keeps the rng path on the one-shot
-                # kernel); size the chunk by the PER-DEVICE score block, so
-                # seq/head sharding divides out like in _auto_flash
-                b_deg, s_deg, h_deg = _q_degrees(ctx)
-                chunk = (
-                    _dense_batch_chunk(
-                        q.shape[0],
-                        max(1, q.shape[2] // h_deg),
-                        max(1, seq // s_deg),
-                        k.shape[1],
-                    )
-                    if (b_deg == 1 and not dropping)
-                    else q.shape[0]
-                )
-                # when even ONE sample's score block overflows the chunk
-                # cap (seq ~2048-8192, small batch), the chunked scan
-                # degenerates to a stores-nothing single-sample remat —
-                # measured 10-60% SLOWER than one-shot dense in isolation.
-                # That band belongs to the hand-tiled kernel: 12.4 ms vs
-                # 21.8 dense / ~52 blockwise at seq 2048 bs8h16 on v5e
-                # (scripts/bench_flash_kernel.py). Below it, chunked dense
-                # keeps the full-step crown (19.0 vs 23.6 ms flagship
-                # A/B, scripts/ab_attn_tiled.py — the tiled kernel's
-                # per-call layout transposes eat its margin at seq 512).
-                single_fits = (
-                    max(1, q.shape[2] // h_deg)
-                    * max(1, seq // s_deg)
-                    * k.shape[1]
-                    * 4
-                    <= _DENSE_CHUNK_SCORE_BYTES
-                )
-                tiled = (
-                    _try_tiled(q, k, v, ctx, causal)
-                    if (
-                        not single_fits
-                        and not dropping
-                        and use_flash is not False
-                    )
-                    else None
-                )
-                if tiled is not None:
-                    attn = tiled
-                elif chunk < q.shape[0]:
-                    attn = _chunked_dense_attention(q, k, v, causal, chunk)
-                else:
-                    attn = scaled_dot_product_attention(
-                        q,
-                        k,
-                        v,
-                        causal=causal,
-                        dropout_rate=dropout if dropping else 0.0,
-                        dropout_rng=ctx.rng if dropping else None,
-                    )
+            dropping = _drops(params, ctx)
+            attn = scaled_dot_product_attention(
+                q,
+                k,
+                v,
+                causal=causal,
+                dropout_rate=dropout if dropping else 0.0,
+                dropout_rng=ctx.rng if dropping else None,
+            )
         return [mha_project_out(attn, ws, ctx, dt, use_bias=use_bias)]
 
     return fn

@@ -320,6 +320,22 @@ class Executor:
         with node_scope(node):
             return self._constrain(x, node.output_shapes[0])
 
+    def node_ctx(self, node, train=True, rng=None) -> LowerCtx:
+        """The context `node` is lowered under: the mesh, its axis names
+        and the parallel shapes of the node's inputs, which is all a
+        sharding-aware lowering sees of the strategy (and what a test or
+        a profile hands `ops.attention.mha_core_plan` to ask which core
+        an attention node gets)."""
+        return LowerCtx(
+            train=train,
+            rng=rng,
+            mesh=self.mesh,
+            axis_names=self.mesh_config.axis_names,
+            in_shapes=[self.graph.shape_of(r) for r in node.inputs],
+            bf16_matmul=self.mixed_precision,
+            seq_length=self.seq_length,
+        )
+
     def forward_values(
         self,
         params,
@@ -365,14 +381,9 @@ class Executor:
                 continue
             ins = [values[(r.guid, r.out_idx)] for r in node.inputs]
             ws = params.get(guid, [])
-            ctx = LowerCtx(
-                train=train,
-                rng=None if rng is None else jax.random.fold_in(rng, guid),
-                mesh=self.mesh,
-                axis_names=self.mesh_config.axis_names,
-                in_shapes=[self.graph.shape_of(r) for r in node.inputs],
-                bf16_matmul=self.mixed_precision,
-                seq_length=self.seq_length,
+            ctx = self.node_ctx(
+                node, train,
+                None if rng is None else jax.random.fold_in(rng, guid),
             )
             hook = op_hooks.get(node.op_type) if op_hooks else None
             outs = self.lower_node(guid, ins, ws, ctx, hook, constrain)

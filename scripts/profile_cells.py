@@ -83,7 +83,37 @@ def train(args, cell, config, traffic):
     )
     print(f"untraced: {untraced_ms:.3f} ms a step on the host's clock "
           f"({args.steps} steps enqueued, one wait)")
-    return {"jit_step": _as_dict(profile), "untraced_wall_ms": untraced_ms}
+    plans = _attention_plans(ex, profile)
+    return {"jit_step": _as_dict(profile), "untraced_wall_ms": untraced_ms,
+            "attention_plans": plans}
+
+
+def _attention_plans(ex, profile):
+    """Which core each `multihead_attention` node was lowered to
+    (`ops/attention.py:mha_core_plan`, asked as `forward_values` asks it
+    in a train step), printed beside the node's row of `profile`."""
+    import jax
+
+    from flexflow_tpu.core.types import OperatorType
+    from flexflow_tpu.ops.attention import mha_core_plan
+
+    rows = {r.scope: r for r in profile.rows}
+    plans = {}
+    for node in (ex.graph.nodes[g] for g in ex.topo):
+        if node.op_type != OperatorType.MULTIHEAD_ATTENTION:
+            continue
+        plan = mha_core_plan(
+            node.params, ex.node_ctx(node, rng=jax.random.PRNGKey(0))
+        )
+        scope = f"{node.op_type.name.lower()}:{node.name}"
+        row = rows.get(scope)
+        times = (f"fwd {row.forward_ms:.3f} bwd {row.backward_ms:.3f} ms"
+                 if row else "no row")
+        print(f"{scope:<44} {plan.core}, {plan.chunk} of {plan.local_batch} "
+              f"local sequences a pass"
+              f"{', per device' if plan.per_device else ''}: {times}")
+        plans[scope] = plan._asdict()
+    return plans
 
 
 def serve(args, cell, config, traffic):

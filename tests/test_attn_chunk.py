@@ -108,3 +108,256 @@ def test_over_cap_band_prefers_memory_safe_chunks():
     # seq 1024, batch 8: 67 MB single-sample chunks fit -> scan
     # (measured 3.7x FASTER than monolithic as well)
     assert A._dense_batch_chunk(8, h, 1024, 1024) == 1
+
+
+# -- on a mesh: the chunked core on each device's LOCAL batch (PR 36) -------
+
+
+@pytest.fixture
+def dense_caps():
+    """`set_dense_caps`, with the caps this process had put back after."""
+    saved = A._DENSE_MONO_SCORE_BYTES, A._DENSE_CHUNK_SCORE_BYTES
+    yield A.set_dense_caps
+    A._DENSE_MONO_SCORE_BYTES, A._DENSE_CHUNK_SCORE_BYTES = saved
+
+
+_SEQ, _HID, _HEADS, _LAYERS = 128, 32, 4, 2
+
+
+def _mesh_model(batch, mesh):
+    """Two attention blocks and a dense head, float32. `mesh`: "dp4" is
+    the benchmark's x4 strategy (one `data` axis of 4); "dp2_tp2" a
+    searched one that shards batch and heads together; "one" a single
+    device; "dp2_idle2" a 2 x 2 mesh whose second axis shards nothing of
+    attention (a strategy that keeps it for other operators)."""
+    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+    from flexflow_tpu.parallel.strategy import (
+        Strategy,
+        annotate_input_batch,
+        data_parallel_strategy,
+        site_strategy,
+    )
+    from flexflow_tpu.runtime.executor import MeshConfig
+    from flexflow_tpu.search.rewrites import AttentionSite, find_tp_sites
+
+    m = FFModel(FFConfig(batch_size=batch, learning_rate=0.05))
+    t = m.create_tensor([batch, _SEQ, _HID], name="x")
+    for _ in range(_LAYERS):
+        t = m.add(t, m.multihead_attention(t, t, t, _HID, _HEADS))
+    m.dense(t, 1, use_bias=False)
+    if mesh == "dp2_tp2":
+        sites = [
+            s for s in find_tp_sites(m.graph) if isinstance(s, AttentionSite)
+        ]
+        assert len(sites) == _LAYERS
+        strategy = site_strategy(m.graph, 4, 2, sites)
+    elif mesh == "dp2_idle2":
+        strategy = Strategy(
+            MeshConfig(("data", "model"), (2, 2)),
+            lambda g: annotate_input_batch(g, 2),
+        )
+    else:
+        strategy = data_parallel_strategy(1 if mesh == "one" else 4, m.graph)
+    m.compile(
+        optimizer=SGDOptimizer(lr=0.05),
+        loss_type=LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+        metrics=[],
+        strategy=strategy,
+        devices=jax.devices()[: strategy.mesh_config.num_devices],
+    )
+    return m
+
+
+def _attention_plans(m):
+    from flexflow_tpu.core.types import OperatorType
+
+    ex = m.executor
+    return [
+        A.mha_core_plan(node.params, ex.node_ctx(node))
+        for node in (ex.graph.nodes[g] for g in ex.topo)
+        if node.op_type == OperatorType.MULTIHEAD_ATTENTION
+    ]
+
+
+def _one_step(m, data):
+    """Loss, outputs, every weight's gradient and every weight after one
+    train step from the model's initial state, on the host."""
+    ex = m.executor
+    placed = ex.shard_batch(data)
+    out = ex.forward_fn()(m.params, placed)
+    grads = ex.grad_fn()(m.params, placed)
+    state = jax.tree_util.tree_map(jnp.copy, (m.params, m.opt_state))
+    new_params, _, loss, _ = ex.train_step()(
+        *state, placed, jax.random.PRNGKey(0)
+    )
+
+    def host(tree):
+        return [np.asarray(w) for g in sorted(tree) for w in tree[g]]
+
+    return float(loss), np.asarray(out), host(grads), host(new_params)
+
+
+def _batch(batch):
+    rng = np.random.default_rng(36)
+    return {
+        "x": rng.normal(size=(batch, _SEQ, _HID)).astype(np.float32),
+        "label": rng.normal(size=(batch, _SEQ, 1)).astype(np.float32),
+    }
+
+
+# a sequence's float32 score block is heads x 128 x 128 x 4 bytes: 256 KiB
+# with 4 heads, 128 KiB with the 2 a head-sharded device holds. Caps of
+# 1 MB take chunks of 4 and of 8 sequences out of a local batch of 8 and 16.
+@pytest.mark.parametrize(
+    "mesh,batch,plan",
+    [
+        ("dp4", 32, A.CorePlan("chunked", 4, 8, True)),
+        ("dp2_tp2", 32, A.CorePlan("chunked", 8, 16, True)),
+        ("dp2_idle2", 16, A.CorePlan("chunked", 4, 8, True)),
+    ],
+)
+def test_mesh_chunks_local_batch_and_matches_one_shot(
+    dense_caps, mesh, batch, plan
+):
+    data = _batch(batch)
+    dense_caps(1, 1)
+    m = _mesh_model(batch, mesh)
+    assert _attention_plans(m) == [plan] * _LAYERS
+    chunked = _one_step(m, data)
+    dense_caps(1 << 20, 1 << 20)  # the chunking forced off
+    m = _mesh_model(batch, mesh)
+    assert [p.core for p in _attention_plans(m)] == ["one_shot"] * _LAYERS
+    one_shot = _one_step(m, data)
+    assert abs(chunked[0] - one_shot[0]) <= 1e-5 * abs(one_shot[0])
+    np.testing.assert_allclose(chunked[1], one_shot[1], rtol=1e-5, atol=1e-5)
+    assert len(chunked[2]) == len(one_shot[2]) > 8 * _LAYERS
+    for got, want in zip(chunked[2] + chunked[3], one_shot[2] + one_shot[3]):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def _compiled_step(m, data):
+    ex = m.executor
+    return ex.train_step().lower(
+        m.params, m.opt_state, ex.shard_batch(data), jax.random.PRNGKey(0)
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("mesh", ["dp4", "dp2_tp2", "dp2_idle2"])
+def test_mesh_step_holds_the_scan_and_no_resharding(dense_caps, mesh):
+    """The compiled mesh step: each attention node's scan is there,
+    forward and backward, and the per-device wrapper costs no collective:
+    what crosses chips is what the one-shot lowering sends too, the
+    all-reduce of the gradients (and, with the heads sharded, of the
+    output projection's partial sums). On "dp2_idle2" that holds because
+    the wrapper keeps its varying-axes checker there: without it the
+    backward psums q's, k's and v's cotangents over the idle axis."""
+    import re
+
+    def collectives(text):
+        return sorted(
+            re.findall(
+                r"\b(all-reduce|all-gather|all-to-all|collective-permute|"
+                r"reduce-scatter)(?:-start)?\(",
+                text,
+            )
+        )
+
+    dense_caps(1, 1)
+    chunked = _compiled_step(_mesh_model(32, mesh), _batch(32))
+    dense_caps(1 << 20, 1 << 20)
+    one_shot = _compiled_step(_mesh_model(32, mesh), _batch(32))
+    assert len(re.findall(r"\bwhile\(", chunked)) >= 2 * _LAYERS
+    assert not re.search(r"\bwhile\(", one_shot)
+    assert set(collectives(chunked)) == {"all-reduce"}
+    assert collectives(chunked) == collectives(one_shot)
+
+
+def _ctx(batch, b_deg=1, s_deg=1, h_deg=1, embed=1024, seq=512, **kw):
+    """The ctx `forward_values` hands a self-attention node whose input
+    [batch, seq, embed] is partitioned b_deg x s_deg, replicated h_deg
+    times for the heads, on a mesh with one axis for each."""
+    from jax.sharding import Mesh
+
+    from flexflow_tpu.core.parallel_tensor import (
+        ParallelDim,
+        ParallelTensorShape,
+    )
+    from flexflow_tpu.core.types import DataType
+    from flexflow_tpu.ops.registry import LowerCtx
+
+    degs = (b_deg, s_deg, h_deg)
+    n = b_deg * s_deg * h_deg
+    mesh = (
+        Mesh(np.array(jax.devices()[:n]).reshape(degs), ("data", "seq", "model"))
+        if n > 1
+        else None
+    )
+    dims = [ParallelDim(h_deg, h_deg, 2, True)] if h_deg > 1 else []
+    dims += [
+        ParallelDim(batch, b_deg, 0 if b_deg > 1 else -1),
+        ParallelDim(seq, s_deg, 1 if s_deg > 1 else -1),
+        ParallelDim(embed),
+    ]
+    x = ParallelTensorShape(tuple(dims), DataType.FLOAT)
+    return LowerCtx(
+        mesh=mesh, axis_names=("data", "seq", "model"), in_shapes=[x, x, x],
+        **kw,
+    )
+
+
+_FLAGSHIP = {"embed_dim": 1024, "num_heads": 16}
+
+
+@pytest.mark.parametrize(
+    "params,ctx,plan",
+    [
+        # `train_ff_b256_x4`: 64 local sequences, 1 GiB of scores a chip
+        (_FLAGSHIP, dict(batch=256, b_deg=4), ("chunked", 4, 64, True)),
+        # `train_ff_b64`: the one-chip program, no wrapper
+        (_FLAGSHIP, dict(batch=64), ("chunked", 4, 64, False)),
+        # batch and heads sharded together: 8 local heads, chunks of 8
+        (_FLAGSHIP, dict(batch=256, b_deg=4, h_deg=2),
+         ("chunked", 8, 64, True)),
+        # heads alone: the global batch is whole on a device, GSPMD
+        # partitions the scan's body
+        (_FLAGSHIP, dict(batch=64, h_deg=2), ("chunked", 8, 64, False)),
+        # the sequence sharded and no seq-parallel path
+        ({**_FLAGSHIP, "seq_parallel": "none"},
+         dict(batch=256, b_deg=2, s_deg=2), ("one_shot", 128, 128, False)),
+        # attention-prob dropout
+        ({**_FLAGSHIP, "dropout": 0.1},
+         dict(batch=256, b_deg=4, train=True, rng=0),
+         ("one_shot", 64, 64, False)),
+        # a local block under the mono cap (64 MB): every toy mesh test
+        (_FLAGSHIP, dict(batch=16, b_deg=4), ("one_shot", 4, 4, False)),
+        # the sequence sharded on q and k alike
+        (_FLAGSHIP, dict(batch=8, b_deg=2, s_deg=2), ("ring", 4, 4, True)),
+    ],
+)
+def test_core_plan(params, ctx, plan):
+    assert A.mha_core_plan(params, _ctx(**ctx)) == A.CorePlan(*plan)
+
+
+def test_one_device_step_scans_without_shard_map(dense_caps):
+    """`b_deg == 1` is left as it was: the scan over the global batch,
+    no wrapper round it (on the chip the compiled `train_ff_b64` step is
+    the parent's text for text: CHANGES.md, PR 36)."""
+    dense_caps(1, 1)
+    m = _mesh_model(8, "one")
+    assert _attention_plans(m) == [A.CorePlan("chunked", 4, 8, False)] * _LAYERS
+    ex = m.executor
+    jaxpr = str(
+        jax.make_jaxpr(ex.train_step_fn())(
+            m.params, m.opt_state, _batch(8), jax.random.PRNGKey(0)
+        )
+    )
+    assert jaxpr.count("scan[") >= 2 * _LAYERS
+    assert "shard_map" not in jaxpr
+    # and the mesh's step does hold one a node and pass
+    m = _mesh_model(32, "dp4")
+    jaxpr = str(
+        jax.make_jaxpr(m.executor.train_step_fn())(
+            m.params, m.opt_state, _batch(32), jax.random.PRNGKey(0)
+        )
+    )
+    assert jaxpr.count("shard_map[") >= 2 * _LAYERS
