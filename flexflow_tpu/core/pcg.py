@@ -57,6 +57,21 @@ class PCGNode:
     def num_outputs(self) -> int:
         return len(self.output_shapes)
 
+    @property
+    def weight_key(self) -> object:
+        """The node's identity across graph rewrites, which re-make nodes
+        under new guids: the builder's name, or the `weight_key` a rewrite
+        stamped on its replacement (substitution.py:_dst_params). What
+        weights are carried over by, and what a borrower names its owner by."""
+        return self.params.get("weight_key", self.name)
+
+    @property
+    def stored_weight_shapes(self) -> Tuple[ParallelTensorShape, ...]:
+        """The weights this node keeps in memory: none where it applies
+        another node's (`params['weights_of']`, FFModel's `weights_of=`).
+        `weight_shapes` is what it reads either way."""
+        return () if "weights_of" in self.params else self.weight_shapes
+
     def params_hash(self) -> int:
         """Hash of (op_type, params) — keys the op-cost cache
         (reference: simulator.cc:532-572 keyed by OperatorParameters)."""
@@ -108,6 +123,39 @@ class PCGGraph:
         for ref in node.inputs:
             self._consumers[ref.guid].add(guid)
         return node
+
+    def weight_owners(self) -> Dict[int, int]:
+        """{borrower guid: owner guid} for every node that applies another
+        node's weights. `params['weights_of']` names the owner by its
+        stable identity (`PCGNode.weight_key`), so the tie survives a rewrite
+        that re-makes either node under a new guid. Raises where the owner
+        is gone or not unique, or where a strategy gave the two different
+        weight shardings."""
+        borrowers = [n for n in self.nodes.values() if "weights_of" in n.params]
+        if not borrowers:
+            return {}
+        by_key: Dict[object, List[PCGNode]] = defaultdict(list)
+        for n in self.nodes.values():
+            if n.stored_weight_shapes:
+                by_key[n.weight_key].append(n)
+        out = {}
+        for n in borrowers:
+            owners = by_key.get(n.params["weights_of"], [])
+            if len(owners) != 1:
+                raise ValueError(
+                    f"node '{n.name}' applies the weights of "
+                    f"'{n.params['weights_of']}', which {len(owners)} nodes "
+                    "of the graph own"
+                )
+            if owners[0].weight_shapes != n.weight_shapes:
+                raise ValueError(
+                    f"node '{n.name}' and '{owners[0].name}', whose weights "
+                    "it applies, need one weight sharding: "
+                    f"{[str(s) for s in n.weight_shapes]} against "
+                    f"{[str(s) for s in owners[0].weight_shapes]}"
+                )
+            out[n.guid] = owners[0].guid
+        return out
 
     def remove_node(self, guid: int):
         node = self.nodes.pop(guid)

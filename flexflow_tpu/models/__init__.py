@@ -17,6 +17,7 @@ from flexflow_tpu.models.nlp import (
     build_deepseek_v3,
     build_mt5_encoder,
     build_olmoe,
+    build_ouro,
     build_transformer_encoder,
 )
 from flexflow_tpu.models.recommender import build_candle_uno, build_dlrm, build_xdl
@@ -34,6 +35,7 @@ __all__ = [
     "build_mt5_encoder",
     "build_deepseek_v3",
     "build_olmoe",
+    "build_ouro",
     "build_dlrm",
     "build_xdl",
     "build_candle_uno",

@@ -1,5 +1,7 @@
 """NLP workloads: Transformer encoder (the flagship bench), BERT proxy,
-mT5-style encoder, and the served decoders (GPT-style, OLMoE)."""
+mT5-style encoder, and the served decoders (GPT-style `build_decoder_lm`,
+`build_olmoe`, `build_deepseek_v3`, and `build_ouro`, whose layers run
+several times over one set of weights)."""
 
 from __future__ import annotations
 
@@ -190,3 +192,63 @@ def build_deepseek_v3(
         shared = ff.gated_mlp(m, shared_experts * expert_hidden)
         t = ff.add(t, ff.add(routed, shared))
     return ff.dense(ff.rms_norm(t, eps=eps), vocab_size, use_bias=False)
+
+
+def build_ouro(
+    ff,
+    token_ids,
+    vocab_size: int = 49152,
+    hidden: int = 2048,
+    num_heads: int = 16,
+    num_layers: int = 48,
+    ff_dim: int = 5632,
+    loops: int = 4,
+    rope_theta: float = 1000000.0,
+    eps: float = 1e-6,
+):
+    """Ouro (ByteDance/Ouro-2.6B, arXiv:2510.25741): a stack of
+    `num_layers` layers run `loops` times over the SAME weights. A layer
+    is `u += N2(Attn(N1(u)))` then `u += N4(MLP(N3(u)))`: causal rotary
+    attention with as many key heads as query heads, a SiLU-gated MLP, an
+    RMSNorm before and after each. After every pass the one final norm,
+    whose output is the next pass's input and feeds the exit gate
+    `sigmoid(w_g . h + b_g)`; an untied head over the last pass. No
+    biases but the gate's.
+
+    Pass 1 makes the nodes that own the weights; passes 2.. apply them
+    (`weights_of=`), so each is stored once while every pass's attention
+    is a node, and a cache layer, of its own: pass t of layer i attends
+    over what pass t of layer i wrote. Nodes are named by pass and layer
+    (`p2.l5.attn`), which the step programs' scopes carry. The gates
+    (`p<t>.exit`) are sinks beside the head: pass the returned head tensor
+    as `compile(logits=)`. Served like build_decoder_lm: vocab logits of
+    the last pass (the published early_exit_threshold of 1 never exits
+    before it), one token-id input."""
+    first = {}
+
+    def part(build, key, *args, **kwargs):
+        # the first call under `key` owns the weights, later ones borrow
+        out = build(*args, weights_of=first.get(key), **kwargs)
+        first.setdefault(key, out)
+        return out
+
+    t = ff.embedding(token_ids, vocab_size, hidden)
+    for p in range(1, loops + 1):
+        for layer in range(1, num_layers + 1):
+            at = f"p{p}.l{layer}"
+            h = part(ff.rms_norm, (layer, "n1"), t, eps=eps, name=f"{at}.n1")
+            a = part(
+                ff.multihead_attention, (layer, "attn"), h, h, h, hidden,
+                num_heads, bias=False, causal=True, rope_theta=rope_theta,
+                name=f"{at}.attn",
+            )
+            a = part(ff.rms_norm, (layer, "n2"), a, eps=eps, name=f"{at}.n2")
+            t = ff.add(t, a, name=f"{at}.add1")
+            h = part(ff.rms_norm, (layer, "n3"), t, eps=eps, name=f"{at}.n3")
+            m = part(ff.gated_mlp, (layer, "mlp"), h, ff_dim, name=f"{at}.mlp")
+            m = part(ff.rms_norm, (layer, "n4"), m, eps=eps, name=f"{at}.n4")
+            t = ff.add(t, m, name=f"{at}.add2")
+        t = part(ff.rms_norm, "norm", t, eps=eps, name=f"p{p}.norm")
+        gate = part(ff.dense, "gate", t, 1, name=f"p{p}.gate")
+        ff.sigmoid(gate, name=f"p{p}.exit")
+    return ff.dense(t, vocab_size, use_bias=False, name="head")

@@ -125,6 +125,11 @@ class Executor:
         self.seq_length = seq_length
         self.sparse_embedding_update = sparse_embedding_update
         self.topo = graph.topo_order()
+        # nodes that apply another node's weights (FFModel's `weights_of=`):
+        # {borrower guid: owner guid}. `params` holds the owner's arrays
+        # only, so the optimizer, a checkpoint and a resharding see each
+        # shared weight once, and its gradient sums over the applications
+        self.weight_owner = graph.weight_owners()
         self._lowered = {
             g: lower_op(graph.nodes[g].op_type, graph.nodes[g].params)
             for g in self.topo
@@ -172,7 +177,7 @@ class Executor:
         params: Dict[int, List[jnp.ndarray]] = {}
         for guid in self.topo:
             node = self.graph.nodes[guid]
-            if not node.weight_shapes or guid in skip_guids:
+            if not node.stored_weight_shapes or guid in skip_guids:
                 continue
             ws = []
             inits = node.params.get("initializers")
@@ -196,7 +201,7 @@ class Executor:
         params: Dict[int, List[jnp.ndarray]] = {}
         for guid in self.topo:
             node = self.graph.nodes[guid]
-            if not node.weight_shapes or guid in skip_guids:
+            if not node.stored_weight_shapes or guid in skip_guids:
                 continue
             if guid not in host_params:
                 raise KeyError(
@@ -284,13 +289,15 @@ class Executor:
         return out
 
     def get_host_param(self, params, guid: int, idx: int):
-        """One weight, in its logical per-guid shape."""
-        return params[guid][idx]
+        """One weight, in its logical per-guid shape (its owner's array,
+        where the node applies another's)."""
+        return params[self.weight_owner.get(guid, guid)][idx]
 
     def set_host_param(self, params, guid: int, idx: int, val):
-        """Write one weight in place (val already validated/dtyped)."""
+        """Write one weight in place (val already validated/dtyped);
+        through a node that applies another's, the owner's."""
         node = self.graph.nodes[guid]
-        params[guid][idx] = jax.device_put(
+        params[self.weight_owner.get(guid, guid)][idx] = jax.device_put(
             val, self.sharding_for(node.weight_shapes[idx])
         )
 
@@ -380,7 +387,7 @@ class Executor:
                 values[(guid, 0)] = _given(node, batch[node.name])
                 continue
             ins = [values[(r.guid, r.out_idx)] for r in node.inputs]
-            ws = params.get(guid, [])
+            ws = params.get(self.weight_owner.get(guid, guid), [])
             ctx = self.node_ctx(
                 node, train,
                 None if rng is None else jax.random.fold_in(rng, guid),
@@ -439,10 +446,14 @@ class Executor:
             return []
         from flexflow_tpu.core.pcg import trace_embedding_ids_input
 
+        # a table that another node applies too takes the dense path: its
+        # gradient is the sum over both, not rows of one lookup
+        tied = set(self.weight_owner) | set(self.weight_owner.values())
         return [
             guid
             for guid in self.topo
-            if trace_embedding_ids_input(self.graph, guid) is not None
+            if guid not in tied
+            and trace_embedding_ids_input(self.graph, guid) is not None
         ]
 
     def train_step_fn(self):

@@ -86,7 +86,10 @@ def apply_fusion(
     the outputs of fused chains)."""
     protected = protected or set()
     g = graph.copy()
-    claimed: Set[int] = set()
+    # a node that applies another node's weights, or lends its own, keeps
+    # its identity: a FUSED node's weights are its chain's, concatenated
+    tied = g.weight_owners()
+    claimed: Set[int] = set(tied) | set(tied.values())
     ref_map: Dict[TensorRef, TensorRef] = {}
 
     for start in list(g.topo_order()):
@@ -130,9 +133,7 @@ def apply_fusion(
                 inits.extend([None] * len(n.weight_shapes))
         params = {
             "sub_ops": sub_ops,
-            "weight_key": "+".join(
-                n.params.get("weight_key", n.name) for n in nodes
-            ),
+            "weight_key": "+".join(str(n.weight_key) for n in nodes),
         }
         if have_inits:
             params["initializers"] = inits

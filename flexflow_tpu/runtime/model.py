@@ -118,10 +118,30 @@ class FFModel:
         self._name_counts[base] = n + 1
         return f"{base}_{n}" if n else base
 
-    def _add(self, op_type, name_base, inputs, params, name=None) -> List[Tensor]:
+    def _add(
+        self, op_type, name_base, inputs, params, name=None, weights_of=None
+    ) -> List[Tensor]:
+        """weights_of: the output of an earlier node of the same kind
+        whose weights this node applies instead of owning any (a layer
+        run again: one stored array, one gradient that sums over the
+        applications, one optimizer slot). Kept in the node's params under
+        the owner's stable identity, which graph rewrites carry."""
         name = self._unique_name(name_base, name)
         in_shapes = [self.graph.shape_of(t.ref) for t in inputs]
         outs, weights = infer_shapes(op_type, in_shapes, params)
+        if weights_of is not None:
+            owner = self.graph.nodes[weights_of.ref.guid]
+            if owner.op_type != op_type or owner.weight_shapes != tuple(weights):
+                raise ValueError(
+                    f"{name}: weights_of='{owner.name}' has weights "
+                    f"{[str(s) for s in owner.weight_shapes]} of a "
+                    f"{owner.op_type.name}, this {op_type.name} needs "
+                    f"{[str(s) for s in weights]}"
+                )
+            params = dict(
+                params,
+                weights_of=owner.params.get("weights_of") or owner.weight_key,
+            )
         node = self.graph.add_node(
             op_type,
             name,
@@ -162,6 +182,7 @@ class FFModel:
         kernel_initializer=None,
         bias_initializer=None,
         name: Optional[str] = None,
+        weights_of: Optional[Tensor] = None,
     ) -> Tensor:
         params = {
             "out_features": out_dim,
@@ -171,7 +192,9 @@ class FFModel:
             if use_bias
             else [kernel_initializer],
         }
-        return self._add(OperatorType.LINEAR, "dense", [input], params, name)[0]
+        return self._add(
+            OperatorType.LINEAR, "dense", [input], params, name, weights_of
+        )[0]
 
     def conv2d(
         self,
@@ -272,12 +295,15 @@ class FFModel:
         return self._add(OperatorType.LAYERNORM, "layer_norm", [input], params, name)[0]
 
     def rms_norm(
-        self, input: Tensor, eps: float = 1e-5, name: Optional[str] = None
+        self, input: Tensor, eps: float = 1e-5, name: Optional[str] = None,
+        weights_of: Optional[Tensor] = None,
     ) -> Tensor:
         """x * rsqrt(mean(x^2) + eps) * gain over the last dim, float32
         statistics; the gain starts at one."""
         params = {"eps": eps, "initializers": [ConstantInitializer(1.0)]}
-        return self._add(OperatorType.RMSNORM, "rms_norm", [input], params, name)[0]
+        return self._add(
+            OperatorType.RMSNORM, "rms_norm", [input], params, name, weights_of
+        )[0]
 
     def embedding(
         self,
@@ -315,6 +341,7 @@ class FFModel:
         qk_norm: bool = False,
         qk_norm_eps: float = 1e-5,
         name: Optional[str] = None,
+        weights_of: Optional[Tensor] = None,
     ) -> Tensor:
         """rope_theta: rotary positions (rotate-half form over each head)
         on q and k; qk_norm: an RMSNorm with a learned gain over the whole
@@ -348,6 +375,7 @@ class FFModel:
             [query, key, value],
             params,
             name,
+            weights_of,
         )[0]
 
     def latent_attention(
@@ -387,13 +415,15 @@ class FFModel:
         )[0]
 
     def gated_mlp(
-        self, input: Tensor, width: int, name: Optional[str] = None
+        self, input: Tensor, width: int, name: Optional[str] = None,
+        weights_of: Optional[Tensor] = None,
     ) -> Tensor:
         """down(silu(gate x) * (up x)) with gate / up [d, width] and down
         [width, d], no biases, as one operator."""
         params = {"width": width, "initializers": [None] * 3}
         return self._add(
-            OperatorType.GATED_MLP, "gated_mlp", [input], params, name
+            OperatorType.GATED_MLP, "gated_mlp", [input], params, name,
+            weights_of,
         )[0]
 
     def dropout(self, input: Tensor, rate: float = 0.5, seed: int = 0, name=None):
@@ -1754,9 +1784,8 @@ class FFModel:
             else None
         )
         if src is not None:
-            key = src.params.get("weight_key", src.name)
             for g, n in self.graph.nodes.items():
-                if n.params.get("weight_key", n.name) == key:
+                if n.weight_key == src.weight_key:
                     return g
         raise KeyError(
             f"tensor guid {guid} not in the compiled graph (and no rewrite "

@@ -91,6 +91,14 @@ class PipelinedExecutor(Executor):
         super().__init__(*args, **kwargs)
         self.pspec = pipeline_spec
         st = pipeline_spec.structure
+        if self.weight_owner:
+            raise ValueError(
+                "nodes that apply another node's weights (weights_of=) are "
+                "not supported under a pipelined strategy: the trunk's "
+                "weights are kept stacked, one slice a block, and a block "
+                "run again would need the same slice on another stage. Use "
+                "a non-pipeline strategy"
+            )
         for blk in st.blocks:
             for g in blk:
                 node = self.graph.nodes[g]

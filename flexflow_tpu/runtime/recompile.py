@@ -63,7 +63,7 @@ def recompile_on_condition(model, state: RecompileState) -> bool:
     # weight_key a substitution stamped on its replacement node (guids are
     # fresh every compile, so they cannot key weights across recompiles)
     def stable_key(node):
-        return node.params.get("weight_key", node.name)
+        return node.weight_key
 
     host = {}
     ambiguous = set()

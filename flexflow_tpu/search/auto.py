@@ -1759,7 +1759,10 @@ def optimize_serving(
                 continue
             node_tp = best.tp if _DECODE_TP_OPS.get(node.op_type) else 1
             weight_bytes += (
-                sum(s.volume() * cm.elem_bytes(s) for s in node.weight_shapes)
+                sum(
+                    s.volume() * cm.elem_bytes(s)
+                    for s in node.stored_weight_shapes
+                )
                 / node_tp
             )
         budget = max(0, spec.hbm_bytes - int(weight_bytes))

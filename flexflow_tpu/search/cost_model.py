@@ -393,7 +393,8 @@ class CostModel:
         bytes_moved += sum(_pb(s) for s in node.output_shapes)
         bytes_moved += sum(_pb(s) for s in node.weight_shapes)
         mem = sum(_pb(s) for s in node.output_shapes)
-        mem += sum(_pb(s) for s in node.weight_shapes)
+        # read once an application, kept once: by the node that owns it
+        mem += sum(_pb(s) for s in node.stored_weight_shapes)
 
         if self.measure and not skip_measure and node.op_type in _MEASURED_OPS:
             times = self.measured_times_floor_adjusted(
@@ -537,7 +538,7 @@ class CostModel:
         out_elem = elem(out) if out is not None else 4
         act_bytes = float(batch) * feat * out_elem / tp
         flops = 2.0 * batch * sum(s.volume() for s in node.weight_shapes) / tp
-        mem = weight_bytes
+        mem = weight_bytes if node.stored_weight_shapes else 0.0
         bytes_moved = weight_bytes + act_bytes
         if node.op_type == OperatorType.MULTIHEAD_ATTENTION:
             heads = int(node.params["num_heads"]) // tp
@@ -623,7 +624,7 @@ class CostModel:
         flops = (
             2.0 * batch * w * sum(s.volume() for s in node.weight_shapes) / tp
         )
-        mem = weight_bytes
+        mem = weight_bytes if node.stored_weight_shapes else 0.0
         bytes_moved = weight_bytes + act_bytes
         if node.op_type == OperatorType.MULTIHEAD_ATTENTION:
             heads = int(node.params["num_heads"]) // tp

@@ -24,7 +24,7 @@ Sites are the unit the search toggles on/off.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from flexflow_tpu.core.pcg import PCGGraph, TensorRef
 from flexflow_tpu.core.types import OperatorType
@@ -344,4 +344,41 @@ def find_tp_sites(graph: PCGGraph) -> List[Site]:
         if node.op_type == OperatorType.LINEAR and guid not in claimed:
             sites.append(SingleLinearSite("single_linear", (guid,)))
             claimed.add(guid)
-    return sites
+    return _tie_sites(graph, sites)
+
+
+@dataclasses.dataclass(frozen=True)
+class TiedSites(Site):
+    """The sites of nodes that apply one weight (an owner and the nodes
+    that borrow from it, FFModel's `weights_of=`), taken together or not
+    at all: one stored array has one sharding."""
+
+    members: Tuple[Site, ...] = ()
+
+    def divisible_by(self, graph, tp):
+        return all(m.divisible_by(graph, tp) for m in self.members)
+
+    def apply(self, graph, tp, axis):
+        for m in self.members:
+            m.apply(graph, tp, axis)
+
+
+def _tie_sites(graph: PCGGraph, sites: List[Site]) -> List[Site]:
+    """`sites` with those that touch one shared weight merged into one
+    `TiedSites`, at the place of the first; `sites` itself for a graph
+    that shares nothing."""
+    owner = graph.weight_owners()
+    if not owner:
+        return sites
+    groups: Dict[tuple, List[Site]] = {}
+    for s in sites:
+        key = tuple(
+            owner.get(g, g) for g in s.guids if graph.nodes[g].weight_shapes
+        ) or s.guids
+        groups.setdefault(key, []).append(s)
+    return [
+        ms[0] if len(ms) == 1 else TiedSites(
+            "tied", tuple(g for m in ms for g in m.guids), tuple(ms)
+        )
+        for ms in groups.values()
+    ]

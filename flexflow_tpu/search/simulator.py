@@ -46,6 +46,7 @@ class GraphCost:
     sync_time: float = 0.0
     update_time: float = 0.0  # optimizer HBM traffic (CostModel.update_cost)
     memory_per_chip: int = 0
+    weight_bytes: int = 0  # stored weights a chip holds, of memory_per_chip
 
     def feasible(self, spec: MachineSpec) -> bool:
         return self.memory_per_chip <= spec.hbm_bytes
@@ -452,7 +453,9 @@ def estimate_graph_cost(
             if sparse_rows is not None
             else 1
         )
-        for w in node.weight_shapes:
+        # a weight another node owns is stored, reduced and updated once,
+        # with its owner
+        for w in node.stored_weight_shapes:
             weight_bytes += w.piece_bytes()
             if include_backward:
                 if sparse_rows is not None:
@@ -503,6 +506,7 @@ def estimate_graph_cost(
             if t is not None:
                 add_edge(t, tu)
 
+    total.weight_bytes = int(weight_bytes)
     total.memory_per_chip = int(weight_bytes * optimizer_state_factor + act_bytes)
 
     if export is not None:

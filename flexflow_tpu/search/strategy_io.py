@@ -46,8 +46,12 @@ def _register_site_kinds():
 def save_search_result(result, graph: PCGGraph, path: str):
     """Persist a SearchResult (search.auto) for later --import-strategy."""
     sites = []
-    for site, enabled in zip(result.sites, result.on):
-        if enabled:
+    for tied, enabled in zip(result.sites, result.on):
+        if not enabled:
+            continue
+        # sites tied by a shared weight are written one by one: all of
+        # them are in the file, so a load turns all of them on
+        for site in getattr(tied, "members", None) or (tied,):
             sites.append(
                 {
                     "kind": site.kind,

@@ -352,7 +352,7 @@ class UnitySearch:
         # the dp data replicas; all-reduce the shards over the actual device
         # ids of one replica group (ids are laid out (dp, ch) row-major, so
         # a group is every ch-th device — possibly crossing nodes)
-        if self.include_backward and node.weight_shapes:
+        if self.include_backward and node.stored_weight_shapes:
             ub, sparse_rows = self._update_bytes(guid)
             group = opt.view.device_ids()[:: opt.ch]
             if sparse_rows is None:
@@ -631,7 +631,7 @@ class UnitySearch:
                     OperatorType.BATCHMATMUL,
                 )
                 bwd.append(3.0 if mxu else 2.0)
-                if node.weight_shapes:
+                if node.stored_weight_shapes:
                     ub, sparse_rows = self._update_bytes(g)
                     sparse = sparse_rows is not None
                     ubytes.append(ub)

@@ -44,6 +44,17 @@ decode attends absorbed over the pool
 or the dense gather). The other step families below are refused for such
 a model at construction (`require`).
 
+A model whose layers run several times over one set of weights
+(`models/nlp.py:build_ouro`) needs nothing of its own here: every pass's
+attention is a node, so `KVCacheSpec.layer_guids` holds passes x layers
+cache layers behind the one block table, and a node that applies another
+node's weights gets them from `Executor.forward_values`
+(`Executor.weight_owner`), hooks included. What the engine adds is the
+count: `weight_walk` and the `serve_weights_*`, `serve_cache_layers`,
+`serve_weight_layers`, `serve_loop_passes` gauges
+(`_publish_weight_walk`). Such a model keeps every step kind a multi-head
+model has.
+
 A third step family serves speculative decoding (serving/spec.py):
 **verify** scores w = k+1 token positions per slot (the last emitted
 token plus k drafted tokens) through the KV cache in ONE prefill-shaped
@@ -404,6 +415,7 @@ class GenerationEngine:
             else:
                 self._head_shard = shard
         self._publish_kernel_block()
+        self._publish_weight_walk()
         # multi-tenant LoRA (serving.tenancy.adapters.AdapterPool):
         # None keeps every traced step byte-for-byte the base engine —
         # the adapter argument is simply never passed, so no select or
@@ -842,6 +854,40 @@ class GenerationEngine:
                 help=f"{what} the paged decode kernel handles at a time "
                 "(from the cache geometry)",
             ).set(getattr(self.kernel_block, field))
+
+    def _publish_weight_walk(self) -> None:
+        """What a step reads of the weights against what is kept, as
+        `weight_walk` and `serve_*` gauges, once: the bytes of `params`
+        (each array once), the bytes a step applies (every node's
+        weights, its owner's where it applies another node's:
+        `Executor.weight_owner`), the cache's layers and how many of them
+        own their weights, and the most often a weight is applied in one
+        step (1 unless layers run again)."""
+        ex = self.executor
+        nbytes = {
+            g: sum(int(w.nbytes) for w in ws)
+            for g, ws in self.model.params.items()
+        }
+        uses: Dict[int, int] = {}
+        for g in ex.topo:
+            owner = ex.weight_owner.get(g, g)
+            if owner in nbytes:
+                uses[owner] = uses.get(owner, 0) + 1
+        layers = self.cache.spec.layer_guids
+        self.weight_walk = {
+            "weights_stored_bytes": sum(nbytes.values()),
+            "weights_applied_bytes": sum(nbytes[g] * n for g, n in uses.items()),
+            "cache_layers": len(layers),
+            "weight_layers": len({ex.weight_owner.get(g, g) for g in layers}),
+            "loop_passes": max(uses.values(), default=1),
+        }
+        if self.telemetry is None:
+            return
+        for name, value in self.weight_walk.items():
+            self.telemetry.registry.gauge(
+                f"serve_{name}",
+                help="of the model as served (engine._publish_weight_walk)",
+            ).set(value)
 
     def _fall_back_to_dense(self, error) -> None:
         self.kernel_fallbacks += 1
