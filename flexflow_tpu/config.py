@@ -157,9 +157,13 @@ class FFConfig:
     # --max-preemptions per request
     serve_admission: str = "reserve"
     serve_max_preemptions: int = 3
-    # --serve-async: the double-buffered engine loop — dispatch step
-    # N+1 while N is in flight, reconcile terminal events one step late
-    serve_async: bool = False
+    # the serving loop keeps one decode step in flight (the default):
+    # step N+1 is dispatched, its tokens chained on the device, before
+    # step N is read back, so terminal events (EOS, cancel() of a running
+    # request, a running deadline) land one step late. --serve-async=0
+    # (ServeConfig.serve_async=False) asks for the synchronous loop, the
+    # token-identical reference; --serve-async alone names the default
+    serve_async: bool = True
     # --check-invariants: run cache.check_invariants() every scheduler
     # iteration (the chaos harness's probe) — debugging/CI posture
     serve_check_invariants: bool = False
@@ -376,8 +380,10 @@ class FFConfig:
                 cfg.serve_admission = take()
             elif a == "--max-preemptions":
                 cfg.serve_max_preemptions = int(take())
-            elif a == "--serve-async":
-                cfg.serve_async = True
+            elif a == "--serve-async" or a.startswith("--serve-async="):
+                cfg.serve_async = a.partition("=")[2].lower() not in (
+                    "0", "false", "off", "no",
+                )
             elif a == "--check-invariants":
                 cfg.serve_check_invariants = True
             elif a == "--metrics-out":

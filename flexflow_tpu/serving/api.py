@@ -86,12 +86,16 @@ class ServeConfig:
     # request before hard FAILED.
     admission: str = "reserve"
     max_preemptions: int = 3
-    # async double-buffered engine (--serve-async): overlap host
-    # scheduling with device steps — dispatch step N+1 while N is in
-    # flight, reconcile terminal events one step late
-    # (AsyncContinuousBatchingScheduler). The sync loop stays the
-    # token-identical reference.
-    serve_async: bool = False
+    # the loop `build_scheduler` gives. True (the default): one decode
+    # step stays in flight, step N+1 dispatched with its tokens chained
+    # on the device before step N is read back
+    # (AsyncContinuousBatchingScheduler), so the device does not wait
+    # for the host between steps. Terminal events land at the read-back:
+    # cancel() of a running request and a running deadline take effect
+    # one step later, and a request that ends on EOS costs one discarded
+    # slot-step. False (--serve-async=0): the synchronous loop, the
+    # reference the overlapped one is held token-identical to.
+    serve_async: bool = True
     # debug: re-run cache.check_invariants() after every scheduler
     # iteration (--check-invariants). Off by default — the full
     # allocator re-derivation is O(slots × pages) per iteration, a

@@ -570,6 +570,9 @@ class GenerationEngine:
         self.pool_steps_donated = 0
         self.pool_steps_copied = 0
         self._decode_jit = self._step_jit(self._decode_impl_paged)
+        # the newest decode step's sampled tokens, on the device: what a
+        # step without a chain hands the program in their place
+        self._last_next = None
         # one jitted prefill per length bucket / one jitted verify per
         # draft width (jit caches by shape anyway; the explicit caches
         # make the compile-count contract inspectable). The verify,
@@ -1271,7 +1274,23 @@ class GenerationEngine:
         an admission whose total exceeds the largest bucket is split, in
         the order given, into consecutive groups that fit, one program a
         group, all dispatched before anything is read back. Returns
-        (next_tokens [n], last_logits [n, V]) in request order."""
+        (next_tokens [n], last_logits [n, V]) in request order. The
+        synchronous wrapper of `prefill_dispatch` + `prefill_reconcile`,
+        between which the overlapped loop dispatches a decode step."""
+        return self.prefill_reconcile(
+            self.prefill_dispatch(params, prompts, slots)
+        )
+
+    def prefill_dispatch(
+        self,
+        params,
+        prompts: Sequence[Sequence[int]],
+        slots: Sequence[int],
+    ) -> list:
+        """Enqueue one admission's programs WITHOUT reading anything
+        back: the cache arrays commit and the slots' lengths are set
+        here. Returns what `prefill_reconcile` reads, a program's outputs
+        a group, still on the device, their copies to the host started."""
         spec = self.cache.spec
         n = len(prompts)
         if n == 0:
@@ -1299,8 +1318,15 @@ class GenerationEngine:
                 *moe, choice = moe
                 choices.append((choice, [len(p) for p in prompts[lo:hi]]))
             outs.append((nxt[: hi - lo], last[: hi - lo], *moe))
+            for a in outs[-1]:
+                a.copy_to_host_async()
         if self._moe_share:
             self.moe_choice["prefill"] = _PackedChoice(choices)
+        return outs
+
+    def prefill_reconcile(self, outs: list) -> Tuple[np.ndarray, np.ndarray]:
+        """Wait for a dispatched admission's tokens, logits and counts:
+        (next_tokens [n], last_logits [n, V]) in request order."""
         host = self._readback("prefill", *(a for out in outs for a in out))
         width = len(outs[0])  # tokens, logits, and the counts if any
         host = [host[i : i + width] for i in range(0, len(host), width)]
@@ -1542,9 +1568,12 @@ class GenerationEngine:
         sampled tokens [max_seqs], the logits [max_seqs, V] (read by who
         asks), and `readback`, int32 [2 * max_seqs + counts]: the tokens
         again, whether each slot's logits row is finite, and the expert
-        layers' counts. `chained`: None, or the in-flight previous step's
-        sampled tokens, taken where the chain flag is set, so that
-        consecutive steps' data dependency stays on the device."""
+        layers' counts. `chained`: int32 [max_seqs] on the device, an
+        earlier step's sampled tokens, taken where the chain flag is set
+        so that consecutive steps' data dependency stays on the device;
+        always there (`decode_dispatch` hands a step without a chain the
+        newest tokens it has, and the flags are all 0), so a model has
+        ONE decode program whoever feeds a slot."""
         import jax
         import jax.numpy as jnp
 
@@ -1552,9 +1581,7 @@ class GenerationEngine:
             tokens, from_chain, lengths = state[:, 0], state[:, 1], state[:, 2]
             active = state[:, 3] != 0
             tables = state[:, self._STATE_COLUMNS:]
-            if chained is not None:
-                tokens = jnp.where(from_chain != 0, chained, tokens)
-            tokens = tokens[:, None]
+            tokens = jnp.where(from_chain != 0, chained, tokens)[:, None]
             share = self._share(active[:, None])
         moe = []
         new_k, new_v, new_ks, new_vs, logits = self._decode_core_paged(
@@ -1661,6 +1688,28 @@ class GenerationEngine:
             ) = jax.lax.scan(body, carry0, steps)
         return nk, nv, nks, nvs, lens, toks, toks_ks, logits_ks, mask_ks
 
+    def _chain_feed(self, params):
+        """The `chained` argument of a decode step that chains on nothing:
+        the newest decode step's tokens, so that the argument is placed as
+        a chain's is and jit keeps one executable for the one program.
+        Before any step has run, zeros placed where the program's outputs
+        will be: with the weights (committed to their device, or
+        replicated over their mesh)."""
+        if self._last_next is None:
+            import jax
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            zeros = np.zeros(self.cache.spec.max_seqs, dtype=np.int32)
+            leaf = jax.tree_util.tree_leaves(params)[0]
+            if getattr(leaf, "committed", False):
+                where = leaf.sharding
+                if isinstance(where, NamedSharding):
+                    where = NamedSharding(where.mesh, PartitionSpec())
+                self._last_next = jax.device_put(zeros, where)
+            else:
+                self._last_next = jax.numpy.asarray(zeros)
+        return self._last_next
+
     def _pack_state(self, tokens, from_chain, active) -> np.ndarray:
         """A decode step's host state as the ONE int32 array
         `_decode_impl_paged` takes apart: per slot the last token, the
@@ -1708,8 +1757,10 @@ class GenerationEngine:
         round-trip: where chain_mask is set, the input token comes from
         the in-flight `chain` step's device_next instead of the host
         `tokens` row — the data dependency between step N and N+1
-        resolves entirely on device (the program's second argument; None
-        without a chain, which is a program of its own)."""
+        resolves entirely on device (the program's second argument).
+        A step without a chain runs the same program: it is handed the
+        newest decode step's tokens (`_chain_feed`), which its all-zero
+        chain flags leave unread."""
         active = np.asarray(active_mask, dtype=bool)
         # the next page, for any sequence about to cross a page boundary
         self._claim_rows(active.astype(np.int32))
@@ -1719,7 +1770,10 @@ class GenerationEngine:
             if chain is not None and chain_mask is not None
             else np.zeros_like(active)
         )
-        chained = chain.device_next if mask.any() else None
+        chained = (
+            chain.device_next if chain is not None
+            else self._chain_feed(params)
+        )
         lengths_snap = np.array(self.cache.lengths)
         # snapshot(): lengths += 1 below, and allocator table edits
         # between iterations, mutate behind the async dispatch queue
@@ -1730,9 +1784,9 @@ class GenerationEngine:
                 params,
                 (chained, snapshot(self._pack_state(host_tokens, mask, active))),
                 self._adapter_slot_args(),
-                program=("decode", "host" if chained is None else "chained"),
             ),
         )
+        self._last_next = nxt
         readback.copy_to_host_async()
         self.cache.lengths[active] += 1
         if self._latent:

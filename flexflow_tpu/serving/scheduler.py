@@ -76,7 +76,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -290,6 +290,14 @@ _STAT_FIELDS: Dict[str, object] = dict(
     dispatch_gap_sum_s=0.0,  # Σ wall time between consecutive dispatches
     commit_wait_s=0.0,  # Σ time blocked on device outputs at reconcile
     overlapped_host_s=0.0,  # Σ host work done while a step was in flight
+    # how far the overlapped loop engaged: decode steps dispatched while
+    # another was still in flight (over decode_steps: the share of steps
+    # the device did not wait for the host; 0 under the synchronous loop),
+    # and the slot-steps whose token the commit's identity check threw
+    # away (a request that ends on EOS costs one; a budgeted end none:
+    # the dispatch's budget gate sees it coming)
+    decode_steps_chained=0,
+    decode_slot_steps_discarded=0,
     # speculative pre-proposals drafted during the in-flight window
     # (async spec mode): used as-is vs rolled back on reconcile mismatch
     pre_proposal_hits=0,
@@ -520,6 +528,17 @@ for _name in _STAT_FIELDS:
 del _name
 
 
+class _Admission(NamedTuple):
+    """An admission between its prefill's dispatch and its read-back."""
+
+    admitted: List[Request]
+    seqs: List[List[int]]  # what each prefill recomputes: prompt + generated
+    cursors: List[int]  # prefix-shared extent of each (0: a plain prefill)
+    pending: Optional[list]  # `engine.prefill_dispatch`'s, of the plain ones
+    programs: int  # engine.prefill_programs before the dispatch
+    slots: frozenset  # no decode step until the first token is the host's
+
+
 class _SchedulerBase:
     """Shared admission/decode/verify machinery. `proposer` switches the
     per-iteration generation step from plain decode to speculative
@@ -691,6 +710,9 @@ class _SchedulerBase:
         # decode/verify waits for the next one, so the chunk planner's
         # grants alone bound the iteration's token work
         self._chunk_unlocked: set = set()
+        # an admission whose prefill is dispatched and not read back yet
+        # (`_admit_batch` -> `_commit_admission`)
+        self._admission: Optional[_Admission] = None
         # -- multi-tenancy ---------------------------------------------------
         # `classes` ({name: PriorityClass}, config order = scheduling
         # order) switches admission and token grants to weighted-fair
@@ -1341,7 +1363,9 @@ class _SchedulerBase:
 
         return heads[name], commit
 
-    def _admit(self, limit: Optional[int] = None) -> List[Request]:
+    def _admit(
+        self, limit: Optional[int] = None, defer: bool = False
+    ) -> List[Request]:
         """FIFO admission into free slots (never reorders the queue —
         starvation-free: the head either admits or blocks everyone
         behind it) + ONE prefill batch for the admitted set. Admission
@@ -1358,11 +1382,19 @@ class _SchedulerBase:
         backlogged class still serves within bounded rounds — but not
         the blocking rule: a selected head that cannot take a slot NOW
         stops admission for everyone (no bypass), exactly the single-
-        class no-reorder guarantee, just applied to the DRR order."""
-        with span("scheduler.step.admit", self._tracer):
-            return self._admit_batch(limit)
+        class no-reorder guarantee, just applied to the DRR order.
 
-    def _admit_batch(self, limit: Optional[int]) -> List[Request]:
+        `defer`: dispatch the prefill and leave its read-back and the
+        first tokens to `_commit_admission`, which the overlapped loop
+        calls after it has dispatched the running slots' decode step
+        behind the prefill. An admission with prefix-shared prompts is
+        committed here all the same."""
+        with span("scheduler.step.admit", self._tracer):
+            return self._admit_batch(limit, defer)
+
+    def _admit_batch(
+        self, limit: Optional[int], defer: bool = False
+    ) -> List[Request]:
         optimistic = self.admission == "optimistic"
         prefix = bool(self.cache.prefix_cache)
         admitted: List[Request] = []
@@ -1448,66 +1480,95 @@ class _SchedulerBase:
                     req.prefill_dispatched = cur
                 return admitted
             programs = self.engine.prefill_programs
+            plain = [i for i, c in enumerate(cursors) if c == 0]
+            pending = None
             try:
-                plain = [i for i, c in enumerate(cursors) if c == 0]
-                shared = [i for i, c in enumerate(cursors) if c > 0]
-                rows: Dict[int, Tuple[int, np.ndarray]] = {}
                 if plain:
-                    nxt_p, last_p = self.engine.prefill(
+                    pending = self.engine.prefill_dispatch(
                         self.params,
                         [seqs[i] for i in plain],
                         [admitted[i].slot for i in plain],
                     )
-                    for j, i in enumerate(plain):
-                        rows[i] = (int(nxt_p[j]), np.asarray(last_p[j]))
-                if shared:
-                    # shared slots recompute only tokens[cursor:] — the
-                    # mapped pages already hold the prefix KV rows
-                    nxt_s, last_s = self.engine.prefill_suffix(
-                        self.params,
-                        [seqs[i] for i in shared],
-                        [admitted[i].slot for i in shared],
-                        [cursors[i] for i in shared],
-                    )
-                    for j, i in enumerate(shared):
-                        rows[i] = (int(nxt_s[j]), np.asarray(last_s[j]))
-                nxt = np.array([rows[i][0] for i in range(len(admitted))])
-                last = np.stack(
-                    [rows[i][1] for i in range(len(admitted))]
-                )
-            except Exception as e:  # fault isolation: the batch fails,
-                # in-flight slots are untouched and keep decoding
-                self.stats.step_faults += 1
-                for req in admitted:
-                    self._fail(req, f"prefill failed: {e!r}")
+            except Exception as e:
+                self._fail_admission(admitted, e)
                 return admitted
-            self.stats.prefill_batches += (
-                self.engine.prefill_programs - programs + bool(shared)
+            self._admission = _Admission(
+                admitted, seqs, cursors, pending, programs,
+                frozenset(r.slot for r in admitted),
             )
-            if prefix:
-                # publish AFTER the prefill returned: a failed dispatch
-                # must never leave hash keys pointing at pages whose
-                # writes never executed
-                for req, seq in zip(admitted, seqs):
-                    self.cache.register_prefix(req.slot, seq, len(seq))
-            if self.injector is not None:
-                # np.array (copy): the step's output buffer is read-only
-                last = np.array(last)
-                self.injector.corrupt_logits(
-                    last,
-                    [r.slot for r in admitted],
-                    rows=range(len(admitted)),
-                )
-            for i, (tok, req) in enumerate(zip(nxt, admitted)):
-                if not np.isfinite(last[i]).all():
-                    self._fail(
-                        req,
-                        f"non-finite prefill logits at iteration "
-                        f"{self._iter}",
-                    )
-                    continue
-                self._emit(req, int(tok))
+            if not defer or len(plain) < len(admitted):
+                self._commit_admission()
         return admitted
+
+    def _fail_admission(self, admitted: List[Request], e: Exception) -> None:
+        # fault isolation: the batch fails, in-flight slots are untouched
+        # and keep decoding
+        self.stats.step_faults += 1
+        for req in admitted:
+            if self.running.get(req.slot) is req:
+                self._fail(req, f"prefill failed: {e!r}")
+
+    def _commit_admission(self) -> None:
+        """The other half of an admission's prefill: read its tokens and
+        logits back and emit the first tokens. A request that left its
+        slot since the dispatch (preempted or failed by the decode step
+        dispatched in between) is passed over."""
+        admitted, seqs, cursors, pending, programs, _ = self._admission
+        self._admission = None  # taken: a failure below must not replay it
+        plain = [i for i, c in enumerate(cursors) if c == 0]
+        shared = [i for i, c in enumerate(cursors) if c > 0]
+        try:
+            rows: Dict[int, Tuple[int, np.ndarray]] = {}
+            if plain:
+                nxt_p, last_p = self.engine.prefill_reconcile(pending)
+                for j, i in enumerate(plain):
+                    rows[i] = (int(nxt_p[j]), np.asarray(last_p[j]))
+            if shared:
+                # shared slots recompute only tokens[cursor:] — the
+                # mapped pages already hold the prefix KV rows
+                nxt_s, last_s = self.engine.prefill_suffix(
+                    self.params,
+                    [seqs[i] for i in shared],
+                    [admitted[i].slot for i in shared],
+                    [cursors[i] for i in shared],
+                )
+                for j, i in enumerate(shared):
+                    rows[i] = (int(nxt_s[j]), np.asarray(last_s[j]))
+            nxt = np.array([rows[i][0] for i in range(len(admitted))])
+            last = np.stack([rows[i][1] for i in range(len(admitted))])
+        except Exception as e:
+            self._fail_admission(admitted, e)
+            return
+        self.stats.prefill_batches += (
+            self.engine.prefill_programs - programs + bool(shared)
+        )
+        live = [self.running.get(r.slot) is r for r in admitted]
+        if self.cache.prefix_cache:
+            # publish AFTER the prefill returned: a failed dispatch
+            # must never leave hash keys pointing at pages whose
+            # writes never executed
+            for req, seq, ok in zip(admitted, seqs, live):
+                if ok:
+                    self.cache.register_prefix(req.slot, seq, len(seq))
+        if self.injector is not None:
+            # np.array (copy): the step's output buffer is read-only
+            last = np.array(last)
+            self.injector.corrupt_logits(
+                last,
+                [r.slot for r in admitted],
+                rows=range(len(admitted)),
+            )
+        for i, (tok, req) in enumerate(zip(nxt, admitted)):
+            if not live[i]:
+                continue
+            if not np.isfinite(last[i]).all():
+                self._fail(
+                    req,
+                    f"non-finite prefill logits at iteration "
+                    f"{self._iter}",
+                )
+                continue
+            self._emit(req, int(tok))
 
     def _emit(self, req: Request, token: int) -> None:
         req.generated.append(token)
@@ -1577,12 +1638,16 @@ class _SchedulerBase:
             # the result anyway. EOS is not predictable at dispatch time, so
             # an EOS retire still costs one wasted (discarded) slot-step.
             stepped: Dict[int, Request] = {}
+            admitting = self._admission.slots if self._admission else ()
             for slot, req in self.running.items():
                 if self._prefill_pending(req) or slot in self._chunk_unlocked:
                     continue  # chunked prefill: no decode until the last
                     #            chunk's token has committed, and none in
                     #            the commit's own iteration (its tokens
                     #            were never in this budget's plan)
+                if slot in admitting:
+                    continue  # its prefill is dispatched, not read back:
+                    #            the first token is not the host's yet
                 chained = (
                     chain is not None
                     and chain.kind == "decode"
@@ -1633,6 +1698,7 @@ class _SchedulerBase:
         step.participants = stepped
         self._note_dispatch(step)
         self.stats.decode_steps += 1
+        self.stats.decode_steps_chained += chain is not None
         self.stats.slot_steps += spec.max_seqs
         self.stats.busy_slot_steps += int(active.sum())
         self._budget_used_iter += int(active.sum())
@@ -1714,6 +1780,7 @@ class _SchedulerBase:
         for slot in active_slots:
             req = step.participants.get(slot)
             if req is None or self.running.get(slot) is not req:
+                self.stats.decode_slot_steps_discarded += 1
                 continue
             if not finite[slot]:
                 self._fail(
@@ -2881,8 +2948,10 @@ class ContinuousBatchingScheduler(_SchedulerBase):
 
 class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
     """Double-buffered Orca loop: overlap host scheduling with device
-    steps (`--serve-async`; the synchronous ContinuousBatchingScheduler
-    stays the reference it is proved token-identical against).
+    steps. The loop `build_scheduler` gives by default
+    (`ServeConfig.serve_async`); the synchronous
+    ContinuousBatchingScheduler (`serve_async=False`) stays the
+    reference it is proved token-identical against.
 
     The sync loop round-trips every iteration — host admission/paging/
     bookkeeping while the device idles, then the jitted step while the
@@ -2902,7 +2971,16 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
     in a page a new sequence owns.
     `cancel()` of a RUNNING request and running-deadline reaping defer
     to the next reconcile for the same reason; queued requests cancel/
-    reap immediately. When a page claim finds the pool dry because of
+    reap immediately. `stats.decode_steps_chained` counts the decode
+    steps dispatched with another in flight,
+    `stats.decode_slot_steps_discarded` the slot-steps thrown away.
+
+    An admitting iteration dispatches the prefill, then the chained
+    decode step of the slots already running, and only then reads the
+    prefill back (`_admit(defer=True)` ... `_commit_admission`): the
+    admitted slots join the step after.
+
+    When a page claim finds the pool dry because of
     pinned pages, `_reclaim_inflight_pages` drains the pipeline (a
     stall, traded for allocator soundness) before any preemption.
 
@@ -2978,16 +3056,39 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
         # the drain stalls the pipeline but keeps the allocator sound
         return self._drain_inflight()
 
+    def _journal_snapshots(self) -> None:
+        # a snapshot is a slot's COMMITTED rows beside its committed
+        # cursor: the step in flight has written one row more, so it
+        # lands first, and what it emitted is journaled before the
+        # iteration returns (FX111)
+        if self._drain_inflight():
+            self.journal.commit_pending(self._iter)
+        super()._journal_snapshots()
+
     def _work_pending(self) -> bool:
         return bool(self.queue or self.running or self._inflight)
 
     def step(self) -> None:
+        if self.proposer is not None and self.decode_multistep:
+            # a verify's and a fused window's inputs are both the host's
+            # decisions, and which of the two an iteration runs is one
+            # more: nothing is left to keep in flight
+            return super().step()
         self._begin_iteration()
-        self._admit()
         if self.proposer is not None:
+            self._admit()
             self._verify_iteration_async()
         else:
+            # an admitting iteration: the prefill is dispatched, then the
+            # chained decode step of the slots already running, and only
+            # then is the prefill read back, so the device goes from the
+            # prefill into a decode step and not into the host's wake-up.
+            # (A fused window reads committed tokens: it admits whole.)
+            self._admit(defer=not self.decode_multistep)
             self._decode_iteration_async()
+            if self._admission is not None:
+                with span("scheduler.step.admit", self._tracer):
+                    self._commit_admission()
         self._end_iteration()
 
     def _decode_iteration_async(self) -> None:

@@ -24,7 +24,12 @@ from flexflow_tpu import (
     LossType,
     SGDOptimizer,
 )
-from flexflow_tpu.models import build_decoder_lm
+from flexflow_tpu.models import (
+    build_decoder_lm,
+    build_deepseek_v3,
+    build_olmoe,
+    build_ouro,
+)
 from flexflow_tpu.serving import (
     AsyncContinuousBatchingScheduler,
     ContinuousBatchingScheduler,
@@ -453,17 +458,146 @@ def test_verify_cache_entries_stat_flows_to_scheduler(lm):
 
 
 def test_serve_async_flag_and_builder_wiring(lm):
-    cfg = FFConfig.parse_args(["--serve-async"])
-    assert cfg.serve_async is True
-    serve = ServeConfig.from_config(cfg)
-    assert serve.serve_async is True
-    sched, _, _ = build_scheduler(lm, ServeConfig(
-        max_seqs=4, max_seq_len=32, serve_async=True))
-    assert isinstance(sched, AsyncContinuousBatchingScheduler)
+    # the overlapped loop is the default; the flag names it, and
+    # `--serve-async=0` / `serve_async=False` ask for the reference
+    assert FFConfig().serve_async is True and ServeConfig().serve_async is True
+    for argv, want in (
+        ([], True), (["--serve-async"], True), (["--serve-async=1"], True),
+        (["--serve-async=0"], False), (["--serve-async=false"], False),
+    ):
+        cfg = FFConfig.parse_args(argv)
+        assert cfg.serve_async is want, argv
+        assert ServeConfig.from_config(cfg).serve_async is want, argv
     sched, _, _ = build_scheduler(lm, ServeConfig(
         max_seqs=4, max_seq_len=32))
+    assert isinstance(sched, AsyncContinuousBatchingScheduler)
+    sched, _, _ = build_scheduler(lm, ServeConfig(
+        max_seqs=4, max_seq_len=32, serve_async=False))
     assert not isinstance(sched, AsyncContinuousBatchingScheduler)
     assert isinstance(sched, ContinuousBatchingScheduler)
+
+
+# -- the default loop, on every served model kind (ISSUE 38) ------------------
+
+_TOY_VOCAB = 97
+_TOYS = {
+    "decoder_lm": lambda m, tok: build_decoder_lm(
+        m, tok, vocab_size=_TOY_VOCAB, hidden=32, num_heads=4, num_layers=2,
+        ff_dim=64,
+    ),
+    "olmoe": lambda m, tok: build_olmoe(
+        m, tok, vocab_size=_TOY_VOCAB, hidden=32, num_heads=4, num_layers=2,
+        expert_hidden=16, num_experts=4, experts_per_token=2,
+    ),
+    "deepseek_v3": lambda m, tok: build_deepseek_v3(
+        m, tok, experts_held=(0, 2), vocab_size=_TOY_VOCAB, hidden=32,
+        num_heads=4, num_layers=2, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, dense_hidden=48, dense_layers=1,
+        expert_hidden=16, num_experts=4, experts_per_token=2,
+        shared_experts=1, routed_scale=2.0, rope_theta=1e4, eps=1e-6,
+    ),
+    "ouro": lambda m, tok: build_ouro(
+        m, tok, vocab_size=_TOY_VOCAB, hidden=32, num_heads=4, num_layers=2,
+        ff_dim=48, loops=3, rope_theta=1e4, eps=1e-6,
+    ),
+}
+
+
+@pytest.fixture(scope="module", params=list(_TOYS))
+def toy(request):
+    model = FFModel(FFConfig(batch_size=4, seed=5))
+    tok = model.create_tensor([4, 32], dtype=DataType.INT32, name="tokens")
+    head = _TOYS[request.param](model, tok)
+    model.compile(
+        optimizer=SGDOptimizer(lr=0.01),
+        loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+        metrics=[], devices=jax.devices()[:1],
+        **({"logits": head} if request.param == "ouro" else {}),
+    )
+    return model
+
+
+#: seven requests over four slots, prompts of 2 to 9 tokens and budgets of
+#: 1 to 9: slots turn over mid-run, so host-fed and chained steps mix
+_MIXED = [
+    ([1, 2, 3], 6), ([4, 5, 6, 7, 8, 9, 10, 11, 12], 9), ([9, 8], 1),
+    ([11, 12, 13, 14], 7), ([21, 22], 3), ([5, 4, 3, 2, 1, 6], 8),
+    ([31, 32, 33], 2),
+]
+
+
+def _mixed_run(model, **kw):
+    serve = ServeConfig(max_seqs=4, max_seq_len=32, debug_invariants=True, **kw)
+    sched, engine, _ = build_scheduler(model, serve)
+    done = sched.run([
+        Request(rid=i, prompt=list(p), max_new_tokens=n)
+        for i, (p, n) in enumerate(_MIXED)
+    ])
+    assert all(r.ok for r in done)
+    return sched, engine, {r.rid: list(r.generated) for r in done}
+
+
+def test_default_loop_is_overlapped_and_token_identical(toy):
+    sched, _, got = _mixed_run(toy)
+    assert isinstance(sched, AsyncContinuousBatchingScheduler)
+    ref, _, want = _mixed_run(toy, serve_async=False)
+    assert not isinstance(ref, AsyncContinuousBatchingScheduler)
+    assert got == want
+    assert [len(got[i]) for i in range(len(_MIXED))] == [n for _, n in _MIXED]
+    # budgets end every request: the dispatch's gate saw each end coming
+    assert sched.stats.busy_slot_steps == ref.stats.busy_slot_steps
+    assert sched.stats.decode_slot_steps_discarded == 0
+
+
+def test_one_decode_program_whoever_feeds_a_slot(toy):
+    sched, engine, _ = _mixed_run(toy)
+    st = sched.stats
+    # both kinds of step ran: a slot's first step after its prefill is fed
+    # by the host, the rest from the step in flight
+    assert 0 < st.decode_steps_chained < st.decode_steps
+    assert engine._decode_jit._cache_size() == 1
+    # and its first dispatch alone was forced (`_dispatch`)
+    counts = bool(engine._count_fields)
+    assert st.device_syncs == (
+        (2 + counts) * st.prefill_batches + st.decode_steps + 1
+    )
+
+
+@pytest.mark.parametrize(
+    "ending", ["budget", "eos", "sync"],
+)
+def test_engagement_counters(lm, ending):
+    """`decode_steps_chained / decode_steps`: above 0.9 in a steady run,
+    0 under the synchronous loop; `decode_slot_steps_discarded`: one for
+    a request that ends on EOS, none for one that ends on its budget."""
+    _, _, _, plain = _run(lm, False, n=1, max_new=24)
+    stream = plain[0].generated
+    assert len(stream) == 24
+    kw = {}
+    if ending == "eos":
+        # a token whose first occurrence is late in the greedy stream
+        at = max(stream.index(t) for t in set(stream))
+        assert at >= 2
+        kw["eos_token"] = stream[at]
+    sched, _, _, done = _run(
+        lm, ending != "sync", reqs=_requests(1, 24, **kw)
+    )
+    st = sched.stats
+    assert done[0].ok
+    if ending == "eos":
+        assert done[0].generated == stream[: at + 1]
+        assert st.decode_slot_steps_discarded == 1
+        assert st.busy_slot_steps == at + 1  # one step past the last token
+    else:
+        assert done[0].generated == stream
+        assert st.decode_slot_steps_discarded == 0
+        assert st.busy_slot_steps == st.decode_steps == 23
+    if ending == "sync":
+        assert st.decode_steps_chained == 0
+    elif ending == "budget":
+        # every step but the first chained on the one in flight
+        assert st.decode_steps_chained == st.decode_steps - 1
+        assert st.decode_steps_chained / st.decode_steps > 0.9
 
 
 def test_inflight_step_snapshot_is_immutable_view(lm):

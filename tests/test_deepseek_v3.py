@@ -184,7 +184,7 @@ def case_scopes(model):
 
     n = spec.max_seqs
     text = engine._decode_jit.trace(
-        model.params, None,
+        model.params, s((n,)),
         s((n, engine._STATE_COLUMNS + spec.max_pages_per_seq)),
         cache.k, cache.v, cache.k_scale, cache.v_scale,
     ).lower().as_text(debug_info=True)

@@ -187,14 +187,22 @@ def _check_allocator_invariants(cache, injector=None):
     for p in set(live):
         expect = int(refs[p]) if refs is not None else 1
         assert live.count(p) == expect, (p, live.count(p), expect)
-    # free-list conservation over UNIQUE pages: free + held
-    # (+ injector-stolen) = pool
+    # free-list conservation over UNIQUE pages: free + held + pinned
+    # for a step still in flight (released by a retire, back on the free
+    # list at that step's reconcile) (+ injector-stolen) = pool
     uniq = set(live)
+    pinned = {p for p, _ in cache._limbo}
+    assert len(pinned) == cache.pinned_pages
     assert uniq.isdisjoint(cache._free_pages)
-    assert len(uniq) + cache.num_free_pages + extra == spec.num_pages
-    assert cache.pages_in_use == len(uniq) + extra
-    # the reserve never promises pages the pool doesn't have
-    assert 0 <= cache._reserved <= cache.num_free_pages + extra
+    assert pinned.isdisjoint(cache._free_pages) and pinned.isdisjoint(uniq)
+    assert (
+        len(uniq) + cache.num_free_pages + len(pinned) + extra
+        == spec.num_pages
+    )
+    assert cache.pages_in_use == len(uniq) + len(pinned) + extra
+    # the reserve never promises pages the pool doesn't have (a pinned
+    # page returns before any claim that needs it: the drain-first rule)
+    assert 0 <= cache._reserved <= cache.num_free_pages + len(pinned) + extra
 
 
 def test_allocator_invariants_through_schedule(lm):

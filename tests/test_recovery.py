@@ -237,18 +237,28 @@ def test_crash_restart_token_identical(lm, tmp_path, layout, dtype, prefix):
     assert _streams(state, resub, comp) == base
 
 
-def test_crash_at_iteration_begin(lm, tmp_path):
+@pytest.mark.parametrize(
+    "serve_async,durable",
+    [
+        # the synchronous loop: iteration 1 committed two tokens per
+        # request (admission prefill + same-iteration decode)
+        pytest.param(False, 8, id="sync"),
+        # the default loop: the prefill's token; the first decode step
+        # was dispatched in iteration 2 at the earliest and read back later
+        pytest.param(True, 4, id="overlapped"),
+    ],
+)
+def test_crash_at_iteration_begin(lm, tmp_path, serve_async, durable):
     """The benign phase: death at the step boundary, before any new
     work — everything journaled survives, nothing was at risk."""
-    over = dict(kv_page_size=8)
+    over = dict(kv_page_size=8, serve_async=serve_async)
     base = _baseline(lm, max_new=8, kv_page_size=8)
     path = tmp_path / "begin.wal"
     _crash_run(lm, path, FaultPlan(crash_iters={2: "begin"}),
                max_new=8, **over)
     state = recover_journal(str(path))
-    # iteration 1 committed two tokens per request (admission prefill +
-    # same-iteration decode), all durable at the begin-phase crash
-    assert state.replayed_tokens == 8
+    # all durable at the begin-phase crash
+    assert state.replayed_tokens == durable
     _, _, resub, comp = _resume(lm, path, state, **over)
     assert _streams(state, resub, comp) == base
 
@@ -343,7 +353,10 @@ def test_journal_write_failure_degrades_not_kills(lm, tmp_path):
     (availability over durability) while serving continues untouched —
     every stream still finishes token-identical to the baseline."""
     path = tmp_path / "fail.wal"
-    inj = FaultInjector(FaultPlan(journal_fail_iters=(2,)))
+    # the synchronous loop commits tokens in iteration 2; the default
+    # loop dispatches its first decode step there and commits it in 3.
+    # The first failed write degrades the journal: one injection each
+    inj = FaultInjector(FaultPlan(journal_fail_iters=(2, 3)))
     sched, _, _ = build_scheduler(
         lm, _cfg(path, kv_page_size=8), injector=inj)
     for r in _requests(max_new=6):

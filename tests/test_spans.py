@@ -223,26 +223,31 @@ def test_fit_under_a_profiler_session_yields_exactly_the_named_spans(tmp_path):
     assert firsts == sorted(firsts)
 
 
-@pytest.mark.parametrize("serve_async", [False, True], ids=["sync", "async"])
+@pytest.mark.parametrize(
+    "serve_async", [False, True, None], ids=["sync", "async", "default"]
+)
 def test_scheduler_under_a_profiler_session_yields_exactly_the_named_spans(
     lm, tmp_path, serve_async
 ):
-    (sched, _, _), events = _profiled(
-        tmp_path, lambda: _serve(lm, serve_async=serve_async)
-    )
+    # the default loop is the overlapped one, under the same names
+    kw = {} if serve_async is None else {"serve_async": serve_async}
+    serve_async = serve_async is not False
+    (sched, _, _), events = _profiled(tmp_path, lambda: _serve(lm, **kw))
+    assert (sched.stats.decode_steps_chained > 0) == serve_async
     ours = _ours(events, ("scheduler.", "door."))
     assert {e[0] for e in ours} == SERVE_SPANS
     _assert_nested(ours)
     count = {n: sum(1 for e in ours if e[0] == n) for n in SERVE_SPANS}
     st = sched.stats
     assert count[STEP + "begin"] == count[STEP + "end"] == st.iterations
-    assert count[STEP + "admit"] == st.iterations
+    # the overlapped loop opens `admit` again round an admission's
+    # read-back, after the decode step it dispatched in between
+    assert count[STEP + "admit"] == st.iterations + (2 if serve_async else 0)
     assert count[STEP + "prefill.dispatch"] == st.prefill_batches == 2
     assert count[STEP + "decode.dispatch"] == st.decode_steps
-    # a program's first dispatch is forced, and no other: the synchronous
-    # loop runs one decode program, the async loop a second one whose
-    # tokens come from the step in flight
-    assert count[STEP + "decode.wait"] == (2 if serve_async else 1)
+    # a program's first dispatch is forced, and no other: both loops run
+    # the ONE decode program, whoever feeds a slot its token
+    assert count[STEP + "decode.wait"] == 1
     assert count[STEP + "decode.readback"] == st.decode_steps
     assert count[STEP + "decode.commit"] == st.host_syncs == st.decode_steps
 
