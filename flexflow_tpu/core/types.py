@@ -160,6 +160,9 @@ class OperatorType(enum.Enum):
     # operator (ops/core_ops.py)
     LATENT_ATTENTION = enum.auto()
     GATED_MLP = enum.auto()
+    # gated delta-rule linear attention (Kimi Delta Attention: a fixed-size
+    # state a sequence instead of rows a token, ops/linear_attention.py)
+    LINEAR_ATTENTION = enum.auto()
 
 
 PARALLEL_OP_TYPES = frozenset(

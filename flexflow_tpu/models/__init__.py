@@ -15,6 +15,7 @@ from flexflow_tpu.models.nlp import (
     build_bert_proxy,
     build_decoder_lm,
     build_deepseek_v3,
+    build_kimi_linear,
     build_mt5_encoder,
     build_olmoe,
     build_ouro,
@@ -34,6 +35,7 @@ __all__ = [
     "build_decoder_lm",
     "build_mt5_encoder",
     "build_deepseek_v3",
+    "build_kimi_linear",
     "build_olmoe",
     "build_ouro",
     "build_dlrm",

@@ -194,6 +194,87 @@ def build_deepseek_v3(
     return ff.dense(ff.rms_norm(t, eps=eps), vocab_size, use_bias=False)
 
 
+def build_kimi_linear(
+    ff,
+    token_ids,
+    vocab_size: int = 163840,
+    hidden: int = 2304,
+    num_heads: int = 32,
+    num_layers: int = 27,
+    kda_layers=(1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22,
+                23, 25, 26),
+    full_attn_layers=(4, 8, 12, 16, 20, 24, 27),
+    kda_head_dim: int = 128,
+    kda_conv_kernel: int = 4,
+    kv_lora_rank: int = 512,
+    qk_nope_head_dim: int = 128,
+    qk_rope_head_dim: int = 64,
+    v_head_dim: int = 128,
+    dense_hidden: int = 9216,
+    dense_layers: int = 1,
+    expert_hidden: int = 1024,
+    num_experts: int = 256,
+    experts_per_token: int = 8,
+    shared_experts: int = 1,
+    routed_scale: float = 2.446,
+    eps: float = 1e-5,
+    renormalise: bool = True,
+    experts_held=None,
+    kda_chunk: int = 64,
+):
+    """Kimi-Linear (moonshotai/Kimi-Linear-48B-A3B, `kimi_linear`):
+    pre-RMSNorm blocks whose attention is, by the published pattern
+    (layers numbered from 1: `kda_layers`, `full_attn_layers`), either
+    Kimi Delta Attention, a gated delta-rule linear attention over a
+    fixed-size state (FFModel.linear_attention), or latent attention
+    WITHOUT positional encoding (`mla_use_nope`: positions enter through
+    the linear layers' recurrence and convolutions); then a SiLU-gated MLP
+    in the first `dense_layers` layers and after them the `deepseek_v3`
+    expert layer (sigmoid scores, a choice bias, renormalised and scaled
+    weights) plus the shared experts as ONE gated MLP; a final RMSNorm and
+    an untied head. No biases. Of the pattern the layers up to
+    `num_layers` are built. `experts_held` (first, count): this chip's
+    share of each expert layer; a sliced vocabulary is a smaller
+    `vocab_size`. Served like build_decoder_lm: vocab logits, one
+    token-id input."""
+    kinds = {layer: "kda" for layer in kda_layers}
+    kinds.update({layer: "full" for layer in full_attn_layers})
+    missing = [n for n in range(1, num_layers + 1) if n not in kinds]
+    if missing or len(kinds) != len(kda_layers) + len(full_attn_layers):
+        raise ValueError(
+            f"kda_layers and full_attn_layers must name every layer up to "
+            f"{num_layers} once: {missing or 'a layer is in both'}"
+        )
+    t = ff.embedding(token_ids, vocab_size, hidden)
+    for layer in range(1, num_layers + 1):
+        h = ff.rms_norm(t, eps=eps)
+        if kinds[layer] == "kda":
+            a = ff.linear_attention(
+                h, hidden, num_heads, kda_head_dim,
+                conv_kernel=kda_conv_kernel, eps=eps, chunk=kda_chunk,
+                name=f"l{layer}.kda",
+            )
+        else:
+            a = ff.latent_attention(
+                h, hidden, num_heads, kv_lora_rank, qk_nope_head_dim,
+                qk_rope_head_dim, v_head_dim, rope_theta=None, eps=eps,
+                name=f"l{layer}.mla",
+            )
+        t = ff.add(t, a)
+        m = ff.rms_norm(t, eps=eps)
+        if layer <= dense_layers:
+            t = ff.add(t, ff.gated_mlp(m, dense_hidden))
+            continue
+        routed = ff.sparse_moe(
+            m, num_experts, experts_per_token, expert_hidden,
+            renormalise=renormalise, scoring="sigmoid", choice_bias=True,
+            scale=routed_scale, experts_held=experts_held,
+        )
+        shared = ff.gated_mlp(m, shared_experts * expert_hidden)
+        t = ff.add(t, ff.add(routed, shared))
+    return ff.dense(ff.rms_norm(t, eps=eps), vocab_size, use_bias=False)
+
+
 def build_ouro(
     ff,
     token_ids,

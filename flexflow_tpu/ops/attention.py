@@ -1288,7 +1288,9 @@ def mla_project(x, ws, params, ctx, positions=None):
     """x [b, s, e] -> (q_nope [b, s, h, nope], q_rope [b, s, h, rope],
     latent [b, s, rank + rope]): the query of every head, its rotary part
     rotated, and the token's latent row [c | kr], c normalised and kr
-    rotated, as a cache keeps it. `positions` as in mha_qk_positions."""
+    rotated, as a cache keeps it. `positions` as in mha_qk_positions.
+    With `rope_theta` None nothing is rotated (a model whose positions
+    enter through other layers)."""
     r, dn, _, _ = _mla_dims(params)
     with jax.named_scope("mla.project"):
         xm, wq, wkva = mm_operands(ctx, x, ws[0], ws[1])
@@ -1299,9 +1301,15 @@ def mla_project(x, ws, params, ctx, positions=None):
         from flexflow_tpu.ops.core_ops import rms_normalize
 
         c = rms_normalize(kva[..., :r], ws[2], params.get("eps", 1e-6))
+        theta = params["rope_theta"]
+        if theta is None:
+            # no positional encoding: neither part is rotated, and the
+            # row [c | kr] is cached as projected
+            return q[..., :dn], q[..., dn:], jnp.concatenate(
+                [c, kva[..., r:]], axis=-1
+            )
         if positions is None:
             positions = jnp.arange(x.shape[1])
-        theta = params["rope_theta"]
         q_rope = rope_interleaved(q[..., dn:], positions, theta)
         kr = rope_interleaved(kva[..., None, r:], positions, theta)[..., 0, :]
         return q[..., :dn], q_rope, jnp.concatenate([c, kr], axis=-1)

@@ -129,5 +129,6 @@ def _ensure_registered():
     """Import op implementation modules for their registration side effects."""
     from flexflow_tpu.ops import core_ops  # noqa: F401
     from flexflow_tpu.ops import attention  # noqa: F401
+    from flexflow_tpu.ops import linear_attention  # noqa: F401
     from flexflow_tpu.ops import moe  # noqa: F401
     from flexflow_tpu.parallel import parallel_ops  # noqa: F401

@@ -387,7 +387,7 @@ class FFModel:
         qk_nope_head_dim: int,
         qk_rope_head_dim: int,
         v_head_dim: int,
-        rope_theta: float = 10000.0,
+        rope_theta: Optional[float] = 10000.0,
         eps: float = 1e-6,
         name: Optional[str] = None,
     ) -> Tensor:
@@ -395,8 +395,9 @@ class FFModel:
         values are decompressed from one latent row a token, [c | kr] of
         kv_lora_rank + qk_rope_head_dim, which is all a serving cache
         keeps (ops/attention.py, "Latent attention"). Rotary positions in
-        the interleaved form over the rope part of q and over kr; an
-        RMSNorm with a learned gain over c. No biases."""
+        the interleaved form over the rope part of q and over kr, or with
+        `rope_theta=None` no positional encoding at all (neither is
+        rotated); an RMSNorm with a learned gain over c. No biases."""
         params = {
             "embed_dim": hidden,
             "num_heads": num_heads,
@@ -404,13 +405,54 @@ class FFModel:
             "qk_nope_head_dim": qk_nope_head_dim,
             "qk_rope_head_dim": qk_rope_head_dim,
             "v_head_dim": v_head_dim,
-            "rope_theta": float(rope_theta),
+            "rope_theta": None if rope_theta is None else float(rope_theta),
             "eps": eps,
             "causal": True,
             "initializers": [None, None, ConstantInitializer(1.0), None, None],
         }
         return self._add(
             OperatorType.LATENT_ATTENTION, "latent_attention", [input],
+            params, name,
+        )[0]
+
+    def linear_attention(
+        self,
+        input: Tensor,
+        hidden: int,
+        num_heads: int,
+        head_dim: int,
+        conv_kernel: int = 4,
+        eps: float = 1e-5,
+        chunk: int = 64,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """Causal gated delta-rule linear attention (Kimi Delta Attention,
+        ops/linear_attention.py): a sequence keeps a fixed-size state, per
+        head a [head_dim, head_dim] matrix decayed per key channel and
+        corrected by the delta rule, behind three short depthwise
+        convolutions of `conv_kernel` taps; a gated RMSNorm over each
+        head's output. The decay's and the output gate's low-rank pairs
+        are `head_dim` wide inside; `chunk`: the tokens the lowering and
+        a serving prefill take at a time. A_log and dt_bias are trained
+        buffers, zero from here. No biases."""
+        taps = (3.0 / conv_kernel) ** 0.5
+        params = {
+            "embed_dim": hidden,
+            "num_heads": num_heads,
+            "head_dim": head_dim,
+            "conv_kernel": conv_kernel,
+            "gate_rank": head_dim,
+            "chunk": chunk,
+            "eps": eps,
+            "causal": True,
+            # the convolutions keep their input's variance (Glorot over
+            # [channels, taps] would shrink it a hundredfold)
+            "initializers": [None] * 3
+            + [UniformInitializer(-taps, taps)] * 3
+            + [None] * 7 + [ConstantInitializer(1.0), None],
+        }
+        return self._add(
+            OperatorType.LINEAR_ATTENTION, "linear_attention", [input],
             params, name,
         )[0]
 

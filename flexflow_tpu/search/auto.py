@@ -909,7 +909,7 @@ def estimate_max_in_flight(
     "optimistic" charge — the reserve gate admits on worst-case
     divergence (every shared page may COW), so sharing buys it
     nothing."""
-    from flexflow_tpu.serving.kv_cache import KVCacheSpec
+    from flexflow_tpu.serving.kv_cache import KVCacheSpec, derive_state
 
     if admission not in ("reserve", "optimistic"):
         raise ValueError(
@@ -926,6 +926,7 @@ def estimate_max_in_flight(
     if prefix_hit_rate and page_size <= 0:
         raise ValueError("prefix_hit_rate > 0 requires a paged layout")
     guids, pools, heads, head_dim = _serving_cache_geometry(graph)
+    state_guids, state_shapes = derive_state(graph, sorted(graph.nodes))
     heads_chip = max(1, heads // max(1, tp))
     if admission == "reserve":
         budget = max_new_tokens if max_new_tokens is not None else mean_gen_len
@@ -947,7 +948,10 @@ def estimate_max_in_flight(
         itemsize=1 if kv_dtype == "int8" else itemsize,
         kv_dtype=kv_dtype,
         kv_pools=pools,
+        state_guids=state_guids,
+        state_shapes=state_shapes,
     )
+    # a sequence's pages, and its slot's row of every recurrent layer
     per_seq = one.total_bytes
     return int(cache_bytes // per_seq) if per_seq else 0
 

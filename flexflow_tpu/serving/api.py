@@ -532,6 +532,8 @@ def build_scheduler(
     engine.require(
         *(("verify", "verify_tree") if proposer is not None else ()),
         *(("chunk",) if serve.token_budget else ()),
+        *(("multistep",) if serve.decode_multistep else ()),
+        *(("swap",) if serve.kv_swap else ()),
     )
     classes = None
     if serve.classes:

@@ -157,9 +157,9 @@ class PoolsLostError(RuntimeError):
 
 
 #: the arguments a step program rewrites and returns, by the name every
-#: `_*_impl` gives them: K and V pools and the int8 scale pools.
-#: These, and nothing else, are donated.
-_POOL_ARGNAMES = ("ck", "cv", "cks", "cvs")
+#: `_*_impl` gives them: K and V pools, the int8 scale pools and the
+#: recurrent layers' per-slot state. These, and nothing else, are donated.
+_POOL_ARGNAMES = ("ck", "cv", "cks", "cvs", "cs")
 
 
 def snapshot(host_state: np.ndarray):
@@ -238,10 +238,12 @@ class _PackedChoice:
     left it: int32 [expert layers, 1, T, k] a program, the prompts end to
     end, on the device. `np.asarray` of it reads them back and lays them
     out [expert layers, row of the call, position, k] (-1 past a prompt's
-    end), across the programs of a split admission."""
+    end), across the programs of a split admission. `stride`: a prompt
+    begins on a multiple of it (`GenerationEngine._laid`)."""
 
-    def __init__(self, programs):
+    def __init__(self, programs, stride: int = 1):
         self._programs = programs  # [(device choice, the prompts' lengths)]
+        self._stride = stride
 
     def __array__(self, dtype=None, copy=None):
         lens = [n for _, ns in self._programs for n in ns]
@@ -253,7 +255,8 @@ class _PackedChoice:
             at = 0
             for n in ns:
                 out[:, row, :n] = choice[:, 0, at : at + n]
-                row, at = row + 1, at + n
+                row += 1
+                at = -(-(at + n) // self._stride) * self._stride
         return out if dtype is None else out.astype(dtype)
 
 
@@ -416,6 +419,7 @@ class GenerationEngine:
                 self._head_shard = shard
         self._publish_kernel_block()
         self._publish_weight_walk()
+        self._publish_state()
         # multi-tenant LoRA (serving.tenancy.adapters.AdapterPool):
         # None keeps every traced step byte-for-byte the base engine —
         # the adapter argument is simply never passed, so no select or
@@ -513,6 +517,47 @@ class GenerationEngine:
                 "chunk": "chunked-prefill and prefix-suffix" + why,
                 "draft": "draft-model" + why,
             }
+        # recurrent layers (ops/linear_attention.py): a fixed-size state a
+        # SLOT (`cache.state`), written whole by the prefill program and
+        # advanced by the single-step decode program. Nothing else reads
+        # or writes it, and nothing can roll it back: the rest is refused
+        # here, in words, as for the latent pool.
+        self._recurrent = tuple(cache.spec.state_guids)
+        self._state_chunk = 1
+        if self._recurrent:
+            chunks = {graph.nodes[g].params["chunk"] for g in self._recurrent}
+            if len(chunks) != 1:
+                raise ValueError(
+                    f"recurrent layers disagree on their chunk: {chunks}"
+                )
+            (self._state_chunk,) = chunks
+            uneven = [
+                b for b in cache.spec.buckets if b % self._state_chunk
+            ]
+            if uneven:
+                raise ValueError(
+                    f"prefill buckets {uneven} are not whole chunks of "
+                    f"{self._state_chunk} tokens: a model with recurrent "
+                    "layers lays every prompt on a chunk boundary"
+                )
+            from flexflow_tpu.serving.kv_cache import STATE_WHY
+
+            why = " not supported for a model with recurrent layers: " + STATE_WHY
+            for asked, what in (
+                (placement is not None, "a serving mesh is"),
+                (adapters is not None, "adapters (multi-LoRA) are"),
+            ):
+                if asked:
+                    raise ValueError(what + why)
+            self._refused.update({
+                "verify": "verify steps (speculative decoding) are" + why,
+                "verify_tree": "tree-verify steps (speculative decoding) "
+                "are" + why,
+                "chunk": "chunked-prefill and prefix-suffix steps are" + why,
+                "draft": "draft-model steps are" + why,
+                "multistep": "multi-step decode windows are" + why,
+                "swap": "kv_swap (swap_out / swap_in) is" + why,
+            })
         # expert layers (ops/moe.py sparse_moe): the prefill and decode
         # programs return their row and touched-expert counts beside the
         # logits, read in the same readback; without one, nothing more.
@@ -559,6 +604,10 @@ class GenerationEngine:
         self.moe_rows_absent_prefill = 0
         self.moe_rows_absent_decode = 0
         self.mla_rows_read_decode = 0
+        # recurrent layers: (live slot, layer) rows the decode steps
+        # advanced, and (request, layer) rows the prefills wrote from zero
+        self.state_rows_decode = 0
+        self.state_resets_prefill = 0
         self._logits_ref = self.executor.logits_ref
         # per-iteration dynamic seq truncation is a training knob; a stale
         # value would truncate serving activations mid-stack
@@ -685,7 +734,8 @@ class GenerationEngine:
     def require(self, *kinds: str) -> None:
         """Raise, in words, if this engine refuses one of the step `kinds`
         ("verify", "verify_tree", "chunk", "draft": what a model with
-        latent attention is not served through). `build_scheduler` asks
+        latent attention is not served through; "multistep" and "swap"
+        besides for one with recurrent layers). `build_scheduler` asks
         before a request is admitted; the program getters above ask again."""
         for kind in kinds:
             if kind in self._refused:
@@ -718,7 +768,7 @@ class GenerationEngine:
         returns, and hand back the rest of its outputs.
 
         Every step program has this shape: `(params, *inputs, ck, cv,
-        cks, cvs [, ad])` in, `(ck', cv', cks', cvs', ...)` out,
+        cks, cvs, cs [, ad])` in, `(ck', cv', cks', cvs', cs', ...)` out,
         with the pools donated (`_step_jit`). So after the call the
         arrays that went in are gone, and the only live pools are the
         ones `commit` stores here: nothing else may keep a pool array
@@ -734,7 +784,7 @@ class GenerationEngine:
                 f"{site} step not dispatched: the KV pools were consumed "
                 "by an earlier step program that failed"
             )
-        pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
+        pools = cache.pools
         args = (params, *inputs, *pools, *adapter_args)
 
         def call():
@@ -892,6 +942,23 @@ class GenerationEngine:
                 help="of the model as served (engine._publish_weight_walk)",
             ).set(value)
 
+    def _publish_state(self) -> None:
+        """The recurrent layers and the bytes of per-slot state all slots
+        keep of them, as `serve_state_layers` / `serve_state_bytes`
+        gauges, once; nothing for a model without such a layer."""
+        spec = self.cache.spec
+        if self.telemetry is None or not spec.state_guids:
+            return
+        for name, value in (
+            ("layers", len(spec.state_guids)),
+            ("bytes", spec.state_bytes_per_slot * spec.max_seqs),
+        ):
+            self.telemetry.registry.gauge(
+                f"serve_state_{name}",
+                help="of the recurrent layers' per-slot state "
+                "(engine._publish_state)",
+            ).set(value)
+
     def _fall_back_to_dense(self, error) -> None:
         self.kernel_fallbacks += 1
         self.kernel_fallback_error = repr(error)
@@ -957,19 +1024,23 @@ class GenerationEngine:
 
     def _forward_logits(
         self, params, tokens, hook, moe_counts=None, latent_hook=None,
-        share=None,
+        share=None, state_hook=None,
     ):
         """`moe_counts`: a list that receives the int32 counts of every
         expert layer (`ops.moe.sparse_moe`), for the programs that
         return them; the layer itself is the executor's lowering.
         `latent_hook`: what stands in for a latent-attention node, from
         the programs that serve one. `share` (`_share`): what the layers
-        of a model that holds a share of its experts are handed besides."""
+        of a model that holds a share of its experts are handed besides.
+        `state_hook`: what stands in for a recurrent (linear-attention)
+        node, from the programs that keep its per-slot state."""
         import jax
 
         hooks = {OperatorType.MULTIHEAD_ATTENTION: hook}
         if latent_hook is not None:
             hooks[OperatorType.LATENT_ATTENTION] = latent_hook
+        if state_hook is not None:
+            hooks[OperatorType.LINEAR_ATTENTION] = state_hook
         if moe_counts is not None and self._moe_guids:
             from flexflow_tpu.ops.moe import sparse_moe
 
@@ -1145,7 +1216,7 @@ class GenerationEngine:
     _REQUEST_ROWS = 3  # slot, prompt length, index of the prompt's last token
 
     def _prefill_impl_paged(
-        self, params, layout, requests, ck, cv, cks, cvs, ad=None,
+        self, params, layout, requests, ck, cv, cks, cvs, cs, ad=None,
     ):
         """One prefill program over the admitted prompts laid END TO END
         in one row of T tokens (T a prefill bucket, of the prompts' TOTAL).
@@ -1170,7 +1241,16 @@ class GenerationEngine:
         cv', cks', cvs', next_tokens [max_seqs], last_logits [max_seqs,
         V]: each request's last position, in the call's order), and for a
         model with expert layers their int32 counts after them (and the
-        routers' choice [expert layers, 1, T, k], `_step_counts`)."""
+        routers' choice [expert layers, 1, T, k], `_step_counts`).
+
+        A model with recurrent layers (`cs`: their per-slot state) has its
+        prompts laid so that each BEGINS on a chunk boundary
+        (`_prefill_group`), so a chunk of the recurrence holds one
+        prompt's tokens only: the carried state is zeroed where a chunk
+        starts a prompt, padding is made a no-op, the convolutions' taps
+        stop at the prompt's first token, and each request's state after
+        its last chunk and its last inputs are written, whole, to its
+        slot's row (what the row held is never read)."""
         import jax
         import jax.numpy as jnp
 
@@ -1181,6 +1261,12 @@ class GenerationEngine:
             mla_project,
             mla_project_out,
             scaled_dot_product_attention,
+        )
+        from flexflow_tpu.ops.linear_attention import (
+            kda_chunked,
+            kda_conv,
+            kda_out,
+            kda_project,
         )
         from flexflow_tpu.serving.tenancy.adapters import (
             adapter_tokens,
@@ -1206,6 +1292,45 @@ class GenerationEngine:
         quant = self.cache.quantized
         new_k, new_v = {}, {}
         new_ks, new_vs = dict(cks), dict(cvs)
+        new_s = dict(cs)
+
+        def state_hook(node, ins, ws, ctx):
+            g, p = node.guid, node.params
+            chunk, kernel = p["chunk"], p["conv_kernel"]
+            qkv, decay, beta, z = kda_project(ins[0], ws, p, ctx)
+            with jax.named_scope("kda.conv"):
+                back = jnp.arange(1, kernel)
+                taps = (position[:, None] >= back)[None]
+                tails = jnp.zeros((1, kernel - 1, qkv.shape[-1]), qkv.dtype)
+            q, k, v, _ = kda_conv(qkv, tails, ws, p, taps)
+            with jax.named_scope("kda.scan"):
+                on = live[None, :, None]
+                k = jnp.where(on[..., None], k, 0)
+                decay = jnp.where(on[..., None], decay, 0)
+                beta = jnp.where(on, beta, 0)
+                fresh = (position[::chunk] == 0)[None]
+            o, states = kda_chunked(
+                q, k, v, decay, beta, jnp.zeros_like(cs[g]["S"][:1]), fresh,
+                chunk,
+            )
+            with jax.named_scope("kda.scan"):
+                # each request's state after its last chunk, and its last
+                # inputs (zeros where the prompt is shorter than the
+                # kernel): the slot's whole row (rows past the last
+                # request name slot max_seqs, which JAX drops)
+                ago = back[::-1] - 1  # oldest first: K - 2 .. 0 tokens back
+                at = last_at[:, None] - ago
+                seen = (prompt_lens[:, None] > ago)[..., None]
+                kept = jnp.where(seen, qkv[0, jnp.maximum(at, 0)], 0)
+                new_s[g] = {
+                    "S": cs[g]["S"].at[slot_ids].set(
+                        states[0, last_at // chunk], mode="drop"
+                    ),
+                    "conv": cs[g]["conv"].at[slot_ids].set(
+                        kept.astype(jnp.float32), mode="drop"
+                    ),
+                }
+            return [kda_out(o, z, ws, p, ctx, ins[0].dtype)]
 
         def hook(node, ins, ws, ctx):
             g = node.guid
@@ -1255,12 +1380,13 @@ class GenerationEngine:
         logits = self._forward_logits(
             params, tokens, hook, moe,
             latent_hook if self._latent else None, share,
+            state_hook if self._recurrent else None,
         )
         with jax.named_scope("step.pick"):
             last = logits[0, last_at]
             nxt = self._pick(last, slot_ids, prompt_lens)
             counts = self._step_counts(moe, share)
-        return new_k, new_v, new_ks, new_vs, nxt, last, *counts
+        return new_k, new_v, new_ks, new_vs, new_s, nxt, last, *counts
 
     def prefill(
         self,
@@ -1303,11 +1429,12 @@ class GenerationEngine:
                 raise ValueError(
                     f"prompt length {len(p)} outside (0, {spec.max_len}]"
                 )
-            spec.bucket(len(p))  # raises for a prompt no bucket holds
-            if total + len(p) > spec.buckets[-1]:
+            laid = self._laid(len(p))
+            spec.bucket(laid)  # raises for a prompt no bucket holds
+            if total + laid > spec.buckets[-1]:
                 groups.append((lo, i))
                 lo, total = i, 0
-            total += len(p)
+            total += laid
         groups.append((lo, n))
         outs, choices = [], []
         for lo, hi in groups:
@@ -1321,7 +1448,9 @@ class GenerationEngine:
             for a in outs[-1]:
                 a.copy_to_host_async()
         if self._moe_share:
-            self.moe_choice["prefill"] = _PackedChoice(choices)
+            self.moe_choice["prefill"] = _PackedChoice(
+                choices, self._state_chunk
+            )
         return outs
 
     def prefill_reconcile(self, outs: list) -> Tuple[np.ndarray, np.ndarray]:
@@ -1338,24 +1467,32 @@ class GenerationEngine:
             np.concatenate([last for _, last, *_ in host]),
         )
 
+    def _laid(self, n: int) -> int:
+        """The tokens a prompt of `n` takes of a packed row: itself, and
+        for a model with recurrent layers the padding up to the next
+        chunk boundary, where the next prompt begins."""
+        return -(-n // self._state_chunk) * self._state_chunk
+
     def _prefill_group(self, params, prompts, slots):
         """Pack `prompts` (whose total fits the largest bucket) into one
         row and dispatch its program: `_prefill_impl_paged`'s outputs
-        behind the pools, still on the device."""
+        behind the pools, still on the device. Padding, behind the last
+        prompt or up to a chunk boundary between two (`_laid`), is
+        segment max_seqs with an out-of-bounds destination."""
         import jax.numpy as jnp
 
         spec = self.cache.spec
         ps = spec.page_size
         lens = [len(p) for p in prompts]
         total = sum(lens)
-        bucket = spec.bucket(total)
+        bucket = spec.bucket(sum(self._laid(n) for n in lens))
         with span(
             "scheduler.step.prefill.pack", self._tracer,
             {"prompts": len(prompts), "bucket": bucket},
         ):
             layout = np.zeros((self._TOKEN_ROWS, bucket), dtype=np.int32)
-            layout[1, total:] = spec.max_seqs
-            layout[3, total:] = spec.total_rows
+            layout[1] = spec.max_seqs
+            layout[3] = spec.total_rows
             requests = np.zeros(
                 (self._REQUEST_ROWS, spec.max_seqs), dtype=np.int32
             )
@@ -1371,8 +1508,9 @@ class GenerationEngine:
                     self.cache.block_tables[s, pos // ps] * ps + pos % ps
                 )
                 requests[:, i] = (s, n, at + n - 1)
-                at += n
+                at += self._laid(n)
             self.prefill_tokens_real += total
+            self.state_resets_prefill += len(prompts) * len(self._recurrent)
             self.prefill_tokens_padded += bucket
             self.prefill_programs += 1
             fn = self._prefill_cache.get(bucket)
@@ -1440,7 +1578,7 @@ class GenerationEngine:
 
     def _decode_core_paged(
         self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
-        ad=None, moe=None, share=None,
+        cs, ad=None, moe=None, share=None,
     ):
         """One decode forward: tokens [max_seqs, 1]; lengths [max_seqs]
         = cache position the incoming token is written at; active
@@ -1456,8 +1594,10 @@ class GenerationEngine:
         multi-LoRA existed. `moe`: the list that receives the expert
         layers' counts and `share` what a model that holds a share
         of its experts hands them (`_forward_logits`); the single-step
-        program returns both, the scan neither. Returns (ck', cv', cks', cvs',
-        logits [max_seqs, V])."""
+        program returns both, the scan neither. `cs`: the recurrent
+        layers' per-slot state; a step advances the rows of its `active`
+        slots and hands every other row back bit-equal. Returns (ck', cv',
+        cks', cvs', cs', logits [max_seqs, V])."""
         import jax
         import jax.numpy as jnp
 
@@ -1471,6 +1611,12 @@ class GenerationEngine:
             paged_decode_attention,
             paged_latent_decode_attention,
         )
+        from flexflow_tpu.ops.linear_attention import (
+            kda_conv,
+            kda_out,
+            kda_project,
+            kda_step,
+        )
         from flexflow_tpu.serving.tenancy.adapters import (
             apply_adapter_out,
             apply_adapter_qkv,
@@ -1483,6 +1629,7 @@ class GenerationEngine:
         new_k = dict(ck)
         new_v = dict(cv)
         new_ks, new_vs = dict(cks), dict(cvs)
+        new_s = dict(cs)
         with jax.named_scope("step.unpack"):
             page = jnp.take_along_axis(
                 tables, (lengths // ps)[:, None], axis=1
@@ -1541,13 +1688,33 @@ class GenerationEngine:
             attn = mla_absorb_values(attended, ws, p, ctx)
             return [mla_project_out(attn, ws, ctx, ins[0].dtype)]
 
+        def state_hook(node, ins, ws, ctx):
+            # one token a slot: the convolutions over the slot's tails,
+            # one step of the recurrence on the slot's state
+            g, p = node.guid, node.params
+            qkv, decay, beta, z = kda_project(ins[0], ws, p, ctx)
+            q, k, v, tails = kda_conv(qkv, cs[g]["conv"], ws, p)
+            o, state = kda_step(
+                q[:, 0], k[:, 0], v[:, 0], decay[:, 0], beta[:, 0], cs[g]["S"]
+            )
+            with jax.named_scope("kda.step"):
+                new_s[g] = {
+                    "S": jnp.where(
+                        active[:, None, None, None], state, cs[g]["S"]
+                    ),
+                    "conv": jnp.where(
+                        active[:, None, None], tails, cs[g]["conv"]
+                    ),
+                }
+            return [kda_out(o[:, None], z, ws, p, ctx, ins[0].dtype)]
+
         logits = self._forward_logits(
             params, tokens, hook, moe, latent_hook if self._latent else None,
-            share,
+            share, state_hook if self._recurrent else None,
         )
         with jax.named_scope("step.pick"):
             last = logits[:, -1, :]
-        return new_k, new_v, new_ks, new_vs, last
+        return new_k, new_v, new_ks, new_vs, new_s, last
 
     #: columns of a decode step's packed host state in front of the slot's
     #: block table: the host's view of the last token, whether the token
@@ -1555,7 +1722,7 @@ class GenerationEngine:
     _STATE_COLUMNS = 4
 
     def _decode_impl_paged(
-        self, params, chained, state, ck, cv, cks, cvs, ad=None,
+        self, params, chained, state, ck, cv, cks, cvs, cs, ad=None,
     ):
         """The single-step jit target: one _decode_core_paged forward
         plus the per-slot sample (the sampled token will be written at
@@ -1584,8 +1751,8 @@ class GenerationEngine:
             tokens = jnp.where(from_chain != 0, chained, tokens)[:, None]
             share = self._share(active[:, None])
         moe = []
-        new_k, new_v, new_ks, new_vs, logits = self._decode_core_paged(
-            params, tokens, lengths, active, tables, ck, cv, cks, cvs, ad,
+        new_k, new_v, new_ks, new_vs, new_s, logits = self._decode_core_paged(
+            params, tokens, lengths, active, tables, ck, cv, cks, cvs, cs, ad,
             moe, share,
         )
         with jax.named_scope("step.pick"):
@@ -1600,7 +1767,10 @@ class GenerationEngine:
                     *extra[:n],
                 ]
             )
-        return new_k, new_v, new_ks, new_vs, nxt, logits, readback, *extra[n:]
+        return (
+            new_k, new_v, new_ks, new_vs, new_s, nxt, logits, readback,
+            *extra[n:],
+        )
 
     # -- device-resident multi-step decode -----------------------------------
 
@@ -1618,6 +1788,7 @@ class GenerationEngine:
         cv,
         cks,
         cvs,
+        cs,
         ad=None,
     ):
         """K fused decode iterations as ONE jitted `lax.scan` — the
@@ -1657,13 +1828,13 @@ class GenerationEngine:
             steps = jnp.arange(k_bucket)
 
         def body(carry, i):
-            ck_c, cv_c, cks_c, cvs_c, lens, toks, alive = carry
+            ck_c, cv_c, cks_c, cvs_c, cs_c, lens, toks, alive = carry
             with jax.named_scope("step.unpack"):
                 act = alive & (i < limits)
                 tokens = toks[:, None]
-            nk, nv, nks, nvs, logits = self._decode_core_paged(
+            nk, nv, nks, nvs, ns, logits = self._decode_core_paged(
                 params, tokens, lens, act, tables, ck_c, cv_c, cks_c, cvs_c,
-                ad,
+                cs_c, ad,
             )
             with jax.named_scope("step.pick"):
                 nxt = self._pick(logits, slots, lens + 1)
@@ -1671,22 +1842,22 @@ class GenerationEngine:
                 new_lens = jnp.where(act, lens + 1, lens)
                 new_toks = jnp.where(act, nxt, toks)
                 still = alive & ~hit
-            return (nk, nv, nks, nvs, new_lens, new_toks, still), (
+            return (nk, nv, nks, nvs, ns, new_lens, new_toks, still), (
                 nxt,
                 logits,
                 act,
             )
 
-        carry0 = (ck, cv, cks, cvs, lengths, tokens, active)
+        carry0 = (ck, cv, cks, cvs, cs, lengths, tokens, active)
         # the loop's own instructions (counter, stacking of the outputs)
         # read `step.scan`; a node's keep the node's scope inside it
         with jax.named_scope("step.scan"):
-            (nk, nv, nks, nvs, lens, toks, _), (
+            (nk, nv, nks, nvs, ns, lens, toks, _), (
                 toks_ks,
                 logits_ks,
                 mask_ks,
             ) = jax.lax.scan(body, carry0, steps)
-        return nk, nv, nks, nvs, lens, toks, toks_ks, logits_ks, mask_ks
+        return nk, nv, nks, nvs, ns, lens, toks, toks_ks, logits_ks, mask_ks
 
     def _chain_feed(self, params):
         """The `chained` argument of a decode step that chains on nothing:
@@ -1794,6 +1965,8 @@ class GenerationEngine:
             self.mla_rows_read_decode += len(self._latent) * int(
                 self.cache.lengths[active].sum()
             )
+        # the slots' rows of per-slot state this step advances
+        self.state_rows_decode += len(self._recurrent) * int(active.sum())
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
         self.cache.begin_inflight()
@@ -1875,6 +2048,7 @@ class GenerationEngine:
         device_next exactly like decode_dispatch."""
         import jax.numpy as jnp
 
+        self.require("multistep")
         spec = self.cache.spec
         limits = np.where(
             np.asarray(active_mask, dtype=bool),
@@ -2028,7 +2202,7 @@ class GenerationEngine:
 
     def _verify_impl_paged(
         self, params, tokens, lengths, draft_lens, tables, ck, cv, cks, cvs,
-        ad=None,
+        cs, ad=None,
     ):
         """tokens [max_seqs, w] int32 — column 0 is each slot's last
         emitted (not yet cached) token, columns 1..draft_lens-1 the
@@ -2119,11 +2293,11 @@ class GenerationEngine:
             return [apply_adapter_out(attn, out, ad, g)]
 
         logits = self._forward_logits(params, tokens, hook)
-        return new_k, new_v, new_ks, new_vs, logits
+        return new_k, new_v, new_ks, new_vs, cs, logits
 
     def _verify_tree_impl_paged(
         self, params, tokens, lengths, draft_lens, parents, tables, ck, cv,
-        cks, cvs, ad=None,
+        cks, cvs, cs, ad=None,
     ):
         """Tree twin of _verify_impl_paged: tokens [max_seqs, w] where
         column 0 is the slot's last emitted token (the tree ROOT's
@@ -2216,7 +2390,7 @@ class GenerationEngine:
             return [apply_adapter_out(attn, out, ad, g)]
 
         logits = self._forward_logits(params, tokens, hook)
-        return new_k, new_v, new_ks, new_vs, logits
+        return new_k, new_v, new_ks, new_vs, cs, logits
 
     def verify_dispatch(
         self,
@@ -2403,7 +2577,7 @@ class GenerationEngine:
 
     def _chunk_impl_paged(
         self, params, tokens, slot_ids, all_lengths, chunk_lens, tables,
-        ck, cv, cks, cvs, ad=None,
+        ck, cv, cks, cvs, cs, ad=None,
     ):
         """tokens [B, w] int32 — the next chunk_lens[b] PROMPT tokens
         of each ACTIVE prefilling slot slot_ids[b] (0-padded);
@@ -2511,7 +2685,7 @@ class GenerationEngine:
                 axis=1,
             )[:, 0]
             nxt = self._pick(last, slot_ids, lengths + chunk_lens)
-        return new_k, new_v, new_ks, new_vs, nxt, last
+        return new_k, new_v, new_ks, new_vs, cs, nxt, last
 
     def prefill_chunk_dispatch(
         self,
@@ -2533,6 +2707,7 @@ class GenerationEngine:
         filled by the scheduler)."""
         import jax.numpy as jnp
 
+        self.require("chunk")
         spec = self.cache.spec
         tokens = np.asarray(tokens, dtype=np.int32)
         chunk_lens = np.asarray(chunk_lens, dtype=np.int32)

@@ -42,6 +42,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import heapq
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -112,6 +113,10 @@ class KVCacheSpec:
     itemsize: int = 4
     kv_dtype: str = "fp32"  # "fp32" | "int8"
     kv_pools: int = 2  # K and V; 1 where both are read from the same rows
+    # recurrent layers (`state_row`): what a SLOT keeps of each, beside
+    # the pages: float32 arrays by name, the same for every such layer
+    state_guids: Tuple[int, ...] = ()
+    state_shapes: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
 
     def bucket(self, length: int) -> int:
         """Smallest bucket >= length (prefill pad target)."""
@@ -155,10 +160,22 @@ class KVCacheSpec:
         return base
 
     @property
+    def state_bytes_per_slot(self) -> int:
+        """What one more slot costs in per-slot state (float32), over all
+        recurrent layers; nothing for a model without one."""
+        return 4 * len(self.state_guids) * sum(
+            math.prod(shape) for _, shape in self.state_shapes
+        )
+
+    @property
     def total_bytes(self) -> int:
-        """Whole-cache footprint across layers — the number
-        optimize_serving's capacity estimate divides the HBM budget by."""
-        return self.bytes_per_layer * len(self.layer_guids)
+        """Whole-cache footprint across layers, the per-slot state of
+        every slot included — the number optimize_serving's capacity
+        estimate divides the HBM budget by."""
+        return (
+            self.bytes_per_layer * len(self.layer_guids)
+            + self.state_bytes_per_slot * self.max_seqs
+        )
 
 
 def _validate_page_geometry(max_seqs, max_len, page_size, num_pages):
@@ -194,6 +211,31 @@ def cache_row(node) -> Tuple[int, int, int]:
         return 1, 1, mla_cache_row(node.params)
     heads = int(node.params["num_heads"])
     return 2, heads, int(node.params["embed_dim"]) // heads
+
+
+#: the operator types that keep a fixed-size state a SLOT, not rows a token
+RECURRENT = (OperatorType.LINEAR_ATTENTION,)
+
+
+def state_row(node) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
+    """((name, shape), ...) of the float32 arrays a recurrent node keeps
+    of each sequence between steps. The one place the per-slot state is
+    sized: the cache, the engine's writes and `optimize_serving`'s
+    capacity estimate all go through KVCacheSpec built from this."""
+    from flexflow_tpu.ops.linear_attention import kda_state_shapes
+
+    return kda_state_shapes(node.params)
+
+
+def derive_state(graph, order) -> Tuple[Tuple[int, ...], Tuple]:
+    """(state_guids, state_shapes) of a graph's recurrent nodes, in
+    `order`; ((), ()) without one. Every such node must agree on
+    `state_row`, as attention nodes must on `cache_row`."""
+    guids = tuple(g for g in order if graph.nodes[g].op_type in RECURRENT)
+    rows = {state_row(graph.nodes[g]) for g in guids}
+    if len(rows) > 1:
+        raise ValueError(f"recurrent layers disagree on their state: {rows}")
+    return guids, (rows.pop() if rows else ())
 
 
 def _derive_geometry(model):
@@ -246,6 +288,14 @@ def _heads_sharding(executor, head_axis):
     from jax.sharding import NamedSharding, PartitionSpec
 
     return NamedSharding(executor.mesh, PartitionSpec(None, None, head_axis))
+
+
+STATE_WHY = (
+    "a slot's recurrent state is the whole of its history in one "
+    "fixed-size row, written by prefill and decode only; it cannot be "
+    "rolled back to an earlier position, shared between slots or resumed "
+    "from pages"
+)
 
 
 class PagedKVCache:
@@ -355,6 +405,30 @@ class PagedKVCache:
                 self.k_scale[g] = fresh(scales, jnp.float32, scale_shardings)
                 if spec.kv_pools == 2:
                     self.v_scale[g] = fresh(scales, jnp.float32, scale_shardings)
+        # per-slot state of the recurrent layers, beside the pools and
+        # committed with them: {layer: {name: [max_seqs, *shape]}} float32,
+        # indexed by SLOT. A prefill writes an admitted slot's whole row
+        # from the zero state and a decode step the rows of its active
+        # slots, so `free` has no device work and a recompute rebuilds
+        # it; what would have to roll a recurrence back or carry it
+        # elsewhere (`_refuse_with_state`) is refused. Empty without such
+        # a layer, which adds no argument to a step program.
+        self.state: Dict[int, Dict[str, object]] = {}
+        if spec.state_guids:
+            whole = None
+            if shardings is not None:
+                from jax.sharding import NamedSharding, PartitionSpec
+
+                whole = NamedSharding(shardings.mesh, PartitionSpec())
+            for g in spec.state_guids:
+                self.state[g] = {
+                    name: fresh((spec.max_seqs,) + shape, jnp.float32, whole)
+                    for name, shape in spec.state_shapes
+                }
+            if self.quantized:
+                self._refuse_with_state("kv_dtype='int8'")
+            if self.prefix_cache:
+                self._refuse_with_state("prefix_cache")
         self.lengths = np.zeros(spec.max_seqs, dtype=np.int32)
         self.block_tables = np.full(
             (spec.max_seqs, spec.max_pages_per_seq),
@@ -1091,6 +1165,15 @@ class PagedKVCache:
         if self._owned(slot) <= self._max_pages[slot]:
             self._reserved_h[h] -= 1
 
+    def _refuse_with_state(self, what: str) -> None:
+        """Raise, in words, where the cache holds per-slot recurrent
+        state and `what` would need it rolled back, moved or shared."""
+        if self.state:
+            raise ValueError(
+                f"{what} is not supported for a model with recurrent "
+                f"layers: {STATE_WHY}"
+            )
+
     def truncate(
         self, slot: int, new_len: int, src_rows: Optional[Sequence[int]] = None
     ) -> None:
@@ -1123,6 +1206,7 @@ class PagedKVCache:
         that row (the _quant_scatter claim rule), so the committed pool
         bytes match what a sequential decode of the accepted path would
         have produced up to the int8 round trip."""
+        self._refuse_with_state("truncate (a speculative roll-back)")
         if slot not in self._active:
             raise ValueError(f"slot {slot} is not active")
         if not 0 <= new_len <= self.spec.max_len:
@@ -1293,6 +1377,7 @@ class PagedKVCache:
         when `swap_bytes_budget` would be exceeded. The staged copy is
         the COMMITTED pool content, so a restore resumes decoding with
         value-identical KV rows — no re-prefill."""
+        self._refuse_with_state("swap_out")
         if slot not in self._active:
             raise ValueError(f"slot {slot} is not active")
         if self._inflight_depth > 0:
@@ -1369,6 +1454,7 @@ class PagedKVCache:
         `total_len` sizes the growth reserve exactly like `alloc`'s;
         None means no host can admit (the handle stays valid for a
         later retry or `discard_swap`)."""
+        self._refuse_with_state("swap_in")
         rec = self._swapped.get(handle)
         if rec is None:
             raise KeyError(f"unknown swap handle {handle}")
@@ -1464,6 +1550,7 @@ class PagedKVCache:
         the caller's, to retry or degrade to recompute). Raises
         ValueError on a geometry mismatch: restoring rows shaped by a
         different page/head layout would scatter garbage."""
+        self._refuse_with_state("import_swap")
         rec = dict(record)
         fp = rec.pop("fingerprint", None)
         if fp is not None and tuple(fp) != self._swap_fingerprint():
@@ -1483,15 +1570,23 @@ class PagedKVCache:
         self._swap_bytes_held += bytes_staged
         return handle
 
+    @property
+    def pools(self) -> Tuple:
+        """What every step program is handed, donated, and returns
+        rewritten, in `commit`'s order."""
+        return self.k, self.v, self.k_scale, self.v_scale, self.state
+
     def commit(
         self,
         new_k: Dict[int, object],
         new_v: Dict[int, object],
         new_k_scale: Optional[Dict[int, object]] = None,
         new_v_scale: Optional[Dict[int, object]] = None,
+        new_state: Optional[Dict[int, Dict[str, object]]] = None,
     ):
         """Swap in the pools a jitted step returned (and, under int8,
-        the scale side pools the step's scatter-max may have claimed).
+        the scale side pools the step's scatter-max may have claimed,
+        and the per-slot state of a model's recurrent layers).
         The step program was handed the previous ones donated
         (engine._step_jit): they are gone, these are the only live
         pools, and nothing else may keep a pool array across a
@@ -1502,6 +1597,8 @@ class PagedKVCache:
             self.k_scale = dict(new_k_scale)
         if new_v_scale is not None:
             self.v_scale = dict(new_v_scale)
+        if new_state is not None:
+            self.state = {g: dict(rows) for g, rows in new_state.items()}
 
     def telemetry_gauges(self) -> Dict[str, float]:
         """Point-in-time allocator gauges for the telemetry sampler:
@@ -1730,6 +1827,7 @@ class PagedKVCache:
                 f"kv_dtype must be 'fp32' or 'int8', got {kv_dtype!r}"
             )
         guids, heads, head_dim, head_axis, executor = _derive_geometry(model)
+        state_guids, state_shapes = derive_state(model.graph, executor.topo)
         if page_size <= 0:
             page_size = default_page_size(max_len)
         if max_len % page_size:
@@ -1749,6 +1847,8 @@ class PagedKVCache:
             num_pages=num_pages,
             kv_dtype=kv_dtype,
             kv_pools=cache_row(model.graph.nodes[guids[0]])[0],
+            state_guids=state_guids,
+            state_shapes=state_shapes,
         )
         if dtype is None:
             dtype = jnp.float32

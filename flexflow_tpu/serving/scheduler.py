@@ -333,6 +333,11 @@ _STAT_FIELDS: Dict[str, object] = dict(
     # latent attention: the live latent rows the decode steps attended,
     # summed over slots and layers
     mla_rows_read_decode=0,
+    # recurrent layers (per-slot state): the (live slot, layer) rows the
+    # decode steps advanced, and the (request, layer) rows the prefills
+    # wrote from the zero state
+    state_rows_decode=0,
+    state_resets_prefill=0,
     # prefix-sharing page cache (--prefix-cache;
     # mirrored from the allocator's ledgers at each iteration end)
     prefix_hits=0,  # admissions that mapped at least one shared page
@@ -2706,7 +2711,7 @@ class _SchedulerBase:
         "moe_rows_prefill", "moe_rows_decode",
         "moe_experts_touched_prefill", "moe_experts_touched_decode",
         "moe_rows_absent_prefill", "moe_rows_absent_decode",
-        "mla_rows_read_decode",
+        "mla_rows_read_decode", "state_rows_decode", "state_resets_prefill",
     )
 
     def _end_iteration(self) -> None:

@@ -679,9 +679,9 @@ def step_program_texts(engine):
         )
         if (fn, shapes) not in seen:
             seen.add((fn, shapes))
-            cache = engine.cache
-            pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
-            text = fn.lower(params, *inputs, *pools, *adapter_args)
+            text = fn.lower(
+                params, *inputs, *engine.cache.pools, *adapter_args
+            )
             with fresh_compile():
                 text = text.compile().as_text()
             texts[f"{parse_hlo(text)[0]} {shapes}"] = text

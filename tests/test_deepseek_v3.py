@@ -186,7 +186,7 @@ def case_scopes(model):
     text = engine._decode_jit.trace(
         model.params, s((n,)),
         s((n, engine._STATE_COLUMNS + spec.max_pages_per_seq)),
-        cache.k, cache.v, cache.k_scale, cache.v_scale,
+        *cache.pools,
     ).lower().as_text(debug_info=True)
     for scope in ("mla.project", "mla.absorb", "mla.attend", "mla.out",
                   "moe.route", "moe.experts", "moe.shared"):

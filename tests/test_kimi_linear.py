@@ -1,0 +1,599 @@
+"""The `kimi_linear` block (Kimi Delta Attention over a per-slot recurrent
+state beside latent attention WITHOUT positions over the latent paged
+pool, a leading dense gated MLP, Kanana's expert layer holding a share)
+through the operator, the builder, and the two step kinds a model with
+recurrent layers is served by, each against the plain reference
+`benchmarks/reference/kimi_linear.py` in exact float32 (conftest pins
+`highest`), at a small size: 5 layers (KDA-dense, KDA, KDA, latent, KDA:
+the published pattern's first five), hidden 64, 4 heads, KDA heads of 16
+behind convolutions of 4 taps in chunks of 8, latent rank 32 + 8, 8
+experts of width 24 with 2 per token of which this "chip" holds 4,
+vocabulary 211, seeded weights; logits, never tokens. The contract of the
+per-slot state (docs/serving.md, C1-C5) has a test a line."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.reference import kimi_linear as reference  # noqa: E402
+from flexflow_tpu import (  # noqa: E402
+    DataType,
+    FFConfig,
+    FFModel,
+    LossType,
+    SGDOptimizer,
+)
+from flexflow_tpu.core.types import OperatorType  # noqa: E402
+from flexflow_tpu.models import build_kimi_linear  # noqa: E402
+from flexflow_tpu.ops import attention as A  # noqa: E402
+from flexflow_tpu.ops import linear_attention as L  # noqa: E402
+from flexflow_tpu.ops.registry import LowerCtx, op_flops  # noqa: E402
+from flexflow_tpu.serving import Request, ServeConfig, build_scheduler  # noqa: E402
+
+VOCAB, K, SEQ, TOL, CHUNK = 211, 2, 64, 1e-4, 8
+EPS, ROPE, SCALE, HELD = 1e-5, 8, 2.446, (0, 4)
+SIZES = dict(
+    vocab_size=VOCAB, hidden=64, num_heads=4, num_layers=5,
+    kda_layers=(1, 2, 3, 5), full_attn_layers=(4,), kda_head_dim=16,
+    kda_conv_kernel=4, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=ROPE, v_head_dim=16, dense_hidden=96, dense_layers=1,
+    expert_hidden=24, num_experts=8, experts_per_token=K, shared_experts=1,
+    routed_scale=SCALE, eps=EPS, kda_chunk=CHUNK,
+)
+BUCKETS = (16, 32, 64)
+
+
+def _nodes(model, op_type):
+    return [n for n in model.graph.nodes.values() if n.op_type == op_type]
+
+
+def _draw_buffers(model, seed):
+    """The trained buffers the builder leaves at zero, drawn so that they
+    show: the routers' choice bias, and each KDA layer's A_log and dt_bias
+    (per-token decays between about 0.2 and 0.95)."""
+    for node in _nodes(model, OperatorType.SPARSE_MOE):
+        ws = model.params[node.guid]
+        ws[4] = jax.random.uniform(
+            jax.random.PRNGKey(seed + node.guid), ws[4].shape, ws[4].dtype,
+            -0.1, 0.1,
+        )
+    for node in _nodes(model, OperatorType.LINEAR_ATTENTION):
+        ws = model.params[node.guid]
+        key = jax.random.PRNGKey(seed + node.guid)
+        assert not np.any(np.asarray(ws[8])) and not np.any(np.asarray(ws[9]))
+        ws[8] = jax.random.uniform(key, ws[8].shape, ws[8].dtype, -2.0, 0.0)
+        ws[9] = jax.random.uniform(
+            jax.random.fold_in(key, 1), ws[9].shape, ws[9].dtype,
+            np.log(0.5), np.log(4.0),
+        )
+
+
+def _model(held=HELD, seed=7, **sizes):
+    cfg = FFConfig(batch_size=4)
+    cfg.seed = seed
+    model = FFModel(cfg)
+    tok = model.create_tensor([4, SEQ], dtype=DataType.INT32, name="tokens")
+    build_kimi_linear(model, tok, experts_held=held, **{**SIZES, **sizes})
+    model.compile(
+        optimizer=SGDOptimizer(lr=0.01),
+        loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+        metrics=[], devices=jax.devices()[:1],
+    )
+    _draw_buffers(model, seed)
+    return model
+
+
+@pytest.fixture(scope="module")
+def served():
+    return _model()
+
+
+def _weights(model):
+    return [list(model.params[g]) for g in sorted(model.params)]
+
+
+def _want(model, seq, positions=None, held=HELD, **kw):
+    logits, _ = reference.run(
+        _weights(model), seq, SEQ, EPS, ROPE, K, SCALE, held, **kw
+    )
+    return logits if positions is None else logits[np.asarray(positions)]
+
+
+def _gap(got, want):
+    return float(np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want)))
+
+
+def _prompt(n, salt=0):
+    return [(salt * 31 + 7 * j * j + 3 * j) % (VOCAB - 1) + 1 for j in range(n)]
+
+
+def _serve(model, **kw):
+    kw.setdefault("max_seqs", 4)
+    kw.setdefault("max_seq_len", SEQ)
+    kw.setdefault("prefill_buckets", BUCKETS)
+    return build_scheduler(model, ServeConfig(**kw))
+
+
+def _step(engine, model, feed):
+    """One decode step of {slot: token}; the logits [max_seqs, V]."""
+    tokens = np.zeros(4, np.int32)
+    active = np.zeros(4, bool)
+    for slot, tok in feed.items():
+        tokens[slot], active[slot] = tok, True
+    return np.asarray(engine.decode(model.params, tokens, active)[1])
+
+
+def _state(cache):
+    return {
+        (g, name): np.asarray(a)
+        for g, rows in cache.state.items() for name, a in rows.items()
+    }
+
+
+# -- the operator -------------------------------------------------------------
+
+
+def _kda_inputs(b, s, h, d, seed=0, strength=1.0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k = jax.random.normal(ks[1], (b, s, h, d))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, s, h, d))
+    g = -strength * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h, d)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    return q, k, v, g, beta
+
+
+def _by_steps(q, k, v, g, beta, state):
+    outs = []
+    for t in range(q.shape[1]):
+        o, state = L.kda_step(q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], state)
+        outs.append(o)
+    return jnp.stack(outs, 1), state
+
+
+@pytest.mark.parametrize("strength", [1.0, 300.0])
+def test_chunked_form_is_the_one_step_form(strength):
+    """Also at decays whose cumulative product underflows float32 within
+    a chunk (exp(-300) a token): every decay inside a chunk is the
+    exponential of a non-positive difference, so nothing overflows."""
+    args = _kda_inputs(2, 40, 3, 8, strength=strength)
+    zero = jnp.zeros((2, 3, 8, 8))
+    want, last = _by_steps(*args, zero)
+    got, states = L.kda_chunked(*args, zero, chunk=CHUNK)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(states[:, -1] - last).max()) < 1e-5
+    assert states.shape == (2, 5, 3, 8, 8)
+
+
+def test_a_token_is_made_a_no_op_by_data_and_a_chunk_resets_by_flag():
+    """What a packed row needs of one program: padding (beta = 0, g = 0,
+    k = 0) leaves the state alone, and a chunk that starts another prompt
+    starts from zero whatever was carried."""
+    q, k, v, g, beta = _kda_inputs(1, 24, 2, 8, seed=3)
+    pad = jnp.arange(24) >= 19  # the last chunk's tail is padding
+    k = jnp.where(pad[None, :, None, None], 0, k)
+    g = jnp.where(pad[None, :, None, None], 0, g)
+    beta = jnp.where(pad[None, :, None], 0, beta)
+    zero = jnp.zeros((1, 2, 8, 8))
+    _, states = L.kda_chunked(q, k, v, g, beta, zero, chunk=CHUNK)
+    _, want = _by_steps(*(a[:, :19] for a in (q, k, v, g, beta)), zero)
+    assert float(jnp.abs(states[:, -1] - want).max()) < 1e-5
+    # chunks 0-1 are one prompt, chunk 2 another: its state is its own
+    reset = jnp.array([[False, False, True]])
+    got, states = L.kda_chunked(q, k, v, g, beta, zero + 5.0, reset, CHUNK)
+    alone, last = _by_steps(*(a[:, 16:19] for a in (q, k, v, g, beta)), zero)
+    assert float(jnp.abs(got[:, 16:19] - alone).max()) < 1e-5
+    assert float(jnp.abs(states[:, 2] - last).max()) < 1e-5
+
+
+def test_chunked_gradients_are_the_one_step_forms():
+    args = _kda_inputs(1, 16, 2, 8, seed=5)
+    zero = jnp.zeros((1, 2, 8, 8))
+    w = jax.random.normal(jax.random.PRNGKey(9), (1, 16, 2, 8))
+
+    def chunked(*a):
+        return jnp.sum(L.kda_chunked(*a, zero, chunk=CHUNK)[0] * w)
+
+    def stepped(*a):
+        return jnp.sum(_by_steps(*a, zero)[0] * w)
+
+    got = jax.grad(chunked, argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(stepped, argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(a - b).max()) < 1e-4 * max(1.0, float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("n", [1, 3, 5, 8, 21, 40])
+def test_the_lowering_is_the_references_layer(served, n):
+    """Prompts shorter than the convolution's kernel (1, 3), shorter than
+    a chunk (5), a whole chunk (8), and with chunk boundaries inside."""
+    node = _nodes(served, OperatorType.LINEAR_ATTENTION)[1]
+    ws = served.params[node.guid]
+    x = jax.random.normal(jax.random.PRNGKey(n), (1, n, 64))
+    want = reference._kda(x[0], jnp.ones(64), ws, EPS, None) - x[0]
+    # the reference normalises its input (gain one here); feed it the same
+    u = x[0] * jax.lax.rsqrt(jnp.mean(x[0] ** 2, -1, keepdims=True) + EPS)
+    got = L._lower_linear_attention(node.params)(
+        [u[None]], ws, LowerCtx(train=False)
+    )[0]
+    assert _gap(got[0], np.asarray(want)) < TOL
+
+
+def test_latent_attention_without_positions_rotates_nothing(served):
+    node = _nodes(served, OperatorType.LATENT_ATTENTION)[0]
+    assert node.params["rope_theta"] is None
+    ws = served.params[node.guid]
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 64))
+    ctx = LowerCtx(train=False)
+    q_nope, q_rope, latent = A.mla_project(x, ws, node.params, ctx, jnp.arange(12)[None])
+    shifted = A.mla_project(x, ws, node.params, ctx, 5 + jnp.arange(12)[None])
+    for a, b in zip((q_nope, q_rope, latent), shifted):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    u = x[0] * jax.lax.rsqrt(jnp.mean(x[0] ** 2, -1, keepdims=True) + EPS)
+    got = A._lower_latent_attention(node.params)([u[None]], ws, ctx)[0]
+    want = reference._latent(x[0], jnp.ones(64), ws, EPS, ROPE) - x[0]
+    assert _gap(got[0], np.asarray(want)) < TOL
+
+
+def test_search_counts_the_node_and_its_state(served):
+    from flexflow_tpu.search.auto import estimate_max_in_flight
+
+    node = _nodes(served, OperatorType.LINEAR_ATTENTION)[0]
+    flops = op_flops(node.op_type, node.output_shapes, node.params)
+    e, hd, r = 64, 64, 16
+    proj = e * (3 * hd + 2 * r + 4) + 2 * r * hd + hd * e + 3 * hd * 4
+    scan = 4 * (7 * CHUNK * 16 + 6 * 16 * 16)
+    assert flops == 4 * SEQ * (2.0 * proj + scan)
+    # a sequence's pages and its slot's row of the four recurrent layers
+    state = 4 * 4 * (4 * 16 * 16 + 3 * 3 * 64)
+    page = 16 * 128 * 4  # one latent layer, rows padded to 128
+    fit = estimate_max_in_flight(
+        served.graph, 10 * (state + page), 8, 8, SEQ, page_size=16
+    )
+    assert fit == 10
+
+
+# -- the model ------------------------------------------------------------------
+
+
+def test_the_builder_follows_the_published_pattern(served):
+    kinds = [
+        n.op_type for n in served.graph.nodes.values()
+        if n.op_type in (OperatorType.LINEAR_ATTENTION, OperatorType.LATENT_ATTENTION)
+    ]
+    lin, lat = OperatorType.LINEAR_ATTENTION, OperatorType.LATENT_ATTENTION
+    assert kinds == [lin, lin, lin, lat, lin]
+    assert len(_nodes(served, OperatorType.SPARSE_MOE)) == 4
+    with pytest.raises(ValueError, match="every layer"):
+        build_kimi_linear(
+            FFModel(FFConfig(batch_size=1)), None, num_layers=5,
+            kda_layers=(1, 2), full_attn_layers=(4,),
+        )
+
+
+def test_forward_pass_is_the_references(served):
+    seqs = np.stack([_prompt(SEQ, salt) for salt in range(4)]).astype(np.int32)
+    ex = served.executor
+    values = ex.forward_values(
+        served.params, {"tokens": jnp.asarray(seqs)}, None, train=False
+    )
+    got = np.asarray(values[(ex.logits_ref.guid, ex.logits_ref.out_idx)])
+    for row in (0, 3):
+        assert _gap(got[row], _want(served, seqs[row])) < TOL
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """model-configs guide, section 4: at a small size the routed parts
+    of the result that the shares give, with what every chip computes
+    alike (the shared expert) counted once, add up to what the uncut
+    reference gives for the whole layer."""
+    from flexflow_tpu.ops import moe
+
+    whole = _model(held=None)
+    node = _nodes(whole, OperatorType.SPARSE_MOE)[0]
+    ws = whole.params[node.guid]
+    shared = whole.params[node.guid + 1]
+    m = jax.random.normal(jax.random.PRNGKey(4), (1, 24, 64))
+    routed, chosen = reference.routed_experts(
+        m[0], ws[0], ws[1], ws[2], ws[3], ws[4], K, SCALE
+    )
+    want = routed + reference._gated(m[0], *shared)
+    parts = jnp.zeros_like(m[0])
+    for first in (0, 2, 4, 6):
+        held = dict(node.params, experts_held=(first, 2))
+        part = [ws[0], ws[1][first: first + 2], ws[2][first: first + 2],
+                ws[3][first: first + 2], ws[4]]
+        y, _ = moe.sparse_moe(m, part, held, LowerCtx(train=False))
+        parts = parts + y[0]
+    got = parts + reference._gated(m[0], *shared)
+    assert _gap(got, np.asarray(want)) < TOL
+
+
+# -- serving: prefill, then decode ---------------------------------------------
+
+
+@pytest.mark.parametrize("n", [2, 11, 16, 29])
+def test_prefill_then_decode_is_the_references_forward_pass(served, n):
+    """Prompts shorter than the kernel and a chunk (2), across a chunk
+    (11), of whole chunks (16), and decode across chunk and page ends."""
+    _, engine, cache = _serve(served)
+    prompt = _prompt(n)
+    slot = cache.alloc(n, n + 12)
+    nxt, last = engine.prefill(served.params, [prompt], [slot])
+    seq, got, tok = list(prompt), [last[0]], int(nxt[0])
+    for _ in range(12):
+        seq.append(tok)
+        logits = _step(engine, served, {slot: tok})
+        got.append(logits[slot])
+        tok = int(np.argmax(logits[slot]))
+    want = _want(served, seq, list(range(n - 1, n + 12)))
+    assert _gap(np.stack(got), want) < TOL
+    assert engine._decode_jit._cache_size() == 1
+    assert engine.state_rows_decode == 12 * 4
+    assert engine.state_resets_prefill == 4
+
+
+def test_packed_admissions_serve_each_prompt_as_if_alone(served):
+    """Four prompts in one row, each on a chunk boundary (5 -> 8, 17 -> 24,
+    3 -> 8, 9 -> 16 tokens laid: the 64 bucket), then decoded together."""
+    _, engine, cache = _serve(served)
+    prompts = [_prompt(n, salt) for salt, n in enumerate((5, 17, 3, 9))]
+    slots = [cache.alloc(len(p), len(p) + 6) for p in prompts]
+    nxt, last = engine.prefill(served.params, prompts, slots)
+    assert engine.prefill_programs == 1
+    assert engine.prefill_tokens_padded == 64 and engine.prefill_tokens_real == 34
+    seqs = [list(p) for p in prompts]
+    got = [[row] for row in last]
+    toks = [int(t) for t in nxt]
+    for _ in range(6):
+        for seq, tok in zip(seqs, toks):
+            seq.append(tok)
+        logits = _step(engine, served, dict(zip(slots, toks)))
+        for i, slot in enumerate(slots):
+            got[i].append(logits[slot])
+        toks = [int(np.argmax(logits[slot])) for slot in slots]
+    for seq, prompt, rows in zip(seqs, prompts, got):
+        n = len(prompt)
+        assert _gap(np.stack(rows), _want(served, seq, list(range(n - 1, n + 6)))) < TOL
+
+
+def test_the_routers_choice_of_a_packed_row_is_read_from_where_prompts_lie(served):
+    """`engine.moe_choice["prefill"]` lays the packed row's choice out by
+    request: a prompt's tokens begin on its chunk boundary, not where the
+    last prompt ended."""
+    _, engine, cache = _serve(served)
+    prompts = [_prompt(n, salt) for salt, n in enumerate((5, 17, 3))]
+    slots = [cache.alloc(len(p), len(p) + 1) for p in prompts]
+    engine.prefill(served.params, prompts, slots)
+    picked = np.asarray(engine.moe_choice["prefill"])
+    assert picked.shape == (4, 3, 17, K)
+    for row, prompt in enumerate(prompts):
+        _, chosen = reference.run(
+            _weights(served), prompt, SEQ, EPS, ROPE, K, SCALE, HELD
+        )
+        got = picked[:, row, : len(prompt)]
+        assert np.array_equal(np.sort(got, -1), np.sort(chosen, -1))
+        assert (picked[:, row, len(prompt):] == -1).all()
+
+
+def test_an_admission_over_the_largest_bucket_is_split_by_laid_lengths(served):
+    _, engine, cache = _serve(served)
+    prompts = [_prompt(n, salt) for salt, n in enumerate((25, 25, 9))]
+    slots = [cache.alloc(len(p), len(p) + 2) for p in prompts]
+    _, last = engine.prefill(served.params, prompts, slots)
+    # 32 + 32 fill the 64 bucket; the third (16 laid) is a program of its own
+    assert engine.prefill_programs == 2
+    for prompt, row in zip(prompts, last):
+        assert _gap(row, _want(served, prompt, [len(prompt) - 1])[0]) < TOL
+
+
+# -- the per-slot state's contract ------------------------------------------------
+
+
+def _poison(cache, slot=None):
+    """NaN in every state row (of one slot)."""
+    at = slice(None) if slot is None else slot
+    cache.state = {
+        g: {name: a.at[at].set(jnp.nan) for name, a in rows.items()}
+        for g, rows in cache.state.items()
+    }
+
+
+def test_c1_a_prefill_starts_from_zero_and_never_reads_the_row(served):
+    _, engine, cache = _serve(served)
+    _poison(cache)
+    prompt = _prompt(13)
+    slot = cache.alloc(13, 20)
+    _, last = engine.prefill(served.params, [prompt], [slot])
+    assert _gap(last[0], _want(served, prompt, [12])[0]) < TOL
+    state = _state(cache)
+    for (g, name), a in state.items():
+        assert np.isfinite(a[slot]).all(), (g, name)
+        others = np.delete(a, slot, axis=0)
+        assert np.isnan(others).all()
+    # and the row is the prompt's: a fresh engine's, bit for bit
+    _, engine2, cache2 = _serve(served)
+    slot2 = cache2.alloc(13, 20)
+    engine2.prefill(served.params, [prompt], [slot2])
+    for key, a in _state(cache2).items():
+        assert np.array_equal(a[slot2], state[key][slot])
+
+
+def test_c2_a_decode_step_touches_its_active_slots_rows_only(served):
+    _, engine, cache = _serve(served)
+    prompts = [_prompt(9, 1), _prompt(12, 2)]
+    slots = [cache.alloc(len(p), len(p) + 4) for p in prompts]
+    nxt, _ = engine.prefill(served.params, prompts, slots)
+    idle = [s for s in range(4) if s not in slots]
+    _poison(cache, idle[0])
+    before = _state(cache)
+    # only the first is active: the second was admitted and joins the NEXT
+    # step (the default loop's order), free rows keep what they hold
+    _step(engine, served, {slots[0]: int(nxt[0])})
+    after = _state(cache)
+    for key in before:
+        assert not np.array_equal(after[key][slots[0]], before[key][slots[0]])
+        for s in [slots[1]] + idle:
+            assert np.array_equal(after[key][s], before[key][s], equal_nan=True)
+
+
+def test_c3_a_stale_step_on_a_freed_slot_cannot_corrupt_the_newcomer(served):
+    """The default loop keeps a decode step in flight. A request ends on
+    EOS while the next step, dispatched with its slot still active, is on
+    its way: that step writes the freed slot's row. The slot's next
+    request's prefill is dispatched behind it and overwrites the whole
+    row (C1), so the newcomer's tokens are a fresh scheduler's."""
+    probe = _serve(served)[0]
+    first = Request(rid=1, prompt=_prompt(7, 3), max_new_tokens=6)
+    probe.run([first])
+    eos = first.generated[2]  # ends the same request after three tokens
+
+    def serve(requests):
+        sched, engine, cache = _serve(served, max_seqs=1)
+        done = sched.run(requests)
+        assert all(r.status == "finished" for r in done)
+        return sched, engine
+
+    early = Request(rid=1, prompt=_prompt(7, 3), max_new_tokens=6, eos_token=eos)
+    late = Request(rid=2, prompt=_prompt(10, 4), max_new_tokens=8)
+    sched, engine = serve([early, late])
+    assert early.generated == first.generated[:3]
+    # the step in flight when EOS was read was thrown away, on slot 0,
+    # which the second request then took
+    assert sched.stats.decode_slot_steps_discarded >= 1
+    alone = Request(rid=2, prompt=_prompt(10, 4), max_new_tokens=8)
+    serve([alone])
+    assert late.generated == alone.generated
+    seq = list(late.prompt) + late.generated[:-1]
+    want = np.argmax(_want(served, seq, list(range(9, 17))), -1)
+    assert late.generated == want.tolist()
+
+
+def test_c4_free_has_no_device_work_and_a_recompute_rebuilds_the_state(served):
+    _, engine, cache = _serve(served)
+    prompt = _prompt(14, 5)
+    slot = cache.alloc(14, 30)
+    nxt, _ = engine.prefill(served.params, [prompt], [slot])
+    seq, tok = list(prompt), int(nxt[0])
+    for _ in range(5):
+        seq.append(tok)
+        tok = int(np.argmax(_step(engine, served, {slot: tok})[slot]))
+    held = {key: id(a) for key, a in (
+        ((g, n), a) for g, rows in cache.state.items() for n, a in rows.items()
+    )}
+    kept = _state(cache)
+    cache.free(slot)
+    assert held == {
+        (g, n): id(a) for g, rows in cache.state.items() for n, a in rows.items()
+    }
+    # preemption by recompute: the history is prefilled again, into
+    # whichever slot, and the state is what decode had made of it
+    again = cache.alloc(len(seq), 30)
+    _, last = engine.prefill(served.params, [seq], [again])
+    assert _gap(last[0], _want(served, seq, [len(seq) - 1])[0]) < TOL
+    for key, a in _state(cache).items():
+        assert np.allclose(a[again], kept[key][slot], atol=1e-5), key
+
+
+@pytest.mark.parametrize("what,config", [
+    ("speculative", dict(spec_draft="ngram")),
+    ("chunked-prefill", dict(token_budget=32, chunk_size=16)),
+    ("multi-step", dict(decode_multistep=True)),
+    ("kv_swap", dict(kv_swap=True, kv_swap_bytes=1 << 20)),
+    ("int8", dict(kv_dtype="int8")),
+    ("prefix_cache", dict(prefix_cache=True)),
+    ("adapters", dict(adapters=2)),
+])
+def test_c5_what_cannot_keep_the_state_is_refused_in_words(served, what, config):
+    with pytest.raises(ValueError, match="recurrent"):
+        _serve(served, **config)
+
+
+@pytest.mark.parametrize("call", [
+    lambda e, c, p: e._verify_fn(3),
+    lambda e, c, p: e._tree_fn(3),
+    lambda e, c, p: e._chunk_fn((1, 8)),
+    lambda e, c, p: e.prefill_suffix(p, [_prompt(9)], [0], [4]),
+    lambda e, c, p: e.decode_multi_dispatch(
+        p, np.zeros(4, np.int32), np.ones(4, bool), np.full(4, 2, np.int32)
+    ),
+    lambda e, c, p: e.require("draft"),
+    lambda e, c, p: c.truncate(0, 4),
+    lambda e, c, p: c.swap_out(0),
+    lambda e, c, p: c.swap_in(0),
+    lambda e, c, p: c.import_swap({}),
+], ids=["verify", "tree", "chunk", "suffix", "multistep", "draft", "truncate",
+        "swap_out", "swap_in", "import_swap"])
+def test_c5_the_step_kinds_and_cache_calls_raise_not_serve(served, call):
+    _, engine, cache = _serve(served)
+    cache.alloc(9, 12)
+    with pytest.raises(ValueError, match="recurrent"):
+        call(engine, cache, served.params)
+
+
+def test_c5_a_serving_mesh_is_refused_in_words():
+    model = _model()  # compile_for_serving re-places the model: its own
+    # the latent layers' refusal of a mesh speaks first; either is in words
+    with pytest.raises(ValueError, match="serving mesh .* is not supported"):
+        _serve(model, serve_mesh="1,1")
+
+
+def test_c5_buckets_must_be_whole_chunks(served):
+    with pytest.raises(ValueError, match="whole chunks"):
+        _serve(served, prefill_buckets=(12, 64))
+
+
+# -- the loops ------------------------------------------------------------------
+
+
+def _requests():
+    return [
+        Request(rid=i, prompt=_prompt(n, i), max_new_tokens=m)
+        for i, (n, m) in enumerate(((6, 9), (19, 5), (3, 12), (11, 7), (8, 4), (27, 6)))
+    ]
+
+
+def test_sync_and_async_loops_are_token_identical_and_the_references(served):
+    outs = []
+    for serve_async in (True, False):
+        sched, engine, _ = _serve(served, serve_async=serve_async)
+        reqs = _requests()
+        done = sched.run(reqs)
+        assert all(r.status == "finished" for r in done)
+        outs.append([list(r.generated) for r in reqs])
+        if serve_async:
+            assert sched.stats.decode_steps_chained > 0
+            assert sched.stats.state_rows_decode == engine.state_rows_decode > 0
+            assert sched.stats.state_resets_prefill == 6 * 4
+        assert engine._decode_jit._cache_size() == 1
+    assert outs[0] == outs[1]
+    for r, got in zip(_requests(), outs[0]):
+        seq = list(r.prompt) + got[:-1]
+        n = len(r.prompt)
+        want = np.argmax(_want(served, seq, list(range(n - 1, len(seq)))), -1)
+        assert got == want.tolist()
+
+
+def test_the_state_is_priced_and_published(served):
+    serve = ServeConfig(
+        max_seqs=4, max_seq_len=SEQ, prefill_buckets=BUCKETS, telemetry=True
+    )
+    sched, engine, cache = build_scheduler(served, serve)
+    spec = cache.spec
+    assert len(spec.state_guids) == 4 and len(spec.layer_guids) == 1
+    per_slot = 4 * 4 * (4 * 16 * 16 + 3 * 3 * 64)
+    assert spec.state_bytes_per_slot == per_slot
+    assert spec.total_bytes == spec.bytes_per_layer + 4 * per_slot
+    assert cache.state[spec.state_guids[0]]["S"].shape == (4, 4, 16, 16)
+    assert cache.state[spec.state_guids[0]]["conv"].shape == (4, 3, 192)
+    reg = sched.telemetry.registry
+    assert reg.gauge("serve_state_layers").value == 4
+    assert reg.gauge("serve_state_bytes").value == 4 * per_slot

@@ -226,9 +226,8 @@ def case_scopes_carry_pass_and_layer(model):
     run_step = engine._run_step
 
     def lowering(site, step_fn, params, inputs, adapter_args=(), **kw):
-        pools = (cache.k, cache.v, cache.k_scale, cache.v_scale)
         texts.append(
-            step_fn().lower(params, *inputs, *pools, *adapter_args)
+            step_fn().lower(params, *inputs, *cache.pools, *adapter_args)
             .as_text(debug_info=True)
         )
         return run_step(site, step_fn, params, inputs, adapter_args, **kw)
