@@ -18,9 +18,11 @@ layer's input (already normalised) and H heads of d = `head_dim`:
 
 One function's two computations out of shared helpers, as "Latent
 attention" (ops/attention.py): CHUNKED (`kda_chunked`: a `lax.scan` over
-chunks of C tokens with matmuls inside, the operator's lowering and a
-serving prefill) and ONE STEP (`kda_step`: a serving decode step, over
-the per-slot state `serving/kv_cache.py` keeps). Weights, in order: Wq,
+chunks of C tokens, each cut into sub-blocks of 16 so that all but the
+[16, 16, d] decays on a chunk's diagonal is matmuls, its triangular system
+included; the operator's lowering and a serving prefill) and ONE STEP
+(`kda_step`: a serving decode step, over the per-slot state
+`serving/kv_cache.py` keeps). Weights, in order: Wq,
 Wk, Wv [e, H d]; the three convolutions [K, H d] (taps-major: a tap is
 one dense row of channels); Wfa [e, r], Wfb
 [r, H d], dt_bias [H d], A_log [H]; Wb [e, H]; Wga [e, r], Wgb [r, H d];
@@ -32,12 +34,16 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from flexflow_tpu.core.parallel_tensor import ParallelDim, ParallelTensorShape
 from flexflow_tpu.core.types import OperatorType
 from flexflow_tpu.ops.registry import mm_operands, mm_out_dtype, register_op
 
 _MM = dict(preferred_element_type=jnp.float32)
+# a matmul that stands where the recurrence had an elementwise float32 sum
+_EXACT = dict(_MM, precision=jax.lax.Precision.HIGHEST)
+_SUB = 16  # tokens of a sub-block of a chunk (`kda_chunked`)
 
 
 def _kda_dims(params):
@@ -148,6 +154,73 @@ def kda_conv(qkv, tails, ws, params, taps=None):
         return q, unit(k).astype(qkv.dtype), v.astype(qkv.dtype), xs[:, s:]
 
 
+def _kda_chunk_terms(qc, kc, vc, gc, bc, sub):
+    """What a chunk of `kda_chunked` needs before it meets the carried
+    state: qc, kc, vc, gc [..., C, d], bc [..., C] -> (tril(B) [..., C, C];
+    w = T (beta k exp G), u_v = T (beta v), q exp G, k exp(G_last - G)
+    [..., C, d]; exp G_last [..., d]), with T = (I + diag(beta)
+    tril(A, -1))^-1, in sub-blocks of `sub` tokens (C a multiple)."""
+    lead, (c, d) = kc.shape[:-2], kc.shape[-2:]
+    m = c // sub
+    run = jnp.cumsum(gc, axis=-2)  # G
+
+    def blocks(t):  # [..., C, ...] -> [..., m, sub, ...]
+        return t.reshape(lead + (m, sub) + t.shape[len(lead) + 1:])
+
+    runb, kb, qb = blocks(run), blocks(kc), blocks(qc)
+    # inside a sub-block, elementwise: exp(G_t[c] - G_j[c]) for j <= t
+    kd = kb[..., None, :, :] * jnp.exp(
+        jnp.minimum(runb[..., :, None, :] - runb[..., None, :, :], 0.0)
+    )
+    a_in = jnp.sum(kb[..., :, None, :] * kd, -1)  # [..., m, sub, sub]
+    b_in = jnp.sum(qb[..., :, None, :] * kd, -1)
+    # between sub-blocks, one matmul through G_ref, G at the last token
+    # before the row's sub-block: exp(G_t - G_ref) exp(G_ref - G_j)
+    ref = jnp.pad(
+        runb[..., :-1, -1:, :], [(0, 0)] * len(lead) + [(1, 0), (0, 0), (0, 0)]
+    )
+    near = jnp.exp(runb - ref)
+    far = kc[..., None, :, :] * jnp.exp(
+        jnp.minimum(ref - run[..., None, :, :], 0.0)
+    )
+    out = jnp.einsum(
+        "...tc,...jc->...tj", jnp.concatenate([kb * near, qb * near], -2), far,
+        **_EXACT,
+    )  # [..., m, 2 sub, C]: A's rows over B's
+    # masks as constants [m, sub, C]: the compiler computes nothing for them
+    row, col = np.arange(c).reshape(m, sub, 1), np.arange(c)
+    before, same = col // sub < row // sub, col // sub == row // sub
+    bm = jnp.where(
+        before, out[..., sub:, :],
+        jnp.where(same & (col <= row), jnp.tile(b_in, m), 0.0),
+    ).reshape(lead + (c, c))
+    # T: each sub-block's own inverse by substitution, row by row, then
+    # block forward substitution over the sub-blocks
+    beta = blocks(bc)[..., None]
+    l_in = beta * jnp.where(np.tril(np.ones((sub, sub), bool), -1), a_in, 0.0)
+    l_out = beta * jnp.where(before, out[..., :sub, :], 0.0)
+    inv = jnp.broadcast_to(jnp.eye(sub), l_in.shape)
+    for r in range(1, sub):
+        inv = inv.at[..., r, :].add(
+            -jnp.sum(l_in[..., r, :r, None] * inv[..., :r, :], -2)
+        )
+    eye, t = np.eye(c, dtype=np.float32), jnp.zeros(lead + (c, c))
+    for i in range(m):
+        rows = slice(i * sub, (i + 1) * sub)
+        rest = eye[rows]
+        if i:
+            rest = rest - jnp.matmul(l_out[..., i, :, :], t, **_EXACT)
+        t = t.at[..., rows, :].set(jnp.matmul(inv[..., i, :, :], rest, **_EXACT))
+    grown = jnp.exp(run)
+    wu = jnp.matmul(
+        t, bc[..., None] * jnp.concatenate([kc * grown, vc], -1), **_EXACT
+    )
+    return (
+        bm, wu[..., :d], wu[..., d:], qc * grown,
+        kc * jnp.exp(run[..., -1:, :] - run), grown[..., -1, :],
+    )
+
+
 def kda_chunked(q, k, v, g, beta, state, reset=None, chunk=64):
     """The recurrence over a sequence, in chunks of `chunk` tokens: q, k, v,
     g [b, s, H, d], beta [b, s, H], state [b, H, d, d] (the state before
@@ -163,13 +236,30 @@ def kda_chunked(q, k, v, g, beta, state, reset=None, chunk=64):
         (I + diag(beta) tril(A, -1)) u = beta (v - (k exp G) S_0),
         o = (q exp G) S_0 + tril(B) u,
         A_tj = sum_c k_t[c] k_j[c] exp(G_t[c] - G_j[c]),  B likewise with q_t.
-    Every decay is the exponential of a NON-POSITIVE difference G_t - G_j
-    (j <= t), never a quotient of two exponentiated sums: exp(-G_j)
-    overflows float32 after a few tokens at the decays A_log allows.
-    Float32 throughout. Differentiable; a chunk's [C, C, d] decays are
-    recomputed in the backward pass, not kept for every chunk."""
+    Every decay is the exponential of a NON-POSITIVE difference, never a
+    quotient of two exponentiated sums: exp(-G_j) overflows float32 after
+    a few tokens at the decays A_log allows. A chunk is cut into
+    sub-blocks of `_SUB` tokens (one, where `chunk` is no multiple). Only
+    the diagonal [sub, sub] blocks of A and B are elementwise sums over
+    [sub, sub, d] decays; the blocks below them are a matmul of
+    k_t exp(G_t - G_ref) with k_j exp(G_ref - G_j), G_ref the G of the
+    last token before the row's sub-block: it lies between the two, so
+    both exponents stay non-positive. The system is solved by matmuls
+    too: T = (I + diag(beta) tril(A, -1))^-1 from the diagonal blocks' own
+    inverses (by substitution: a series in the powers of tril(A, -1)
+    loses every digit where keys repeat) and block forward substitution,
+    so u = T beta v - (T beta k exp G) S_0. The matmuls that stand where
+    elementwise float32 sums stood (A's and B's blocks, T and its two
+    products) run at `HIGHEST` whatever the ambient precision; the three
+    products with the state and B u at the ambient one, as they always
+    did. A chunk's terms do not read the state, yet stay in the scan:
+    computed for all chunks at once they leave the chip's fast memory
+    and cost more, and for a few at once no less (PERF.md, PR 41).
+    Float32 throughout. Differentiable; a
+    chunk's decays are recomputed in the backward pass, not kept."""
     b, s, h, d = q.shape
     n = s // chunk
+    sub = _SUB if chunk % _SUB == 0 else chunk
     f32 = jnp.float32
 
     def chunks(t):  # [b, s, H, ...] -> [n, b, H, C, ...]
@@ -179,36 +269,17 @@ def kda_chunked(q, k, v, g, beta, state, reset=None, chunk=64):
     resets = (
         jnp.zeros((n, b), bool) if reset is None else jnp.moveaxis(reset, 1, 0)
     )
-    low = jnp.tril(jnp.ones((chunk, chunk), bool))
-    strict = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
 
     @jax.checkpoint
     def one(carry, xs):
-        qc, kc, vc, gc, bc, fresh = xs
+        *raw, fresh = xs
+        bm, w, uv, qg, left, last = _kda_chunk_terms(*raw, sub)
         s0 = jnp.where(fresh[:, None, None, None], 0.0, carry)
-        run = jnp.cumsum(gc, axis=2)  # G [b, H, C, d]
-        # decay[t, j, c] = exp(G_t[c] - G_j[c]) for j <= t (else unused)
-        decay = jnp.exp(
-            jnp.minimum(run[:, :, :, None, :] - run[:, :, None, :, :], 0.0)
-        )
-        kd = kc[:, :, None, :, :] * decay
-        a = jnp.where(strict, jnp.sum(kc[:, :, :, None, :] * kd, -1), 0.0)
-        bm = jnp.where(low, jnp.sum(qc[:, :, :, None, :] * kd, -1), 0.0)
-        grown = jnp.exp(run)
-        rhs = bc[..., None] * (
-            vc - jnp.einsum("bhtc,bhcv->bhtv", kc * grown, s0, **_MM)
-        )
-        u = jax.scipy.linalg.solve_triangular(
-            jnp.eye(chunk, dtype=f32) + bc[..., None] * a, rhs,
-            lower=True, unit_diagonal=True,
-        )
-        o = jnp.einsum("bhtc,bhcv->bhtv", qc * grown, s0, **_MM) + jnp.einsum(
+        u = uv - jnp.einsum("bhtc,bhcv->bhtv", w, s0, **_MM)
+        o = jnp.einsum("bhtc,bhcv->bhtv", qg, s0, **_MM) + jnp.einsum(
             "bhtj,bhjv->bhtv", bm, u, **_MM
         )
-        left = jnp.exp(run[:, :, -1:, :] - run)  # what is left at the end
-        s1 = grown[:, :, -1, :, None] * s0 + jnp.einsum(
-            "bhtc,bhtv->bhcv", kc * left, u, **_MM
-        )
+        s1 = last[..., None] * s0 + jnp.einsum("bhtc,bhtv->bhcv", left, u, **_MM)
         return s1, (o, s1)
 
     with jax.named_scope("kda.scan"):

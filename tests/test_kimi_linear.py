@@ -150,57 +150,99 @@ def _kda_inputs(b, s, h, d, seed=0, strength=1.0):
     return q, k, v, g, beta
 
 
+@jax.jit
 def _by_steps(q, k, v, g, beta, state):
-    outs = []
-    for t in range(q.shape[1]):
-        o, state = L.kda_step(q[:, t], k[:, t], v[:, t], g[:, t], beta[:, t], state)
-        outs.append(o)
-    return jnp.stack(outs, 1), state
+    def one(state, token):
+        o, state = L.kda_step(*token, state)
+        return state, o
+
+    tokens = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    state, outs = jax.lax.scan(one, state, tokens)
+    return jnp.moveaxis(outs, 0, 1), state
 
 
-@pytest.mark.parametrize("strength", [1.0, 300.0])
-def test_chunked_form_is_the_one_step_form(strength):
-    """Also at decays whose cumulative product underflows float32 within
-    a chunk (exp(-300) a token): every decay inside a chunk is the
-    exponential of a non-positive difference, so nothing overflows."""
-    args = _kda_inputs(2, 40, 3, 8, strength=strength)
-    zero = jnp.zeros((2, 3, 8, 8))
+_chunked = jax.jit(L.kda_chunked, static_argnames="chunk")
+
+
+# (chunk, head_dim): a chunk of 8 is ONE sub-block; a chunk of 64 is four
+# of 16 with matmuls between them, at a toy head and at the served one
+FORMS = [(CHUNK, 8), (64, 8), (64, 128)]
+
+
+def _heads(d):
+    return 3 if d == 8 else 2
+
+
+@pytest.mark.parametrize("chunk,d", FORMS)
+@pytest.mark.parametrize("strength", [0.03, 1.0, 300.0])
+def test_chunked_form_is_the_one_step_form(strength, chunk, d):
+    """At a decay that forgets little in a chunk (0.03: the long memory
+    that stresses the inverse), and at decays whose cumulative product
+    underflows float32 within a chunk (exp(-300) a token): every decay
+    inside a chunk is the exponential of a non-positive difference, so
+    nothing overflows."""
+    h, n = _heads(d), 5 if chunk == CHUNK else 3
+    args = _kda_inputs(2, n * chunk, h, d, strength=strength)
+    zero = jnp.zeros((2, h, d, d))
     want, last = _by_steps(*args, zero)
-    got, states = L.kda_chunked(*args, zero, chunk=CHUNK)
+    got, states = _chunked(*args, zero, chunk=chunk)
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.abs(got - want).max()) < 1e-5
     assert float(jnp.abs(states[:, -1] - last).max()) < 1e-5
-    assert states.shape == (2, 5, 3, 8, 8)
+    assert states.shape == (2, n, h, d, d)
 
 
-def test_a_token_is_made_a_no_op_by_data_and_a_chunk_resets_by_flag():
+@pytest.mark.parametrize("chunk,d", FORMS)
+def test_chunked_form_holds_on_one_token_repeated(chunk, d):
+    """A prompt of one token repeated gives every position the same key,
+    so tril(A, -1) is as far from small as it gets and a series in its
+    powers (a Neumann product for the inverse) loses every digit; the
+    substitution the chunked form does is held to the one-step form."""
+    h = _heads(d)
+    q, k, v, g, beta = _kda_inputs(1, 3 * chunk, h, d, seed=2, strength=0.03)
+    q, k = (jnp.broadcast_to(t[:, :1], t.shape) for t in (q, k))
+    beta = jax.nn.sigmoid(jax.scipy.special.logit(beta) + 3.0)
+    zero = jnp.zeros((1, h, d, d))
+    want, last = _by_steps(q, k, v, g, beta, zero)
+    got, states = _chunked(q, k, v, g, beta, zero, chunk=chunk)
+    limit = 1e-5 * max(1.0, float(jnp.abs(want).max()))
+    assert float(jnp.abs(got - want).max()) < limit
+    assert float(jnp.abs(states[:, -1] - last).max()) < limit
+
+
+@pytest.mark.parametrize("chunk,d", FORMS)
+def test_a_token_is_made_a_no_op_by_data_and_a_chunk_resets_by_flag(chunk, d):
     """What a packed row needs of one program: padding (beta = 0, g = 0,
     k = 0) leaves the state alone, and a chunk that starts another prompt
     starts from zero whatever was carried."""
-    q, k, v, g, beta = _kda_inputs(1, 24, 2, 8, seed=3)
-    pad = jnp.arange(24) >= 19  # the last chunk's tail is padding
+    h, s, real = _heads(d), 3 * chunk, 2 * chunk + 3
+    q, k, v, g, beta = _kda_inputs(1, s, h, d, seed=3)
+    pad = jnp.arange(s) >= real  # the last chunk's tail is padding
     k = jnp.where(pad[None, :, None, None], 0, k)
     g = jnp.where(pad[None, :, None, None], 0, g)
     beta = jnp.where(pad[None, :, None], 0, beta)
-    zero = jnp.zeros((1, 2, 8, 8))
-    _, states = L.kda_chunked(q, k, v, g, beta, zero, chunk=CHUNK)
-    _, want = _by_steps(*(a[:, :19] for a in (q, k, v, g, beta)), zero)
+    zero = jnp.zeros((1, h, d, d))
+    _, states = _chunked(q, k, v, g, beta, zero, chunk=chunk)
+    _, want = _by_steps(*(a[:, :real] for a in (q, k, v, g, beta)), zero)
     assert float(jnp.abs(states[:, -1] - want).max()) < 1e-5
     # chunks 0-1 are one prompt, chunk 2 another: its state is its own
     reset = jnp.array([[False, False, True]])
-    got, states = L.kda_chunked(q, k, v, g, beta, zero + 5.0, reset, CHUNK)
-    alone, last = _by_steps(*(a[:, 16:19] for a in (q, k, v, g, beta)), zero)
-    assert float(jnp.abs(got[:, 16:19] - alone).max()) < 1e-5
+    got, states = _chunked(q, k, v, g, beta, zero + 5.0, reset, chunk)
+    third = slice(2 * chunk, real)
+    alone, last = _by_steps(*(a[:, third] for a in (q, k, v, g, beta)), zero)
+    assert float(jnp.abs(got[:, third] - alone).max()) < 1e-5
     assert float(jnp.abs(states[:, 2] - last).max()) < 1e-5
 
 
-def test_chunked_gradients_are_the_one_step_forms():
-    args = _kda_inputs(1, 16, 2, 8, seed=5)
-    zero = jnp.zeros((1, 2, 8, 8))
-    w = jax.random.normal(jax.random.PRNGKey(9), (1, 16, 2, 8))
+@pytest.mark.parametrize("chunk,d", FORMS)
+def test_chunked_gradients_are_the_one_step_forms(chunk, d):
+    h, s = _heads(d), 3 * chunk
+    args = _kda_inputs(1, s, h, d, seed=5)
+    zero = jnp.zeros((1, h, d, d))
+    w = jax.random.normal(jax.random.PRNGKey(9), (1, s, h, d))
 
     def chunked(*a):
-        return jnp.sum(L.kda_chunked(*a, zero, chunk=CHUNK)[0] * w)
+        return jnp.sum(_chunked(*a, zero, chunk=chunk)[0] * w)
 
     def stepped(*a):
         return jnp.sum(_by_steps(*a, zero)[0] * w)
@@ -209,6 +251,27 @@ def test_chunked_gradients_are_the_one_step_forms():
     want = jax.grad(stepped, argnums=(0, 1, 2, 3, 4))(*args)
     for a, b in zip(got, want):
         assert float(jnp.abs(a - b).max()) < 1e-4 * max(1.0, float(jnp.abs(b).max()))
+
+
+def test_the_served_shape_has_no_chunk_wide_decays_and_no_triangular_solve():
+    """What the scan's share of its roofline says on the chip, held here
+    by the program's text: at a served layer's shape a chunk's decays are
+    [16, 16, d] pieces, never [C, C, d], and its inverse is matmuls."""
+    b, s, h, d, chunk = 1, 256, 32, 128, 64
+    row = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32)
+    text = jax.jit(
+        lambda q, k, v, g, beta, state: L.kda_chunked(
+            q, k, v, g, beta, state, chunk=chunk
+        )
+    ).lower(
+        row, row, row, row, jax.ShapeDtypeStruct((b, s, h), jnp.float32),
+        jax.ShapeDtypeStruct((b, h, d, d), jnp.float32),
+    ).as_text()
+    # `stablehlo.triangular_solve` on a TPU, `@_solve_triangular` calling
+    # lapack's `strsm` here
+    assert "triangular" not in text and "trsm" not in text
+    assert f"x{chunk}x{chunk}x{d}xf32>" not in text
+    assert f"x16x16x{d}xf32>" in text and "dot_general" in text
 
 
 @pytest.mark.parametrize("n", [1, 3, 5, 8, 21, 40])
