@@ -16,6 +16,12 @@ Design rules:
   backend and fans committed tokens out to per-request queues; client
   coroutines only await their own queue. The engine never runs
   per-client — exactly the continuous-batching posture.
+* **An arrival crosses the door between two steps.** `step()` holds
+  the event loop for a whole engine iteration, so between two steps
+  the pump yields `FrontDoor.PASSES` passes of the loop: enough for a
+  request that fell due during the last step to reach `submit` before
+  the next one (the derivation is at `PASSES`). What one thread
+  cannot remove is the `step()` in progress when the request arrives.
 * **Disconnect is cancel.** A client that stops consuming its stream
   (GeneratorExit / connection reset) cancels its request; the
   scheduler's deferred-cancel semantics retire it at the next safe
@@ -100,7 +106,28 @@ class FrontDoor:
       its weighted share (`backend.classes` weights; equal shares
       without them) is SHED: an immediate `done(status="shed")` event
       with a `retry_after_s` hint, never submitted to the engine and
-      never journaled — so the retry is clean."""
+      never journaled — so the retry is clean.
+
+    `submits_by_pass` (`serve_door_submits_total{pass}` in the backend's
+    telemetry) counts the submissions handed to the backend by WHEN
+    they crossed the door: "idle" with no iteration running, else the
+    pass of the loop since the last `step()` (1..`PASSES`). Passes 2
+    and later are arrivals that a pump yielding once would have held
+    for another whole iteration, or several."""
+
+    #: Passes of the event loop the pump yields between two `step()`s.
+    #: `step()` blocks the loop for a whole engine iteration, and every
+    #: hop of an arrival's chain is appended to the loop's ready list
+    #: BEHIND the pump's own resumption, so a hop runs in the pass the
+    #: pump ends by yielding once more. The longest chain the door
+    #: serves is the open-loop client's: (1) the timer's (for
+    #: `serve_tcp`, the socket's) callback fires, (2) the task sleeping
+    #: on it wakes and starts a client task, (3) that task calls
+    #: `submit`; a task started in pass p is queued behind the pump's
+    #: resumption in pass p + 1, so its `submit` needs the pump to yield
+    #: a fourth time. Three are not enough, a fifth buys nothing. A fact
+    #: about asyncio's ready list, not a tuning value: nothing sets it.
+    PASSES = 4
 
     def __init__(
         self,
@@ -122,6 +149,9 @@ class FrontDoor:
         # (or the journal it recovered from) knows
         self._keys: Dict[str, int] = {}
         self.shed_total: Dict[str, int] = {}
+        # pass of the loop since the last step(); 0 = no iteration running
+        self._pass = 0
+        self.submits_by_pass: Dict[str, int] = {}
         self.recovered_requests = 0
         self.replayed_tokens = 0
         reg = self._registry()
@@ -144,6 +174,16 @@ class FrontDoor:
         if tele is not None and getattr(tele, "enabled", False):
             return tele.registry
         return None
+
+    def _count(
+        self, tally: Dict[str, int], name: str, help: str, key: str, label: str
+    ) -> None:
+        """One more under `label`: in the door's own tally (tests, the
+        probe) and in the backend's telemetry as `name{key=label}`."""
+        tally[label] = tally.get(label, 0) + 1
+        reg = self._registry()
+        if reg is not None:
+            reg.counter(name, help=help, labels={key: label}).inc()
 
     def _adopt(self, recovery, restore_decider=None) -> None:
         """Rebuild the live set from a journal RecoveryState: re-admit
@@ -286,15 +326,13 @@ class FrontDoor:
                 )
             )
             self._done.add(rid)
-            label = cls or "default"
-            self.shed_total[label] = self.shed_total.get(label, 0) + 1
-            reg = self._registry()
-            if reg is not None:
-                reg.counter(
-                    "serve_shed_total",
-                    help="admissions shed at the front door, by class",
-                    labels={"class": label},
-                ).inc()
+            self._count(
+                self.shed_total,
+                "serve_shed_total",
+                "admissions shed at the front door, by class",
+                "class",
+                cls or "default",
+            )
             return rid
         rid = self._next_rid
         self._next_rid += 1
@@ -314,6 +352,14 @@ class FrontDoor:
         self._published[rid] = 0
         if request_key:
             self._keys[request_key] = rid
+        self._count(
+            self.submits_by_pass,
+            "serve_door_submits_total",
+            "submissions handed to the backend, by the pass of the event "
+            "loop since the last step() (idle: no iteration was running)",
+            "pass",
+            str(self._pass) if self._pass else "idle",
+        )
         self.backend.submit(req)
         self._ensure_pump()
         self._publish()  # a rejected submit is terminal already
@@ -347,11 +393,7 @@ class FrontDoor:
         """Run the backend until every submitted stream is terminal
         (test/bench convenience — a live server just lets the pump
         idle)."""
-        while self.backend.work_pending():
-            self.backend.step()
-            self._publish()
-            await asyncio.sleep(0)
-        self._publish()
+        await self._iterate()
 
     # -- engine pump ---------------------------------------------------------
 
@@ -359,19 +401,34 @@ class FrontDoor:
         if self._pump_task is None or self._pump_task.done():
             self._pump_task = asyncio.ensure_future(self._pump())
 
-    async def _pump(self) -> None:
-        """THE engine driver: step, publish fresh commits, yield to the
-        event loop (so client coroutines drain their queues between
-        iterations), repeat until idle. Submissions restart it. A
-        backend exception must not strand consumers on silent queues —
-        every live stream gets a failed terminal event before the
-        exception propagates into the task."""
-        try:
-            while self.backend.work_pending():
-                self.backend.step()
-                self._publish()
-                await asyncio.sleep(0)
+    async def _iterate(self) -> None:
+        """Engine iterations until the backend is idle: ONE `step()`,
+        publish its commits, then `PASSES` passes of the event loop
+        before the next. The first pass lets client coroutines drain
+        their queues; all of them let a request that fell due while
+        `step()` held the loop reach `submit`, so the next iteration
+        admits it. Bounded, so a loop that clients keep busy cannot
+        starve the engine."""
+        while self.backend.work_pending():
+            self.backend.step()
             self._publish()
+            for n in range(1, self.PASSES + 1):
+                self._pass = n
+                await asyncio.sleep(0)
+            self._pass = 0
+        self._publish()
+
+    async def _pump(self) -> None:
+        """THE engine driver: iterate (`_iterate`: one `step()`, then
+        `PASSES` passes of the loop, not one, so that an arrival's whole
+        chain runs between two steps) until idle. Submissions restart
+        it; a request still waits for the `step()` in progress when it
+        arrives, which one thread cannot remove. A backend exception
+        must not strand consumers on silent queues — every live stream
+        gets a failed terminal event before the exception propagates
+        into the task."""
+        try:
+            await self._iterate()
         except Exception as exc:
             for rid, queue in list(self._queues.items()):
                 if rid not in self._done:

@@ -12,6 +12,11 @@ LRU orders by age. Sync/fp32 legs are tier 1; the async × int8 matrix
 legs are tier 2 (slow)."""
 
 import asyncio
+import json
+import os
+import socket
+import time
+import types
 
 import jax.numpy as jnp
 import numpy as np
@@ -427,6 +432,250 @@ def test_deadline_reaps_in_every_phase(lm):
         pipe2.step()
     assert probe.status == RequestStatus.TIMED_OUT
     assert "handoff" in [e[1] for e in probe.events]
+
+
+# -- front door: how many iterations an arrival waits --------------------------
+
+
+class _SteppedClock:
+    """A backend whose `step()` records its index and HOLDS the loop:
+    the event loop's clock (`loop.time`, patched in by `_run_stepped`)
+    reads the number of steps run, so a timer set at 2.5 expires
+    during iteration 2 whatever the machine's speed. A request is
+    served one token a step; `submitted_at` is the number of
+    iterations that had run when the request reached the backend."""
+
+    def __init__(self, on_step=None, telemetry=None):
+        self.iterations = 0
+        self.submitted_at = {}
+        self.on_step = on_step
+        self.telemetry = telemetry
+        self._live = []
+
+    def submit(self, req):
+        self.submitted_at[req.rid] = self.iterations
+        req.status = RequestStatus.RUNNING
+        self._live.append(req)
+
+    def cancel(self, rid):
+        return False
+
+    def work_pending(self):
+        return bool(self._live)
+
+    def step(self):
+        if self.on_step is not None:
+            self.on_step(self.iterations)
+        for req in list(self._live):
+            req.generated.append(1)
+            if len(req.generated) >= req.max_new_tokens:
+                req.status = RequestStatus.FINISHED
+                self._live.remove(req)
+        self.iterations += 1
+
+
+def _run_stepped(backend, main):
+    """Run `main(door)` on a loop whose clock is the backend's count of
+    iterations."""
+
+    async def run():
+        asyncio.get_running_loop().time = lambda: float(backend.iterations)
+        return await main(FrontDoor(backend))
+
+    return asyncio.run(run())
+
+
+async def _open_loop_arrival(door, due, max_new_tokens=2):
+    """One request started the way the benchmark's generator starts
+    one: a task sleeping on a timer wakes, starts a client task, and
+    the client awaits `door.submit`."""
+    got = {}
+
+    async def client():
+        got["rid"] = await door.submit([1], max_new_tokens=max_new_tokens)
+
+    await asyncio.sleep(due)
+    await asyncio.ensure_future(client())
+    return got["rid"]
+
+
+@pytest.mark.parametrize("iteration", [0, 2, 5])
+def test_timer_arrival_waits_for_the_step_in_progress_only(iteration):
+    """A request whose timer expires during iteration i is in the
+    backend before iteration i + 1 begins. (A pump that yields one
+    pass between two steps submits it before iteration i + 4: every
+    hop of the arrival's chain queues behind the pump's resumption.)"""
+    backend = _SteppedClock()
+
+    async def main(door):
+        arrival = asyncio.ensure_future(
+            _open_loop_arrival(door, iteration + 0.5)
+        )
+        await door.submit([1], max_new_tokens=12)  # keeps a pump running
+        rid = await arrival
+        await door.drain()
+        return rid
+
+    rid = _run_stepped(backend, main)
+    assert backend.submitted_at[rid] == iteration + 1
+    assert backend.iterations == 12
+
+
+def test_tcp_arrival_waits_for_the_step_in_progress_only():
+    """The same through `serve_tcp`: a submit line written to a
+    loopback socket during iteration i (socket readable -> the
+    connection's handler wakes -> `door.submit`) is in the backend
+    before iteration i + 1 begins (one pass between steps: i + 3)."""
+    from flexflow_tpu.serving import serve_tcp
+
+    wire = {}
+
+    def on_step(i):
+        if i == 2:
+            wire["raw"].sendall(
+                json.dumps(
+                    {"op": "submit", "prompt": [1], "max_new_tokens": 2}
+                ).encode() + b"\n"
+            )
+            time.sleep(0.002)  # hold the loop: the line is delivered
+
+    backend = _SteppedClock(on_step)
+
+    async def main():
+        server = await serve_tcp(backend)
+        port = server.sockets[0].getsockname()[1]
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        # a blocking handle on the client's end, for `step()` to write
+        wire["raw"] = socket.socket(
+            fileno=os.dup(writer.get_extra_info("socket").fileno())
+        )
+        writer.write(
+            json.dumps(
+                {"op": "submit", "prompt": [1], "max_new_tokens": 12}
+            ).encode() + b"\n"
+        )
+        await writer.drain()
+        events = []
+        while sum(e["event"] == "done" for e in events) < 2:
+            events.append(json.loads(await reader.readline()))
+        wire["raw"].close()
+        writer.close()
+        server.close()
+        return events
+
+    events = asyncio.run(main())
+    assert backend.submitted_at == {0: 0, 1: 3}
+    tokens = [e["rid"] for e in events if e["event"] == "token"]
+    assert tokens.count(0) == 12 and tokens.count(1) == 2
+    assert [e["status"] for e in events if e["event"] == "done"] == [
+        "finished", "finished",
+    ]
+
+
+def test_door_counts_submissions_by_pass():
+    """`submits_by_pass` / `serve_door_submits_total{pass}`: every
+    submission handed to the backend is counted once, "idle" when no
+    iteration was running, else by the pass of the loop since the last
+    `step()`; the open loop's arrivals land in passes 2 and later,
+    where a pump yielding one pass could not have served them."""
+    from flexflow_tpu.telemetry.registry import MetricsRegistry, series_name
+
+    registry = MetricsRegistry()
+    backend = _SteppedClock(
+        telemetry=types.SimpleNamespace(enabled=True, registry=registry)
+    )
+
+    async def main(door):
+        arrivals = [
+            asyncio.ensure_future(_open_loop_arrival(door, due))
+            for due in (1.5, 1.6, 4.5, 7.5)
+        ]
+        await door.submit([1], max_new_tokens=10)
+        # a client already awake submits in the FIRST pass after a step
+        async for ev in door.stream(0):
+            if ev.kind == "token":
+                direct = await door.submit([1], max_new_tokens=1)
+                break
+        rids = await asyncio.gather(*arrivals)
+        await door.drain()
+        assert not door._pump_task or door._pump_task.done()
+        # the door stood idle: a submission restarts the pump
+        late = await door.submit([1], max_new_tokens=1)
+        await door.drain()
+        return door, rids + [direct, late]
+
+    door, rids = _run_stepped(backend, main)
+    assert set(backend.submitted_at) == {0, *rids}
+    counts = door.submits_by_pass
+    assert sum(counts.values()) == len(backend.submitted_at) == 7
+    assert set(counts) <= {"idle", *(str(n) for n in range(1, door.PASSES + 1))}
+    assert counts["idle"] == 2 and counts["1"] == 1
+    later = sum(counts.get(str(n), 0) for n in range(2, door.PASSES + 1))
+    assert later == 4  # the four open-loop arrivals
+    assert later / (sum(counts.values()) - counts["idle"]) > 0.5
+    sample = registry.sample()
+    for label, n in counts.items():
+        key = series_name("serve_door_submits_total", {"pass": label})
+        assert sample[key] == n
+
+
+def test_flooded_loop_cannot_starve_the_engine():
+    """The passes are bounded: with callbacks that reschedule
+    themselves for ever and tasks that never sleep, the engine still
+    steps after `PASSES` passes of the loop, and a request finishes."""
+    ticks = {"tasks": 0, "callbacks": 0}
+    at_step = []
+    backend = _SteppedClock(on_step=lambda i: at_step.append(dict(ticks)))
+
+    async def main():
+        loop = asyncio.get_running_loop()
+        door = FrontDoor(backend)
+        flooding = True
+
+        async def spin():
+            while flooding:
+                ticks["tasks"] += 1
+                await asyncio.sleep(0)
+
+        def again():
+            ticks["callbacks"] += 1
+            if flooding:
+                loop.call_soon(again)
+
+        spinners = [asyncio.ensure_future(spin()) for _ in range(3)]
+        loop.call_soon(again)
+        rid = await door.submit([1], max_new_tokens=8)
+        tokens = [ev.token async for ev in door.stream(rid) if ev.kind == "token"]
+        flooding = False
+        await asyncio.gather(*spinners)
+        return door, tokens
+
+    door, tokens = asyncio.run(main())
+    assert len(tokens) == 8 and backend.iterations == 8
+    for before, after in zip(at_step, at_step[1:]):
+        # one tick a pass for the callback, one a pass for each task
+        assert after["callbacks"] - before["callbacks"] == door.PASSES
+        assert after["tasks"] - before["tasks"] == 3 * door.PASSES
+
+
+def test_drain_and_pump_share_one_iteration():
+    """`drain()` runs the pump's own iteration: one `step()`, then the
+    same bounded passes, so an arrival during a drain crosses the door
+    as it does under the pump."""
+    backend = _SteppedClock()
+
+    async def main(door):
+        req = Request(rid=100, prompt=[1], max_new_tokens=8)
+        backend.submit(req)  # work the door's pump knows nothing of
+        arrival = asyncio.ensure_future(_open_loop_arrival(door, 2.5))
+        await asyncio.sleep(0)  # the arrival's timer is set
+        await door.drain()
+        return door, await arrival
+
+    door, rid = _run_stepped(backend, main)
+    assert door._pump_task is not None  # started by the arrival alone
+    assert backend.submitted_at[rid] == 3
+    assert door.submits_by_pass == {str(door.PASSES): 1}
 
 
 # -- cost-aware prefix eviction ------------------------------------------------
