@@ -104,29 +104,20 @@ findings go to the baseline):
   a live reference ships rows the next decode step is rewriting; the
   staged record (``export_swap``'s host-side numpy copies) is the
   only sanctioned carrier across the engine boundary.
-* **FX109** — device-resident multi-step decode discipline (the fused
-  K-step ``lax.scan`` window). Two findings: (a) a multi-step dispatch
-  function (``multi`` + ``dispatch`` in the name) captures live
+* **FX109** — tree-verify dispatch discipline: a tree-verify dispatch
+  function (``tree`` + ``dispatch`` in the name) captures live
   mutated host allocator state (``lengths`` / ``block_tables`` /
-  ``_free_pages``) without a snapshot — the scan executes K decode
-  steps behind the async dispatch queue, so a live reference is up to
-  K iterations stale when the device finally reads it, K times the
-  exposure of the single-step FX101 race. Scalars materialized at
-  call time (``int()``/``len()``/``min()``...) are synchronous host
-  reads and stay sanctioned, as do Assign/AugAssign store TARGETS
-  (the dispatch-side pre-advance ``cache.lengths[act] += limits`` is
-  the commit itself, not a capture). (b) reconcile-phase code reads
-  multi-step window state (``k_steps`` / ``step_limits`` /
-  ``device_tokens`` / ``device_mask`` / ``device_lengths``) from
-  anywhere but the step record — the window's geometry travels WITH
-  its ``InflightStep``; any scheduler-side mirror is a whole window
-  stale under async double-buffering, so commit/rollback decisions
-  made against it truncate to the wrong length or emit phantom
-  steps. Part (a) also applies to tree-verify dispatch functions
-  (``tree`` + ``dispatch`` in the name): the parent table and page
-  claims ride the same async queue, so live allocator state handed
-  to the jitted tree step (or stored on the ``InflightStep``) must
-  cross as a snapshot.
+  ``_free_pages``) without a snapshot — the parent table and page
+  claims ride the async dispatch queue and the reconcile walks them
+  an iteration later, so live allocator state handed to the jitted
+  tree step (or stored on the ``InflightStep``) must cross as a
+  snapshot. Scalars materialized at call time
+  (``int()``/``len()``/``min()``...) are synchronous host reads and
+  stay sanctioned, as do Assign/AugAssign store TARGETS (a
+  dispatch-side ``cache.lengths[act] += n`` is the commit itself, not
+  a capture). The tree-plan state (``tree_parents`` / ``tree_plan``)
+  a reconcile reads must come off the step record (reported under
+  FX103, whose extension it is).
 * **FX110** — adapter-pool ledger discipline for the multi-tenant
   LoRA pool (``serving/tenancy/adapters.AdapterPool``), FX106's rule
   applied to its sibling allocator: a subscript store into an
@@ -187,8 +178,7 @@ RULES = {
     "allocator helpers",
     "FX108": "cross-engine swap handle consumed twice, or handoff code "
     "reading live source-engine pool state",
-    "FX109": "multi-step or tree-verify dispatch captures live host "
-    "state, or reconcile reads window state off the step record",
+    "FX109": "tree-verify dispatch captures live host state",
     "FX110": "adapter-pool table/refcount write or free-heap mutation "
     "outside the blessed AdapterPool helpers",
     "FX111": "stream-visible token commit (a 'generated' list "
@@ -340,11 +330,11 @@ _HANDOFF_POOL_ATTRS = {
 #: reconcile must never read (FX105); the snapshot is `step.chunks`
 _CHUNK_PROGRESS_ATTRS = {"prefill_seq", "prefill_pos", "prefill_dispatched"}
 
-#: host allocator state a multi-step dispatch must snapshot before the
-#: fused scan captures it (FX109a). Deliberately NOT the full mutated
-#: set: the device pools (`cache.k`/`cache.v`) are donated device
-#: arrays that legitimately ride into the jit raw.
-_MULTISTEP_HOST_ATTRS = {
+#: host allocator state a tree-verify dispatch must snapshot before the
+#: jitted tree step captures it (FX109). Deliberately NOT the full
+#: mutated set: the device pools (`cache.k`/`cache.v`) are donated
+#: device arrays that legitimately ride into the jit raw.
+_TREE_DISPATCH_HOST_ATTRS = {
     "lengths",
     "block_tables",
     "_free_pages",
@@ -353,18 +343,8 @@ _MULTISTEP_HOST_ATTRS = {
 
 #: single-name builtins whose call materializes a host SCALAR at call
 #: time — a synchronous read, immune to the deferred-read race, so a
-#: multi-step dispatch may apply them to live state (`int(lengths[s])`)
-_MULTI_DISPATCH_SCALARS = {"int", "float", "bool", "len", "min", "max"}
-
-#: fused-window state on InflightStep — reconcile-phase code must read
-#: these through the step record, never a scheduler-side mirror (FX109b)
-_WINDOW_STATE_ATTRS = {
-    "k_steps",
-    "step_limits",
-    "device_tokens",
-    "device_mask",
-    "device_lengths",
-}
+#: tree-verify dispatch may apply them to live state (`int(lengths[s])`)
+_TREE_DISPATCH_SCALARS = {"int", "float", "bool", "len", "min", "max"}
 
 #: tree-verify plan state on InflightStep — the dispatched parent table
 #: and the per-slot DraftTree plan; the reconcile's accept walk must
@@ -547,36 +527,28 @@ def _chunk_progress_violations(
     return found
 
 
-def _is_multistep_dispatch(fn) -> bool:
-    """Multi-step dispatch code by the same name convention _step_params
-    uses to EXEMPT dispatch functions from FX103/FX105: it takes the
-    window's snapshots, so it reads live state by definition — but what
-    it hands the fused scan must be snapshotted (FX109a)."""
-    return "multi" in fn.name and "dispatch" in fn.name
-
-
 def _is_tree_dispatch(fn) -> bool:
-    """Tree-verify dispatch code, by the same naming convention as
-    _is_multistep_dispatch ('tree' + 'dispatch'). Exempt from
-    FX103/FX105 like every dispatch function — it takes the snapshots
-    — but what it hands the jitted tree step or stores on the
+    """Tree-verify dispatch code, by the name convention _step_params
+    uses to EXEMPT dispatch functions from FX103/FX105 ('tree' +
+    'dispatch'): it takes the snapshots, so it reads live state by
+    definition — but what it hands the jitted tree step or stores on the
     InflightStep must be snapshotted (FX109): the parent table is read
     behind the async dispatch queue and walked again at reconcile, an
     iteration after the live tables have moved on."""
     return "tree" in fn.name and "dispatch" in fn.name
 
 
-def _multistep_capture_violations(
+def _tree_capture_violations(
     fn, mutated: Set[str]
 ) -> List[Tuple[str, int]]:
     """(attr, line) for loads of live host allocator state inside a
-    multi-step dispatch function with no snapshot wrapper and no
-    scalar materialization. The fused scan reads its captures behind
-    the async dispatch queue — K steps after this function returns —
-    so every mutable host array must cross as a copy. Store targets
-    (the pre-advance ``cache.lengths[act] += limits``) are the
-    dispatch-side commit and never match."""
-    attrs = _MULTISTEP_HOST_ATTRS & mutated
+    tree-verify dispatch function with no snapshot wrapper and no
+    scalar materialization. The jitted tree step reads its captures
+    behind the async dispatch queue, after this function returns, so
+    every mutable host array must cross as a copy. Store targets (a
+    dispatch-side ``cache.lengths[act] += n``) are the commit itself
+    and never match."""
+    attrs = _TREE_DISPATCH_HOST_ATTRS & mutated
     found: List[Tuple[str, int]] = []
 
     def visit(node: ast.AST) -> None:
@@ -587,12 +559,12 @@ def _multistep_capture_violations(
             if (
                 chain is not None
                 and len(chain) == 1
-                and chain[0] in _MULTI_DISPATCH_SCALARS
+                and chain[0] in _TREE_DISPATCH_SCALARS
             ):
                 return  # scalar materialized at call time: synchronous
         if isinstance(node, (ast.Assign, ast.AugAssign)):
-            # store targets are the dispatch-side commit (pre-advance);
-            # only the VALUE can leak a live reference
+            # store targets are the dispatch-side commit; only the
+            # VALUE can leak a live reference
             visit(node.value)
             return
         if (
@@ -609,30 +581,6 @@ def _multistep_capture_violations(
 
     for stmt in fn.body:
         visit(stmt)
-    return found
-
-
-def _window_state_violations(
-    fn, step_params: Set[str]
-) -> List[Tuple[str, int]]:
-    """(attr, line) for loads of fused-window state inside a
-    reconcile-phase function that do not come through the step
-    parameter. The window's geometry (k_steps, per-slot limits) and
-    per-step device stacks travel WITH the InflightStep; a
-    scheduler-side mirror is one whole window stale under async
-    double-buffering."""
-    found: List[Tuple[str, int]] = []
-    for node in ast.walk(fn):
-        if not (
-            isinstance(node, ast.Attribute)
-            and isinstance(node.ctx, ast.Load)
-            and node.attr in _WINDOW_STATE_ATTRS
-        ):
-            continue
-        chain = name_chain(node)
-        if chain is not None and chain[0] in step_params:
-            continue
-        found.append((node.attr, node.lineno))
     return found
 
 
@@ -1053,28 +1001,8 @@ def run(trees: Dict[str, ast.Module]) -> List[Diagnostic]:
                 node, (ast.FunctionDef, ast.AsyncFunctionDef)
             ):
                 continue
-            if _is_multistep_dispatch(node):
-                for attr, line in _multistep_capture_violations(
-                    node, mutated
-                ):
-                    diags.append(
-                        Diagnostic(
-                            "FX109",
-                            path,
-                            line,
-                            f"multi-step dispatch '{node.name}' captures "
-                            f"live host attribute '{attr}' into the "
-                            "fused K-step window without a snapshot — "
-                            "the scan reads it behind the dispatch "
-                            "queue, up to K iterations after this call "
-                            "returns; wrap it in snapshot()/np.array or "
-                            "materialize a scalar (int())",
-                        )
-                    )
-            elif _is_tree_dispatch(node):
-                for attr, line in _multistep_capture_violations(
-                    node, mutated
-                ):
+            if _is_tree_dispatch(node):
+                for attr, line in _tree_capture_violations(node, mutated):
                     diags.append(
                         Diagnostic(
                             "FX109",
@@ -1093,20 +1021,6 @@ def run(trees: Dict[str, ast.Module]) -> List[Diagnostic]:
             steps = _step_params(node)
             if not steps:
                 continue
-            for attr, line in _window_state_violations(node, steps):
-                diags.append(
-                    Diagnostic(
-                        "FX109",
-                        path,
-                        line,
-                        f"reconcile-phase function '{node.name}' reads "
-                        f"multi-step window state '{attr}' off the "
-                        "step record — the window's geometry travels "
-                        "WITH its InflightStep; a scheduler-side "
-                        "mirror is a whole window stale under async "
-                        "double-buffering",
-                    )
-                )
             for attr, line in _tree_plan_violations(node, steps):
                 diags.append(
                     Diagnostic(

@@ -204,12 +204,6 @@ class FFConfig:
     serve_kv_swap: bool = False
     serve_kv_swap_bytes: int = 0
     serve_prefix_evict: str = "none"
-    # device-resident multi-step decode (serving/engine.py +
-    # scheduler.py): --decode-multistep fuses scheduler-invariant runs
-    # of decode iterations into one jitted lax.scan window of up to
-    # --max-fused-steps steps, reconciled in a single host sync
-    serve_decode_multistep: bool = False
-    serve_max_fused_steps: int = 8
     # multi-tenant serving (serving/tenancy/): --adapters provisions a
     # paged pool of that many LoRA adapter ids (--adapter-rank rows
     # each); --classes "gold:4:200:20,bronze:1" declares priority
@@ -410,10 +404,6 @@ class FFConfig:
                 cfg.serve_kv_swap_bytes = int(take())
             elif a == "--prefix-evict":
                 cfg.serve_prefix_evict = take()
-            elif a == "--decode-multistep":
-                cfg.serve_decode_multistep = True
-            elif a == "--max-fused-steps":
-                cfg.serve_max_fused_steps = int(take())
             elif a == "--adapters":
                 cfg.serve_adapters = int(take())
             elif a == "--adapter-rank":

@@ -730,14 +730,6 @@ _DECODE_TP_OPS = {
     OperatorType.EMBEDDING: lambda n: int(n.params["out_dim"]),
 }
 
-#: modeled host round-trip per decode dispatch/reconcile (device-resident
-#: multi-step decode amortizes this over K fused steps): dispatch
-#: enqueue + output materialization + scheduler bookkeeping. An
-#: assumption, not a chip reading: PERF.md section 5 has the measured
-#: host gaps round a decode step
-DECODE_HOST_SYNC_S = 50e-6
-
-
 class ServingSearchResult:
     """One costed serving configuration (mesh + per-token step time).
 
@@ -763,7 +755,6 @@ class ServingSearchResult:
         page_size: int = 0,
         max_in_flight: Optional[int] = None,
         max_in_flight_reserve: Optional[int] = None,
-        fused_steps: int = 1,
     ):
         self.dp = dp
         self.tp = tp
@@ -773,9 +764,6 @@ class ServingSearchResult:
         self.page_size = page_size
         self.max_in_flight = max_in_flight
         self.max_in_flight_reserve = max_in_flight_reserve
-        # device-resident multi-step decode: the window depth K that
-        # minimized amortized per-token time (1 = step-at-a-time)
-        self.fused_steps = int(fused_steps)
         # Which mesh the engine will ACTUALLY execute. The search alone
         # does not apply anything — serving inherits the training
         # strategy's sharding unless `FFModel.compile_for_serving` flips
@@ -802,13 +790,10 @@ class ServingSearchResult:
             "max_in_flight": self.max_in_flight,
             "max_in_flight_reserve": self.max_in_flight_reserve,
             "mesh_execution": self.mesh_execution,
-            "fused_steps": self.fused_steps,
         }
 
     def describe(self) -> str:
         layout = f", pages of {self.page_size}" if self.page_size else ""
-        if self.fused_steps > 1:
-            layout += f", K={self.fused_steps} fused"
         fit = (
             f", ~{self.max_in_flight} seqs fit"
             if self.max_in_flight is not None
@@ -966,8 +951,6 @@ def estimate_decode_step(
     page_size: int = 0,
     decode_kernel: str = "dense",
     kv_dtype: str = "fp32",
-    fused_steps: int = 1,
-    host_sync_s: float = 0.0,
 ) -> Optional[GraphCost]:
     """Cost one decode iteration of the whole PCG under a (dp, tp) mesh;
     None when infeasible (dp doesn't divide the batch, tp doesn't divide
@@ -979,14 +962,7 @@ def estimate_decode_step(
     Megatron column→row pairing (which needs one per PAIR), acceptable
     because decode activations are tiny and the verdict is driven by the
     weight-read term; the over-count only biases AGAINST tp, so a tp
-    winner is a conservative conclusion.
-
-    `host_sync_s` charges the host dispatch/reconcile round-trip every
-    decode step pays, amortized over `fused_steps` when the
-    device-resident multi-step loop fuses K iterations into one scan
-    window (--decode-multistep) — the term optimize_serving minimizes
-    to pick K. Defaults to 0.0 so every per-step caller (swap pricing,
-    token-budget search) keeps its pure device cost."""
+    winner is a conservative conclusion."""
     if batch % dp != 0:
         return None
     b_chip = batch // dp
@@ -1013,14 +989,12 @@ def estimate_decode_step(
             out = node.output_shapes[0]
             act = b_chip * out.logical_sizes[-1] * cm.elem_bytes(out)
             sync += cm.all_reduce(float(act), node_tp)
-    host = float(host_sync_s) / max(1, int(fused_steps))
-    cost = GraphCost(
-        step_time=compute + sync + host,
+    return GraphCost(
+        step_time=compute + sync,
         compute_time=compute,
-        sync_time=sync + host,
+        sync_time=sync,
         memory_per_chip=int(mem),
     )
-    return cost
 
 
 def estimate_verify_step(
@@ -1674,8 +1648,6 @@ def optimize_serving(
     max_new_tokens: Optional[int] = None,
     kv_dtype: str = "fp32",
     prefix_hit_rate: float = 0.0,
-    max_fused_steps: int = 1,
-    host_sync_s: float = DECODE_HOST_SYNC_S,
 ) -> ServingSearchResult:
     """Pick the decode-latency-optimal (dp, tp) mesh for serving
     `batch_size` concurrent sequences at `kv_len` cache positions.
@@ -1704,55 +1676,32 @@ def optimize_serving(
     (--prefix-cache at measured hit rate h): see
     estimate_max_in_flight — the decode step-time cost itself also
     shifts under int8 (thinner pool reads, extra scale reads), priced
-    through CostModel.decode_op_cost's kv_dtype term.
-
-    `max_fused_steps` > 1 additionally enumerates the device-resident
-    multi-step window depth K (powers of two up to the cap, matching
-    the engine's K-bucketing): each candidate's step time carries the
-    `host_sync_s` round-trip amortized over K
-    (estimate_decode_step's fused_steps term), and — when mean_gen_len
-    is known — a retire-waste factor 1 + (K-1)/(2·mean_gen_len) for
-    the window tail an EOS discards on average, so the optimal K is a
-    real trade-off rather than always-the-cap. The winner carries its
-    K as `fused_steps` (--max-fused-steps takes it from the doc)."""
+    through CostModel.decode_op_cost's kv_dtype term."""
     cm = CostModel(
         spec,
         measure=False,  # the measured table times training shapes
         machine_model=machine_model,
         mixed_precision=mixed_precision,
     )
-    fused_cands = [1]
-    while max_fused_steps >= fused_cands[-1] * 2:
-        fused_cands.append(fused_cands[-1] * 2)
     best: Optional[ServingSearchResult] = None
-    best_eff = float("inf")
     for used in range(1, num_devices + 1):
         if num_devices % used != 0:
             continue
         for dp, tp in _mesh_factorizations(used):
-            for kf in fused_cands:
-                cost = estimate_decode_step(
-                    graph, cm, dp, tp, batch_size, kv_len,
-                    page_size=page_size, decode_kernel=decode_kernel,
-                    kv_dtype=kv_dtype, fused_steps=kf,
-                    host_sync_s=host_sync_s if max_fused_steps > 1 else 0.0,
-                )
-                if cost is None or not cost.feasible(spec):
-                    continue
-                waste = (
-                    1.0 + (kf - 1) / (2.0 * mean_gen_len)
-                    if mean_gen_len
-                    else 1.0
-                )
-                eff = cost.step_time * waste
-                cur = ServingSearchResult(
-                    dp, tp, batch_size, kv_len, cost,
-                    page_size=page_size, fused_steps=kf,
-                )
-                if verbose:
-                    print(f"[serve-search] {cur.describe()}")
-                if best is None or eff < best_eff:
-                    best, best_eff = cur, eff
+            cost = estimate_decode_step(
+                graph, cm, dp, tp, batch_size, kv_len,
+                page_size=page_size, decode_kernel=decode_kernel,
+                kv_dtype=kv_dtype,
+            )
+            if cost is None or not cost.feasible(spec):
+                continue
+            cur = ServingSearchResult(
+                dp, tp, batch_size, kv_len, cost, page_size=page_size,
+            )
+            if verbose:
+                print(f"[serve-search] {cur.describe()}")
+            if best is None or cost.step_time < best.cost.step_time:
+                best = cur
     if best is None:
         raise RuntimeError("serving search found no feasible strategy")
     if mean_prompt_len is not None and mean_gen_len is not None:
@@ -1856,11 +1805,6 @@ def search_serving_strategy(
             prefix_hit_rate or 0.0
             if getattr(cfg, "serve_prefix_cache", False)
             else 0.0
-        ),
-        max_fused_steps=(
-            int(getattr(cfg, "serve_max_fused_steps", 1))
-            if getattr(cfg, "serve_decode_multistep", False)
-            else 1
         ),
     )
 

@@ -139,14 +139,6 @@ class ServeConfig:
     kv_swap: bool = False
     kv_swap_bytes: int = 0
     prefix_evict: str = "none"
-    # device-resident multi-step decode (--decode-multistep):
-    # scheduler-invariant runs of decode iterations fuse into ONE
-    # jitted lax.scan window of up to max_fused_steps
-    # (--max-fused-steps) steps, reconciled in a single host sync —
-    # token/logit-identical to step-at-a-time, ~K fewer host
-    # round-trips per committed token on quiet stretches.
-    decode_multistep: bool = False
-    max_fused_steps: int = 8
     # multi-tenant serving (serving/tenancy/). adapters (--adapters):
     # > 0 attaches a paged multi-LoRA AdapterPool sized for that many
     # resident adapters of rank <= adapter_rank (--adapter-rank);
@@ -284,11 +276,6 @@ class ServeConfig:
                 "prefix_evict needs prefix_cache=True (only published "
                 "prefix pages are ever evictable)"
             )
-        if self.max_fused_steps < 1:
-            raise ValueError(
-                f"max_fused_steps must be >= 1, got "
-                f"{self.max_fused_steps}"
-            )
         if self.adapters < 0:
             raise ValueError(
                 f"adapters must be >= 0 (0 = no pool), got {self.adapters}"
@@ -382,8 +369,6 @@ class ServeConfig:
             kv_swap=cfg.serve_kv_swap,
             kv_swap_bytes=cfg.serve_kv_swap_bytes,
             prefix_evict=cfg.serve_prefix_evict,
-            decode_multistep=cfg.serve_decode_multistep,
-            max_fused_steps=cfg.serve_max_fused_steps,
             adapters=cfg.serve_adapters,
             adapter_rank=cfg.serve_adapter_rank,
             classes=cfg.serve_classes,
@@ -532,7 +517,6 @@ def build_scheduler(
     engine.require(
         *(("verify", "verify_tree") if proposer is not None else ()),
         *(("chunk",) if serve.token_budget else ()),
-        *(("multistep",) if serve.decode_multistep else ()),
         *(("swap",) if serve.kv_swap else ()),
     )
     classes = None
@@ -561,8 +545,6 @@ def build_scheduler(
         swap_decider=(
             build_swap_decider(model) if serve.kv_swap else None
         ),
-        decode_multistep=serve.decode_multistep,
-        max_fused_steps=serve.max_fused_steps,
         classes=classes,
         victim_pricer=(
             build_victim_pricer(model)

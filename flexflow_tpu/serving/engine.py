@@ -77,6 +77,12 @@ request's consecutive chunks: they pipeline under the async loop), and
 only the FINAL chunk's sampled token means anything (the scheduler
 discards the rest).
 
+That makes five step bodies (`_prefill_impl_paged`, `_decode_impl_paged`,
+`_verify_impl_paged`, `_verify_tree_impl_paged`: a verify whose rows form
+a draft tree, the parent table riding in as data, and
+`_chunk_impl_paged`), each built into a program in one place
+(`_step_jit`).
+
 All steps are jitted with static shapes: decode always runs at
 `[max_seqs, 1]`, prefill at `[1, bucket]` per bucket of the admitted
 prompts' total length (an admission over the largest bucket runs several),
@@ -122,7 +128,6 @@ rejection-sampling verify needs.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import logging
 import time
 from collections import OrderedDict
@@ -193,12 +198,12 @@ def _tree_depths(parents):
 class _JitCache:
     """Bounded keyed LRU over jitted step programs.
 
-    The verify, chunked-prefill, and multi-step decode families each
-    jit one program per shape key (draft width, compact batch, K
-    bucket); widths churn with re-tuning and per-request budget caps,
-    and an unbounded dict would keep every key's device executable
-    alive for the engine's whole life. One helper owns the discipline
-    all three caches previously hand-rolled: a hit refreshes recency, a
+    The verify, tree-verify and chunked-prefill families each jit one
+    program per shape key (draft width, row width, compact batch);
+    widths churn with re-tuning and per-request budget caps, and an
+    unbounded dict would keep every key's device executable alive for
+    the engine's whole life. One helper owns the discipline for all
+    three caches: a hit refreshes recency, a
     miss calls `trace(key)` and evicts the least-recently-used entry
     past `max_entries`. Iteration/containment/len mirror the dict so
     the compile population stays inspectable (the
@@ -304,18 +309,6 @@ class InflightStep:
     # then the counts of a model with expert layers
     # (`GenerationEngine._count_fields`)
     device_readback: object = None
-    # device-resident multi-step decode (kind "multistep"): the fused
-    # window's per-step device outputs — sampled tokens / logits /
-    # executed-step masks are [K, max_seqs] stacks, device_lengths the
-    # end-of-window cache lengths, k_steps the window depth actually
-    # dispatched, step_limits the per-slot fused-step caps the commit
-    # rolls truncation against. Reconcile code consumes THESE, never a
-    # live scheduler copy of the window bookkeeping (fxlint FX109).
-    device_tokens: object = None  # [K, max_seqs] sampled token per step
-    device_mask: object = None  # [K, max_seqs] bool — step ran for slot
-    device_lengths: object = None  # [max_seqs] end-of-window lengths
-    k_steps: int = 1  # fused steps dispatched in this window
-    step_limits: Optional[np.ndarray] = None  # int32 [max_seqs] per-slot cap
     # scheduler-side snapshot: slot -> Request identity at dispatch,
     # verify draft plan, and the dispatching iteration (fault keying)
     participants: Dict[int, object] = dataclasses.field(default_factory=dict)
@@ -327,7 +320,7 @@ class InflightStep:
     # the reconcile walks the tree and compacts the cache against
     # THESE, never a live proposer/scheduler tree the host has since
     # rebuilt (fxlint FX103/FX109 hold tree-reconcile code to the step
-    # record exactly like the multistep window state).
+    # record).
     tree_parents: Optional[np.ndarray] = None  # int32 [max_seqs, w]
     tree_plan: Optional[Dict[int, object]] = None  # slot -> DraftTree
     # dispatch sequence number (scheduler._note_dispatch): the trace
@@ -477,9 +470,9 @@ class GenerationEngine:
         # a layer of [c | kr] rows, written by the prefill and decode
         # programs through the same `dest` scatter as K and V rows, and
         # attended decompressed (prefill) or absorbed over the pool
-        # (decode, multi-step decode). What has not been taken through
-        # those helpers and tested is refused here, in words, not served
-        # by the operator's plain lowering with no cache behind it.
+        # (decode). What has not been taken through those helpers and
+        # tested is refused here, in words, not served by the operator's
+        # plain lowering with no cache behind it.
         self._latent = tuple(
             g for g in cache.spec.layer_guids
             if graph.nodes[g].op_type == OperatorType.LATENT_ATTENTION
@@ -503,13 +496,12 @@ class GenerationEngine:
                 if asked:
                     raise ValueError(
                         f"{what} is not supported for a model with latent "
-                        "attention: only prefill, decode and multi-step "
-                        "decode read and write the latent pool"
+                        "attention: only prefill and decode read and "
+                        "write the latent pool"
                     )
             why = (
                 " steps are not supported for a model with latent attention: "
-                "only prefill, decode and multi-step decode read and write "
-                "the latent pool"
+                "only prefill and decode read and write the latent pool"
             )
             self._refused = {
                 "verify": "verify (speculative decoding)" + why,
@@ -555,7 +547,6 @@ class GenerationEngine:
                 "are" + why,
                 "chunk": "chunked-prefill and prefix-suffix steps are" + why,
                 "draft": "draft-model steps are" + why,
-                "multistep": "multi-step decode windows are" + why,
                 "swap": "kv_swap (swap_out / swap_in) is" + why,
             })
         # expert layers (ops/moe.py sparse_moe): the prefill and decode
@@ -625,12 +616,11 @@ class GenerationEngine:
         # one jitted prefill per length bucket / one jitted verify per
         # draft width (jit caches by shape anyway; the explicit caches
         # make the compile-count contract inspectable). The verify,
-        # chunk, and multi-step caches are bounded LRUs (_JitCache):
+        # tree-verify and chunk caches are bounded LRUs (_JitCache):
         # draft widths vary with optimize_spec_k re-tuning and
-        # per-request budget caps, chunk widths with the token budget,
-        # K buckets with the scheduler's fusing horizon — unbounded
-        # dicts kept every key's jitted program (and its device
-        # executable) alive for the engine's whole life.
+        # per-request budget caps, chunk widths with the token budget —
+        # unbounded dicts kept every key's jitted program (and its
+        # device executable) alive for the engine's whole life.
         self._prefill_cache: Dict[int, object] = {}
         self._verify_cache = _JitCache(
             lambda w: self._step_jit(self._verify_impl_paged)
@@ -641,12 +631,6 @@ class GenerationEngine:
         self._chunk_cache = _JitCache(
             lambda key: self._step_jit(self._chunk_impl_paged)
         )
-        # multi-step decode scan programs, one per (B, K-bucket) key —
-        # K buckets are powers of two, so the population is
-        # log2(max_fused_steps) at most
-        self._multistep_cache = _JitCache(
-            lambda key: self._step_jit(self._decode_multi_impl_paged, key[1])
-        )
         # tree-verify programs, one per row width w = 1 + tree nodes.
         # Kept apart from `_verify_cache` because the tree impl carries
         # an extra parent-table operand; the scheduler pins a single
@@ -655,10 +639,9 @@ class GenerationEngine:
             lambda w: self._step_jit(self._verify_tree_impl_paged)
         )
 
-    def _step_jit(self, impl, *bound):
-        """The one place a step program's jit is built: `impl` (`bound`:
-        its leading trace-time constants),
-        jitted with the pools it rewrites and returns DONATED. XLA may
+    def _step_jit(self, impl):
+        """The one place a step program's jit is built: `impl`, jitted
+        with the pools it rewrites and returns DONATED. XLA may
         not write into a parameter it does not own, so an undonated pool
         is copied whole into the output buffer in front of every
         scatter; a donated one is updated in place. Parameters, adapter
@@ -667,8 +650,6 @@ class GenerationEngine:
         differ between the programs."""
         import jax
 
-        if bound:
-            impl = functools.partial(impl, *bound)
         return jax.jit(impl, donate_argnames=_POOL_ARGNAMES)
 
     @property
@@ -683,12 +664,6 @@ class GenerationEngine:
         """Live jitted tree-verify programs — the `verify_cache_entries`
         twin for the tree-width family."""
         return len(self._tree_cache)
-
-    @property
-    def multistep_cache_entries(self) -> int:
-        """Live jitted multi-step scan programs (LRU-bounded), the
-        `verify_cache_entries` twin for the fused-decode family."""
-        return len(self._multistep_cache)
 
     @property
     def verify_cache_max(self) -> int:
@@ -734,8 +709,8 @@ class GenerationEngine:
     def require(self, *kinds: str) -> None:
         """Raise, in words, if this engine refuses one of the step `kinds`
         ("verify", "verify_tree", "chunk", "draft": what a model with
-        latent attention is not served through; "multistep" and "swap"
-        besides for one with recurrent layers). `build_scheduler` asks
+        latent attention is not served through; "swap" besides for one
+        with recurrent layers). `build_scheduler` asks
         before a request is admitted; the program getters above ask again."""
         for kind in kinds:
             if kind in self._refused:
@@ -746,7 +721,7 @@ class GenerationEngine:
     def _adapter_slot_args(self):
         """() without a pool, else a 1-tuple holding the slot-indexed
         (tables, has, pools) adapter gather for the decode/verify/
-        multistep/chunk steps. The host tables snapshot at dispatch
+        chunk steps. The host tables snapshot at dispatch
         (FX103: the step rides its own copy — scheduler attach/detach
         between iterations never mutates an in-flight step's view); the
         device pools are immutable arrays, rebound wholesale by loads,
@@ -983,7 +958,6 @@ class GenerationEngine:
         self._decode_jit = self._step_jit(self._decode_impl_paged)
         self._verify_cache.clear()
         self._chunk_cache.clear()
-        self._multistep_cache.clear()
         self._tree_cache.clear()
 
     def _readback(self, kind: str, *arrays):
@@ -1576,28 +1550,40 @@ class GenerationEngine:
 
     # -- decode --------------------------------------------------------------
 
-    def _decode_core_paged(
-        self, params, tokens, lengths, active, tables, ck, cv, cks, cvs,
-        cs, ad=None, moe=None, share=None,
+    #: columns of a decode step's packed host state in front of the slot's
+    #: block table: the host's view of the last token, whether the token
+    #: comes from the chained step instead, the cache length, and activity
+    _STATE_COLUMNS = 4
+
+    def _decode_impl_paged(
+        self, params, chained, state, ck, cv, cks, cvs, cs, ad=None,
     ):
-        """One decode forward: tokens [max_seqs, 1]; lengths [max_seqs]
-        = cache position the incoming token is written at; active
-        [max_seqs] bool; tables [max_seqs, max_pages_per_seq] int32
-        block tables. The new K/V row scatters
-        into `tables[slot, lengths // page_size] * page_size + lengths %
-        page_size` of the flattened pool; inactive slots are routed to an
-        out-of-bounds destination (dropped). The single-step jit and
-        the multi-step scan body both trace THIS function, so their HLO
-        op sequence — and therefore their logits — match exactly (the
-        token/logit-identity contract). `ad=None` (no adapter pool)
-        leaves the traced HLO byte-for-byte what it was before
-        multi-LoRA existed. `moe`: the list that receives the expert
-        layers' counts and `share` what a model that holds a share
-        of its experts hands them (`_forward_logits`); the single-step
-        program returns both, the scan neither. `cs`: the recurrent
-        layers' per-slot state; a step advances the rows of its `active`
-        slots and hands every other row back bit-equal. Returns (ck', cv',
-        cks', cvs', cs', logits [max_seqs, V])."""
+        """The decode step's jit target: one forward over tokens
+        [max_seqs, 1] plus the per-slot sample (the sampled token will be
+        written at cache position lengths + 1).
+
+        Everything the host knows about the step arrives as ONE int32
+        array, `state` [max_seqs, _STATE_COLUMNS + max_pages_per_seq]
+        (`_pack_state`: token, chain flag, length, active, block table),
+        and everything it decides on leaves as one: after the pools, the
+        sampled tokens [max_seqs], the logits [max_seqs, V] (read by who
+        asks), and `readback`, int32 [2 * max_seqs + counts]: the tokens
+        again, whether each slot's logits row is finite, and the expert
+        layers' counts. `chained`: int32 [max_seqs] on the device, an
+        earlier step's sampled tokens, taken where the chain flag is set
+        so that consecutive steps' data dependency stays on the device;
+        always there (`decode_dispatch` hands a step without a chain the
+        newest tokens it has, and the flags are all 0), so a model has
+        ONE decode program whoever feeds a slot.
+
+        lengths [max_seqs] = cache position the incoming token is
+        written at. The new K/V row scatters into `tables[slot, lengths
+        // page_size] * page_size + lengths % page_size` of the flattened
+        pool; inactive slots are routed to an out-of-bounds destination
+        (dropped). `ad=None` (no adapter pool) leaves the traced HLO
+        byte-for-byte what it was before multi-LoRA existed. `cs`: the
+        recurrent layers' per-slot state; a step advances the rows of its
+        `active` slots and hands every other row back bit-equal."""
         import jax
         import jax.numpy as jnp
 
@@ -1631,6 +1617,11 @@ class GenerationEngine:
         new_ks, new_vs = dict(cks), dict(cvs)
         new_s = dict(cs)
         with jax.named_scope("step.unpack"):
+            tokens, from_chain, lengths = state[:, 0], state[:, 1], state[:, 2]
+            active = state[:, 3] != 0
+            tables = state[:, self._STATE_COLUMNS:]
+            tokens = jnp.where(from_chain != 0, chained, tokens)[:, None]
+            share = self._share(active[:, None])
             page = jnp.take_along_axis(
                 tables, (lengths // ps)[:, None], axis=1
             )[:, 0]
@@ -1708,54 +1699,13 @@ class GenerationEngine:
                 }
             return [kda_out(o[:, None], z, ws, p, ctx, ins[0].dtype)]
 
+        moe = []  # receives the expert layers' counts
         logits = self._forward_logits(
             params, tokens, hook, moe, latent_hook if self._latent else None,
             share, state_hook if self._recurrent else None,
         )
         with jax.named_scope("step.pick"):
-            last = logits[:, -1, :]
-        return new_k, new_v, new_ks, new_vs, new_s, last
-
-    #: columns of a decode step's packed host state in front of the slot's
-    #: block table: the host's view of the last token, whether the token
-    #: comes from the chained step instead, the cache length, and activity
-    _STATE_COLUMNS = 4
-
-    def _decode_impl_paged(
-        self, params, chained, state, ck, cv, cks, cvs, cs, ad=None,
-    ):
-        """The single-step jit target: one _decode_core_paged forward
-        plus the per-slot sample (the sampled token will be written at
-        cache position lengths + 1).
-
-        Everything the host knows about the step arrives as ONE int32
-        array, `state` [max_seqs, _STATE_COLUMNS + max_pages_per_seq]
-        (`_pack_state`: token, chain flag, length, active, block table),
-        and everything it decides on leaves as one: after the pools, the
-        sampled tokens [max_seqs], the logits [max_seqs, V] (read by who
-        asks), and `readback`, int32 [2 * max_seqs + counts]: the tokens
-        again, whether each slot's logits row is finite, and the expert
-        layers' counts. `chained`: int32 [max_seqs] on the device, an
-        earlier step's sampled tokens, taken where the chain flag is set
-        so that consecutive steps' data dependency stays on the device;
-        always there (`decode_dispatch` hands a step without a chain the
-        newest tokens it has, and the flags are all 0), so a model has
-        ONE decode program whoever feeds a slot."""
-        import jax
-        import jax.numpy as jnp
-
-        with jax.named_scope("step.unpack"):
-            tokens, from_chain, lengths = state[:, 0], state[:, 1], state[:, 2]
-            active = state[:, 3] != 0
-            tables = state[:, self._STATE_COLUMNS:]
-            tokens = jnp.where(from_chain != 0, chained, tokens)[:, None]
-            share = self._share(active[:, None])
-        moe = []
-        new_k, new_v, new_ks, new_vs, new_s, logits = self._decode_core_paged(
-            params, tokens, lengths, active, tables, ck, cv, cks, cvs, cs, ad,
-            moe, share,
-        )
-        with jax.named_scope("step.pick"):
+            logits = logits[:, -1, :]
             slots = jnp.arange(lengths.shape[0])
             nxt = self._pick(logits, slots, lengths + 1)
             extra = self._step_counts(moe, share)
@@ -1771,93 +1721,6 @@ class GenerationEngine:
             new_k, new_v, new_ks, new_vs, new_s, nxt, logits, readback,
             *extra[n:],
         )
-
-    # -- device-resident multi-step decode -----------------------------------
-
-    def _decode_multi_impl_paged(
-        self,
-        k_bucket,
-        params,
-        tokens,
-        lengths,
-        active,
-        limits,
-        eos,
-        tables,
-        ck,
-        cv,
-        cks,
-        cvs,
-        cs,
-        ad=None,
-    ):
-        """K fused decode iterations as ONE jitted `lax.scan` — the
-        device-resident inner loop. tokens [max_seqs] int32 (the last
-        emitted token per slot); lengths [max_seqs] pre-window cache
-        lengths; active [max_seqs] bool; limits [max_seqs] int32
-        PER-SLOT fused-step caps (a budget- or boundary-capped slot
-        stops contributing at its own limit while deeper slots keep
-        fusing); eos [max_seqs] int32 EOS token id per slot (-1 =
-        none). Each scan step traces the SAME `_decode_core_paged` the
-        single-step jit traces, then samples with the identical
-        position-derived `_pick` key — fold_in(fold_in(seed, slot),
-        position) depends only on the running length, never the step
-        counter, so the fused stream is identical-by-construction to
-        step-at-a-time. EOS detection, length bumps, and
-        retire-the-slot masking all live in the scan carry; `k_bucket`
-        is the trace-time scan length (the pow-2 bucket the dispatch
-        rounds K up to — steps past a slot's limit are masked out).
-        The block tables ride in
-        as ONE trace-time snapshot: the dispatch pre-claims every page
-        the window can touch (the scheduler's per-slot limits never
-        cross more than one fresh page — the page-boundary K cap), so
-        the scan body recomputes each step's scatter destination from
-        the carried lengths against STATIC tables. int8 scale pools
-        ride the carry through `_quant_scatter` exactly like the
-        single-step path.
-
-        Returns (ck', cv', cks', cvs', final_lengths, final_tokens,
-        tokens_ks [K, max_seqs], logits_ks [K, max_seqs, V],
-        mask_ks [K, max_seqs]) — the per-step stacks the window
-        reconcile slices to the true K."""
-        import jax
-        import jax.numpy as jnp
-
-        with jax.named_scope("step.unpack"):
-            slots = jnp.arange(lengths.shape[0])
-            steps = jnp.arange(k_bucket)
-
-        def body(carry, i):
-            ck_c, cv_c, cks_c, cvs_c, cs_c, lens, toks, alive = carry
-            with jax.named_scope("step.unpack"):
-                act = alive & (i < limits)
-                tokens = toks[:, None]
-            nk, nv, nks, nvs, ns, logits = self._decode_core_paged(
-                params, tokens, lens, act, tables, ck_c, cv_c, cks_c, cvs_c,
-                cs_c, ad,
-            )
-            with jax.named_scope("step.pick"):
-                nxt = self._pick(logits, slots, lens + 1)
-                hit = act & (eos >= 0) & (nxt == eos)
-                new_lens = jnp.where(act, lens + 1, lens)
-                new_toks = jnp.where(act, nxt, toks)
-                still = alive & ~hit
-            return (nk, nv, nks, nvs, ns, new_lens, new_toks, still), (
-                nxt,
-                logits,
-                act,
-            )
-
-        carry0 = (ck, cv, cks, cvs, cs, lengths, tokens, active)
-        # the loop's own instructions (counter, stacking of the outputs)
-        # read `step.scan`; a node's keep the node's scope inside it
-        with jax.named_scope("step.scan"):
-            (nk, nv, nks, nvs, ns, lens, toks, _), (
-                toks_ks,
-                logits_ks,
-                mask_ks,
-            ) = jax.lax.scan(body, carry0, steps)
-        return nk, nv, nks, nvs, ns, lens, toks, toks_ks, logits_ks, mask_ks
 
     def _chain_feed(self, params):
         """The `chained` argument of a decode step that chains on nothing:
@@ -2016,166 +1879,6 @@ class GenerationEngine:
         nxt, _ = self.decode_reconcile(step)
         (logits,) = self._readback("decode", step.device_logits)
         return nxt, logits
-
-    def decode_multi_dispatch(
-        self,
-        params,
-        tokens: np.ndarray,
-        active_mask: np.ndarray,
-        step_limits: np.ndarray,
-        eos_tokens: Optional[np.ndarray] = None,
-        chain: Optional[InflightStep] = None,
-        chain_mask: Optional[np.ndarray] = None,
-    ) -> InflightStep:
-        """Enqueue ONE fused K-step decode window WITHOUT blocking.
-
-        tokens [max_seqs] (last emitted token per slot), active_mask
-        [max_seqs] bool, step_limits [max_seqs] int32 — how many fused
-        steps each slot runs (K = max over active slots; the scan
-        traces at the pow-2 bucket of K and masks steps past a slot's
-        own limit). eos_tokens [max_seqs] int32 per-slot EOS ids (-1 =
-        none): EOS retires the slot INSIDE the scan — it emits its
-        final token, then contributes nothing past it.
-
-        The host's view is reserved-K-steps-ahead: active lengths bump
-        by their full limits at dispatch, and the paged allocator
-        pre-claims every page the window can touch before the tables
-        snapshot (the existing begin_inflight/end_inflight reserve
-        window pins them for the window's whole life). The window
-        reconcile rolls back what the device did not take
-        (cache.truncate — EOS inside the window returns the surplus).
-        `chain`/`chain_mask` pipeline a window onto an in-flight step's
-        device_next exactly like decode_dispatch."""
-        import jax.numpy as jnp
-
-        self.require("multistep")
-        spec = self.cache.spec
-        limits = np.where(
-            np.asarray(active_mask, dtype=bool),
-            np.asarray(step_limits, dtype=np.int32),
-            0,
-        ).astype(np.int32)
-        k = int(limits.max()) if limits.size else 0
-        if k < 1:
-            raise ValueError(
-                "multi-step window needs at least one fused step"
-            )
-        lengths_snap = np.array(self.cache.lengths)
-        for slot in np.nonzero(limits)[0]:
-            if int(lengths_snap[slot]) + int(limits[slot]) > spec.max_len:
-                raise ValueError(
-                    f"slot {int(slot)}: {int(limits[slot])} fused steps "
-                    f"overrun max_len {spec.max_len}"
-                )
-        # pow-2 K bucket: the scan length is a trace-time constant, so
-        # bucketing keeps the compile population log-bounded; the
-        # per-slot limits mask the bucket's surplus steps out
-        k_bucket = 1 << (k - 1).bit_length()
-        # the block tables ride in as one trace-time snapshot, so all K
-        # steps' destinations must already map (the scheduler's
-        # page-boundary K cap keeps the claims to at most one fresh page
-        # per slot)
-        self._claim_rows(limits)
-        host_tokens = np.asarray(tokens, dtype=np.int32)
-        eos = (
-            np.asarray(eos_tokens, dtype=np.int32)
-            if eos_tokens is not None
-            else np.full(spec.max_seqs, -1, dtype=np.int32)
-        )
-        mask = (
-            np.asarray(chain_mask, dtype=bool)
-            if chain is not None and chain_mask is not None
-            else None
-        )
-        if mask is None or not mask.any():
-            dev_tokens = jnp.asarray(host_tokens)
-        elif mask.all() or np.array_equal(
-            mask, np.asarray(active_mask, dtype=bool)
-        ):
-            dev_tokens = chain.device_next
-        else:
-            dev_tokens = jnp.where(
-                jnp.asarray(mask), chain.device_next, jnp.asarray(host_tokens)
-            )
-        # snapshot() every mutable host array (lengths += limits below,
-        # allocator table edits between iterations mutate behind the
-        # async dispatch queue); see decode_dispatch()
-        key = (spec.max_seqs, k_bucket)
-        d_lens, d_toks, toks_ks, logits_ks, mask_ks = self._run_step(
-            "multistep",
-            lambda: self._multistep_cache.get(key),
-            params,
-            (
-                dev_tokens,
-                snapshot(self.cache.lengths),
-                jnp.asarray(np.asarray(active_mask, dtype=bool)),
-                jnp.asarray(limits),
-                jnp.asarray(eos),
-                snapshot(self.cache.block_tables),
-            ),
-            self._adapter_slot_args(),
-            program=("multistep", key),
-        )
-        act = np.asarray(active_mask, dtype=bool)
-        self.cache.lengths[act] += limits[act]
-        # the in-flight window pins pages this window's snapshot tables
-        # reference for all K steps; decode_multi_reconcile closes it
-        self.cache.begin_inflight()
-        return InflightStep(
-            kind="multistep",
-            dispatch_t=time.perf_counter(),
-            active=np.array(active_mask, dtype=bool),
-            lengths=lengths_snap,
-            host_tokens=host_tokens,
-            device_next=d_toks,
-            device_logits=logits_ks,
-            device_tokens=toks_ks,
-            device_mask=mask_ks,
-            device_lengths=d_lens,
-            k_steps=k,
-            step_limits=limits,
-        )
-
-    def decode_multi_reconcile(
-        self, step: InflightStep
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Block on a fused window's device outputs and close its
-        in-flight window. Returns (tokens_ks [K, max_seqs], logits_ks
-        [K, max_seqs, V], mask_ks [K, max_seqs]) sliced to the
-        window's true K (the scan ran the pow-2 bucket; rows past K
-        are all-masked padding). Commit decisions — which tokens to
-        emit, how far to roll lengths back — belong to the caller,
-        made against the step record's snapshots ONLY: by the time
-        this runs, live cache/scheduler state is a whole window
-        ahead (fxlint FX109)."""
-        try:
-            toks_ks, logits_ks, mask_ks = self._readback(
-                "multistep", step.device_tokens, step.device_logits,
-                step.device_mask,
-            )
-        finally:
-            self.cache.end_inflight()
-        k = int(step.k_steps)
-        return toks_ks[:k], logits_ks[:k], mask_ks[:k]
-
-    def decode_multi(
-        self,
-        params,
-        tokens: np.ndarray,
-        active_mask: np.ndarray,
-        step_limits: np.ndarray,
-        eos_tokens: Optional[np.ndarray] = None,
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Synchronous fused window (dispatch + immediate reconcile).
-        NOTE: the host lengths stay advanced by the FULL per-slot
-        limits; callers roll back early-retired slots with
-        cache.truncate(slot, lengths + taken) like the scheduler's
-        window commit does."""
-        return self.decode_multi_reconcile(
-            self.decode_multi_dispatch(
-                params, tokens, active_mask, step_limits, eos_tokens
-            )
-        )
 
     # -- verify (speculative decoding) ---------------------------------------
 

@@ -168,9 +168,10 @@ class FaultPlan:
     # scheduler iteration. Phase "begin" crashes at the step boundary
     # BEFORE any work (nothing new to lose); phase "commit" crashes at
     # the END of the iteration AFTER tokens were emitted but BEFORE the
-    # journal's commit flush — the worst case: a whole fused multi-step
-    # window's or tree-verify round's accepted run is host-visible yet
-    # unjournaled, and the restart must recompute it token-identically.
+    # journal's commit flush — the worst case: a whole verify or
+    # tree-verify round's accepted run (a multi-token commit) is
+    # host-visible yet unjournaled, and the restart must recompute it
+    # token-identically.
     crash_iters: Mapping[int, str] = dataclasses.field(default_factory=dict)
     # journal write failure: at each listed iteration the NEXT journal
     # append refuses (OSError stand-in); the journal must degrade, not
@@ -406,8 +407,8 @@ class FaultInjector:
         "begin" right after `on_iteration` (the step dies before doing
         work) and "commit" at the end of `_end_iteration` BEFORE the
         journal's commit flush (the step's emitted tokens die
-        unjournaled — a crash mid-fused-window or mid-tree-verify, since
-        those reconcile exactly once per iteration)."""
+        unjournaled — a crash mid-tree-verify, whose multi-token commit
+        reconciles exactly once per iteration)."""
         if self.plan.crash_iters.get(self._iter) == phase:
             self.injected["crash"] += 1
             raise ProcessCrash(

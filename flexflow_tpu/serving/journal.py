@@ -12,8 +12,8 @@ that state as it is created and rebuilds it after a crash:
   params, tenant/class/adapter — appended the moment the scheduler
   accepts (or strict=False-rejects) a request;
 * **commit records** — the accepted token RUN per request per host
-  sync, written at the reconcile grain: a fused multi-step window or a
-  tree-verify batch journals its whole accepted run as one record, a
+  sync, written at the reconcile grain: a verify or tree-verify step
+  (a multi-token commit) journals its whole accepted run as one record, a
   plain decode one token — the journal's granularity is the engine's,
   not per-token;
 * **terminal records** — final status + error, written by `_finalize`
@@ -304,8 +304,8 @@ class RequestJournal:
 
     def commit_pending(self, iteration: int) -> None:
         """One commit record per request with fresh tokens — the
-        per-host-sync grain: a K-step fused window's or a tree-verify
-        round's whole accepted run lands as one record."""
+        per-host-sync grain: a verify or tree-verify round's whole
+        accepted run (a multi-token commit) lands as one record."""
         if self.degraded or not self._pending:
             return
         # detach the batch first: a write failure mid-loop degrades the

@@ -258,12 +258,8 @@ _STAT_FIELDS: Dict[str, object] = dict(
     chunk_tokens=0,  # Σ prompt tokens streamed in via chunks
     budget_deferrals=0,  # prefill-pending slots granted no tokens
     budget_used=0,  # tokens the LAST iteration charged to its budget
-    # device-resident multi-step decode (decode_multistep=True)
-    multistep_windows=0,  # fused K-step scan windows dispatched
-    multistep_steps=0,  # Σ decode steps executed inside fused windows
     host_syncs=0,  # step RECONCILES, all kinds: one per step, however
     # many device values it reads (those are device_syncs, below)
-    multistep_cache_entries=0,  # live jitted scan programs (LRU gauge)
 
     # request lifecycle (filled at terminal transitions)
     submitted_requests=0,
@@ -519,10 +515,9 @@ class SchedulerStats:
 
     @property
     def host_syncs_per_token(self) -> float:
-        """Host round-trips (step reconciles) per committed token — the
-        cost the device-resident multi-step loop exists to amortize:
-        the step-at-a-time loop sits at ~1.0, a fused K-step window
-        pushes it toward 1/K."""
+        """Host round-trips (step reconciles) per committed token: plain
+        decode sits at ~1.0, a verify step that accepts drafts commits
+        several tokens behind one."""
         if not self.tokens_generated:
             return 0.0
         return self.host_syncs / self.tokens_generated
@@ -570,8 +565,6 @@ class _SchedulerBase:
         chunk_size: int = 16,
         kv_swap: bool = False,
         swap_decider=None,
-        decode_multistep: bool = False,
-        max_fused_steps: int = 8,
         classes=None,
         victim_pricer=None,
         journal=None,
@@ -596,10 +589,6 @@ class _SchedulerBase:
         if self.spec_branch < 1:
             raise ValueError(f"spec_branch must be >= 1, got {spec_branch}")
         self._tree_nodes = self.spec_k * self.spec_branch
-        # iteration-scoped dry-proposal cache: _fusable_steps may draft
-        # to learn whether speculation has work this iteration; the
-        # result is handed to _verify_once so nothing drafts twice
-        self._cached_proposals = None
         if admission not in _ADMISSION_MODES:
             raise ValueError(
                 f"admission must be one of {_ADMISSION_MODES}, "
@@ -648,9 +637,9 @@ class _SchedulerBase:
         # attached, submit/commit/terminal records flow through it at
         # the seams below — submit() at queue entry, _emit -> note
         # (buffered), _end_iteration -> commit_pending (ONE commit
-        # record per request per host sync, so a fused window or
-        # tree-verify round journals its accepted run at its natural
-        # grain), _finalize -> terminal. The commit flush runs INSIDE
+        # record per request per host sync, so a verify or tree-verify
+        # round journals its accepted run at its natural grain),
+        # _finalize -> terminal. The commit flush runs INSIDE
         # step(), before any front door can observe the new tokens:
         # journal-before-publish (fxlint FX111).
         self.journal = journal
@@ -668,18 +657,6 @@ class _SchedulerBase:
         # refuses (budget / in-flight step), or the injector fails it.
         self.kv_swap = bool(kv_swap)
         self.swap_decider = swap_decider
-        # device-resident multi-step decode: when on, runs of decode
-        # iterations with no host-visible event pending fuse into ONE
-        # jitted lax.scan window of up to max_fused_steps steps
-        # (engine.decode_multi_dispatch) reconciled in a single host
-        # sync — see _fusable_steps for the event list that holds
-        # fusing to one step
-        self.decode_multistep = bool(decode_multistep)
-        self.max_fused_steps = int(max_fused_steps)
-        if self.decode_multistep and self.max_fused_steps < 1:
-            raise ValueError(
-                f"max_fused_steps must be >= 1, got {max_fused_steps}"
-            )
         # ServeConfig.debug_invariants / --check-invariants: re-derive
         # the cache/allocator accounting after EVERY iteration (what the
         # chaos harness does), so an invariant violation surfaces at the
@@ -1720,10 +1697,6 @@ class _SchedulerBase:
                 nxt, finite = self.engine.decode_reconcile(step)
             elif step.kind == "chunk":
                 nxt, logits = self.engine.prefill_chunk_reconcile(step)
-            elif step.kind == "multistep":
-                toks_ks, logits_ks, mask_ks = self.engine.decode_multi_reconcile(
-                    step
-                )
             else:
                 logits = self.engine.verify_reconcile(step)
         except Exception as e:
@@ -1745,8 +1718,6 @@ class _SchedulerBase:
                 self._commit_decode(step, nxt, finite)
             elif step.kind == "chunk":
                 self._commit_chunk(step, nxt, logits)
-            elif step.kind == "multistep":
-                self._commit_multistep(step, toks_ks, logits_ks, mask_ks)
             elif step.kind == "verify_tree":
                 self._commit_verify_tree(step, logits)
             else:
@@ -1755,9 +1726,7 @@ class _SchedulerBase:
             # the step's whole in-flight window (dispatch → outputs
             # materialized) on a device lane
             self._tele.tracer.device_window(
-                f"multistep[{int(step.k_steps)}]"
-                if step.kind == "multistep"
-                else step.kind,
+                step.kind,
                 step.seq,
                 step.dispatch_t,
                 t1,
@@ -1802,193 +1771,6 @@ class _SchedulerBase:
         step = self._decode_dispatch_step()
         if step is not None:
             self._reconcile_step(step)
-
-    # -- device-resident multi-step decode (decode_multistep=True) -----------
-
-    def _fusable_steps(self) -> int:
-        """How many decode steps the NEXT dispatch may fuse into one
-        device-resident scan window: `max_fused_steps` when no
-        host-visible event can need the host mid-window, else 1. The
-        events that hold fusing to a single step: a speculative
-        iteration with live drafts (a verify's acceptance is host
-        logic), a non-empty queue (admission next iteration changes
-        the batch), optimistic admission (preemption must never
-        coexist with an open window), any chunk streaming in progress
-        or a final chunk that just committed (phase changes), and
-        deferred cancels waiting on a reconcile. Deadlines
-        deliberately do NOT hold fusing: a deadline expiring
-        mid-window reaps at the window's reconcile — at most K-1 steps
-        of wasted (discarded) device work, the same one-step-stale
-        contract the async loop already carries. Per-slot EOS and
-        page-boundary caps are handled inside the window itself
-        (`_decode_multi_dispatch_step`), not here.
-
-        Speculation holds fusing only while it has something to
-        verify: a STATELESS proposer is dry-run here (result cached
-        for `_verify_once`, nothing drafts twice) and an iteration
-        where no slot drafted — cold n-gram table, post-rollback gap —
-        fuses exactly like plain decode. A stateful proposer keeps the
-        unconditional one-step hold: its draft cache must advance with
-        every committed token. A fused draft+verify round (one device
-        window that drafts AND scores) would relax the live-drafts
-        hold too; the tree-verify mask is already threaded as data
-        (`InflightStep.tree_parents`), which is the seam such a fused
-        kernel would dispatch through."""
-        if not self.decode_multistep or self.max_fused_steps <= 1:
-            return 1
-        if self.queue:
-            return 1
-        if self.admission == "optimistic":
-            return 1
-        if self._chunk_unlocked:
-            return 1
-        if any(self._prefill_pending(r) for r in self.running.values()):
-            return 1
-        if getattr(self, "_pending_cancels", None):
-            return 1
-        if self.proposer is not None:
-            if not getattr(self.proposer, "stateless", False):
-                return 1
-            if self._dry_propose():
-                return 1
-        return int(self.max_fused_steps)
-
-    def _dry_propose(self) -> bool:
-        """Draft for this iteration ahead of the fuse/verify decision
-        and cache the result for `_verify_once`; True when any slot has
-        a live draft (speculation needs the per-iteration host sync)."""
-        if self.spec_branch > 1:
-            trees = self._propose_trees()
-            self._cached_proposals = ("tree", trees)
-            return any(len(t.tokens) > 0 for t in trees.values())
-        proposals = self._propose(self.spec_k)
-        self._cached_proposals = ("linear", proposals)
-        return any(len(d) > 0 for d in proposals.values())
-
-    def _decode_multi_dispatch_step(self, k: int):
-        """Dispatch phase of one fused K-step decode window: per slot,
-        cap the window depth at the request's remaining token budget,
-        the cache horizon, and the distance to the next
-        page boundary — so the window claims AT MOST one fresh page per
-        slot, which `_secure_pages` handles exactly like a plain decode
-        step's claim. Every cache read on this side goes through
-        `int()`/`np` snapshots (fxlint FX109a): the scan then runs K
-        steps device-side against this dispatch's snapshot, carrying
-        sampling, EOS detection, and length bumps in the scan state.
-        Returns the InflightStep, or None when there is nothing to
-        step."""
-        stepped: Dict[int, Request] = {}
-        limits: Dict[int, int] = {}
-        ps = self.cache.spec.page_size
-        max_len = self.cache.spec.max_len
-        for slot, req in self.running.items():
-            if self._prefill_pending(req) or slot in self._chunk_unlocked:
-                continue
-            cur_len = int(self.cache.lengths[slot])
-            cap = min(
-                k,
-                req.max_new_tokens - len(req.generated),
-                max_len - cur_len,
-            )
-            # page-boundary truncation: the window ends where the
-            # slot's next fresh page would begin
-            cap = min(cap, ps - (cur_len % ps))
-            if cap >= 1:
-                stepped[slot] = req
-                limits[slot] = cap
-        self._secure_pages({slot: 1 for slot in stepped})
-        stepped = {s: r for s, r in stepped.items() if self.running.get(s) is r}
-        if not stepped:
-            return None
-        spec = self.cache.spec
-        tokens = np.zeros(spec.max_seqs, dtype=np.int32)
-        active = np.zeros(spec.max_seqs, dtype=bool)
-        step_limits = np.zeros(spec.max_seqs, dtype=np.int32)
-        eos = np.full(spec.max_seqs, -1, dtype=np.int32)
-        for slot, req in stepped.items():
-            tokens[slot] = req.generated[-1]
-            active[slot] = True
-            step_limits[slot] = limits[slot]
-            if req.eos_token is not None:
-                eos[slot] = int(req.eos_token)
-        args = {"iter": self._iter, "active": int(active.sum())}
-        try:
-            with span("scheduler.step.multistep.dispatch", self._tracer, args):
-                step = self.engine.decode_multi_dispatch(
-                    self.params, tokens, active, step_limits, eos_tokens=eos
-                )
-                args["k"] = kmax = int(step.k_steps)
-        except KernelCompileError:
-            raise  # never ran: not a fault to isolate
-        except Exception as e:
-            self._fail_all_running(f"multistep decode failed: {e!r}")
-            return None
-        if self._tele is not None:
-            reg = self._tele.registry
-            reg.counter(
-                "serve_multistep_windows_total",
-                help="fused K-step decode windows dispatched",
-            ).inc()
-            reg.counter(
-                "serve_multistep_steps_total",
-                help="decode steps executed inside fused windows",
-            ).inc(kmax)
-            reg.histogram(
-                "serve_multistep_window_size",
-                bounds=(1, 2, 4, 8, 16, 32, 64),
-                help="fused-window depth K per dispatched window",
-            ).observe(float(kmax))
-        step.iteration = self._iter
-        step.participants = stepped
-        self._note_dispatch(step)
-        stats = self.stats
-        stats.multistep_windows += 1
-        stats.multistep_steps += kmax
-        stats.decode_steps += kmax
-        stats.slot_steps += spec.max_seqs * kmax
-        stats.busy_slot_steps += int(step_limits.sum())
-        self._budget_used_iter += int(step_limits.sum())
-        return step
-
-    def _commit_multistep(self, step, toks_ks, logits_ks, mask_ks) -> None:
-        """Commit a reconciled K-step window: per slot, roll the cache
-        back from the dispatch-time pre-advance to the length the scan
-        actually took (an in-scan EOS hit clears the per-step mask for
-        every later step, so `taken` lands exactly at the EOS
-        position), then emit the taken tokens in order. Rollback runs
-        BEFORE emitting: _emit may retire the request, which frees the
-        slot (truncating a freed slot would be an error). Reads ONLY
-        the step record — pre-step lengths, per-slot limits, and the
-        per-step token/logit/mask stacks all ride the InflightStep
-        (fxlint FX103/FX109b); live cache state is a full window
-        ahead."""
-        active_slots = [s for s, a in enumerate(step.active) if a]
-        if self.injector is not None:
-            logits_ks = np.array(logits_ks)  # writable copy
-            self.injector.corrupt_logits(
-                logits_ks[0], active_slots, iteration=step.iteration
-            )
-        for slot in active_slots:
-            req = step.participants.get(slot)
-            if req is None or self.running.get(slot) is not req:
-                continue
-            taken = int(mask_ks[:, slot].sum())
-            if taken < int(step.step_limits[slot]):
-                # EOS retired the slot mid-window: return the unused
-                # pre-advanced rows (paged slots give surplus pages
-                # back to the reserve) before any emit can free it
-                self.cache.truncate(slot, int(step.lengths[slot]) + taken)
-            for i in range(taken):
-                if not np.isfinite(logits_ks[i, slot]).all():
-                    self._fail(
-                        req,
-                        f"non-finite logits at iteration "
-                        f"{step.iteration} (window step {i})",
-                    )
-                    break
-                self._emit(req, int(toks_ks[i, slot]))
-                if self.running.get(slot) is not req:
-                    break  # retired (EOS/budget) — nothing past it
 
     def _propose(self, k: int) -> Dict[int, List[int]]:
         """Draft tokens for the running slots; a proposer fault (real or
@@ -2141,26 +1923,11 @@ class _SchedulerBase:
     def _verify_once(self) -> None:
         """Synchronous speculative iteration: draft up to spec_k tokens
         per slot (a spec_branch-way tree under tree speculation),
-        dispatch ONE batched verify, and reconcile it immediately.
-        Consumes `_fusable_steps`' dry-proposal when one was cached
-        this iteration, so the fuse-or-verify probe never drafts
-        twice."""
-        cached = self._cached_proposals
-        self._cached_proposals = None
+        dispatch ONE batched verify, and reconcile it immediately."""
         if self.spec_branch > 1:
-            trees = (
-                cached[1]
-                if cached is not None and cached[0] == "tree"
-                else self._propose_trees()
-            )
-            step = self._verify_tree_dispatch_step(trees)
+            step = self._verify_tree_dispatch_step(self._propose_trees())
         else:
-            proposals = (
-                cached[1]
-                if cached is not None and cached[0] == "linear"
-                else self._propose(self.spec_k)
-            )
-            step = self._verify_dispatch_step(proposals)
+            step = self._verify_dispatch_step(self._propose(self.spec_k))
         if step is not None:
             self._reconcile_step(step)
 
@@ -2669,15 +2436,6 @@ class _SchedulerBase:
             self._reconcile_step(step)
 
     def _generate_once(self) -> None:
-        # the fuse probe runs FIRST even under speculation: an
-        # iteration where no slot drafted (see _fusable_steps) runs a
-        # fused decode window instead of a degenerate w=1 verify
-        k = self._fusable_steps()
-        if k > 1:
-            step = self._decode_multi_dispatch_step(k)
-            if step is not None:
-                self._reconcile_step(step)
-            return
         if self.proposer is not None:
             self._verify_once()
             return
@@ -2691,7 +2449,6 @@ class _SchedulerBase:
             self.stats.iterations += 1
             self._budget_used_iter = 0
             self._chunk_unlocked.clear()
-            self._cached_proposals = None
             if self.injector is not None:
                 self.injector.on_iteration(self._iter, self)
                 # chaos: process death at the step boundary, before any
@@ -2704,7 +2461,7 @@ class _SchedulerBase:
 
     #: engine ledgers mirrored into the stats at each iteration's end
     _ENGINE_MIRRORS = (
-        "verify_cache_entries", "kernel_fallbacks", "multistep_cache_entries",
+        "verify_cache_entries", "kernel_fallbacks",
         "device_syncs", "readback_bytes", "prefill_tokens_real",
         "prefill_tokens_padded", "prefill_programs", "pool_steps_donated",
         "pool_steps_copied",
@@ -2751,10 +2508,10 @@ class _SchedulerBase:
         if self.injector is not None:
             # chaos: process death AFTER this iteration's tokens were
             # emitted but BEFORE the journal's commit flush below — the
-            # worst case: a whole fused multi-step window's or
-            # tree-verify round's accepted run is host-visible yet
-            # unjournaled, and the restart must recompute it
-            # token-identically from the last durable cursor
+            # worst case: a whole verify or tree-verify round's
+            # accepted run is host-visible yet unjournaled, and the
+            # restart must recompute it token-identically from the last
+            # durable cursor
             crash = getattr(self.injector, "maybe_crash", None)
             if crash is not None:
                 crash("commit")
@@ -3074,11 +2831,6 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
         return bool(self.queue or self.running or self._inflight)
 
     def step(self) -> None:
-        if self.proposer is not None and self.decode_multistep:
-            # a verify's and a fused window's inputs are both the host's
-            # decisions, and which of the two an iteration runs is one
-            # more: nothing is left to keep in flight
-            return super().step()
         self._begin_iteration()
         if self.proposer is not None:
             self._admit()
@@ -3088,8 +2840,7 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
             # chained decode step of the slots already running, and only
             # then is the prefill read back, so the device goes from the
             # prefill into a decode step and not into the host's wake-up.
-            # (A fused window reads committed tokens: it admits whole.)
-            self._admit(defer=not self.decode_multistep)
+            self._admit(defer=True)
             self._decode_iteration_async()
             if self._admission is not None:
                 with span("scheduler.step.admit", self._tracer):
@@ -3104,19 +2855,7 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
         prompt tokens are accepted by construction, the engine advances
         lengths at dispatch), so chunks pipeline exactly like chained
         decodes and both steps of iteration N ride the device while the
-        host reconciles N-1.
-
-        A fused multi-step window (decode_multistep=True) rides the
-        same deque but cannot be token-chained — its last token is K
-        steps deep in the scan — so any in-flight step drains before a
-        window dispatches (the window reads committed generated[-1]
-        tokens), and an open window drains at the NEXT iteration's top
-        before anything else dispatches, which is also where deferred
-        cancels and running-deadline reaping land. The host work that
-        overlaps an open window is the next iteration's admission and
-        bookkeeping, exactly as for a plain in-flight step."""
-        if any(s.kind == "multistep" for s in self._inflight):
-            self._drain_inflight()
+        host reconciles N-1."""
         keep = 0
         if self.token_budget and self.running:
             step = self._chunk_dispatch_step(
@@ -3126,34 +2865,17 @@ class AsyncContinuousBatchingScheduler(ContinuousBatchingScheduler):
                 self._inflight.append(step)
                 keep += 1
         if self.running:
-            k = self._fusable_steps()
-            if k > 1:
-                # k > 1 implies no chunk streaming in progress, so
-                # nothing was appended above (keep == 0); drain any
-                # plain decode step still in flight from the previous
-                # iteration — the window's input tokens must be
-                # committed before the scan captures them
-                self._drain_inflight()
-                step = self._decode_multi_dispatch_step(k)
-                if step is not None:
-                    self._inflight.append(step)
-                    keep += 1
-            else:
-                # chain on the newest in-flight DECODE step — an
-                # interleaved chunk step never carries the decoding
-                # slots' next tokens
-                chain = next(
-                    (
-                        s
-                        for s in reversed(self._inflight)
-                        if s.kind == "decode"
-                    ),
-                    None,
-                )
-                step = self._decode_dispatch_step(chain=chain)
-                if step is not None:
-                    self._inflight.append(step)
-                    keep += 1
+            # chain on the newest in-flight DECODE step — an
+            # interleaved chunk step never carries the decoding
+            # slots' next tokens
+            chain = next(
+                (s for s in reversed(self._inflight) if s.kind == "decode"),
+                None,
+            )
+            step = self._decode_dispatch_step(chain=chain)
+            if step is not None:
+                self._inflight.append(step)
+                keep += 1
         while len(self._inflight) > keep:
             self._reconcile_front()
         if not keep:

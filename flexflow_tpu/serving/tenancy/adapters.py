@@ -350,7 +350,7 @@ class AdapterPool:
 
     def slot_tables(self) -> Tuple[np.ndarray, np.ndarray]:
         """(tbl [max_seqs, P] int32, has [max_seqs] bool) for the
-        slot-indexed steps (decode/verify/multistep/chunk). Fresh host
+        slot-indexed steps (decode/verify/chunk). Fresh host
         arrays — the engine snapshots them at dispatch, so the step
         rides its own copy (FX103 discipline)."""
         has = self.slot_adapter >= 0

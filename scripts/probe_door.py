@@ -22,7 +22,11 @@ it, from the harness's own stamps of the requests due in the window:
   passes 2 and later of those that found an iteration running. A tree
   from before PR 42 has no such counter and reads `null`.
 
-Each as [p50, p90]. Run it in a process that fetches its programs (after
+Each as [p50, p90]. With `PROBE_DUMP=<file>` in the environment every
+request's stamps and every step's tuple are written there as JSON, so
+that two trees can be compared request by request (same seed, same
+index) where a percentile is one request's luck (`serve_gpt2m_chat`'s
+`ttft_p50_ms`: `PERF.md` section 7, PR 43). Run it in a process that fetches its programs (after
 one `benchmarks/run.py` of the same cell in the same checkout): a
 process that compiles starts over as `benchmarks/run.py` and the probe's
 line is lost.
@@ -86,6 +90,15 @@ def main(argv=None) -> int:
             "later_share": later,
         }
     }), flush=True)
+    dump = os.environ.get("PROBE_DUMP")
+    if dump:
+        stamps = ("index", "segment", "prompt_len", "asked", "due", "started",
+                  "accepted", "admit", "admit_iter", "first", "last", "done", "tokens")
+        with open(dump, "w") as f:
+            json.dump({
+                "requests": [{k: getattr(r, k) for k in stamps} for r in seen["records"]],
+                "steps": [[float(x) for x in step] for step in seen["steps"]],
+            }, f)
     return 0
 
 

@@ -1,7 +1,7 @@
 """FX109/FX103 positives — token-tree verify violations.
 
 A tree-verify dispatch captures live allocator state into the jitted
-tree step (FX109, tree extension of part a), and a tree reconcile
+tree step (FX109), and a tree reconcile
 reads the dispatched parent table / DraftTree plan from a
 scheduler-side mirror instead of the step record (FX103).
 """

@@ -27,6 +27,7 @@ from flexflow_tpu import (
 from flexflow_tpu.models import (
     build_decoder_lm,
     build_deepseek_v3,
+    build_kimi_linear,
     build_olmoe,
     build_ouro,
 )
@@ -98,10 +99,25 @@ def _run(lm, serve_async, layout="paged", n=6, max_new=8, reqs=None,
 # -- token-identity parity ----------------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["one_page", "paged"])
-def test_async_matches_sync_greedy_streams(lm, layout):
-    _, _, _, sync = _run(lm, False, layout)
-    _, _, _, asy = _run(lm, True, layout)
+@pytest.mark.parametrize(
+    "layout,kw",
+    [
+        ("one_page", {}),
+        ("paged", {}),
+        ("paged", dict(kv_dtype="int8")),
+        ("paged", dict(prefix_cache=True)),
+        # the Pallas kernel, interpreted on the CPU
+        ("paged", dict(decode_kernel="pallas")),
+        ("paged", dict(decode_kernel="pallas", kv_dtype="int8")),
+    ],
+    ids=["one_page", "paged", "paged-int8", "paged-prefix", "paged-pallas",
+         "paged-pallas-int8"],
+)
+def test_async_matches_sync_greedy_streams(lm, layout, kw):
+    _, _, _, sync = _run(lm, False, layout, **kw)
+    sched, engine, _, asy = _run(lm, True, layout, **kw)
+    assert sched.stats.decode_steps_chained > 0
+    assert engine.kernel_fallbacks == 0
     assert set(sync) == set(asy)
     for rid in sync:
         assert sync[rid].ok and asy[rid].ok
@@ -499,6 +515,14 @@ _TOYS = {
     "ouro": lambda m, tok: build_ouro(
         m, tok, vocab_size=_TOY_VOCAB, hidden=32, num_heads=4, num_layers=2,
         ff_dim=48, loops=3, rope_theta=1e4, eps=1e-6,
+    ),
+    "kimi_linear": lambda m, tok: build_kimi_linear(
+        m, tok, experts_held=(0, 2), vocab_size=_TOY_VOCAB, hidden=32,
+        num_heads=4, num_layers=3, kda_layers=(1, 3), full_attn_layers=(2,),
+        kda_head_dim=8, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, dense_hidden=48, dense_layers=1,
+        expert_hidden=16, num_experts=4, experts_per_token=2,
+        shared_experts=1, routed_scale=2.0, eps=1e-6, kda_chunk=8,
     ),
 }
 

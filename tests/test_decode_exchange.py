@@ -4,8 +4,9 @@ The scheduler's reconcile reads ONE small int32 vector (the picked tokens,
 a finite flag per slot, the expert layers' counts), whose copy starts at
 dispatch; the logits stay on the device for who asks (`engine.decode()`);
 `_dispatch` forces a program's first run only. Held here, on the CPU, for
-the three served model kinds (a dense decoder, the OLMoE block, the
-`deepseek_v3` block) and both loops:
+the five served model kinds (a dense decoder, the OLMoE block, the
+`deepseek_v3` block, Ouro's looped stack, Kimi-Linear's recurrent layers
+with their per-slot state among the donated pools) and both loops:
 
 * a scripted run's token streams equal what stepping `engine.decode()` by
   hand gives;
@@ -23,7 +24,13 @@ import pytest
 
 from flexflow_tpu import DataType, FFConfig, FFModel, LossType, SGDOptimizer
 from flexflow_tpu.core.types import OperatorType
-from flexflow_tpu.models import build_decoder_lm, build_deepseek_v3, build_olmoe
+from flexflow_tpu.models import (
+    build_decoder_lm,
+    build_deepseek_v3,
+    build_kimi_linear,
+    build_olmoe,
+    build_ouro,
+)
 from flexflow_tpu.serving import Request, ServeConfig, build_scheduler
 from flexflow_tpu.serving.engine import KernelCompileError, PoolsLostError
 from flexflow_tpu.serving.faults import FaultInjector, FaultPlan
@@ -49,9 +56,25 @@ BUILDERS = {
         expert_hidden=16, num_experts=4, experts_per_token=2,
         shared_experts=1, routed_scale=2.0, rope_theta=1e4, eps=1e-6,
     ),
+    # two layers run twice over one set of weights; returns its head
+    "ouro": lambda m, tok: build_ouro(
+        m, tok, vocab_size=VOCAB, hidden=32, num_heads=4, num_layers=2,
+        ff_dim=48, loops=2, rope_theta=1e4, eps=1e-6,
+    ),
+    # two recurrent layers round one of latent attention
+    "kimi_linear": lambda m, tok: build_kimi_linear(
+        m, tok, experts_held=(0, 2), vocab_size=VOCAB, hidden=32,
+        num_heads=4, num_layers=3, kda_layers=(1, 3), full_attn_layers=(2,),
+        kda_head_dim=8, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, dense_hidden=48, dense_layers=1,
+        expert_hidden=16, num_experts=4, experts_per_token=2,
+        shared_experts=1, routed_scale=2.0, eps=1e-6, kda_chunk=8,
+    ),
 }
 #: int32 counts a decode program returns beside tokens and flags
-COUNTS = {"dense": 0, "olmoe": 2, "deepseek_v3": 3}
+COUNTS = {"dense": 0, "olmoe": 2, "deepseek_v3": 3, "ouro": 0, "kimi_linear": 3}
+#: expert layers of the toy
+EXPERT_LAYERS = {"dense": 0, "olmoe": 2, "deepseek_v3": 1, "ouro": 0, "kimi_linear": 2}
 
 
 @pytest.fixture(scope="module", params=list(BUILDERS))
@@ -59,11 +82,13 @@ def served(request):
     cfg = FFConfig(batch_size=SLOTS, seed=3)
     model = FFModel(cfg)
     tok = model.create_tensor([SLOTS, SEQ], dtype=DataType.INT32, name="tokens")
-    BUILDERS[request.param](model, tok)
+    out = BUILDERS[request.param](model, tok)
     model.compile(
         optimizer=SGDOptimizer(lr=0.01),
         loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
         metrics=[], devices=jax.devices()[:1],
+        # Ouro's exit gates are sinks beside its head
+        logits=out if request.param == "ouro" else None,
     )
     return request.param, model
 
@@ -210,10 +235,10 @@ def test_counts_ride_the_one_readback(served):
     sched, engine, _ = _build(model)
     sched.run(_requests())
     st = sched.stats
-    if kind == "dense":
+    layers = EXPERT_LAYERS[kind]
+    if not layers:
         assert st.moe_rows_decode == 0
     else:
-        layers = 2 if kind == "olmoe" else 1  # expert layers of the toy
         # every busy slot's row goes to two experts in each expert layer;
         # a layer that holds a share leaves some of them to the others
         rows = st.moe_rows_decode + st.moe_rows_absent_decode

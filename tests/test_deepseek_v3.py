@@ -419,8 +419,9 @@ def case_swap_round_trip(model):
     assert _gap(logits[slot][None], want) < TOL
 
 
-def case_multistep(model):
-    """The fused multi-step decode scan traces the same decode core."""
+def case_scheduler_streams(model):
+    """The overlapped loop's chained decode steps emit what the
+    synchronous loop emits, and that is the reference's greedy stream."""
     from flexflow_tpu.serving import Request
 
     def run(**kw):
@@ -432,8 +433,8 @@ def case_multistep(model):
         out = {r.rid: r.generated for r in sched.run(reqs)}
         return out, sched.stats
 
-    plain, stats = run()
-    assert run(decode_multistep=True, max_fused_steps=4)[0] == plain
+    plain, stats = run(serve_async=False)
+    assert run()[0] == plain
     # the plain stream is the reference's greedy stream
     seq = _prompt(5, salt=0)
     for tok in plain[0]:

@@ -494,95 +494,6 @@ def test_seeded_reconcile_bypass_is_caught(tmp_path):
     assert run_rules([str(clean)], ["dispatch-race"]) == []
 
 
-def test_multistep_fixtures():
-    """FX109: device-resident multi-step decode discipline — (a) a
-    multi-step dispatch capturing live allocator state into the fused
-    K-step scan window, (b) a window reconcile reading the window's
-    geometry from a scheduler-side mirror instead of the step record."""
-    diags = _by_file(
-        run_rules([os.path.join(FIXTURES, "multistep")], ["dispatch-race"])
-    )
-    # raw lengths + raw block tables into the window (2 × part a),
-    # mirror-read window depth (1 × part b)
-    assert diags.get("bad.py", []).count("FX109") == 3, diags
-    # snapshot()/np.array carriers, int() scalars, the pre-advance
-    # store, and step-record reads all silent
-    assert "good.py" not in diags
-
-
-def test_seeded_multistep_capture_is_caught(tmp_path):
-    """Re-introduce the bug FX109a exists for: hand the fused window
-    the LIVE length table instead of the snapshot — the scan would
-    read it K steps behind the dispatch queue. fxlint must flag it;
-    the unmodified engine stays clean."""
-    src_path = os.path.join(PACKAGE, "serving", "engine.py")
-    with open(src_path) as f:
-        src = f.read()
-    seeded = src.replace(
-        "                snapshot(self.cache.lengths),\n"
-        "                jnp.asarray(np.asarray(active_mask, dtype=bool)),\n",
-        "                self.cache.lengths,\n"
-        "                jnp.asarray(np.asarray(active_mask, dtype=bool)),\n",
-        1,
-    )
-    assert seeded != src, (
-        "engine.py's decode_multi_dispatch no longer snapshots "
-        "cache.lengths next to the bool active mask — update this "
-        "seeding recipe alongside the refactor"
-    )
-    (tmp_path / "engine.py").write_text(seeded)
-    diags = run_rules([str(tmp_path)], ["dispatch-race"])
-    assert any(
-        d.rule_id == "FX109" and "lengths" in d.message for d in diags
-    ), [d.format() for d in diags]
-    # the unmodified engine stays clean
-    clean = tmp_path / "clean"
-    clean.mkdir()
-    shutil.copy(src_path, clean / "engine.py")
-    assert run_rules([str(clean)], ["dispatch-race"]) == [], [
-        d.format() for d in run_rules([str(clean)], ["dispatch-race"])
-    ]
-
-
-def test_seeded_window_mirror_read_is_caught(tmp_path):
-    """Re-introduce the bug FX109b exists for: make the step reconcile
-    label the Perfetto span from a scheduler-side window mirror
-    instead of the step record's own k_steps."""
-    src_path = os.path.join(PACKAGE, "serving", "scheduler.py")
-    with open(src_path) as f:
-        src = f.read()
-    seeded = src.replace(
-        'f"multistep[{int(step.k_steps)}]"',
-        'f"multistep[{int(self._last_step.k_steps)}]"',
-        1,
-    )
-    assert seeded != src, (
-        "scheduler.py's _reconcile_step no longer labels the span from "
-        "step.k_steps — update this seeding recipe alongside the "
-        "refactor"
-    )
-    (tmp_path / "scheduler.py").write_text(seeded)
-    shutil.copy(
-        os.path.join(PACKAGE, "serving", "kv_cache.py"),
-        tmp_path / "kv_cache.py",
-    )
-    diags = run_rules([str(tmp_path)], ["dispatch-race"])
-    assert any(
-        d.rule_id == "FX109" and "k_steps" in d.message for d in diags
-    ), [d.format() for d in diags]
-    # the unmodified pair stays clean
-    clean = tmp_path / "clean"
-    clean.mkdir()
-    shutil.copy(src_path, clean / "scheduler.py")
-    shutil.copy(
-        os.path.join(PACKAGE, "serving", "kv_cache.py"),
-        clean / "kv_cache.py",
-    )
-    assert run_rules([str(clean)], ["dispatch-race"]) == [], [
-        d.format() for d in run_rules([str(clean)], ["dispatch-race"])
-    ]
-
-
 def test_tree_fixtures():
     """Token-tree verify discipline — (a) FX109: a tree-verify
     dispatch capturing live allocator state into the jitted tree step,
@@ -601,7 +512,7 @@ def test_tree_fixtures():
 
 
 def test_seeded_tree_capture_is_caught(tmp_path):
-    """Re-introduce the bug the tree FX109 extension exists for: hand
+    """Re-introduce the bug FX109 exists for: hand
     the jitted tree step the LIVE length table instead of the snapshot
     — the step reads it behind the async dispatch queue and the
     reconcile's accept walk runs an iteration later. fxlint must flag

@@ -569,7 +569,6 @@ def test_c4_free_has_no_device_work_and_a_recompute_rebuilds_the_state(served):
 @pytest.mark.parametrize("what,config", [
     ("speculative", dict(spec_draft="ngram")),
     ("chunked-prefill", dict(token_budget=32, chunk_size=16)),
-    ("multi-step", dict(decode_multistep=True)),
     ("kv_swap", dict(kv_swap=True, kv_swap_bytes=1 << 20)),
     ("int8", dict(kv_dtype="int8")),
     ("prefix_cache", dict(prefix_cache=True)),
@@ -585,15 +584,12 @@ def test_c5_what_cannot_keep_the_state_is_refused_in_words(served, what, config)
     lambda e, c, p: e._tree_fn(3),
     lambda e, c, p: e._chunk_fn((1, 8)),
     lambda e, c, p: e.prefill_suffix(p, [_prompt(9)], [0], [4]),
-    lambda e, c, p: e.decode_multi_dispatch(
-        p, np.zeros(4, np.int32), np.ones(4, bool), np.full(4, 2, np.int32)
-    ),
     lambda e, c, p: e.require("draft"),
     lambda e, c, p: c.truncate(0, 4),
     lambda e, c, p: c.swap_out(0),
     lambda e, c, p: c.swap_in(0),
     lambda e, c, p: c.import_swap({}),
-], ids=["verify", "tree", "chunk", "suffix", "multistep", "draft", "truncate",
+], ids=["verify", "tree", "chunk", "suffix", "draft", "truncate",
         "swap_out", "swap_in", "import_swap"])
 def test_c5_the_step_kinds_and_cache_calls_raise_not_serve(served, call):
     _, engine, cache = _serve(served)

@@ -3,9 +3,10 @@
 (a) Every instruction a step program traced itself sits under a node's
 scope (`kind:name`, `Executor.lower_node`), `loss`, `update` or a
 `step.*`: the train step on one device and on a 2x2 mesh, the sparse
-step, the pipelined executor, and the engine's six step programs of a
-dense, an OLMoE-like and a latent toy model. (b) The reduction
-`utils.profiling.fold_step` as a pure function of (events, HLO text).
+step, the pipelined executor, and the engine's five step programs of a
+dense, an OLMoE-like, a latent, a looped and a recurrent toy model.
+(b) The reduction `utils.profiling.fold_step` as a pure function of
+(events, HLO text).
 (c) A recorded trace of a toy train step on a TPU v5e with its compiled
 text (`tests/data/node_scopes_v5e.*`; `python3 tests/test_node_scopes.py`
 on the chip records it), folded to known rows: CPU traces have no
@@ -51,7 +52,20 @@ def _op_names(text):
     ]
 
 
-def _check_scoped(text, graph=None, backward=False):
+def _feeding(graph, guid):
+    """The nodes whose outputs reach node `guid`, itself included."""
+    reach, todo = set(), [guid]
+    while todo:
+        g = todo.pop()
+        if g not in reach:
+            reach.add(g)
+            todo.extend(r.guid for r in graph.nodes[g].inputs)
+    return reach
+
+
+def _check_scoped(text, graph=None, backward=False, only=None):
+    """`only`: the guids held to a scope of their own (a serving program
+    computes what feeds the logits, and a sink beside them is dead code)."""
     names = _op_names(text)
     assert names
     bare = sorted({n for n in names if scope_of(n) is None})
@@ -63,6 +77,8 @@ def _check_scoped(text, graph=None, backward=False):
         # a node without weights (a layout op, a reshape) may leave no
         # instruction of its own in the compiled program
         if not node.inputs or not node.weight_shapes:
+            continue
+        if only is not None and node.guid not in only:
             continue
         scope = f"{node.op_type.name.lower()}:{node.name}"
         assert (scope, "forward") in seen, scope
@@ -169,10 +185,12 @@ def test_pipelined_step_is_scoped():
     assert trunk and ("update", "other") in seen
 
 
-# the engine's six step programs, of three toy models
+# the engine's five step programs, of five toy models
 
-PROGRAMS = ("prefill", "decode", "decode_multi", "verify", "verify_tree", "chunk")
-LATENT_PROGRAMS = ("prefill", "decode", "decode_multi")  # the rest are refused
+PROGRAMS = ("prefill", "decode", "verify", "verify_tree", "chunk")
+REFUSING_PROGRAMS = ("prefill", "decode")  # the rest are refused
+# a model with latent attention or recurrent layers is served by these alone
+REFUSING = ("latent", "kimi_linear")
 
 
 def _dense_model():
@@ -190,26 +208,25 @@ def _dense_model():
     return model
 
 
-def _drive(model, latent):
+def _drive(model, refusing):
     """{program: compiled text} of every step program the engine has for
     the model, each dispatched once."""
-    from flexflow_tpu.serving import Request, ServeConfig, build_scheduler
+    from flexflow_tpu.serving import ServeConfig, build_scheduler
 
     def one_hot(values, slot, dtype=np.int32):
         out = np.zeros((4,) + np.shape(values), dtype)
         out[slot] = values
         return out
 
-    texts = {}
     _, engine, cache = build_scheduler(model, ServeConfig(max_seqs=4, max_seq_len=32))
-    with profiling.step_program_texts(engine) as got:
+    with profiling.step_program_texts(engine) as texts:
         prompt = [(7 * j * j + 3 * j) % 210 + 1 for j in range(9)]
         slot = cache.alloc(len(prompt), len(prompt) + 8)
         nxt, _ = engine.prefill(model.params, [prompt], [slot])
         engine.decode(
             model.params, one_hot(int(nxt[0]), slot), one_hot(True, slot, bool)
         )
-        if not latent:
+        if not refusing:
             draft = [int(nxt[0]), 17, 23, 5]
             engine.verify(
                 model.params, one_hot(draft, slot), one_hot(len(draft), slot)
@@ -224,25 +241,15 @@ def _drive(model, latent):
             engine.prefill_chunk(
                 model.params, one_hot([3, 4, 5, 6], other), one_hot(4, other)
             )
-        texts.update(got)
-    sched, engine, _ = build_scheduler(
-        model,
-        ServeConfig(max_seqs=4, max_seq_len=32, decode_multistep=True, max_fused_steps=4),
-    )
-    with profiling.step_program_texts(engine) as got:
-        sched.run([Request(rid=0, prompt=prompt, max_new_tokens=9)])
-        texts.update(got)
-    out = {}
-    for key, text in texts.items():
-        # the scan is jitted through a `functools.partial`: `jit__unknown`
-        name = re.fullmatch(r"jit__(\w+?)(?:_impl_paged)? \(.*", key).group(1)
-        out.setdefault("decode_multi" if name == "unknown" else name, text)
-    return out
+    return {
+        re.fullmatch(r"jit__(\w+?)_impl_paged \(.*", key).group(1): text
+        for key, text in texts.items()
+    }
 
 
 @pytest.fixture(scope="module")
 def engine_texts():
-    from tests import test_deepseek_v3, test_olmoe
+    from tests import test_deepseek_v3, test_kimi_linear, test_olmoe, test_ouro
 
     made = {}
 
@@ -252,8 +259,10 @@ def engine_texts():
                 "dense": _dense_model,
                 "olmoe": test_olmoe._model,
                 "latent": test_deepseek_v3._model,
+                "ouro": test_ouro._model,
+                "kimi_linear": test_kimi_linear._model,
             }[kind]()
-            made[kind] = (model, _drive(model, latent=kind == "latent"))
+            made[kind] = (model, _drive(model, refusing=kind in REFUSING))
         return made[kind]
 
     return texts
@@ -261,19 +270,20 @@ def engine_texts():
 
 @pytest.mark.parametrize(
     "kind,program",
-    [(k, p) for k in ("dense", "olmoe") for p in PROGRAMS]
-    + [("latent", p) for p in LATENT_PROGRAMS],
+    [(k, p) for k in ("dense", "olmoe", "ouro") for p in PROGRAMS]
+    + [(k, p) for k in REFUSING for p in REFUSING_PROGRAMS],
 )
 def test_engine_step_programs_are_scoped(engine_texts, kind, program):
     model, texts = engine_texts(kind)
-    assert set(texts) == set(LATENT_PROGRAMS if kind == "latent" else PROGRAMS)
-    _check_scoped(texts[program], model.graph)
+    assert set(texts) == set(REFUSING_PROGRAMS if kind in REFUSING else PROGRAMS)
+    _check_scoped(
+        texts[program], model.graph,
+        only=_feeding(model.graph, model.executor.logits_ref.guid),
+    )
     seen = {s for s, _ in map(scope_of, _op_names(texts[program]))}
     assert "step.unpack" in seen
-    if program in ("prefill", "decode", "decode_multi", "chunk"):
+    if program in ("prefill", "decode", "chunk"):
         assert "step.pick" in seen
-    if program == "decode_multi":
-        assert "step.scan" in seen
 
 
 @pytest.mark.parametrize(
@@ -380,9 +390,10 @@ def test_scope_of_reads_the_phase_from_the_wrapper():
     assert scope_of("jit(step)/transpose(jvp(loss))/mul;jit(step)/x") == (
         "loss", "backward")
     # a node's scope wins over the loop's it sits in
-    assert scope_of("jit(f)/step.scan/while/body/closed_call/linear:d/add") == (
+    assert scope_of("jit(f)/step.pipeline/while/body/closed_call/linear:d/add") == (
         "linear:d", "forward")
-    assert scope_of("jit(f)/step.scan/while/body/add") == ("step.scan", "other")
+    assert scope_of("jit(f)/step.pipeline/while/body/add") == (
+        "step.pipeline", "other")
     assert scope_of("jit(step)/jvp()/sharding_constraint") is None
     assert scope_of("params[101][0]") is None
 

@@ -235,8 +235,9 @@ def case_verify_tree(model):
         assert _gap(logits[slot][row][None], want) < TOL, row
 
 
-def case_multistep(model):
-    """The fused multi-step decode scan carries lengths, and so positions."""
+def case_scheduler_streams(model):
+    """A chained decode step takes its tokens on the device and its
+    positions from the lengths the host reserved a step ahead."""
     from flexflow_tpu.serving import Request
 
     def run(**kw):
@@ -247,8 +248,8 @@ def case_multistep(model):
         ]
         return {r.rid: r.generated for r in sched.run(reqs)}
 
-    plain = run()
-    assert run(decode_multistep=True, max_fused_steps=4) == plain
+    plain = run(serve_async=False)
+    assert run() == plain
     # and the plain stream is the reference's greedy stream
     seq = _prompt(5, salt=0)
     for tok in plain[0]:

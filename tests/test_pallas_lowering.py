@@ -333,7 +333,7 @@ def test_mosaic_compiles_head_sharded_kernel(as_tpu):
 # -- the engine's step programs own the pools they rewrite --------------------
 
 from test_pool_donation import (  # noqa: E402,F401
-    KINDS, PROMPT, _step, build_lm, lm,
+    KINDS, PROMPT, RECURRENT_KINDS, _step, build_lm, lm, recurrent_lm,
 )
 
 
@@ -378,7 +378,7 @@ def _step_program(lm, programs, kind, layout, dtype="fp32"):
         ServeConfig(
             max_seqs=2, max_seq_len=32, **page_geometry(layout, 32),
             kv_dtype=dtype,
-            decode_kernel="pallas", decode_multistep=(kind == "multistep"),
+            decode_kernel="pallas",
         ),
     )
     slot = cache.alloc(len(PROMPT), len(PROMPT) + 8)
@@ -389,21 +389,32 @@ def _step_program(lm, programs, kind, layout, dtype="fp32"):
     return jitted.trace(*shapes).lower(lowering_platforms=("tpu",)), cache
 
 
-@pytest.mark.parametrize("layout", ["one_page", "paged"])
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize(
+    "kind,layout",
+    [(k, l) for k in KINDS for l in ("one_page", "paged")]
+    + [(k, "recurrent") for k in RECURRENT_KINDS],
+)
 def test_step_program_donates_exactly_its_pools(
-    lm, step_programs, kind, layout
+    request, step_programs, kind, layout
 ):
-    """Each of the six programs, lowered for the TPU, marks every pool it
+    """Each of the five programs, lowered for the TPU, marks every pool it
     rewrites as donated (the lowered text carries one aliasing attribute a
     pool) and nothing else: an edit that drops the donation at one site,
-    or extends it to the parameters, fails here without a chip."""
-    lowered, cache = _step_program(lm, step_programs, kind, layout)
+    or extends it to the parameters, fails here without a chip. A model
+    with recurrent layers has its per-slot state among them."""
+    recurrent = layout == "recurrent"
+    lowered, cache = _step_program(
+        request.getfixturevalue("recurrent_lm" if recurrent else "lm"),
+        step_programs, kind, "paged" if recurrent else layout,
+    )
     pools = {
         (s.shape, s.dtype)
-        for s in jax.tree.leaves((cache.k, cache.v))
+        for s in jax.tree.leaves((cache.k, cache.v, cache.state))
     }
-    n_pools = 2 * len(cache.spec.layer_guids)
+    spec = cache.spec
+    # K and V a layer, or the one latent pool; S and conv a recurrent layer
+    n_pools = spec.kv_pools * len(spec.layer_guids) + 2 * len(spec.state_guids)
+    assert bool(spec.state_guids) == recurrent
     args = jax.tree.leaves(lowered.args_info)
     donated = [a for a in args if a.donated]
     assert len(donated) == n_pools

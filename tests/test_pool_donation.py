@@ -1,7 +1,7 @@
 """The step programs own the KV pools they rewrite (serving/engine.py
-`_step_jit`): every engine program donates `ck`, `cv` and the scale
-pools, so XLA scatters into the pool it was handed
-instead of copying it whole into a fresh output first.
+`_step_jit`): every engine program donates `ck`, `cv`, the scale
+pools and the recurrent layers' per-slot state, so XLA scatters into the
+pool it was handed instead of copying it whole into a fresh output first.
 
 What that asks of the host, held here on the CPU backend (which honours
 a donation, so every check is exact): after a dispatch the arrays that
@@ -34,13 +34,16 @@ pytestmark = pytest.mark.serving
 VOCAB = 50
 PROMPT = [3, 1, 4, 1, 5]
 SLOTS = 2
-KINDS = ("prefill", "decode", "multistep", "verify", "tree", "chunk")
+KINDS = ("prefill", "decode", "verify", "tree", "chunk")
 # (ServeConfig keywords of the cache geometry, kv_dtype)
-LAYOUTS = [
-    pytest.param(({"kv_page_size": 32}, "fp32"), id="one_page"),
-    pytest.param(({}, "fp32"), id="paged"),
-    pytest.param(({}, "int8"), id="paged-int8"),
-]
+LAYOUTS = {
+    "one_page": ({"kv_page_size": 32}, "fp32"),
+    "paged": ({}, "fp32"),
+    "paged-int8": ({}, "int8"),
+}
+# a model with recurrent layers is served by these two programs alone,
+# and keeps a row a SLOT beside the pages (`cache.state`)
+RECURRENT_KINDS = ("prefill", "decode")
 
 
 def build_lm(hidden=32):
@@ -65,11 +68,21 @@ def lm():
     return build_lm()
 
 
+@pytest.fixture(scope="module")
+def recurrent_lm():
+    from tests import test_kimi_linear
+
+    return test_kimi_linear._model()
+
+
 def _pool_leaves(cache):
     """Every pool array the cache holds right now, by name."""
     groups = {"k": cache.k, "v": cache.v}
     if cache.quantized:
         groups.update(k_scale=cache.k_scale, v_scale=cache.v_scale)
+    for g, rows in cache.state.items():
+        for name, a in rows.items():
+            groups.setdefault("state." + name, {})[g] = a
     return {(n, g): a for n, d in groups.items() for g, a in d.items()}
 
 
@@ -95,11 +108,6 @@ def _step(kind, eng, cache, params, slot, nxt):
     if kind == "decode":
         eng.decode(params, tokens, active)
         return range(at, at + 1)
-    if kind == "multistep":
-        limits = np.zeros(SLOTS, dtype=np.int32)
-        limits[slot] = 2
-        eng.decode_multi(params, tokens, active, limits)
-        return range(at, at + 2)
     w = {"verify": 3, "tree": 4, "chunk": 3}[kind]
     wide = np.zeros((SLOTS, w), dtype=np.int32)
     wide[slot] = [nxt, 7, 2, 9][:w]
@@ -117,15 +125,20 @@ def _step(kind, eng, cache, params, slot, nxt):
     return range(at, at + w)
 
 
-@pytest.mark.parametrize("layout", LAYOUTS)
-@pytest.mark.parametrize("kind", KINDS)
-def test_step_consumes_its_pools_and_commits_the_rows(lm, kind, layout):
-    geometry, kv_dtype = layout
+@pytest.mark.parametrize(
+    "kind,layout",
+    [(k, l) for k in KINDS for l in LAYOUTS]
+    + [(k, "recurrent") for k in RECURRENT_KINDS],
+)
+def test_step_consumes_its_pools_and_commits_the_rows(request, kind, layout):
+    geometry, kv_dtype = LAYOUTS.get(layout, ({}, "fp32"))
+    model = request.getfixturevalue(
+        "recurrent_lm" if layout == "recurrent" else "lm"
+    )
     sched, eng, cache = build_scheduler(
-        lm,
+        model,
         ServeConfig(
-            max_seqs=SLOTS, max_seq_len=32, **geometry,
-            kv_dtype=kv_dtype, decode_multistep=(kind == "multistep"),
+            max_seqs=SLOTS, max_seq_len=32, **geometry, kv_dtype=kv_dtype,
         ),
     )
     params = sched.params
@@ -171,6 +184,11 @@ def test_step_consumes_its_pools_and_commits_the_rows(lm, kind, layout):
             changed = np.any(new != old, axis=2)
             assert changed[wrote].all(), (name, g)
             assert not changed[~own].any(), (name, g)
+        elif name.startswith("state."):
+            # [max_seqs, ...]: the slot's row moved, and no other
+            assert np.any(new[slot] != old[slot]), (name, g)
+            others = np.arange(SLOTS) != slot
+            assert np.array_equal(new[others], old[others]), (name, g)
         else:  # int8 scale pools [pages, heads]: claimed for written pages
             pages = wrote.any(axis=1)
             assert (new[pages] > 0).all(), (name, g)

@@ -1,7 +1,7 @@
 """Durable serving (flexflow_tpu/serving/journal.py + the front door's
 recovery/overload layers): the write-ahead request journal round-trips
 and tolerates exactly one torn tail record, a process crash at ANY
-iteration phase — plain decode, mid-fused-window, mid-tree-verify —
+iteration phase — plain decode, a chained step in flight, mid-tree-verify —
 restarts into token-identical streams with zero duplicated and zero
 lost published tokens (the journal-before-publish ordering, fxlint
 FX111), idempotent resubmission dedups on client request-keys across
@@ -279,23 +279,22 @@ def test_crash_after_torn_append_still_recovers(lm, tmp_path):
     assert _streams(state, resub, comp) == base
 
 
-def test_crash_mid_fused_window_recovers_token_identical(lm, tmp_path):
-    """A whole fused K-step window's run is host-visible yet
-    unjournaled at the commit-phase crash; the restart recomputes it
-    from the last durable cursor. Commit records land at the window
-    grain — one record per request per host sync, K tokens long."""
-    over = dict(kv_page_size=8,
-                decode_multistep=True, max_fused_steps=4)
-    base = _baseline(lm, max_new=12, kv_page_size=8,
-                     decode_multistep=True, max_fused_steps=4)
-    path = tmp_path / "fused.wal"
+def test_crash_with_a_chained_step_in_flight_recovers_token_identical(
+    lm, tmp_path
+):
+    """A commit-phase crash of the default loop dies with a chained
+    decode step dispatched and not read back: the rows it wrote and the
+    token it sampled were never the host's, the journal knows nothing of
+    them, and the restart recomputes them from the last durable cursor."""
+    over = dict(kv_page_size=8)
+    base = _baseline(lm, max_new=12, kv_page_size=8)
+    path = tmp_path / "chained.wal"
     sched = _crash_run(
-        lm, path, FaultPlan(crash_iters={3: "commit"}), max_new=12, **over)
-    assert sched.stats.multistep_windows > 0  # the crash hit mid-matrix
+        lm, path, FaultPlan(crash_iters={4: "commit"}), max_new=12, **over)
+    assert sched.stats.decode_steps_chained > 0
+    assert [s.kind for s in sched._inflight] == ["decode"]
     records, _ = read_journal(str(path))
-    assert any(
-        r["type"] == "commit" and len(r["tokens"]) > 1 for r in records
-    )
+    assert any(r["type"] == "commit" for r in records)
     state = recover_journal(str(path))
     assert state.replayed_tokens > 0
     _, _, resub, comp = _resume(lm, path, state, **over)

@@ -765,18 +765,16 @@ def test_faultplan_validation():
         FaultPlan(spike_s=-0.1)
 
 
-# -- chaos matrix: every injector site inside fused windows / tree rounds -----
+# -- chaos matrix: every injector site under a chained step / in tree rounds --
 #
-# PR 17 (decode_multistep) and PR 19 (spec_branch tree verify) moved
-# multiple logical decode steps inside one host sync. Every injector
-# site must keep the single-victim contract when its iteration lands
-# inside that regime, and the window/round boundary reconcile must
-# keep unaffected streams token-identical. Two sites CANNOT land
-# inside an open fused window by construction — swap_fail and
-# host_down need preemption (optimistic admission), and
-# `_fusable_steps` holds fusing to 1 whenever admission is optimistic
-# — so those two are driven through the tree-verify matrix (which has
-# no such gate) instead.
+# The overlapped loop keeps a decode step in flight behind the one being
+# committed, and a tree-verify round (spec_branch) moves several logical
+# decode steps inside one host sync. Every injector site must keep the
+# single-victim contract when its iteration lands inside either regime,
+# and the reconcile at the step's or round's boundary must keep
+# unaffected streams token-identical. swap_fail and host_down need
+# preemption (optimistic admission): they are driven through the
+# tree-verify matrix.
 
 
 def _chaos_run(lm, plan, seed=0, n=4, max_new=10, reqs=None, **cfg_kw):
@@ -793,17 +791,13 @@ def _chaos_run(lm, plan, seed=0, n=4, max_new=10, reqs=None, **cfg_kw):
     return inj, sched, engine, cache, {r.rid: r for r in sched.finished}
 
 
-_MULTISTEP_CFG = dict(kv_page_size=8,
-                      decode_multistep=True, max_fused_steps=4)
-
-
 @pytest.mark.parametrize("site", ["spike", "cancel", "nan", "kernel",
                                   "steal"])
-def test_chaos_site_inside_multistep_window(lm, site):
-    """Each injectable site fired at an iteration the fused-window
-    regime covers: exactly the planned victim is touched, every other
-    stream is token-identical to the fault-free run, and windows
-    actually fused around the fault."""
+def test_chaos_site_with_a_chained_step_in_flight(lm, site):
+    """Each injectable site fired at an iteration of the default loop
+    where a chained decode step is in flight: exactly the planned victim
+    is touched, every other stream is token-identical to the fault-free
+    run, and steps were actually chained around the fault."""
     base = _baseline(lm, max_new=10,
                      decode_kernel="dense")
     plan = {
@@ -817,10 +811,10 @@ def test_chaos_site_inside_multistep_window(lm, site):
     inj, sched, engine, cache, st = _chaos_run(
         lm, plan,
         decode_kernel="pallas" if site == "kernel" else "dense",
-        **_MULTISTEP_CFG,
+        kv_page_size=8,
     )
-    # the regime was real: windows fused, and the site actually fired
-    assert sched.stats.multistep_windows > 0
+    # the regime was real: steps chained, and the site actually fired
+    assert sched.stats.decode_steps_chained > 0
     assert sum(inj.summary().values()) > 0
     # nothing lost: every rid terminal exactly once
     assert set(st) == set(range(4))
@@ -829,9 +823,9 @@ def test_chaos_site_inside_multistep_window(lm, site):
             == sched.stats.submitted_requests == 4)
     if site == "cancel":
         assert st[1].status == RequestStatus.CANCELLED
-        # window-boundary reconcile: the cancelled stream is a clean
-        # PREFIX of the fault-free stream — nothing duplicated or
-        # invented inside the open window
+        # the cancel lands at the next read-back: the cancelled stream
+        # is a clean PREFIX of the fault-free stream — the token of the
+        # step in flight is discarded, nothing duplicated or invented
         assert st[1].generated == base[1][: len(st[1].generated)]
     elif site == "nan":
         assert st[1].status == RequestStatus.FAILED

@@ -75,7 +75,6 @@ PARENTS = {
     STEP + "decode.wait": STEP + "decode.dispatch",
     STEP + "chunk.wait": STEP + "chunk.dispatch",
     STEP + "verify.wait": STEP + "verify.dispatch",
-    STEP + "multistep.wait": STEP + "multistep.dispatch",
 }
 
 
@@ -260,11 +259,8 @@ def test_scheduler_under_a_profiler_session_yields_exactly_the_named_spans(
         (dict(spec_draft="ngram", spec_k=2),
          {"draft.propose", "verify.dispatch", "verify.wait",
           "verify.readback", "verify.commit"}),
-        (dict(decode_multistep=True, max_fused_steps=4),
-         {"multistep.dispatch", "multistep.wait", "multistep.readback",
-          "multistep.commit"}),
     ],
-    ids=["chunked", "spec", "multistep"],
+    ids=["chunked", "spec"],
 )
 def test_other_step_kinds_follow_the_pattern(lm, tmp_path, kw, expected):
     """`scheduler.step.<kind>.{dispatch,wait,readback,commit}` by

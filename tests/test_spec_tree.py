@@ -8,7 +8,7 @@ the longest surviving root-to-leaf path (greedy and rejection-sampled),
 truncate's src_rows compaction commits a scattered accepted branch into
 contiguous cache rows with dead-branch pages returned under reserve
 accounting, the n-gram/model proposers emit deduped branching drafts,
-multistep fusion still fires on draft-free iterations, and the cost
+and the cost
 family (verify_op_cost tree_nodes / optimize_spec_tree) prices the tree
 shape. All CPU-fast (tier 1)."""
 
@@ -590,39 +590,6 @@ def test_tree_sampling_reproducible(lm):
         serve_config=ServeConfig(**{**sc, "seed": 13}),
     )
     assert c != a
-
-
-# -- multistep fusion on draft-free iterations (satellite) --------------------
-
-
-@pytest.mark.parametrize(
-    "branch", [pytest.param(1, marks=pytest.mark.slow), 2])
-def test_multistep_fuses_when_nothing_drafted(lm, branch):
-    """--decode-multistep composes with speculation: on iterations where
-    the (stateless) proposer has nothing drafted, the scheduler opens a
-    fused window instead of stepping one-by-one — and the stream stays
-    the plain greedy stream. An 8-gram only matches once the tiny LM
-    starts looping, so the run interleaves fused windows (early,
-    draft-free) with verify steps (late) and both must agree with
-    plain decode."""
-    plain = lm.generate(
-        PROMPTS, max_new_tokens=8,
-        serve_config=ServeConfig(max_seqs=2, max_seq_len=32),
-    )
-    sched, _, _ = build_scheduler(
-        lm,
-        ServeConfig(max_seqs=2, max_seq_len=32, spec_draft="ngram",
-                    spec_ngram=8, spec_k=3, spec_branch=branch,
-                    decode_multistep=True, max_fused_steps=4),
-    )
-    done = sched.run([
-        Request(rid=i, prompt=list(p), max_new_tokens=8)
-        for i, p in enumerate(PROMPTS)
-    ])
-    got = [list(r.generated) for r in sorted(done, key=lambda r: r.rid)]
-    assert got == plain
-    s = sched.stats
-    assert s.multistep_steps > 0  # fusion fired on draft-free iterations
 
 
 # -- config wiring -------------------------------------------------------------

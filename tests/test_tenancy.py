@@ -306,8 +306,6 @@ _MATRIX = [
     pytest.param({"spec_draft": "ngram", "spec_k": 3}, id="paged-spec"),
     pytest.param({"token_budget": 10, "chunk_size": 4,
                   "decode_kernel": "dense"}, id="paged-chunked"),
-    pytest.param({"decode_multistep": True, "max_fused_steps": 4},
-                 id="paged-multistep"),
     pytest.param({"decode_kernel": "pallas"}, id="paged-pallas"),
     pytest.param({"kv_page_size": 32, "decode_kernel": "pallas"},
                  id="one_page-pallas"),
@@ -332,7 +330,7 @@ def test_adapter_identity_matrix(lm, serve_kw):
     serving requests that never reference an adapter (adapter_id = -1,
     the default), emits bit-identical tokens to an engine with no pool
     at all — on every path: {slot, paged} x {fp32, int8} x {sync,
-    async} x speculative x chunked x multistep x {dense, pallas}."""
+    async} x speculative x chunked x {dense, pallas}."""
     mk = lambda: [  # noqa: E731
         Request(rid=i, prompt=[2 + i, 3, 5], max_new_tokens=5)
         for i in range(3)
