@@ -281,7 +281,45 @@ def sparse_moe_route(
     return weights, experts.astype(jnp.int32)
 
 
-def sparse_moe(x, ws, params, ctx=None, live=None, chosen=None):
+def expert_products(
+    rows, w_gate, w_up, w_down, group_sizes, ctx, grad, rows_per_group
+):
+    """(silu(rows @ w_gate) * (rows @ w_up)) @ w_down on rows sorted by
+    group, each row by its own group's matrices -> (out [rows, d]
+    float32, whether the kernel made it). One algorithm, two makers, and
+    the choice reads what it can see: the platform, whether a gradient is
+    wanted, the operands' type and the static shapes
+    (`grouped_matmul.use_kernel`). A serving step on a TPU at lane-tile
+    widths takes `ops/pallas/grouped_matmul.py`, whose row tile follows
+    `rows_per_group`; training, narrow experts and anything off a TPU
+    take three `jax.lax.ragged_dot`, which XLA expands."""
+    from flexflow_tpu.ops.pallas import grouped_matmul
+
+    dtype = rows.dtype
+    rows, w_gate, w_up = mm_operands(ctx, rows, w_gate, w_up)
+    # a Mosaic kernel is not partitioned over a mesh: one device's arrays
+    alone = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
+    if alone and w_gate.dtype == w_down.dtype == rows.dtype and (
+        grouped_matmul.use_kernel(
+            rows.shape[0], rows.shape[1], w_gate.shape[2], rows.dtype, grad
+        )
+    ):
+        out = grouped_matmul.expert_mlp(
+            rows, w_gate, w_up, w_down, group_sizes,
+            rows_per_group=rows_per_group,
+        )
+        return out, True
+    mm = dict(preferred_element_type=jnp.float32)
+    gate = jax.lax.ragged_dot(rows, w_gate, group_sizes, **mm)
+    up = jax.lax.ragged_dot(rows, w_up, group_sizes, **mm)
+    hidden = (jax.nn.silu(gate) * up).astype(dtype)
+    hidden, w_down = mm_operands(ctx, hidden, w_down)
+    return jax.lax.ragged_dot(hidden, w_down, group_sizes, **mm), False
+
+
+def sparse_moe(
+    x, ws, params, ctx=None, live=None, chosen=None, grad=True, took=None
+):
     """The layer on global logical arrays: x [*lead, d] -> (y [*lead, d],
     counts int32). counts is (rows computed, distinct experts with a row),
     and for a layer that holds a share of the experts (`experts_held` =
@@ -294,7 +332,10 @@ def sparse_moe(x, ws, params, ctx=None, live=None, chosen=None):
     step's other rows are padding: idle slots, positions past a prompt),
     and only their rows count as absent. `chosen`: a list that receives
     the router's choice, experts [*lead, k] int32 in the router's own
-    numbering."""
+    numbering. `grad`: whether the caller may differentiate the layer
+    (the operator's lowering may; a serving step does not), `took`: a
+    list that receives whether the experts' products came from the
+    grouped-matmul kernel (`expert_products`, decided at trace time)."""
     router, w_gate, w_up, w_down = ws[:4]
     n, k = params["num_experts"], params["k"]
     held = params.get("experts_held")
@@ -323,13 +364,13 @@ def sparse_moe(x, ws, params, ctx=None, live=None, chosen=None):
         group_sizes = jnp.zeros((n,), jnp.int32).at[flat].add(1)
         rows = x2[order // k]  # [tokens * k, d], grouped by expert
     with jax.named_scope("moe.experts"):
-        rows, w_gate, w_up = mm_operands(ctx, rows, w_gate, w_up)
-        mm = dict(preferred_element_type=jnp.float32)
-        gate = jax.lax.ragged_dot(rows, w_gate, group_sizes, **mm)
-        up = jax.lax.ragged_dot(rows, w_up, group_sizes, **mm)
-        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-        hidden, w_down = mm_operands(ctx, hidden, w_down)
-        out = jax.lax.ragged_dot(hidden, w_down, group_sizes, **mm)
+        # a router spreads its rows over ALL its experts, held or not
+        out, kernel = expert_products(
+            rows, w_gate, w_up, w_down, group_sizes, ctx, grad,
+            tokens * k / params["num_experts"],
+        )
+        if took is not None:
+            took.append(kernel)
     with jax.named_scope("moe.combine"):
         # unsort by the inverse permutation: row r again belongs to token
         # r // k, and a token's k rows are summed under its gate weights

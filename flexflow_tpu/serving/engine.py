@@ -594,6 +594,13 @@ class GenerationEngine:
         self.moe_experts_touched_decode = 0
         self.moe_rows_absent_prefill = 0
         self.moe_rows_absent_decode = 0
+        # the prefill and decode programs dispatched whose expert layers'
+        # products came from ops/pallas/grouped_matmul.py, and what each
+        # traced program's layers took (`_forward_logits` notes it at
+        # trace time: the choice is `ops.moe.expert_products`')
+        self.moe_kernel_programs_prefill = 0
+        self.moe_kernel_programs_decode = 0
+        self._moe_kernel_traced: Dict[tuple, bool] = {}
         self.mla_rows_read_decode = 0
         # recurrent layers: (live slot, layer) rows the decode steps
         # advanced, and (request, layer) rows the prefills wrote from zero
@@ -998,7 +1005,7 @@ class GenerationEngine:
 
     def _forward_logits(
         self, params, tokens, hook, moe_counts=None, latent_hook=None,
-        share=None, state_hook=None,
+        share=None, state_hook=None, program=None,
     ):
         """`moe_counts`: a list that receives the int32 counts of every
         expert layer (`ops.moe.sparse_moe`), for the programs that
@@ -1007,7 +1014,9 @@ class GenerationEngine:
         the programs that serve one. `share` (`_share`): what the layers
         of a model that holds a share of its experts are handed besides.
         `state_hook`: what stands in for a recurrent (linear-attention)
-        node, from the programs that keep its per-slot state."""
+        node, from the programs that keep its per-slot state. `program`:
+        the key under which `_moe_kernel_traced` keeps whether this
+        program's expert layers took the grouped-matmul kernel."""
         import jax
 
         hooks = {OperatorType.MULTIHEAD_ATTENTION: hook}
@@ -1018,9 +1027,12 @@ class GenerationEngine:
         if moe_counts is not None and self._moe_guids:
             from flexflow_tpu.ops.moe import sparse_moe
 
+            took = []
+
             def count(node, ins, ws, ctx):
                 y, counts = sparse_moe(
-                    ins[0], ws, node.params, ctx, **(share or {})
+                    ins[0], ws, node.params, ctx, grad=False, took=took,
+                    **(share or {}),
                 )
                 moe_counts.append(counts)
                 return [y]
@@ -1044,6 +1056,8 @@ class GenerationEngine:
             op_hooks=hooks,
             constrain=False,
         )
+        if program is not None and moe_counts:
+            self._moe_kernel_traced[program] = all(took)
         return values[(self._logits_ref.guid, self._logits_ref.out_idx)]
 
     def _share(self, live):
@@ -1355,6 +1369,7 @@ class GenerationEngine:
             params, tokens, hook, moe,
             latent_hook if self._latent else None, share,
             state_hook if self._recurrent else None,
+            program=("prefill", tokens.shape[1]),
         )
         with jax.named_scope("step.pick"):
             last = logits[0, last_at]
@@ -1496,6 +1511,9 @@ class GenerationEngine:
             out = self._run_step(
                 "prefill", lambda: fn, params, inputs,
                 self._adapter_slot_args(), kernel_path=False,
+            )
+            self.moe_kernel_programs_prefill += self._moe_kernel_traced.get(
+                ("prefill", bucket), False
             )
             for s, n in zip(slots, lens):
                 self.cache.lengths[s] = n
@@ -1703,6 +1721,7 @@ class GenerationEngine:
         logits = self._forward_logits(
             params, tokens, hook, moe, latent_hook if self._latent else None,
             share, state_hook if self._recurrent else None,
+            program=("decode",),
         )
         with jax.named_scope("step.pick"):
             logits = logits[:, -1, :]
@@ -1822,6 +1841,9 @@ class GenerationEngine:
         )
         self._last_next = nxt
         readback.copy_to_host_async()
+        self.moe_kernel_programs_decode += self._moe_kernel_traced.get(
+            ("decode",), False
+        )
         self.cache.lengths[active] += 1
         if self._latent:
             # the live latent rows this step attends, the new one included

@@ -326,6 +326,11 @@ _STAT_FIELDS: Dict[str, object] = dict(
     # experts it does not hold, which it leaves out
     moe_rows_absent_prefill=0,
     moe_rows_absent_decode=0,
+    # the prefill and decode programs dispatched whose expert layers took
+    # ops/pallas/grouped_matmul.py (healthy on a TPU at lane-tile widths:
+    # every prefill program and every decode step)
+    moe_kernel_programs_prefill=0,
+    moe_kernel_programs_decode=0,
     # latent attention: the live latent rows the decode steps attended,
     # summed over slots and layers
     mla_rows_read_decode=0,
@@ -2468,6 +2473,7 @@ class _SchedulerBase:
         "moe_rows_prefill", "moe_rows_decode",
         "moe_experts_touched_prefill", "moe_experts_touched_decode",
         "moe_rows_absent_prefill", "moe_rows_absent_decode",
+        "moe_kernel_programs_prefill", "moe_kernel_programs_decode",
         "mla_rows_read_decode", "state_rows_decode", "state_resets_prefill",
     )
 

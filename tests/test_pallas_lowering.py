@@ -492,3 +492,179 @@ def test_compiled_step_program_aliases_its_pools(
         if " copy(" in line and shape in line.split("=", 1)[-1][:80]
     ]
     assert not copies, copies[:2]
+
+
+# -- the grouped-matmul kernel of an expert layer's prefill (PR 46) -----------
+
+from flexflow_tpu.ops.pallas import grouped_matmul as gm  # noqa: E402
+
+# hidden, expert width, the router's experts, and the rows of the three
+# expert cells' programs as they run (slots x k of the decode step first,
+# then bucket x k of the prefill programs), 64 experts stacked
+EXPERT_CELLS = {
+    "olmoe": (2048, 1024, 64, (128, 1024, 2048, 5120)),
+    "kanana": (2048, 768, 128, (96, 768, 1536, 3840)),
+    "kimi": (2304, 1024, 256, (256, 2048, 4096, 8192, 12800)),
+}
+EXPERT_SHAPES = [
+    pytest.param(d, f, experts, rows, id=f"{cell}-rows{rows}")
+    for cell, (d, f, experts, buckets) in EXPERT_CELLS.items()
+    for rows in buckets
+]
+
+
+def _expert_mlp(experts, rows):
+    def fn(x, w_gate, w_up, w_down, sizes):
+        return gm.expert_mlp(
+            x, w_gate, w_up, w_down, sizes, rows_per_group=rows / experts,
+            interpret=False,
+        )
+
+    return fn
+
+
+def _expert_operands(d, f, rows):
+    return (
+        _sds((rows, d)), _sds((64, d, f)), _sds((64, d, f)), _sds((64, f, d)),
+        _sds((64,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("d, f, experts, rows", EXPERT_SHAPES)
+def test_grouped_matmul_lowers_and_compiles_at_the_cells_shapes(
+    d, f, experts, rows
+):
+    """Both calls of `expert_mlp` (gate + up + silu, then down) at the
+    precision the cells serve at: the Pallas TPU lowering here, Mosaic
+    itself where libtpu describes a v5e."""
+    assert gm.use_kernel(rows, d, f, jnp.float32, grad=False) is False  # a CPU
+    fn, shapes = _expert_mlp(experts, rows), _expert_operands(d, f, rows)
+    text = jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert "grouped_gate_up" in text and "grouped_matmul" in text
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    _compile_for(devices, fn, shapes)
+
+
+def test_grouped_matmul_compiles_at_highest_too():
+    """The cells' first correctness pass traces the step programs under
+    `jax.default_matmul_precision("highest")`: Mosaic's fp32 contraction."""
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    d, f, experts, (_, rows, *_) = EXPERT_CELLS["kimi"]
+    with jax.default_matmul_precision("highest"):
+        _compile_for(
+            devices, _expert_mlp(experts, rows), _expert_operands(d, f, rows)
+        )
+
+
+def _expert_lm():
+    """An expert model at lane-tile widths: its one prefill bucket hands
+    its expert layer 64 x 8 rows, a decode step 2 x 8."""
+    from flexflow_tpu import DataType, FFConfig, FFModel, LossType, SGDOptimizer
+    from flexflow_tpu.models import build_olmoe
+
+    model = FFModel(FFConfig(batch_size=2, seed=0))
+    tok = model.create_tensor([2, 64], dtype=DataType.INT32, name="tokens")
+    build_olmoe(
+        model, tok, vocab_size=50, hidden=128, num_heads=4, num_layers=1,
+        expert_hidden=128, num_experts=8, experts_per_token=8,
+    )
+    model.compile(
+        optimizer=SGDOptimizer(lr=0.01),
+        loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+        metrics=[], devices=jax.devices()[:1],
+    )
+    return model
+
+
+def _served_programs(model, programs, seq):
+    """{kind: lowered-for-TPU text} of the prefill and decode programs an
+    engine builds for `model` (`step_programs` records them), and the
+    engine."""
+    from flexflow_tpu.serving import ServeConfig, build_scheduler
+
+    sched, eng, cache = build_scheduler(
+        model,
+        ServeConfig(max_seqs=2, max_seq_len=seq, prefill_buckets=(seq,)),
+    )
+    slot = cache.alloc(len(PROMPT), len(PROMPT) + 8)
+    texts = {}
+    eng.prefill(sched.params, [PROMPT], [slot])
+    texts["prefill"] = programs[-1]
+    _step("decode", eng, cache, sched.params, slot, 7)
+    texts["decode"] = programs[-1]
+    return {
+        kind: jitted.trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+        for kind, (jitted, shapes) in texts.items()
+    }, eng
+
+
+def _train_step_text(model):
+    import numpy as np
+
+    ex = model.executor
+    batch = {
+        name: np.zeros(
+            tuple(d.size for d in shape.dims if not d.is_replica_dim),
+            shape.dtype.to_jnp(),
+        )
+        for name, shape in ex.input_shapes().items()
+    }
+    return (
+        ex.train_step()
+        .trace(model.params, model.opt_state, ex.shard_batch(batch),
+               jax.random.PRNGKey(0))
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+
+
+KERNEL, XLAS = "grouped_", "ragged_dot"
+
+
+def test_an_expert_models_served_programs_take_the_kernel_and_its_train_step_does_not(
+    step_programs,
+):
+    """Lowered as on the chip: the prefill and the decode program's expert
+    layer is the grouped-matmul kernel's two calls and no `ragged_dot`
+    (the probe found no row count that XLA's call served better: no
+    threshold), the engine counts the programs it dispatched, and the same
+    model's TRAIN step, which differentiates the layer, keeps XLA's."""
+    model = _expert_lm()
+    texts, eng = _served_programs(model, step_programs, 64)
+    for text in texts.values():
+        assert text.count("grouped_gate_up") == 1
+        assert KERNEL in text and XLAS not in text
+    assert eng.prefill_programs == eng.moe_kernel_programs_prefill == 1
+    assert eng.moe_kernel_programs_decode == 1
+    train = _train_step_text(model)
+    assert XLAS in train and KERNEL not in train
+
+
+@pytest.mark.parametrize("family", ["decoder", "looped_decoder", "narrow_experts"])
+def test_programs_without_a_prefills_expert_rows_hold_no_kernel_call(
+    request, step_programs, family
+):
+    """The bypass, shown on lowered text and not asserted: a decoder's and
+    a looped decoder's step programs and train steps have no expert layer
+    and so neither maker's call; the CPU tests' narrow experts (32 wide)
+    keep `ragged_dot` in every program."""
+    if family == "decoder":
+        model, seq = request.getfixturevalue("lm"), 32
+    elif family == "looped_decoder":
+        from tests import test_ouro
+
+        model, seq = test_ouro._model(), test_ouro.SEQ
+    else:
+        from tests import test_olmoe
+
+        model, seq = test_olmoe._model(), test_olmoe.SEQ
+    texts, eng = _served_programs(model, step_programs, seq)
+    texts["train"] = _train_step_text(model)
+    for kind, text in texts.items():
+        assert KERNEL not in text, kind
+        assert (XLAS in text) == (family == "narrow_experts"), kind
+    assert eng.moe_kernel_programs_prefill == eng.moe_kernel_programs_decode == 0
