@@ -308,6 +308,29 @@ def kda_step(q, k, v, g, beta, state):
         return o.astype(q.dtype), new
 
 
+def kda_step_live(q, k, v, g, beta, state, active, ctx=None):
+    """`kda_step` as a serving decode step wants it: the rows whose
+    `active` flag (bool [b]) is set advance, every other row of the state
+    comes back bit-equal -> (o [b, H, d], the new state, whether the
+    kernel made it). One algorithm, two makers, and the choice reads what
+    it can see: the platform, the state's type, the static shapes and
+    whether the arrays are one device's (`kda_step.use_kernel`). On a TPU
+    at lane-tile heads `ops/pallas/kda_step.py` visits the live rows
+    only, in place; anything else takes `kda_step` over every row and a
+    `where`. An idle row's output is the kernel's zeros or `kda_step`'s
+    of its stale state: nobody reads it."""
+    from flexflow_tpu.ops.pallas import kda_step as kernel
+
+    # a Mosaic kernel is not partitioned over a mesh: one device's arrays
+    alone = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
+    if alone and kernel.use_kernel(state.shape[1], state.shape[2], state.dtype):
+        with jax.named_scope("kda.step"):
+            return (*kernel.kda_step_rows(q, k, v, g, beta, state, active), True)
+    o, new = kda_step(q, k, v, g, beta, state)
+    with jax.named_scope("kda.step"):
+        return o, jnp.where(active[:, None, None, None], new, state), False
+
+
 def kda_out(o, z, ws, params, ctx, out_dtype):
     """o, z [b, s, H, d] -> [b, s, e]: each head's output normalised
     (one gain for all heads), gated by sigmoid(z), and projected."""

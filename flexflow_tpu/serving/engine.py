@@ -606,6 +606,11 @@ class GenerationEngine:
         # advanced, and (request, layer) rows the prefills wrote from zero
         self.state_rows_decode = 0
         self.state_resets_prefill = 0
+        # the decode steps dispatched whose recurrent layers all advanced
+        # their state through ops/pallas/kda_step.py, and what the traced
+        # decode program's took (`linear_attention.kda_step_live`'s choice)
+        self.kda_kernel_programs_decode = 0
+        self._kda_kernel_traced = False
         self._logits_ref = self.executor.logits_ref
         # per-iteration dynamic seq truncation is a training knob; a stale
         # value would truncate serving activations mid-stack
@@ -1619,7 +1624,7 @@ class GenerationEngine:
             kda_conv,
             kda_out,
             kda_project,
-            kda_step,
+            kda_step_live,
         )
         from flexflow_tpu.serving.tenancy.adapters import (
             apply_adapter_out,
@@ -1703,14 +1708,14 @@ class GenerationEngine:
             g, p = node.guid, node.params
             qkv, decay, beta, z = kda_project(ins[0], ws, p, ctx)
             q, k, v, tails = kda_conv(qkv, cs[g]["conv"], ws, p)
-            o, state = kda_step(
-                q[:, 0], k[:, 0], v[:, 0], decay[:, 0], beta[:, 0], cs[g]["S"]
+            o, state, kernel = kda_step_live(
+                q[:, 0], k[:, 0], v[:, 0], decay[:, 0], beta[:, 0],
+                cs[g]["S"], active, ctx,
             )
+            kda_took.append(kernel)
             with jax.named_scope("kda.step"):
                 new_s[g] = {
-                    "S": jnp.where(
-                        active[:, None, None, None], state, cs[g]["S"]
-                    ),
+                    "S": state,
                     "conv": jnp.where(
                         active[:, None, None], tails, cs[g]["conv"]
                     ),
@@ -1718,11 +1723,13 @@ class GenerationEngine:
             return [kda_out(o[:, None], z, ws, p, ctx, ins[0].dtype)]
 
         moe = []  # receives the expert layers' counts
+        kda_took = []  # whether each recurrent layer's step took the kernel
         logits = self._forward_logits(
             params, tokens, hook, moe, latent_hook if self._latent else None,
             share, state_hook if self._recurrent else None,
             program=("decode",),
         )
+        self._kda_kernel_traced = bool(kda_took) and all(kda_took)
         with jax.named_scope("step.pick"):
             logits = logits[:, -1, :]
             slots = jnp.arange(lengths.shape[0])
@@ -1852,6 +1859,7 @@ class GenerationEngine:
             )
         # the slots' rows of per-slot state this step advances
         self.state_rows_decode += len(self._recurrent) * int(active.sum())
+        self.kda_kernel_programs_decode += self._kda_kernel_traced
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
         self.cache.begin_inflight()

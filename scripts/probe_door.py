@@ -20,7 +20,11 @@ it, from the harness's own stamps of the requests due in the window:
   submissions by the pass of the event loop since the last `step()`
   in which they crossed the door, and `later_share`, the share in
   passes 2 and later of those that found an iteration running. A tree
-  from before PR 42 has no such counter and reads `null`.
+  from before PR 42 has no such counter and reads `null`;
+* `engine_counters`: the scheduler's final `decode_steps`,
+  `prefill_programs`, `kernel_fallbacks` and the counters that say which
+  maker the step programs took (`moe_kernel_programs_*`, PR 46;
+  `kda_kernel_programs_decode`, PR 48; `null` on a tree before them).
 
 Each as [p50, p90]. With `PROBE_DUMP=<file>` in the environment every
 request's stamps and every step's tuple are written there as JSON, so
@@ -54,7 +58,7 @@ def main(argv=None) -> int:
     drive = lm.drive
 
     async def spying_drive(ctx, door, backend, plan, traffic, records, vocab):
-        seen.update(door=door, records=records, steps=backend.steps)
+        seen.update(door=door, records=records, steps=backend.steps, backend=backend)
         return await drive(ctx, door, backend, plan, traffic, records, vocab)
 
     lm.drive = spying_drive
@@ -69,6 +73,7 @@ def main(argv=None) -> int:
     door = [1e3 * (r.accepted - r.due) for r in win]
     period = stats.median(readers.tpots_of(win, finished_only=True))
     by_pass = getattr(seen["door"], "submits_by_pass", None)
+    sched = getattr(seen["backend"], "_sched", None)
     later = None
     if by_pass is not None:
         running = sum(n for label, n in by_pass.items() if label != "idle")
@@ -88,6 +93,15 @@ def main(argv=None) -> int:
             "door_over_period": [d / period for d in both(door)],
             "submits_by_pass": by_pass,
             "later_share": later,
+            # which makers the step programs took (None on a tree before
+            # the counter): equal to `decode_steps` where a kernel engaged
+            "engine_counters": {
+                name: getattr(sched.stats, name, None) for name in (
+                    "decode_steps", "prefill_programs", "kernel_fallbacks",
+                    "moe_kernel_programs_prefill", "moe_kernel_programs_decode",
+                    "kda_kernel_programs_decode",
+                )
+            } if sched is not None else None,
         }
     }), flush=True)
     dump = os.environ.get("PROBE_DUMP")

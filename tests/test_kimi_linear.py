@@ -380,6 +380,67 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert _gap(got, np.asarray(want)) < TOL
 
 
+# -- the one-step form's kernel: live rows only, in place (PR 48) --------------
+
+from flexflow_tpu.ops.pallas import kda_step as KS  # noqa: E402
+
+# (slots, heads): the served layer's shape (32 slots, 32 heads of 128) and a
+# small one; which slots are live
+KERNEL_SHAPES = {"served": (32, 32), "small": (4, 8)}
+LIVE = {
+    "none": lambda b: np.zeros(b, bool),
+    "one": lambda b: np.arange(b) == 1,
+    "every_other": lambda b: np.arange(b) % 2 == 0,
+    "all": lambda b: np.ones(b, bool),
+    "last_alone": lambda b: np.arange(b) == b - 1,
+}
+
+
+@pytest.mark.parametrize("live", LIVE)
+@pytest.mark.parametrize("shape", KERNEL_SHAPES)
+def test_the_kernel_is_kda_step_on_the_live_rows_and_leaves_the_others(shape, live):
+    """In the Pallas interpreter, against `kda_step` followed by the
+    `where`: the live rows' outputs and new states within float32
+    rounding, every other row of the state bit-equal to what went in
+    (NaN included: a row the grid does not visit is not read)."""
+    b, h = KERNEL_SHAPES[shape]
+    d = 128
+    assert KS.supports(h, d, jnp.float32) and not KS.use_kernel(h, d, jnp.float32)
+    q, k, v, g, beta = (a[:, 0] for a in _kda_inputs(b, 1, h, d, seed=3))
+    mask = LIVE[live](b)
+    state = jax.random.normal(jax.random.PRNGKey(4), (b, h, d, d))
+    state = jnp.where(mask[:, None, None, None], state, jnp.nan)
+    before = np.asarray(state)
+    want_o, want = L.kda_step(q, k, v, g, beta, state)
+    got_o, got = KS.kda_step_rows(
+        q, k, v, g, beta, state, jnp.asarray(mask), interpret=True
+    )
+    got_o, got = np.asarray(got_o), np.asarray(got)
+    assert np.array_equal(got[~mask], before[~mask], equal_nan=True)
+    assert not np.any(got_o[~mask])
+    scale = float(jnp.abs(state[mask]).max()) if mask.any() else 1.0
+    assert np.abs(got[mask] - np.asarray(want)[mask]).max(initial=0) < 1e-6 * scale
+    assert np.abs(got_o[mask] - np.asarray(want_o)[mask]).max(initial=0) < 1e-5 * scale
+
+
+def test_the_kernels_gate_reads_type_and_shape():
+    assert KS.supports(32, 128, jnp.float32) and KS.supports(8, 256, "float32")
+    assert not KS.supports(32, 128, jnp.bfloat16)  # a float32 state
+    assert not KS.supports(4, 16, jnp.float32)  # the CPU tests' heads
+    assert not KS.supports(12, 128, jnp.float32)  # whole sublane tiles of heads
+    assert [KS.heads_per_block(h) for h in (8, 32, 40, 64)] == [8, 32, 8, 32]
+    # off a TPU `kda_step_live` is `kda_step` and the `where`
+    q, k, v, g, beta = (a[:, 0] for a in _kda_inputs(2, 1, 8, 128))
+    state = jnp.ones((2, 8, 128, 128))
+    o, new, kernel = L.kda_step_live(
+        q, k, v, g, beta, state, jnp.asarray([True, False])
+    )
+    want_o, want = L.kda_step(q, k, v, g, beta, state)
+    assert kernel is False
+    assert np.array_equal(o, want_o) and np.array_equal(new[0], want[0])
+    assert np.array_equal(new[1], state[1])
+
+
 # -- serving: prefill, then decode ---------------------------------------------
 
 
@@ -490,7 +551,31 @@ def test_c1_a_prefill_starts_from_zero_and_never_reads_the_row(served):
         assert np.array_equal(a[slot2], state[key][slot])
 
 
-def test_c2_a_decode_step_touches_its_active_slots_rows_only(served):
+@pytest.fixture(scope="module")
+def served_wide():
+    """`served` with recurrent heads the kernel takes: 8 of 128."""
+    return _model(num_heads=8, kda_head_dim=128)
+
+
+@pytest.fixture
+def kernel_here(monkeypatch):
+    """`kda_step_live` chooses as on the chip, but for the platform: the
+    kernel wherever type and shape allow, in the interpreter."""
+    monkeypatch.setattr(KS, "use_kernel", KS.supports)
+
+
+@pytest.fixture(params=["kda_step", "kernel"])
+def served_either(request, served):
+    """The toy model through `kda_step` and the `where`, and the wide one
+    through the kernel: (model, whether its decode steps take the kernel)."""
+    if request.param == "kda_step":
+        return served, False
+    request.getfixturevalue("kernel_here")
+    return request.getfixturevalue("served_wide"), True
+
+
+def test_c2_a_decode_step_touches_its_active_slots_rows_only(served_either):
+    served, kernel = served_either
     _, engine, cache = _serve(served)
     prompts = [_prompt(9, 1), _prompt(12, 2)]
     slots = [cache.alloc(len(p), len(p) + 4) for p in prompts]
@@ -506,6 +591,42 @@ def test_c2_a_decode_step_touches_its_active_slots_rows_only(served):
         assert not np.array_equal(after[key][slots[0]], before[key][slots[0]])
         for s in [slots[1]] + idle:
             assert np.array_equal(after[key][s], before[key][s], equal_nan=True)
+    # the counter that says which maker ran: decode steps dispatched
+    assert engine.kda_kernel_programs_decode == (1 if kernel else 0)
+
+
+def test_the_kernels_decode_steps_are_the_references_and_counted(
+    served_wide, kernel_here
+):
+    """Two prompts prefilled and decoded side by side through the kernel
+    (a slot idle beside them), against the reference's forward pass; the
+    engine counts a decode step a dispatch, and the scheduler mirrors it."""
+    _, engine, cache = _serve(served_wide)
+    prompts = [_prompt(11, 1), _prompt(5, 2)]
+    slots = [cache.alloc(len(p), len(p) + 6) for p in prompts]
+    nxt, last = engine.prefill(served_wide.params, prompts, slots)
+    seqs, got = [list(p) for p in prompts], [[row] for row in last]
+    toks = [int(t) for t in nxt]
+    for _ in range(6):
+        for seq, tok in zip(seqs, toks):
+            seq.append(tok)
+        logits = _step(engine, served_wide, dict(zip(slots, toks)))
+        for i, slot in enumerate(slots):
+            got[i].append(logits[slot])
+        toks = [int(np.argmax(logits[slot])) for slot in slots]
+    for prompt, seq, rows in zip(prompts, seqs, got):
+        want = _want(served_wide, seq, list(range(len(prompt) - 1, len(seq))))
+        assert _gap(np.stack(rows), want) < TOL
+    assert engine._decode_jit._cache_size() == 1
+    assert engine.kda_kernel_programs_decode == 6 and engine.kernel_fallbacks == 0
+    sched, engine, _ = _serve(served_wide)
+    done = sched.run(_requests()[:3])
+    assert all(r.status == "finished" for r in done)
+    assert (
+        sched.stats.kda_kernel_programs_decode
+        == engine.kda_kernel_programs_decode
+        == sched.stats.decode_steps > 0
+    )
 
 
 def test_c3_a_stale_step_on_a_freed_slot_cannot_corrupt_the_newcomer(served):

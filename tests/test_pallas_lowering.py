@@ -668,3 +668,129 @@ def test_programs_without_a_prefills_expert_rows_hold_no_kernel_call(
         assert KERNEL not in text, kind
         assert (XLAS in text) == (family == "narrow_experts"), kind
     assert eng.moe_kernel_programs_prefill == eng.moe_kernel_programs_decode == 0
+
+
+# -- the one-step kernel of a recurrent layer's decode step (PR 48) -----------
+
+from flexflow_tpu.ops.pallas import kda_step as ks  # noqa: E402
+
+# the longform cell's recurrent state: 32 slots, 32 heads of 128
+KDA_CELL = (32, 32, 128)
+
+
+def _kda_operands(slots, heads, d):
+    return (
+        *(_sds((slots, heads, d)),) * 4, _sds((slots, heads)),
+        _sds((slots, heads, d, d)), _sds((slots,), jnp.bool_),
+    )
+
+
+@pytest.mark.parametrize("heads_a_block", [None, 8])
+def test_kda_step_kernel_lowers_and_compiles_at_the_cells_shape(heads_a_block):
+    """The Pallas TPU lowering here, Mosaic itself where libtpu describes
+    a v5e, at the block the kernel picks (a whole slot: 32 heads) and at
+    a sublane tile of heads; compiled, the new state is the state's own
+    buffer (the call aliases it and the program donates it)."""
+    slots, heads, d = KDA_CELL
+    assert ks.use_kernel(heads, d, jnp.float32) is False  # a CPU
+    assert ks.heads_per_block(heads) == 32
+
+    def fn(q, k, v, g, beta, state, active):
+        return ks.kda_step_rows(
+            q, k, v, g, beta, state, active, heads=heads_a_block,
+            interpret=False,
+        )
+
+    shapes = _kda_operands(*KDA_CELL)
+    text = jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert ks.NAME in text
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    one_chip = SingleDeviceSharding(devices[0])
+    placed = [
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip) for s in shapes
+    ]
+    compiled = (
+        jax.jit(fn, donate_argnums=(5,)).trace(*placed)
+        .lower(lowering_platforms=("tpu",)).compile()
+    )
+    state_bytes = 4 * slots * heads * d * d
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 64
+    # XLA does not stage the state through fast memory ahead of the call
+    assert "slice-start" not in compiled.as_text()
+
+
+def _all_slot_selects(text, state):
+    """The lowered text's `select`s over an array of the state's shape."""
+    shape = "x".join(map(str, state.shape)) + "xf32"
+    return [
+        line for line in text.splitlines()
+        if "stablehlo.select" in line and shape in line
+    ]
+
+
+def test_a_recurrent_models_decode_program_takes_the_kernel_and_the_others_do_not(
+    step_programs,
+):
+    """Lowered as on the chip, a model with recurrent layers of lane-tile
+    heads: the decode program holds the kernel and no `select` over the
+    whole per-slot state (the `where` that handed the idle rows back);
+    its prefill writes the state through `kda_chunked` and its train step
+    differentiates the chunked form: neither holds the call."""
+    from tests import test_kimi_linear as kimi
+
+    model = kimi._model(num_heads=8, kda_head_dim=128)
+    from flexflow_tpu.serving import ServeConfig, build_scheduler
+
+    sched, eng, cache = build_scheduler(
+        model,
+        ServeConfig(max_seqs=2, max_seq_len=kimi.SEQ, prefill_buckets=kimi.BUCKETS),
+    )
+    state = cache.state[cache.spec.state_guids[0]]["S"]
+    slot = cache.alloc(len(PROMPT), len(PROMPT) + 8)
+    eng.prefill(sched.params, [PROMPT], [slot])
+    _step("decode", eng, cache, sched.params, slot, 7)
+    (prefill, p_shapes), (decode, d_shapes) = step_programs[-2:]
+    prefill = prefill.trace(*p_shapes).lower(lowering_platforms=("tpu",)).as_text()
+    decode = decode.trace(*d_shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert ks.NAME in decode and not _all_slot_selects(decode, state)
+    assert ks.NAME not in prefill
+    assert ks.NAME not in _train_step_text(model)
+    assert eng.kda_kernel_programs_decode == 1 and eng.kernel_fallbacks == 0
+
+
+@pytest.mark.parametrize(
+    "family", ["decoder", "experts", "latent", "looped_decoder", "toy_recurrent"]
+)
+def test_programs_without_lane_tile_recurrent_heads_hold_no_kda_kernel_call(
+    request, step_programs, family
+):
+    """The bypass, shown on lowered text: a decoder's, an expert model's,
+    a latent model's and a looped decoder's step programs and train steps
+    have no recurrent layer and so no call; the CPU tests' recurrent
+    heads of 16 keep `kda_step` and the `where`, the `select` over every
+    slot's state that the kernel's programs do not have."""
+    if family == "decoder":
+        model, seq = request.getfixturevalue("lm"), 32
+    elif family == "experts":
+        model, seq = _expert_lm(), 64
+    elif family == "latent":
+        from tests import test_deepseek_v3
+
+        model, seq = test_deepseek_v3._model(), test_deepseek_v3.SEQ
+    elif family == "looped_decoder":
+        from tests import test_ouro
+
+        model, seq = test_ouro._model(), test_ouro.SEQ
+    else:
+        model, seq = request.getfixturevalue("recurrent_lm"), 64
+    texts, eng = _served_programs(model, step_programs, seq)
+    texts["train"] = _train_step_text(model)
+    for kind, text in texts.items():
+        assert ks.NAME not in text, kind
+    assert eng.kda_kernel_programs_decode == 0
+    if family == "toy_recurrent":
+        state = next(iter(eng.cache.state.values()))["S"]
+        assert _all_slot_selects(texts["decode"], state)
