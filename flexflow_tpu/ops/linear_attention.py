@@ -20,9 +20,17 @@ One function's two computations out of shared helpers, as "Latent
 attention" (ops/attention.py): CHUNKED (`kda_chunked`: a `lax.scan` over
 chunks of C tokens, each cut into sub-blocks of 16 so that all but the
 [16, 16, d] decays on a chunk's diagonal is matmuls, its triangular system
-included; the operator's lowering and a serving prefill) and ONE STEP
-(`kda_step`: a serving decode step, over the per-slot state
-`serving/kv_cache.py` keeps). Weights, in order: Wq,
+included) and ONE STEP (`kda_step`, over the per-slot state
+`serving/kv_cache.py` keeps). Which caller takes which form: the
+operator's lowering (`_lower_linear_attention`, differentiated under
+`jax.checkpoint`) takes `kda_chunked`, always; a serving prefill takes
+`kda_chunked_rows`, which is `ops/pallas/kda_scan.py` (forward only: the
+same chunked algorithm as one Mosaic call whose output block is the
+admitted request's row of the per-slot state) on a TPU at lane-tile heads
+and `kda_chunked` with a scatter anywhere else; a serving decode step
+takes `kda_step_live`, which is `ops/pallas/kda_step.py` under the same
+gate and `kda_step` with a `where` anywhere else. `kda_chunked` is the
+scan kernel's reference and `kda_step` both kernels'. Weights, in order: Wq,
 Wk, Wv [e, H d]; the three convolutions [K, H d] (taps-major: a tap is
 one dense row of channels); Wfa [e, r], Wfb
 [r, H d], dt_bias [H d], A_log [H]; Wb [e, H]; Wga [e, r], Wgb [r, H d];
@@ -329,6 +337,46 @@ def kda_step_live(q, k, v, g, beta, state, active, ctx=None):
     o, new = kda_step(q, k, v, g, beta, state)
     with jax.named_scope("kda.step"):
         return o, jnp.where(active[:, None, None, None], new, state), False
+
+
+def kda_chunked_rows(
+    q, k, v, g, beta, rows, live, fresh, slots, last_at, chunk, ctx=None
+):
+    """`kda_chunked` as a serving prefill wants it: ONE packed row of
+    requests laid in order, each beginning on a chunk boundary and from
+    the zero state, each one's state after its last chunk written whole to
+    its slot's row of the per-slot state (what the row held is never
+    read) -> (o [1, T, H, d], the new rows, whether the kernel made
+    them). q, k, v, g [1, T, H, d], beta [1, T, H]; rows [slots, H, d, d];
+    live bool [T]: the tokens that are someone's (every other is made a
+    no-op); fresh bool [T / chunk]: the chunks that start a request;
+    slots, last_at int32 [requests]: each request's slot (the rows past
+    the last name a slot out of range, which is dropped) and where its
+    last token stands. One algorithm, two makers, and the choice reads
+    what it can see, as `kda_step_live`'s: on a TPU at lane-tile heads
+    `ops/pallas/kda_scan.py` carries a head's state in fast memory from
+    chunk to chunk and its output block IS the slot's row; anything else
+    takes `kda_chunked` (masks over the row, a state a chunk) and a
+    scatter."""
+    from flexflow_tpu.ops.pallas import kda_scan as kernel
+
+    alone = ctx is None or ctx.mesh is None or ctx.mesh.size == 1
+    if alone and kernel.use_kernel(rows.shape[1], rows.shape[2], chunk, rows.dtype):
+        with jax.named_scope("kda.scan"):
+            o, new = kernel.kda_scan_rows(
+                q[0], k[0], v[0], g[0], beta[0], rows, live, fresh[0], slots,
+                last_at, chunk=chunk,
+            )
+            return o[None], new, True
+    with jax.named_scope("kda.scan"):
+        on = live[None, :, None]
+        k = jnp.where(on[..., None], k, 0)
+        g = jnp.where(on[..., None], g, 0)
+        beta = jnp.where(on, beta, 0)
+    o, states = kda_chunked(q, k, v, g, beta, jnp.zeros_like(rows[:1]), fresh, chunk)
+    with jax.named_scope("kda.scan"):
+        new = rows.at[slots].set(states[0, last_at // chunk], mode="drop")
+    return o, new, False
 
 
 def kda_out(o, z, ws, params, ctx, out_dtype):

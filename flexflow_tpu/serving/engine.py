@@ -607,10 +607,13 @@ class GenerationEngine:
         self.state_rows_decode = 0
         self.state_resets_prefill = 0
         # the decode steps dispatched whose recurrent layers all advanced
-        # their state through ops/pallas/kda_step.py, and what the traced
-        # decode program's took (`linear_attention.kda_step_live`'s choice)
+        # their state through ops/pallas/kda_step.py, the prefill programs
+        # whose recurrent layers all scanned through ops/pallas/kda_scan.py,
+        # and what each traced program's took (`linear_attention.
+        # kda_step_live`'s and `kda_chunked_rows`' choice)
         self.kda_kernel_programs_decode = 0
-        self._kda_kernel_traced = False
+        self.kda_kernel_programs_prefill = 0
+        self._kda_kernel_traced: Dict[tuple, bool] = {}
         self._logits_ref = self.executor.logits_ref
         # per-iteration dynamic seq truncation is a training knob; a stale
         # value would truncate serving activations mid-stack
@@ -1256,7 +1259,7 @@ class GenerationEngine:
             scaled_dot_product_attention,
         )
         from flexflow_tpu.ops.linear_attention import (
-            kda_chunked,
+            kda_chunked_rows,
             kda_conv,
             kda_out,
             kda_project,
@@ -1297,28 +1300,24 @@ class GenerationEngine:
                 tails = jnp.zeros((1, kernel - 1, qkv.shape[-1]), qkv.dtype)
             q, k, v, _ = kda_conv(qkv, tails, ws, p, taps)
             with jax.named_scope("kda.scan"):
-                on = live[None, :, None]
-                k = jnp.where(on[..., None], k, 0)
-                decay = jnp.where(on[..., None], decay, 0)
-                beta = jnp.where(on, beta, 0)
                 fresh = (position[::chunk] == 0)[None]
-            o, states = kda_chunked(
-                q, k, v, decay, beta, jnp.zeros_like(cs[g]["S"][:1]), fresh,
-                chunk,
+            # each request's state after its last chunk: its slot's whole
+            # row (rows past the last request name slot max_seqs, which
+            # is dropped)
+            o, rows, took = kda_chunked_rows(
+                q, k, v, decay, beta, cs[g]["S"], live, fresh, slot_ids,
+                last_at, chunk, ctx,
             )
+            kda_took.append(took)
             with jax.named_scope("kda.scan"):
-                # each request's state after its last chunk, and its last
-                # inputs (zeros where the prompt is shorter than the
-                # kernel): the slot's whole row (rows past the last
-                # request name slot max_seqs, which JAX drops)
+                # and its last inputs (zeros where the prompt is shorter
+                # than the kernel)
                 ago = back[::-1] - 1  # oldest first: K - 2 .. 0 tokens back
                 at = last_at[:, None] - ago
                 seen = (prompt_lens[:, None] > ago)[..., None]
                 kept = jnp.where(seen, qkv[0, jnp.maximum(at, 0)], 0)
                 new_s[g] = {
-                    "S": cs[g]["S"].at[slot_ids].set(
-                        states[0, last_at // chunk], mode="drop"
-                    ),
+                    "S": rows,
                     "conv": cs[g]["conv"].at[slot_ids].set(
                         kept.astype(jnp.float32), mode="drop"
                     ),
@@ -1370,12 +1369,16 @@ class GenerationEngine:
             return [mla_project_out(attn, ws, ctx, ins[0].dtype)]
 
         moe = []
+        kda_took = []  # whether each recurrent layer's scan took the kernel
         logits = self._forward_logits(
             params, tokens, hook, moe,
             latent_hook if self._latent else None, share,
             state_hook if self._recurrent else None,
             program=("prefill", tokens.shape[1]),
         )
+        self._kda_kernel_traced[("prefill", tokens.shape[1])] = bool(
+            kda_took
+        ) and all(kda_took)
         with jax.named_scope("step.pick"):
             last = logits[0, last_at]
             nxt = self._pick(last, slot_ids, prompt_lens)
@@ -1518,6 +1521,9 @@ class GenerationEngine:
                 self._adapter_slot_args(), kernel_path=False,
             )
             self.moe_kernel_programs_prefill += self._moe_kernel_traced.get(
+                ("prefill", bucket), False
+            )
+            self.kda_kernel_programs_prefill += self._kda_kernel_traced.get(
                 ("prefill", bucket), False
             )
             for s, n in zip(slots, lens):
@@ -1729,7 +1735,7 @@ class GenerationEngine:
             share, state_hook if self._recurrent else None,
             program=("decode",),
         )
-        self._kda_kernel_traced = bool(kda_took) and all(kda_took)
+        self._kda_kernel_traced[("decode",)] = bool(kda_took) and all(kda_took)
         with jax.named_scope("step.pick"):
             logits = logits[:, -1, :]
             slots = jnp.arange(lengths.shape[0])
@@ -1859,7 +1865,9 @@ class GenerationEngine:
             )
         # the slots' rows of per-slot state this step advances
         self.state_rows_decode += len(self._recurrent) * int(active.sum())
-        self.kda_kernel_programs_decode += self._kda_kernel_traced
+        self.kda_kernel_programs_decode += self._kda_kernel_traced.get(
+            ("decode",), False
+        )
         # the in-flight window pins pages this step's snapshot tables
         # reference; decode_reconcile closes it
         self.cache.begin_inflight()

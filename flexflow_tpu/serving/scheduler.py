@@ -340,8 +340,11 @@ _STAT_FIELDS: Dict[str, object] = dict(
     state_rows_decode=0,
     state_resets_prefill=0,
     # the decode steps dispatched whose recurrent layers took
-    # ops/pallas/kda_step.py (healthy on a TPU at lane-tile heads: every one)
+    # ops/pallas/kda_step.py, and the prefill programs whose recurrent
+    # layers took ops/pallas/kda_scan.py (healthy on a TPU at lane-tile
+    # heads: every one)
     kda_kernel_programs_decode=0,
+    kda_kernel_programs_prefill=0,
     # prefix-sharing page cache (--prefix-cache;
     # mirrored from the allocator's ledgers at each iteration end)
     prefix_hits=0,  # admissions that mapped at least one shared page
@@ -2478,7 +2481,7 @@ class _SchedulerBase:
         "moe_rows_absent_prefill", "moe_rows_absent_decode",
         "moe_kernel_programs_prefill", "moe_kernel_programs_decode",
         "mla_rows_read_decode", "state_rows_decode", "state_resets_prefill",
-        "kda_kernel_programs_decode",
+        "kda_kernel_programs_decode", "kda_kernel_programs_prefill",
     )
 
     def _end_iteration(self) -> None:

@@ -24,7 +24,8 @@ it, from the harness's own stamps of the requests due in the window:
 * `engine_counters`: the scheduler's final `decode_steps`,
   `prefill_programs`, `kernel_fallbacks` and the counters that say which
   maker the step programs took (`moe_kernel_programs_*`, PR 46;
-  `kda_kernel_programs_decode`, PR 48; `null` on a tree before them).
+  `kda_kernel_programs_decode`, PR 48; `kda_kernel_programs_prefill`,
+  PR 52; `null` on a tree before them).
 
 Each as [p50, p90]. With `PROBE_DUMP=<file>` in the environment every
 request's stamps and every step's tuple are written there as JSON, so
@@ -99,7 +100,7 @@ def main(argv=None) -> int:
                 name: getattr(sched.stats, name, None) for name in (
                     "decode_steps", "prefill_programs", "kernel_fallbacks",
                     "moe_kernel_programs_prefill", "moe_kernel_programs_decode",
-                    "kda_kernel_programs_decode",
+                    "kda_kernel_programs_decode", "kda_kernel_programs_prefill",
                 )
             } if sched is not None else None,
         }

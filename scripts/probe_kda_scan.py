@@ -14,6 +14,18 @@ profile_cells.py --keep-trace` left (here in the sandbox: it needs no
 chip) and lists the prefill program's instructions under `kda.scan`.
 `--impl module:function` times another function of the same signature
 (the next form, before it replaces this one).
+
+    chiprun -- python3 scripts/probe_kda_scan.py --rows --tokens 256 768 1600
+
+`--rows` (PR 52) times what a served layer's prefill does for the
+recurrence, `kda_chunked_rows`, both ways at once: the tree's fallback
+(masks over the row, `kda_chunked`, each request's last state scattered
+into its slot's row of a donated `[32, H, d, d]` state) and `ops/pallas/
+kda_scan.py` (`--heads-per-block 8 16` sweeps its block), on a packed row
+of requests of four chunks less 20 tokens with its last two chunks
+padding; ms a layer from the profile, the kernel held to the fallback at
+the default precision and at `highest`. `--rehearse` runs it tiny through
+the interpreter and prints no time.
 """
 
 from __future__ import annotations
@@ -181,6 +193,90 @@ def run(args):
                   f"{float(jnp.abs(last).max()):.3e}")
 
 
+def _packed(tokens, chunk, slots):
+    """A packed row's bookkeeping: requests of four chunks less 20 tokens
+    (fewer chunks for the last), the row's last two chunks padding where
+    it has more than four -> (live [T], fresh [1, n], slot ids and last
+    token of each request [slots], padded with `slots` and 0)."""
+    import numpy as np
+
+    n = tokens // chunk
+    used = n - 2 if n > 4 else n
+    live, fresh = np.zeros(tokens, bool), np.zeros((1, n), bool)
+    ids, last = np.full(slots, slots, np.int32), np.zeros(slots, np.int32)
+    for r, c in enumerate(range(0, used, 4)):
+        end = min(c + 4, used) * chunk - 20
+        live[c * chunk: end] = True
+        fresh[0, c] = True
+        ids[r], last[r] = (5 * r + 3) % slots, end - 1
+    return tuple(jnp.asarray(a) for a in (live, fresh, ids, last))
+
+
+def run_rows(args):
+    from flexflow_tpu.ops.pallas import kda_scan as kernel
+
+    d = jax.devices()[0]
+    print(f"device: {d.platform} {d.device_kind}; kda_chunked_rows")
+    shape = (args.slots, args.heads, args.head_dim, args.head_dim)
+    gate = kernel.use_kernel
+
+    pick = kernel.heads_per_block
+
+    def maker(take, heads=None):  # the choice is made when `fn` is traced
+        def fn(*operands):
+            kernel.use_kernel = lambda *a: take
+            kernel.heads_per_block = (lambda h: heads) if heads else pick
+            try:
+                return L.kda_chunked_rows(*operands, args.chunk)[:2]
+            finally:
+                kernel.use_kernel, kernel.heads_per_block = gate, pick
+        return fn
+
+    forms = [("kda_chunked + scatter", maker(False))] + [
+        (f"kernel, {hb} heads a block", maker(True, hb))
+        for hb in args.heads_per_block
+    ]
+    for tokens in args.tokens:
+        ins = _inputs(tokens, args.heads, args.head_dim, args.seed)
+        book = _packed(tokens, args.chunk, args.slots)
+        live = book[0]
+        print(f"== {tokens} tokens, {int(live.sum())} live, "
+              f"{int(book[1].sum())} requests")
+        results = {}
+        for name, fn in forms:
+            for precision in (None, "highest"):
+                rows = jax.random.normal(jax.random.PRNGKey(7), shape)
+                with jax.default_matmul_precision(precision or "default"):
+                    jitted = jax.jit(fn, donate_argnums=(5,))
+                    if precision is None and not args.rehearse:
+                        with profiling.fresh_compile():
+                            compiled = jitted.lower(*ins, rows, *book).compile()
+                        out = compiled(*ins, rows, *book)
+                        jax.block_until_ready(out)
+                        with tempfile.TemporaryDirectory() as profile_dir:
+                            with profiling.trace(profile_dir):
+                                for _ in range(args.steps):
+                                    out = compiled(*ins, out[1], *book)
+                                    jax.block_until_ready(out)
+                            events = profiling.read_device_events(profile_dir)
+                        print(f"-- {name}")
+                        table(events, compiled.as_text(), everything=True,
+                              top=args.top)
+                    else:
+                        out = jitted(*ins, rows, *book)
+                results[name, precision] = jax.block_until_ready(out)
+        base = forms[0][0]
+        for name, _ in forms[1:]:
+            for precision in (None, "highest"):
+                (wo, wr), (go, gr) = results[base, precision], results[name, precision]
+                print(f"{name} against {base}, matmuls at "
+                      f"{precision or 'the default'}: outputs "
+                      f"{float(jnp.abs(go - wo)[0, live].max()):.3e} of "
+                      f"{float(jnp.abs(wo)[0, live].max()):.3e}, rows "
+                      f"{float(jnp.abs(gr - wr).max()):.3e} of "
+                      f"{float(jnp.abs(wr).max()):.3e}")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--fold", help="a kept profile of scripts/profile_cells.py")
@@ -192,9 +288,16 @@ def main():
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--top", type=int, default=24)
+    ap.add_argument("--rows", action="store_true",
+                    help="kda_chunked_rows: the fallback beside the kernel")
+    ap.add_argument("--heads-per-block", type=int, nargs="+", default=[8])
+    ap.add_argument("--slots", type=int, default=32)
+    ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     if args.fold:
         fold(args.fold, args.top)
+    elif args.rows:
+        run_rows(args)
     else:
         run(args)
 

@@ -444,10 +444,122 @@ def test_the_kernels_gate_reads_type_and_shape():
 # -- serving: prefill, then decode ---------------------------------------------
 
 
+# -- the scan kernel of a recurrent layer's prefill (PR 52) -------------------
+
+from flexflow_tpu.ops.pallas import kda_scan as KC  # noqa: E402
+
+SCAN_CHUNK = 64
+# a packed row: (prompt lengths in order, their slots, tokens of the row,
+# slots of the state, heads); a prompt begins on a chunk boundary
+PACKED = {
+    "prompts_on_boundaries_padding_behind": ((70, 64, 130), (3, 0, 4), 512, 5, 8),
+    "one_chunk_and_many": ((9, 300), (1, 0), 384, 3, 8),
+    "one_prompt_fills_the_row": ((256,), (2,), 256, 4, 8),
+    "two_blocks_of_heads": ((100, 20), (0, 2), 256, 3, 16),
+    "one_token_repeated": ((192,), (1,), 256, 2, 8),
+}
+
+
+def _packed_row(case, d=128):
+    lens, slots, tokens, rows, h = PACKED[case]
+    q, k, v, g, beta = _kda_inputs(
+        1, tokens, h, d, seed=5, strength=0.03 if "repeated" in case else 1.0
+    )
+    if "repeated" in case:
+        q, k = (jnp.broadcast_to(t[:, :1], t.shape) for t in (q, k))
+        beta = jax.nn.sigmoid(jax.scipy.special.logit(beta) + 3.0)
+    live, fresh = np.zeros(tokens, bool), np.zeros((1, tokens // SCAN_CHUNK), bool)
+    ids = np.full(rows + 2, rows, np.int32)  # rows past the last: slot `rows`
+    last = np.zeros(rows + 2, np.int32)
+    spans, at = [], 0
+    for i, (n, slot) in enumerate(zip(lens, slots)):
+        live[at: at + n] = True
+        fresh[0, at // SCAN_CHUNK] = True
+        ids[i], last[i] = slot, at + n - 1
+        spans.append((at, at + n))
+        at += -(-n // SCAN_CHUNK) * SCAN_CHUNK
+    # stale rows everywhere: an admitted slot's is never read, another's
+    # comes back bit-equal (NaN included)
+    state = np.full((rows, h, d, d), np.nan, np.float32)
+    book = tuple(jnp.asarray(a) for a in (live, fresh, ids, last))
+    return (q, k, v, g, beta), jnp.asarray(state), book, spans, slots
+
+
+@pytest.mark.parametrize("case", PACKED)
+def test_the_scan_kernel_is_kda_chunked_and_the_scatter(case, monkeypatch):
+    """`kda_chunked_rows` through the kernel (the Pallas interpreter)
+    against its fallback, `kda_chunked` over the masked row and the
+    scatter, and against `kda_step` token by token, prompt by prompt: the
+    live tokens' outputs and each admitted slot's row within float32
+    rounding, every other slot's row bit-equal to what went in."""
+    ins, state, book, spans, slots = _packed_row(case)
+    with jax.default_matmul_precision("highest"):
+        want_o, want, took = L.kda_chunked_rows(*ins, state, *book, SCAN_CHUNK)
+        assert took is False  # a CPU
+        monkeypatch.setattr(KC, "use_kernel", KC.supports)
+        got_o, got, took = L.kda_chunked_rows(*ins, state, *book, SCAN_CHUNK)
+        assert took is True
+    live = np.asarray(book[0])
+    got_o, got, before = np.asarray(got_o), np.asarray(got), np.asarray(state)
+    others = [s for s in range(state.shape[0]) if s not in slots]
+    assert np.array_equal(got[others], before[others], equal_nan=True)
+    assert np.isfinite(got[list(slots)]).all() and np.isfinite(got_o[0, live]).all()
+    scale = max(1.0, float(np.abs(np.asarray(want)[list(slots)]).max()))
+    assert np.abs(got_o[0, live] - np.asarray(want_o)[0, live]).max() < 1e-5
+    assert np.abs(got[list(slots)] - np.asarray(want)[list(slots)]).max() < 1e-5 * scale
+    zero = jnp.zeros((1,) + state.shape[1:])
+    for (lo, hi), slot in zip(spans, slots):
+        steps_o, steps = _by_steps(*(a[:, lo:hi] for a in ins), zero)
+        scale = max(float(jnp.abs(steps_o).max()), float(jnp.abs(steps).max()))
+        limit = 2e-5 * max(1.0, scale)
+        assert np.abs(got_o[0, lo:hi] - np.asarray(steps_o)[0]).max() < limit
+        assert np.abs(got[slot] - np.asarray(steps)[0]).max() < limit
+
+
+def test_the_scan_kernel_at_the_served_precision_is_one_bfloat16_pass_away():
+    """At the default precision the three products with the state and B u
+    take one bfloat16 pass, as XLA's DEFAULT does on a TPU (here, on a
+    CPU, the fallback's are exact): close, not equal."""
+    ins, state, book, _, slots = _packed_row("prompts_on_boundaries_padding_behind")
+    want_o, want, _ = L.kda_chunked_rows(*ins, state, *book, SCAN_CHUNK)
+    got_o, got = KC.kda_scan_rows(
+        *(a[0] for a in ins), state, book[0], book[1][0], *book[2:],
+        chunk=SCAN_CHUNK, interpret=True,
+    )
+    live = np.asarray(book[0])
+    gap = np.abs(np.asarray(got_o)[live] - np.asarray(want_o)[0, live]).max()
+    assert 1e-6 < gap < 2e-2 * float(jnp.abs(want_o[0, live]).max())
+    rows = np.abs(np.asarray(got)[list(slots)] - np.asarray(want)[list(slots)]).max()
+    assert rows < 2e-2 * float(jnp.abs(want[jnp.asarray(slots)]).max())
+
+
+def test_the_scan_kernels_gate_reads_type_shape_and_mesh(monkeypatch):
+    assert KC.supports(32, 128, 64, jnp.float32) and KC.supports(8, 256, 16, "float32")
+    assert not KC.supports(32, 128, 64, jnp.bfloat16)  # a float32 state
+    assert not KC.supports(4, 16, 8, jnp.float32)  # the CPU tests' heads
+    assert not KC.supports(12, 128, 64, jnp.float32)  # whole sublane tiles of heads
+    assert not KC.supports(32, 128, 24, jnp.float32)  # whole sub-blocks
+    assert not KC.use_kernel(32, 128, 64, jnp.float32)  # a CPU
+    assert [KC.heads_per_block(h) for h in (8, 32, 40)] == [8, 8, 8]
+    # a mesh of more than one device keeps `kda_chunked` and the scatter,
+    # whatever the platform: a Mosaic kernel is not partitioned
+    monkeypatch.setattr(KC, "use_kernel", KC.supports)
+    ins, state, book, _, _ = _packed_row("one_prompt_fills_the_row")
+
+    class Ctx:
+        mesh = type("Mesh", (), {"size": 2})()
+
+    assert L.kda_chunked_rows(*ins, state, *book, SCAN_CHUNK, Ctx())[2] is False
+    Ctx.mesh = None
+    assert L.kda_chunked_rows(*ins, state, *book, SCAN_CHUNK, Ctx())[2] is True
+
+
 @pytest.mark.parametrize("n", [2, 11, 16, 29])
-def test_prefill_then_decode_is_the_references_forward_pass(served, n):
+def test_prefill_then_decode_is_the_references_forward_pass(served_either, n):
     """Prompts shorter than the kernel and a chunk (2), across a chunk
-    (11), of whole chunks (16), and decode across chunk and page ends."""
+    (11), of whole chunks (16), and decode across chunk and page ends;
+    through `kda_chunked` and `kda_step`, and through the two kernels."""
+    served, kernel = served_either
     _, engine, cache = _serve(served)
     prompt = _prompt(n)
     slot = cache.alloc(n, n + 12)
@@ -463,6 +575,10 @@ def test_prefill_then_decode_is_the_references_forward_pass(served, n):
     assert engine._decode_jit._cache_size() == 1
     assert engine.state_rows_decode == 12 * 4
     assert engine.state_resets_prefill == 4
+    # the counters that say which makers ran: programs dispatched
+    assert engine.kda_kernel_programs_prefill == (1 if kernel else 0)
+    assert engine.kda_kernel_programs_decode == (12 if kernel else 0)
+    assert engine.kernel_fallbacks == 0
 
 
 def test_packed_admissions_serve_each_prompt_as_if_alone(served):
@@ -531,7 +647,8 @@ def _poison(cache, slot=None):
     }
 
 
-def test_c1_a_prefill_starts_from_zero_and_never_reads_the_row(served):
+def test_c1_a_prefill_starts_from_zero_and_never_reads_the_row(served_either):
+    served, kernel = served_either
     _, engine, cache = _serve(served)
     _poison(cache)
     prompt = _prompt(13)
@@ -553,21 +670,25 @@ def test_c1_a_prefill_starts_from_zero_and_never_reads_the_row(served):
 
 @pytest.fixture(scope="module")
 def served_wide():
-    """`served` with recurrent heads the kernel takes: 8 of 128."""
-    return _model(num_heads=8, kda_head_dim=128)
+    """`served` with recurrent heads the kernels take, 8 of 128, and
+    chunks of whole sub-blocks."""
+    return _model(num_heads=8, kda_head_dim=128, kda_chunk=KC.SUB)
 
 
 @pytest.fixture
 def kernel_here(monkeypatch):
-    """`kda_step_live` chooses as on the chip, but for the platform: the
-    kernel wherever type and shape allow, in the interpreter."""
+    """`kda_step_live` and `kda_chunked_rows` choose as on the chip, but
+    for the platform: the kernels wherever type and shape allow, in the
+    interpreter."""
     monkeypatch.setattr(KS, "use_kernel", KS.supports)
+    monkeypatch.setattr(KC, "use_kernel", KC.supports)
 
 
 @pytest.fixture(params=["kda_step", "kernel"])
 def served_either(request, served):
-    """The toy model through `kda_step` and the `where`, and the wide one
-    through the kernel: (model, whether its decode steps take the kernel)."""
+    """The toy model through `kda_chunked`, the scatter, `kda_step` and the
+    `where`, and the wide one through the two kernels: (model, whether its
+    prefills and decode steps take them)."""
     if request.param == "kda_step":
         return served, False
     request.getfixturevalue("kernel_here")
@@ -619,6 +740,7 @@ def test_the_kernels_decode_steps_are_the_references_and_counted(
         assert _gap(np.stack(rows), want) < TOL
     assert engine._decode_jit._cache_size() == 1
     assert engine.kda_kernel_programs_decode == 6 and engine.kernel_fallbacks == 0
+    assert engine.kda_kernel_programs_prefill == engine.prefill_programs == 1
     sched, engine, _ = _serve(served_wide)
     done = sched.run(_requests()[:3])
     assert all(r.status == "finished" for r in done)
@@ -627,6 +749,38 @@ def test_the_kernels_decode_steps_are_the_references_and_counted(
         == engine.kda_kernel_programs_decode
         == sched.stats.decode_steps > 0
     )
+    assert (
+        sched.stats.kda_kernel_programs_prefill
+        == engine.kda_kernel_programs_prefill
+        == engine.prefill_programs > 0
+    )
+
+
+def test_a_packed_admission_through_the_scan_kernel_serves_each_prompt_as_if_alone(
+    served_wide, kernel_here
+):
+    """Three prompts in one row of the 64 bucket, each on a chunk boundary
+    (5 -> 16, 17 -> 32, 3 -> 16 tokens laid), a stale row in every slot:
+    each prompt's logits are the reference's of the prompt alone, each
+    admitted slot's row is written whole and the fourth slot's is not
+    touched; then two admissions of different buckets, one kernel each."""
+    _, engine, cache = _serve(served_wide)
+    _poison(cache)
+    prompts = [_prompt(n, salt) for salt, n in enumerate((5, 17, 3))]
+    slots = [cache.alloc(len(p), len(p) + 2) for p in prompts]
+    _, last = engine.prefill(served_wide.params, prompts, slots)
+    assert engine.prefill_programs == engine.kda_kernel_programs_prefill == 1
+    assert engine.prefill_tokens_padded == 64 and engine.prefill_tokens_real == 25
+    for prompt, row in zip(prompts, last):
+        assert _gap(row, _want(served_wide, prompt, [len(prompt) - 1])[0]) < TOL
+    (idle,) = [s for s in range(4) if s not in slots]
+    for (g, name), a in _state(cache).items():
+        assert np.isfinite(a[slots]).all() and np.isnan(a[idle]).all(), (g, name)
+    cache.free(slots[0])
+    again = cache.alloc(9, 12)
+    engine.prefill(served_wide.params, [_prompt(9, 5)], [again])  # the 16 bucket
+    assert engine.prefill_programs == engine.kda_kernel_programs_prefill == 2
+    assert engine.kernel_fallbacks == 0
 
 
 def test_c3_a_stale_step_on_a_freed_slot_cannot_corrupt_the_newcomer(served):

@@ -722,6 +722,58 @@ def test_kda_step_kernel_lowers_and_compiles_at_the_cells_shape(heads_a_block):
     assert "slice-start" not in compiled.as_text()
 
 
+# -- the scan kernel of a recurrent layer's prefill (PR 52) -------------------
+
+from flexflow_tpu.ops.pallas import kda_scan as kc  # noqa: E402
+
+KDA_CHUNK = 64
+
+
+def _kda_scan_operands(tokens, slots, heads, d):
+    n = tokens // KDA_CHUNK
+    return (
+        *(_sds((tokens, heads, d)),) * 4, _sds((tokens, heads)),
+        _sds((slots, heads, d, d)), _sds((tokens,), jnp.bool_),
+        _sds((n,), jnp.bool_), _sds((slots,), jnp.int32), _sds((slots,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("tokens", [256, 1600])
+def test_kda_scan_kernel_lowers_and_compiles_at_the_cells_shapes(tokens):
+    """The Pallas TPU lowering here, Mosaic itself where libtpu describes
+    a v5e, at the longform cell's smallest and largest prefill bucket (32
+    heads of 128, chunks of 64, 32 slots): compiled, the new state is the
+    state's own buffer (the call aliases it and the program donates it),
+    nothing of the per-chunk states' size is a temporary, and XLA does
+    not stage an operand through fast memory ahead of the call."""
+    slots, heads, d = KDA_CELL
+    assert kc.use_kernel(heads, d, KDA_CHUNK, jnp.float32) is False  # a CPU
+    assert kc.heads_per_block(heads) == 8
+
+    def fn(*operands):
+        return kc.kda_scan_rows(*operands, chunk=KDA_CHUNK, interpret=False)
+
+    shapes = _kda_scan_operands(tokens, *KDA_CELL)
+    text = jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+    assert kc.NAME in text
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("libtpu cannot describe a v5e topology here")
+    one_chip = SingleDeviceSharding(devices[0])
+    placed = [
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip) for s in shapes
+    ]
+    compiled = (
+        jax.jit(fn, donate_argnums=(5,)).trace(*placed)
+        .lower(lowering_platforms=("tpu",)).compile()
+    )
+    state_bytes = 4 * slots * heads * d * d
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
+    # a state a chunk is 2 MiB x chunks a layer: none of it is kept
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * heads * d * d
+    assert "slice-start" not in compiled.as_text()
+
+
 def _all_slot_selects(text, state):
     """The lowered text's `select`s over an array of the state's shape."""
     shape = "x".join(map(str, state.shape)) + "xf32"
@@ -731,17 +783,30 @@ def _all_slot_selects(text, state):
     ]
 
 
+def _state_scatters(text, state):
+    """The lowered text's `scatter`s into an array of the state's shape
+    (the line that closes the op's region carries its types)."""
+    import re
+
+    shape = "x".join(map(str, state.shape)) + "xf32"
+    return re.findall(
+        rf"\}}\) : \(tensor<{shape}>, tensor<[0-9x]+xi32>, tensor<[^>]+>\) "
+        rf"-> tensor<{shape}>", text,
+    )
+
+
 def test_a_recurrent_models_decode_program_takes_the_kernel_and_the_others_do_not(
     step_programs,
 ):
     """Lowered as on the chip, a model with recurrent layers of lane-tile
-    heads: the decode program holds the kernel and no `select` over the
-    whole per-slot state (the `where` that handed the idle rows back);
-    its prefill writes the state through `kda_chunked` and its train step
-    differentiates the chunked form: neither holds the call."""
+    heads: the decode program holds the step kernel and no `select` over
+    the whole per-slot state (the `where` that handed the idle rows back);
+    its prefill holds the scan kernel, whose output block is the slot's
+    row, and no scatter over the `"S"` state; its train step
+    differentiates the chunked form and holds neither call."""
     from tests import test_kimi_linear as kimi
 
-    model = kimi._model(num_heads=8, kda_head_dim=128)
+    model = kimi._model(num_heads=8, kda_head_dim=128, kda_chunk=kc.SUB)
     from flexflow_tpu.serving import ServeConfig, build_scheduler
 
     sched, eng, cache = build_scheduler(
@@ -756,9 +821,12 @@ def test_a_recurrent_models_decode_program_takes_the_kernel_and_the_others_do_no
     prefill = prefill.trace(*p_shapes).lower(lowering_platforms=("tpu",)).as_text()
     decode = decode.trace(*d_shapes).lower(lowering_platforms=("tpu",)).as_text()
     assert ks.NAME in decode and not _all_slot_selects(decode, state)
-    assert ks.NAME not in prefill
-    assert ks.NAME not in _train_step_text(model)
+    assert kc.NAME in prefill and not _state_scatters(prefill, state)
+    assert ks.NAME not in prefill and kc.NAME not in decode
+    train = _train_step_text(model)
+    assert ks.NAME not in train and kc.NAME not in train
     assert eng.kda_kernel_programs_decode == 1 and eng.kernel_fallbacks == 0
+    assert eng.kda_kernel_programs_prefill == eng.prefill_programs == 1
 
 
 @pytest.mark.parametrize(
@@ -789,8 +857,10 @@ def test_programs_without_lane_tile_recurrent_heads_hold_no_kda_kernel_call(
     texts, eng = _served_programs(model, step_programs, seq)
     texts["train"] = _train_step_text(model)
     for kind, text in texts.items():
-        assert ks.NAME not in text, kind
-    assert eng.kda_kernel_programs_decode == 0
+        assert ks.NAME not in text and kc.NAME not in text, kind
+    assert eng.kda_kernel_programs_decode == eng.kda_kernel_programs_prefill == 0
     if family == "toy_recurrent":
+        # heads of 16 keep `kda_chunked` and the scatter
         state = next(iter(eng.cache.state.values()))["S"]
         assert _all_slot_selects(texts["decode"], state)
+        assert _state_scatters(texts["prefill"], state)
