@@ -631,7 +631,7 @@ class Executor:
 
     # -- data placement ------------------------------------------------------
 
-    def shard_batch(self, batch: Dict[str, np.ndarray]):
+    def shard_batch(self, batch: Dict[str, np.ndarray], tracer=None):
         """Host→device transfer with each input's searched sharding
         (the TPU analog of the reference's SingleDataLoader index-launched
         shard copies, python/flexflow_dataloader.cc). On multi-host runs
@@ -640,7 +640,9 @@ class Executor:
         (runtime/multihost.place_batch)."""
         from flexflow_tpu.runtime.multihost import place_batch
 
-        return place_batch(self, batch, multi=jax.process_count() > 1)
+        return place_batch(
+            self, batch, multi=jax.process_count() > 1, tracer=tracer
+        )
 
     def input_shapes(self) -> Dict[str, ParallelTensorShape]:
         out = {}

@@ -1284,7 +1284,9 @@ class FFModel:
                 with span("train.input.next_batch", tracer):
                     np_batch = loader.borrow_batch(lease_wait)
                 with span("train.input.shard_batch", tracer):
-                    batch = loader.lend(self.executor.shard_batch(np_batch))
+                    batch = loader.lend(
+                        self.executor.shard_batch(np_batch, tracer)
+                    )
                 with span("train.input.dispatch", tracer):
                     self._rng, key = jax.random.split(self._rng)
                     self.params, self.opt_state, loss, mets = step(

@@ -113,7 +113,7 @@ def place_array(value, sharding=None, multi: Optional[bool] = None):
 
 
 def place_batch(
-    executor, batch: Dict[str, np.ndarray], multi: bool
+    executor, batch: Dict[str, np.ndarray], multi: bool, tracer=None
 ) -> Dict[str, "np.ndarray"]:
     """THE batch-placement loop (single source of truth for both the
     single- and multi-host paths — Executor.shard_batch delegates here).
@@ -125,13 +125,14 @@ def place_batch(
     process's devices own — the analog of the reference's
     SingleDataLoader index-launch shard copies
     (python/flexflow_dataloader.cc: every node sees the whole dataset in
-    zero-copy memory; each GPU's task copies out just its slice)."""
+    zero-copy memory; each GPU's task copies out just its slice).
+    `tracer`: the caller's Chrome tracer, for the span of each array."""
     import jax
 
     shapes = executor.input_shapes()
     out = {}
     for name, arr in batch.items():
-        with span(f"train.input.shard_batch.{name}"):
+        with span(f"train.input.shard_batch.{name}", tracer):
             if name in shapes:
                 sharding = executor.sharding_for(shapes[name])
                 out[name] = place_array(arr, sharding, multi=multi)

@@ -131,12 +131,12 @@ import dataclasses
 import logging
 import time
 from collections import OrderedDict
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 from flexflow_tpu.core.types import OperatorType
-from flexflow_tpu.telemetry.trace import span
+from flexflow_tpu.telemetry.trace import StepLog, StepRecord, span
 
 _log = logging.getLogger(__name__)
 
@@ -282,8 +282,10 @@ class InflightStep:
     reconcile blocks on.
     """
 
-    kind: str  # "decode" | "verify" | "chunk"
-    dispatch_t: float  # wall clock at dispatch (overlap accounting)
+    kind: str  # "decode" | "verify" | "verify_tree" | "chunk"
+    # the program's entry in `engine.step_log`: its stamps (the call, the
+    # enqueue, the read) are the overlap accounting's and the trace's
+    record: StepRecord
     active: np.ndarray  # bool [max_seqs] — slots the step ran for
     lengths: np.ndarray  # int32 [max_seqs] — cache lengths BEFORE the step
     host_tokens: Optional[np.ndarray] = None  # decode: host-view input tokens
@@ -323,10 +325,15 @@ class InflightStep:
     # record).
     tree_parents: Optional[np.ndarray] = None  # int32 [max_seqs, w]
     tree_plan: Optional[Dict[int, object]] = None  # slot -> DraftTree
-    # dispatch sequence number (scheduler._note_dispatch): the trace
-    # layer's step index — device in-flight windows alternate lanes by
-    # its parity so overlapping async windows still render
-    seq: int = -1
+
+
+class PendingPrefill(NamedTuple):
+    """An admission's prefill programs between `prefill_dispatch` and
+    `prefill_reconcile`."""
+
+    outs: list  # a program's outputs a group, on the device
+    records: List[StepRecord]  # a group's program, in the step log
+    groups: List[Tuple[int, int]]  # [lo, hi) of the admission's prompts
 
 
 class GenerationEngine:
@@ -375,6 +382,9 @@ class GenerationEngine:
             else None
         )
         self._tracer = getattr(self.telemetry, "tracer", None)
+        # every program this engine dispatches, and the requests they ran
+        # for (telemetry/trace.py; always on, reachable by `step_logs()`)
+        self.step_log = StepLog()
         # counted inside the span that does the work, mirrored into
         # SchedulerStats at each iteration's end: blocking reads of a
         # device value and the bytes they brought to the host; prompt
@@ -752,10 +762,12 @@ class GenerationEngine:
 
     def _run_step(
         self, site: str, step_fn, params, inputs, adapter_args=(),
-        program=None, kernel_path: bool = True,
+        program=None, kernel_path: bool = True, *, record: StepRecord,
     ):
         """Call one step program on the live pools, commit the pools it
-        returns, and hand back the rest of its outputs.
+        returns, and hand back the rest of its outputs. `record` enters
+        `step_log` here, stamped around the call: all five step bodies
+        are dispatched through this one place.
 
         Every step program has this shape: `(params, *inputs, ck, cv,
         cks, cvs, cs [, ad])` in, `(ck', cv', cks', cvs', cs', ...)` out,
@@ -780,11 +792,13 @@ class GenerationEngine:
         def call():
             return step_fn()(*args)
 
+        t_call = time.perf_counter()
         out = (
             self._dispatch(site, call, program, witness)
             if kernel_path
             else call()
         )
+        self.step_log.enqueued(record, t_call, time.perf_counter())
         if witness.is_deleted():
             self.pool_steps_donated += 1
         else:
@@ -975,13 +989,16 @@ class GenerationEngine:
         self._chunk_cache.clear()
         self._tree_cache.clear()
 
-    def _readback(self, kind: str, *arrays):
+    def _readback(self, kind: str, *arrays, records=()):
         """Bring a step's device outputs to the host: one blocking read
-        each, counted where it happens. This is where a fault the device
-        raised while running the step's program surfaces (`_dispatch`
-        blocks on a program's first run only), and by then the program
-        has consumed the pools it was handed: PoolsLostError."""
+        each, counted where it happens, and the stamps of the read on the
+        `records` of the programs it closes. This is where a fault the
+        device raised while running the step's program surfaces
+        (`_dispatch` blocks on a program's first run only), and by then
+        the program has consumed the pools it was handed:
+        PoolsLostError."""
         with span(f"scheduler.step.{kind}.readback", self._tracer):
+            t_read = time.perf_counter()
             try:
                 out = [np.asarray(a) for a in arrays]
             except Exception as e:
@@ -989,6 +1006,8 @@ class GenerationEngine:
                     f"{kind} step failed after its program consumed the "
                     f"KV pools: {e!r}"
                 ) from e
+            finally:
+                self.step_log.read(records, t_read, time.perf_counter())
             self.device_syncs += len(out)
             self.readback_bytes += sum(a.nbytes for a in out)
         return out
@@ -1409,11 +1428,12 @@ class GenerationEngine:
         params,
         prompts: Sequence[Sequence[int]],
         slots: Sequence[int],
-    ) -> list:
+    ) -> PendingPrefill:
         """Enqueue one admission's programs WITHOUT reading anything
         back: the cache arrays commit and the slots' lengths are set
         here. Returns what `prefill_reconcile` reads, a program's outputs
-        a group, still on the device, their copies to the host started."""
+        a group, still on the device, their copies to the host started,
+        beside each program's record and its group of the prompts."""
         spec = self.cache.spec
         n = len(prompts)
         if n == 0:
@@ -1433,11 +1453,12 @@ class GenerationEngine:
                 lo, total = i, 0
             total += laid
         groups.append((lo, n))
-        outs, choices = [], []
+        outs, records, choices = [], [], []
         for lo, hi in groups:
-            nxt, last, *moe = self._prefill_group(
+            rec, (nxt, last, *moe) = self._prefill_group(
                 params, prompts[lo:hi], slots[lo:hi]
             )
+            records.append(rec)
             if self._moe_share:
                 *moe, choice = moe
                 choices.append((choice, [len(p) for p in prompts[lo:hi]]))
@@ -1448,12 +1469,18 @@ class GenerationEngine:
             self.moe_choice["prefill"] = _PackedChoice(
                 choices, self._state_chunk
             )
-        return outs
+        return PendingPrefill(outs, records, groups)
 
-    def prefill_reconcile(self, outs: list) -> Tuple[np.ndarray, np.ndarray]:
+    def prefill_reconcile(
+        self, pending: PendingPrefill
+    ) -> Tuple[np.ndarray, np.ndarray]:
         """Wait for a dispatched admission's tokens, logits and counts:
         (next_tokens [n], last_logits [n, V]) in request order."""
-        host = self._readback("prefill", *(a for out in outs for a in out))
+        outs = pending.outs
+        host = self._readback(
+            "prefill", *(a for out in outs for a in out),
+            records=pending.records,
+        )
         width = len(outs[0])  # tokens, logits, and the counts if any
         host = [host[i : i + width] for i in range(0, len(host), width)]
         for _, _, *counts in host:
@@ -1472,7 +1499,8 @@ class GenerationEngine:
 
     def _prefill_group(self, params, prompts, slots):
         """Pack `prompts` (whose total fits the largest bucket) into one
-        row and dispatch its program: `_prefill_impl_paged`'s outputs
+        row and dispatch its program: its record, and
+        `_prefill_impl_paged`'s outputs
         behind the pools, still on the device. Padding, behind the last
         prompt or up to a chunk boundary between two (`_laid`), is
         segment max_seqs with an out-of-bounds destination."""
@@ -1516,9 +1544,10 @@ class GenerationEngine:
                 self._prefill_cache[bucket] = fn
             inputs = (jnp.asarray(layout), jnp.asarray(requests))
         with span("scheduler.step.prefill.dispatch", self._tracer):
+            rec = StepRecord("prefill", rows=total, bucket=bucket)
             out = self._run_step(
                 "prefill", lambda: fn, params, inputs,
-                self._adapter_slot_args(), kernel_path=False,
+                self._adapter_slot_args(), kernel_path=False, record=rec,
             )
             self.moe_kernel_programs_prefill += self._moe_kernel_traced.get(
                 ("prefill", bucket), False
@@ -1528,7 +1557,7 @@ class GenerationEngine:
             )
             for s, n in zip(slots, lens):
                 self.cache.lengths[s] = n
-        return out
+        return rec, out
 
     def prefill_suffix(
         self,
@@ -1841,6 +1870,7 @@ class GenerationEngine:
             else self._chain_feed(params)
         )
         lengths_snap = np.array(self.cache.lengths)
+        rec = StepRecord("decode", rows=int(active.sum()))
         # snapshot(): lengths += 1 below, and allocator table edits
         # between iterations, mutate behind the async dispatch queue
         nxt, logits, readback = self._keep_choice(
@@ -1850,6 +1880,7 @@ class GenerationEngine:
                 params,
                 (chained, snapshot(self._pack_state(host_tokens, mask, active))),
                 self._adapter_slot_args(),
+                record=rec,
             ),
         )
         self._last_next = nxt
@@ -1873,7 +1904,7 @@ class GenerationEngine:
         self.cache.begin_inflight()
         return InflightStep(
             kind="decode",
-            dispatch_t=time.perf_counter(),
+            record=rec,
             active=active.copy(),
             lengths=lengths_snap,
             host_tokens=host_tokens,
@@ -1894,7 +1925,9 @@ class GenerationEngine:
         needs lives on the step record's snapshots — by the time this
         runs, live cache/scheduler state is one iteration ahead."""
         try:
-            (got,) = self._readback("decode", step.device_readback)
+            (got,) = self._readback(
+                "decode", step.device_readback, records=(step.record,)
+            )
         finally:
             self.cache.end_inflight()
         n = step.active.shape[0]
@@ -2179,6 +2212,7 @@ class GenerationEngine:
         # snapshot() lengths/tables: the caller truncates the cache
         # right after the reconcile, and jnp.asarray's host read is
         # deferred behind the dispatch queue — see decode_dispatch()
+        rec = StepRecord("verify", rows=int(np.count_nonzero(draft_lens)))
         (logits,) = self._run_step(
             "verify",
             lambda: self._verify_fn(w),
@@ -2191,11 +2225,12 @@ class GenerationEngine:
             ),
             self._adapter_slot_args(),
             program=("verify", w),
+            record=rec,
         )
         self.cache.begin_inflight()
         return InflightStep(
             kind="verify",
-            dispatch_t=time.perf_counter(),
+            record=rec,
             active=np.asarray(draft_lens) > 0,
             lengths=lengths_snap,
             draft_lens=np.array(draft_lens),
@@ -2207,7 +2242,9 @@ class GenerationEngine:
         in-flight window. Acceptance/rollback decisions belong to the
         caller, made against the step record's SNAPSHOT lengths."""
         try:
-            return self._readback(step.kind, step.device_logits)[0]
+            return self._readback(
+                step.kind, step.device_logits, records=(step.record,)
+            )[0]
         finally:
             self.cache.end_inflight()
 
@@ -2275,6 +2312,9 @@ class GenerationEngine:
                 )
         self._claim_rows(draft_lens)
         lengths_snap = np.array(self.cache.lengths)
+        rec = StepRecord(
+            "verify_tree", rows=int(np.count_nonzero(draft_lens))
+        )
         (logits,) = self._run_step(
             "verify",
             lambda: self._tree_fn(w),
@@ -2288,11 +2328,12 @@ class GenerationEngine:
             ),
             self._adapter_slot_args(),
             program=("tree", w),
+            record=rec,
         )
         self.cache.begin_inflight()
         return InflightStep(
             kind="verify_tree",
-            dispatch_t=time.perf_counter(),
+            record=rec,
             active=np.asarray(draft_lens) > 0,
             lengths=lengths_snap,
             draft_lens=np.array(draft_lens),
@@ -2480,6 +2521,7 @@ class GenerationEngine:
         # The batch compacts to the chunking slots (tokens/chunk_lens
         # rows); the jitted impl gathers its lengths/tables rows from
         # the full snapshots by slot_ids.
+        rec = StepRecord("chunk", rows=int(chunk_lens.sum()))
         nxt, last = self._run_step(
             "chunk",
             lambda: self._chunk_fn((slot_ids.size, w)),
@@ -2493,6 +2535,7 @@ class GenerationEngine:
             ),
             self._adapter_slot_args(),
             program=("chunk", slot_ids.size, w),
+            record=rec,
         )
         # prompt rows are committed by construction — advance the
         # cursors now so the NEXT chunk step dispatches against them
@@ -2501,7 +2544,7 @@ class GenerationEngine:
         self.cache.begin_inflight()
         return InflightStep(
             kind="chunk",
-            dispatch_t=time.perf_counter(),
+            record=rec,
             active=np.array(active, dtype=bool),
             lengths=lengths_snap,
             draft_lens=np.array(chunk_lens),
@@ -2521,7 +2564,8 @@ class GenerationEngine:
         snapshot on the step record says which."""
         try:
             nxt_c, logits_c = self._readback(
-                "chunk", step.device_next, step.device_logits
+                "chunk", step.device_next, step.device_logits,
+                records=(step.record,),
             )
         finally:
             self.cache.end_inflight()

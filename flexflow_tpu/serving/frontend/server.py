@@ -450,7 +450,8 @@ class FrontDoor:
         stream — no scheduler hook needed, and a burst (speculative
         accepts, chunk-final + decode) publishes as individual
         events."""
-        with span("door.pump.publish"):
+        tele = getattr(self.backend, "telemetry", None)
+        with span("door.pump.publish", getattr(tele, "tracer", None)):
             for rid, queue in list(self._queues.items()):
                 if rid in self._done:
                     continue

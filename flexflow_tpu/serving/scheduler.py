@@ -84,7 +84,11 @@ from flexflow_tpu.serving.engine import KernelCompileError
 from flexflow_tpu.serving.kv_cache import PagePoolExhausted
 from flexflow_tpu.telemetry import MetricsRegistry
 from flexflow_tpu.telemetry.slo import percentiles as _percentiles
-from flexflow_tpu.telemetry.trace import span
+from flexflow_tpu.telemetry.trace import (
+    GAP_SHARE_BUCKETS,
+    request_parts,
+    span,
+)
 
 
 class RequestStatus:
@@ -695,7 +699,13 @@ class _SchedulerBase:
         self._iter = 0
         self._iter_t0 = 0.0
         self._gauge_handles: Optional[Dict[str, object]] = None
-        self._last_dispatch_t: Optional[float] = None
+        # the engine's record of every program it dispatches: this
+        # scheduler signs the records with what only it knows, hands the
+        # log its requests, and advances the dispatch/commit split of
+        # its stats from them (`_note_dispatch`, `_note_read`)
+        self.step_log = engine.step_log
+        self.step_log.running = self.running
+        self._noted = None  # the newest step's record (`_note_dispatch`)
         # per-iteration budget ledger: zeroed by _begin_iteration,
         # published as the `budget_used` gauge by _end_iteration
         self._budget_used_iter = 0
@@ -880,6 +890,7 @@ class _SchedulerBase:
             self.cache.discard_swap(req.swap_handle)
             req.swap_handle = None
         self.finished.append(req)
+        stamps = self.step_log.retire(req.rid, req.events)
         stats = self.stats
         stats.events_dropped += req.events_dropped
         if status == RequestStatus.FINISHED:
@@ -949,7 +960,25 @@ class _SchedulerBase:
                     mon.observe_finished(
                         req.finish_time, len(req.generated)
                     )
-            tele.tracer.request_lifecycle(req)
+            parts = request_parts(stamps, self.step_log.records)
+            for part, seconds in (parts.ttft or {}).items():
+                reg.histogram(
+                    "serve_ttft_part_ms",
+                    help="submit to first token, by what the request "
+                    "waited for (telemetry.trace.request_parts)",
+                    labels={"part": part},
+                ).observe(1e3 * seconds)
+            whole = sum((parts.gap or {}).values())
+            if whole > 0.0:
+                for part, seconds in parts.gap.items():
+                    reg.histogram(
+                        "serve_token_gap_part_share",
+                        bounds=GAP_SHARE_BUCKETS,
+                        help="share of first token to terminal event, by "
+                        "what the request waited for",
+                        labels={"part": part},
+                    ).observe(seconds / whole)
+            tele.tracer.request_lifecycle(req, parts)
 
     def _fail(self, req: Request, error: str) -> None:
         self._finalize(req, RequestStatus.FAILED, error=error)
@@ -1485,6 +1514,9 @@ class _SchedulerBase:
             except Exception as e:
                 self._fail_admission(admitted, e)
                 return admitted
+            if pending is not None:
+                for rec, (lo, hi) in zip(pending.records, pending.groups):
+                    self._sign(rec, [admitted[i] for i in plain[lo:hi]])
             self._admission = _Admission(
                 admitted, seqs, cursors, pending, programs,
                 frozenset(r.slot for r in admitted),
@@ -1525,6 +1557,10 @@ class _SchedulerBase:
                     [admitted[i].slot for i in shared],
                     [cursors[i] for i in shared],
                 )
+                # the suffixes' one chunk step, dispatched and read by now
+                self._sign(
+                    self.step_log.records[-1], [admitted[i] for i in shared]
+                )
                 for j, i in enumerate(shared):
                     rows[i] = (int(nxt_s[j]), np.asarray(last_s[j]))
             nxt = np.array([rows[i][0] for i in range(len(admitted))])
@@ -1535,6 +1571,12 @@ class _SchedulerBase:
         self.stats.prefill_batches += (
             self.engine.prefill_programs - programs + bool(shared)
         )
+        if self._tele is not None and pending is not None:
+            for rec in pending.records:
+                self._tele.tracer.device_window(
+                    rec.kind, rec.seq, rec.t_call, rec.t_ready,
+                    args={"iter": rec.iteration, "bucket": rec.bucket},
+                )
         live = [self.running.get(r.slot) is r for r in admitted]
         if self.cache.prefix_cache:
             # publish AFTER the prefill returned: a failed dispatch
@@ -1603,16 +1645,34 @@ class _SchedulerBase:
         for req in list(self.running.values()):
             self._fail(req, error)
 
+    def _sign(self, rec, requests) -> None:
+        """What only the scheduler knows of a dispatched program."""
+        rec.iteration = self._iter
+        rec.rids = tuple(r.rid for r in requests)
+
     def _note_dispatch(self, step) -> None:
         self.stats.dispatch_count += 1
-        # dispatch sequence number: the trace layer's step index (device
-        # in-flight windows alternate lanes by its parity)
-        step.seq = int(self.stats.dispatch_count)
-        if self._last_dispatch_t is not None:
+        rec = step.record
+        self._sign(rec, step.participants.values())
+        if self._noted is not None:
             self.stats.dispatch_gap_sum_s += (
-                step.dispatch_t - self._last_dispatch_t
+                rec.t_enqueued - self._noted.t_enqueued
             )
-        self._last_dispatch_t = step.dispatch_t
+        self._noted = rec
+
+    def _note_read(self, step) -> None:
+        """A step's record has closed: the dispatch/commit split of the
+        stats advances from it, and the trace gets its window."""
+        rec = step.record
+        self.stats.overlapped_host_s += max(0.0, rec.t_read - rec.t_enqueued)
+        self.stats.commit_wait_s += rec.t_ready - rec.t_read
+        if self._tele is not None:
+            # the step's whole in-flight window (the call → outputs
+            # materialized) on a device lane
+            self._tele.tracer.device_window(
+                rec.kind, rec.seq, rec.t_call, rec.t_ready,
+                args={"iter": rec.iteration},
+            )
 
     def _decode_dispatch_step(self, chain=None):
         """Dispatch phase of one decode iteration: claim every page the
@@ -1701,8 +1761,6 @@ class _SchedulerBase:
         """Reconcile phase: block on the step's device outputs, then
         commit its results — under the async loop this runs one
         iteration after the dispatch, against the step's snapshot."""
-        t0 = time.perf_counter()
-        self.stats.overlapped_host_s += max(0.0, t0 - step.dispatch_t)
         try:
             if step.kind == "decode":
                 nxt, finite = self.engine.decode_reconcile(step)
@@ -1713,8 +1771,7 @@ class _SchedulerBase:
         except Exception as e:
             self._fail_all_running(f"{step.kind} step failed: {e!r}")
             return
-        t1 = time.perf_counter()
-        self.stats.commit_wait_s += t1 - t0
+        self._note_read(step)
         # every reconcile is exactly one host round-trip, whatever the
         # step's width — the denominator of host_syncs_per_token
         self.stats.host_syncs += 1
@@ -1723,7 +1780,7 @@ class _SchedulerBase:
         # record, never live cache state (fxlint FX103)
         with span(
             f"scheduler.step.{step.kind}.commit", self._tracer,
-            {"iter": step.iteration, "step": step.seq},
+            {"iter": step.iteration, "step": step.record.seq},
         ):
             if step.kind == "decode":
                 self._commit_decode(step, nxt, finite)
@@ -1733,16 +1790,6 @@ class _SchedulerBase:
                 self._commit_verify_tree(step, logits)
             else:
                 self._commit_verify(step, logits)
-        if self._tele is not None:
-            # the step's whole in-flight window (dispatch → outputs
-            # materialized) on a device lane
-            self._tele.tracer.device_window(
-                step.kind,
-                step.seq,
-                step.dispatch_t,
-                t1,
-                args={"iter": step.iteration},
-            )
 
     def _commit_decode(self, step, nxt, finite) -> None:
         """Commit a reconciled decode step: NaN isolation, token emit,

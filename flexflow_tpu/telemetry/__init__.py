@@ -52,8 +52,12 @@ from flexflow_tpu.telemetry.trace import (
     PID_REQUESTS,
     TID_DEVICE0,
     TID_HOST,
+    StepLog,
+    StepRecord,
     Tracer,
+    request_parts,
     span,
+    step_logs,
 )
 from flexflow_tpu.telemetry.validate import (
     ValidationError,
@@ -86,6 +90,10 @@ __all__ = [
     "validate_durability_metrics",
     "Tracer",
     "span",
+    "StepLog",
+    "StepRecord",
+    "request_parts",
+    "step_logs",
     "SLOMonitor",
     "RollingWindow",
     "percentiles",
@@ -127,7 +135,7 @@ class NullTracer:
     def device_window(self, *a, **k) -> None:
         pass
 
-    def request_lifecycle(self, req) -> None:
+    def request_lifecycle(self, req, parts=None) -> None:
         pass
 
     def host_lane(self, host: int) -> int:

@@ -27,8 +27,21 @@ it, from the harness's own stamps of the requests due in the window:
   `kda_kernel_programs_decode`, PR 48; `kda_kernel_programs_prefill`,
   PR 52; `null` on a tree before them).
 
+* `program_split`: the same time to first token from the PROGRAM's own
+  record (`engine.step_log`, `telemetry.trace.request_parts`; PR 54):
+  `queue_ms`, `ahead_ms` (admit -> its prefill is on the device's
+  queue), `inflight_ms` (-> the prefill's outputs are the host's),
+  `emit_ms` (-> `first_token`), their sum `admit_to_first_ms`, and
+  `harness_less_program_ms`, the harness's `first - admit` less the
+  program's for the same request: the client's wake-up. `gap_*_share`:
+  a request's first token -> terminal event by what it waited for.
+  `sum_error_us`: the largest distance of a request's parts from its
+  own `submit -> first_token` and `first_token -> terminal` (rounding).
+  `null` on a tree without the record.
+
 Each as [p50, p90]. With `PROBE_DUMP=<file>` in the environment every
-request's stamps and every step's tuple are written there as JSON, so
+request's stamps (the program's split beside them, under `program`),
+every step's tuple and every step record are written there as JSON, so
 that two trees can be compared request by request (same seed, same
 index) where a percentile is one request's luck (`serve_gpt2m_chat`'s
 `ttft_p50_ms`: `PERF.md` section 7, PR 43). Run it in a process that fetches its programs (after
@@ -79,6 +92,7 @@ def main(argv=None) -> int:
     if by_pass is not None:
         running = sum(n for label, n in by_pass.items() if label != "idle")
         later = (running - by_pass.get("1", 0)) / running if running else 0.0
+    split, by_rid, log = program_split(sched, win, both)
     print(json.dumps({
         "door_probe": {
             "requests": len(win),
@@ -103,18 +117,68 @@ def main(argv=None) -> int:
                     "kda_kernel_programs_decode", "kda_kernel_programs_prefill",
                 )
             } if sched is not None else None,
+            "program_split": split,
         }
     }), flush=True)
     dump = os.environ.get("PROBE_DUMP")
     if dump:
         stamps = ("index", "segment", "prompt_len", "asked", "due", "started",
                   "accepted", "admit", "admit_iter", "first", "last", "done", "tokens")
+        fields = ("seq", "kind", "bucket", "iteration", "t_call", "t_enqueued",
+                  "t_read", "t_ready", "rows", "rids", "chained")
         with open(dump, "w") as f:
             json.dump({
-                "requests": [{k: getattr(r, k) for k in stamps} for r in seen["records"]],
+                "requests": [
+                    dict({k: getattr(r, k) for k in stamps},
+                         program=by_rid.get(r.rid))
+                    for r in seen["records"]
+                ],
                 "steps": [[float(x) for x in step] for step in seen["steps"]],
+                "step_records": [
+                    {k: getattr(rec, k) for k in fields} for rec in log
+                ],
             }, f)
     return 0
+
+
+def program_split(sched, win, both):
+    """The window requests' time by the program's own record: the
+    summary, each request's parts by rid, and the records."""
+    log = getattr(sched, "step_log", None)
+    if log is None:
+        return None, {}, []
+    from flexflow_tpu.telemetry.trace import request_parts
+
+    records = list(log.records)
+    by_rid, worst = {}, 0.0
+    for s in log.requests():
+        parts = request_parts(s, records)
+        by_rid[s.rid] = {
+            "stamps": list(s[1:]), "ttft": parts.ttft, "gap": parts.gap,
+            "others_prefills": len(parts.others_at),
+        }
+        if parts.ttft:
+            worst = max(worst, abs(sum(parts.ttft.values()) - (s.first_token - s.submit)))
+        if parts.gap:
+            worst = max(worst, abs(sum(parts.gap.values()) - (s.terminal - s.first_token)))
+    mine = [(r, by_rid[r.rid]) for r in win if by_rid.get(r.rid, {}).get("ttft")]
+    split = {"requests": len(mine), "sum_error_us": 1e6 * worst}
+    for part in ("queue", "ahead", "inflight", "emit"):
+        split[part + "_ms"] = both([1e3 * p["ttft"][part] for _, p in mine])
+    first = [
+        1e3 * (p["ttft"]["ahead"] + p["ttft"]["inflight"] + p["ttft"]["emit"])
+        for _, p in mine
+    ]
+    split["admit_to_first_ms"] = both(first)
+    split["harness_less_program_ms"] = both(
+        [1e3 * (r.first - r.admit) - f for (r, _), f in zip(mine, first)]
+    )
+    gaps = [p["gap"] for _, p in mine if p["gap"] and sum(p["gap"].values()) > 0]
+    for part in ("others_prefill", "decode", "host"):
+        split[f"gap_{part}_share"] = both(
+            [100.0 * g[part] / sum(g.values()) for g in gaps]
+        )
+    return split, by_rid, records
 
 
 if __name__ == "__main__":

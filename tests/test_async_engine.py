@@ -644,3 +644,135 @@ def test_inflight_step_snapshot_is_immutable_view(lm):
     assert finite.shape == (4,) and finite.dtype == bool and finite[slot]
     assert np.isfinite(np.asarray(step.device_logits)[slot]).all()
     assert nxt.shape == (4,) and nxt.dtype == np.int32
+
+
+# -- the record of every dispatched step program (ISSUE 54) -------------------
+
+
+def _closed(rec):
+    return rec.t_call <= rec.t_enqueued <= rec.t_read <= rec.t_ready
+
+
+@pytest.mark.parametrize("serve_async", [True, False], ids=["async", "sync"])
+@pytest.mark.parametrize(
+    "kind,kw",
+    [
+        ("prefill", {}),
+        ("decode", {}),
+        ("verify", dict(spec_draft="ngram", spec_k=3)),
+        ("verify_tree", dict(spec_draft="ngram", spec_k=3, spec_branch=2)),
+        ("chunk", dict(token_budget=8, chunk_size=4, decode_kernel="dense")),
+    ],
+    ids=["prefill", "decode", "verify", "verify_tree", "chunk"],
+)
+def test_step_log_holds_one_record_a_dispatched_program(lm, serve_async, kind, kw):
+    """Every program the engine dispatches is one `StepRecord` in
+    `engine.step_log`, whichever of the five step bodies and whichever
+    loop: the steps the scheduler counts plus the prefill programs, each
+    with its four stamps in order and the requests it ran for."""
+    sched, engine, _, done = _run(lm, serve_async, max_new=10, **kw)
+    log, st = engine.step_log, sched.stats
+    assert sched.step_log is log and log.dropped_records == 0
+    records = list(log.records)
+    assert len(records) == log.dispatched
+    assert len(records) == st.dispatch_count + engine.prefill_programs
+    assert [r.seq for r in records] == list(range(1, len(records) + 1))
+    assert all(_closed(r) for r in records)
+    by_kind = {}
+    for r in records:
+        by_kind.setdefault(r.kind, []).append(r)
+    assert kind in by_kind
+    assert len(by_kind.get("prefill", ())) == engine.prefill_programs
+    assert len(by_kind.get("decode", ())) == st.decode_steps
+    assert len(by_kind.get("chunk", ())) == st.chunk_steps
+    assert len(by_kind.get("verify", ())) + len(
+        by_kind.get("verify_tree", ())
+    ) == st.verify_steps
+    assert len(by_kind.get("verify_tree", ())) == st.tree_verify_steps
+    rids = set(done)
+    for r in records:
+        assert r.rids and set(r.rids) <= rids, r
+        assert r.iteration >= 1 and r.rows >= 1
+        assert (r.bucket is not None) == (r.kind == "prefill")
+    # a prefill's rows are its prompts' real tokens
+    assert sum(r.rows for r in by_kind.get("prefill", ())) == (
+        engine.prefill_tokens_real
+    )
+    # records are in the order of the call, and all are read back
+    assert all(a.t_call <= b.t_call for a, b in zip(records, records[1:]))
+    assert log.open == 0
+    if not serve_async:
+        assert not any(r.chained for r in records)
+    elif kind == "decode":
+        chained = [r for r in by_kind["decode"] if r.chained]
+        assert len(chained) >= st.decode_steps_chained > 0
+
+
+@pytest.mark.parametrize("serve_async", [True, False], ids=["async", "sync"])
+def test_dispatch_commit_split_is_advanced_from_the_records(lm, serve_async):
+    """`overlapped_host_s`, `commit_wait_s`, `mean_dispatch_gap_s` and
+    `overlap_fraction` are what the ring says, recomputed here."""
+    sched, engine, _, _ = _run(lm, serve_async)
+    st = sched.stats
+    steps = [r for r in engine.step_log.records if r.kind != "prefill"]
+    assert len(steps) == st.dispatch_count > 1
+    overlapped = sum(max(0.0, r.t_read - r.t_enqueued) for r in steps)
+    waited = sum(r.t_ready - r.t_read for r in steps)
+    gaps = sum(b.t_enqueued - a.t_enqueued for a, b in zip(steps, steps[1:]))
+    assert st.overlapped_host_s == pytest.approx(overlapped, abs=1e-9)
+    assert st.commit_wait_s == pytest.approx(waited, abs=1e-9)
+    assert st.mean_dispatch_gap_s == pytest.approx(
+        gaps / (len(steps) - 1), abs=1e-9
+    )
+    assert st.overlap_fraction == pytest.approx(
+        overlapped / (overlapped + waited), abs=1e-9
+    )
+
+
+def test_step_log_is_bounded_and_counts_what_it_drops(lm):
+    from collections import deque
+
+    from flexflow_tpu.telemetry.trace import (
+        REQUEST_LOG_MAX, STEP_LOG_MAX, request_parts,
+    )
+
+    serve = ServeConfig(max_seqs=4, max_seq_len=32)
+    sched, engine, _ = build_scheduler(lm, serve)
+    log = engine.step_log
+    assert log.records.maxlen == STEP_LOG_MAX
+    assert log.retired.maxlen == REQUEST_LOG_MAX
+    log.records = deque(maxlen=5)
+    log.retired = deque(maxlen=2)
+    done = sched.run(_requests(6, 8))
+    assert all(r.ok for r in done)
+    assert len(log.records) == 5 and len(log.retired) == 2
+    assert log.dropped_records == log.dispatched - 5 > 0
+    assert log.dropped_requests == 4
+    assert log.records[-1].seq == log.dispatched
+    # what the ring no longer reaches is not accounted, never guessed
+    first = next(r for r in done if r.rid == 0)
+    from flexflow_tpu.telemetry.trace import lifecycle_stamps
+
+    parts = request_parts(lifecycle_stamps(0, first.events), log.records)
+    assert parts.ttft is None and parts.gap is None
+
+
+def test_direct_engine_calls_are_recorded_too(lm):
+    """The synchronous wrappers (`prefill`, `decode`) go through the
+    same place: a caller without a scheduler gets records without
+    request ids."""
+    serve = ServeConfig(max_seqs=4, max_seq_len=32)
+    _, engine, cache = build_scheduler(lm, serve)
+    slot = cache.alloc(2, 32)
+    engine.prefill(lm.params, [[1, 2]], [slot])
+    tokens = np.zeros(4, dtype=np.int32)
+    active = np.zeros(4, dtype=bool)
+    active[slot] = True
+    step = engine.decode_dispatch(lm.params, tokens, active)
+    pre, dec = engine.step_log.records
+    assert (pre.kind, pre.bucket, pre.rows) == ("prefill", cache.spec.bucket(2), 2)
+    assert step.record is dec and dec.kind == "decode" and dec.rows == 1
+    assert _closed(pre) and not pre.chained and not dec.chained
+    assert dec.t_ready is None and dec.rids == ()
+    engine.decode_reconcile(step)
+    assert _closed(dec)

@@ -360,12 +360,31 @@ def test_training_chrome_export_carries_fit_phases_and_validates():
     _fit(telemetry=tele, epochs=2)
     doc = tele.tracer.to_json()
     names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
-    # place_batch has no tracer to hand: its per-input spans are the
-    # profiler's only
-    assert names == (
-        TRAIN_SPANS
-        - {"train.input.shard_batch.x", "train.input.shard_batch.label"}
-    ) | {"iteration", "epoch"}
+    # place_batch is handed fit()'s tracer: its per-input spans are in
+    # the Chrome export too, inside `train.input.shard_batch`
+    assert names == TRAIN_SPANS | {"iteration", "epoch"}
+    assert validate_trace(doc, errors="list") == []
+
+
+def test_front_door_publish_span_reaches_the_chrome_export(lm):
+    import asyncio
+
+    from flexflow_tpu.serving.frontend.server import FrontDoor
+
+    tele = Telemetry(trace_enabled=True)
+
+    async def drive():
+        sched, _, _ = build_scheduler(
+            lm, ServeConfig(max_seqs=2, max_seq_len=32), telemetry=tele
+        )
+        door = FrontDoor(sched)
+        rid = await door.submit([1, 2, 3], max_new_tokens=3)
+        return [ev async for ev in door.stream(rid)]
+
+    out = asyncio.run(drive())
+    assert [ev.kind for ev in out] == ["token"] * 3 + ["done"]
+    doc = tele.tracer.to_json()
+    assert any(e["name"] == "door.pump.publish" for e in doc["traceEvents"])
     assert validate_trace(doc, errors="list") == []
 
 
@@ -441,3 +460,139 @@ def test_dense_decode_path_has_no_wait_and_one_sync_fewer_per_step(lm, tmp_path)
     assert names == SERVE_SPANS - {STEP + "decode.wait"}
     st = sched.stats
     assert st.device_syncs == 2 * st.prefill_batches + st.decode_steps
+
+
+# -- a request's time, by what it waited for (ISSUE 54) -------------------------
+
+
+def _rec(seq, kind, t_call, t_enqueued, t_read, t_ready, rids, bucket=None):
+    r = trace_mod.StepRecord(kind, rows=len(rids), bucket=bucket)
+    r.seq, r.rids = seq, tuple(rids)
+    r.t_call, r.t_enqueued, r.t_read, r.t_ready = t_call, t_enqueued, t_read, t_ready
+    return r
+
+
+#: request 7 is admitted at 1.0 with request 8; its prefill is called at
+#: 1.5, on the queue at 2.0 and read at 4.0. Between its tokens request 9
+#: is admitted: that prefill covers 6.0-7.5, over a decode step of 7's
+#: (5.5-6.5) and before the next (7.0-9.0); the last step is read at 11.
+HAND = [
+    _rec(1, "decode", 0.2, 0.3, 0.8, 0.9, (5,)),
+    _rec(2, "prefill", 1.5, 2.0, 3.5, 4.0, (7, 8), bucket=16),
+    _rec(3, "decode", 4.5, 4.6, 5.2, 5.4, (7, 8)),
+    _rec(4, "decode", 5.5, 5.6, 6.4, 6.5, (7, 8)),
+    _rec(5, "prefill", 6.0, 6.2, 7.3, 7.5, (9,), bucket=16),
+    _rec(6, "decode", 7.0, 7.1, 8.8, 9.0, (7, 8)),
+    _rec(7, "decode", 9.5, 9.6, 10.5, 11.0, (7, 9)),
+    _rec(8, "decode", 11.2, 11.3, 11.8, 12.0, (9,)),
+]
+
+
+def test_request_parts_over_a_hand_written_log():
+    events = [
+        (0.5, "submit", ""), (1.0, "admit", "slot 0"), (4.25, "first_token", ""),
+        (11.5, "finished", ""),
+    ]
+    stamps = trace_mod.lifecycle_stamps(7, events)
+    assert stamps == (7, 0.5, 1.0, 4.25, 11.5)
+    parts = trace_mod.request_parts(stamps, HAND)
+    assert parts.ttft == pytest.approx(
+        {"queue": 0.5, "ahead": 1.0, "inflight": 2.0, "emit": 0.25}
+    )
+    # others' prefill 6.0-7.5; its own steps cover 4.5-5.4, 5.5-6.0 (the
+    # prefill's window wins from there), 7.5-9.0 and 9.5-11.0
+    assert parts.gap == pytest.approx(
+        {"others_prefill": 1.5, "decode": 0.9 + 0.5 + 1.5 + 1.5,
+         "host": 7.25 - 1.5 - 4.4}
+    )
+    assert parts.others_at == (6.0,)
+    assert sum(parts.ttft.values()) == pytest.approx(4.25 - 0.5, abs=1e-12)
+    assert sum(parts.gap.values()) == pytest.approx(11.5 - 4.25, abs=1e-12)
+    # request 9 saw nobody else's prefill; a request still running has no
+    # gap parts yet, one never admitted none at all
+    nine = trace_mod.request_parts(
+        trace_mod.RequestStamps(9, 5.8, 5.9, 7.6, 12.5), HAND
+    )
+    assert nine.gap["others_prefill"] == 0.0 and nine.others_at == ()
+    assert nine.ttft["ahead"] == pytest.approx(0.3)
+    running = trace_mod.request_parts(
+        trace_mod.RequestStamps(9, 5.8, 5.9, 7.6, None), HAND
+    )
+    assert running.ttft == nine.ttft and running.gap is None
+    queued = trace_mod.request_parts(
+        trace_mod.RequestStamps(4, 5.8, None, None, None), HAND
+    )
+    assert queued.ttft is None and queued.gap is None
+
+
+def test_lifecycle_stamps_take_the_admission_the_first_token_came_out_of():
+    events = [
+        (0.0, "submit", ""), (1.0, "admit", ""), (2.0, "preempt", ""),
+        (3.0, "admit", ""), (4.0, "first_token", ""), (5.0, "preempt", ""),
+        (6.0, "admit", ""), (9.0, "cancelled", ""),
+    ]
+    assert trace_mod.lifecycle_stamps(3, events) == (3, 0.0, 3.0, 4.0, 9.0)
+    assert trace_mod.lifecycle_stamps(3, events[:3]) == (3, 0.0, 1.0, None, None)
+
+
+@pytest.mark.parametrize("serve_async", [True, False], ids=["async", "sync"])
+def test_parts_sum_to_the_whole_on_a_scripted_run(lm, serve_async):
+    """Requests 0 and 1 are admitted together; request 2 arrives while
+    they decode, so its prefill lands between their tokens."""
+    sched, engine, _ = build_scheduler(
+        lm, ServeConfig(max_seqs=4, max_seq_len=32, serve_async=serve_async)
+    )
+    first, second, late = (
+        Request(rid=i, prompt=list(p), max_new_tokens=8)
+        for i, p in enumerate(SCRIPT)
+    )
+    sched.submit(first)
+    sched.submit(second)
+    for _ in range(3):
+        sched.step()
+    assert len(first.generated) >= 1 and not first.finished
+    # the log answers for a request that is still running
+    live = {s.rid: s for s in engine.step_log.requests()}
+    assert live[0].terminal is None and live[0].first_token is not None
+    sched.submit(late)
+    done = sched.run()
+    assert all(r.ok for r in done)
+    log = engine.step_log
+    assert first.admit_iter == second.admit_iter < late.admit_iter
+    by_rid = {}
+    for stamps in log.requests():
+        parts = trace_mod.request_parts(stamps, log.records)
+        by_rid[stamps.rid] = parts
+        assert sum(parts.ttft.values()) == pytest.approx(
+            stamps.first_token - stamps.submit, abs=1e-6
+        )
+        assert sum(parts.gap.values()) == pytest.approx(
+            stamps.terminal - stamps.first_token, abs=1e-6
+        )
+        assert all(v >= 0.0 for v in parts.ttft.values())
+        assert all(v >= -1e-9 for v in parts.gap.values())
+    assert set(by_rid) == {0, 1, 2}
+    # co-admitted: one prefill program, so the same `inflight`
+    assert by_rid[0].ttft["inflight"] == by_rid[1].ttft["inflight"]
+    # the late admission's prefill is in the others' token gaps, not its own
+    for rid in (0, 1):
+        assert len(by_rid[rid].others_at) == 1
+        assert by_rid[rid].gap["others_prefill"] > 0.0
+    assert by_rid[2].others_at == () and by_rid[2].gap["others_prefill"] == 0.0
+    # and the program's stamps are the request's own
+    for r in (first, second, late):
+        assert sum(by_rid[r.rid].ttft.values()) == pytest.approx(r.ttft_s, abs=1e-4)
+
+
+def test_step_logs_reaches_the_live_engines_without_holding_one(lm):
+    import gc
+
+    before = set(map(id, trace_mod.step_logs()))
+    sched, engine, _ = build_scheduler(lm, ServeConfig(max_seqs=2, max_seq_len=32))
+    assert engine.step_log in trace_mod.step_logs()
+    sched.run(_requests())
+    mine = [g for g in trace_mod.step_logs() if id(g) not in before]
+    assert mine == [engine.step_log] and len(mine[0].retired) == 3
+    del sched, engine, mine
+    gc.collect()
+    assert set(map(id, trace_mod.step_logs())) <= before

@@ -364,6 +364,76 @@ def test_async_trace_shows_dispatch_overlapping_reconcile(async_run):
     )
 
 
+def test_async_trace_holds_the_prefill_windows_and_the_request_parts(lm):
+    """Eight requests over four slots: the later admissions' prefills
+    are dispatched with a decode step in flight and another chained
+    behind them, on a lane of their own; each request's RUNNING span
+    holds `ahead`, `inflight` and `emit` end to end from its admission to
+    its first token, and the earlier requests show the later prefills as
+    instants between their tokens."""
+    tele = Telemetry(trace_enabled=True)
+    sched, _, _ = build_scheduler(
+        lm, _serve(serve_async=True), telemetry=tele
+    )
+    # staggered ends: a slot frees while the others still decode
+    reqs = [
+        Request(rid=i, prompt=[1 + i, 2, 3], max_new_tokens=4 + 3 * (i % 4))
+        for i in range(8)
+    ]
+    done = sched.run(reqs)
+    assert all(r.ok for r in done)
+    doc = tele.tracer.to_json()
+    validate_trace(doc)
+    events = doc["traceEvents"]
+    windows = [e for e in events if e.get("name", "").startswith("inflight:")]
+    prefills = [e for e in windows if e["name"] == "inflight:prefill"]
+    assert len(prefills) == sched.engine.prefill_programs >= 2
+    assert {e["tid"] for e in prefills} == {12}
+    assert all(e["args"]["bucket"] for e in prefills)
+    steps = [e for e in windows if e["name"] != "inflight:prefill"]
+    assert {e["tid"] for e in steps} == {10, 11}
+    # a window's `step` is its record's seq: one number a program
+    assert sorted(e["args"]["step"] for e in windows) == [
+        r.seq for r in sched.step_log.records
+    ]
+    # some prefill was in flight together with a decode step
+    assert any(
+        p["ts"] < s["ts"] + s["dur"] and s["ts"] < p["ts"] + p["dur"]
+        for p in prefills for s in steps
+    )
+    for r in done:
+        lane = [e for e in events if e.get("pid") == 2 and e.get("tid") == r.rid]
+        spans = {e["name"]: e for e in lane if e.get("ph") == "X"}
+        running = spans["RUNNING"]
+        first = next(e for e in lane if e["name"] == "first_token")
+        at = running["ts"]
+        for name in ("ahead", "inflight", "emit"):
+            assert spans[name]["ts"] == pytest.approx(at, abs=2e-3)
+            at = spans[name]["ts"] + spans[name]["dur"]
+        assert at == pytest.approx(first["ts"], abs=2e-3)
+    others = [e for e in events if e.get("name") == "others_prefill"]
+    assert others and {e["ph"] for e in others} == {"i"}
+    assert {e["pid"] for e in others} == {2}
+
+
+def test_request_part_histograms_are_exported(async_run):
+    sched, done, paths = async_run
+    reg = sched.telemetry.registry
+    for part in ("queue", "ahead", "inflight", "emit"):
+        h = reg.get("serve_ttft_part_ms", labels={"part": part})
+        assert h is not None and h.count == len(done)
+    shares = [
+        reg.get("serve_token_gap_part_share", labels={"part": part})
+        for part in ("others_prefill", "decode", "host")
+    ]
+    assert all(h is not None and h.count == len(done) for h in shares)
+    # the parts of one request sum to the whole: so do the shares' sums
+    assert sum(h.sum for h in shares) == pytest.approx(len(done), abs=1e-6)
+    text = open(paths["metrics_out"]).read()
+    assert 'serve_ttft_part_ms_bucket{le="0.5",part="ahead"}' in text
+    assert 'serve_token_gap_part_share_count{part="others_prefill"}' in text
+
+
 def test_request_lifecycle_spans_in_trace(async_run):
     sched, done, paths = async_run
     doc = json.load(open(paths["trace"]))
@@ -658,8 +728,18 @@ def test_flag_wiring_to_serveconfig_and_bundle(tmp_path):
         ServeConfig(slo_window=0)
 
 
-def test_disabled_telemetry_is_fully_absent(lm):
-    sched, engine, _ = build_scheduler(lm, _serve())
+def test_disabled_telemetry_is_fully_absent(lm, monkeypatch):
+    from flexflow_tpu.serving import scheduler as scheduler_mod
+    from flexflow_tpu.telemetry import registry as registry_mod
+
+    def touched(*a, **k):
+        raise AssertionError("no Telemetry attached: nothing to observe")
+
+    # the step log is always on, but what is derived from it per request
+    # (the parts, their histograms) is computed only for a Telemetry
+    monkeypatch.setattr(scheduler_mod, "request_parts", touched)
+    monkeypatch.setattr(registry_mod.Histogram, "observe", touched)
+    sched, engine, _ = build_scheduler(lm, _serve(serve_async=True))
     assert sched.telemetry is None and sched._tele is None
     assert engine.telemetry is None
     done = sched.run(_requests(n=2, max_new=4))
@@ -667,6 +747,14 @@ def test_disabled_telemetry_is_fully_absent(lm):
     # stats still work on their private registry
     assert sched.stats.tokens_generated == sum(
         len(r.generated) for r in done
+    )
+    assert len(engine.step_log.records) == (
+        sched.stats.dispatch_count + engine.prefill_programs
+    )
+    assert [s.rid for s in engine.step_log.retired] == [r.rid for r in done]
+    assert not any(
+        isinstance(m, registry_mod.Histogram)
+        for m in sched.stats._registry.metrics()
     )
 
 
