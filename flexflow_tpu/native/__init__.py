@@ -11,6 +11,7 @@ fallback so the framework works where no C++ toolchain exists
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import hashlib
 import os
@@ -119,6 +120,10 @@ def _declare(lib: ctypes.CDLL):
     lib.ffn_loader_borrow.argtypes = [ctypes.c_void_p]
     lib.ffn_loader_release.restype = None
     lib.ffn_loader_release.argtypes = [ctypes.c_void_p, ctypes.c_int64]
+    lib.ffn_loader_gathered.restype = ctypes.c_int64
+    lib.ffn_loader_gathered.argtypes = [ctypes.c_void_p]
+    lib.ffn_loader_queue_perm.restype = None
+    lib.ffn_loader_queue_perm.argtypes = [ctypes.c_void_p, i64p]
     lib.ffn_loader_reset.restype = None
     lib.ffn_loader_reset.argtypes = [ctypes.c_void_p, i64p]
     lib.ffn_loader_destroy.restype = None
@@ -473,6 +478,10 @@ def _py_simulate(resource_of, duration, edges, num_resources):
 # -- data loader --------------------------------------------------------------
 
 
+def _i64_ptr(a: np.ndarray):
+    return a.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
+
+
 def _alloc_slot(shape, dtype) -> np.ndarray:
     """One reusable batch buffer, on a 64-byte boundary. Where the slot
     sits decides nothing about correctness (a backend that keeps host
@@ -497,7 +506,13 @@ class NativeLoader:
     Batches are gathered into a ring of `prefetch_depth` slots that this
     object owns and reuses. `next_batch()` copies a slot out and the
     caller owns the copy; `borrow()` lends the slot itself, until
-    `release()`."""
+    `release()`.
+
+    A batch's index, and with it its slot and its lease, counts on from
+    the last reset. The stream ends with the epoch unless the order of
+    another was queued (`queue_perm`): then it goes on into that epoch
+    with no reset, every lease kept, and the worker gathers its first
+    batches beside the last of this one."""
 
     def __init__(
         self,
@@ -523,7 +538,7 @@ class NativeLoader:
         self._lib = get_lib() if use_lib else None
         self._perm = self._make_perm(seed)
         self._slots = None  # [depth][array]; the fallback makes them on first use
-        self._lent = set()  # batch indices of this epoch whose slot is out
+        self._lent = set()  # indices of the batches whose slot is out
         if self._lib is not None:
             self._slots = self._make_slots()
             ptrs = (ctypes.c_void_p * len(self.arrays))(
@@ -537,10 +552,14 @@ class NativeLoader:
             )
             self._handle = self._lib.ffn_loader_create(
                 ptrs, row_bytes, len(self.arrays), n, batch_size,
-                self._perm.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+                _i64_ptr(self._perm),
                 1 if drop_last else 0, self.depth, slot_ptrs,
             )
+        # the fallback's stream, as dataloader.cc keeps it: the next batch,
+        # where `_perm`'s epoch ends, the orders of the epochs after it
         self._pos = 0
+        self._perm_end = self.num_batches
+        self._queued = collections.deque()
 
     def _make_perm(self, seed) -> np.ndarray:
         idx = np.arange(self.arrays[0].shape[0], dtype=np.int64)
@@ -566,14 +585,19 @@ class NativeLoader:
 
     def _gather(self) -> int:
         """The fallback's gather, on the caller's thread: the next batch
-        into its slot. Returns its index, -1 at epoch end, -2 when that
-        slot is still lent (as ffn_loader_borrow does)."""
+        into its slot. Returns its index, -1 at the end of the last epoch
+        an order was given for, -2 when that slot is still lent (as
+        ffn_loader_borrow does)."""
         idx = self._pos
-        if idx >= self.num_batches:
+        if idx == self._perm_end and not self._queued:
             return -1
         if idx - self.depth in self._lent:
             return -2
-        rows = self._perm[idx * self.batch_size : (idx + 1) * self.batch_size]
+        if idx == self._perm_end:  # the epoch's turn: on in the next order
+            self._perm = self._queued.popleft()
+            self._perm_end += self.num_batches
+        at = idx - (self._perm_end - self.num_batches)
+        rows = self._perm[at * self.batch_size : (at + 1) * self.batch_size]
         if len(rows) < self.batch_size:  # pad short final batch
             rows = np.concatenate(
                 [rows, np.repeat(rows[:1], self.batch_size - len(rows))]
@@ -588,8 +612,9 @@ class NativeLoader:
         return idx
 
     def borrow(self) -> Optional[Tuple[int, List[np.ndarray]]]:
-        """The next batch, lent: (its index, per-array [batch_size, ...]
-        views of a slot this loader reuses), or None at epoch end. The
+        """The next batch, lent: (its index since the last reset,
+        per-array [batch_size, ...] views of a slot this loader reuses),
+        or None at the end of the last epoch an order was given for. The
         views hold the batch until `release(index)` or a reset; at most
         `depth` batches can be out at once."""
         if self._handle is not None:
@@ -627,21 +652,46 @@ class NativeLoader:
         self.reset_perm(self._make_perm(seed))
 
     def reset_perm(self, perm: np.ndarray):
-        """New epoch with an explicit sample order (len == num_samples).
-        Takes every lent slot back."""
+        """A new stream of one epoch with an explicit sample order (len ==
+        num_samples), from its first batch. Takes every lent slot back and
+        drops what was gathered or queued ahead."""
         self._perm = np.ascontiguousarray(perm, dtype=np.int64)
         self._pos = 0
+        self._perm_end = self.num_batches
+        self._queued.clear()
         self._lent.clear()
         if self._handle is not None:
-            self._lib.ffn_loader_reset(
-                self._handle,
-                self._perm.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            )
+            self._lib.ffn_loader_reset(self._handle, _i64_ptr(self._perm))
 
-    def __del__(self):
+    def queue_perm(self, perm: np.ndarray):
+        """One more epoch after the last one known, in the order `perm`:
+        the stream goes on into it with no reset and every lease kept. The
+        order is copied: the caller may shuffle its own in place."""
+        perm = np.array(perm, dtype=np.int64)
+        if perm.shape != self._perm.shape:
+            raise ValueError(
+                f"an order of {perm.shape} for {self._perm.shape[0]} samples"
+            )
+        if self._handle is not None:
+            self._lib.ffn_loader_queue_perm(self._handle, _i64_ptr(perm))
+        else:
+            self._queued.append(perm)
+
+    def gathered(self) -> int:
+        """Batches gathered since the last reset: those with an index
+        below it are ready in the ring, or were. The worker runs ahead of
+        `borrow` as slots allow; the fallback gathers inside it."""
+        if self._handle is not None:
+            return int(self._lib.ffn_loader_gathered(self._handle))
+        return self._pos
+
+    def close(self):
+        """Stops and joins the worker; what it gathered ahead is dropped."""
         if getattr(self, "_handle", None) is not None and self._lib is not None:
             self._lib.ffn_loader_destroy(self._handle)
             self._handle = None
+
+    __del__ = close
 
 
 def available() -> bool:

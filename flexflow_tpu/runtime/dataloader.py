@@ -12,7 +12,9 @@ C++ core is available, so the next batch is prefetched while the chip is
 still executing the current step — the role the reference's background
 CPU load tasks played. The gather writes into a ring of reused slots, and
 `fit()` transfers straight out of the slot (`borrow_batch` / `lend`):
-after the gather no batch is copied again on the host."""
+after the gather no batch is copied again on the host. When another epoch
+follows (`begin_epoch(follows=True)`) the ring goes on into it by itself:
+its first batches are gathered beside this epoch's last steps."""
 
 from __future__ import annotations
 
@@ -48,7 +50,16 @@ class SingleDataLoader:
     is what `fit()` does: it places the views on the device, tells the
     loader what it made (`lend`), and the loader refills that slot only
     after those device arrays are ready, so no batch-sized block is
-    allocated or copied on the host per step."""
+    allocated or copied on the host per step.
+
+    Two ways to start an epoch. `reset()` starts from nothing: every
+    lease ended, the ring rewound. `begin_epoch(follows)` is `fit()`'s:
+    its first call is a reset, and each says whether another epoch comes
+    right after. That epoch's order is then drawn at once (one shuffle an
+    epoch from `_rng`, in the sequence a reset an epoch draws them) and
+    queued in the ring, whose batches count on across the turn: the
+    epoch's first batch may be borrowed before its `begin_epoch`, which
+    then rewinds nothing and ends no lease."""
 
     def __init__(
         self,
@@ -68,7 +79,11 @@ class SingleDataLoader:
         self.shuffle = shuffle
         self.drop_last = drop_last
         self._rng = np.random.RandomState(seed)
+        # the last order drawn, and whether its epoch is yet to begin
+        # (drawn ahead and queued in the ring: what the next reset starts
+        # from, so that no draw is skipped)
         self._order = np.arange(self.num_samples)
+        self._drawn_ahead = False
         self._pos = 0
         self._keys = list(arrays.keys())
         self._ring = None
@@ -79,6 +94,10 @@ class SingleDataLoader:
         self.batches_borrowed = 0
         self.batches_copied = 0
         self.lease_wait_s = 0.0
+        self.batches_gathered_ahead = 0
+        # ring indices: where this epoch began, and the next batch to take
+        self._epoch_start = 0
+        self._taken = 0
         # The ring of reused slots (and, with the C++ core, the worker
         # thread that gathers ahead into it): only for full-batch epochs
         # (drop_last) so that every batch has the slot's shape, and only
@@ -103,24 +122,83 @@ class SingleDataLoader:
             return self.num_samples // self.batch_size
         return (self.num_samples + self.batch_size - 1) // self.batch_size
 
-    def reset(self):
-        self._pos = 0
+    def _draw_order(self):
+        """The order of the epoch after the last one drawn: one shuffle of
+        it, and nothing else draws from `_rng`. A copy is shuffled: the
+        ring may still be reading the last one."""
         if self.shuffle:
+            self._order = self._order.copy()
             self._rng.shuffle(self._order)
+
+    def _end_leases(self):
+        self._pending = None
+        while self._out:
+            self._return_oldest()
+
+    def reset(self):
+        """An epoch from its first batch, with nothing kept: every lease
+        ended, whatever the ring gathered ahead dropped."""
+        self._pos = 0
+        if not self._drawn_ahead:
+            self._draw_order()
+        self._drawn_ahead = False
         if self._ring is not None:
             # the ring rewinds under every lease: end them first
-            self._pending = None
-            while self._out:
-                self._return_oldest()
+            self._end_leases()
             self._ring.reset_perm(self._order)
+            self._epoch_start = self._taken = 0
+
+    def begin_epoch(self, follows: bool = False):
+        """`fit()`'s turn of an epoch. `follows` promises that another
+        epoch comes right after this one, begun here too: a ring learns
+        its order now and goes on into it, and that epoch's `begin_epoch`
+        finds it begun, this epoch's batches all taken and perhaps the
+        first of the next. Anything else is a reset. Batches gathered
+        ahead and never asked for go with `close()` or the next reset.
+        Returns whether the ring goes on: whether the next epoch's first
+        batch may be borrowed before its `begin_epoch`."""
+        rolled = (
+            self._drawn_ahead
+            and self._taken >= self._epoch_start + self.num_batches
+        )
+        if rolled:
+            self._drawn_ahead = False
+            self._epoch_start += self.num_batches
+            self.batches_gathered_ahead += (
+                self._ring.gathered() - self._epoch_start
+            )
+        else:
+            self.reset()
+        if follows and self._ring is not None:
+            self._draw_order()
+            self._drawn_ahead = True
+            self._ring.queue_perm(self._order)
+        return self._drawn_ahead
+
+    def close(self):
+        """Ends every lease, after the wait for each, and joins the
+        ring's worker; what it gathered ahead is dropped."""
+        if self._ring is not None:
+            self._end_leases()
+            self._ring.close()
+
+    def _take(self):
+        """The ring's next batch, lent: (index, views)."""
+        got = self._ring.borrow()
+        if got is None:  # epoch rollover
+            # an order drawn ahead was the stream's last epoch: it has run
+            self._drawn_ahead = False
+            self.reset()
+            got = self._ring.borrow()
+        self._taken = got[0] + 1
+        return got
 
     def next_batch(self) -> Dict[str, np.ndarray]:
         if self._ring is not None:
-            bufs = self._ring.next_batch()
-            if bufs is None:  # epoch rollover
-                self.reset()
-                bufs = self._ring.next_batch()
-            return dict(zip(self._keys, bufs))
+            index, views = self._take()
+            out = {k: v.copy() for k, v in zip(self._keys, views)}
+            self._ring.release(index)
+            return out
         remaining = self.num_samples - self._pos
         if remaining < self.batch_size and (self.drop_last or remaining == 0):
             self.reset()
@@ -159,12 +237,8 @@ class SingleDataLoader:
             self._pending = None
         while len(self._out) > max(0, self._ring.depth - 2):
             self._return_oldest(waiting)
-        got = self._ring.borrow()
-        if got is None:  # epoch rollover
-            self.reset()
-            got = self._ring.borrow()
-        self._pending = got
-        return dict(zip(self._keys, got[1]))
+        self._pending = self._take()
+        return dict(zip(self._keys, self._pending[1]))
 
     def lend(self, placed: Dict[str, object]) -> Dict[str, object]:
         """`placed` is what the caller made on the device from the last
@@ -192,10 +266,15 @@ class SingleDataLoader:
         return placed
 
     def take_counts(self):
-        """(batches borrowed, batches copied, seconds waited for leases)
-        since the last call."""
-        got = (self.batches_borrowed, self.batches_copied, self.lease_wait_s)
+        """(batches borrowed, batches copied, seconds waited for leases,
+        batches the ring had gathered before their epoch began) since the
+        last call."""
+        got = (
+            self.batches_borrowed, self.batches_copied, self.lease_wait_s,
+            self.batches_gathered_ahead,
+        )
         self.batches_borrowed = self.batches_copied = 0
+        self.batches_gathered_ahead = 0
         self.lease_wait_s = 0.0
         return got
 

@@ -1256,9 +1256,22 @@ class FFModel:
         def lease_wait():
             return span("train.input.next_batch.lease_wait", tracer)
 
+        def place_next():
+            """The loader's next batch on the device, and its rows. The
+            batch is views of the loader's slot, lent until the arrays
+            placed from it are ready (dataloader.py)."""
+            with span("train.input.next_batch", tracer):
+                np_batch = loader.borrow_batch(lease_wait)
+            with span("train.input.shard_batch", tracer):
+                batch = loader.lend(
+                    self.executor.shard_batch(np_batch, tracer)
+                )
+            return batch, len(next(iter(np_batch.values())))
+
         history = []
         warm = False
         early_stop = False
+        placed = None  # an epoch's first batch, placed before its turn
         for epoch in range(epochs):
             for cb in callbacks:
                 cb.on_epoch_begin(epoch)
@@ -1268,7 +1281,10 @@ class FFModel:
                 step = self.executor.train_step()
             perf = PerfMetrics()
             with span("train.epoch_end.reset", tracer):
-                loader.reset()
+                # a reset for the first epoch. After it the loader's ring
+                # has gone on into the epoch by itself, beside the last
+                # one's final steps: nothing rewinds, no lease ends
+                rolls_on = loader.begin_epoch(follows=epoch + 1 < epochs)
             t0 = time.perf_counter()
             epoch_t0 = t0
             samples = 0
@@ -1279,14 +1295,8 @@ class FFModel:
             for it in range(loader.num_batches):
                 for cb in callbacks:
                     cb.on_batch_begin(it)
-                # the batch is views of the loader's slot, lent until the
-                # arrays placed from it are ready (dataloader.py)
-                with span("train.input.next_batch", tracer):
-                    np_batch = loader.borrow_batch(lease_wait)
-                with span("train.input.shard_batch", tracer):
-                    batch = loader.lend(
-                        self.executor.shard_batch(np_batch, tracer)
-                    )
+                batch, rows = placed or place_next()
+                placed = None
                 with span("train.input.dispatch", tracer):
                     self._rng, key = jax.random.split(self._rng)
                     self.params, self.opt_state, loss, mets = step(
@@ -1296,9 +1306,7 @@ class FFModel:
                     # dispatch-to-dispatch host stamps; rows/spans are
                     # built at epoch end, off the hot loop
                     stamps.append(time.perf_counter())
-                    sample_counts.append(
-                        len(next(iter(np_batch.values())))
-                    )
+                    sample_counts.append(rows)
                 if self._cache_specs:
                     # surface cache-op inputs to the host memoizer
                     # (syncs; only models that built cache() ops pay it)
@@ -1317,7 +1325,12 @@ class FFModel:
                     t0 = time.perf_counter()
                     warm = True
                 else:
-                    samples += len(next(iter(np_batch.values())))
+                    samples += rows
+                # a step's results are final when its program ends: their
+                # copies to the host start now, so that the epoch's end
+                # reads host values and not a drained device, one by one
+                for leaf in jax.tree_util.tree_leaves((loss, mets)):
+                    leaf.copy_to_host_async()
                 step_results.append((loss, mets))
                 for cb in callbacks:
                     cb.on_batch_end(it)
@@ -1330,6 +1343,11 @@ class FFModel:
                         f"iter {it + 1}/{loader.num_batches}: "
                         f"loss = {float(loss):.4f}"
                     )
+            if rolls_on:
+                # the next epoch's first batch goes to the device beside
+                # this epoch's last steps (a transfer, not a step); should
+                # a callback stop the run it is dropped, never stepped on
+                placed = place_next()
             with span("train.epoch_end.drain", tracer):
                 jax.block_until_ready(self.params)
             elapsed = time.perf_counter() - t0
@@ -1364,6 +1382,9 @@ class FFModel:
                     early_stop = True
             if early_stop:
                 break
+        # every lease ended, a batch placed ahead among them, and the
+        # loader's worker joined with whatever it gathered ahead
+        loader.close()
         for cb in callbacks:
             cb.on_train_end()
         if tele is not None:
@@ -1426,9 +1447,15 @@ class FFModel:
             help="mean wait per batch for a lent slot's transfer, "
             "last epoch",
         )
-        borrowed, copied, lease_wait_s = loader.take_counts()
+        c_ahead = reg.counter(
+            "train_input_batches_gathered_ahead",
+            help="batches ready in the loader's ring before their epoch "
+            "began (the ring went on across the epoch's turn by itself)",
+        )
+        borrowed, copied, lease_wait_s, ahead = loader.take_counts()
         c_borrowed.inc(borrowed)
         c_copied.inc(copied)
+        c_ahead.inc(ahead)
         g_lease.set(1e3 * lease_wait_s / max(len(stamps), 1))
         tracer = tele.tracer
         g_epoch.set(epoch)

@@ -16,10 +16,19 @@
 // ffn_loader_release (or a reset). The caller may hold several slots at
 // once, for as long as a device transfer reads them; the worker gathers
 // ahead into whatever slots are free.
+//
+// Epochs: a batch's index, and with it its slot and its lease, counts on
+// from the last reset and does not rewind at an epoch's turn. The caller
+// may hand over the order of the epoch after the last one known
+// (ffn_loader_queue_perm); the worker then goes from an epoch's last batch
+// straight on to the next epoch's first, as slots are released, and the
+// caller borrows across the turn as inside an epoch. With no order queued
+// the stream ends with the epoch, until a reset.
 
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
+#include <deque>
 #include <mutex>
 #include <numeric>
 #include <thread>
@@ -29,7 +38,7 @@ namespace {
 
 struct Slot {
   std::vector<uint8_t*> buffers;  // one per array, the caller's memory
-  int64_t index = -1;             // batch index within the epoch
+  int64_t index = -1;             // batch index since the last reset
   enum { FREE, READY, LENT } state = FREE;
 };
 
@@ -40,17 +49,22 @@ struct Loader {
   int64_t batch_size = 0;
   bool drop_last = true;
 
-  // Sample order for the epoch. Always supplied by the caller (the Python
-  // wrapper shuffles with numpy's seeded RNG) so that the batch stream is
-  // bit-identical with and without the native library.
+  // Sample order of the epoch the worker is gathering, and of the epochs
+  // after it. Always supplied by the caller (the Python wrapper shuffles
+  // with numpy's seeded RNG) so that the batch stream is bit-identical
+  // with and without the native library. The worker reads `perm` outside
+  // the lock, so only the worker (or a reset, which waits for it) writes it.
   std::vector<int64_t> perm;
-  int64_t num_batches = 0;
+  std::deque<std::vector<int64_t>> queued;
+  int64_t num_batches = 0;  // per epoch
 
   // Batch i lives in slot i % slots.size(): FREE until the worker has
   // gathered it, READY until the caller borrows it, LENT until released.
   std::vector<Slot> slots;
-  int64_t produced = 0;  // next batch index the worker will fill
-  int64_t taken = 0;     // next batch index the caller will borrow
+  int64_t produced = 0;    // next batch index the worker will fill
+  int64_t taken = 0;       // next batch index the caller will borrow
+  int64_t perm_end = 0;    // where `perm`'s epoch ends
+  int64_t stream_end = 0;  // where the last queued epoch ends
   bool filling = false;  // worker is copying outside the lock
   bool stop = false;
   std::thread worker;
@@ -61,16 +75,26 @@ struct Loader {
     return slots[(size_t)(batch_idx % (int64_t)slots.size())];
   }
 
-  void set_perm(const int64_t* p) {
-    perm.resize(num_samples);
+  std::vector<int64_t> order(const int64_t* p) const {
+    std::vector<int64_t> out((size_t)num_samples);
     if (p)
-      std::memcpy(perm.data(), p, sizeof(int64_t) * num_samples);
+      std::memcpy(out.data(), p, sizeof(int64_t) * num_samples);
     else
-      std::iota(perm.begin(), perm.end(), 0);
+      std::iota(out.begin(), out.end(), 0);
+    return out;
   }
 
-  void fill(Slot* s, int64_t batch_idx) {
-    int64_t begin = batch_idx * batch_size;
+  // A stream of one epoch in order `p`, from its first batch.
+  void start(const int64_t* p) {
+    perm = order(p);
+    queued.clear();
+    produced = taken = 0;
+    perm_end = stream_end = num_batches;
+  }
+
+  // Batch `in_epoch` of `perm`'s epoch into slot `s`.
+  void fill(Slot* s, int64_t in_epoch) {
+    int64_t begin = in_epoch * batch_size;
     int64_t rows = std::min(batch_size, num_samples - begin);
     for (size_t a = 0; a < arrays.size(); ++a) {
       int64_t rb = row_bytes[a];
@@ -88,15 +112,21 @@ struct Loader {
     for (;;) {
       std::unique_lock<std::mutex> lk(mu);
       cv_produce.wait(lk, [&] {
-        return stop || (produced < num_batches &&
+        return stop || (produced < stream_end &&
                         slot_of(produced).state == Slot::FREE);
       });
       if (stop) return;
+      if (produced == perm_end) {  // the epoch's turn: on in the next order
+        perm = std::move(queued.front());
+        queued.pop_front();
+        perm_end += num_batches;
+      }
       int64_t idx = produced;
       Slot* slot = &slot_of(idx);
+      int64_t in_epoch = idx - (perm_end - num_batches);
       filling = true;
       lk.unlock();
-      fill(slot, idx);
+      fill(slot, in_epoch);
       lk.lock();
       filling = false;
       // A reset waits for `filling` to clear, so it runs after this
@@ -133,7 +163,7 @@ void* ffn_loader_create(const void** arrays, const int64_t* row_bytes,
   L->drop_last = drop_last != 0;
   L->num_batches = drop_last ? num_samples / batch_size
                              : (num_samples + batch_size - 1) / batch_size;
-  L->set_perm(perm);
+  L->start(perm);
   L->slots.resize((size_t)depth);
   for (int32_t s = 0; s < depth; ++s)
     for (int32_t i = 0; i < num_arrays; ++i)
@@ -148,13 +178,14 @@ int64_t ffn_loader_num_batches(void* loader) {
 }
 
 // Blocks until the next batch is gathered and lends its slot to the
-// caller. Returns the batch index (its slot is index % depth), -1 at
-// epoch end, or -2 when the slot this batch needs is still lent: the
-// caller would be waiting for itself.
+// caller. Returns the batch index, counted from the last reset (its slot
+// is index % depth, its place in its epoch index % num_batches), -1 at the
+// end of the last epoch an order was given for, or -2 when the slot this
+// batch needs is still lent: the caller would be waiting for itself.
 int64_t ffn_loader_borrow(void* loader) {
   Loader* L = (Loader*)loader;
   std::unique_lock<std::mutex> lk(L->mu);
-  if (L->taken >= L->num_batches) return -1;
+  if (L->taken >= L->stream_end) return -1;
   Slot& s = L->slot_of(L->taken);
   if (s.state == Slot::LENT) return -2;
   L->cv_consume.wait(lk, [&] { return s.state == Slot::READY; });
@@ -174,18 +205,33 @@ void ffn_loader_release(void* loader, int64_t batch_idx) {
   }
 }
 
-// New epoch: install the caller's new sample order and restart
-// prefetching from batch 0. Every lent slot is taken back: the caller
-// has to be done with all of them.
+// Batches gathered since the last reset: those below it are READY or were.
+int64_t ffn_loader_gathered(void* loader) {
+  Loader* L = (Loader*)loader;
+  std::unique_lock<std::mutex> lk(L->mu);
+  return L->produced;
+}
+
+// One more epoch after the last one known, in the order `perm` (copied):
+// the stream goes on into it with no reset, and every lease stays.
+void ffn_loader_queue_perm(void* loader, const int64_t* perm) {
+  Loader* L = (Loader*)loader;
+  std::unique_lock<std::mutex> lk(L->mu);
+  L->queued.push_back(L->order(perm));
+  L->stream_end += L->num_batches;
+  L->cv_produce.notify_all();
+}
+
+// A new stream: install the caller's sample order and restart prefetching
+// from batch 0, dropping whatever was gathered or queued ahead. Every lent
+// slot is taken back: the caller has to be done with all of them.
 void ffn_loader_reset(void* loader, const int64_t* perm) {
   Loader* L = (Loader*)loader;
   std::unique_lock<std::mutex> lk(L->mu);
   // Wait until the worker is parked on the condition variable (not copying
   // outside the lock) before touching the permutation or counters.
   L->cv_consume.wait(lk, [&] { return !L->filling; });
-  L->set_perm(perm);
-  L->produced = 0;
-  L->taken = 0;
+  L->start(perm);
   for (auto& s : L->slots) {
     s.state = Slot::FREE;
     s.index = -1;

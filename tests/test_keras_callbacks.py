@@ -152,3 +152,71 @@ def test_evaluate_callbacks():
     perf = model.evaluate(x, y, callbacks=[rec])
     assert rec.events[0] == "train_begin" and rec.events[-1] == "train_end"
     assert perf.get_accuracy() >= 0.0
+
+
+# -- the epoch's turn (ISSUE 56) ---------------------------------------------------
+#
+# `fit()` places the next epoch's first batch on the device beside this
+# epoch's last steps. That is a transfer: what a callback may count on at
+# an epoch's end and beginning is as it was.
+
+
+@pytest.mark.parametrize("stop_at", [None, 1], ids=["to_the_end", "stopped"])
+def test_no_step_of_an_epoch_runs_before_its_hooks_have_returned(stop_at):
+    """Every hook and every dispatched step in one log: an epoch's steps
+    lie between its `on_epoch_begin` and its `on_epoch_end`, whose `True`
+    stops the run with no step more; a `LearningRateScheduler` that
+    rebinds the step at `on_epoch_begin` is the step the epoch runs."""
+    x, y = _mnist_like()
+    ff = _model().ffmodel
+    rec = _Recorder()
+    real_train_step = ff.executor.train_step
+
+    def train_step():
+        step = real_train_step()
+
+        def logged(*args):
+            rec.events.append("step")
+            return step(*args)
+
+        return logged
+
+    ff.executor.train_step = train_step
+
+    class Stop(Callback):
+        def on_epoch_end(self, epoch, logs=None):
+            return epoch == stop_at
+
+    ff.fit(x, y, epochs=3, callbacks=[rec, Stop()], verbose=False)
+    want = ["train_begin"]
+    for e in range(3 if stop_at is None else stop_at + 1):
+        want.append(("epoch_begin", e))
+        for b in range(4):
+            want += [("batch_begin", b), "step", ("batch_end", b)]
+        want.append(("epoch_end", e))
+    assert rec.events == want + ["train_end"]
+
+
+def test_a_scheduled_rate_takes_effect_at_the_turn_it_was_set_for():
+    """Two runs of three epochs, one with the rate of the last epoch set
+    by a `LearningRateScheduler` at its `on_epoch_begin`, one from two
+    `fit()`s at the two rates: the same parameters, whatever was placed
+    on the device before the turn."""
+    import jax
+
+    def leaves(ff):
+        return [
+            np.asarray(w).tobytes() for w in jax.tree_util.tree_leaves(ff.params)
+        ]
+
+    x, y = _mnist_like()
+    scheduled, by_hand = _model(), _model()
+    assert leaves(scheduled.ffmodel) == leaves(by_hand.ffmodel)
+    scheduled.fit(
+        x, y, epochs=3, verbose=False,
+        callbacks=[LearningRateScheduler(lambda e: 0.1 if e < 2 else 0.01)],
+    )
+    by_hand.fit(x, y, epochs=2, verbose=False)
+    by_hand.ffmodel.set_learning_rate(0.01)
+    by_hand.fit(x, y, epochs=1, verbose=False)
+    assert leaves(scheduled.ffmodel) == leaves(by_hand.ffmodel)

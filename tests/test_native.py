@@ -165,6 +165,123 @@ def test_loader_pads_short_final_batch(impl):
     assert b1[0] == 4 and len(b1) == 4
 
 
+@pytest.mark.parametrize("use_lib", [True, False], ids=["core", "python"])
+def test_loader_goes_on_into_a_queued_epoch(use_lib):
+    """With the next epoch's order queued the stream crosses the epoch's
+    end with no reset: batches counted on from the last reset, slots lent
+    across the turn kept, and the end where the orders end."""
+    x = np.arange(24, dtype=np.int64).reshape(12, 2)
+    perms = [np.random.RandomState(s).permutation(12) for s in range(3)]
+    dl = native.NativeLoader([x], batch_size=4, shuffle=False, use_lib=use_lib)
+    assert (dl._handle is not None) == (use_lib and native.available())
+    dl.reset_perm(perms[0])
+    queued = perms[1].copy()
+    dl.queue_perm(queued)
+    queued[:] = 0  # the caller's to write: the loader took a copy
+    held = {}
+    for g in range(9):
+        if g == 3:  # an order queued late, beside the epoch it follows
+            dl.queue_perm(perms[2])
+        index, views = dl.borrow()
+        assert index == g and dl.gathered() > g
+        rows = perms[g // 3][(g % 3) * 4 : (g % 3 + 1) * 4]
+        np.testing.assert_array_equal(views[0], x[rows])
+        held[g] = rows
+        if g >= 2:  # two leases stay out, across both turns
+            np.testing.assert_array_equal(
+                dl._slots[(g - 2) % dl.depth][0], x[held[g - 2]]
+            )
+            dl.release(g - 2)
+    assert dl.borrow() is None and dl.gathered() == 9
+    dl.reset_perm(perms[1])  # a reset drops what was queued
+    assert [dl.next_batch() is None for _ in range(4)] == [False] * 3 + [True]
+    with pytest.raises(ValueError, match="samples"):
+        dl.queue_perm(perms[0][:5])
+    dl.close()
+    assert dl._handle is None
+    dl.close()
+
+
+@pytest.mark.parametrize("shuffle", [False, True], ids=["in_order", "shuffled"])
+def test_loader_rolls_over_many_epochs_under_a_hurried_caller(impl, shuffle):
+    """The worker and the caller share the ring across every turn: 300
+    epochs of three batches in a ring of three, each order queued one
+    epoch ahead, each slot given back at once. A batch gathered by the
+    wrong order, into a lent slot or twice would show other rows."""
+    import time
+
+    x = np.arange(36, dtype=np.int64).reshape(12, 3)
+    rng = np.random.RandomState(3)
+    dl = native.NativeLoader([x], batch_size=4, shuffle=False)
+    perms = [
+        rng.permutation(12) if shuffle else np.arange(12) for _ in range(300)
+    ]
+    dl.reset_perm(perms[0])
+    deadline = time.monotonic() + 60.0
+    for e, perm in enumerate(perms):
+        if e + 1 < len(perms):
+            dl.queue_perm(perms[e + 1])
+        for b in range(3):
+            index, views = dl.borrow()
+            assert index == 3 * e + b
+            np.testing.assert_array_equal(views[0], x[perm[4 * b : 4 * b + 4]])
+            dl.release(index)
+        assert time.monotonic() < deadline
+    assert dl.borrow() is None and dl.gathered() == 900
+
+
+@pytest.mark.parametrize("use_native", [True, False], ids=["core", "python"])
+@pytest.mark.parametrize("shuffle", [False, True], ids=["in_order", "shuffled"])
+def test_single_dataloader_rolled_epochs_are_the_reset_epochs(use_native, shuffle):
+    """Three epochs started as `fit()` starts them (`begin_epoch`: the
+    ring goes on, the next order drawn an epoch early) are, batch for
+    batch, three epochs started by a reset each: the same draws from the
+    same RNG in the same sequence. A reset after an early stop starts
+    from the order already drawn, as the epoch that never ran would; a
+    caller that runs off the stream's end (`borrow_batch()`'s rollover)
+    draws the order after it."""
+    from flexflow_tpu.runtime.dataloader import SingleDataLoader
+
+    data = {
+        "x": np.arange(48, dtype=np.float32).reshape(24, 2),
+        "y": np.arange(24, dtype=np.int32),
+    }
+
+    def stream(how, epochs=3):
+        dl = SingleDataLoader(
+            dict(data), batch_size=4, shuffle=shuffle, seed=11,
+            use_native=use_native,
+        )
+        out = []
+        for e in range(epochs):
+            if how == "reset":
+                dl.reset()
+            else:
+                dl.begin_epoch(follows=how != "rolled" or e + 1 < epochs)
+            for _ in range(dl.num_batches):
+                got = dl.borrow_batch()
+                out.append({k: v.copy() for k, v in got.items()})
+                dl.lend({})
+        if how == "then_reset":  # the order drawn ahead is not skipped
+            out += [{k: v.copy() for k, v in b.items()} for b in dl]
+        if how == "then_runs_on":  # nor is it used twice
+            for _ in range(2 * dl.num_batches):
+                out.append({k: v.copy() for k, v in dl.borrow_batch().items()})
+                dl.lend({})
+        return out
+
+    want = stream("reset")
+    for got in (
+        stream("rolled"),
+        stream("then_reset", epochs=2),
+        stream("then_runs_on", epochs=1),
+    ):
+        assert len(got) == len(want) == 18
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(a["x"], b["x"])
+            np.testing.assert_array_equal(a["y"], b["y"])
+
+
 def test_single_dataloader_native_matches_fallback(monkeypatch):
     """Same seed → bit-identical batch stream with and without the native
     prefetch path (the permutation is always drawn from numpy's RNG)."""

@@ -17,6 +17,7 @@ the server's step is marked. Held here:
   `readback_bytes`, `prefill_tokens_real/padded`) equal hand counts.
 """
 
+import collections
 import glob
 import os
 
@@ -203,9 +204,10 @@ def test_fit_under_a_profiler_session_yields_exactly_the_named_spans(tmp_path):
     count = {n: sum(1 for e in ours if e[0] == n) for n in TRAIN_SPANS}
     # 2 epochs of 3 batches: one of each per step, one of each per epoch
     assert count["train.input.next_batch"] == 6
-    # the third batch of an epoch takes back the first one's slot; the
-    # other two leases end at the epoch's turn, under the reset
-    assert count["train.input.next_batch.lease_wait"] == 2
+    # from the third batch on each takes back the slot of the batch two
+    # before it, across the epoch's turn too: the ring goes on by itself
+    # there and no lease ends under the reset (ISSUE 56)
+    assert count["train.input.next_batch.lease_wait"] == 4
     assert count["train.input.shard_batch.label"] == 6
     assert count["train.input.dispatch"] == 6
     assert count["train.epoch_end.drain"] == 2
@@ -220,6 +222,28 @@ def test_fit_under_a_profiler_session_yields_exactly_the_named_spans(tmp_path):
         )
     ]
     assert firsts == sorted(firsts)
+
+
+@pytest.mark.parametrize("epochs", [1, 3])
+def test_the_spans_the_benchmark_reads_open_once_a_step_or_once_an_epoch(
+    tmp_path, epochs
+):
+    """`input_next_batch_idle_ms`, `input_shard_batch_idle_ms`,
+    `input_dispatch_idle_ms` and `epoch_turn_idle_ms` read these six by
+    name (benchmarks/metrics/): whether the loader was reset at an
+    epoch's turn or had gone on by itself, each opens as often."""
+    _, events = _profiled(tmp_path, lambda: _fit(epochs=epochs))
+    count = collections.Counter(e[0] for e in _ours(events, ("train.",)))
+    for name in (
+        "train.input.next_batch", "train.input.shard_batch",
+        "train.input.dispatch",
+    ):
+        assert count[name] == 3 * epochs, name
+    for name in (
+        "train.epoch_end.drain", "train.epoch_end.losses",
+        "train.epoch_end.reset",
+    ):
+        assert count[name] == epochs, name
 
 
 @pytest.mark.parametrize(
