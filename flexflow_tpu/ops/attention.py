@@ -159,7 +159,9 @@ def is_positional(params) -> bool:
     return params.get("rope_theta") is not None or bool(params.get("qk_norm"))
 
 
-def mha_project_qkv(ins, ws, ctx, use_bias=True, params=None, positions=None):
+def mha_project_qkv(
+    ins, ws, ctx, use_bias=True, params=None, positions=None, rows=False
+):
     """Input projections of the MHA lowering: (xq, xk, xv) [b, s, e] ->
     (q, k, v) [b, s, h, d]. Split out of _lower_mha so the serving engine
     (flexflow_tpu.serving.engine) computes the exact same projections when
@@ -167,7 +169,20 @@ def mha_project_qkv(ins, ws, ctx, use_bias=True, params=None, positions=None):
     numerics must match training bit-for-bit or cache-equivalence breaks.
     With the node's `params`, QK-norm and rotary positions follow the
     projection (mha_qk_positions): the keys a serving step writes to its
-    cache are already normalised and rotated."""
+    cache are already normalised and rotated.
+
+    `rows`: the projections as ROWS, (q, k, v) [b, s, h * d], for a core
+    that reads them as they lie (`_tiled_rows`): the same contraction
+    against the weight as an [e, h * d] matrix, the bias added along the
+    row. The numbers are the same; what differs is the layout XLA's TPU
+    layout assignment gives the result. The `ehd` product, and any
+    [b, s, h, d] bfloat16 value that stands alone (the bias add outside
+    a `shard_map`), is written sequence-minor ({1,3,2,0}), and a Mosaic
+    call, which takes its operands row-major, then costs a 67 MB
+    relayout copy an operand at the training cells' shape (eight a
+    layer, sixteen on the mesh; AOT compile for a described v5e, PR 59).
+    QK-norm and rotary positions work in head space and are applied on a
+    [b, s, h, d] view (no cell trains such a model: not measured)."""
     xq, xk, xv = ins
     wq, wk, wv = ws[0], ws[1], ws[2]
     xq, xk, xv, wq, wk, wv = mm_operands(ctx, xq, xk, xv, wq, wk, wv)
@@ -175,26 +190,48 @@ def mha_project_qkv(ins, ws, ctx, use_bias=True, params=None, positions=None):
     # stays f32 inside the attention core), else the input dtype
     cdt = xq.dtype
     mm = dict(preferred_element_type=jnp.float32)
-    q = jnp.einsum("bse,ehd->bshd", xq, wq, **mm).astype(cdt)
-    k = jnp.einsum("bse,ehd->bshd", xk, wk, **mm).astype(cdt)
-    v = jnp.einsum("bse,ehd->bshd", xv, wv, **mm).astype(cdt)
+    if rows:
+        q, k, v = (
+            jnp.einsum(
+                "bse,ef->bsf", x, w.reshape(w.shape[0], -1), **mm
+            ).astype(cdt)
+            for x, w in ((xq, wq), (xk, wk), (xv, wv))
+        )
+    else:
+        q = jnp.einsum("bse,ehd->bshd", xq, wq, **mm).astype(cdt)
+        k = jnp.einsum("bse,ehd->bshd", xk, wk, **mm).astype(cdt)
+        v = jnp.einsum("bse,ehd->bshd", xv, wv, **mm).astype(cdt)
     if use_bias:
-        bq, bk, bv = ws[4], ws[5], ws[6]
+        bq, bk, bv = (b.reshape(-1) if rows else b for b in ws[4:7])
         q = q + bq.astype(cdt)
         k = k + bk.astype(cdt)
         v = v + bv.astype(cdt)
     if params is not None and is_positional(params):
+        if rows:
+            heads = wq.shape[1:]
+            q, k = (a.reshape(*a.shape[:2], *heads) for a in (q, k))
         q, k = mha_qk_positions(q, k, ws, params, positions)
+        if rows:
+            q, k = (a.reshape(*a.shape[:2], -1) for a in (q, k))
     return q, k, v
 
 
-def mha_project_out(attn, ws, ctx, out_dtype, use_bias=True):
+def mha_project_out(attn, ws, ctx, out_dtype, use_bias=True, rows=False):
     """Output projection of the MHA lowering: attn [b, s, h, d] -> [b, s, e].
-    Shared with the serving engine like mha_project_qkv."""
+    Shared with the serving engine like mha_project_qkv; `rows`: attn
+    comes as rows [b, s, h * d], contracted against the weight as a
+    matrix."""
     attn_m, wo_m = mm_operands(ctx, attn, ws[3])
-    y = jnp.einsum(
-        "bshd,hde->bse", attn_m, wo_m, preferred_element_type=jnp.float32
-    ).astype(mm_out_dtype(ctx, out_dtype))
+    if rows:
+        y = jnp.einsum(
+            "bsf,fe->bse", attn_m, wo_m.reshape(-1, wo_m.shape[-1]),
+            preferred_element_type=jnp.float32,
+        )
+    else:
+        y = jnp.einsum(
+            "bshd,hde->bse", attn_m, wo_m, preferred_element_type=jnp.float32
+        )
+    y = y.astype(mm_out_dtype(ctx, out_dtype))
     if use_bias:
         y = y + ws[7].astype(y.dtype)
     return y
@@ -939,6 +976,38 @@ def _tiled(q, k, v, ctx, causal):
     return _tiled_flash_sharded(q, k, v, ctx, causal, _batch_head_specs(ctx))
 
 
+def _whole_form_takes(ctx, shape) -> bool:
+    """Whether the hand-tiled kernel runs a device's block of the GLOBAL
+    `shape` (batch, sq, sk, heads, head_dim) in its whole-sequence form
+    (flash_kernel.supports_whole, at the operands' width under `ctx`)."""
+    from flexflow_tpu.ops.pallas.flash_kernel import supports_whole
+
+    _, sq, sk, heads, head_dim = shape
+    _, s_deg, h_deg = _q_degrees(ctx)
+    itemsize = 2 if getattr(ctx, "bf16_matmul", False) else 4
+    return supports_whole(
+        max(1, sq // s_deg), sk, max(1, heads // h_deg), head_dim, itemsize
+    )
+
+
+def _tiled_rows(q, k, v, heads, ctx, causal):
+    """The hand-tiled kernel's whole-sequence form on the projections'
+    rows [b, s, heads * d], for a node `_whole_form_takes`: direct call
+    on a single device, per device over the batch/head axes on a mesh
+    (a device's share of the row is its own heads, side by side)."""
+    from flexflow_tpu.ops.pallas.flash_kernel import flash_attention_rows
+
+    if _single_device(ctx):
+        return flash_attention_rows(q, k, v, heads, causal=causal)
+    b_ax, _, h_ax, _ = _batch_head_specs(ctx)
+    local = heads // (ctx.mesh.shape[h_ax] if h_ax is not None else 1)
+    # check_vma off: as in _tiled_flash_sharded
+    return _per_device(
+        lambda a, b, c: flash_attention_rows(a, b, c, local, causal=causal),
+        ctx, (b_ax, None, h_ax), check_vma=False,
+    )(q, k, v)
+
+
 def _drops(params, ctx) -> bool:
     """Whether this lowering applies attention-prob dropout."""
     return bool(
@@ -968,11 +1037,15 @@ def mha_core_plan(params, ctx, shape=None) -> CorePlan:
 
     Sequence sharded and k/v sharded alike: `ring` or `ulysses`. Else by
     the PER-DEVICE float32 score block [b, h, sq, sk]: `flash` (the
-    blockwise or library kernel) from _FLASH_SCORE_BYTES up, `tiled`
-    (the hand-tiled kernel) there and where one sequence's block already
-    overflows the chunk cap, if the kernel takes the shape; `chunked`
-    (the rematerialised scan over chunks of the local batch) past the
-    mono cap; `one_shot` below it. The scan wants its leading axis whole
+    blockwise or library kernel) from _FLASH_SCORE_BYTES up; `tiled`
+    (the hand-tiled kernel, on a TPU, if it takes the shape) there,
+    where one sequence's block already overflows the chunk cap, and at
+    every self-attention short enough for its whole-sequence form
+    (`_whole_form_takes`: 512 at the flagship's heads, whatever the
+    batch); `chunked` (the rematerialised scan over chunks of the local
+    batch) past the mono cap; `one_shot` below it. Off a TPU the last two
+    are all there is below the flash threshold. The scan wants its
+    leading axis whole
     on a device: the global batch when nothing shards it, and each
     device's LOCAL batch (`per_device`) when the mesh shards the batch
     and not the sequence. Attention-prob dropout keeps the one-shot
@@ -1053,17 +1126,31 @@ def mha_core_plan(params, ctx, shape=None) -> CorePlan:
         "chunked" if chunk < local_b else "one_shot", chunk, local_b,
         per_device,
     )
-    # when even ONE sample's score block overflows the chunk
-    # cap (seq ~2048-8192, small batch), the chunked scan
-    # degenerates to a stores-nothing single-sample remat —
-    # measured 10-60% SLOWER than one-shot dense in isolation.
-    # That band belongs to the hand-tiled kernel: 12.4 ms vs
-    # 21.8 dense / ~52 blockwise at seq 2048 bs8h16 on v5e
-    # (scripts/bench_flash_kernel.py). Below it, chunked dense
-    # keeps the full-step crown (19.0 vs 23.6 ms flagship
-    # A/B, scripts/ab_attn_tiled.py — the tiled kernel's
-    # per-call layout transposes eat its margin at seq 512).
-    if h_loc * sq_loc * sk * 4 > _DENSE_CHUNK_SCORE_BYTES:
+    # The hand-tiled kernel, where it takes the shape (`tiled_or`: a TPU,
+    # the sequence whole on a device), has two bands.
+    # (1) A sequence short enough for its WHOLE-SEQUENCE form
+    # (flash_kernel.supports_whole: one head's float32 score block and
+    # its backward's temporaries in VMEM; 512, and 1,024 in bf16): one
+    # Mosaic call a pass over the projections' own [b, s, h * d] rows,
+    # where the chunked scan pays for its loops (chunk copies in and out
+    # of the stacked buffers, their zero fill) and XLA for a layout of its
+    # own. Flagship step (12 x 1024, 16 heads, seq 512, mixed precision),
+    # batch 64, "TPU v5 lite", interleaved, losses equal to the last
+    # digit: 114.11 against 130.11 ms chunked (scripts/ab_attn_tiled.py
+    # 64); the core 30.8 against 50-52 ms a step, 2.62 against 2.86 ms a
+    # layer alone (scripts/probe_attn_whole.py; PR 59). This supersedes
+    # the "19.0 vs 23.6 ms at batch 8" reading that kept seq 512 on the
+    # scan: that was the GRID form, whose [b, h, s, d] transposes and
+    # 1,024 small programs a call read 10.08 ms a layer here.
+    # (2) Past the chunk cap (seq ~2048-8192, small batch), where the
+    # scan degenerates to a stores-nothing single-sample remat, measured
+    # 10-60% SLOWER than one-shot dense in isolation: the grid form,
+    # 12.4 ms vs 21.8 dense / ~52 blockwise at seq 2048 bs8h16 on v5e
+    # (scripts/bench_flash_kernel.py).
+    if (
+        _whole_form_takes(ctx, shape)
+        or h_loc * sq_loc * sk * 4 > _DENSE_CHUNK_SCORE_BYTES
+    ):
         return tiled_or(dense)
     return dense
 
@@ -1140,12 +1227,17 @@ def _lower_mha(params):
 
     def fn(ins, ws, ctx):
         dt = ins[0].dtype
-        q, k, v = mha_project_qkv(
-            ins, ws, ctx, use_bias=use_bias, params=params
+        shape = (
+            ins[0].shape[0], ins[0].shape[1], ins[1].shape[1],
+            *ws[0].shape[1:],
         )
-        plan = mha_core_plan(
-            params, ctx,
-            (q.shape[0], q.shape[1], k.shape[1], q.shape[2], q.shape[3]),
+        plan = mha_core_plan(params, ctx, shape)
+        # the kernel's whole-sequence form reads the projections' rows
+        # as they lie: q, k, v and attn are [b, s, h * d] from here to
+        # the output projection (mha_project_qkv)
+        rows = plan.core == "tiled" and _whole_form_takes(ctx, shape)
+        q, k, v = mha_project_qkv(
+            ins, ws, ctx, use_bias=use_bias, params=params, rows=rows
         )
         if plan.core == "ulysses":
             seq_ax, batch_ax, _ = _seq_parallel_axes(ctx)
@@ -1164,6 +1256,8 @@ def _lower_mha(params):
                 batch_axis=batch_ax,
                 head_axis=head_ax,
             )
+        elif rows:
+            attn = _tiled_rows(q, k, v, shape[3], ctx, causal)
         elif plan.core == "tiled":
             attn = _tiled(q, k, v, ctx, causal)
         elif plan.core == "flash":
@@ -1193,7 +1287,9 @@ def _lower_mha(params):
                 dropout_rate=dropout if dropping else 0.0,
                 dropout_rng=ctx.rng if dropping else None,
             )
-        return [mha_project_out(attn, ws, ctx, dt, use_bias=use_bias)]
+        return [
+            mha_project_out(attn, ws, ctx, dt, use_bias=use_bias, rows=rows)
+        ]
 
     return fn
 

@@ -12,6 +12,7 @@ compilation caching.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -152,6 +153,9 @@ class Executor:
         # rebind) — mirrored into train_jit_* series by FFModel.fit
         self.jit_builds = 0
         self.jit_invalidations = 0
+        # attention nodes of the train step by the core they took, set
+        # when the step is built (`attention_plans`)
+        self.attention_cores: Dict[str, int] = {}
 
     # -- shardings -----------------------------------------------------------
 
@@ -587,7 +591,25 @@ class Executor:
         if self._train_step is None:
             self._train_step = jax.jit(self.train_step_fn(), donate_argnums=(0, 1))
             self.jit_builds += 1
+            self.attention_cores = dict(
+                collections.Counter(p.core for p in self.attention_plans())
+            )
         return self._train_step
+
+    def attention_plans(self) -> List:
+        """The core each `multihead_attention` node of the train step is
+        lowered to (`ops.attention.mha_core_plan`, asked as
+        `forward_values` asks it in a train step), in graph order; by
+        core and count it is `attention_cores`, which `FFModel.fit`
+        mirrors into the `train_attention_core_nodes` gauges."""
+        from flexflow_tpu.ops.attention import mha_core_plan
+
+        rng = jax.random.PRNGKey(0)
+        return [
+            mha_core_plan(node.params, self.node_ctx(node, rng=rng))
+            for node in (self.graph.nodes[g] for g in self.topo)
+            if node.op_type == OperatorType.MULTIHEAD_ATTENTION
+        ]
 
     def eval_step(self):
         if self._eval_step is None:

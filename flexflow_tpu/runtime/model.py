@@ -1452,6 +1452,13 @@ class FFModel:
             help="batches ready in the loader's ring before their epoch "
             "began (the ring went on across the epoch's turn by itself)",
         )
+        for core, nodes in self.executor.attention_cores.items():
+            reg.gauge(
+                "train_attention_core_nodes",
+                help="multihead_attention nodes of the train step by the "
+                "core their lowering took (ops.attention.mha_core_plan)",
+                labels={"core": core},
+            ).set(nodes)
         borrowed, copied, lease_wait_s, ahead = loader.take_counts()
         c_borrowed.inc(borrowed)
         c_copied.inc(copied)

@@ -9,6 +9,12 @@ PERF.md section 5 holds; the same as JSON under `chiprun_out/`.
     chiprun --chips 4 -- python3 scripts/profile_cells.py --cell train_ff_b256_x4
     chiprun -- python3 scripts/profile_cells.py --cell serve_olmoe_chat
 
+A training cell's rows come with the attention core each node took
+(`ops/attention.py:mha_core_plan`): since PR 59 both cells read `tiled`,
+the hand-tiled kernel's whole-sequence form (`flash_whole_fwd` and
+`flash_whole_bwd`, one Mosaic call a node and pass), per device on
+`train_ff_b256_x4`; off a TPU the same model reads `chunked`.
+
 A serving cell is driven with `--concurrent` requests of `--prompt`
 tokens admitted together (one prefill program) that decode `--new`
 tokens side by side; the family's own scope shares are read from the

@@ -308,34 +308,139 @@ def _ctx(batch, b_deg=1, s_deg=1, h_deg=1, embed=1024, seq=512, **kw):
 _FLAGSHIP = {"embed_dim": 1024, "num_heads": 16}
 
 
-@pytest.mark.parametrize(
-    "params,ctx,plan",
-    [
-        # `train_ff_b256_x4`: 64 local sequences, 1 GiB of scores a chip
-        (_FLAGSHIP, dict(batch=256, b_deg=4), ("chunked", 4, 64, True)),
-        # `train_ff_b64`: the one-chip program, no wrapper
-        (_FLAGSHIP, dict(batch=64), ("chunked", 4, 64, False)),
-        # batch and heads sharded together: 8 local heads, chunks of 8
-        (_FLAGSHIP, dict(batch=256, b_deg=4, h_deg=2),
-         ("chunked", 8, 64, True)),
-        # heads alone: the global batch is whole on a device, GSPMD
-        # partitions the scan's body
-        (_FLAGSHIP, dict(batch=64, h_deg=2), ("chunked", 8, 64, False)),
-        # the sequence sharded and no seq-parallel path
-        ({**_FLAGSHIP, "seq_parallel": "none"},
-         dict(batch=256, b_deg=2, s_deg=2), ("one_shot", 128, 128, False)),
-        # attention-prob dropout
-        ({**_FLAGSHIP, "dropout": 0.1},
-         dict(batch=256, b_deg=4, train=True, rng=0),
-         ("one_shot", 64, 64, False)),
-        # a local block under the mono cap (64 MB): every toy mesh test
-        (_FLAGSHIP, dict(batch=16, b_deg=4), ("one_shot", 4, 4, False)),
-        # the sequence sharded on q and k alike
-        (_FLAGSHIP, dict(batch=8, b_deg=2, s_deg=2), ("ring", 4, 4, True)),
-    ],
-)
-def test_core_plan(params, ctx, plan):
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The repo's own backend probes answer as they do on the chip (JAX
+    itself never calls `jax.default_backend` through the module)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+# (params, ctx, the plan off a TPU, the plan on one)
+_PLAN_ROWS = [
+    # `train_ff_b256_x4`: 64 local sequences, 1 GiB of scores a chip; on
+    # the chip the kernel's whole-sequence form, per device
+    (_FLAGSHIP, dict(batch=256, b_deg=4), ("chunked", 4, 64, True),
+     ("tiled", 64, 64, True)),
+    # `train_ff_b64`: the one-chip program, no wrapper
+    (_FLAGSHIP, dict(batch=64), ("chunked", 4, 64, False),
+     ("tiled", 64, 64, False)),
+    # the cells as they run, bf16 matmul operands
+    (_FLAGSHIP, dict(batch=64, bf16_matmul=True), ("chunked", 4, 64, False),
+     ("tiled", 64, 64, False)),
+    # batch and heads sharded together: 8 local heads, chunks of 8
+    (_FLAGSHIP, dict(batch=256, b_deg=4, h_deg=2), ("chunked", 8, 64, True),
+     ("tiled", 64, 64, True)),
+    # heads alone: the global batch is whole on a device, GSPMD
+    # partitions the scan's body; the kernel runs per device
+    (_FLAGSHIP, dict(batch=64, h_deg=2), ("chunked", 8, 64, False),
+     ("tiled", 64, 64, True)),
+    # the sequence sharded and no seq-parallel path
+    ({**_FLAGSHIP, "seq_parallel": "none"},
+     dict(batch=256, b_deg=2, s_deg=2), ("one_shot", 128, 128, False), None),
+    # attention-prob dropout
+    ({**_FLAGSHIP, "dropout": 0.1},
+     dict(batch=256, b_deg=4, train=True, rng=0),
+     ("one_shot", 64, 64, False), None),
+    # asked for the dense core by name
+    ({**_FLAGSHIP, "use_flash": False}, dict(batch=64),
+     ("chunked", 4, 64, False), None),
+    # a local block under the mono cap (64 MB): every toy mesh test
+    (_FLAGSHIP, dict(batch=16, b_deg=4), ("one_shot", 4, 4, False),
+     ("tiled", 4, 4, True)),
+    # the sequence sharded on q and k alike
+    (_FLAGSHIP, dict(batch=8, b_deg=2, s_deg=2), ("ring", 4, 4, True), None),
+    # a sequence whose score block is over the kernel's VMEM reckoning
+    # and under the chunk cap: the scan, on a TPU too
+    (_FLAGSHIP, dict(batch=8, seq=1024, bf16_matmul=False),
+     ("chunked", 1, 8, False), "whole"),
+    (_FLAGSHIP, dict(batch=2, seq=2048), ("chunked", 1, 2, False),
+     ("tiled", 2, 2, False)),
+]
+
+
+@pytest.mark.parametrize("params,ctx,plan,_", _PLAN_ROWS)
+def test_core_plan(params, ctx, plan, _):
+    """The backend as it is here: the answers before PR 59, every row."""
     assert A.mha_core_plan(params, _ctx(**ctx)) == A.CorePlan(*plan)
+
+
+@pytest.mark.parametrize("params,ctx,off_tpu,on_tpu", _PLAN_ROWS)
+def test_core_plan_on_a_tpu(as_tpu, params, ctx, off_tpu, on_tpu):
+    """The same rows with the backend seen as a TPU: `tiled` where the
+    hand-tiled kernel takes the per-device shape (the whole-sequence form
+    at the cells' 512, the grid form past the chunk cap), the rest as
+    off a TPU."""
+    if on_tpu == "whole":  # 1,024: taken or not by what the chip said
+        from flexflow_tpu.ops.pallas.flash_kernel import supports_whole
+
+        on_tpu = ("tiled", 8, 8, False) if supports_whole(
+            1024, 1024, 16, 64, 4
+        ) else None
+    want = A.CorePlan(*(on_tpu or off_tpu))
+    assert A.mha_core_plan(params, _ctx(**ctx)) == want
+
+
+def _stack(layers, batch=2, seq=128, hidden=128, heads=2):
+    """`layers` attention blocks of lane-tile heads (2 of 64) and a dense
+    head: the smallest model whose nodes take the kernel's whole form."""
+    from flexflow_tpu import FFConfig, FFModel, LossType, SGDOptimizer
+
+    m = FFModel(FFConfig(batch_size=batch, learning_rate=0.05))
+    t = m.create_tensor([batch, seq, hidden], name="x")
+    for _ in range(layers):
+        t = m.add(t, m.multihead_attention(t, t, t, hidden, heads))
+    m.dense(t, 1, use_bias=False)
+    m.compile(
+        optimizer=SGDOptimizer(lr=0.05),
+        loss_type=LossType.MEAN_SQUARED_ERROR_AVG_REDUCE,
+        metrics=[],
+        devices=jax.devices()[:1],
+    )
+    return m
+
+
+def test_twelve_nodes_hold_one_kernel_body_a_pass(as_tpu):
+    """The step lowered for a TPU: twelve attention nodes call ONE
+    forward and ONE backward Mosaic body (the calls sit behind an inner
+    jit, so the kernel is traced and lowered once a program), and no
+    scan is left."""
+    m = _stack(12)
+    ex = m.executor
+    assert [p.core for p in _attention_plans(m)] == ["tiled"] * 12
+    data = {
+        "x": jax.ShapeDtypeStruct((2, 128, 128), jnp.float32),
+        "label": jax.ShapeDtypeStruct((2, 128, 1), jnp.float32),
+    }
+    text = (
+        jax.jit(ex.train_step_fn())
+        .trace(m.params, m.opt_state, data, jax.random.PRNGKey(0))
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    assert text.count("tpu_custom_call") == 2
+    assert text.count("call @_whole_fwd") == 12
+    assert text.count("call @_whole_bwd") == 12
+    assert "stablehlo.while" not in text
+
+
+def test_the_step_publishes_the_cores_its_nodes_took(dense_caps):
+    """`train_attention_core_nodes`: set when the step is built, mirrored
+    into the telemetry by `fit()`. On a CPU every node of a step over
+    the mono cap reads `chunked`."""
+    from flexflow_tpu.telemetry import Telemetry
+
+    dense_caps(0, 0)
+    m = _stack(3, batch=4)
+    assert m.executor.attention_cores == {}
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(8, 128, 128)).astype(np.float32)
+    y = rng.normal(size=(8, 128, 1)).astype(np.float32)
+    tele = Telemetry()
+    m.fit(x, y, epochs=1, verbose=False, telemetry=tele)
+    assert m.executor.attention_cores == {"chunked": 3}
+    row = tele.registry.sample()
+    cores = {k: v for k, v in row.items() if "train_attention_core_nodes" in k}
+    assert list(cores.values()) == [3] and "chunked" in next(iter(cores))
 
 
 def test_one_device_step_scans_without_shard_map(dense_caps):

@@ -118,6 +118,122 @@ def test_uneven_seq_blocks():
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
+# -- the whole-sequence form (PR 59) ------------------------------------------
+
+
+def _sdpa_loss(fn, w):
+    return lambda q, k, v: (fn(q, k, v).astype(jnp.float32) * w).sum()
+
+
+@pytest.mark.parametrize("sq", [128, 512])
+@pytest.mark.parametrize("block_heads", [2, 4, None], ids=["2", "4", "all"])
+@pytest.mark.parametrize(
+    "dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"]
+)
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_whole_form_matches_sdpa(causal, dtype, block_heads, sq):
+    """One call forward and one backward over the projections' own rows,
+    held to `scaled_dot_product_attention`: values and the three
+    gradients, a batch of 3, 2 / 4 / all 8 heads a grid step. bfloat16
+    differs by roundings of single values only (both round the
+    probabilities before `p v`): a few units of the last place."""
+    from flexflow_tpu.ops.attention import scaled_dot_product_attention
+
+    rng = np.random.RandomState(sq)
+    q, k, v, w = (
+        jnp.asarray(rng.randn(3, sq, 8, 64).astype(np.float32), dtype)
+        for _ in range(4)
+    )
+    whole = functools.partial(
+        flash_attention_tpu, causal=causal, block_heads=block_heads,
+        interpret=True,
+    )
+    ref = functools.partial(scaled_dot_product_attention, causal=causal)
+    w = w.astype(jnp.float32)
+    got = (whole(q, k, v), *jax.grad(_sdpa_loss(whole, w), (0, 1, 2))(q, k, v))
+    want = (ref(q, k, v), *jax.grad(_sdpa_loss(ref, w), (0, 1, 2))(q, k, v))
+    # bfloat16: values of a few units, 8 mantissa bits
+    tol = dict(atol=5e-5, rtol=5e-4) if dtype == jnp.float32 else dict(
+        atol=6e-2, rtol=2e-2
+    )
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol
+        )
+
+
+@pytest.fixture
+def forms(monkeypatch):
+    """Which form's forward each `flash_attention_tpu` call traced."""
+    from flexflow_tpu.ops.pallas import flash_kernel as fk
+
+    seen = []
+    for name in ("_whole_fwd", "_fwd"):
+        inner = getattr(fk, name)
+
+        def spy(*a, _inner=inner, _name=name, **kw):
+            seen.append(_name)
+            return _inner(*a, **kw)
+
+        monkeypatch.setattr(fk, name, spy)
+    return seen
+
+
+@pytest.mark.parametrize(
+    "seq,kwargs,form",
+    [
+        (256, {}, "_whole_fwd"),
+        # the ring's residual: the grid form, whatever the length
+        (256, {"return_lse": True}, "_fwd"),
+        # a caller that names its blocks asks for the grid
+        (256, {"block_q": 128, "block_k": 128}, "_fwd"),
+        # one head's score block and the backward's temporaries are over
+        # the VMEM reckoning
+        (2048, {}, "_fwd"),
+    ],
+    ids=["short", "return_lse", "named_blocks", "over_the_cap"],
+)
+def test_which_form_a_call_takes(forms, seq, kwargs, form):
+    """...and the grid form's results are what they were: against dense."""
+    rng = np.random.RandomState(2)
+    q, k, v = (
+        jnp.asarray(rng.randn(1, seq, 2, 64).astype(np.float32))
+        for _ in range(3)
+    )
+    out = flash_attention_tpu(q, k, v, causal=True, interpret=True, **kwargs)
+    assert forms == [form]
+    ref, logits = _dense(q, k, v, True)
+    if kwargs.get("return_lse"):
+        out, lse = out
+        np.testing.assert_allclose(
+            lse, jax.scipy.special.logsumexp(logits, axis=-1),
+            atol=2e-5, rtol=2e-5,
+        )
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize(
+    "shape,itemsize,heads",
+    [
+        ((512, 512, 16, 64), 2, 8),  # the training cells', bf16
+        ((512, 512, 16, 64), 4, 8),
+        ((256, 256, 12, 64), 2, 12),  # no divisor in sublane tiles: all
+        ((1024, 1024, 16, 64), 2, 8),
+        ((2048, 2048, 16, 64), 2, None),  # the grid form's
+        ((512, 1024, 16, 64), 2, None),  # no self-attention
+        ((100, 100, 16, 64), 2, None),  # not in lane tiles
+        ((128, 128, 4, 8), 4, None),  # 32 lanes of heads: no whole tile
+        ((512, 512, 8, 128), 2, 8),
+    ],
+)
+def test_whole_block_heads(shape, itemsize, heads):
+    from flexflow_tpu.ops.pallas import flash_kernel as fk
+
+    assert fk.whole_block_heads(*shape, itemsize) == heads
+    assert fk.supports_whole(*shape, itemsize) == (heads is not None)
+
+
 def test_supports():
     assert supports(4096, 4096, 64)
     assert supports(256, 256, 64)

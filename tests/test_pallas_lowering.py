@@ -42,8 +42,16 @@ GEOMETRIES = {
 }
 # the serving cells' pools, in tokens (elsewhere: every slot's max_len)
 POOL_TOKENS = {"gpt2_medium": 8192, "olmoe": 16384}
-# (batch, seq, heads, head_dim) the training path hands the tiled kernel
-FLASH_SHAPES = {"smoke": (1, 256, 2, 8), "flagship": (8, 2048, 16, 64)}
+# (batch, seq, heads, head_dim) the training path hands the tiled kernel:
+# "flagship" is the grid form's band, "train_cells" what a chip of
+# `train_ff_b64` and of `train_ff_b256_x4` holds, the whole-sequence form
+# (forward and both bodies through the VJP; with the log-sum-exp asked
+# for, the grid form at that shape)
+FLASH_SHAPES = {
+    "smoke": (1, 256, 2, 8),
+    "flagship": (8, 2048, 16, 64),
+    "train_cells": (64, 512, 16, 64),
+}
 
 
 def _sds(shape, dtype=jnp.float32):
@@ -134,12 +142,8 @@ def _flash_cases(geom):
 
 
 CASES = [
-    c
-    for geom in GEOMETRIES
-    for c in (
-        *_decode_cases(geom),
-        *(_flash_cases(geom) if geom in FLASH_SHAPES else ()),
-    )
+    *(c for geom in GEOMETRIES for c in _decode_cases(geom)),
+    *(c for geom in FLASH_SHAPES for c in _flash_cases(geom)),
 ]
 
 
